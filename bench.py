@@ -1,10 +1,13 @@
 """Headline benchmark: GPT-2-small training throughput on one chip.
 
 Prints ONE JSON line:
-``{"metric": ..., "value": N, "unit": ..., "vs_baseline": N}``
+``{"metric": ..., "value": N, "unit": ..., "vs_baseline": N, "device": ..}``
 
 ``vs_baseline`` is measured MFU / 0.40 — the north-star target from
 ``BASELINE.json`` (≥40% MFU on v5e). >1.0 beats the target.
+
+A device measurement or nothing: with no TPU, or on a chip whose peak
+is not on record, this exits non-zero without printing a metric.
 """
 from __future__ import annotations
 
@@ -12,41 +15,28 @@ import json
 import time
 
 
-def _peak_flops(device) -> float:
-    kind = getattr(device, "device_kind", "").lower()
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12   # bf16 peak per v5e chip
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    if "v3" in kind:
-        return 123e12
-    if "v2" in kind:
-        return 45e12
-    return 0.0          # unknown (CPU run) → MFU not computable
-
-
 def main() -> None:
+    import dataclasses
+
+    from ray_tpu._private import chip
+
+    chip.ensure_compile_cache()
     import jax
     import numpy as np
 
     from ray_tpu.models import gpt
     from ray_tpu.parallel import create_mesh
 
-    import dataclasses
-
-    dev = jax.devices()[0]
-    on_tpu = dev.platform == "tpu"
+    device = chip.require_tpu()
+    peak = chip.peak_flops(device["kind"])
     # Tuned on v5e: batch 32 saturates HBM headroom with selective remat
     # + the Pallas flash kernel (block 512); larger batches OOM on the
     # f32 loss logits.
-    cfg = gpt.CONFIGS["small"] if on_tpu else gpt.CONFIGS["nano"]
-    cfg = dataclasses.replace(cfg, remat="dots", attn_backend="auto")
-    batch, seq = (32, 1024) if on_tpu else (8, 64)
-    seq = min(seq, cfg.max_seq)  # loss uses tokens[:, :-1], so seq==max_seq ok
+    cfg = dataclasses.replace(gpt.CONFIGS["small"], remat="dots",
+                              attn_backend="auto")
+    batch, seq = 32, 1024   # loss uses tokens[:, :-1], so seq==max_seq ok
 
-    mesh = create_mesh({"dp": 1}, devices=[dev])
+    mesh = create_mesh({"dp": 1}, devices=[jax.devices()[0]])
     init, step, state_sh, batch_sh = gpt.make_train_step(cfg, mesh)
     state = init(jax.random.PRNGKey(0))
     tokens = jax.device_put(
@@ -54,13 +44,12 @@ def main() -> None:
         batch_sh)
     data = {"tokens": tokens}
 
-    # Warmup/compile. Sync via a host fetch of the loss — on some PJRT
-    # transports block_until_ready returns at dispatch, not completion.
+    # Warmup/compile, then wait for the device before the clock starts.
     for _ in range(3):
         state, metrics = step(state, data)
     float(metrics["loss"])
 
-    iters = 20 if on_tpu else 3
+    iters = 20
     t0 = time.perf_counter()
     for _ in range(iters):
         state, metrics = step(state, data)
@@ -72,15 +61,15 @@ def main() -> None:
     # 6N matmul + 12*L*S*d attention flops per token (fwd+bwd).
     flops_per_token = (6 * cfg.num_params()
                        + 12 * cfg.n_layer * seq * cfg.d_model)
-    achieved = tokens_per_sec * flops_per_token
-    peak = _peak_flops(dev)
-    mfu = achieved / peak if peak else 0.0
+    mfu = tokens_per_sec * flops_per_token / peak
 
     print(json.dumps({
         "metric": "gpt2s_train_tokens_per_sec_per_chip",
         "value": round(tokens_per_sec, 1),
         "unit": "tokens/s",
         "vs_baseline": round(mfu / 0.40, 4),
+        "attention": gpt.attention_plan(cfg, seq),
+        "device": device,
     }))
 
 

@@ -3,12 +3,15 @@
 
 Builds an ``{fsdp: N}`` mesh over every visible device and measures
 training throughput + MFU. Model size scales with the device count:
-the 1b preset needs its optimizer state sharded across ≥8 chips
+the 1b preset needs its optimizer state sharded across several chips
 (adamw f32 master+moments ≈ 17 GB), so a single chip runs the medium
 (GPT-2-medium, 350M) preset instead — same code path, same sharding
 rules, smaller shapes.
 
 Run: ``python benchmarks/lm_sharded.py [--config 1b] [--batch N]``
+
+A device measurement or nothing: with no TPU, or on a chip whose peak
+is not on record, this exits non-zero without printing a metric.
 """
 from __future__ import annotations
 
@@ -22,17 +25,6 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-def _peak_flops(device) -> float:
-    kind = getattr(device, "device_kind", "").lower()
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v4" in kind:
-        return 275e12
-    return 0.0
-
-
 def main():
     parser = argparse.ArgumentParser()
     parser.add_argument("--config", default=None,
@@ -42,23 +34,20 @@ def main():
     parser.add_argument("--iters", type=int, default=10)
     args = parser.parse_args()
 
+    from ray_tpu._private import chip
+
+    chip.ensure_compile_cache()
     import jax
     import numpy as np
 
     from ray_tpu.models import gpt
     from ray_tpu.parallel import create_mesh
 
+    device = chip.require_tpu()
+    peak = chip.peak_flops(device["kind"])
     devs = jax.devices()
     n = len(devs)
-    on_tpu = devs[0].platform == "tpu"
-    if args.config:
-        name = args.config
-    elif not on_tpu:
-        name = "nano"
-    elif n >= 8:
-        name = "1b"
-    else:
-        name = "medium"
+    name = args.config or ("1b" if n >= 4 else "medium")
     cfg = dataclasses.replace(gpt.CONFIGS[name], remat="dots",
                               attn_backend="auto")
     batch = args.batch or (8 if name in ("medium", "1b") else 4) * n
@@ -85,8 +74,7 @@ def main():
     tokens_per_sec = batch * seq * args.iters / dt
     flops_per_token = (6 * cfg.num_params()
                        + 12 * cfg.n_layer * seq * cfg.d_model)
-    peak = _peak_flops(devs[0]) * n
-    mfu = tokens_per_sec * flops_per_token / peak if peak else 0.0
+    mfu = tokens_per_sec * flops_per_token / (peak * n)
     print(json.dumps({
         "metric": f"gpt_{name}_fsdp{n}_tokens_per_sec",
         "value": round(tokens_per_sec, 1),
@@ -94,7 +82,9 @@ def main():
         "params": cfg.num_params(),
         "batch": batch, "seq": seq,
         "mfu": round(mfu, 4),
-        "vs_baseline": round(mfu / 0.40, 4) if peak else None,
+        "vs_baseline": round(mfu / 0.40, 4),
+        "attention": gpt.attention_plan(cfg, seq),
+        "device": device,
     }))
 
 

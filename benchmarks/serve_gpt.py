@@ -1,5 +1,4 @@
-"""Streaming GPT serving benchmark (VERDICT round 2 item 4; round 5
-weak #5): a decode-loop replica with bucketed prefill streaming through
+"""Streaming GPT serving benchmark: a decode-loop replica with bucketed prefill streaming through
 Serve (replica generator → handle → chunked HTTP), now with an A/B
 chunked-decode mode.
 
@@ -18,7 +17,13 @@ amortization itself — jitted dispatches per generated token counted on
 the replica. JSON lines; chunk 1 keeps the legacy metric names.
 
 Run: ``python benchmarks/serve_gpt.py [--clients 4] [--tokens 32]
-[--chunk 1,8]`` (CPU fallback shrinks the model).
+[--chunk 1,8] [--config nano]``. The model is the ``--config``
+argument; no process here looks at its device to pick a size. These
+arms are CPU correctness A/Bs (tier-1 spawns their ``--smoke`` forms):
+their replicas ask for no chip, so on a TPU node they are confined to
+the CPU, and several arms compute their oracle in the driver — the
+chip path is ``chip_smoke.py`` until ROADMAP S1's benchmark replaces
+this script.
 
 ``--overload`` switches to the request-lifecycle A/B instead: offered
 load ~3x a 4-slot replica, once with an effectively unbounded admission
@@ -119,7 +124,12 @@ def main():
     parser.add_argument("--tokens", type=int, default=32)
     parser.add_argument("--streams", type=int, default=8,
                         help="total streams per client")
-    parser.add_argument("--config", default="")
+    parser.add_argument("--config", default="nano",
+                        help="gpt preset, chosen by the caller: no "
+                             "process here looks at its device to pick "
+                             "a size (the driver of the serve arms must "
+                             "stay off jax — a parent that touched it "
+                             "holds the chip its replicas need)")
     parser.add_argument("--chunk", default="1,8",
                         help="comma-separated decode chunk sizes to A/B "
                              "(1 = per-token decode_step loop)")
@@ -222,20 +232,14 @@ def main():
                 os.environ.get("XLA_FLAGS", "") +
                 f" --xla_force_host_platform_device_count="
                 f"{max(8, args.tp)}").strip()
-        import jax as _jax
-
-        cfg_name = args.config or (
-            "small" if _jax.devices()[0].platform == "tpu" else "nano")
+        cfg_name = args.config
         run_tp_ab(args, np, cfg_name, f"gpt_{cfg_name}")
         return
 
     if args.paged:
         # Direct engine drive: the A/B isolates the pool architecture
         # (flat reservation vs pages) from the serve transport.
-        import jax as _jax
-
-        cfg_name = args.config or (
-            "small" if _jax.devices()[0].platform == "tpu" else "nano")
+        cfg_name = args.config
         run_paged_ab(args, np, cfg_name, f"gpt_{cfg_name}")
         return
 
@@ -243,10 +247,7 @@ def main():
         # Direct engine drive again: the A/B isolates the dispatch-loop
         # arithmetic (k sequential target steps vs draft + one verify
         # forward) from the serve transport.
-        import jax as _jax
-
-        cfg_name = args.config or (
-            "small" if _jax.devices()[0].platform == "tpu" else "nano")
+        cfg_name = args.config
         run_spec_ab(args, np, cfg_name, f"gpt_{cfg_name}")
         return
 
@@ -262,10 +263,7 @@ def main():
     else:
         serve.start(proxy=False)
 
-    import jax
-
-    on_tpu = jax.devices()[0].platform == "tpu"
-    cfg_name = args.config or ("small" if on_tpu else "nano")
+    cfg_name = args.config
     max_new = args.tokens
 
     @serve.deployment(max_ongoing_requests=8)
@@ -275,6 +273,8 @@ def main():
         per-chunk token slice."""
 
         def __init__(self, cfg_name: str, max_len: int, chunk_sizes):
+            import jax
+
             from ray_tpu.models import gpt, gpt_decode
 
             self.cfg = gpt.CONFIGS[cfg_name]
@@ -300,6 +300,7 @@ def main():
                 self._tokens += tokens
 
         def warm(self, prompt_bucket: int, _=None):
+            import jax
             import jax.numpy as jnp
 
             cache = self.gd.init_cache(self.cfg, 1, self.max_len)
@@ -514,11 +515,12 @@ def make_traced_deployment(serve, np):
     streaming handler, so ONE traced request crosses every serve stage
     — proxy admission, router queue, replica dispatch, batch flush, and
     one fused decode dispatch per chunk."""
-    import jax
 
     @serve.deployment(max_ongoing_requests=4)
     class GPTTraced:
         def __init__(self, cfg_name: str, max_len: int, chunk: int):
+            import jax
+
             from ray_tpu.models import gpt, gpt_decode
 
             self.cfg = gpt.CONFIGS[cfg_name]
@@ -759,11 +761,12 @@ def make_continuous_deployments(serve, np, plen: int, slots: int):
       ``@serve.batch(continuous=True)`` — persistent KV pool, per-slot
       admission at chunk boundaries, per-slot freeing at max_new.
     """
-    import jax
 
     @serve.deployment(max_ongoing_requests=128)
     class GPTStatic:
         def __init__(self, cfg_name: str, max_len: int, chunk: int):
+            import jax
+
             from ray_tpu.models import gpt, gpt_decode
 
             self.cfg = gpt.CONFIGS[cfg_name]
@@ -820,6 +823,8 @@ def make_continuous_deployments(serve, np, plen: int, slots: int):
     class GPTContinuous:
         def __init__(self, cfg_name: str, max_len: int, slots: int,
                      chunk: int):
+            import jax
+
             from ray_tpu.models import gpt
             from ray_tpu.serve.engine import DecodeEngine
 
@@ -1380,7 +1385,7 @@ def _run_attn_kernel_arm(args, np, cfg, params, model):
                        token_streams["pallas"][i])
         for i in range(n_req))
     assert identical, "kernel arm diverged from gather at temp 0"
-    import jax as _jax
+    from ray_tpu._private.chip import pallas_interpret
 
     print(json.dumps({
         "metric": f"serve_{model}_attn_kernel_ab",
@@ -1389,7 +1394,7 @@ def _run_attn_kernel_arm(args, np, cfg, params, model):
         "unit": "x_tpot_gather_vs_kernel",
         "token_identical_temp0": identical,
         "kernel_dispatches": rows["pallas"]["kernel_dispatches"],
-        "interpret_mode": _jax.default_backend() != "tpu",
+        "interpret_mode": pallas_interpret(),
         "smoke": bool(args.smoke),
     }))
 
@@ -1472,7 +1477,7 @@ def run_tp_ab(args, np, cfg_name, model):
         "dispatches_equal": accounting[1] == accounting[args.tp],
         "tok_s_tp1": rows[1]["tok_s"],
         "tok_s_sharded": rows[args.tp]["tok_s"],
-        "host_mesh": jax.default_backend() != "tpu",
+        "host_mesh": jax.default_backend() == "cpu",
         "smoke": bool(args.smoke),
     }))
 

@@ -8,7 +8,9 @@ once per bucket. N closed-loop clients fire requests; we report p50/p99
 latency and throughput as JSON lines.
 
 Run: ``python benchmarks/serve_resnet.py [--clients 16] [--secs 10]``
-(CPU fallback uses a shrunken resnet18 so the benchmark completes).
+The model is what the arguments say (ResNet-50 at 224 by default); the
+driver never touches jax — the replica holds the chip (it asks for one
+when the node has one) and reports the device it ran on.
 """
 from __future__ import annotations
 
@@ -27,42 +29,50 @@ def main():
     parser.add_argument("--clients", type=int, default=16)
     parser.add_argument("--secs", type=float, default=10.0)
     parser.add_argument("--max-batch", type=int, default=16)
+    parser.add_argument("--depth", type=int, default=50)
+    parser.add_argument("--size", type=int, default=224)
     args = parser.parse_args()
 
     import numpy as np
 
     import ray_tpu as rt
     from ray_tpu import serve
+    from ray_tpu._private.accelerators import local_chip_count
+    from ray_tpu._private.chip import ensure_compile_cache
 
+    ensure_compile_cache()      # before rt.init(): workers inherit it
     rt.init(num_cpus=8, ignore_reinit_error=True)
     serve.start(http_options={"host": "127.0.0.1", "port": 0})
+    depth, size = args.depth, args.size
 
-    import jax
-
-    on_tpu = jax.devices()[0].platform == "tpu"
-    depth, size = (50, 224) if on_tpu else (18, 64)
-
-    @serve.deployment(max_ongoing_requests=64)
+    # One chip for the one replica where the node has chips: a worker
+    # that is granted none is confined to the CPU.
+    @serve.deployment(max_ongoing_requests=64, ray_actor_options={
+        "num_tpus": min(1, local_chip_count())})
     class ResNetReplica:
         def __init__(self, depth: int, size: int, max_batch: int):
+            import jax
+
+            from ray_tpu._private.chip import device_summary
             from ray_tpu.models import resnet
 
+            self.device = device_summary()
             self.cfg = resnet.ResNetConfig(depth=depth)
             params = resnet.init_params(jax.random.PRNGKey(0), self.cfg)
             self.predict = resnet.make_predictor(self.cfg, params,
                                                  uint8_input=True)
             self.size = size
             self.max_batch = max_batch
-
-        def warm(self, _=None):
-            # Compile every bucket AFTER deploy (first XLA compile can
-            # exceed the deploy-ready timeout) so p50 excludes compiles.
+            # Compile every bucket before the replica reports ready
+            # (readiness has no deadline), so p50 excludes compiles.
             from ray_tpu.serve.batching import default_buckets
 
-            for b in default_buckets(self.max_batch):
+            for b in default_buckets(max_batch):
                 np.asarray(self.predict(np.zeros(
-                    (b, self.size, self.size, 3), np.uint8)))
-            return "warm"
+                    (b, size, size, 3), np.uint8)))
+
+        def device_summary(self):
+            return self.device
 
         # Class is defined inside main(), so the decorator can take the
         # CLI's batch size — serving and warmup always agree on buckets.
@@ -81,7 +91,7 @@ def main():
     handle = serve.run(
         ResNetReplica.bind(depth, size, args.max_batch),
         name="resnet", route_prefix=None)
-    assert handle.options(method_name="warm").remote().result() == "warm"
+    device = handle.options(method_name="device_summary").remote().result()
     handle.remote().result()  # end-to-end warm
 
     latencies = []
@@ -112,10 +122,10 @@ def main():
     print(json.dumps({"metric": f"serve_{model}_p50_ms",
                       "value": round(p50, 2), "unit": "ms",
                       "clients": args.clients,
-                      "p99_ms": round(p99, 2)}))
+                      "p99_ms": round(p99, 2), "device": device}))
     print(json.dumps({"metric": f"serve_{model}_throughput",
                       "value": round(n / wall, 1), "unit": "req/s",
-                      "clients": args.clients}))
+                      "clients": args.clients, "device": device}))
     serve.shutdown()
     rt.shutdown()
 

@@ -3,11 +3,16 @@
 The reference ships its data plane as prebuilt C++ (bazel targets under
 ``src/ray/``); this runtime compiles its single-file extension lazily with
 the system compiler and caches the .so next to the source, keyed by the
-python ABI. If no compiler is available the callers fall back to the
-Python implementations in ``_private/serialization.py``.
+python ABI AND the content of ``codec.cpp`` — git ignores the binary, so
+a copied tree can carry one built from another commit's source, and an
+mtime says nothing about that. If no compiler is available the callers
+fall back to the Python implementations in
+``_private/serialization.py``.
 """
 from __future__ import annotations
 
+import glob
+import hashlib
 import os
 import subprocess
 import sysconfig
@@ -19,23 +24,27 @@ _mod = None
 _tried = False
 
 
-def _so_path() -> str:
-    tag = sysconfig.get_config_var("SOABI") or "generic"
-    return os.path.join(_here, f"_rt_native.{tag}.so")
-
-
 def _build() -> str:
     src = os.path.join(_here, "codec.cpp")
-    out = _so_path()
-    if os.path.exists(out) and \
-            os.path.getmtime(out) >= os.path.getmtime(src):
+    tag = sysconfig.get_config_var("SOABI") or "generic"
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    out = os.path.join(_here, f"_rt_native.{tag}.{digest}.so")
+    if os.path.exists(out):
         return out
     include = sysconfig.get_paths()["include"]
     cxx = os.environ.get("CXX", "g++")
+    tmp = f"{out}.{os.getpid()}.tmp"    # concurrent first users race
     cmd = [cxx, "-O3", "-shared", "-fPIC", "-std=c++17",
-           f"-I{include}", src, "-o", out + ".tmp"]
+           f"-I{include}", src, "-o", tmp]
     subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    os.replace(out + ".tmp", out)
+    os.replace(tmp, out)
+    for stale in glob.glob(os.path.join(_here, f"_rt_native.{tag}*.so")):
+        if stale != out:
+            try:
+                os.remove(stale)
+            except OSError:     # another first user got there first
+                pass
     return out
 
 

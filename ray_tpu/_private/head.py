@@ -38,6 +38,7 @@ from typing import Any, Dict, List, Optional, Set, Tuple
 from . import reaper, rpc
 from .config import Config
 from .ids import ActorID, NodeID, PlacementGroupID, WorkerID
+from .accelerators import node_chip_ids
 from .utils import spawn_env_with_pkg_root
 from .wal import HeadWAL
 
@@ -57,6 +58,10 @@ class WorkerInfo:
     charge: Any = None
     started_at: float = field(default_factory=time.time)
     leased_at: Optional[float] = None  # last lease grant (OOM ranking)
+    # Chip ids this PROCESS is confined to (set once, before it runs any
+    # user code — libtpu reads its visibility variables at jax import):
+    # None = never bound (fresh, or on a node without chips).
+    chips: Optional[Tuple[str, ...]] = None
 
 
 @dataclass
@@ -79,6 +84,12 @@ class NodeInfo:
     phys_host: str = ""
     # Per-node dashboard agent endpoint (reference dashboard/agent.py)
     agent_url: Optional[str] = None
+    # The node's TPU chips (ids as libtpu's visibility variable spells
+    # them; empty on a node that advertises no TPU) and which live
+    # charge holds each one — a ``TPU: k`` grant is k of THESE, not
+    # just a number.
+    chips: List[str] = field(default_factory=list)
+    chip_owner: Dict[str, Any] = field(default_factory=dict)
 
     def utilization(self) -> float:
         fracs = [1.0 - self.available.get(k, 0.0) / v
@@ -142,7 +153,8 @@ class HeadService:
         local = NodeInfo(node_id=self.node_id.hex(),
                          hostname=socket.gethostname(),
                          total=dict(resources), available=dict(resources),
-                         is_head=True, phys_host=socket.gethostname())
+                         is_head=True, phys_host=socket.gethostname(),
+                         chips=node_chip_ids(resources.get("TPU", 0.0)))
         self.nodes: Dict[str, NodeInfo] = {local.node_id: local}
         self.local_node = local
         self.workers: Dict[WorkerID, WorkerInfo] = {}
@@ -700,7 +712,7 @@ class HeadService:
         node, charge = found
         self._apply_charge(charge)
         try:
-            w = await self._place_actor(actor, node)
+            w = await self._place_actor(actor, node, charge)
         except Exception:
             self._release_charged(charge)
             raise
@@ -737,6 +749,12 @@ class HeadService:
         """Release a node-resource or placement-group bundle charge."""
         if not charge:
             return
+        if charge[-1].get("TPU"):
+            # By identity, on whichever node holds them: a bundle's
+            # chips must come back even after its group is gone.
+            for node in self.nodes.values():
+                for c in self._chips_of(node, charge):
+                    del node.chip_owner[c]
         kind = charge[0]
         if kind == "pg":
             _, pg_id, idx, req = charge
@@ -882,12 +900,41 @@ class HeadService:
         info.proc = proc
         return info
 
-    async def _get_worker(self, node: NodeInfo) -> WorkerInfo:
-        while node.idle:
-            w = node.idle.popleft()
-            if w.worker_id in self.workers:
-                return w
-        return await self._spawn_worker(node)
+    async def _get_worker(self, node: NodeInfo, charge) -> WorkerInfo:
+        """A worker process for ``charge``, confined to exactly the
+        chips the charge holds. On a node without chips nothing is bound
+        and any pooled worker serves; on a node WITH chips every worker
+        is bound before it first runs user code — to its chips, or to
+        none (jax pinned to the CPU), so that a controller, proxy or
+        data actor cannot take a chip by importing jax. A pooled worker
+        is reused only for the SAME set: a process that initialised jax
+        on other chips keeps them until it exits, so one whose set
+        overlaps a different grant is retired first."""
+        chips = self._chips_of(node, charge) if node.chips else None
+        for w in [w for w in node.idle if w.worker_id not in self.workers]:
+            node.idle.remove(w)             # died while pooled
+        if chips:
+            for w in [w for w in node.idle if w.chips and w.chips != chips
+                      and set(w.chips) & set(chips)]:
+                node.idle.remove(w)
+                proc = w.proc
+                self._kill_worker(w)
+                if proc is not None:
+                    await self._loop.run_in_executor(
+                        None, lambda: proc.wait(timeout=10))
+        w = next((w for w in node.idle
+                  if chips is None or w.chips in (None, chips)), None)
+        if w is not None:
+            node.idle.remove(w)
+        else:
+            w = await self._spawn_worker(node)
+        if chips is not None and w.chips is None:
+            await w.conn.call_simple(
+                "bind_chips", {"chips": list(chips),
+                               "node_chips": len(node.chips)},
+                timeout=self.config.worker_lease_timeout_s)
+            w.chips = chips
+        return w
 
     def _return_worker(self, w: WorkerInfo):
         if w.worker_id in self.workers:
@@ -916,6 +963,14 @@ class HeadService:
     def _find_grant(self, req: Dict[str, float], pg_meta, strategy
                     ) -> Optional[Tuple[NodeInfo, Any]]:
         """Find (node, charge) for a request, or None if infeasible now."""
+        n_chips = int(req.get("TPU", 0))
+
+        def chips_fit(node: NodeInfo) -> bool:
+            # The TPU *count* fitting is not enough: the chips must be
+            # k that one process can hold together.
+            return not (n_chips and node.chips) or \
+                self._free_chip_group(node, n_chips) is not None
+
         if pg_meta is not None:
             pg_id, bundle_index = pg_meta
             pg = self.pgs.get(pg_id)
@@ -929,30 +984,66 @@ class HeadService:
                 node = self.nodes.get(nid) if nid else None
                 if node is None or node.state != "ALIVE":
                     continue
-                if all(rem.get(k, 0.0) + 1e-9 >= v for k, v in req.items()):
+                if all(rem.get(k, 0.0) + 1e-9 >= v
+                       for k, v in req.items()) and chips_fit(node):
                     return node, ("pg", pg_id, i, dict(req))
             return None
         node = self._pick_node(req, strategy)
-        if node is None:
+        if node is None or not chips_fit(node):
             return None
         return node, ("node", node.node_id, dict(req))
 
     def _apply_charge(self, charge):
         if charge[0] == "pg":
             _, pg_id, idx, req = charge
-            rem = self.pgs[pg_id].remaining[idx]
+            pg = self.pgs[pg_id]
+            rem = pg.remaining[idx]
             for k, v in req.items():
                 rem[k] = rem.get(k, 0.0) - v
+            node = self.nodes[pg.bundle_nodes[idx]]
         else:
             _, node_hex, req = charge
-            self._node_acquire(self.nodes[node_hex], req)
+            node = self.nodes[node_hex]
+            self._node_acquire(node, req)
+        self._take_chips(node, charge, int(req.get("TPU", 0)))
+
+    @staticmethod
+    def _chips_of(node: NodeInfo, charge) -> Tuple[str, ...]:
+        return tuple(c for c, ch in node.chip_owner.items()
+                     if ch is charge)
+
+    @staticmethod
+    def _free_chip_group(node: NodeInfo, k: int
+                         ) -> Optional[Tuple[str, ...]]:
+        """k free chips of ``node`` that one process can hold, or None
+        while there are none (the grant then waits, like any other
+        resource). Prefers the set an idle pooled worker is already
+        confined to — that process can be reused, jit caches and all —
+        then a free ALIGNED group: libtpu forms a two-chip process only
+        from mesh neighbours (0,1) or (2,3); (0,2) finds no topology
+        (established on the four-chip v5e host)."""
+        free = [c for c in node.chips if c not in node.chip_owner]
+        groups = [w.chips for w in node.idle
+                  if w.chips and len(w.chips) == k] + [
+            tuple(node.chips[i:i + k])
+            for i in range(0, len(node.chips) - k + 1, k)]
+        return next((g for g in groups if all(c in free for c in g)),
+                    None)
+
+    def _take_chips(self, node: NodeInfo, charge, k: int):
+        """Turn a ``TPU: k`` charge into k concrete chips of ``node``,
+        synchronously with the charge (so concurrent grants never pick
+        the same chip); ``_find_grant`` made sure a group is free."""
+        if k and node.chips:
+            for c in self._free_chip_group(node, k):
+                node.chip_owner[c] = charge
 
     async def _grant_lease(self, node: NodeInfo, charge) -> dict:
         """Spawn/reuse a worker for an ALREADY-APPLIED charge (callers must
         call ``_apply_charge`` synchronously right after ``_find_grant`` so
         concurrent grants can't double-book the same capacity)."""
         try:
-            w = await self._get_worker(node)
+            w = await self._get_worker(node, charge)
         except Exception:
             self._release_charged(charge)
             raise
@@ -992,8 +1083,8 @@ class HeadService:
                 fut.set_exception(e)
 
     # ------------------------------------------------------------- actors
-    async def _place_actor(self, actor: ActorInfo, node: NodeInfo):
-        w = await self._get_worker(node)
+    async def _place_actor(self, actor: ActorInfo, node: NodeInfo, charge):
+        w = await self._get_worker(node, charge)
         w.assignment = actor.actor_id
         actor.worker = w
         # Ask the worker to instantiate the actor.
@@ -1052,6 +1143,7 @@ class HeadService:
             labels=dict(payload.get("labels") or {}),
             phys_host=payload.get("host") or payload.get("hostname") or "?",
             agent_url=payload.get("agent_url"),
+            chips=list(payload.get("chip_ids") or ()),
         )
         self.nodes[node.node_id] = node
         prev_close = conn.on_close
@@ -1235,7 +1327,7 @@ class HeadService:
         node, charge = found
         self._apply_charge(charge)
         try:
-            w = await self._place_actor(actor, node)
+            w = await self._place_actor(actor, node, charge)
         except Exception as e:  # noqa: BLE001
             self._release_charged(charge)
             self._mark_actor_dead(actor, f"creation failed: {e}")

@@ -1,34 +1,21 @@
-"""Version-bridging shims for jax APIs the repo relies on.
+"""The jax names the repo's sharded programs go through.
 
-The repo targets the modern spelling (``jax.shard_map(..., check_vma=)``);
-older jax releases ship the same primitive as
-``jax.experimental.shard_map.shard_map`` with the check named
-``check_rep``. Resolve the spelling once here so every call site stays
-on the modern one.
+``shard_map`` is ``jax.shard_map`` of the one installation there is
+(jax 0.9.0): call sites import it from here so the spelling lives in
+one line.
 
 Also home to :func:`decode_mesh`, the one place a tensor-parallel
 DecodeEngine turns ``tp=N`` into a device mesh: every sharded jit
 factory in ``models/gpt_decode.py`` and every cache allocator keys off
 the mesh built here, so tp=2 on an 8-way forced-host-device CPU run
-and tp=8 on a TPU slice go through the identical code path.
+and tp=4 on a four-chip host go through the identical code path.
 """
 import functools
 
 import jax
 import numpy as np
 
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pre-0.6 jax: experimental spelling, check_vma named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kw):
-        # check_rep stays off: the legacy replication checker rejects
-        # valid cond-under-shard_map programs (its own error message
-        # says to pass check_rep=False as the workaround).
-        del check_vma
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False, **kw)
+shard_map = jax.shard_map
 
 
 @functools.lru_cache(maxsize=8)

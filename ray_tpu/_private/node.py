@@ -21,6 +21,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from . import reaper, rpc
 from .ids import NodeID, WorkerID
+from .accelerators import node_chip_ids
 from .utils import spawn_env_with_pkg_root
 
 
@@ -137,6 +138,7 @@ class NodeService:
             "hostname": self.shm_domain,
             "host": socket.gethostname(),
             "resources": self.resources,
+            "chip_ids": node_chip_ids(self.resources.get("TPU", 0.0)),
             "labels": self.labels,
             "agent_url": (
                 f"http://{self._agent_adv_host}:{self._agent.port}"

@@ -99,14 +99,14 @@ def _cmd_start(args) -> int:
         import tempfile
 
         from ._private import node_main
-        from .api import _detect_tpu_chips
+        from ._private.accelerators import local_chip_count
 
         session_dir = args.session_dir or tempfile.mkdtemp(
             prefix="ray_tpu_node_")
         # Same TPU autodetection as the head path: joining a TPU host
         # without --num-tpus must still advertise its chips.
         num_tpus = (args.num_tpus if args.num_tpus is not None
-                    else float(_detect_tpu_chips()))
+                    else float(local_chip_count()))
         argv = ["--head", args.address, "--session-dir", session_dir,
                 "--num-cpus", str(args.num_cpus)]
         if num_tpus:
@@ -121,10 +121,9 @@ def _cmd_start(args) -> int:
     import asyncio
     import time
 
-    from ._private.accelerators import gang_resources
+    from ._private.accelerators import gang_resources, local_chip_count
     from ._private.config import Config, set_global_config
     from ._private.head import HeadService
-    from .api import _detect_tpu_chips
 
     session_dir = args.session_dir or os.path.join(
         os.environ.get("TMPDIR", "/tmp"), "ray_tpu",
@@ -134,7 +133,7 @@ def _cmd_start(args) -> int:
     set_global_config(config)
     total = {"CPU": float(args.num_cpus),
              "TPU": float(args.num_tpus if args.num_tpus is not None
-                          else _detect_tpu_chips()),
+                          else local_chip_count()),
              # Same default total as rt.init()'s embedded head — a
              # missing "memory" resource would strand memory-requesting
              # leases forever.

@@ -22,6 +22,7 @@ import concurrent.futures
 import hashlib
 import os
 import socket
+import sys
 import threading
 import time
 import traceback
@@ -2527,6 +2528,20 @@ class CoreWorker:
             return {}
         if method == "create_actor":
             return await self._exec_create_actor(payload, bufs)
+        if method == "bind_chips":
+            # The head's chip assignment for this process, delivered
+            # before the first lease or actor lands here — so before
+            # user code, and so before jax is imported (libtpu reads
+            # its visibility variables once, at start-up).
+            from .._private.accelerators import chip_visibility_env
+
+            if "jax" in sys.modules:
+                raise rpc.RpcError(
+                    "this worker imported jax before its chip "
+                    "assignment arrived; it cannot be confined now")
+            os.environ.update(chip_visibility_env(
+                payload["chips"], payload["node_chips"]))
+            return {}
         if method == "pubsub":
             self._on_pubsub(payload["topic"], payload["msg"])
             return {}

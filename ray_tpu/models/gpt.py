@@ -162,13 +162,28 @@ def _attention_xla(q, k, v, cfg: GPTConfig):
 
 
 def _resolve_attn_backend(cfg: GPTConfig, seq: int) -> str:
-    """auto → flash on TPU when the Pallas kernel's constraints hold."""
+    """auto → flash where the Pallas kernel's SHAPE constraints hold.
+    The platform is not consulted: how the kernel then runs is
+    :func:`ray_tpu._private.chip.pallas_interpret`'s decision alone."""
     if cfg.attn_backend != "auto":
         return cfg.attn_backend
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if on_tpu and seq >= 512 and seq % 256 == 0 and cfg.head_dim % 8 == 0:
+    if seq >= 512 and seq % 256 == 0 and cfg.head_dim % 8 == 0:
         return "flash"
     return "xla"
+
+
+def attention_plan(cfg: GPTConfig, seq: int) -> Dict[str, str]:
+    """Which attention a step at this (cfg, seq) runs and how — the
+    fact a caller prints or asserts instead of guessing: ``backend`` is
+    what ``attn_backend`` resolves to, ``mode`` is ``"compiled"`` /
+    ``"interpret"`` for the Pallas kernel and ``"xla"`` otherwise."""
+    from ray_tpu._private.chip import pallas_interpret
+
+    backend = _resolve_attn_backend(cfg, seq)
+    if backend != "flash":
+        return {"backend": backend, "mode": "xla"}
+    return {"backend": backend,
+            "mode": "interpret" if pallas_interpret() else "compiled"}
 
 
 def _sp_shard_map(fn, cfg: GPTConfig, mesh):

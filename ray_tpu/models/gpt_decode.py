@@ -1008,14 +1008,6 @@ def init_paged_cache(cfg: GPTConfig, slots: int, n_pages: int,
     return cache if mesh is None else _shard_cache(cache, mesh)
 
 
-def _pallas_interpret() -> bool:
-    """Pallas lowers natively only on TPU; everywhere else (CPU tier-1,
-    dev boxes) the kernel runs in interpret mode — same grid, same
-    block program, emulated through XLA — so tests exercise the exact
-    kernel logic that ships."""
-    return jax.default_backend() != "tpu"
-
-
 def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
                     pt: jax.Array, pos: jax.Array, *, page_size: int,
                     kernel: str = "gather", ks=None, vs=None
@@ -1093,9 +1085,27 @@ def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
     unread) prefetch touches it. VMEM scratch carries the running max
     ``m [H, 1]``, exp-sum ``l [H, 1]`` and f32 accumulator
     ``acc [H, hd]`` across the slot's grid steps; the output block is
-    written once, on the slot's last step."""
+    written once, on the slot's last step.
+
+    The block body is written for what Mosaic lowers, not for the
+    shortest jnp spelling: a page is walked ROW BY ROW (``k_ref[0, p]``
+    indexes the block's untiled leading axis), so every value in the
+    kernel is a 2-D ``[H, hd]`` or ``[H, 1]`` tile — q·k is a VPU
+    multiply and a lane reduction, p·v a lane broadcast and an
+    accumulate. One query row per head has no use for the MXU, and the
+    obvious ``einsum("hd,phd->hp")`` is a batched contraction whose
+    batch axis sits in the MIDDLE of the page operand, which Mosaic
+    refuses. Products of two compute-dtype values are exact in f32, so
+    the arithmetic differs from the gather path's einsum only in f32
+    summation order, as before. int8 scales arrive gathered per
+    (slot, column) and shaped ``[.., H, 1]``: a ``(1, H)`` block over
+    the pool's ``[n_pages, H]`` scale array has a second-minor block
+    dim that is neither 8-aligned nor the full dim, and the ``[H, 1]``
+    layout is the one the per-row dequant broadcasts from."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    from .._private.chip import pallas_interpret
 
     B = q.shape[0]
     H, hd = q.shape[2], q.shape[3]
@@ -1126,38 +1136,43 @@ def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
         # Skip condition: unmapped column, or page wholly past pos[b].
         # A processed page always holds >= 1 valid position.
         live = (pt_ref[b, j] != PT_SENTINEL) & (j * ps <= pos_ref[b])
+        qf = q_ref[0].astype(jnp.float32)              # [H, hd]
 
-        def logits():
-            kv = k_ref[0]                              # [ps, H, hd]
+        def row(ref, s_ref, p):
+            """Page row p as f32 ``[H, hd]``, through the compute dtype
+            (int8 rows dequantize exactly as :func:`_deq_page`)."""
+            r = ref[0, p]
             if quant:
-                kv = (kv.astype(jnp.float32)
-                      * ks_ref[0][None, :, None]).astype(dtype)
-            lg = jnp.einsum("hd,phd->hp", q_ref[0], kv,
-                            preferred_element_type=jnp.float32) * scale
-            vpos = j * ps + lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-            return jnp.where(vpos <= pos_ref[b], lg, -1e30)
+                r = (r.astype(jnp.float32) * s_ref[0, 0]).astype(dtype)
+            return r.astype(jnp.float32)
+
+        def logit(p):
+            lg = jnp.sum(qf * row(k_ref, ks_ref if quant else None, p),
+                         axis=1, keepdims=True) * scale         # [H, 1]
+            return jnp.where(j * ps + p <= pos_ref[b], lg, -1e30)
 
         @pl.when(live & (phase == 0))
         def _stats():
-            lg = logits()
-            m_prev = m_ref[...]
-            m_new = jnp.maximum(m_prev, lg.max(axis=1, keepdims=True))
-            l_ref[...] = (l_ref[...] * jnp.exp(m_prev - m_new)
-                          + jnp.exp(lg - m_new).sum(axis=1,
-                                                    keepdims=True))
-            m_ref[...] = m_new
+            def body(p, carry):
+                m, l = carry
+                lg = logit(p)
+                m_new = jnp.maximum(m, lg)
+                return m_new, l * jnp.exp(m - m_new) + jnp.exp(lg - m_new)
+
+            m, l = lax.fori_loop(0, ps, body, (m_ref[...], l_ref[...]))
+            m_ref[...] = m
+            l_ref[...] = l
 
         @pl.when(live & (phase == 1))
         def _accum():
-            lg = logits()
-            p = (jnp.exp(lg - m_ref[...]) / l_ref[...]).astype(dtype)
-            vv = v_ref[0]
-            if quant:
-                vv = (vv.astype(jnp.float32)
-                      * vs_ref[0][None, :, None]).astype(dtype)
-            acc_ref[...] += jnp.einsum(
-                "hp,phd->hd", p, vv,
-                preferred_element_type=jnp.float32)
+            m, l = m_ref[...], l_ref[...]
+
+            def body(p, acc):
+                pr = (jnp.exp(logit(p) - m) / l).astype(dtype)  # [H, 1]
+                return acc + pr.astype(jnp.float32) * row(
+                    v_ref, vs_ref if quant else None, p)
+
+            acc_ref[...] = lax.fori_loop(0, ps, body, acc_ref[...])
 
         @pl.when((phase == 1) & (j == max_pages - 1))
         def _emit():
@@ -1167,7 +1182,7 @@ def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
         return (jnp.clip(pt_s[b, j], 0, n_pages - 1), 0, 0, 0)
 
     def scale_map(b, phase, j, pt_s, pos_s):
-        return (jnp.clip(pt_s[b, j], 0, n_pages - 1), 0)
+        return (b, j, 0, 0)
 
     def slot_map(b, phase, j, pt_s, pos_s):
         return (b, 0, 0)
@@ -1177,9 +1192,10 @@ def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
                 pl.BlockSpec((1, ps, H, hd), page_map)]
     inputs = [q[:, 0], kc, vc]
     if quant:
-        in_specs += [pl.BlockSpec((1, H), scale_map),
-                     pl.BlockSpec((1, H), scale_map)]
-        inputs += [ks, vs]
+        ptc = jnp.clip(pt, 0, n_pages - 1)
+        in_specs += [pl.BlockSpec((1, 1, H, 1), scale_map),
+                     pl.BlockSpec((1, 1, H, 1), scale_map)]
+        inputs += [ks[ptc][..., None], vs[ptc][..., None]]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1191,7 +1207,7 @@ def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
                             pltpu.VMEM((H, 1), jnp.float32),
                             pltpu.VMEM((H, hd), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), dtype),
-        interpret=_pallas_interpret(),
+        interpret=pallas_interpret(),
     )(pt, pos, *inputs)
     return out[:, None]
 
@@ -1515,7 +1531,12 @@ def jit_decode_chunk_slots_paged(cfg: GPTConfig, k: int, page_size: int,
             inner, mesh=mesh,
             in_specs=(_tp_param_specs(params), cspec,
                       P(), P(), P(), P()),
-            out_specs=(P(), cspec, P(), P()))(
+            out_specs=(P(), cspec, P(), P()),
+            # pallas_call's out_shape carries no vma annotation, which
+            # strict shard_map rejects (and the interpreter's own
+            # slicing trips the same check on the CPU): the kernel
+            # program runs unchecked, like the flash kernel's.
+            check_vma=attn_kernel != "pallas")(
                 params, cache, token, rngs, active, pt)
 
     return jax.jit(fn, donate_argnums=(1,))

@@ -54,13 +54,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu  # noqa: F401  (doc import)
+
+from .._private.chip import pallas_interpret
 
 NEG_INF = -1e30
-
-
-def _use_interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
 
 
 def _mask_diag_block(s, i, j, bq, bk):
@@ -296,15 +293,15 @@ def _pick_block(S: int) -> int:
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    block_k: Optional[int] = None) -> jax.Array:
     """Blockwise attention. q, k, v: ``[B, S, H, hd]`` → ``[B, S, H, hd]``.
 
-    Differentiable (custom VJP, flash backward). Falls back to the Pallas
-    interpreter off-TPU so tests run on the virtual CPU mesh.
+    Differentiable (custom VJP, flash backward). Compiled through
+    Mosaic on a TPU, interpreted on the CPU so tests run the same kernel
+    body on the virtual mesh — :func:`pallas_interpret` decides, nobody
+    else does.
     """
-    if interpret is None:
-        interpret = _use_interpret()
+    interpret = pallas_interpret()
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if block_q is None:

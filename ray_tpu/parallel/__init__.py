@@ -5,12 +5,12 @@ torch DDP/FSDP wrapping — ``python/ray/train/torch/config.py``,
 ``train_loop_utils.py``) with jax Mesh + NamedSharding: the compiler, not
 the framework, owns the collective schedule.
 """
+from .._private.accelerators import local_chip_count  # noqa: F401
 from .mesh import (  # noqa: F401
     AXIS_ORDER,
     MeshConfig,
     create_mesh,
     initialize_multihost,
-    local_chip_count,
     mesh_shape,
     single_device_mesh,
 )

@@ -18,7 +18,6 @@ Axis conventions (any subset may be present, sizes multiply to #devices):
 from __future__ import annotations
 
 import math
-import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -105,14 +104,3 @@ def initialize_multihost(coordinator_address: Optional[str] = None,
     if process_id is not None:
         kwargs["process_id"] = process_id
     jax.distributed.initialize(**kwargs)
-
-
-def local_chip_count() -> int:
-    """Best-effort local TPU chip count without initializing the runtime."""
-    env = os.environ.get("TPU_VISIBLE_CHIPS") or os.environ.get(
-        "TPU_VISIBLE_DEVICES")
-    if env:
-        return len([c for c in env.split(",") if c.strip()])
-    import glob
-
-    return len(glob.glob("/dev/accel*")) or 0

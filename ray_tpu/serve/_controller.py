@@ -105,12 +105,31 @@ class ServeController:
             # must find the full desired state, not a torso.
             self._journal_app(name)
             self._reconcile_once()
-        deadline = time.time() + 60
-        while time.time() < deadline:
+        # The reconcile above waited for every replica it started to
+        # finish constructing — weights, engine pools and the whole
+        # compiled-program set included — or to die trying: readiness
+        # is an event the runtime reports, not a deadline guessed from
+        # how long a small model takes. So the app is ready now, or a
+        # replica failed and the caller of serve.run() gets that
+        # replica's own error (a compiler message would otherwise stay
+        # in a worker log on a machine that may be thrown away). A pass
+        # that did neither (its health sweep raised first) is repeated,
+        # a bounded number of times.
+        for attempt in range(3):
+            if attempt:
+                self._reconcile_once()
             if self._app_ready(name):
                 return self.status()
-            time.sleep(0.05)
-        raise TimeoutError(f"app {name!r} did not become ready")
+            with self._lock:
+                app = self._apps.get(name) or {"deployments": {}}
+                errors = {dname: d["start_error"]
+                          for dname, d in app["deployments"].items()
+                          if d.get("start_error")}
+            if errors:
+                break
+        raise RuntimeError(
+            f"app {name!r} did not become ready; replica start errors: "
+            f"{errors or 'none recorded'}")
 
     def _apply_deployment(self, app: dict, dspec: dict) -> list:
         """Mutate deployment state; returns deferred blocking actions for
@@ -852,14 +871,18 @@ class ServeController:
                 try:
                     new.append(self._start_replica(app_name, dname, d,
                                                    role=role))
-                except Exception:  # noqa: BLE001 - journal/create
+                except Exception as e:  # noqa: BLE001 - journal/create
                     # failure: retried next tick (intent, if written,
                     # is swept by recovery)
                     traceback.print_exc()
+                    d["start_error"] = f"{type(e).__name__}: {e}"
             ok = []
             for rid, handle in new:
                 try:
-                    handle._wait_ready(timeout=60)
+                    # No deadline: the wait ends when the constructor
+                    # returns or the actor dies (the head reports
+                    # either), however long a cold compile takes.
+                    handle._wait_ready()
                     try:
                         node_id = rt.get(handle.get_node_id.remote(),
                                          timeout=10)
@@ -867,8 +890,9 @@ class ServeController:
                         node_id = None
                     self._maybe_crash("scale_up_created")
                     ok.append((rid, handle, node_id))
-                except Exception:  # noqa: BLE001
+                except Exception as e:  # noqa: BLE001
                     traceback.print_exc()
+                    d["start_error"] = f"{type(e).__name__}: {e}"
                     # Never-ready replica: kill it and clear its
                     # intent, or the named (detached) actor would
                     # linger as an orphan no journal entry describes.
@@ -882,6 +906,7 @@ class ServeController:
                     except Exception:  # noqa: BLE001 - swept later
                         pass
             if ok:
+                d.pop("start_error", None)
                 with self._lock:
                     for rid, handle, node_id in ok:
                         d["replicas"][rid] = {"handle": handle,

@@ -60,6 +60,13 @@ class Replica:
             self._user = callable_def  # plain function deployment
         if engine_config:
             self._apply_engine_config(engine_config)
+        # Compile every engine's whole program set BEFORE this
+        # constructor returns — that return is what reports the replica
+        # ready, and health probes (so the driver's wedge timer) only
+        # start after it. A compiler error raises here and reaches the
+        # caller of serve.run() as the replica's start error.
+        for eng in self._engines():
+            eng.warm_up()
         self._lock = threading.Lock()
         # Signalled when the last in-flight request finishes, so drain()
         # wakes immediately instead of polling (rtlint RT104 audit: the

@@ -304,7 +304,10 @@ def run(app: Application, *, name: str = DEFAULT_APP_NAME,
                         "`Deployment.bind()`")
     ctrl = start(proxy=_proxy)
     spec = _build_app_spec(app, name, route_prefix)
-    rt.get(ctrl.deploy_app.remote(spec), timeout=120)
+    # No deadline: deploy_app returns once every replica has finished
+    # constructing (cold compile included) and raises the replica's own
+    # error the moment one fails; a dead controller fails the get.
+    rt.get(ctrl.deploy_app.remote(spec))
     handle = DeploymentHandle(name, spec["ingress"])
     if blocking:
         try:

@@ -591,6 +591,10 @@ class DecodeEngine:
         # Fallback flight-recorder ids for bare in-process submissions
         # (no router upstream to stamp the contextvar).
         self._req_uid = 0
+        # A pending warm_up() request the driver picks up at its next
+        # loop boundary, and the report of the last one that ran.
+        self._warm: Optional[dict] = None
+        self._warm_report: Optional[dict] = None
         if auto_start:
             self.start()
 
@@ -1211,6 +1215,90 @@ class DecodeEngine:
             self._count(resumed=1)
         return lane
 
+    def warm_up(self) -> dict:
+        """Compile the engine's WHOLE program set now — every prompt
+        bucket's prefill, the chunk program, the verify program when a
+        drafter is bound, and the handoff program a disaggregated role
+        uses — instead of leaving each to the first request that
+        happens to need it. The replica calls this before it reports
+        ready: a cold compile at real width then lands in start-up,
+        where nothing times it, and never inside a supervised dispatch
+        (the driver stamps its heartbeat only between dispatches, so a
+        first-call compile would read as a wedge) or a client's
+        time-to-first-token. A compiler error is raised HERE, to the
+        caller, with the compiler's own message.
+
+        Every program runs once on the driver thread with inputs that
+        change nothing a later admission reads (no active lane, an
+        all-sentinel page table); the per-program seconds are set-up
+        time, kept in ``stats()["warm_up"]`` together with HOW the
+        attention kernel was built — read off the lowered chunk
+        program, not inferred from the platform. Blocks until done;
+        call it before traffic."""
+        req = {"done": threading.Event(), "error": None}
+        self.start()
+        self._warm = req
+        req["done"].wait()
+        if req["error"] is not None:
+            raise req["error"]
+        return self._warm_report
+
+    # rtlint: owner=driver
+    def _run_warm_up(self, req: dict):
+        import jax
+
+        gd = self._gd
+        secs = {}
+
+        def timed(name, fn, *args):
+            t0 = time.monotonic()
+            # rtlint: sync-ok=warm-up start-up compile, before traffic
+            out = jax.block_until_ready(fn(*args))
+            secs[name] = round(time.monotonic() - t0, 3)
+            return out
+
+        try:
+            key = jax.random.PRNGKey(0)
+            active = np.zeros((self.slots,), bool)
+            # The paged programs' extra operands: no history, an
+            # all-sentinel page table (every write drops), no COW.
+            none = np.full((self.max_pages,), gd.PT_SENTINEL, np.int32)
+            mid = (np.int32(0), none, np.int32(gd.PT_SENTINEL)) \
+                if self.paged else ()
+            tail = (self._pt,) if self.paged else ()
+            for b in self.prompt_buckets:
+                _, self._cache, _ = timed(
+                    f"prefill_{b}", self._prefill, self._params_dev,
+                    self._cache, np.zeros((1, b), np.int32), np.int32(1),
+                    *mid, np.int32(0), key)
+            step_args = (self._params_dev, self._cache, self._token,
+                         self._rngs, active, *tail)
+            mode = None
+            if self.paged and self.attn_kernel == "pallas":
+                from .._private.chip import compiled_by_mosaic
+
+                mode = "compiled" if compiled_by_mosaic(
+                    self._step.lower(*step_args).as_text()) \
+                    else "interpret"
+            _, self._cache, _, _ = timed("chunk", self._step, *step_args)
+            if self._verify is not None:
+                _, _, self._cache, _ = timed(
+                    "verify", self._verify, self._params_dev, self._cache,
+                    self._token,
+                    np.zeros((self.slots, self.draft_k), np.int32),
+                    self._rngs, active, *tail)
+            if self.role == "prefill":
+                timed("export", self._export, self._cache,
+                      none if self.paged else np.int32(0))
+            self._warm_report = {"programs": secs,
+                                 "total_s": round(sum(secs.values()), 3),
+                                 "attn_kernel_mode": mode}
+        except Exception as e:  # noqa: BLE001 - handed to warm_up()'s caller
+            req["error"] = e
+        finally:
+            self._warm = None
+            req["done"].set()
+
     def queue_depth(self) -> int:
         """Requests accepted but not yet admitted to a slot (submit
         queue + the driver's deferred FIFO). THE offline-pipeline
@@ -1297,9 +1385,10 @@ class DecodeEngine:
         ``check_health``; safe from any thread."""
         with self._supervise_lock:
             t = self._thread
-            if t is None or self._shutdown:
-                # Never started (auto_start=False) or deliberately shut
-                # down: not a health signal.
+            if t is None or self._shutdown or self._warm is not None:
+                # Never started (auto_start=False), deliberately shut
+                # down, or compiling its program set on request: not a
+                # health signal.
                 return True
             alive = t.is_alive()
             beat_age = time.monotonic() - self._beat
@@ -1456,6 +1545,7 @@ class DecodeEngine:
             "leases_reclaimed": ls["reclaimed"],
         }
         t = self._thread
+        out["warm_up"] = self._warm_report
         out["driver_alive"] = bool(t is not None and t.is_alive())
         out["heartbeat_age_s"] = round(time.monotonic() - self._beat, 3)
         out["draining"] = self._draining
@@ -1513,6 +1603,10 @@ class DecodeEngine:
                 # Heartbeat BEFORE any work: supervise() reads its age
                 # to tell a wedged dispatch from a live idle loop.
                 self._beat = time.monotonic()
+                warm = self._warm
+                if warm is not None:
+                    self._run_warm_up(warm)
+                    continue
                 self._check_fault()
                 if stop.is_set():
                     # Woke from a wedge (fault sleep / stuck dispatch)

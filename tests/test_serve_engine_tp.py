@@ -64,20 +64,25 @@ def _mk_prompt(rid: int, vocab: int, n: int = 7):
 
 
 # ------------------------------------------------------- token identity
-@pytest.mark.parametrize("paged,temperature",
-                         [(False, 0.0), (True, 0.0),
-                          (False, 1.0), (True, 1.0)])
-def test_tp2_token_identity(nano, nano_params, paged, temperature):
+@pytest.mark.parametrize("paged,temperature,attn_kernel",
+                         [(False, 0.0, "gather"), (True, 0.0, "gather"),
+                          (False, 1.0, "gather"), (True, 1.0, "gather"),
+                          (True, 0.0, "pallas")])
+def test_tp2_token_identity(nano, nano_params, paged, temperature,
+                            attn_kernel):
     """tp=2 output == tp=1 output, stream for stream, at temp 0 and
     seeded temp>0, flat and paged — concurrent mixed-length requests
-    through both pools."""
+    through both pools. The pallas row pins the kernel under the tp
+    shard_map (ISSUE 21: it did not trace there — pallas_call's output
+    has no vma annotation)."""
     prompts = [_mk_prompt(i, nano.vocab_size, n)
                for i, n in enumerate((5, 8, 11, 16))]
     max_news = [10, 7, 12, 3]
 
     def run(tp):
+        kw = {"attn_kernel": attn_kernel} if paged else {}
         eng = _make_engine(nano, nano_params, paged=paged, page_size=8,
-                           temperature=temperature, tp=tp)
+                           temperature=temperature, tp=tp, **kw)
         try:
             outs = {}
 
