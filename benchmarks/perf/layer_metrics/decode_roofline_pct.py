@@ -1,0 +1,45 @@
+"""Bandwidth roofline share of a decode step: the bytes a step needs
+(every weight once as held, and the keys and values of the live tokens:
+kernel_costs.decode_step_bytes) over the chip's peak bytes/s, over the
+step's device time. Live tokens are read off the client's stamps at the
+middle of the traced slice. Bound by bandwidth, not by compute.
+"""
+LAYER = "kernels"
+UNIT = "%"
+SOURCE = "device_trace"
+MOVES = "tpot_p90_ms"
+
+
+import kernel_costs
+from perf_harness import load_reader
+
+
+def live_tokens(run):
+    mid = run.get("trace_mid")
+    if mid is None:
+        return None
+    live = 0
+    for r in run["rows"]:
+        if not r["slices"] or r["slices"][0][0] > mid:
+            continue
+        if r.get("end") is not None and r["end"] <= mid:
+            continue
+        live += r["prompt_len"] + sum(n for t, n in r["slices"]
+                                      if t <= mid)
+    return live
+
+
+def read(run):
+    step_ms = load_reader("decode_step_dev_ms").read(run)
+    if not step_ms or not run.get("peaks"):
+        return None
+    live = live_tokens(run)
+    if live is None:
+        return None
+    wb = {"float32": 4, "bfloat16": 2}[
+        run["conf"]["numerics"]["param_dtype"]]
+    kb = {"fp": 2, "int8": 1}[run["conf"]["engine"]["kv_dtype"]]
+    need = kernel_costs.decode_step_bytes(run["conf"]["model"], wb, kb,
+                                          live)
+    least_s = need / run["peaks"]["hbm_bytes_per_s"]
+    return 100.0 * least_s / (step_ms / 1e3)

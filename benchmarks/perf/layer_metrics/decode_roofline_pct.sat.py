@@ -1,0 +1,10 @@
+"""decode_roofline_pct in the saturated cells.
+"""
+from perf_harness import twin
+
+LAYER = "kernels"
+UNIT = "%"
+SOURCE = "device_trace"
+MOVES = "out_tokens_per_s"
+
+read = twin("decode_roofline_pct")

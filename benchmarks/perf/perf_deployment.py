@@ -1,0 +1,322 @@
+"""The deployment class the serving cells run: an ordinary
+``@serve.deployment`` around a ``DecodeEngine`` behind
+``@serve.batch(continuous=True)``, as a user of the system writes one.
+
+It is the benchmark's, so that the one process which holds the chip can
+do for the benchmark what nothing else can: make the weights from the
+seed on the device, start and stop ``jax.profiler`` and reduce the
+trace, sample what its engine's driver thread is doing, compare the
+served arithmetic with the plain reference, and hand out the engine's
+counters. Everything it reads from the program is public
+(``engine.stats()``) except where a comment says otherwise.
+"""
+from __future__ import annotations
+
+import os
+import sys
+import threading
+import time
+
+
+def model_cfg(conf: dict):
+    """The program's ``GPTConfig`` at the sizes of a configuration file
+    (the one place that maps published names to the program's)."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+
+    m = conf["model"]
+    dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+    return gpt.GPTConfig(
+        vocab_size=m["embedding_rows_held"], n_layer=m["n_layer"],
+        n_head=m["n_head"], d_model=m["n_embd"], d_ff=m["n_inner"],
+        max_seq=m["n_positions"],
+        dtype=dtypes[conf["numerics"]["compute_dtype"]],
+        param_dtype=dtypes[conf["numerics"]["param_dtype"]],
+        remat=conf.get("train", {}).get("remat", "dots"),
+        loss_chunk=conf.get("train", {}).get("loss_chunk", 0))
+
+
+def seeded_params(cfg, seed: int, init: dict):
+    """Weights from the seed under ONE jit, on the device, in the type
+    the program holds them in. The tree (names, shapes, types) is the
+    program's own (``eval_shape`` of its ``init_params``); the values
+    are the GPT-2 initialisation the configuration file states (``init``:
+    the standard deviation by kind of leaf), drawn with the chip's
+    hardware generator (the ``rbg`` key type), which makes 1.3 G values
+    in a fraction of the time of the program's threefry-seeded init."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+
+    shapes = jax.eval_shape(lambda k: gpt.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    L, d = cfg.n_layer, cfg.d_model
+    named = {"resid": 1.0 / math.sqrt(2 * L * d)}
+
+    def std(path: str, shape) -> float:
+        for part, val in init["std"].items():
+            if part in path:
+                return named.get(val, val) if isinstance(val, str) \
+                    else float(val)
+        return 1.0 / math.sqrt(shape[-2])         # fan-in of a matrix
+
+    def make(key):
+        out = []
+        for i, (path, leaf) in enumerate(leaves):
+            name = jax.tree_util.keystr(path)
+            if "scale" in name:
+                out.append(jnp.ones(leaf.shape, leaf.dtype))
+                continue
+            k = jax.random.fold_in(key, i)
+            out.append((jax.random.normal(k, leaf.shape, jnp.float32)
+                        * std(name, leaf.shape)).astype(leaf.dtype))
+        return jax.tree_util.tree_unflatten(treedef, out)
+
+    key = jax.random.key(seed % (2 ** 31), impl="rbg")
+    return jax.block_until_ready(jax.jit(make)(key))
+
+
+def device_peak_bytes(stats: dict) -> int:
+    """Peak memory of one chip as the runtime reports it: the
+    allocator's ``peak_bytes_in_use`` (arrays: weights, cache, state)
+    plus, where the backend keeps one, the pool it reserves for compiled
+    programs' temporaries (``peak_bytes_reserved``), which the first
+    figure does not include."""
+    return int(stats.get("peak_bytes_in_use", 0)) \
+        + int(stats.get("peak_bytes_reserved", 0))
+
+
+class HostSampler:
+    """What a thread is doing, every ``period_s``: the innermost frame
+    inside ``anchor`` (a file name), as ``file.py:function``. Stamps are
+    ``time.monotonic_ns()``."""
+
+    def __init__(self, anchor: str, entry: str, period_s: float = 0.002):
+        self.anchor, self.entry, self.period_s = anchor, entry, period_s
+        self.samples = []
+        self._stop = threading.Event()
+        self._thread = None
+
+    def _label(self, frame):
+        inner, seen_entry = None, False
+        while frame is not None:
+            code = frame.f_code
+            if code.co_filename.endswith(self.anchor):
+                if inner is None:
+                    inner = code.co_name
+                if code.co_name == self.entry:
+                    seen_entry = True
+            frame = frame.f_back
+        if not seen_entry:
+            return None
+        name = inner.replace("<", "_").replace(">", "_")
+        return f"{os.path.basename(self.anchor)}:{name}"
+
+    def _run(self):
+        tid = None
+        while not self._stop.is_set():
+            frames = sys._current_frames()
+            now = time.monotonic_ns()
+            if tid is None or tid not in frames:
+                tid = next((t for t, f in frames.items()
+                            if t != threading.get_ident()
+                            and self._label(f)), None)
+            if tid is not None:
+                lab = self._label(frames[tid])
+                if lab:
+                    self.samples.append((now, lab))
+            del frames
+            time.sleep(self.period_s)
+
+    def start(self):
+        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread.start()
+
+    def stop(self):
+        self._stop.set()
+        if self._thread is not None:
+            self._thread.join(2.0)
+        return self.samples
+
+
+class Tracer:
+    """``jax.profiler`` around a window, in the process that holds the
+    chip, with the host sampler beside it and one marker event that
+    ties the host's clock to the trace's."""
+
+    def __init__(self, log_dir: str, anchor: str, entry: str):
+        self.log_dir, self.anchor, self.entry = log_dir, anchor, entry
+        self.sampler = None
+        self.sync_host_ns = None
+        self.t_start = self.t_stop = None
+
+    def start(self):
+        import jax
+
+        jax.profiler.start_trace(self.log_dir)
+        self.t_start = time.monotonic_ns()
+        with jax.profiler.TraceAnnotation("perfbench_sync"):
+            self.sync_host_ns = time.monotonic_ns()
+        self.sampler = HostSampler(self.anchor, self.entry)
+        self.sampler.start()
+
+    def stop(self):
+        """Stop tracing and sampling; the file is written, not read."""
+        import jax
+
+        self.samples = self.sampler.stop()
+        self.t_stop = time.monotonic_ns()
+        jax.profiler.stop_trace()
+
+    def result(self, describe: bool = False) -> dict:
+        """Reduce the trace (seconds of Python: call it once the
+        measured window is over)."""
+        import trace_reduce
+
+        samples = self.samples
+        path = trace_reduce.find_xplane(self.log_dir)
+        trace = trace_reduce.load_xplane(path)
+        sync = trace_reduce.sync_event_ns(trace)
+        offset = None if sync is None else sync - self.sync_host_ns
+        window = None
+        if offset is not None:
+            window = (self.t_start + offset, self.t_stop + offset)
+        red = trace_reduce.reduce(trace, window=window, samples=samples,
+                                  host_offset_ns=offset)
+        red["host_window_s"] = (self.t_stop - self.t_start) / 1e9
+        red["samples"] = len(samples)
+        red["xplane_bytes"] = os.path.getsize(path)
+        if describe:
+            red["describe"] = trace_reduce.describe(path)
+            if window is not None:
+                red["cut"] = trace_reduce.cut(
+                    trace, (window[0] + 10 ** 9,
+                            window[0] + 10 ** 9 + 7 * 10 ** 8),
+                    samples, offset)
+        return red
+
+
+def make_deployment(conf: dict, seed: int, require_tpu: bool,
+                    trace_dir: str):
+    """The deployment for one serving configuration file. Deployment
+    settings an operator sets come from ``conf["deployment"]``."""
+    from ray_tpu import serve
+
+    dep = conf["deployment"]
+    eng = conf["engine"]
+
+    @serve.deployment(
+        num_replicas=1,
+        max_ongoing_requests=dep["max_ongoing_requests"],
+        max_queued_requests=dep["max_queued_requests"],
+        health_check_period_s=dep["health_check_period_s"],
+        ray_actor_options={"num_tpus": 1 if require_tpu else 0})
+    class PerfGPT:
+        def __init__(self):
+            t0 = time.monotonic()
+            from ray_tpu._private import chip
+            from ray_tpu.serve.engine import DecodeEngine
+
+            self.device = chip.require_tpu() if require_tpu \
+                else chip.device_summary()
+            print(f"[pid {os.getpid()}] platform="
+                  f"{self.device['platform']} device_kind="
+                  f"{self.device['kind']!r} count={self.device['count']}",
+                  flush=True)
+            self.cfg = model_cfg(conf)
+            t1 = time.monotonic()
+            params = seeded_params(self.cfg, seed, conf["init"])
+            t2 = time.monotonic()
+            self.engine = DecodeEngine(
+                params, self.cfg, slots=eng["slots"], chunk=eng["chunk"],
+                max_len=eng["max_len"],
+                prompt_buckets=tuple(eng["prompt_buckets"]),
+                paged=True, page_size=eng["page_size"],
+                n_pages=eng["n_pages"], prefix_cache=eng["prefix_cache"],
+                attn_kernel=eng["attn_kernel"], kv_dtype=eng["kv_dtype"])
+            self.timing = {"import_s": t1 - t0, "weights_s": t2 - t1,
+                           "engine_s": time.monotonic() - t2}
+            self.arrivals = {}
+            self.tracer = None
+
+        @serve.batch(continuous=True)
+        def decode(self, request):
+            return self.engine, {"prompt": request["prompt"],
+                                 "max_new": request["max_new"]}
+
+        def __call__(self, request):
+            rec = [time.monotonic(), None]
+            self.arrivals[request["rid"]] = rec
+            return _Stamped(self.decode(request), rec)
+
+        def report(self) -> dict:
+            import jax
+
+            mem = [d.memory_stats() or {} for d in jax.devices()]
+            return {"device": self.device, "pid": os.getpid(),
+                    "timing": self.timing,
+                    "stats": self.engine.stats(),
+                    "t": time.monotonic(),
+                    "memory_peak_bytes": max(device_peak_bytes(m)
+                                             for m in mem),
+                    "memory_stats": {k: int(v) for k, v in mem[0].items()
+                                     if isinstance(v, (int, float))}}
+
+        def arrivals_log(self) -> dict:
+            """rid -> [arrival at the replica, first slice out of the
+            engine], monotonic seconds."""
+            return dict(self.arrivals)
+
+        def reference_check(self, n_prompt: int, n_steps: int,
+                            served=None) -> dict:
+            import perf_reference_check
+
+            return perf_reference_check.serve_check(
+                self.engine, self.cfg, conf, seed, n_prompt, n_steps,
+                served)
+
+        def trace_start(self) -> bool:
+            import program_names
+
+            self.tracer = Tracer(trace_dir, program_names.ENGINE_FILE,
+                                 program_names.DRIVER_ENTRY)
+            self.tracer.start()
+            return True
+
+        def trace_stop(self) -> bool:
+            self.tracer.stop()
+            return True
+
+        def trace_result(self, describe: bool = False) -> dict:
+            tracer, self.tracer = self.tracer, None
+            return tracer.result(describe)
+
+    return PerfGPT
+
+
+class _Stamped:
+    """The engine's stream, with the time its first slice left the
+    engine noted. Keeps the marker the replica reads off engine
+    streams."""
+
+    __rt_engine_stream__ = True
+
+    def __init__(self, inner, rec):
+        self._inner, self._rec = inner, rec
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        item = next(self._inner)
+        if self._rec[1] is None:
+            self._rec[1] = time.monotonic()
+        return item
+
+    def close(self):
+        self._inner.close()
