@@ -192,6 +192,7 @@ class HeadService:
         # spans processes dropped at buffer capacity before flushing.
         self.spans: deque = deque(maxlen=100_000)
         self.spans_dropped_total = 0
+        self.span_clocks: Dict[str, dict] = {}   # process -> clock pair
         self._shutting_down = False
         # Observability: per-process metric snapshots (worker_id → snap)
         # merged on demand; dashboard server started in start().
@@ -1656,6 +1657,11 @@ class HeadService:
         # the legacy shape from pre-upgrade workers.
         if isinstance(payload, dict):
             self.spans_dropped_total += int(payload.get("dropped", 0))
+            clock = payload.get("clock")
+            if clock:
+                # newest (wall, monotonic) pair of that process: places
+                # its mono_ns spans on the timeline's axis
+                self.span_clocks[clock["process"]] = clock
             payload = payload.get("spans", [])
         if self.spans.maxlen:
             # The bounded deque evicts silently on extend; those drops
@@ -2190,10 +2196,18 @@ class HeadService:
         # Tracing spans render on per-trace rows so one request's
         # submit → execute chain reads left-to-right on one line.
         for sp in list(self.spans):
+            start, end = sp["start"], sp["end"]
+            clock = self.span_clocks.get(sp.get("process"))
+            if clock and sp.get("mono_ns"):
+                # measured on the monotonic clock: placed through the
+                # process's newest clock pair, so a wall clock that
+                # stepped since does not tear one process's spans apart
+                start, end = (clock["wall"] + (t - clock["mono_ns"]) / 1e9
+                              for t in sp["mono_ns"])
             out.append({
                 "name": sp["name"], "cat": f"span:{sp['kind']}", "ph": "X",
-                "ts": int(sp["start"] * 1e6),
-                "dur": max(1, int((sp["end"] - sp["start"]) * 1e6)),
+                "ts": int(start * 1e6),
+                "dur": max(1, int((end - start) * 1e6)),
                 "pid": "trace",
                 "tid": sp["trace_id"][:12],
                 "args": {"span_id": sp["span_id"],

@@ -505,19 +505,11 @@ def serve_metrics() -> dict:
             engine_pages_free=Gauge(
                 "serve_engine_pages_free",
                 "KV pages on the paged engine's free list"),
-            engine_kv_bytes_per_token=Gauge(
-                "serve_engine_kv_bytes_per_token",
-                "HBM bytes one KV-cache position costs under the "
-                "engine's configured kv_dtype (int8 pages carry codes "
-                "plus amortized per-page scales)"),
             engine_attn_kernel_dispatches=Counter(
                 "serve_engine_attn_kernel_dispatches_total",
                 "Fused decode dispatches that ran the paged-attention "
                 "kernel path (attn_kernel=pallas) instead of the XLA "
                 "gather reference"),
-            engine_pages_used=Gauge(
-                "serve_engine_pages_used",
-                "KV pages held by live lanes or the prefix cache"),
             engine_prefix_hits=Counter(
                 "serve_engine_prefix_hits_total",
                 "Admissions that mapped a cached prompt prefix instead "

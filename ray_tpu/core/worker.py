@@ -3505,8 +3505,12 @@ class CoreWorker:
             for s in spans:
                 s.setdefault("process", me)
             try:
+                # with a fresh clock pair: the head lays spans that
+                # carry mono_ns on one axis through it
                 self.head_call("report_spans",
-                               {"spans": spans, "dropped": dropped})
+                               {"spans": spans, "dropped": dropped,
+                                "clock": dict(tracing.clock_pair(),
+                                              process=me)})
             except Exception:
                 # Head unreachable (e.g. crash-restart window): put the
                 # spans back for the next flush — traces covering a

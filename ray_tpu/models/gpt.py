@@ -531,12 +531,12 @@ def make_train_step(cfg: GPTConfig, mesh, optimizer=None, *,
         pp_mode = cfg.pp_axis and cfg.pp_axis in mesh.axis_names
         rules = shr.PP_LM_RULES if pp_mode else shr.LM_RULES
 
-    def init(rng):
+    def train_init(rng):       # the XLA module: jit_train_init
         params = init_params(rng, cfg)
         opt_state = optimizer.init(params)
         return {"params": params, "opt": opt_state, "step": jnp.zeros((), jnp.int32)}
 
-    abstract = jax.eval_shape(init, jax.random.PRNGKey(0))
+    abstract = jax.eval_shape(train_init, jax.random.PRNGKey(0))
     param_sh = shr.tree_shardings(abstract["params"], mesh, rules)
 
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -554,9 +554,9 @@ def make_train_step(cfg: GPTConfig, mesh, optimizer=None, *,
     # propagates that sharding through the surrounding ops.
     batch_sh = shr.batch_sharding(mesh)
 
-    init_jit = jax.jit(init, out_shardings=state_sh)
+    init_jit = jax.jit(train_init, out_shardings=state_sh)
 
-    def step(state, batch):
+    def train_step(state, batch):      # the XLA module: jit_train_step
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(state["params"], batch, cfg, mesh)
         updates, new_opt = optimizer.update(grads, state["opt"],
@@ -566,7 +566,7 @@ def make_train_step(cfg: GPTConfig, mesh, optimizer=None, *,
                  "step": state["step"] + 1}, metrics)
 
     step_jit = jax.jit(
-        step,
+        train_step,
         in_shardings=(state_sh, batch_sh),
         out_shardings=(state_sh, None),
         donate_argnums=(0,) if donate else (),

@@ -178,6 +178,16 @@ from .request import (RequestDeadlineExceeded, deadline_expired,
                       get_request_id)
 
 
+#: The driver loop's phases (``stats()["driver_ns_<phase>"]``): blocked
+#: on the empty queue; admission (prefix lookup, page allocation and
+#: eviction, lease sweep); prefill (dispatch to the first token's read,
+#: every flavour); page coverage (park / preempt); decode (chunk or
+#: verify dispatch to the tokens' read); delivery (per-slot routing, EOS
+#: trimming, frees); and the rest of the loop.
+_PHASES = ("idle", "admit", "prefill", "cover", "decode", "deliver",
+           "other")
+
+
 def default_prompt_buckets(max_len: int) -> List[int]:
     """Powers of two from 8 up to (and including) max_len."""
     return sorted(b for b in default_buckets(max_len) if b >= 8) \
@@ -209,7 +219,14 @@ class _EngineRequest:
     deadline_s: Optional[float]
     trace_ctx: Optional[dict]
     seed: int
-    enq_t: float
+    #: ``time.monotonic_ns()`` when the request was queued (reset when
+    #: a preemption requeues it), when a slot and its pages were
+    #: granted, and when the first token reached the host. The engine
+    #: sums their differences into ``stats()``; a traced request gets
+    #: them as the ``engine.admission`` and ``engine.prefill`` spans.
+    enq_ns: int
+    granted_ns: int = 0
+    first_ns: int = 0
     #: Tokens already delivered before a recompute preemption: the
     #: replay regenerates them (identical — the per-request PRNG lane
     #: is deterministic) and suppresses this many from the stream.
@@ -570,7 +587,25 @@ class DecodeEngine:
                        "handoffs_exported": 0, "handoffs_imported": 0,
                        "handoff_import_fallbacks": 0,
                        "handoff_ship_bytes": 0,
-                       "attn_kernel_dispatches": 0}
+                       "attn_kernel_dispatches": 0,
+                       # request lifecycle, summed where it happens
+                       # (monotonic ns): queued -> slot granted over
+                       # `admitted`; slot granted -> first token on the
+                       # host and the suffix tokens prefilled over
+                       # `prefills`; and, before each decode/verify
+                       # dispatch, the time since the previous one's
+                       # tokens were read while a lane stayed occupied
+                       "admission_wait_ns_sum": 0, "prefill_ns_sum": 0,
+                       "prefill_tokens_sum": 0, "decode_gap_ns_sum": 0}
+        # What the driver thread is doing, by phase (self time, ns):
+        # written by the driver alone through its PhaseClock, read
+        # racily by stats(). Outlives driver restarts.
+        self._driver_ns = dict.fromkeys(_PHASES + ("total",), 0)
+        self._phases: Optional[tracing.PhaseClock] = None
+        # monotonic ns at which the last decode/verify dispatch's tokens
+        # were read, while a lane has stayed occupied since; else None
+        self._decode_read_ns: Optional[int] = None
+        self._compiles = tracing.compile_counts()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # ---- driver supervision (ISSUE 7): the driver stamps _beat at
@@ -1006,7 +1041,7 @@ class DecodeEngine:
             self._queue.put(_EngineRequest(
                 prompt=prompt, bucket=bucket, max_new=int(max_new),
                 lane=lane, deadline_s=deadline_s, trace_ctx=trace_ctx,
-                seed=int(seed), enq_t=time.time(), skip=resume_from,
+                seed=int(seed), enq_ns=time.monotonic_ns(), skip=resume_from,
                 req_id=req_id))
         if resume_from:
             self._count(resumed=1)
@@ -1059,7 +1094,7 @@ class DecodeEngine:
             self._queue.put(_EngineRequest(
                 prompt=prompt, bucket=bucket, max_new=int(max_new),
                 lane=lane, deadline_s=deadline_s, trace_ctx=trace_ctx,
-                seed=int(seed), enq_t=time.time(), export=True,
+                seed=int(seed), enq_ns=time.monotonic_ns(), export=True,
                 ttl_s=float(ttl_s or 0.0), req_id=req_id))
         # Synchronous drain: ONE item (the descriptor), then END. The
         # wait is deadline-bounded so a wedged driver surfaces as the
@@ -1207,7 +1242,7 @@ class DecodeEngine:
             self._queue.put(_EngineRequest(
                 prompt=prompt, bucket=bucket, max_new=max_new,
                 lane=lane, deadline_s=deadline_s, trace_ctx=trace_ctx,
-                seed=seed, enq_t=time.time(), skip=resume_from,
+                seed=seed, enq_ns=time.monotonic_ns(), skip=resume_from,
                 handoff={"payload": payload,
                          "created_t": desc.get("created_t")},
                 req_id=req_id))
@@ -1508,6 +1543,10 @@ class DecodeEngine:
         out["avg_occupancy"] = out.pop("occupancy_sum") / d
         out["dispatches_per_token"] = (
             (out["dispatches"] + out["prefills"]) / max(out["tokens"], 1))
+        out.update({f"driver_ns_{ph}": ns
+                    for ph, ns in self._driver_ns.items()})
+        out["compiles"] = self._compiles["n"]
+        out["compile_ns"] = self._compiles["ns"]
         out["paged"] = self.paged
         out["deployment"] = self.deployment
         out["tp"] = self.tp
@@ -1598,43 +1637,62 @@ class DecodeEngine:
     # first loop).
     # rtlint: owner=driver entry=driver
     def _run(self, stop: threading.Event, epoch: int):
+        # One phase clock per driver run (its spans share a trace id);
+        # the table it adds to is the engine's.
+        self._phases = tracing.PhaseClock(
+            "engine", self._driver_ns, epoch=epoch,
+            deployment=self.deployment)
+        self._decode_read_ns = None
         try:
             while not stop.is_set():
-                # Heartbeat BEFORE any work: supervise() reads its age
-                # to tell a wedged dispatch from a live idle loop.
-                self._beat = time.monotonic()
-                warm = self._warm
-                if warm is not None:
-                    self._run_warm_up(warm)
-                    continue
-                self._check_fault()
-                if stop.is_set():
-                    # Woke from a wedge (fault sleep / stuck dispatch)
-                    # to find the supervisor restarted past this run:
-                    # exit before touching the rebuilt structures.
-                    break
-                self._admit_pending(epoch)
-                self._observe_queue_depth()
-                self._sweep_leases()
-                if not any(s is not None for s in self._state):
-                    if self._pending:
-                        # Deferred head with an empty pool and ZERO
-                        # running lanes cannot happen (n_pages holds a
-                        # full max_len sequence and the prefix cache
-                        # evicts first) — but never busy-spin on it.
-                        time.sleep(0.001)
+                # The whole iteration is phase "other"; what it opens
+                # inside is charged to its own phase. The body stays in
+                # THIS frame: profilers label the driver by function.
+                with self._phases.phase("other") as loop:
+                    # Heartbeat BEFORE any work: supervise() reads its
+                    # age to tell a wedged dispatch from a live idle
+                    # loop.
+                    self._beat = time.monotonic()
+                    warm = self._warm
+                    if warm is not None:
+                        self._run_warm_up(warm)
                         continue
-                    # Idle: block briefly for the next arrival instead
-                    # of spinning; the timeout bounds shutdown latency.
-                    try:
-                        self._pending.append(self._queue.get(timeout=0.05))
-                    except queue.Empty:
-                        continue
-                    continue  # boundary: admission pass first
-                if self._drafter is not None:
-                    self._dispatch_spec(epoch)
-                else:
-                    self._dispatch_chunk(epoch)
+                    self._check_fault()
+                    if stop.is_set():
+                        # Woke from a wedge (fault sleep / stuck
+                        # dispatch) to find the supervisor restarted
+                        # past this run: exit before touching the
+                        # rebuilt structures.
+                        break
+                    with self._phases.phase("admit"):
+                        self._admit_pending(epoch)
+                        self._sweep_leases()
+                    self._observe_queue_depth()
+                    if not any(s is not None for s in self._state):
+                        if self._pending:
+                            # Deferred head with an empty pool and ZERO
+                            # running lanes cannot happen (n_pages holds
+                            # a full max_len sequence and the prefix
+                            # cache evicts first) — but never busy-spin
+                            # on it.
+                            time.sleep(0.001)
+                            continue
+                        # Idle: block briefly for the next arrival
+                        # instead of spinning; the timeout bounds
+                        # shutdown latency.
+                        with self._phases.phase("idle"):
+                            try:
+                                self._pending.append(
+                                    self._queue.get(timeout=0.05))
+                            except queue.Empty:
+                                # nothing to do, twenty times a second:
+                                # counted, not recorded as spans
+                                loop.muted = True
+                        continue  # boundary: admission pass first
+                    if self._drafter is not None:
+                        self._dispatch_spec(epoch)
+                    else:
+                        self._dispatch_chunk(epoch)
             self._fail_all(EngineShutdownError("engine shut down"),
                            epoch=epoch)
         except BaseException as e:  # noqa: BLE001 - driver died: fan out
@@ -1738,14 +1796,8 @@ class DecodeEngine:
         if sm is None:
             from .._private.metrics import serve_metrics
             sm = serve_metrics()
-        free = self._pool.available()
-        labels = {"deployment": self.deployment}
-        sm["engine_pages_free"].set(free, labels=labels)
-        sm["engine_pages_used"].set(self.n_pages - free, labels=labels)
-        sm["engine_kv_bytes_per_token"].set(
-            self._gd.kv_bytes_per_page(self.cfg, self.page_size,
-                                       self.kv_dtype) / self.page_size,
-            labels=labels)
+        sm["engine_pages_free"].set(
+            self._pool.available(), labels={"deployment": self.deployment})
 
     def _sweep_leases(self):  # rtlint: owner=driver
         """Reclaim expired handoff leases once per driver loop
@@ -1857,17 +1909,20 @@ class DecodeEngine:
             admitted = self._prefill_paged(req, slot, P, sm, jax, epoch)
             if admitted is None:
                 return False
-            first, pages, t_admit = admitted
+            first, pages, hist, bucket = admitted
         else:
-            t_admit = time.time()
-            padded = np.zeros((1, req.bucket), np.int32)
-            padded[0, :P] = req.prompt
-            tok, cache, key = self._prefill(
-                self._params_dev, self._cache, padded, np.int32(P),
-                np.int32(slot), jax.random.PRNGKey(req.seed))
-            # One transfer per admission — THE TTFT point.
-            # rtlint: sync-ok=ttft first token streams from the host
-            first = int(np.asarray(tok))
+            hist, bucket = 0, req.bucket
+            with self._phases.phase("prefill", bucket=bucket,
+                                    hist_len=0) as ph:
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :P] = req.prompt
+                tok, cache, key = self._prefill(
+                    self._params_dev, self._cache, padded, np.int32(P),
+                    np.int32(slot), jax.random.PRNGKey(req.seed))
+                # One transfer per admission — THE TTFT point.
+                # rtlint: sync-ok=ttft first token streams from the host
+                first = int(np.asarray(tok))
+            req.granted_ns, req.first_ns = ph.t0, ph.t1
             if epoch >= 0 and epoch != self._epoch:
                 return True          # stale driver: drop on the floor
             self._cache = cache
@@ -1875,20 +1930,43 @@ class DecodeEngine:
             # rtlint: sync-ok=prng-mirror re-uploaded per dispatch
             self._rngs[slot] = np.asarray(key)
             pages = []
-        sm["engine_admission_wait"].observe(
-            max(t_admit - req.enq_t, 0.0),
-            labels={"deployment": self.deployment})
+        fresh = req.skip == 0 and not req.export
+        self._note_admission(req, slot, sm, fresh)
         if req.trace_ctx is not None:
-            tracing.record_span("engine.admission", req.enq_t, t_admit,
+            tracing.record_span("engine.prefill",
+                                mono_ns=(req.granted_ns, req.first_ns),
                                 parent_ctx=req.trace_ctx, slot=slot,
+                                bucket=bucket, hist_len=hist,
+                                pages=len(pages),
                                 deployment=self.deployment)
-        self._count(prefills=1,
-                    admitted=1 if (req.skip == 0 and not req.export)
-                    else 0)
+        self._count(prefills=1, admitted=1 if fresh else 0,
+                    prefill_ns_sum=req.first_ns - req.granted_ns,
+                    prefill_tokens_sum=P - hist)
         self._token[slot] = first
         if req.export:
-            return self._finish_export(req, slot, P, pages, first, sm)
+            with self._phases.phase("prefill", export=True):
+                return self._finish_export(req, slot, P, pages, first,
+                                           sm)
         return self._enter_steady_state(req, slot, first, P, pages, sm)
+
+    # rtlint: owner=driver
+    def _note_admission(self, req: _EngineRequest, slot: int, sm,
+                        fresh: bool, **attrs):
+        """A slot (and its pages) was granted at ``req.granted_ns``:
+        the wait since the request was queued goes to the histogram, to
+        ``admission_wait_ns_sum`` (for requests that count as
+        ``admitted``: a replay's requeue is not a client's wait) and,
+        for a traced request, to its ``engine.admission`` span."""
+        wait_ns = max(req.granted_ns - req.enq_ns, 0)
+        sm["engine_admission_wait"].observe(
+            wait_ns / 1e9, labels={"deployment": self.deployment})
+        if fresh:
+            self._count(admission_wait_ns_sum=wait_ns)
+        if req.trace_ctx is not None:
+            tracing.record_span("engine.admission",
+                                mono_ns=(req.enq_ns, req.granted_ns),
+                                parent_ctx=req.trace_ctx, slot=slot,
+                                deployment=self.deployment, **attrs)
 
     # rtlint: owner=driver
     def _enter_steady_state(self, req: _EngineRequest, slot: int,
@@ -1936,7 +2014,7 @@ class DecodeEngine:
     # rtlint: owner=driver
     def _prefill_paged(self, req: _EngineRequest, slot: int, P: int,
                        sm, jax, epoch: int = -1
-                       ) -> Optional[Tuple[int, List[int], float]]:
+                       ) -> Optional[Tuple[int, List[int], int, int]]:
         """Paged admission: map the cached prefix (refcounted, COW fork
         if it ends mid-page), allocate fresh pages for the suffix,
         prefill ONLY the suffix, then register the prompt's pages in the
@@ -1953,7 +2031,8 @@ class DecodeEngine:
         prefix = self._prefix
         hist, shared_pages = (0, [])
         if prefix is not None:
-            hist, shared_pages = prefix.lookup(req.prompt)
+            with self._phases.step("lookup"):
+                hist, shared_pages = prefix.lookup(req.prompt)
         shared_full = hist // ps
         partial = hist % ps
         cow_src = shared_pages[shared_full] if partial else \
@@ -1965,29 +2044,43 @@ class DecodeEngine:
         if partial:
             pool.ref([cow_src])
         n_fresh = -(-P // ps) - shared_full
-        fresh = self._alloc_pages(n_fresh, pool, prefix)
+        evicted0 = prefix.evictions if prefix is not None else 0
+        with self._phases.step("alloc", pages=n_fresh):
+            fresh = self._alloc_pages(n_fresh, pool, prefix)
         if fresh is None:
             pool.unref(shared)
             if partial:
                 pool.unref([cow_src])
             return None
         pages = shared + fresh
-        t_admit = time.time()
         suffix = req.prompt[hist:]
         sl = P - hist
         bucket = next(b for b in self.prompt_buckets if b >= sl)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :sl] = suffix
-        pt_row = np.full((self.max_pages,), gd.PT_SENTINEL, np.int32)
-        pt_row[:len(pages)] = pages
-        self._pt[slot] = pt_row
-        tok, cache, key = self._prefill(
-            self._params_dev, self._cache, padded, np.int32(sl),
-            np.int32(hist), pt_row, np.int32(cow_src), np.int32(slot),
-            jax.random.PRNGKey(req.seed))
-        # One transfer per admission — THE TTFT point.
-        # rtlint: sync-ok=ttft first token streams from the host
-        first = int(np.asarray(tok))
+        clock = self._phases
+        with clock.phase(
+                "prefill", bucket=bucket, hist_len=hist,
+                pages_evicted=(prefix.evictions - evicted0)
+                if prefix is not None else 0) as ph:
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :sl] = suffix
+            pt_row = np.full((self.max_pages,), gd.PT_SENTINEL, np.int32)
+            pt_row[:len(pages)] = pages
+            self._pt[slot] = pt_row
+            with clock.step("key"):
+                rng = jax.random.PRNGKey(req.seed)
+            with clock.step("dispatch"):
+                tok, cache, key = self._prefill(
+                    self._params_dev, self._cache, padded, np.int32(sl),
+                    np.int32(hist), pt_row, np.int32(cow_src),
+                    np.int32(slot), rng)
+            # One transfer per admission — THE TTFT point. The read
+            # stays in this frame (and the step annotation is no
+            # function of this file): profilers that label the driver
+            # by function see the wait here.
+            with clock.step("read"):
+                # rtlint: sync-ok=ttft first token streams from the host
+                first = int(np.asarray(tok))
+        req.granted_ns, req.first_ns = ph.t0, ph.t1
         if epoch >= 0 and epoch != self._epoch:
             # Stale driver: drop the result AND hand back every page
             # this admission took — against the SAME pool snapshot, so
@@ -2014,8 +2107,9 @@ class DecodeEngine:
             sm["engine_prefix_hits"].inc(
                 labels={"deployment": self.deployment})
         if prefix is not None:
-            prefix.insert(req.prompt, pages)
-        return first, pages, t_admit
+            with clock.step("register"):
+                prefix.insert(req.prompt, pages)
+        return first, pages, hist, bucket
 
     # rtlint: owner=driver
     def _finish_export(self, req: _EngineRequest, slot: int, P: int,
@@ -2112,7 +2206,7 @@ class DecodeEngine:
         L = self.cfg.n_layer
         H, hd = self.cfg.n_head, self.cfg.head_dim
         dt = payload["k"].dtype
-        t_admit = time.time()
+        req.granted_ns = time.monotonic_ns()
         if self.paged:
             ps = self.page_size
             # ONE pool snapshot for the whole admission (see
@@ -2174,9 +2268,7 @@ class DecodeEngine:
         first = int(payload["first"])
         self._token[slot] = first
         self._rngs[slot] = np.asarray(payload["rng"], np.uint32)
-        sm["engine_admission_wait"].observe(
-            max(t_admit - req.enq_t, 0.0),
-            labels={"deployment": self.deployment})
+        self._note_admission(req, slot, sm, req.skip == 0, imported=True)
         created = req.handoff.get("created_t")
         if created:
             # Export stamp -> successful import: THE handoff latency.
@@ -2185,11 +2277,6 @@ class DecodeEngine:
             sm["kv_handoff"].observe(
                 max(time.time() - float(created), 0.0),
                 labels={"deployment": self.deployment})
-        if req.trace_ctx is not None:
-            tracing.record_span("engine.admission", req.enq_t, t_admit,
-                                parent_ctx=req.trace_ctx, slot=slot,
-                                imported=True,
-                                deployment=self.deployment)
         self._count(handoffs_imported=1,
                     admitted=1 if req.skip == 0 else 0)
         _driver_emit("engine.import", request=req.req_id, slot=slot,
@@ -2278,7 +2365,7 @@ class DecodeEngine:
         st = self._state[youngest]
         req = st.req
         req.skip = st.emitted
-        req.enq_t = time.time()
+        req.enq_ns = time.monotonic_ns()
         self._free_slot(youngest)
         self._pending.appendleft(req)
         self._count(preempted=1)
@@ -2307,115 +2394,132 @@ class DecodeEngine:
             # it against the NEW driver's pool would preempt a healthy
             # restarted lane.
             return
-        if cover and self.paged and not self._cover_pages():
-            return                    # re-run admission/coverage pass
+        if cover and self.paged:
+            with self._phases.phase("cover"):
+                runnable = self._cover_pages()
+            if not runnable:
+                return                # re-run admission/coverage pass
         active = np.array([s is not None and not s.parked
                            for s in self._state], bool)
         n_active = int(active.sum())
-        t0 = time.time()
-        if self.paged:
-            toks, cache, _done, rngs = self._step(
-                self._params_dev, self._cache, self._token, self._rngs,
-                active, self._pt)
-        else:
-            toks, cache, _done, rngs = self._step(
-                self._params_dev, self._cache, self._token, self._rngs,
-                active)
-        # ONE transfer per fused k-step chunk — the engine's designed
-        # streaming granularity.
-        # rtlint: sync-ok=chunk-boundary one transfer per chunk
-        toks_np = np.asarray(toks)
-        # rtlint: sync-ok=chunk-boundary PRNG lanes ride the same sync
-        rngs_np = np.asarray(rngs)
-        t1 = time.time()
+        with self._phases.phase("decode", slots_active=n_active) as ph:
+            self._note_decode_gap(ph.t0)
+            if self.paged:
+                toks, cache, _done, rngs = self._step(
+                    self._params_dev, self._cache, self._token,
+                    self._rngs, active, self._pt)
+            else:
+                toks, cache, _done, rngs = self._step(
+                    self._params_dev, self._cache, self._token,
+                    self._rngs, active)
+            # ONE transfer per fused k-step chunk — the engine's
+            # designed streaming granularity.
+            # rtlint: sync-ok=chunk-boundary one transfer per chunk
+            toks_np = np.asarray(toks)
+            # rtlint: sync-ok=chunk-boundary PRNG lanes ride the same sync
+            rngs_np = np.asarray(rngs)
         if epoch >= 0 and epoch != self._epoch:
             return                    # stale driver: drop on the floor
-        self._cache = cache
-        sm = serve_metrics()
-        sm["engine_slot_occupancy"].observe(
-            n_active / self.slots, labels={"deployment": self.deployment})
-        sm["engine_dispatches"].inc(
-            labels={"deployment": self.deployment})
-        self._count(dispatches=1, occupancy_sum=n_active / self.slots)
-        # Rate-capped: under a dispatch-per-token storm the cap drops
-        # the excess (counted) instead of flooding the ring.
-        _driver_emit("engine.dispatch", epoch=self._epoch,
-                     active=n_active, chunk=self.chunk,
-                     dispatch_s=round(t1 - t0, 6))
-        if self.tp > 1:
-            # Post-mortem breadcrumb for sharded dispatch: which mesh
-            # shape ran which compiled program. Same rate cap as
-            # engine.dispatch — one pair per chunk boundary.
-            _driver_emit("shard.dispatch", epoch=self._epoch,
-                         mesh=[("tp", self.tp)],
-                         program="chunk_paged" if self.paged
-                         else "chunk")
-        if self.paged and self.attn_kernel == "pallas":
-            # One fused-kernel dispatch per chunk program launch (the
-            # kernel runs k times per layer inside it).
-            sm["engine_attn_kernel_dispatches"].inc(
+        with self._phases.phase("deliver", slots_active=n_active):
+            self._cache = cache
+            sm = serve_metrics()
+            sm["engine_slot_occupancy"].observe(
+                n_active / self.slots,
                 labels={"deployment": self.deployment})
-            self._count(attn_kernel_dispatches=1)
-        with self._stats_lock:
-            self._stats["peak_active"] = max(self._stats["peak_active"],
-                                             n_active)
-        emitted = 0
-        for i, st in enumerate(self._state):
-            if st is None or st.parked:
-                continue                     # parked: nothing advanced
-            self._token[i] = toks_np[i, -1]
-            self._rngs[i] = rngs_np[i]
-            st.pos += self.chunk             # mirrors the device pos
-            if st.lane.closed:               # consumer left: free now
-                self._free_slot(i)
-                self._count(abandoned=1)
-                continue
-            if deadline_expired(st.deadline_s):
-                st.lane.q.put(("err", RequestDeadlineExceeded(
-                    "request deadline passed mid-generation")))
-                self._free_slot(i)
-                self._count(expired=1)
-                sm["requests_expired"].inc(
-                    labels={"where": "engine",
-                            "deployment": self.deployment})
-                continue
-            row = toks_np[i]
-            j = min(self.chunk, st.remaining)
-            finished = st.remaining <= self.chunk
-            if self.eos_token >= 0:
-                hits = np.flatnonzero(row[:j] == self.eos_token)
-                if hits.size:                # free at the EOS, not the
-                    j = int(hits[0]) + 1     # end of the gang batch
-                    finished = True
-            if st.trace_ctx is not None:
-                tracing.record_span("decode.chunk", t0, t1,
-                                    parent_ctx=st.trace_ctx, slot=i,
-                                    active_slots=n_active, tokens=j,
-                                    deployment=self.deployment)
-            # Recompute replay: the first ``skip`` regenerated tokens
-            # were already delivered before the preemption — suppress
-            # them, stream only the new tail.
-            cut = min(st.skip, j)
-            st.skip -= cut
-            if j > cut:
-                st.lane.q.put(("item", row[cut:j].copy()))
-                st.emitted += j - cut
-                emitted += j - cut
-            st.remaining -= j
-            if finished:
-                st.lane.q.put((_STREAM_END, None))
-                self._free_slot(i)
-                self._count(completed=1)
-            elif self._drafter is not None:
-                # Adaptive fallback round: keep the drafter's history
-                # (and its self-assessment) current; -1 marks "nothing
-                # was proposed this round".
-                self._drafter.observe(i, row[:j], -1)
-        if emitted:
-            sm["engine_tokens"].inc(
-                emitted, labels={"deployment": self.deployment})
-            self._count(tokens=emitted)
-        self._observe_pages(sm)
+            sm["engine_dispatches"].inc(
+                labels={"deployment": self.deployment})
+            self._count(dispatches=1, occupancy_sum=n_active / self.slots)
+            # Rate-capped: under a dispatch-per-token storm the cap drops
+            # the excess (counted) instead of flooding the ring.
+            _driver_emit("engine.dispatch", epoch=self._epoch,
+                         active=n_active, chunk=self.chunk,
+                         dispatch_s=round((ph.t1 - ph.t0) / 1e9, 6))
+            if self.tp > 1:
+                # Post-mortem breadcrumb for sharded dispatch: which mesh
+                # shape ran which compiled program. Same rate cap as
+                # engine.dispatch — one pair per chunk boundary.
+                _driver_emit("shard.dispatch", epoch=self._epoch,
+                             mesh=[("tp", self.tp)],
+                             program="chunk_paged" if self.paged
+                             else "chunk")
+            if self.paged and self.attn_kernel == "pallas":
+                # One fused-kernel dispatch per chunk program launch (the
+                # kernel runs k times per layer inside it).
+                sm["engine_attn_kernel_dispatches"].inc(
+                    labels={"deployment": self.deployment})
+                self._count(attn_kernel_dispatches=1)
+            with self._stats_lock:
+                self._stats["peak_active"] = max(self._stats["peak_active"],
+                                                 n_active)
+            emitted = 0
+            for i, st in enumerate(self._state):
+                if st is None or st.parked:
+                    continue                     # parked: nothing advanced
+                self._token[i] = toks_np[i, -1]
+                self._rngs[i] = rngs_np[i]
+                st.pos += self.chunk             # mirrors the device pos
+                if st.lane.closed:               # consumer left: free now
+                    self._free_slot(i)
+                    self._count(abandoned=1)
+                    continue
+                if deadline_expired(st.deadline_s):
+                    st.lane.q.put(("err", RequestDeadlineExceeded(
+                        "request deadline passed mid-generation")))
+                    self._free_slot(i)
+                    self._count(expired=1)
+                    sm["requests_expired"].inc(
+                        labels={"where": "engine",
+                                "deployment": self.deployment})
+                    continue
+                row = toks_np[i]
+                j = min(self.chunk, st.remaining)
+                finished = st.remaining <= self.chunk
+                if self.eos_token >= 0:
+                    hits = np.flatnonzero(row[:j] == self.eos_token)
+                    if hits.size:                # free at the EOS, not the
+                        j = int(hits[0]) + 1     # end of the gang batch
+                        finished = True
+                if st.trace_ctx is not None:
+                    tracing.record_span("decode.chunk",
+                                        mono_ns=(ph.t0, ph.t1),
+                                        parent_ctx=st.trace_ctx, slot=i,
+                                        active_slots=n_active, tokens=j,
+                                        deployment=self.deployment)
+                # Recompute replay: the first ``skip`` regenerated tokens
+                # were already delivered before the preemption — suppress
+                # them, stream only the new tail.
+                cut = min(st.skip, j)
+                st.skip -= cut
+                if j > cut:
+                    st.lane.q.put(("item", row[cut:j].copy()))
+                    st.emitted += j - cut
+                    emitted += j - cut
+                st.remaining -= j
+                if finished:
+                    st.lane.q.put((_STREAM_END, None))
+                    self._free_slot(i)
+                    self._count(completed=1)
+                elif self._drafter is not None:
+                    # Adaptive fallback round: keep the drafter's history
+                    # (and its self-assessment) current; -1 marks "nothing
+                    # was proposed this round".
+                    self._drafter.observe(i, row[:j], -1)
+            if emitted:
+                sm["engine_tokens"].inc(
+                    emitted, labels={"deployment": self.deployment})
+                self._count(tokens=emitted)
+            self._observe_pages(sm)
+            self._decode_read_ns = ph.t1 if any(
+                s is not None for s in self._state) else None
+
+    # rtlint: owner=driver
+    def _note_decode_gap(self, now_ns: int):
+        """At the start of a decode/verify dispatch: the time since the
+        previous one's tokens were read, if a lane stayed occupied all
+        the while — what running lanes lost to whatever came between
+        (delivery, admission, another request's prefill)."""
+        if self._decode_read_ns is not None:
+            self._count(decode_gap_ns_sum=now_ns - self._decode_read_ns)
 
     def _dispatch_spec(self, epoch: int = -1):  # rtlint: owner=driver
         """Draft-k-verify-once twin of :meth:`_dispatch_chunk`
@@ -2442,8 +2546,11 @@ class DecodeEngine:
 
         if epoch >= 0 and epoch != self._epoch:
             return
-        if self.paged and not self._cover_pages():
-            return                    # re-run admission/coverage pass
+        if self.paged:
+            with self._phases.phase("cover"):
+                runnable = self._cover_pages()
+            if not runnable:
+                return                # re-run admission/coverage pass
         active = np.array([s is not None and not s.parked
                            for s in self._state], bool)
         n_active = int(active.sum())
@@ -2462,104 +2569,109 @@ class DecodeEngine:
                 self._dispatch_chunk(epoch, cover=False)
                 return
         draft = self._drafter.propose(active, self._token)
-        t0 = time.time()
-        if self.paged:
-            committed, n_acc, cache, rngs = self._verify(
-                self._params_dev, self._cache, self._token, draft,
-                self._rngs, active, self._pt)
-        else:
-            committed, n_acc, cache, rngs = self._verify(
-                self._params_dev, self._cache, self._token, draft,
-                self._rngs, active)
-        # ONE transfer per verify round: committed tokens, accept
-        # counts, and PRNG lanes come back together.
-        # rtlint: sync-ok=verify-boundary one transfer per round
-        com_np = np.asarray(committed)
-        # rtlint: sync-ok=verify-boundary same round-trip
-        acc_np = np.asarray(n_acc)
-        # rtlint: sync-ok=verify-boundary same round-trip
-        rngs_np = np.asarray(rngs)
-        t1 = time.time()
+        with self._phases.phase("decode", slots_active=n_active,
+                                spec=True) as ph:
+            self._note_decode_gap(ph.t0)
+            if self.paged:
+                committed, n_acc, cache, rngs = self._verify(
+                    self._params_dev, self._cache, self._token, draft,
+                    self._rngs, active, self._pt)
+            else:
+                committed, n_acc, cache, rngs = self._verify(
+                    self._params_dev, self._cache, self._token, draft,
+                    self._rngs, active)
+            # ONE transfer per verify round: committed tokens, accept
+            # counts, and PRNG lanes come back together.
+            # rtlint: sync-ok=verify-boundary one transfer per round
+            com_np = np.asarray(committed)
+            # rtlint: sync-ok=verify-boundary same round-trip
+            acc_np = np.asarray(n_acc)
+            # rtlint: sync-ok=verify-boundary same round-trip
+            rngs_np = np.asarray(rngs)
         if epoch >= 0 and epoch != self._epoch:
             return                    # stale driver: drop on the floor
-        self._cache = cache
-        sm = serve_metrics()
-        labels = {"deployment": self.deployment}
-        sm["engine_slot_occupancy"].observe(n_active / self.slots,
-                                            labels=labels)
-        sm["engine_dispatches"].inc(labels=labels)
-        accepted_total = int(acc_np[active].sum()) if n_active else 0
-        sm["engine_spec_proposed"].inc(self.draft_k * n_active,
-                                       labels=labels)
-        if accepted_total:
-            sm["engine_spec_accepted"].inc(accepted_total, labels=labels)
-        self._count(dispatches=1, occupancy_sum=n_active / self.slots,
-                    spec_rounds=1, spec_proposed=self.draft_k * n_active,
-                    spec_accepted=accepted_total, spec_lanes=n_active)
-        _driver_emit("engine.dispatch", epoch=self._epoch,
-                     active=n_active, spec=True,
-                     accepted=accepted_total)
-        if self.tp > 1:
-            _driver_emit("shard.dispatch", epoch=self._epoch,
-                         mesh=[("tp", self.tp)],
-                         program="verify_paged" if self.paged
-                         else "verify")
-        with self._stats_lock:
-            self._stats["peak_active"] = max(self._stats["peak_active"],
-                                             n_active)
-        emitted = 0
-        for i, st in enumerate(self._state):
-            if st is None or st.parked or not active[i]:
-                continue                     # parked or chunk-mode slot
-            na = int(acc_np[i])
-            adv = na + 1
-            sm["engine_spec_accept_len"].observe(na, labels=labels)
-            self._rngs[i] = rngs_np[i]
-            st.pos += adv                    # mirrors the device pos
-            if st.lane.closed:               # consumer left: free now
-                self._free_slot(i)
-                self._count(abandoned=1)
-                continue
-            if deadline_expired(st.deadline_s):
-                st.lane.q.put(("err", RequestDeadlineExceeded(
-                    "request deadline passed mid-generation")))
-                self._free_slot(i)
-                self._count(expired=1)
-                sm["requests_expired"].inc(
-                    labels={"where": "engine",
-                            "deployment": self.deployment})
-                continue
-            row = com_np[i]
-            j = min(adv, st.remaining)
-            finished = st.remaining <= adv
-            if self.eos_token >= 0:
-                hits = np.flatnonzero(row[:j] == self.eos_token)
-                if hits.size:                # free at the EOS
-                    j = int(hits[0]) + 1
-                    finished = True
-            self._token[i] = row[j - 1]      # last DELIVERED token
-            if st.trace_ctx is not None:
-                tracing.record_span("decode.chunk", t0, t1,
-                                    parent_ctx=st.trace_ctx, slot=i,
-                                    active_slots=n_active, tokens=j,
-                                    accepted=na,
-                                    deployment=self.deployment)
-            # Replay suppression counts DELIVERED tokens — variable
-            # advance changes nothing about the token arithmetic.
-            cut = min(st.skip, j)
-            st.skip -= cut
-            if j > cut:
-                st.lane.q.put(("item", row[cut:j].copy()))
-                st.emitted += j - cut
-                emitted += j - cut
-            st.remaining -= j
-            if finished:
-                st.lane.q.put((_STREAM_END, None))
-                self._free_slot(i)           # drafter.free rides along
-                self._count(completed=1)
-            else:
-                self._drafter.observe(i, row[:j], na)
-        if emitted:
-            sm["engine_tokens"].inc(emitted, labels=labels)
-            self._count(tokens=emitted)
-        self._observe_pages(sm)
+        with self._phases.phase("deliver", slots_active=n_active):
+            self._cache = cache
+            sm = serve_metrics()
+            labels = {"deployment": self.deployment}
+            sm["engine_slot_occupancy"].observe(n_active / self.slots,
+                                                labels=labels)
+            sm["engine_dispatches"].inc(labels=labels)
+            accepted_total = int(acc_np[active].sum()) if n_active else 0
+            sm["engine_spec_proposed"].inc(self.draft_k * n_active,
+                                           labels=labels)
+            if accepted_total:
+                sm["engine_spec_accepted"].inc(accepted_total, labels=labels)
+            self._count(dispatches=1, occupancy_sum=n_active / self.slots,
+                        spec_rounds=1, spec_proposed=self.draft_k * n_active,
+                        spec_accepted=accepted_total, spec_lanes=n_active)
+            _driver_emit("engine.dispatch", epoch=self._epoch,
+                         active=n_active, spec=True,
+                         accepted=accepted_total)
+            if self.tp > 1:
+                _driver_emit("shard.dispatch", epoch=self._epoch,
+                             mesh=[("tp", self.tp)],
+                             program="verify_paged" if self.paged
+                             else "verify")
+            with self._stats_lock:
+                self._stats["peak_active"] = max(self._stats["peak_active"],
+                                                 n_active)
+            emitted = 0
+            for i, st in enumerate(self._state):
+                if st is None or st.parked or not active[i]:
+                    continue                     # parked or chunk-mode slot
+                na = int(acc_np[i])
+                adv = na + 1
+                sm["engine_spec_accept_len"].observe(na, labels=labels)
+                self._rngs[i] = rngs_np[i]
+                st.pos += adv                    # mirrors the device pos
+                if st.lane.closed:               # consumer left: free now
+                    self._free_slot(i)
+                    self._count(abandoned=1)
+                    continue
+                if deadline_expired(st.deadline_s):
+                    st.lane.q.put(("err", RequestDeadlineExceeded(
+                        "request deadline passed mid-generation")))
+                    self._free_slot(i)
+                    self._count(expired=1)
+                    sm["requests_expired"].inc(
+                        labels={"where": "engine",
+                                "deployment": self.deployment})
+                    continue
+                row = com_np[i]
+                j = min(adv, st.remaining)
+                finished = st.remaining <= adv
+                if self.eos_token >= 0:
+                    hits = np.flatnonzero(row[:j] == self.eos_token)
+                    if hits.size:                # free at the EOS
+                        j = int(hits[0]) + 1
+                        finished = True
+                self._token[i] = row[j - 1]      # last DELIVERED token
+                if st.trace_ctx is not None:
+                    tracing.record_span("decode.chunk",
+                                        mono_ns=(ph.t0, ph.t1),
+                                        parent_ctx=st.trace_ctx, slot=i,
+                                        active_slots=n_active, tokens=j,
+                                        accepted=na,
+                                        deployment=self.deployment)
+                # Replay suppression counts DELIVERED tokens — variable
+                # advance changes nothing about the token arithmetic.
+                cut = min(st.skip, j)
+                st.skip -= cut
+                if j > cut:
+                    st.lane.q.put(("item", row[cut:j].copy()))
+                    st.emitted += j - cut
+                    emitted += j - cut
+                st.remaining -= j
+                if finished:
+                    st.lane.q.put((_STREAM_END, None))
+                    self._free_slot(i)           # drafter.free rides along
+                    self._count(completed=1)
+                else:
+                    self._drafter.observe(i, row[:j], na)
+            if emitted:
+                sm["engine_tokens"].inc(emitted, labels=labels)
+                self._count(tokens=emitted)
+            self._observe_pages(sm)
+            self._decode_read_ns = ph.t1 if any(
+                s is not None for s in self._state) else None

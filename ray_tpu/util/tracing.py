@@ -33,7 +33,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional, Union
+from typing import Any, Dict, List, Optional, Tuple, Union
 
 # (trace_id, span_id) of the active span in this thread/coroutine.
 _current: contextvars.ContextVar = contextvars.ContextVar(
@@ -86,14 +86,38 @@ def current_context() -> Optional[Dict[str, str]]:
     return {"trace_id": cur[0], "span_id": cur[1]}
 
 
+def clock_pair() -> Dict[str, float]:
+    """This process's wall clock and monotonic clock read together.
+    Every span flush carries a fresh pair, so the head can place spans
+    that have ``mono_ns`` stamps on one axis however the wall clock
+    stepped since they were taken (one machine's processes share
+    ``CLOCK_MONOTONIC``; waits that cross nodes keep the wall clock)."""
+    return {"wall": time.time(), "mono_ns": time.monotonic_ns()}
+
+
+# Maps a ``time.monotonic_ns()`` stamp to wall-clock seconds for the
+# ``start``/``end`` every span carries.
+_anchor = clock_pair()
+
+
+def wall_of(mono_ns: int) -> float:
+    return _anchor["wall"] + (mono_ns - _anchor["mono_ns"]) / 1e9
+
+
 def _record(name: str, kind: str, trace_id: str, span_id: str,
             parent_id: Optional[str], start: float, end: float,
-            attrs: Optional[Dict[str, Any]], status: str = "ok") -> dict:
+            attrs: Optional[Dict[str, Any]], status: str = "ok",
+            mono_ns: Optional[Tuple[int, int]] = None) -> dict:
     span = {
         "name": name, "kind": kind,
         "trace_id": trace_id, "span_id": span_id, "parent_id": parent_id,
         "start": start, "end": end, "status": status,
     }
+    if mono_ns is not None:
+        # the same interval on time.monotonic_ns(): the clock of the
+        # engine's counters, the benchmark's stamps and (through one
+        # marker event) a jax.profiler trace
+        span["mono_ns"] = [int(mono_ns[0]), int(mono_ns[1])]
     if attrs:
         span["attrs"] = attrs
     if len(_buffer) >= (_buffer.maxlen or 0) > 0:
@@ -226,12 +250,17 @@ def manual_span(name: str, kind: str = "internal",
     return ManualSpan(name, kind, parent, attrs)
 
 
-def record_span(name: str, start: float, end: Optional[float] = None,
-                kind: str = "stage",
+def record_span(name: str, start: Optional[float] = None,
+                end: Optional[float] = None, kind: str = "stage",
                 parent_ctx: Optional[Dict[str, str]] = None,
-                status: str = "ok", **attrs) -> Optional[dict]:
+                status: str = "ok",
+                mono_ns: Optional[Tuple[int, int]] = None,
+                **attrs) -> Optional[dict]:
     """Record an already-measured span (start/end are wall-clock
-    ``time.time()`` stamps) without touching the active context.
+    ``time.time()`` stamps) without touching the active context. A span
+    measured on the monotonic clock passes ``mono_ns=(start, end)``
+    (``time.monotonic_ns()``) instead; its wall-clock stamps derive
+    from those and the span keeps both.
 
     The serve data plane uses this for stage timings whose lifetime does
     not match any ``with`` block: queue waits measured across a process
@@ -247,11 +276,138 @@ def record_span(name: str, start: float, end: Optional[float] = None,
         parent = _current.get()
     if parent is None and not _enabled:
         return None
+    if mono_ns is not None:
+        start, end = wall_of(mono_ns[0]), wall_of(mono_ns[1])
     trace_id = parent[0] if parent else _new_id(16)
     return _record(name, kind, trace_id, _new_id(8),
                    parent[1] if parent else None, start,
                    time.time() if end is None else end,
-                   attrs or None, status)
+                   attrs or None, status, mono_ns)
+
+
+_compiles: Optional[Dict[str, int]] = None
+_compiles_lock = threading.Lock()
+
+
+def compile_counts() -> Dict[str, int]:
+    """``{"n", "ns"}``: programs XLA built in this process and the time
+    that took, counted by one ``jax.monitoring`` listener (registered on
+    the first call; jax reports a build that the persistent cache served
+    too). The table is live: after warm-up both must stand still."""
+    global _compiles
+    with _compiles_lock:
+        if _compiles is None:
+            import jax.monitoring
+
+            table = {"n": 0, "ns": 0}
+
+            def on_duration(event: str, seconds: float, **_kw):
+                if event == "/jax/core/compile/backend_compile_duration":
+                    table["n"] += 1
+                    table["ns"] += int(seconds * 1e9)
+
+            jax.monitoring.register_event_duration_secs_listener(
+                on_duration)
+            _compiles = table
+        return _compiles
+
+
+class PhaseClock:
+    """What one driver thread (the serving engine's) is doing, phase by
+    phase. The thread is at every moment in exactly one phase; phases
+    nest, and a phase is charged its SELF time (its span minus what the
+    phases opened inside it cover), so the phases of ``table`` sum to
+    ``table["total"]`` by construction. Entering and leaving a phase
+    takes one ``time.monotonic_ns()`` stamp each and does three things
+    with the pair:
+
+    - adds the self time to ``table[name]``: plain ints owned by the
+      driver thread; other threads read them racily (a phase in flight
+      is not counted yet);
+    - holds a ``jax.profiler.TraceAnnotation("<prefix>.<name>", ...)``
+      open over the same interval, so that in any ``jax.profiler`` trace
+      the phases lie in the host plane on the trace's own clock, beside
+      the device operations (an atomic load when no trace is running);
+    - when :func:`enabled`, records one span (``kind="driver"``, one
+      trace id per clock, i.e. per driver run) with ``mono_ns``. Spans
+      are held until the outermost phase closes, and dropped if that
+      one was ``muted``: a loop that wakes twenty times a second to find
+      nothing to do must not fill the span buffers with it.
+
+    One clock per driver run: the table outlives it (the engine's), the
+    stack of open phases does not.
+    """
+
+    def __init__(self, prefix: str, table: Dict[str, int], **attrs):
+        # jax only where a driver thread exists: this module is imported
+        # by processes that must never load it
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self.prefix = prefix
+        self.table = table
+        self.attrs = attrs              # on every span of this clock
+        self.trace_id = _new_id(16)
+        self._open: List["_Phase"] = []
+        self._spans: List[tuple] = []   # of the outermost open phase
+
+    def phase(self, name: str, **args) -> "_Phase":
+        """``with clock.phase("decode", slots_active=n) as ph:``; after
+        the block ``ph.t0``/``ph.t1`` are its monotonic stamps. ``args``
+        go to the annotation and the span."""
+        return _Phase(self, name, args)
+
+    def step(self, name: str, **args):
+        """A named part of the phase that is open: an annotation in the
+        profiler's trace (``<prefix>.<phase>.<name>``), nothing else."""
+        top = self._open[-1].name if self._open else "loop"
+        return self._annotation(f"{self.prefix}.{top}.{name}", **args)
+
+
+class _Phase:
+    __slots__ = ("clock", "name", "args", "t0", "t1", "span_id",
+                 "muted", "_inner", "_ann")
+
+    def __init__(self, clock: PhaseClock, name: str, args: dict):
+        self.clock, self.name, self.args = clock, name, args
+        self.t0 = self.t1 = self._inner = 0
+        self.span_id = _new_id(8) if _enabled else None
+        self.muted = False      # set on an outermost phase: no spans
+
+    def __enter__(self):
+        c = self.clock
+        self._ann = c._annotation(f"{c.prefix}.{self.name}", **self.args)
+        self._ann.__enter__()
+        c._open.append(self)
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        self.t1 = time.monotonic_ns()
+        c = self.clock
+        dur = self.t1 - self.t0
+        stack = c._open
+        stack.pop()             # ``with`` blocks of one thread: LIFO
+        c.table[self.name] = c.table.get(self.name, 0) + dur - self._inner
+        if stack:
+            stack[-1]._inner += dur
+        else:
+            c.table["total"] = c.table.get("total", 0) + dur
+        self._ann.__exit__(et, ev, tb)
+        if _enabled:
+            c._spans.append((
+                f"{c.prefix}.{self.name}", "driver", c.trace_id,
+                self.span_id or _new_id(8),
+                stack[-1].span_id if stack else None,
+                wall_of(self.t0), wall_of(self.t1),
+                {**c.attrs, **self.args} or None,
+                "ok" if et is None else "error", (self.t0, self.t1)))
+        if not stack and c._spans:
+            spans, c._spans = c._spans, []
+            if not self.muted:
+                for span in spans:
+                    _record(*span)
+        return False
 
 
 @contextlib.contextmanager

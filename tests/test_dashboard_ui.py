@@ -18,8 +18,13 @@ def test_dashboard_spa_and_all_apis_multinode():
     """Every endpoint the SPA consumes works against a live 2-node
     cluster: state kinds, per-node agent stats, worker log tail, jobs +
     job logs, timeline, metrics, and the page itself."""
+    import ray_tpu
     from ray_tpu.cluster_utils import Cluster
 
+    if ray_tpu.is_initialized():
+        # an earlier file of this worker left conftest.rt_cluster's
+        # session up for reuse (files run back to back under loadfile)
+        ray_tpu.shutdown()
     c = Cluster(head_resources={"CPU": 2})
     rt = c.connect()
     try:

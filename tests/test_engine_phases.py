@@ -1,0 +1,323 @@
+"""The engine's driver tells its own time (ISSUE 24): named programs,
+driver phases in ``engine.stats()``, the request lifecycle counted where
+it happens, compiles counted, and spans on the monotonic clock.
+
+CPU, ``nano``: these are counts, names and orderings, never a speed.
+"""
+import inspect
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from ray_tpu.util import tracing
+
+PHASES = ("idle", "admit", "prefill", "cover", "decode", "deliver",
+          "other")
+KINDS = {
+    "flat": dict(),
+    "paged": dict(paged=True, page_size=8, prefix_cache=True),
+    "spec": dict(paged=True, page_size=8, spec_decode="ngram", draft_k=2),
+}
+
+
+@pytest.fixture(scope="module")
+def nano():
+    from ray_tpu.models import gpt
+
+    return gpt.CONFIGS["nano"]
+
+
+@pytest.fixture(scope="module")
+def nano_params(nano):
+    import jax
+
+    from ray_tpu.models import gpt
+
+    return gpt.init_params(jax.random.PRNGKey(0), nano)
+
+
+@pytest.fixture
+def make(nano, nano_params):
+    from ray_tpu.serve.engine import DecodeEngine
+
+    made = []
+
+    def _make(kind="flat", **kw):
+        kw = {**KINDS[kind], **kw}
+        kw.setdefault("slots", 2)
+        kw.setdefault("chunk", 4)
+        kw.setdefault("max_len", 64)
+        kw.setdefault("prompt_buckets", (8, 16))
+        made.append(DecodeEngine(nano_params, nano, **kw))
+        return made[-1]
+
+    yield _make
+    for eng in made:
+        eng.shutdown()
+
+
+def _prompt(nano, n, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, nano.vocab_size, (n,)).astype(np.int32)
+
+
+def _run_all(eng, nano, n, max_new=9, **kw):
+    threads = [threading.Thread(
+        target=lambda i=i: list(eng.stream(
+            _prompt(nano, 3 + i % 5, i), max_new, **kw)))
+        for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+
+
+def _delta(a, b):
+    return {k: b[k] - a[k] for k in b
+            if isinstance(b[k], int) and not isinstance(b[k], bool)
+            and isinstance(a.get(k), int)}
+
+
+# ------------------------------------------------------- program names
+def _factories():
+    from ray_tpu.models import gpt_decode as gd
+
+    out = []
+    for name, fn in sorted(vars(gd).items()):
+        if not name.startswith("jit_") or not callable(fn):
+            continue
+        params = inspect.signature(fn).parameters
+        for tp in (1, 2) if "tp" in params else (1,):
+            out.append(pytest.param(name, tp, id=f"{name}-tp{tp}"))
+    return out
+
+
+@pytest.mark.parametrize("factory,tp", _factories())
+def test_program_carries_its_factorys_name(nano, factory, tp):
+    """Every ``jit_<x>`` factory returns a program jax calls ``<x>``
+    (the XLA module is ``jit_<x>``), on one chip and under shard_map."""
+    from ray_tpu.models import gpt_decode as gd
+
+    fn = getattr(gd, factory)
+    given = {"cfg": nano, "k": 4, "page_size": 8}
+    args = {p: given[p] for p in inspect.signature(fn).parameters
+            if p in given}
+    if tp > 1:
+        args["tp"] = tp
+    assert fn(**args).__name__ == factory[len("jit_"):]
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+def test_lowered_module_is_named(make, tp):
+    """What a profile shows: the module name of the lowered program."""
+    eng = make("paged", tp=tp)
+    active = np.zeros((eng.slots,), bool)
+    text = eng._step.lower(eng._params_dev, eng._cache, eng._token,
+                           eng._rngs, active, eng._pt).as_text()
+    assert "module @jit_decode_chunk_slots_paged" in text
+    assert eng._prefill.__name__ == "prefill_into_slot_paged"
+
+
+def test_train_programs_are_named(nano):
+    import jax
+    from jax.sharding import Mesh
+
+    from ray_tpu.models import gpt
+
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
+                ("dp", "fsdp"))
+    init, step, _s, _b = gpt.make_train_step(nano, mesh)
+    assert (init.__name__, step.__name__) == ("train_init", "train_step")
+
+
+# ------------------------------------------------------- driver phases
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_phases_sum_to_total(make, nano, kind):
+    eng = make(kind)
+    a = eng.stats()
+    _run_all(eng, nano, 20)
+    time.sleep(0.12)          # let the last iteration close its phase
+    d = _delta(a, eng.stats())
+    assert d["admitted"] == 20 and d["prefills"] == 20
+    parts = sum(d[f"driver_ns_{p}"] for p in PHASES)
+    assert abs(parts - d["driver_ns_total"]) <= 0.01 * d["driver_ns_total"]
+    for p in ("admit", "prefill", "decode", "deliver"):
+        assert d[f"driver_ns_{p}"] > 0, p
+    # every prefill was timed where it ran
+    assert d["prefill_ns_sum"] == d["driver_ns_prefill"]
+    assert d["prefill_tokens_sum"] == sum(3 + i % 5 for i in range(20))
+    assert d["admission_wait_ns_sum"] > 0
+
+
+def test_idle_engine_is_idle(make):
+    eng = make("paged")
+    time.sleep(0.1)
+    a = eng.stats()
+    time.sleep(0.6)
+    d = _delta(a, eng.stats())
+    assert d["driver_ns_total"] > 0.4e9
+    assert d["driver_ns_idle"] > 0.9 * d["driver_ns_total"]
+    for k in ("admission_wait_ns_sum", "prefill_ns_sum",
+              "prefill_tokens_sum", "decode_gap_ns_sum", "compiles",
+              "compile_ns", "driver_ns_prefill", "driver_ns_decode"):
+        assert d[k] == 0, k
+
+
+@pytest.mark.parametrize("kind", ["flat", "paged"])
+def test_compiles_stand_still_after_first_use(make, nano, kind):
+    """After a bucket's first request nothing compiles, however many
+    requests follow; the next bucket's first use builds one program."""
+    # a pool shape no other test of this process has compiled
+    eng = make(kind, slots=3, max_len=40)
+    list(eng.stream(_prompt(nano, 5), 6))
+    a = eng.stats()
+    assert a["compiles"] > 0 and a["compile_ns"] > 0
+    _run_all(eng, nano, 50, max_new=7)            # prompts of 3-7: bucket 8
+    b = eng.stats()
+    assert b["compiles"] == a["compiles"]
+    assert b["compile_ns"] == a["compile_ns"]
+    list(eng.stream(_prompt(nano, 12), 6))        # bucket 16, first use
+    assert eng.stats()["compiles"] == b["compiles"] + 1
+    list(eng.stream(_prompt(nano, 13), 6))
+    assert eng.stats()["compiles"] == b["compiles"] + 1
+
+
+# ---------------------------------------------------------- decode gap
+@pytest.mark.parametrize("kind", ["flat", "paged"])
+def test_decode_gap_is_what_a_prefill_costs_running_lanes(make, nano,
+                                                          kind):
+    eng = make(kind)
+    # ---- a lone lane: between its decode steps there is only the
+    # driver's own bookkeeping, and its own prefill came before any
+    a = eng.stats()
+    list(eng.stream(_prompt(nano, 5), 40))
+    time.sleep(0.12)
+    d = _delta(a, eng.stats())
+    assert d["dispatches"] >= 9
+    host = sum(d[f"driver_ns_{p}"]
+               for p in ("admit", "cover", "deliver", "other"))
+    assert d["decode_gap_ns_sum"] <= host
+    assert d["decode_gap_ns_sum"] < d["driver_ns_decode"]
+
+    # ---- a request admitted beside a running lane: the lane waits for
+    # the whole prefill, which the gap therefore contains
+    eng.inject_fault("driver_slow", wedge_s=0.01)   # keep lane A running
+    lane = eng.stream(_prompt(nano, 5, 1), 40)
+    next(lane)                                      # A is decoding
+    a = eng.stats()
+    other = threading.Thread(
+        target=lambda: list(eng.stream(_prompt(nano, 6, 2), 4)))
+    other.start()
+    list(lane)
+    other.join()
+    time.sleep(0.12)
+    b = eng.stats()
+    d = _delta(a, b)
+    assert b["peak_active"] == 2 and d["prefills"] == 1
+    assert d["prefill_ns_sum"] > 0
+    assert d["decode_gap_ns_sum"] >= d["prefill_ns_sum"]
+
+
+# --------------------------------------------------------------- spans
+@pytest.fixture
+def spans_on():
+    tracing.drain()
+    tracing.enable()
+    try:
+        yield
+    finally:
+        tracing.disable()
+        tracing.drain()
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_traced_request_and_driver_spans(make, nano, spans_on, kind):
+    eng = make(kind)
+    time.sleep(0.1)
+    a = eng.stats()
+    tracing.drain()
+    ctx = {"trace_id": "ab" * 16, "span_id": "cd" * 8}
+    lanes = [eng.submit(_prompt(nano, 5, i), 9,
+                        trace_ctx=ctx if i == 0 else None)
+             for i in range(3)]
+    from ray_tpu.serve.batching import _EngineStream
+
+    for ln in lanes:
+        list(_EngineStream(ln))
+    time.sleep(0.12)
+    d = _delta(a, eng.stats())
+    spans = tracing.local_spans()
+
+    # the traced request: admission -> prefill -> decode chunks, all
+    # children of the caller's span, in order on the monotonic clock
+    mine = sorted((s for s in spans if s["trace_id"] == ctx["trace_id"]),
+                  key=lambda s: s["mono_ns"][0])
+    assert all(s["parent_id"] == ctx["span_id"] for s in mine)
+    names = [s["name"] for s in mine]
+    assert names[:2] == ["engine.admission", "engine.prefill"]
+    assert names[2:] and set(names[2:]) == {"decode.chunk"}
+    for s, nxt in zip(mine, mine[1:]):
+        assert s["mono_ns"][0] <= s["mono_ns"][1] <= nxt["mono_ns"][0]
+    for s in mine:      # wall-clock stamps derive from the same pair
+        assert abs((s["end"] - s["start"])
+                   - (s["mono_ns"][1] - s["mono_ns"][0]) / 1e9) < 1e-6
+    pre = mine[1]["attrs"]
+    assert pre["bucket"] == 8 and pre["hist_len"] == 0
+
+    # the driver's own trace: one trace id for this driver run, one
+    # engine.decode span per dispatch whatever the number of slots
+    drv = [s for s in spans if s["kind"] == "driver"]
+    assert len({s["trace_id"] for s in drv}) == 1
+    assert ctx["trace_id"] not in {s["trace_id"] for s in drv}
+    decodes = [s for s in drv if s["name"] == "engine.decode"]
+    assert len(decodes) == d["dispatches"] > 0
+    assert {s["attrs"]["slots_active"] for s in decodes} <= {1, 2}
+    by_id = {s["span_id"]: s for s in drv}
+    for s in decodes:   # phases nest under the loop iteration's span
+        assert by_id[s["parent_id"]]["name"] == "engine.other"
+        assert s["attrs"]["deployment"] == eng.deployment
+    assert sum(s["name"] == "engine.prefill" for s in drv) == 3
+    # an iteration that found nothing to do leaves no spans behind
+    tracing.drain()
+    time.sleep(0.3)
+    assert tracing.local_spans() == []
+    assert eng.stats()["driver_ns_idle"] > a["driver_ns_idle"]
+
+
+def test_tracing_off_records_nothing(make, nano, monkeypatch):
+    """With tracing off a phase allocates no span: recording one would
+    blow up the driver."""
+    def bomb(*a, **kw):
+        raise AssertionError("a span was recorded with tracing off")
+
+    assert not tracing.enabled()
+    eng = make("paged")
+    time.sleep(0.1)         # the driver has made its clock's trace id
+    monkeypatch.setattr(tracing, "_record", bomb)
+    monkeypatch.setattr(tracing, "_new_id", bomb)
+    _run_all(eng, nano, 6)
+    st = eng.stats()
+    assert st["driver_restarts"] == 0 and st["completed"] == 6
+    assert st["driver_ns_decode"] > 0
+    assert tracing.local_spans() == []
+
+
+def test_phase_clock_self_times():
+    """The clock alone: nested phases are charged their self time."""
+    table = {}
+    clock = tracing.PhaseClock("t", table)
+    with clock.phase("other"):
+        time.sleep(0.01)
+        with clock.phase("admit") as ph:
+            time.sleep(0.02)
+            with clock.phase("prefill", bucket=8):
+                time.sleep(0.03)
+            with clock.step("register"):
+                pass
+    assert ph.t1 - ph.t0 >= 0.05e9
+    assert table["prefill"] >= 0.03e9 and table["admit"] >= 0.02e9
+    assert table["admit"] < 0.03e9 + 0.02e9     # without its child
+    assert table["total"] == (table["other"] + table["admit"]
+                              + table["prefill"])

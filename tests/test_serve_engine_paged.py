@@ -515,11 +515,10 @@ def test_paged_engine_metrics_observed(nano, nano_params):
         sm = serve_metrics()
         key = (("deployment", "paged_probe"),)
         free = dict(sm["engine_pages_free"].collect())
-        used = dict(sm["engine_pages_used"].collect())
         hits = dict(sm["engine_prefix_hits"].collect())
         cows = dict(sm["engine_cow_copies"].collect())
-        assert key in free and key in used
-        assert free[key] + used[key] == eng.n_pages
+        assert key in free
+        assert free[key] + eng.stats()["pages_used"] == eng.n_pages
         assert hits.get(key, 0) >= 1
         assert cows.get(key, 0) >= 1
         st = eng.stats()

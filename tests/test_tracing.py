@@ -12,9 +12,20 @@ import ray_tpu
 from ray_tpu.util import tracing
 
 
+def _fresh_init(**kw):
+    """``conftest.rt_cluster`` leaves its cluster running for reuse, and
+    ``--dist loadfile`` runs files back to back in one process: a file
+    that used it before this one leaves a session behind, and a bare
+    ``init()`` then raises "already called" (all nine tests, in the
+    driver's run of the whole suite). Same guard as ``rt_fresh``."""
+    if ray_tpu.is_initialized():
+        ray_tpu.shutdown()
+    ray_tpu.init(**kw)
+
+
 @pytest.fixture
 def traced_cluster():
-    ray_tpu.init(num_cpus=6)
+    _fresh_init(num_cpus=6)
     tracing.enable()
     try:
         yield
@@ -158,7 +169,7 @@ def test_error_status_recorded(traced_cluster):
 
 
 def test_disabled_is_free():
-    ray_tpu.init(num_cpus=1)
+    _fresh_init(num_cpus=1)
     try:
         assert not tracing.enabled()
 
