@@ -82,6 +82,21 @@ clamped into another slot's page. The compiled-program set therefore
 stays exactly as flat: one prefill program per (suffix) prompt bucket +
 one chunk program, for ANY page-table contents.
 
+The pool is CARRIED through the decode and verify steps' layer scans,
+not scanned: as ``xs``/``ys`` of the scan XLA slices each layer's pool
+out of the stacked pool and writes it back, K and V, in every layer of
+every token step (a quarter to two fifths of a step on the chip at
+2048 pages, whatever the live tokens: PERF.md, PR 25). The carry is
+the stacked pool viewed as ``[L * n_pages, page_size, H, hd]``
+(:func:`_flat_pool`; merging the leading axes moves no bytes), and
+layer ``l`` addresses page ``p`` at ``l * n_pages + p``
+(:func:`_layer_pages`, which leaves :data:`PT_SENTINEL` a sentinel):
+the scatter of the new rows, the int8 merge, the gather and the
+kernel's scalar-prefetched page map then run on the carried pool as
+they would on one layer's, and a step writes its rows in place. The
+cache dict keeps the stacked ``[L, n_pages, ...]`` shapes at every
+program boundary (prefill, export/import, the tp specs).
+
 Shared-prefix reuse rides the same machinery: a prompt whose prefix is
 already resident (the engine's prefix cache) maps the cached pages into
 its page table and prefills only the **suffix** — ``hist_len`` is a
@@ -917,6 +932,37 @@ def _deq_page(codes: jax.Array, scales: jax.Array, dtype) -> jax.Array:
             * scales[..., None, :, None]).astype(dtype)
 
 
+def _flat_pool(cache: Cache) -> Cache:
+    """The stacked pool of ``cache`` (``k``, ``v``, and ``ks``, ``vs``
+    for int8; not ``pos``) viewed as ONE pool of ``L * n_pages`` pages.
+    Merging the two leading axes moves no bytes; it is what lets a
+    layer scan CARRY the pool and hand each layer its pages by index
+    (:func:`_layer_pages`) instead of slicing the layer's pool out of
+    the stacked one and writing it back. :func:`_stacked_pool` undoes
+    the view."""
+    return {n: a.reshape((-1,) + a.shape[2:])
+            for n, a in cache.items() if n != "pos"}
+
+
+def _stacked_pool(pool: Cache, like: Cache) -> Cache:
+    """A flattened ``pool`` (:func:`_flat_pool`) back in the stacked
+    ``[L, n_pages, ...]`` shapes of the cache ``like``."""
+    return {n: a.reshape(like[n].shape) for n, a in pool.items()}
+
+
+def _layer_pages(pages: jax.Array, layer: jax.Array,
+                 n_pages: int) -> jax.Array:
+    """Page indices of one layer's pool (a page table, or the pages a
+    step writes) as indices into the flattened stacked pool: page ``p``
+    of layer ``l`` is page ``l * n_pages + p``. Everything that is not
+    a page of the layer's pool (:data:`PT_SENTINEL`, any index at or
+    past ``n_pages``) stays :data:`PT_SENTINEL`, so it is still dropped
+    on a write, skipped by the kernel and clipped on a read, and can
+    never land in a neighbour layer's page."""
+    return jnp.where(pages < n_pages, pages + layer * n_pages,
+                     jnp.int32(PT_SENTINEL))
+
+
 def _merge_span_int8(codes: jax.Array, scales: jax.Array,
                      vals: jax.Array, pt: jax.Array, start: jax.Array,
                      count, active: jax.Array, page_size: int):
@@ -998,7 +1044,10 @@ def init_paged_cache(cfg: GPTConfig, slots: int, n_pages: int,
     per-slot ``[slots]`` (virtual position, exactly as flat). With
     ``kv_dtype="int8"`` the page arrays hold quantized codes and the
     pool grows ``"ks"``/``"vs"`` per-(layer, page, head) float32
-    scales."""
+    scales. The layer axis leads and the page axis follows it, so the
+    decode and verify steps can carry the whole pool through their
+    layer scans as ``L * n_pages`` pages (:func:`_flat_pool`) and never
+    copy a layer's pool out of it."""
     if kv_dtype not in KV_DTYPES:
         raise ValueError(
             f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}")
@@ -1031,6 +1080,13 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     mapped by its page-table row ``pt [B, max_pages]``), valid at
     positions ``<= pos[b]``. Returns the attention context
     ``[B, 1, H, hd]`` in ``q.dtype``.
+
+    ``kc``/``vc`` ``[n_pages, page_size, H, hd]`` are ONE pool of
+    pages, indexed by ``pt``: a single layer's pool with the slots'
+    page table, or — as the decode step calls it — the whole stacked
+    pool flattened to ``L * n_pages`` pages with the layer's offset
+    page table (:func:`_layer_pages`). It is the same call; nothing
+    here knows of layers.
 
     ``kernel="gather"`` is the reference path: gather every mapped page
     into virtual order and run masked full-length attention (sentinel
@@ -1373,7 +1429,15 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     clamped into another slot's page; int8 pools merge through
     :func:`_merge_span_int8` instead) and attends over its virtual
     sequence via :func:`paged_attention`, valid ``<= pos[b]``.
-    Inactive slots neither write nor advance."""
+    Inactive slots neither write nor advance.
+
+    The layer scan carries the pool (``xs`` are the block weights and
+    the layer index, there are no ``ys``): layer ``l`` writes and reads
+    its pages inside the flattened stacked pool at ``l * n_pages +
+    page`` (:func:`_flat_pool`, :func:`_layer_pages`), so no layer's
+    pool is ever sliced out of the stacked pool or written back, and
+    the step's rows land in place. Takes and returns the cache in its
+    stacked ``[L, n_pages, ...]`` shapes."""
     B = token.shape[0]
     ps = page_size
     max_pages = pt.shape[1]
@@ -1389,44 +1453,36 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     page_w = jnp.where(active & (vp < max_pages), page_idx,
                        jnp.int32(PT_SENTINEL))
     off = pos % ps
-    xs = (params["block"], cache["k"], cache["v"])
-    if quant:
-        xs = xs + (cache["ks"], cache["vs"])
+    L, n_pages = cache["k"].shape[:2]
 
     def body(carry, layer):
-        x = carry
-        if quant:
-            p, kc, vc, ksc, vsc = layer      # [n_pages, ps, H, hd]
-        else:
-            p, kc, vc = layer
-            ksc = vsc = None
+        x, pool = carry                      # [L * n_pages, ps, H, hd]
+        p, l = layer
+        pt_l = _layer_pages(pt, l, n_pages)
         q, k, v = _block_kv(x, p, cfg)       # [B, 1, H, hd]
         if quant:
-            kc, ksc = _merge_span_int8(kc, ksc, k, pt, pos, 1,
-                                       active, ps)
-            vc, vsc = _merge_span_int8(vc, vsc, v, pt, pos, 1,
-                                       active, ps)
+            kc, ksc = _merge_span_int8(pool["k"], pool["ks"], k, pt_l,
+                                       pos, 1, active, ps)
+            vc, vsc = _merge_span_int8(pool["v"], pool["vs"], v, pt_l,
+                                       pos, 1, active, ps)
+            pool = {"k": kc, "v": vc, "ks": ksc, "vs": vsc}
         else:
-            kc = kc.at[page_w, off].set(k[:, 0], mode="drop")
-            vc = vc.at[page_w, off].set(v[:, 0], mode="drop")
-        att = paged_attention(q, kc, vc, pt, pos, page_size=ps,
-                              kernel=attn_kernel, ks=ksc, vs=vsc)
+            page_w_l = _layer_pages(page_w, l, n_pages)
+            pool = {n: pool[n].at[page_w_l, off].set(new[:, 0],
+                                                     mode="drop")
+                    for n, new in (("k", k), ("v", v))}
+        att = paged_attention(q, pool["k"], pool["v"], pt_l, pos,
+                              page_size=ps, kernel=attn_kernel,
+                              ks=pool.get("ks"), vs=pool.get("vs"))
         x = x + _mm_row(att.reshape(B, 1, -1), p["wo"]["kernel"],
                         cfg.dtype, tp_axis)
         x = _ffn(x, p, cfg, tp_axis)
-        if quant:
-            return x, (kc, vc, ksc, vsc)
-        return x, (kc, vc)
+        return (x, pool), None
 
-    if quant:
-        x, (k_new, v_new, ks_new, vs_new) = lax.scan(body, x, xs)
-        cache_out = {"k": k_new, "v": v_new, "ks": ks_new,
-                     "vs": vs_new,
-                     "pos": pos + active.astype(jnp.int32)}
-    else:
-        x, (k_new, v_new) = lax.scan(body, x, xs)
-        cache_out = {"k": k_new, "v": v_new,
-                     "pos": pos + active.astype(jnp.int32)}
+    (x, pool), _ = lax.scan(body, (x, _flat_pool(cache)),
+                            (params["block"], jnp.arange(L)))
+    cache_out = {**_stacked_pool(pool, cache),
+                 "pos": pos + active.astype(jnp.int32)}
     x = _rmsnorm(x, params["ln_f_scale"])
     logits = _project_vocab(x, params["embed"]["kernel"], cfg)
     return logits[:, 0], cache_out
@@ -1732,11 +1788,14 @@ def verify_chunk_slots_paged(params: Params, cache: Cache,
     read them back dequantized — so accept/reject decisions are made
     on exactly the K/V any later decode step will see; a rejected
     span's codes past the rolled-back ``pos`` are re-zeroed by the
-    next write to that page (the merge's canonical-zeros invariant)."""
+    next write to that page (the merge's canonical-zeros invariant).
+    The pool is carried through the layer scan and addressed per layer
+    exactly as in :func:`_slot_decode_step_paged`, with which the
+    speculative engine alternates this program on the same pool."""
     B = token.shape[0]
     S = k + 1
-    H, hd = cfg.n_head, cfg.head_dim
-    n_pages = cache["k"].shape[1]
+    hd = cfg.head_dim
+    L, n_pages = cache["k"].shape[:2]
     ps = page_size
     max_pages = pt.shape[1]
     V = max_pages * ps
@@ -1755,36 +1814,32 @@ def verify_chunk_slots_paged(params: Params, cache: Cache,
     page_w = jnp.where(active[:, None] & (vp < max_pages), page_idx,
                        jnp.int32(PT_SENTINEL))
     off = positions % ps
-    ptc = jnp.clip(pt, 0, n_pages - 1)                 # [B, max_pages]
     arv = jnp.arange(V)
     valid = arv[None, None, None, :] <= positions[:, None, :, None]
     quant = kv_dtype == "int8"
-    xs = (params["block"], cache["k"], cache["v"])
-    if quant:
-        xs = xs + (cache["ks"], cache["vs"])
 
     def body(carry, layer):
-        x = carry
-        if quant:
-            p, kc, vc, ksc, vsc = layer      # [n_pages, ps, H, hd]
-        else:
-            p, kc, vc = layer
-            ksc = vsc = None
+        x, pool = carry                      # [L * n_pages, ps, H, hd]
+        p, l = layer
+        pt_l = _layer_pages(pt, l, n_pages)
+        ptc = jnp.clip(pt_l, 0, L * n_pages - 1)       # [B, max_pages]
         q, kk, vv = _block_kv(x, p, cfg)     # [B, S, H, hd]
         if quant:
-            kc, ksc = _merge_span_int8(kc, ksc, kk, pt, pos, S,
-                                       active, ps)
-            vc, vsc = _merge_span_int8(vc, vsc, vv, pt, pos, S,
-                                       active, ps)
+            kc, ksc = _merge_span_int8(pool["k"], pool["ks"], kk, pt_l,
+                                       pos, S, active, ps)
+            vc, vsc = _merge_span_int8(pool["v"], pool["vs"], vv, pt_l,
+                                       pos, S, active, ps)
+            pool = {"k": kc, "v": vc, "ks": ksc, "vs": vsc}
             hk = _deq_page(kc[ptc], ksc[ptc],
                            q.dtype).reshape(B, V, -1, hd)
             hv = _deq_page(vc[ptc], vsc[ptc],
                            q.dtype).reshape(B, V, -1, hd)
         else:
-            kc = kc.at[page_w, off].set(kk, mode="drop")
-            vc = vc.at[page_w, off].set(vv, mode="drop")
-            hk = kc[ptc].reshape(B, V, -1, hd)
-            hv = vc[ptc].reshape(B, V, -1, hd)
+            page_w_l = _layer_pages(page_w, l, n_pages)
+            pool = {n: pool[n].at[page_w_l, off].set(new, mode="drop")
+                    for n, new in (("k", kk), ("v", vv))}
+            hk = pool["k"][ptc].reshape(B, V, -1, hd)
+            hv = pool["v"][ptc].reshape(B, V, -1, hd)
         logits = jnp.einsum("bqhd,bkhd->bhqk", q, hk,
                             preferred_element_type=jnp.float32) * scale
         logits = jnp.where(valid, logits, -1e30)
@@ -1794,23 +1849,16 @@ def verify_chunk_slots_paged(params: Params, cache: Cache,
                          ).astype(q.dtype).reshape(B, S, -1)
         x = x + _mm_row(att, p["wo"]["kernel"], cfg.dtype, tp_axis)
         x = _ffn(x, p, cfg, tp_axis)
-        if quant:
-            return x, (kc, vc, ksc, vsc)
-        return x, (kc, vc)
+        return (x, pool), None
 
-    if quant:
-        x, (k_new, v_new, ks_new, vs_new) = lax.scan(body, x, xs)
-    else:
-        x, (k_new, v_new) = lax.scan(body, x, xs)
+    (x, pool), _ = lax.scan(body, (x, _flat_pool(cache)),
+                            (params["block"], jnp.arange(L)))
     x = _rmsnorm(x, params["ln_f_scale"])
     logits = _project_vocab(x, params["embed"]["kernel"], cfg)
     committed, n_acc, rngs = _spec_accept(logits, draft, rngs,
                                           temperature, k)
     pos2 = pos + (1 + n_acc) * active.astype(jnp.int32)
-    cache_out = {"k": k_new, "v": v_new, "pos": pos2}
-    if quant:
-        cache_out["ks"] = ks_new
-        cache_out["vs"] = vs_new
+    cache_out = {**_stacked_pool(pool, cache), "pos": pos2}
     return committed, n_acc, cache_out, rngs
 
 

@@ -455,3 +455,277 @@ def test_kv_bytes_per_page_accounting(nano):
     assert i8 == nano.n_layer * 2 * (8 * nano.n_head * nano.head_dim
                                      + 4 * nano.n_head)
     assert fp / i8 > 1.5
+
+
+# ------------------------------------------- the pool is carried (PR 25)
+def _pool_case(nano, kv_dtype, n_pages, rng=None):
+    """A 4-slot paged pool over nano with every addressing case of a
+    decode or verify step in it. Slot 0 writes mid-page behind
+    ``PT_SENTINEL`` columns; slot 1 is inactive; slot 2's write target
+    is unmapped (``pos`` lies in a sentinel column); slot 3 holds a
+    full, out-of-order table and starts a new page. With ``rng`` the
+    pool is filled with noise, so a row landing in any page of any
+    layer that the step should not touch shows."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode
+
+    ps, max_pages = 8, 4
+    cache = gpt_decode.init_paged_cache(nano, 4, n_pages, ps, kv_dtype)
+    if rng is not None:
+        for name, a in cache.items():
+            if name in ("k", "v"):
+                noise = rng.integers(-127, 128, a.shape) \
+                    if kv_dtype == "int8" \
+                    else rng.standard_normal(a.shape)
+                cache[name] = jnp.asarray(noise, a.dtype)
+            elif name in ("ks", "vs"):
+                cache[name] = jnp.asarray(
+                    rng.uniform(0.01, 0.05, a.shape), a.dtype)
+    pt = np.full((4, max_pages), gpt_decode.PT_SENTINEL, np.int32)
+    pt[0, :2] = [5, 3]
+    pt[1, :1] = [6]
+    pt[2, :2] = [9, 1]
+    pt[3, :4] = [7, 0, 10, 2]
+    cache["pos"] = jnp.asarray([12, 4, 16, 24], jnp.int32)
+    active = jnp.asarray([True, False, True, True])
+    token = jnp.asarray([3, 5, 7, 11], jnp.int32)
+    return cache, token, active, jnp.asarray(pt), ps
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in eqn.params.values():
+            for j in sub if isinstance(sub, (list, tuple)) else (sub,):
+                inner = getattr(j, "jaxpr", j)
+                if hasattr(inner, "eqns"):
+                    yield from _scans(inner)
+
+
+@pytest.mark.parametrize("program,kv_dtype,attn_kernel", [
+    ("decode", "fp", "gather"), ("decode", "fp", "pallas"),
+    ("decode", "int8", "gather"), ("decode", "int8", "pallas"),
+    ("verify", "fp", "gather"), ("verify", "int8", "gather")])
+def test_pool_is_carried_not_scanned(nano, nano_params, program,
+                                     kv_dtype, attn_kernel):
+    """No ``scan`` of the chunk and verify programs takes or returns
+    the pool through ``xs``/``ys``: scanned, XLA slices every layer's
+    pool out of the stacked pool and writes it back in every token
+    step (a quarter to two fifths of a decode step on the chip, PR 23).
+    The pool is larger here than any stacked weight, so one layer's
+    worth of elements among the scanned operands can only be the pool;
+    the int8 scales are small and told by their shapes."""
+    import jax
+
+    from ray_tpu.models import gpt_decode
+
+    n_pages = 1024
+    cache, token, active, pt, ps = _pool_case(nano, kv_dtype, n_pages)
+    rngs = jax.random.split(jax.random.PRNGKey(0), 4)
+    if program == "decode":
+        jaxpr = jax.make_jaxpr(lambda c: gpt_decode.decode_chunk_slots_paged(
+            nano_params, c, token, rngs, active, pt, cfg=nano, k=2,
+            page_size=ps, kv_dtype=kv_dtype,
+            attn_kernel=attn_kernel))(cache)
+    else:
+        draft = np.zeros((4, 2), np.int32)
+        jaxpr = jax.make_jaxpr(lambda c: gpt_decode.verify_chunk_slots_paged(
+            nano_params, c, token, draft, rngs, active, pt, cfg=nano,
+            k=2, page_size=ps, kv_dtype=kv_dtype))(cache)
+    layer_elems = cache["k"][0].size
+    assert layer_elems > max(
+        a.size for a in jax.tree_util.tree_leaves(nano_params))
+    scale_shapes = {cache[n].shape[i:] for n in ("ks", "vs")
+                    if n in cache for i in (0, 1)}
+    carried = False
+    for eqn in _scans(jaxpr.jaxpr):
+        n_fixed = eqn.params["num_consts"] + eqn.params["num_carry"]
+        scanned = list(eqn.invars[n_fixed:]) \
+            + list(eqn.outvars[eqn.params["num_carry"]:])
+        for var in scanned:
+            assert var.aval.size < layer_elems, var.aval
+            assert var.aval.shape not in scale_shapes, var.aval
+        carried |= any(
+            v.aval.size == cache["k"].size
+            for v in eqn.outvars[:eqn.params["num_carry"]])
+    assert carried
+
+
+def _per_layer_reference(nano_params, cache, layer_fn, x):
+    """The layer scan as it was before the pool was carried: layer
+    ``l``'s pool is sliced out of the stacked pool (the scan's ``xs``),
+    handed to ``layer_fn(x, p, kc, vc, ksc, vsc)`` with the slots' page
+    table as it is, and the layers' pools are stacked again (``ys``).
+    Returns the last ``x`` and the stacked pool."""
+    import jax
+
+    names = [n for n in ("k", "v", "ks", "vs") if n in cache]
+
+    def body(x, layer):
+        p, pool = layer
+        x, pool = layer_fn(x, p, *pool, *([None] * (4 - len(pool))))
+        return x, pool[:len(names)]
+
+    x, pool = jax.lax.scan(
+        body, x, (nano_params["block"], tuple(cache[n] for n in names)))
+    return x, dict(zip(names, pool))
+
+
+@pytest.mark.parametrize("kv_dtype,attn_kernel", [
+    ("fp", "gather"), ("fp", "pallas"),
+    ("int8", "gather"), ("int8", "pallas")])
+def test_decode_step_matches_per_layer_reference(nano, nano_params,
+                                                 kv_dtype, attn_kernel):
+    """The step that carries the stacked pool and addresses layer
+    ``l``'s pages at ``l * n_pages + page`` gives the same logits and
+    the same pool, bit for bit, as the step that slices each layer's
+    pool, scatters, attends and stacks — over sentinel columns, an
+    inactive slot and an unmapped write target, on a pool of noise."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode as gd
+    from ray_tpu.models.gpt import _project_vocab, _rmsnorm
+
+    cache, token, active, pt, ps = _pool_case(
+        nano, kv_dtype, 12, np.random.default_rng(31))
+    pos = cache["pos"]
+    page_idx = jnp.take_along_axis(
+        pt, jnp.clip(pos // ps, 0, pt.shape[1] - 1)[:, None], axis=1)[:, 0]
+    page_w = jnp.where(active & (pos // ps < pt.shape[1]), page_idx,
+                       jnp.int32(gd.PT_SENTINEL))
+
+    def layer_fn(x, p, kc, vc, ksc, vsc):
+        q, k, v = gd._block_kv(x, p, nano)
+        if kv_dtype == "int8":
+            kc, ksc = gd._merge_span_int8(kc, ksc, k, pt, pos, 1,
+                                          active, ps)
+            vc, vsc = gd._merge_span_int8(vc, vsc, v, pt, pos, 1,
+                                          active, ps)
+        else:
+            kc = kc.at[page_w, pos % ps].set(k[:, 0], mode="drop")
+            vc = vc.at[page_w, pos % ps].set(v[:, 0], mode="drop")
+        att = gd.paged_attention(q, kc, vc, pt, pos, page_size=ps,
+                                 kernel=attn_kernel, ks=ksc, vs=vsc)
+        x = x + gd._mm_row(att.reshape(4, 1, -1), p["wo"]["kernel"],
+                           nano.dtype)
+        return gd._ffn(x, p, nano), (kc, vc, ksc, vsc)
+
+    @jax.jit
+    def reference(cache):
+        x = nano_params["embed"]["kernel"].astype(nano.dtype)[token]
+        x = x + nano_params["pos_embed"][pos].astype(nano.dtype)
+        x, pool = _per_layer_reference(nano_params, cache, layer_fn,
+                                       x[:, None])
+        x = _rmsnorm(x, nano_params["ln_f_scale"])
+        logits = _project_vocab(x, nano_params["embed"]["kernel"], nano)
+        return logits[:, 0], {**pool, "pos": pos + active}
+
+    step = jax.jit(lambda c: gd._slot_decode_step_paged(
+        nano_params, c, token, active, pt, nano, ps, kv_dtype,
+        attn_kernel))
+    want_logits, want = reference(cache)
+    got_logits, got = step(cache)
+    # Slot 2 attends at a position whose page is unmapped. The kernel
+    # skips the column; the gather reads whichever page the sentinel
+    # clips to, so that row's logits are noise here as they were before
+    # (the engine parks a slot it cannot cover, never steps it).
+    rows = [0, 1, 3] if attn_kernel == "gather" else [0, 1, 2, 3]
+    assert np.array_equal(np.asarray(got_logits, np.float32)[rows],
+                          np.asarray(want_logits, np.float32)[rows])
+    assert sorted(got) == sorted(want)
+    for name in want:
+        assert got[name].shape == cache[name].shape
+        assert np.array_equal(np.asarray(got[name], np.float32),
+                              np.asarray(want[name], np.float32)), name
+    # And the step did write: the pages of slots 0 and 3 at their
+    # positions, in every layer, and no other page of any layer.
+    changed = np.asarray(got["k"] != cache["k"]).any(axis=(2, 3, 4))
+    assert changed.tolist() == [[p in (2, 3) for p in range(12)]] * 2
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_verify_matches_per_layer_reference(nano, nano_params, kv_dtype):
+    """The speculative verify carries the same pool the decode step
+    does (the spec engine alternates the two): k+1 rows a slot, the
+    same per-layer reference, bit-equal logits-derived outputs and
+    pool."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode as gd
+    from ray_tpu.models.gpt import _project_vocab, _rmsnorm
+
+    k, S = 2, 3
+    cache, token, active, pt, ps = _pool_case(
+        nano, kv_dtype, 12, np.random.default_rng(32))
+    pos = cache["pos"]
+    draft = jnp.asarray([[1, 2], [3, 4], [5, 6], [7, 8]], jnp.int32)
+    rngs = jax.random.split(jax.random.PRNGKey(1), 4)
+    positions = pos[:, None] + jnp.arange(S)[None, :]
+    vp = positions // ps
+    page_w = jnp.where(
+        active[:, None] & (vp < pt.shape[1]),
+        jnp.take_along_axis(pt, jnp.clip(vp, 0, pt.shape[1] - 1), axis=1),
+        jnp.int32(gd.PT_SENTINEL))
+    V = pt.shape[1] * ps
+    valid = jnp.arange(V)[None, None, None, :] \
+        <= positions[:, None, :, None]
+    ptc = jnp.clip(pt, 0, 11)
+
+    def layer_fn(x, p, kc, vc, ksc, vsc):
+        q, kk, vv = gd._block_kv(x, p, nano)
+        if kv_dtype == "int8":
+            kc, ksc = gd._merge_span_int8(kc, ksc, kk, pt, pos, S,
+                                          active, ps)
+            vc, vsc = gd._merge_span_int8(vc, vsc, vv, pt, pos, S,
+                                          active, ps)
+            hk = gd._deq_page(kc[ptc], ksc[ptc], q.dtype)
+            hv = gd._deq_page(vc[ptc], vsc[ptc], q.dtype)
+        else:
+            kc = kc.at[page_w, positions % ps].set(kk, mode="drop")
+            vc = vc.at[page_w, positions % ps].set(vv, mode="drop")
+            hk, hv = kc[ptc], vc[ptc]
+        hk = hk.reshape(4, V, -1, nano.head_dim)
+        hv = hv.reshape(4, V, -1, nano.head_dim)
+        lg = jnp.einsum("bqhd,bkhd->bhqk", q, hk,
+                        preferred_element_type=jnp.float32) \
+            / jnp.sqrt(jnp.asarray(nano.head_dim, jnp.float32))
+        probs = jax.nn.softmax(jnp.where(valid, lg, -1e30),
+                               axis=-1).astype(q.dtype)
+        att = jnp.einsum("bhqk,bkhd->bqhd", probs, hv,
+                         preferred_element_type=jnp.float32
+                         ).astype(q.dtype).reshape(4, S, -1)
+        x = x + gd._mm_row(att, p["wo"]["kernel"], nano.dtype)
+        return gd._ffn(x, p, nano), (kc, vc, ksc, vsc)
+
+    @jax.jit
+    def reference(cache):
+        seq = jnp.concatenate([token[:, None], draft], axis=1)
+        x = nano_params["embed"]["kernel"].astype(nano.dtype)[seq]
+        x = x + nano_params["pos_embed"][positions].astype(nano.dtype)
+        x, pool = _per_layer_reference(nano_params, cache, layer_fn, x)
+        x = _rmsnorm(x, nano_params["ln_f_scale"])
+        logits = _project_vocab(x, nano_params["embed"]["kernel"], nano)
+        committed, n_acc, _ = gd._spec_accept(logits, draft, rngs, 0.0, k)
+        return committed, n_acc, pool
+
+    verify = jax.jit(lambda c: gd.verify_chunk_slots_paged(
+        nano_params, c, token, draft, rngs, active, pt, cfg=nano, k=k,
+        page_size=ps, kv_dtype=kv_dtype))
+    want_committed, want_acc, want = reference(cache)
+    committed, n_acc, got, _ = verify(cache)
+    rows = [0, 1, 3]     # slot 2 attends an unmapped page: noise, as above
+    assert np.array_equal(np.asarray(committed)[rows],
+                          np.asarray(want_committed)[rows])
+    assert np.array_equal(np.asarray(n_acc)[rows],
+                          np.asarray(want_acc)[rows])
+    for name in want:
+        assert got[name].shape == cache[name].shape
+        assert np.array_equal(np.asarray(got[name], np.float32),
+                              np.asarray(want[name], np.float32)), name
+    changed = np.asarray(got["k"] != cache["k"]).any(axis=(2, 3, 4))
+    assert changed.tolist() == [[p in (2, 3) for p in range(12)]] * 2
