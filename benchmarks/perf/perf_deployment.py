@@ -18,63 +18,31 @@ import threading
 import time
 
 
-def model_cfg(conf: dict):
-    """The program's ``GPTConfig`` at the sizes of a configuration file
-    (the one place that maps published names to the program's)."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models import gpt
-
-    m = conf["model"]
-    dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
-    return gpt.GPTConfig(
-        vocab_size=m["embedding_rows_held"], n_layer=m["n_layer"],
-        n_head=m["n_head"], d_model=m["n_embd"], d_ff=m["n_inner"],
-        max_seq=m["n_positions"],
-        dtype=dtypes[conf["numerics"]["compute_dtype"]],
-        param_dtype=dtypes[conf["numerics"]["param_dtype"]],
-        remat=conf.get("train", {}).get("remat", "dots"),
-        loss_chunk=conf.get("train", {}).get("loss_chunk", 0))
-
-
-def seeded_params(cfg, seed: int, init: dict):
+def seeded_params(arch, cfg, seed: int, init: dict):
     """Weights from the seed under ONE jit, on the device, in the type
-    the program holds them in. The tree (names, shapes, types) is the
-    program's own (``eval_shape`` of its ``init_params``); the values
-    are the GPT-2 initialisation the configuration file states (``init``:
-    the standard deviation by kind of leaf), drawn with the chip's
-    hardware generator (the ``rbg`` key type), which makes 1.3 G values
-    in a fraction of the time of the program's threefry-seeded init."""
-    import math
-
+    the program holds them in. The tree (names, shapes, types) and each
+    leaf's standard deviation are the architecture's
+    (``arch.param_shapes``, ``arch.leaf_std`` from the configuration
+    file's ``init``); the values are drawn with the chip's hardware
+    generator (the ``rbg`` key type), which makes 1.3 G values in a
+    fraction of the time of the program's threefry-seeded init."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import gpt
-
-    shapes = jax.eval_shape(lambda k: gpt.init_params(k, cfg),
-                            jax.random.PRNGKey(0))
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
-    L, d = cfg.n_layer, cfg.d_model
-    named = {"resid": 1.0 / math.sqrt(2 * L * d)}
-
-    def std(path: str, shape) -> float:
-        for part, val in init["std"].items():
-            if part in path:
-                return named.get(val, val) if isinstance(val, str) \
-                    else float(val)
-        return 1.0 / math.sqrt(shape[-2])         # fan-in of a matrix
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(
+        arch.param_shapes(cfg))
 
     def make(key):
         out = []
         for i, (path, leaf) in enumerate(leaves):
-            name = jax.tree_util.keystr(path)
-            if "scale" in name:
+            std = arch.leaf_std(cfg, init, jax.tree_util.keystr(path),
+                                leaf.shape)
+            if std is None:
                 out.append(jnp.ones(leaf.shape, leaf.dtype))
                 continue
             k = jax.random.fold_in(key, i)
             out.append((jax.random.normal(k, leaf.shape, jnp.float32)
-                        * std(name, leaf.shape)).astype(leaf.dtype))
+                        * std).astype(leaf.dtype))
         return jax.tree_util.tree_unflatten(treedef, out)
 
     key = jax.random.key(seed % (2 ** 31), impl="rbg")
@@ -208,7 +176,6 @@ def make_deployment(conf: dict, seed: int, require_tpu: bool,
     from ray_tpu import serve
 
     dep = conf["deployment"]
-    eng = conf["engine"]
 
     @serve.deployment(
         num_replicas=1,
@@ -219,8 +186,8 @@ def make_deployment(conf: dict, seed: int, require_tpu: bool,
     class PerfGPT:
         def __init__(self):
             t0 = time.monotonic()
+            import perf_harness
             from ray_tpu._private import chip
-            from ray_tpu.serve.engine import DecodeEngine
 
             self.device = chip.require_tpu() if require_tpu \
                 else chip.device_summary()
@@ -228,17 +195,13 @@ def make_deployment(conf: dict, seed: int, require_tpu: bool,
                   f"{self.device['platform']} device_kind="
                   f"{self.device['kind']!r} count={self.device['count']}",
                   flush=True)
-            self.cfg = model_cfg(conf)
+            self.arch = perf_harness.load_architecture(conf)
+            self.cfg = self.arch.model_cfg(conf)
             t1 = time.monotonic()
-            params = seeded_params(self.cfg, seed, conf["init"])
+            params = seeded_params(self.arch, self.cfg, seed,
+                                   conf["init"])
             t2 = time.monotonic()
-            self.engine = DecodeEngine(
-                params, self.cfg, slots=eng["slots"], chunk=eng["chunk"],
-                max_len=eng["max_len"],
-                prompt_buckets=tuple(eng["prompt_buckets"]),
-                paged=True, page_size=eng["page_size"],
-                n_pages=eng["n_pages"], prefix_cache=eng["prefix_cache"],
-                attn_kernel=eng["attn_kernel"], kv_dtype=eng["kv_dtype"])
+            self.engine = self.arch.make_engine(params, self.cfg, conf)
             self.timing = {"import_s": t1 - t0, "weights_s": t2 - t1,
                            "engine_s": time.monotonic() - t2}
             self.arrivals = {}
@@ -277,8 +240,8 @@ def make_deployment(conf: dict, seed: int, require_tpu: bool,
             import perf_reference_check
 
             return perf_reference_check.serve_check(
-                self.engine, self.cfg, conf, seed, n_prompt, n_steps,
-                served)
+                self.arch, self.engine, self.cfg, conf, seed, n_prompt,
+                n_steps, served)
 
         def trace_start(self) -> bool:
             import program_names

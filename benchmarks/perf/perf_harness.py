@@ -6,9 +6,19 @@ chip, which belongs to the replica (serving) or the training child.
 
 A cell is data. ``BENCHMARK.json`` names its configuration and traffic
 mix; ``configs/<config>.json``, ``traffic/<mix>.json`` and
-``layer_metrics/<metric>.py`` are found by those names, so a later PR
-adds a cell, a mix, a configuration or a per-layer metric by adding
-files and entries and edits nothing that is here.
+``layer_metrics/<metric>.py`` are found by those names, and so is the
+model: a configuration file's ``"architecture"`` (absent: ``"gpt2"``)
+names ``architectures/<name>.py``, the one file that knows the
+program's model code and its plain reference (``load_architecture``).
+So a later PR adds a cell, a mix, a configuration, a per-layer metric
+or a model by adding files and entries and edits nothing that is here.
+
+``correct`` holds a serving run to the reference twice: the logits of
+the served arithmetic within the configuration's ``logits_rel_tol``,
+and every distinct answer the engine gave to the check request ranked
+by the reference within twice that of its best logit at every token,
+with a control (the same tokens against another prompt's logits) that
+has to fail (``perf_reference_check.py``).
 """
 from __future__ import annotations
 
@@ -71,6 +81,16 @@ def load_mix(name: str, here: str = HERE) -> dict:
     return load_json(path)
 
 
+def load_file(path: str, prefix: str):
+    """The module in the file at ``path``, under a name of its own."""
+    stem = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location(
+        prefix + stem.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def load_reader(name: str, here: str = HERE):
     """The per-layer metric's own reader: ``layer_metrics/<name>.py``
     with ``read(run) -> number or None`` and the constants ``LAYER``,
@@ -78,11 +98,22 @@ def load_reader(name: str, here: str = HERE):
     path = os.path.join(here, "layer_metrics", name + ".py")
     if not os.path.exists(path):
         raise BenchError(f"no reader {path} for per-layer metric {name!r}")
-    spec = importlib.util.spec_from_file_location(
-        "perf_layer_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return load_file(path, "perf_layer_")
+
+
+def load_architecture(conf: dict, here: str = HERE):
+    """The one module that knows the configuration's model:
+    ``architectures/<name>.py`` for the configuration file's
+    ``"architecture"`` (absent: ``"gpt2"``). ``architectures/gpt2.py``
+    says what such a module provides. It imports jax and the program
+    inside its functions only: the driver of a serving cell loads it
+    too, and stays off the chip."""
+    name = conf.get("architecture", "gpt2")
+    path = os.path.join(here, "architectures", name + ".py")
+    if not os.path.exists(path):
+        raise BenchError(f"no architecture file {path} for configuration "
+                         f"{conf.get('name')!r}")
+    return load_file(path, "perf_arch_")
 
 
 def twin(name: str):

@@ -4,108 +4,78 @@ run inside the process that holds the chip, outside the measured window.
 Serving is held to the reference twice. ``serve_check`` compares LOGITS
 of the served arithmetic (the one tolerance that tells a cruder number
 format from bfloat16) and, in the same pass of the reference, takes
-tokens that came out of the ``DecodeEngine`` through the handle (chunk
-program, page tables, prefix cache, eviction) and asks whether each was
-a best token within a margin (``served_verdict``): a page of someone
-else's keys, or a step that is no longer the scanned one, yields tokens
-the reference ranks far below its best.
+the answers that came out of the engine through the handle (chunk
+program, page tables, prefix cache, eviction) and asks of EVERY
+distinct one whether each token was a best token within a margin
+(``served_verdict``): a page of someone else's keys, or a step that is
+no longer the scanned one, yields tokens the reference ranks far below
+its best. Two answers to one request may differ where the reference
+itself cannot tell their tokens apart (a whole prefill and a
+prefix-cache hit are two arithmetic paths in bfloat16); each still has
+to pass. A control from the same pass, the first answer against another
+prompt's logits, has to FAIL, so the check cannot go blind.
+
+Nothing here knows a model: the served arithmetic and the reference are
+the architecture module's (``perf_harness.load_architecture``).
 """
 from __future__ import annotations
 
 import time
 
 
-def serve_check(engine, cfg, conf: dict, seed: int, n_prompt: int,
+def serve_check(arch, engine, cfg, conf: dict, seed: int, n_prompt: int,
                 n_steps: int, served=None) -> dict:
-    """Two seeded sequences through the SERVED arithmetic — the paged
-    prefill program, then single decode steps through the paged cache
-    with the engine's attention kernel, on a small pool of its own —
-    against the reference's full forward pass: the logits right after
-    prefill and after ``n_steps`` cached decode steps.
+    """Two seeded sequences through the served arithmetic
+    (``arch.served_logits``) against the reference's full forward pass:
+    the logits right after prefill and after ``n_steps`` cached decode
+    steps, teacher-forced on the sequence's own tokens.
 
-    The paged prefill returns a token, not logits, so it is given the
-    prompt less its last token, and the first decode step (fed that
-    last token, reading the keys and values prefill wrote) yields the
-    logits "after prefill"; the tokens fed afterwards are the
-    sequence's own (teacher forcing), so both sides see the same
-    inputs. ``_slot_decode_step_paged`` is the program's step function
-    that the chunk program scans; it is read here because no public
-    entry returns logits.
-
-    ``served``: optionally (prompt, tokens) of a request the engine
-    answered at temperature 0. It rides in the same pass of the
-    reference as a third row (the reference is causal, so padding the
-    shorter rows on the right changes nothing), and ``served_verdict``
-    judges it: the result's ``"served"``.
+    ``served``: optionally (prompt, answers) of a request the engine
+    answered at temperature 0, ``answers`` being the distinct token
+    sequences it gave. Each rides in the same pass of the reference as
+    a row of its own (the reference is causal, so padding the shorter
+    rows on the right changes nothing), and ``served_verdicts`` judges
+    them, with the first seeded sequence's logits as the control: the
+    result's ``"served"``.
     """
-    import functools
-
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    import reference_gpt2
-    from ray_tpu.models import gpt_decode as gd
-
     t0 = time.monotonic()
-    ps = engine.page_size
-    vocab = conf["model"]["vocab_size"]
+    vocab = arch.vocab(conf)[0]
     total = n_prompt + n_steps
-    max_pages = -(-(total + 1) // ps)
     B = 2
     rng = np.random.default_rng([seed & (2 ** 63 - 1), 77])
     seqs = rng.integers(0, vocab, (B, total + 1)).astype(np.int32)
-    bucket = next(b for b in engine.prompt_buckets if b >= n_prompt - 1)
-    cache = gd.init_paged_cache(cfg, B, B * max_pages, ps,
-                                engine.kv_dtype)
-    pt = np.arange(B * max_pages, dtype=np.int32).reshape(B, max_pages)
-    prefill = gd.jit_prefill_into_slot_paged(cfg, ps, 0.0,
-                                             engine.kv_dtype)
-    step = jax.jit(functools.partial(
-        gd._slot_decode_step_paged, cfg=cfg, page_size=ps,
-        kv_dtype=engine.kv_dtype, attn_kernel=engine.attn_kernel))
-    params = engine.params
-    for b in range(B):
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :n_prompt - 1] = seqs[b, :n_prompt - 1]
-        _tok, cache, _key = prefill(
-            params, cache, padded, np.int32(n_prompt - 1), np.int32(0),
-            pt[b], np.int32(gd.PT_SENTINEL), np.int32(b),
-            jax.random.PRNGKey(0))
-    active = np.ones((B,), bool)
-    got = {}
-    for i in range(n_steps + 1):
-        pos = n_prompt - 1 + i
-        logits, cache = step(params, cache, jnp.asarray(seqs[:, pos]),
-                             active, jnp.asarray(pt))
-        if i in (0, n_steps):
-            got[i] = np.asarray(logits, np.float32)[:, :vocab]
+    got = arch.served_logits(engine, cfg, seqs, n_prompt, n_steps)
     rows = [seqs[b, :total] for b in range(B)]
     if served is not None:
         s_prompt = np.asarray(served[0], np.int32)
-        s_tokens = np.asarray(served[1], np.int32)
-        rows.append(np.concatenate([s_prompt, s_tokens[:-1]]))
+        answers = [np.asarray(a, np.int32) for a in served[1]]
+        rows += [np.concatenate([s_prompt, a[:-1]]) for a in answers]
     width = max(len(r) for r in rows)
     ref_in = np.zeros((len(rows), width), np.int32)
     for b, r in enumerate(rows):
         ref_in[b, :len(r)] = r
-    at = [n_prompt - 1, n_prompt - 1 + n_steps]
-    ref_all = jax.jit(functools.partial(reference_gpt2.forward,
-                                        n_head=cfg.n_head))(
-        reference_gpt2.from_program(params), jnp.asarray(ref_in))
-    ref = {i: np.asarray(ref_all[:B, pos], np.float32)[:, :vocab]
-           for i, pos in zip((0, n_steps), at)}
+    from_program, forward, _loss = arch.reference(cfg)
+    ref_all = jax.jit(forward)(from_program(engine.params),
+                               jnp.asarray(ref_in))
     out = {"seconds": None, "checks": []}
     tol = conf["correct"]["logits_rel_tol"]
     if served is not None:
         first = len(s_prompt) - 1
-        out["served"] = served_verdict(
-            np.asarray(ref_all[B, first:first + len(s_tokens)],
-                       np.float32), s_tokens, tol)
+        n = len(answers[0])
+        out["served"] = served_verdicts(
+            [np.asarray(ref_all[B + j, first:first + len(a)], np.float32)
+             for j, a in enumerate(answers)], answers, tol,
+            control=np.asarray(ref_all[0, max(0, total - n):total],
+                               np.float32))
     ok = True
-    for i, name in ((0, "after_prefill"), (n_steps, "after_decode")):
-        want = ref[i]
-        err = float(np.abs(got[i] - want).max())
+    for i, pos, name in ((0, n_prompt - 1, "after_prefill"),
+                         (n_steps, total - 1, "after_decode")):
+        want = np.asarray(ref_all[:B, pos], np.float32)[:, :vocab]
+        err = float(np.abs(got[i][:, :vocab] - want).max())
         scale = float(np.abs(want).max())
         rel = err / scale
         ok = ok and rel <= tol
@@ -148,23 +118,46 @@ def served_verdict(ref_logits, tokens, tol: float) -> dict:
             "logit_std": float(ref.std())}
 
 
-def train_check(params, batch_rows, cfg, mesh, n_head: int,
-                program_loss_fn) -> dict:
+def served_verdicts(ref_logits: list, answers: list, tol: float,
+                    control) -> dict:
+    """Every distinct answer to the one check request against the
+    reference's logits along its own tokens (``ref_logits[j]`` [N, rows]
+    for ``answers[j]`` [N]): each has to pass ``served_verdict``. The
+    entry is the first answer's verdict, with ``ok`` and ``max_gap``
+    over all of them. ``control`` [N, rows] is the reference's logits
+    for ANOTHER prompt: judged against them the first answer has to
+    fail, or the verdict tells nothing and the run is not correct.
+    Where two answers differ, ``parted`` says where the second first
+    left the first and how far apart the reference ranks the two tokens
+    there (both rows hold the same tokens up to that position): a
+    near-tie is closer than the margin."""
+    import numpy as np
+
+    each = [served_verdict(r, a, tol) for r, a in zip(ref_logits, answers)]
+    ctrl = served_verdict(control, answers[0][:len(control)], tol)
+    out = dict(each[0], distinct=len(answers),
+               max_gap=max(v["max_gap"] for v in each),
+               control_max_gap=ctrl["max_gap"],
+               control_margin=ctrl["margin"])
+    if len(answers) > 1:
+        out["each"] = each
+        at = int(np.flatnonzero(answers[0] != answers[1])[0])
+        row = np.asarray(ref_logits[0][at], np.float32)
+        out["parted"] = {"at": at, "ref_gap": float(abs(
+            row[answers[0][at]] - row[answers[1][at]]))}
+    out["ok"] = bool(all(v["ok"] for v in each) and not ctrl["ok"])
+    return out
+
+
+def train_check(arch, params, batch_rows, cfg, program_loss) -> dict:
     """The program's loss on a few rows of the batch against the
     reference's loss on the same rows, both on the sharded weights."""
-    import functools
-
     import jax
 
-    import reference_gpt2
-
     t0 = time.monotonic()
-    sys_loss = float(jax.jit(
-        lambda p, t: program_loss_fn(p, {"tokens": t}, cfg, mesh)[0])(
-            params, batch_rows))
-    ref_loss = float(jax.jit(functools.partial(
-        reference_gpt2.loss, n_head=n_head))(
-            reference_gpt2.from_program(params), batch_rows))
+    from_program, _forward, loss = arch.reference(cfg)
+    sys_loss = float(jax.jit(program_loss)(params, batch_rows))
+    ref_loss = float(jax.jit(loss)(from_program(params), batch_rows))
     return {"program_loss": sys_loss, "reference_loss": ref_loss,
             "abs_err": abs(sys_loss - ref_loss),
             "seconds": time.monotonic() - t0}

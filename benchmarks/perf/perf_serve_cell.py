@@ -56,13 +56,18 @@ def run(found: dict, seed: int, seconds: float, trace: int,
     core = rt.init(num_cpus=8, num_tpus=None if require_tpu else 0)
     mark("rt_init")
     stamps = perf_loadgen.Stamps()
-    vocab = conf["model"]["vocab_size"]
+    vocab, rows_held = H.load_architecture(conf).vocab(conf)
     ps = conf["engine"]["page_size"]
     pid0 = None
     try:
+        # no call through the handle carries a deadline of the
+        # benchmark's making, set-up calls included: a reference pass
+        # that compiles takes a minute, the handle's default is 60 s
+        no_deadline = float(mix.get("timeout_s", 86400.0))
         handle = serve.run(perf_deployment.make_deployment(
             conf, seed, require_tpu,
-            os.path.join(out, "trace")).bind(), _proxy=False)
+            os.path.join(out, "trace")).bind(), _proxy=False).options(
+                timeout_s=no_deadline)
         first = handle.report.remote().result()
         pid0 = first["pid"]
         mark("replica_ready")
@@ -80,7 +85,7 @@ def run(found: dict, seed: int, seconds: float, trace: int,
                         max_new=row["max_new"],
                         tokens_before=perf_metrics.n_tokens(row))
 
-        send = _send_fn(handle, float(mix.get("timeout_s", 86400.0)))
+        send = _send_fn(handle, no_deadline)
 
         # ---- set-up traffic: the check request, the cache fill, the
         # reference. All of it warms the served path end to end.
@@ -108,11 +113,11 @@ def run(found: dict, seed: int, seconds: float, trace: int,
         send_check()            # after the fill's evictions
         send_check()            # a hit on pages that were reused
         engine_ck = _served_check(conf, mix, served, counters)
+        answers = engine_ck.pop("answers")
         ref = handle.reference_check.remote(
             ck["prompt_tokens"], ck["decode_steps"],
             ([int(t) for t in perf_traffic.tokens_for(rep, seed, vocab)],
-             [int(t) for t in served[0]])
-            if engine_ck["identical"] else None).result()
+             answers) if answers else None).result()
         engine_ck["reference"] = ref.get("served") or {"ok": False}
         engine_ck["ok"] = bool(engine_ck.pop("engine_ok")
                                and engine_ck["reference"]["ok"])
@@ -176,8 +181,7 @@ def run(found: dict, seed: int, seconds: float, trace: int,
               "preempted": st2["preempted"], "resumed": st2["resumed"],
               "expired": st2["expired"], "abandoned": st2["abandoned"],
               "pid_before": pid0, "pid_after": final["pid"]}
-    faults = perf_metrics.stream_faults(
-        rows, conf["model"]["embedding_rows_held"])
+    faults = perf_metrics.stream_faults(rows, rows_held)
     window_fail = [r for r in rows if r.get("error")
                    and r["error_t"] <= t_close]
     correct = bool(ref["ok"] and engine_ck["ok"] and not faults
@@ -226,30 +230,40 @@ def _served_check(conf: dict, mix: dict, served: list,
     """What the engine did with the check request, which went through
     the handle four times: into fresh pages, as a hit on them, after
     the cache fill had evicted, and as a hit on pages that were used
-    before. All four answers have to be the same tokens (the reference
-    then judges them: ``perf_reference_check.served_verdict``).
-    ``counters`` are ``engine.stats()`` before the first and after each
-    send; where the fill is larger than the pool they have to show the
-    evictions and the later hit, or the check did not see what it is
-    for and the run is not ``correct``."""
+    before. All four answers have to be whole (``repeat_answer``
+    tokens); they need not be the same tokens, because a whole prefill
+    and a prefix-cache hit are two arithmetic paths and may part at a
+    near-tie: the reference judges every distinct one (``answers``, in
+    the order they first came: ``perf_reference_check.
+    served_verdicts``). ``counters`` are ``engine.stats()`` before the
+    first and after each send; where the fill is larger than the pool
+    they have to show the evictions and the later hit, or the check did
+    not see what it is for and the run is not ``correct``."""
     want = conf["correct"]["repeat_answer"]
 
     def moved(key, a, b):
         return counters[b].get(key, 0) - counters[a].get(key, 0)
 
-    same = len(served) == 4 and len(counters) == 5 and all(
-        len(t) == want and (t == served[0]).all() for t in served)
-    evictions = moved("prefix_evictions", 0, 3) if same else 0
-    out = {"identical": bool(same), "evictions_before_resend": evictions,
-           "hit_fresh": bool(same
+    complete = len(served) == 4 and len(counters) == 5 and all(
+        len(t) == want for t in served)
+    answers = []
+    for t in served if complete else []:
+        t = [int(x) for x in t]
+        if t not in answers:
+            answers.append(t)
+    evictions = moved("prefix_evictions", 0, 3) if complete else 0
+    out = {"complete": complete, "answers": answers,
+           "identical": len(answers) == 1,
+           "evictions_before_resend": evictions,
+           "hit_fresh": bool(complete
                              and moved("prefix_tokens_reused", 1, 2) > 0),
            "hit_after_eviction": bool(
-               same and evictions > 0
+               complete and evictions > 0
                and moved("prefix_tokens_reused", 3, 4) > 0),
            "expected_hit_after_eviction": bool(
                conf["engine"].get("prefix_cache")
                and mix.get("fill_pages", 0) >= conf["engine"]["n_pages"])}
-    out["engine_ok"] = bool(same and (
+    out["engine_ok"] = bool(complete and (
         out["hit_after_eviction"]
         or not out["expected_hit_after_eviction"]))
     return out

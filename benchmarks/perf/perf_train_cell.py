@@ -61,8 +61,6 @@ def child(args) -> int:
 
     import perf_deployment
     import perf_reference_check
-    from ray_tpu.models import gpt
-    from ray_tpu.parallel import create_mesh
 
     device = chip.require_tpu() if args.require_tpu \
         else chip.device_summary()
@@ -76,23 +74,24 @@ def child(args) -> int:
     conf = H.load_json(args.config_file)
     mix = H.load_mix(args.mix)
     tr = conf["train"]
-    cfg = perf_deployment.model_cfg(conf)
+    arch = H.load_architecture(conf)
+    cfg = arch.model_cfg(conf)
     n = args.chips
-    mesh = create_mesh({tr["mesh_axis"]: n}, devices=jax.devices()[:n])
-    init, step, _state_sh, batch_sh = gpt.make_train_step(cfg, mesh)
+    prog = arch.train_program(cfg, conf, jax.devices()[:n])
+    step, batch_sh = prog["step"], prog["batch_sharding"]
     t_a = time.monotonic()
-    state = init(jax.random.PRNGKey(args.seed % (2 ** 31)))
+    state = prog["init"](jax.random.PRNGKey(args.seed % (2 ** 31)))
     B, S = tr["global_batch"], tr["seq"]
     rng = np.random.default_rng([args.seed & (2 ** 63 - 1), 5])
-    host_tokens = rng.integers(0, conf["model"]["vocab_size"],
+    host_tokens = rng.integers(0, arch.vocab(conf)[0],
                                (B, S + 1)).astype(np.int32)
     data = {"tokens": jax.device_put(host_tokens, batch_sh)}
     jax.block_until_ready(state)
     t_b = time.monotonic()
     rows = conf["correct"]["reference_rows"]
     ref = perf_reference_check.train_check(
-        state["params"], jax.device_put(host_tokens[:rows], batch_sh),
-        cfg, mesh, cfg.n_head, gpt.loss_fn)
+        arch, state["params"],
+        jax.device_put(host_tokens[:rows], batch_sh), cfg, prog["loss"])
     ref["ok"] = ref["abs_err"] <= conf["correct"]["loss_abs_tol"]
     losses = []
     for _ in range(int(mix.get("warm_steps", 2))):

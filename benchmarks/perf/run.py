@@ -9,6 +9,20 @@ prints, as the last line of its standard output, one JSON object with
 ``breakdown`` when traced). With ``--trace 0`` the metrics are the
 cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics.
 
+A cell's files are found by name (``perf_harness.py``): its
+configuration, its traffic mix, its per-layer readers, and through the
+configuration's ``"architecture"`` the one file that knows its model
+and reference (``architectures/<name>.py``; absent: ``gpt2``).
+
+``correct`` holds a serving run to the plain reference: the served
+arithmetic's logits within the configuration's tolerance, and every
+distinct answer the engine gave the check request (sent four times:
+fresh pages, a prefix-cache hit, after eviction, a hit on reused pages)
+within twice that tolerance of the reference's best logit at each
+token. Answers to one request may differ at a near-tie of the
+reference; a control (the answer against another prompt's logits) has
+to fail. Every number compared is printed beside its limit (``SETUP``).
+
 This process never imports jax: the chip belongs to the replica
 (serving) or the training child. No TPU, or fewer chips than the cell
 asks for: a non-zero exit and no result line.

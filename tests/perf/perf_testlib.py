@@ -20,9 +20,12 @@ def benchmark():
 
 
 def copy_with_additions(tmp, *, configs=(), mixes=(), readers=(),
-                        cells=(), metrics=(), join=None):
+                        architectures=(), cells=(), metrics=(),
+                        join=None):
     """A copy of BENCHMARK.json and benchmarks/perf under ``tmp``, plus
-    new files and new entries only. Returns the copy's root."""
+    new files and new entries only. Returns the copy's root.
+    ``architectures``: (file name under ``architectures/``, path of the
+    file to copy there): a model and the reference beside it."""
     root = os.path.join(str(tmp), "co")
     shutil.copytree(PERF, os.path.join(root, "benchmarks", "perf"),
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -39,6 +42,11 @@ def copy_with_additions(tmp, *, configs=(), mixes=(), readers=(),
     for name, path in mixes:
         dst = os.path.join(root, "benchmarks", "perf", "traffic",
                            name + ".json")
+        assert not os.path.exists(dst)
+        shutil.copy(path, dst)
+    for name, path in architectures:
+        dst = os.path.join(root, "benchmarks", "perf", "architectures",
+                           name)
         assert not os.path.exists(dst)
         shutil.copy(path, dst)
     for name, text in readers:

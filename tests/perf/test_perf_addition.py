@@ -1,6 +1,7 @@
-"""A later PR adds a configuration, a mix, a per-layer metric and a
-cell as new files and new entries and edits nothing that is there: a
-temporary copy gets a dummy of each and runs, at ``nano``, on the CPU.
+"""A later PR adds a configuration, a mix, a per-layer metric, a cell
+and a MODEL as new files and new entries and edits nothing that is
+there: a temporary copy gets a dummy of each and runs, at ``nano``, on
+the CPU.
 
 These are REHEARSALS: they prove the command's control flow and its one
 result line. No number they print is a measurement of any device.
@@ -29,21 +30,32 @@ def _cell(name, config, traffic, chips=1):
             "chips": chips, "why": "test"}
 
 
+def _fixture(name):
+    return os.path.join(L.FIXTURES, name)
+
+
+#: a second architecture: its module, and its reference beside it
+DUMMY_ARCH = [("dummy.py", _fixture("dummy_arch.py")),
+              ("dummy_reference.py", _fixture("dummy_arch_reference.py"))]
+
+
 @pytest.fixture(scope="module")
 def serve_copy(tmp_path_factory):
     return L.copy_with_additions(
         tmp_path_factory.mktemp("perf_serve"),
-        configs=[("nano-serve", os.path.join(L.FIXTURES,
-                                             "nano-serve.json"))],
-        mixes=[("nano-chat", os.path.join(L.FIXTURES,
-                                          "nano-chat.json"))],
+        configs=[("nano-serve", _fixture("nano-serve.json")),
+                 ("dummy-serve", _fixture("dummy-serve.json"))],
+        mixes=[("nano-chat", _fixture("nano-chat.json"))],
         readers=[("dummy_attempted", READER)],
-        cells=[_cell("nano-chat", "nano-serve", "nano-chat")],
+        architectures=DUMMY_ARCH,
+        cells=[_cell("nano-chat", "nano-serve", "nano-chat"),
+               _cell("dummy-chat", "dummy-serve", "nano-chat")],
         metrics=[("per_layer", {
             "name": "dummy_attempted", "unit": "1", "better": "higher",
             "source": "host_clock", "layer": "load generator",
             "moves": "tpot_p90_ms", "workloads": ["nano-chat"]})],
-        join={"nano-chat": "cgpt1b3-chat-steady"})
+        join={"nano-chat": "cgpt1b3-chat-steady",
+              "dummy-chat": "cgpt1b3-chat-steady"})
 
 
 def test_nothing_that_was_there_is_edited(serve_copy):
@@ -92,6 +104,47 @@ def test_whole_run_of_the_added_chat_cell_ends_in_one_result_line(
     assert "busy_s" not in res["device"]
 
 
+def test_a_model_added_as_files_serves_under_its_own_reference(
+        serve_copy):
+    """``architectures/dummy.py``, ``dummy_reference.py`` and a
+    configuration that names them (its ``model`` block under key names
+    of its own) were ADDED to the copy; the cell runs and is judged by
+    the dummy's reference."""
+    import perf_harness as H
+
+    rc, out, err = L.run_copy(
+        serve_copy, "--workload", "dummy-chat", "--seed",
+        str(2 ** 31 + 9), "--seconds", "3", "--trace", "0",
+        "--rehearsal")
+    assert rc == 0, (out[-5:], err[-2000:])
+    res = json.loads(out[-1])
+    assert res["correct"] is True and res["failed"] == 0
+    assert set(res["metrics"]) == {"tpot_p90_ms", "setup_s"}
+    setup = json.loads(next(ln for ln in out
+                            if ln.startswith("SETUP "))[6:])
+    served = setup["served_check"]["reference"]
+    assert served["ok"] and served["distinct"] >= 1
+    assert served["control_max_gap"] > served["control_margin"]
+    assert all(c["rel"] <= c["tol"] for c in setup["reference"])
+    # found by name, in the copy, with the reference beside it
+    conf = H.load_json(_fixture("dummy-serve.json"))
+    arch = H.load_architecture(conf, here=os.path.join(
+        serve_copy, "benchmarks", "perf"))
+    assert arch.vocab(conf) == (500, 512)
+    _, forward, loss = arch.reference(arch.model_cfg(conf))
+    assert forward.func.__module__ == loss.func.__module__ \
+        == "perf_arch_dummy_reference"
+
+
+def test_a_configuration_that_names_no_architecture_file_fails(
+        serve_copy):
+    import perf_harness as H
+
+    assert H.load_architecture({}).__name__ == "perf_arch_gpt2"
+    with pytest.raises(H.BenchError, match="no architecture file"):
+        H.load_architecture({"name": "x", "architecture": "absent"})
+
+
 def test_without_a_tpu_the_command_fails_and_prints_no_result(
         serve_copy):
     rc, out, err = L.run_copy(
@@ -109,13 +162,15 @@ def test_unknown_workload_fails_without_a_result(serve_copy):
     assert rc != 0 and not out
 
 
+@pytest.mark.parametrize("config", ["nano-fsdp4", "dummy-fsdp4"])
 def test_added_training_cell_runs_on_four_virtual_devices(
-        tmp_path_factory):
+        tmp_path_factory, config):
+    """``dummy-fsdp4``: the training cell of the model added as files."""
     root = L.copy_with_additions(
         tmp_path_factory.mktemp("perf_train"),
-        configs=[("nano-fsdp4", os.path.join(L.FIXTURES,
-                                             "nano-fsdp4.json"))],
-        cells=[_cell("nano-train", "nano-fsdp4", "train-steps", 4)],
+        configs=[(config, _fixture(config + ".json"))],
+        architectures=DUMMY_ARCH,
+        cells=[_cell("nano-train", config, "train-steps", 4)],
         join={"nano-train": "cgpt1b3-train-fsdp4"})
     rc, out, err = L.run_copy(
         root, "--workload", "nano-train", "--seed", "5", "--seconds",
@@ -125,3 +180,45 @@ def test_added_training_cell_runs_on_four_virtual_devices(
     assert res["device"]["count"] == 4 and res["correct"] is True
     assert res["attempted"] > 3 and res["rehearsal"] is True
     assert set(res["metrics"]) == {"train_tokens_per_s", "setup_s"}
+
+
+#: read from the parent of PR 27 (``perf_deployment.model_cfg`` and
+#: ``seeded_params``, seed 2**31 + 11) before they moved behind
+#: ``architectures/gpt2.py``
+PARENT_CFG = dict(vocab_size=512, n_layer=2, n_head=2, d_model=64,
+                  d_ff=256, max_seq=128, dtype="bfloat16",
+                  param_dtype="float32", remat="dots")
+PARENT_WEIGHTS = \
+    "e2aee54930b223b9e716ed2b4a72e7fa1ee5414af820f719b945195e03f43542"
+
+
+@pytest.mark.parametrize("fixture,loss_chunk", [("nano-serve", 0),
+                                                ("nano-fsdp4", 32)])
+def test_gpt2_config_and_seeded_weights_did_not_move(fixture,
+                                                     loss_chunk):
+    import dataclasses
+    import hashlib
+
+    import jax
+    import numpy as np
+
+    import perf_deployment
+    import perf_harness as H
+
+    conf = H.load_json(_fixture(fixture + ".json"))
+    arch = H.load_architecture(conf)
+    cfg = arch.model_cfg(conf)
+    got = dataclasses.asdict(cfg)
+    for key in ("dtype", "param_dtype"):
+        got[key] = np.dtype(got[key]).name
+    assert {k: got[k] for k in PARENT_CFG} == PARENT_CFG
+    assert got["loss_chunk"] == loss_chunk and got["n_experts"] == 0
+    params = perf_deployment.seeded_params(arch, cfg, 2 ** 31 + 11,
+                                           conf["init"])
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        a = np.asarray(leaf)
+        for part in (jax.tree_util.keystr(path), a.dtype, a.shape):
+            h.update(str(part).encode())
+        h.update(a.tobytes())
+    assert h.hexdigest() == PARENT_WEIGHTS

@@ -90,12 +90,16 @@ def _counters(evictions, reused):
 
 
 @pytest.mark.parametrize("case,ok", [
-    ("all_good", True), ("one_answer_differs", False),
-    ("a_stream_was_lost", False),
+    ("all_good", True), ("one_answer_differs", True),
+    ("a_stream_was_lost", False), ("an_answer_is_short", False),
     ("no_eviction_where_the_fill_exceeds_the_pool", False),
     ("no_hit_after_the_eviction", False),
     ("small_fill_needs_no_eviction", True)])
-def test_served_check_needs_same_tokens_and_a_hit_after_eviction(case, ok):
+def test_served_check_needs_whole_answers_and_a_hit_after_eviction(
+        case, ok):
+    """The engine's side of the check: four whole answers and the
+    counters. Answers that differ are not the engine's fault to find:
+    each distinct one goes to the reference (``answers``)."""
     import numpy as np
 
     import perf_serve_cell
@@ -109,6 +113,8 @@ def test_served_check_needs_same_tokens_and_a_hit_after_eviction(case, ok):
         served[3] = np.array([5, 6, 8])
     elif case == "a_stream_was_lost":
         served.pop()
+    elif case == "an_answer_is_short":
+        served[1] = np.array([5, 6])
     elif case == "no_eviction_where_the_fill_exceeds_the_pool":
         counters = _counters([0] * 5, [0, 0, 16, 16, 32])
     elif case == "no_hit_after_the_eviction":
@@ -118,8 +124,11 @@ def test_served_check_needs_same_tokens_and_a_hit_after_eviction(case, ok):
         counters = _counters([0] * 5, [0, 0, 16, 32, 48])
     got = perf_serve_cell._served_check(conf, mix, served, counters)
     assert got["engine_ok"] is ok, got
-    assert got["identical"] is (case not in ("one_answer_differs",
-                                             "a_stream_was_lost"))
+    whole = case not in ("a_stream_was_lost", "an_answer_is_short")
+    assert got["complete"] is whole
+    assert got["identical"] is (whole and case != "one_answer_differs")
+    assert got["answers"] == ([] if not whole else [[5, 6, 7]] + (
+        [[5, 6, 8]] if case == "one_answer_differs" else []))
 
 
 def test_on_the_chip_a_listed_metric_that_reads_nothing_fails_the_run():

@@ -34,6 +34,26 @@ def test_reference_shares_no_code_with_the_program():
     assert "import ray_tpu" not in src and "from ray_tpu" not in src
 
 
+def test_only_the_architecture_file_knows_the_model():
+    """The harness finds a model by name: nothing under
+    ``benchmarks/perf`` but ``architectures/gpt2.py`` imports the
+    program's model code or names the reference module."""
+    knows = []
+    for folder, _dirs, files in os.walk(perf_testlib.PERF):
+        for name in files:
+            if not name.endswith(".py") or name == "reference_gpt2.py":
+                continue
+            with open(os.path.join(folder, name)) as f:
+                src = f.read()
+            code = [ln for ln in src.splitlines()
+                    if ln.lstrip().startswith(("import ", "from "))]
+            if any("ray_tpu.models" in ln or "reference_gpt2" in ln
+                   or "ray_tpu.serve.engine" in ln for ln in code):
+                knows.append(os.path.relpath(
+                    os.path.join(folder, name), perf_testlib.PERF))
+    assert knows == [os.path.join("architectures", "gpt2.py")]
+
+
 def test_forward_agrees_with_the_program_in_float32(nano):
     import jax
 
@@ -155,3 +175,107 @@ def test_parameter_count_is_the_programs_and_costs_follow_shapes(nano):
     assert b1 - b0 == 2 * 24 * 2048 * 2 * 1000
     assert kernel_costs.train_flops_per_token(model, 2048) == \
         6 * kernel_costs.n_params(model) + 12 * 24 * 2048 * 2048
+
+
+def _synthetic(rng, n=9, rows=300, best=4.0):
+    """Reference logits [n, rows] of standard deviation ~0.9 whose best
+    token at every position is known, with the greedy answer."""
+    ref = rng.normal(0.0, 0.9, (n, rows)).astype(np.float32).clip(-3, 3)
+    answer = rng.integers(0, rows, n)
+    ref[np.arange(n), answer] = best
+    return ref, answer
+
+
+@pytest.mark.parametrize("case,ok", [
+    ("one_answer", True),
+    ("parted_at_a_near_tie", True),
+    ("parted_where_the_reference_is_sure", False),
+    ("an_answer_from_another_prompt", False),
+    ("a_control_that_passes", False)])
+def test_every_distinct_answer_meets_the_reference(case, ok):
+    """``served_verdicts`` on planted reference logits: answers may
+    part where the reference cannot tell their tokens apart and nowhere
+    else; each is judged along its own tokens; and a control that
+    passes (the check has gone blind) makes the run not correct."""
+    import perf_reference_check as C
+
+    tol = _serve_tol()
+    rng = np.random.default_rng(27)
+    ref, first = _synthetic(rng)
+    other_ref, other = _synthetic(rng)
+    margin = 2 * tol * 4.0
+    refs, answers, control = [ref], [first], other_ref
+    if case.startswith("parted"):
+        gap = 0.1 * margin if case == "parted_at_a_near_tie" \
+            else 4 * margin
+        second = first.copy()
+        second[6] = (first[6] + 1) % ref.shape[1]
+        ref[6, second[6]] = 4.0 - gap
+        # the second answer's own row: the same up to where it parts,
+        # then logits of its own, of which it picks the best
+        ref2, tail = _synthetic(rng)
+        ref2[:7] = ref[:7]
+        second[7:] = tail[7:]
+        refs, answers = [ref, ref2], [first, second]
+    elif case == "an_answer_from_another_prompt":
+        refs, answers = [ref, ref], [first, other]
+    elif case == "a_control_that_passes":
+        control = ref
+    got = C.served_verdicts(refs, answers, tol, control)
+    assert got["ok"] is ok, got
+    assert got["distinct"] == len(answers)
+    assert got["margin"] == pytest.approx(margin)
+    if case.startswith("parted"):
+        assert got["parted"]["at"] == 6
+        assert got["parted"]["ref_gap"] == pytest.approx(gap, rel=1e-3)
+        assert got["max_gap"] == pytest.approx(gap, rel=1e-3)
+        assert [v["ok"] for v in got["each"]] == [True, ok]
+    if case == "an_answer_from_another_prompt":
+        assert got["max_gap"] > 3 * margin and got["each"][0]["ok"]
+    if case == "a_control_that_passes":
+        assert got["control_max_gap"] <= got["control_margin"]
+        assert got["max_gap"] == 0.0          # the answer itself is sound
+    else:
+        assert got["control_max_gap"] > 3 * got["control_margin"]
+
+
+def test_a_planted_page_fault_reads_not_correct_through_the_engine(
+        tmp_path):
+    """At ``nano`` through the handle and the engine on the CPU: an
+    engine whose prefix-cache hits read their pages in the wrong order
+    (``fixtures/faulty_pages.py``, added to a copy as an architecture)
+    answers the check request's resends from other positions' keys. The
+    answers differ, which alone no longer fails a run; the reference
+    ranks the wrong one far outside the margin, and that does."""
+    with open(os.path.join(perf_testlib.FIXTURES, "nano-serve.json")) as f:
+        conf = json.load(f)
+    conf["architecture"] = "faulty_pages"
+    conf_path = os.path.join(str(tmp_path), "nano-faulty.json")
+    with open(conf_path, "w") as f:
+        json.dump(conf, f)
+    root = perf_testlib.copy_with_additions(
+        tmp_path, configs=[("nano-faulty", conf_path)],
+        mixes=[("nano-chat", os.path.join(perf_testlib.FIXTURES,
+                                          "nano-chat.json"))],
+        architectures=[("faulty_pages.py", os.path.join(
+            perf_testlib.FIXTURES, "faulty_pages.py"))],
+        cells=[{"name": "nano-faulty", "config": "nano-faulty",
+                "traffic": "nano-chat", "chips": 1, "why": "test"}],
+        join={"nano-faulty": "cgpt1b3-chat-steady"})
+    rc, out, err = perf_testlib.run_copy(
+        root, "--workload", "nano-faulty", "--seed", str(2 ** 31 + 7),
+        "--seconds", "2", "--trace", "0", "--rehearsal")
+    assert rc == 0, (out[-5:], err[-2000:])
+    res = json.loads(out[-1])
+    assert res["correct"] is False and res["failed"] == 0
+    check = json.loads(next(ln for ln in out
+                            if ln.startswith("SERVED-CHECK "))[13:])
+    ref = check["reference"]
+    assert check["complete"] and not check["identical"]
+    assert ref["distinct"] == 2 and not ref["ok"]
+    assert ref["each"][0]["ok"] and not ref["each"][1]["ok"]
+    assert ref["max_gap"] > 3 * ref["margin"]
+    # the arithmetic itself is sound: only the tokens give the fault away
+    setup = json.loads(next(ln for ln in out
+                            if ln.startswith("SETUP "))[6:])
+    assert all(c["rel"] <= c["tol"] for c in setup["reference"])
