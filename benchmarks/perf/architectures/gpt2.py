@@ -1,6 +1,10 @@
-"""The GPT-2-shaped decoder of ``ray_tpu/models/gpt.py``: the ONE file
-under ``benchmarks/perf`` that imports the program's model code and
-names its plain reference (``reference_gpt2.py``).
+"""The GPT-2-shaped decoder of ``ray_tpu/models/gpt.py``, and the
+contract of an architecture module. Under ``benchmarks/perf`` only the
+files of ``architectures/`` import the program's model or engine code,
+and each names no plain reference but its own (this one's is
+``reference_gpt2.py``, beside the harness since PR 23; a new one lies
+beside its module as ``<name>_reference.py``). ``tests/perf`` holds the
+tree and a rehearsal copy with a second architecture to both.
 
 An architecture module is found by the configuration file's
 ``"architecture"`` (``perf_harness.load_architecture``; absent means
@@ -24,12 +28,27 @@ a module provides, all of it functions of the configuration file
     the serving engine for ``conf["engine"]``: ``stats()``, and what
     ``@serve.batch(continuous=True)`` asks of an engine.
 ``served_logits(engine, cfg, seqs, n_prompt, n_steps)``
-    the served arithmetic, for the logits comparison.
+    the served arithmetic, for the logits comparison: what it computed,
+    always. It leaves nothing out and replaces nothing.
 ``reference(cfg)``
     ``(from_program, forward, loss)`` of the plain reference at
     ``cfg``'s sizes: ``forward(from_program(params), tokens[B, S])`` is
     float32 logits ``[B, S, rows]``, ``loss(from_program(params),
     tokens[B, S + 1])`` the mean next-token cross-entropy.
+``decidable(cfg, conf)``  (optional; this module has none)
+    for a model that makes discrete choices (the experts it holds among
+    a layer's top k): a function ``(from_program(params), tokens[B, S])
+    -> bool[B, S]`` of the REFERENCE's parameters and the token rows
+    alone, float32 at precision ``highest``, that says for every row
+    and position whether every such choice the reference makes for the
+    logits there, in every layer, clears the edge of its selection by
+    ``conf["correct"]["tie_eps"]``. A bfloat16 program and the float32
+    reference choose differently at a near-tie and then differ by a
+    whole expert's part; the harness (``perf_reference_check``), never
+    this module and never the program, leaves the logit vectors and
+    served tokens at undecidable positions out of ``correct``, counts
+    them (``compared``, ``left_out``) and refuses a run that compared
+    too few. A module without it leaves nothing out.
 ``train_program(cfg, conf, devices)``
     a training configuration's mesh, jitted ``init(key) -> state`` with
     ``state["params"]``, ``step(state, {"tokens": t}) -> (state,
@@ -37,6 +56,31 @@ a module provides, all of it functions of the configuration file
     program's ``loss(params, tokens)``.
 
 jax and the program are imported inside the functions.
+
+What a configuration's file states (``tests/perf/perf_testlib.py:
+check_configuration`` holds every file to it):
+
+``source.url``  the entry's ``source`` in ``BENCHMARK.json``.
+``reduced``     the entry's ``reduced``: every key whose value differs
+                from the source's, never a width.
+``assumed``     what the source does not give and was set here.
+``cut``         for each key in ``reduced`` and no other: ``{"published":
+                the source's value, "held": the value this file holds
+                under that key}`` (the key at the file's top level or
+                in its ``model`` block).
+``cut_stands_for``  where ``reduced`` is not empty: the deployment the
+                cut stands for, ``{"chips_sharing_a_layer": n, "how":
+                "what one of the n holds of a layer (experts, heads,
+                vocabulary rows) and where the layers left out lie"}``.
+``correct``     the check's sizes and limits, with ``why``. Beside
+                ``prompt_tokens``, ``decode_steps``, ``repeat_prompt``,
+                ``repeat_answer`` and ``logits_rel_tol``: ``rows`` (the
+                seeded sequences of the logits comparison, default 2),
+                ``tie_eps`` (required where the module has
+                ``decidable``; its reason in ``why``) and
+                ``min_compared`` (the share of the logit vectors, and
+                of each answer's tokens, that has to be compared;
+                default 1, all).
 """
 from __future__ import annotations
 

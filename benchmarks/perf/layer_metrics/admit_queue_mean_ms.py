@@ -10,7 +10,7 @@ and ``admit_wait_p90_ms`` is the p90 of the two together.
 LAYER = "admission and batching"
 UNIT = "ms"
 SOURCE = "program_counter"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 def read(run):

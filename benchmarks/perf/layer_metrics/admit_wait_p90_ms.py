@@ -4,12 +4,12 @@ chunk boundary and then the prefill. Stamped by the benchmark's own
 deployment class around the engine's stream; the engine.admission span
 gives the wait alone and is the tracing issue's to make readable. It is
 nearly all of first_token_p90_ms; the prefill in it stops every running
-lane, which is how it reaches tpot_p90_ms, the cell's end-to-end tail.
+lane, which is how it reaches tpot_mean_ms, the cell's end-to-end time per token.
 """
 LAYER = "admission and batching"
 UNIT = "ms"
 SOURCE = "host_clock"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 from perf_harness import quantile

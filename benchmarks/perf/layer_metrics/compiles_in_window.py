@@ -7,7 +7,7 @@ unexplained tail.
 LAYER = "model step"
 UNIT = "1"
 SOURCE = "program_counter"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 def read(run):

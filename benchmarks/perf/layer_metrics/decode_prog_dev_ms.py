@@ -11,7 +11,7 @@ launch at each edge short.
 LAYER = "model step"
 UNIT = "ms"
 SOURCE = "device_trace"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 PROGRAM = "jit_decode_chunk_slots_paged("
 

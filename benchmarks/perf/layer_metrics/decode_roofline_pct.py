@@ -7,7 +7,7 @@ middle of the traced slice. Bound by bandwidth, not by compute.
 LAYER = "kernels"
 UNIT = "%"
 SOURCE = "device_trace"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 import kernel_costs

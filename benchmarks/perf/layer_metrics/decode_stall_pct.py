@@ -9,7 +9,7 @@ whole window, not the traced slice.
 LAYER = "admission and batching"
 UNIT = "%"
 SOURCE = "program_counter"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 def read(run):

@@ -7,7 +7,7 @@ over (launches x chunk). The programs carry no names of their own yet
 LAYER = "model step"
 UNIT = "ms"
 SOURCE = "device_trace"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 from program_names import CHUNK_WAIT

@@ -4,7 +4,7 @@
 LAYER = "device"
 UNIT = "%"
 SOURCE = "device_trace"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 def read(run):

@@ -8,7 +8,7 @@ dispatch, so this is the host's part of the loop: what
 LAYER = "admission and batching"
 UNIT = "%"
 SOURCE = "program_counter"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 HOST = ("admit", "cover", "deliver", "other")
 

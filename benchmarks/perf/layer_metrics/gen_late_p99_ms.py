@@ -6,7 +6,7 @@ are and so the time per token: a guard more than a lever.
 LAYER = "load generator"
 UNIT = "ms"
 SOURCE = "host_clock"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 def read(run):

@@ -6,7 +6,7 @@ programs' temporaries), as perf_deployment.device_peak_bytes adds them.
 LAYER = "device"
 UNIT = "%"
 SOURCE = "program_counter"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 def read(run):

@@ -6,7 +6,7 @@ cell's one end-to-end tail is the latter (PERF.md, section 2).
 LAYER = "KV page manager"
 UNIT = "%"
 SOURCE = "program_counter"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 def read(run):

@@ -9,7 +9,7 @@ lane waits, so its share stretches the time per output token.
 LAYER = "model step"
 UNIT = "%"
 SOURCE = "device_trace"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 from program_names import PREFILL_WAIT

@@ -7,7 +7,7 @@ Every running lane waits this long for each admission.
 LAYER = "admission and batching"
 UNIT = "ms"
 SOURCE = "program_counter"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 def read(run):

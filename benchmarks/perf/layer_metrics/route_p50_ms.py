@@ -8,7 +8,7 @@ section 2).
 LAYER = "router and handle"
 UNIT = "ms"
 SOURCE = "host_clock"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 from perf_harness import quantile

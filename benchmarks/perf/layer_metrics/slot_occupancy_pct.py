@@ -6,7 +6,7 @@ differenced.
 LAYER = "admission and batching"
 UNIT = "%"
 SOURCE = "program_counter"
-MOVES = "tpot_p90_ms"
+MOVES = "tpot_mean_ms"
 
 
 def read(run):

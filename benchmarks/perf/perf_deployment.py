@@ -115,17 +115,25 @@ class HostSampler:
 class Tracer:
     """``jax.profiler`` around a window, in the process that holds the
     chip, with the host sampler beside it and one marker event that
-    ties the host's clock to the trace's."""
+    ties the host's clock to the trace's. What tracing cost is part of
+    the result (``run["trace"]["cost"]``): the seconds
+    ``stop_trace()`` took (a serving cell calls it inside the window),
+    the file's size, the device events loaded and the seconds of
+    loading and reducing."""
 
     def __init__(self, log_dir: str, anchor: str, entry: str):
         self.log_dir, self.anchor, self.entry = log_dir, anchor, entry
         self.sampler = None
         self.sync_host_ns = None
-        self.t_start = self.t_stop = None
+        self.t_start = self.t_stop = self.stop_s = None
 
     def start(self):
         import jax
 
+        # the profiler's defaults, Python tracer included: without it
+        # a trace is a quarter to a half smaller and device_idle_pct
+        # reads 0.6-1.5 points lower (PERF.md section 6, PR 29), so
+        # turning it off is a step in every traced metric's history
         jax.profiler.start_trace(self.log_dir)
         self.t_start = time.monotonic_ns()
         with jax.profiler.TraceAnnotation("perfbench_sync"):
@@ -140,12 +148,14 @@ class Tracer:
         self.samples = self.sampler.stop()
         self.t_stop = time.monotonic_ns()
         jax.profiler.stop_trace()
+        self.stop_s = (time.monotonic_ns() - self.t_stop) / 1e9
 
     def result(self, describe: bool = False) -> dict:
         """Reduce the trace (seconds of Python: call it once the
         measured window is over)."""
         import trace_reduce
 
+        t0 = time.monotonic()
         samples = self.samples
         path = trace_reduce.find_xplane(self.log_dir)
         trace = trace_reduce.load_xplane(path)
@@ -158,7 +168,14 @@ class Tracer:
                                   host_offset_ns=offset)
         red["host_window_s"] = (self.t_stop - self.t_start) / 1e9
         red["samples"] = len(samples)
-        red["xplane_bytes"] = os.path.getsize(path)
+        red["cost"] = {
+            "trace_stop_s": self.stop_s,
+            "xplane_bytes": os.path.getsize(path),
+            "device_events": sum(
+                len(ln["events"]) for p in trace["planes"]
+                if trace_reduce.DEVICE_PLANE.match(p["name"])
+                for ln in p["lines"]),
+            "reduce_s": time.monotonic() - t0}
         if describe:
             red["describe"] = trace_reduce.describe(path)
             if window is not None:

@@ -57,6 +57,7 @@ def open_loop(rows: List[dict], t0: float, t1: float, t_close: float
         "attempted": len(mine), "failed": n_failed,
         "ttft_p50_ms": _ms(quantile(ttft, 0.5)),
         "ttft_p90_ms": _ms(quantile(ttft, 0.9)),
+        "tpot_mean_ms": _ms(sum(tpot) / len(tpot)) if tpot else None,
         "tpot_p50_ms": _ms(quantile(tpot, 0.5)),
         "tpot_p90_ms": _ms(quantile(tpot, 0.9)),
         "gen_late_p99_ms": _ms(quantile(late, 0.99)),
@@ -69,8 +70,8 @@ def closed_loop(rows: List[dict], t0: float, t1: float
                 ) -> Dict[str, object]:
     """Attempted: requests that finished or failed inside the window. A
     request still running when it closes is neither. The rate counts
-    every output token whose arrival stamp lies in the window, whatever
-    request it belongs to, over the whole window."""
+    every output token produced in the window (``_window_tokens``),
+    whatever request it belongs to, over the whole window."""
     done = [r for r in rows if r.get("end") is not None
             and t0 <= r["end"] < t1]
     failed = [r for r in rows if r.get("error")
@@ -84,8 +85,30 @@ def closed_loop(rows: List[dict], t0: float, t1: float
     }
 
 
-def _window_tokens(rows: List[dict], t0: float, t1: float) -> int:
-    return sum(n for r in rows for t, n in r["slices"] if t0 <= t < t1)
+def _window_tokens(rows: List[dict], t0: float, t1: float) -> float:
+    """Output tokens produced in [t0, t1). The engine hands a stream its
+    tokens a chunk at a time, every lane's at one instant, so a count by
+    arrival stamp jumps by a whole delivery (32 lanes x 8 tokens: 2.5%
+    of a 40 s window) with where the window's edges fall between two
+    deliveries, which follows the seed and not the system (PERF.md,
+    section 6, PR 29). So a slice's tokens are laid evenly over the time
+    since the same request's previous slice, in which the program made
+    them one a step, and the window is credited the part that lies in
+    it. A request's first slice (the prefill's token) has no earlier
+    stamp and counts at its own. What a request that is still running
+    made after its last stamp is not counted: the mix's ``drain_s``
+    keeps the stamps coming past the window's end."""
+    total = 0.0
+    for r in rows:
+        prev = None
+        for t, n in r["slices"]:
+            if prev is None or t <= prev:
+                total += n if t0 <= t < t1 else 0
+            else:
+                total += n * max(0.0, min(t, t1) - max(prev, t0)) \
+                    / (t - prev)
+            prev = t
+    return total
 
 
 def _ms(v: Optional[float]) -> Optional[float]:

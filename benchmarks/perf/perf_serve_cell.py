@@ -214,8 +214,10 @@ def run(found: dict, seed: int, seconds: float, trace: int,
     print("SETUP " + json.dumps({
         "setup_s": setup_s, "marks": marks, "replica": first["timing"],
         "reference_s": ref["seconds"], "reference": ref["checks"],
+        "reference_vectors": ref.get("vectors"),
         "served_check": engine_ck,
         "fill_requests": len(fill), "health": health,
+        "trace_cost": (tracer["red"] or {}).get("cost"),
         "memory_stats": final.get("memory_stats")}), flush=True)
     return {"run": run_data, "correct": correct,
             "attempted": e2e["attempted"], "failed": e2e["failed"],

@@ -34,6 +34,11 @@ A mix file (``traffic/<mix>.json``) has:
               sent (one token each) before the ramp, so that the LRU
               prefix cache is full, as on any server that has run for
               more than a few minutes.
+``drain_s``   how long after the window the streams are still stamped:
+              an open loop waits that long for its requests to end; a
+              closed loop's running requests go on that long, so that
+              the slice that straddles the window's end is there to be
+              shared out (``perf_metrics._window_tokens``).
 ``block``     size of the blocks the order is stratified in: lengths
               and, in an open loop, inter-arrival gaps (16 if absent).
 """

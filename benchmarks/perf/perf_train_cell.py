@@ -153,6 +153,7 @@ def child(args) -> int:
                          red)
     print("SETUP " + json.dumps({"timing": run["timing"],
                                  "reference": ref,
+                                 "trace_cost": (red or {}).get("cost"),
                                  "memory_stats": run["memory_stats"],
                                  "first_loss": losses[0],
                                  "last_loss": losses[-1]}), flush=True)

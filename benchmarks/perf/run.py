@@ -77,6 +77,44 @@ def _metrics(found: dict, res: dict, trace: int, strict: bool) -> dict:
     return out
 
 
+def compared_lines(run: dict) -> list:
+    """Each number ``correct`` compared beside its limit, one line
+    each: the run's last lines on standard error, so that the record of
+    a run that is not correct keeps them."""
+    ref = run.get("reference") or {}
+    out = []
+    for c in ref.get("checks", []):         # serving: logits
+        out.append(f"logits {c['where']}: rel {c['rel']} <= tol "
+                   f"{c['tol']} (max|err| {c['max_abs_err']}, max|ref| "
+                   f"{c['max_abs_ref']}; compared {c['compared']}, "
+                   f"left out {c['left_out']})")
+    if "vectors" in ref:
+        out.append("logit vectors: compared {compared} >= needed "
+                   "{needed} (left out {left_out})".format(
+                       **ref["vectors"]))
+    ck = run.get("served_check")
+    if ck:                                  # serving: the engine's answers
+        v = ck.get("reference", {})
+        out.append(f"answers: complete {ck.get('complete')}, hit after "
+                   f"eviction {ck.get('hit_after_eviction')} (expected "
+                   f"{ck.get('expected_hit_after_eviction')})")
+        if "margin" in v:
+            out.append(
+                f"served tokens: max gap {v['max_gap']} <= margin "
+                f"{v['margin']} over {v.get('distinct')} answer(s); "
+                f"compared {v.get('compared')} of {v.get('tokens')} "
+                f"(share needed {v.get('min_compared')}); control max "
+                f"gap {v.get('control_max_gap')} > margin "
+                f"{v.get('control_margin')}")
+    if "abs_err" in ref:                    # training
+        tol = run["conf"]["correct"]["loss_abs_tol"]
+        out.append(f"loss: program {ref['program_loss']} reference "
+                   f"{ref['reference_loss']}: |err| {ref['abs_err']} <= "
+                   f"{tol}; first {run['losses'][0]} > last "
+                   f"{run['losses'][-1]}")
+    return out
+
+
 def run_cell(found: dict, seed: int, seconds: float, trace: int,
              require_tpu: bool = True, overrides: dict = None,
              describe: bool = False) -> dict:
@@ -140,6 +178,10 @@ def main(argv=None) -> int:
                           for p in res["run"].get("polls") or []]}),
                   flush=True)
         extra = {"rehearsal": True} if args.rehearsal else None
+        for ln in compared_lines(res["run"]):
+            print("perfbench compared: " + ln, file=sys.stderr)
+        print(f"perfbench correct: {bool(res['correct'])}",
+              file=sys.stderr, flush=True)
         print(H.result_line(
             correct=res["correct"], attempted=res["attempted"],
             failed=res["failed"], metrics=metrics, device=dev,

@@ -2,7 +2,11 @@
 benchmark as ``architectures/dummy.py`` with ``dummy_reference.py``
 beside it. It is the nano GPT under other key names in the
 configuration's ``model`` block, with a reference of its own: what a
-PR that adds a model brings, as files."""
+PR that adds a model brings, as files. Like such a PR's file it imports
+the program's model code openly (inside its functions: the driver of a
+serving cell loads this module and stays off jax), and its
+configuration is a CUT one (``dummy-serve.json``: ``reduced``, ``cut``,
+``cut_stands_for``)."""
 import functools
 import os
 
@@ -22,12 +26,19 @@ def vocab(conf):
 
 
 def model_cfg(conf):
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+
     m = conf["model"]
-    return _gpt2.model_cfg(dict(conf, model={
-        "n_layer": m["layers"], "n_embd": m["width"],
-        "n_head": m["heads"], "n_inner": m["ffn"],
-        "n_positions": m["positions"], "vocab_size": m["vocab"],
-        "embedding_rows_held": m["rows"]}))
+    dtypes = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
+    return gpt.GPTConfig(
+        vocab_size=m["rows"], n_layer=m["layers"], n_head=m["heads"],
+        d_model=m["width"], d_ff=m["ffn"], max_seq=m["positions"],
+        dtype=dtypes[conf["numerics"]["compute_dtype"]],
+        param_dtype=dtypes[conf["numerics"]["param_dtype"]],
+        remat=conf.get("train", {}).get("remat", "dots"),
+        loss_chunk=conf.get("train", {}).get("loss_chunk", 0))
 
 
 def reference(cfg):

@@ -13,49 +13,12 @@ import pytest
 
 import perf_testlib as L
 
-READER = '''"""A dummy per-layer metric: requests the window attempted."""
-LAYER = "load generator"
-UNIT = "1"
-SOURCE = "host_clock"
-MOVES = "tpot_p90_ms"
-
-
-def read(run):
-    return float(run["e2e"]["attempted"])
-'''
-
-
-def _cell(name, config, traffic, chips=1):
-    return {"name": name, "config": config, "traffic": traffic,
-            "chips": chips, "why": "test"}
-
-
-def _fixture(name):
-    return os.path.join(L.FIXTURES, name)
-
-
-#: a second architecture: its module, and its reference beside it
-DUMMY_ARCH = [("dummy.py", _fixture("dummy_arch.py")),
-              ("dummy_reference.py", _fixture("dummy_arch_reference.py"))]
+_cell, _fixture, DUMMY_ARCH = L.cell, L.fixture, L.DUMMY_ARCH
 
 
 @pytest.fixture(scope="module")
 def serve_copy(tmp_path_factory):
-    return L.copy_with_additions(
-        tmp_path_factory.mktemp("perf_serve"),
-        configs=[("nano-serve", _fixture("nano-serve.json")),
-                 ("dummy-serve", _fixture("dummy-serve.json"))],
-        mixes=[("nano-chat", _fixture("nano-chat.json"))],
-        readers=[("dummy_attempted", READER)],
-        architectures=DUMMY_ARCH,
-        cells=[_cell("nano-chat", "nano-serve", "nano-chat"),
-               _cell("dummy-chat", "dummy-serve", "nano-chat")],
-        metrics=[("per_layer", {
-            "name": "dummy_attempted", "unit": "1", "better": "higher",
-            "source": "host_clock", "layer": "load generator",
-            "moves": "tpot_p90_ms", "workloads": ["nano-chat"]})],
-        join={"nano-chat": "cgpt1b3-chat-steady",
-              "dummy-chat": "cgpt1b3-chat-steady"})
+    return L.rehearsal_copy(tmp_path_factory.mktemp("perf_serve"))
 
 
 def test_nothing_that_was_there_is_edited(serve_copy):
@@ -102,6 +65,23 @@ def test_whole_run_of_the_added_chat_cell_ends_in_one_result_line(
     assert res["metrics"]["dummy_attempted"]["value"] == 15.0
     # no device plane in a CPU trace: the device metrics are left out
     assert "busy_s" not in res["device"]
+    assert "attn_kernel_share_pct" not in res["metrics"]
+    # what tracing cost is part of the run
+    setup = json.loads(next(ln for ln in out
+                            if ln.startswith("SETUP "))[6:])
+    cost = setup["trace_cost"]
+    assert set(cost) == {"trace_stop_s", "xplane_bytes", "device_events",
+                         "reduce_s"}
+    assert cost["trace_stop_s"] > 0 and cost["xplane_bytes"] > 0
+    assert cost["device_events"] == 0          # a CPU has no device plane
+    # each number compared stands beside its limit at the end of stderr
+    tail = [ln for ln in err.strip().splitlines()
+            if ln.startswith("perfbench ")]
+    assert tail[-1] == "perfbench correct: True"
+    assert sum("logits after_" in ln and "<= tol 0.025" in ln
+               and "compared 2, left out 0" in ln for ln in tail) == 2
+    assert any("served tokens: max gap" in ln and "control max gap" in ln
+               for ln in tail)
 
 
 def test_a_model_added_as_files_serves_under_its_own_reference(
@@ -119,13 +99,21 @@ def test_a_model_added_as_files_serves_under_its_own_reference(
     assert rc == 0, (out[-5:], err[-2000:])
     res = json.loads(out[-1])
     assert res["correct"] is True and res["failed"] == 0
-    assert set(res["metrics"]) == {"tpot_p90_ms", "setup_s"}
+    assert set(res["metrics"]) == {"tpot_mean_ms", "setup_s"}
     setup = json.loads(next(ln for ln in out
                             if ln.startswith("SETUP "))[6:])
     served = setup["served_check"]["reference"]
     assert served["ok"] and served["distinct"] >= 1
     assert served["control_max_gap"] > served["control_margin"]
     assert all(c["rel"] <= c["tol"] for c in setup["reference"])
+    # the configuration's correct.rows (3) seeded rows, each compared
+    # after prefill and after decode; no decidable: nothing left out
+    assert [(c["compared"], c["left_out"])
+            for c in setup["reference"]] == [(3, 0), (3, 0)]
+    assert setup["reference_vectors"] == {"compared": 6, "left_out": 0,
+                                          "needed": 6}
+    assert (served["tokens"], served["compared"], served["left_out"]) \
+        == (6, 6, 0)
     # found by name, in the copy, with the reference beside it
     conf = H.load_json(_fixture("dummy-serve.json"))
     arch = H.load_architecture(conf, here=os.path.join(

@@ -45,11 +45,14 @@ def test_open_loop_counts_a_failed_and_a_still_running_request_as_worst():
     assert got["ttft_p50_ms"] == pytest.approx(500.0)
     assert got["ttft_p90_ms"] > 40_000
     assert got["tpot_p50_ms"] == pytest.approx(100.0)
+    # the mean over requests takes the two lost ones at the worst too
+    assert got["tpot_mean_ms"] == pytest.approx((8 * 0.1 + 2 * 50.0) * 100)
     assert got["gen_late_p99_ms"] == pytest.approx(1.0)
     none_failed = M.open_loop(rows[:8], t0, t1, t_close)
     assert none_failed["failed"] == 0
     assert none_failed["ttft_p90_ms"] == pytest.approx(500.0)
     assert none_failed["tpot_p90_ms"] == pytest.approx(100.0)
+    assert none_failed["tpot_mean_ms"] == pytest.approx(100.0)
 
 
 def test_tpot_is_last_minus_first_over_tokens_less_one():
@@ -60,7 +63,7 @@ def test_tpot_is_last_minus_first_over_tokens_less_one():
     assert M.tpot_s(_row(1, 0.0, first=1.0, n=1)) is None
 
 
-def test_closed_loop_rate_counts_tokens_by_arrival_stamp():
+def test_closed_loop_rate_counts_the_tokens_made_in_the_window():
     t0, t1 = 10.0, 20.0
     before = _row(0, 5.0, first=1.0, n=17, gap=0.5)   # straddles t0
     inside = _row(1, 11.0, first=1.0, n=9, gap=0.1)
@@ -68,10 +71,36 @@ def test_closed_loop_rate_counts_tokens_by_arrival_stamp():
     failed = _row(3, 12.0, error="X: y")
     rows = [before, inside, running, failed]
     got = M.closed_loop(rows, t0, t1)
-    want = sum(n for r in rows for t, n in r["slices"] if t0 <= t < t1)
+    # `before`: a token at 6.0, then eight a slice over (6, 10] and
+    # (10, 14]; `inside` lies whole in the window; `running`: a token
+    # at 19.0 and eight over (19.0, 20.6], of which 1.0 s is inside
+    want = 8 + 9 + 1 + 8 * 1.0 / 1.6
     assert got["out_tokens_per_s"] == pytest.approx(want / 10.0)
-    assert 0 < want < sum(M.n_tokens(r) for r in rows)
     assert got["attempted"] == 3 and got["failed"] == 1   # not `running`
+
+
+@pytest.mark.parametrize("shift", [0.0, 0.3, 0.6, 0.9])
+def test_the_rate_does_not_follow_where_the_window_falls_between_deliveries(
+        shift):
+    """32 lanes deliver eight tokens each at one instant, once a second:
+    a count by arrival stamp reads 39 or 40 deliveries in 39.5 s with
+    the phase of the window's edges; the rate must not."""
+    rows = [{"idx": i, "phase": "window", "due": 0.0, "sent": 0.0,
+             "slices": [[0.5, 1]] + [[1.0 + k, 8] for k in range(60)],
+             "end": None, "error": None, "error_t": None,
+             "prompt_len": 10, "max_new": 481, "id_min": 0, "id_max": 5}
+            for i in range(32)]
+    t0 = 5.0 + shift
+    got = M.closed_loop(rows, t0, t0 + 39.5)
+    assert got["out_tokens_per_s"] == pytest.approx(256.0)
+
+
+def test_what_a_running_request_made_after_its_last_stamp_is_not_counted():
+    row = _row(0, 0.0, first=1.0, n=9, gap=0.1, end=False)  # last at 1.8
+    assert M.closed_loop([row], 0.0, 5.0)["out_tokens_per_s"] == \
+        pytest.approx(9 / 5.0)
+    assert M.closed_loop([row], 0.0, 1.4)["out_tokens_per_s"] == \
+        pytest.approx((1 + 8 * 0.4 / 0.8) / 1.4)
 
 
 def test_stream_faults_hold_a_stream_to_its_length_and_ids():

@@ -1,6 +1,7 @@
 """The plain reference against the program's model at ``nano``: it
 shares no code with ``ray_tpu/models`` and agrees with it."""
 import dataclasses
+import hashlib
 import json
 import os
 
@@ -32,26 +33,67 @@ def test_reference_shares_no_code_with_the_program():
     with open(os.path.join(perf_testlib.PERF, "reference_gpt2.py")) as f:
         src = f.read()
     assert "import ray_tpu" not in src and "from ray_tpu" not in src
+    # it stays where it is, byte for byte: a later PR adds its own
+    # reference beside its architecture file and edits none
+    assert hashlib.sha256(src.encode()).hexdigest() == \
+        "b011ef468e267f2be780de2a2c4e1803945ee68f00acc0eb1a090409f7f551ec"
 
 
-def test_only_the_architecture_file_knows_the_model():
-    """The harness finds a model by name: nothing under
-    ``benchmarks/perf`` but ``architectures/gpt2.py`` imports the
-    program's model code or names the reference module."""
-    knows = []
-    for folder, _dirs, files in os.walk(perf_testlib.PERF):
-        for name in files:
-            if not name.endswith(".py") or name == "reference_gpt2.py":
-                continue
-            with open(os.path.join(folder, name)) as f:
-                src = f.read()
-            code = [ln for ln in src.splitlines()
-                    if ln.lstrip().startswith(("import ", "from "))]
-            if any("ray_tpu.models" in ln or "reference_gpt2" in ln
-                   or "ray_tpu.serve.engine" in ln for ln in code):
-                knows.append(os.path.relpath(
-                    os.path.join(folder, name), perf_testlib.PERF))
-    assert knows == [os.path.join("architectures", "gpt2.py")]
+@pytest.fixture(scope="module", params=["tree", "rehearsal"])
+def root(request, tmp_path_factory):
+    return perf_testlib.root_of(request.param, tmp_path_factory)
+
+
+def test_only_the_architecture_file_knows_the_model(root):
+    """The harness finds a model by name: the files under
+    ``benchmarks/perf`` that import the program's model or engine code,
+    or name a reference module, all lie under ``architectures/``, each
+    ``architectures/<name>.py`` names no reference but its own, and no
+    reference imports the program (``perf_testlib.
+    who_knows_the_model``). Held on the tree and on the rehearsal's
+    copy, whose second architecture imports ``ray_tpu.models`` openly."""
+    knows, references = perf_testlib.who_knows_the_model(
+        perf_testlib.perf_dir(root))
+    arch = os.path.join("architectures", "")
+    if root == perf_testlib.ROOT:
+        assert knows == [arch + "gpt2.py"]
+        assert references == ["reference_gpt2.py"]
+    else:
+        assert knows == [arch + "dummy.py", arch + "gpt2.py"]
+        assert references == [arch + "dummy_reference.py",
+                              "reference_gpt2.py"]
+
+
+@pytest.mark.parametrize("fault", [
+    "a_harness_file_imports_the_model",
+    "a_reader_imports_the_engine_through_importlib",
+    "an_architecture_names_anothers_reference",
+    "a_reference_imports_the_program"])
+def test_a_planted_file_that_knows_the_model_is_refused(tmp_path, fault):
+    root = perf_testlib.rehearsal_copy(tmp_path)
+    perf = perf_testlib.perf_dir(root)
+    perf_testlib.who_knows_the_model(perf)              # sound as it is
+    where, text = {
+        "a_harness_file_imports_the_model": (
+            "perf_extra.py",
+            "def f():\n    from ray_tpu.models import gpt\n"
+            "    return gpt\n"),
+        "a_reader_imports_the_engine_through_importlib": (
+            os.path.join("layer_metrics", "sly.py"),
+            "import importlib\n\n\ndef read(run):\n    return "
+            "importlib.import_module('ray_tpu.serve.engine')\n"),
+        "an_architecture_names_anothers_reference": (
+            os.path.join("architectures", "third.py"),
+            "def reference(cfg):\n    import reference_gpt2\n"
+            "    return reference_gpt2\n"),
+        "a_reference_imports_the_program": (
+            os.path.join("architectures", "third_reference.py"),
+            "from ray_tpu.models import gpt\n"),
+    }[fault]
+    with open(os.path.join(perf, where), "w") as f:
+        f.write(text)
+    with pytest.raises(AssertionError, match=os.path.basename(where)):
+        perf_testlib.who_knows_the_model(perf)
 
 
 def test_forward_agrees_with_the_program_in_float32(nano):
@@ -279,3 +321,161 @@ def test_a_planted_page_fault_reads_not_correct_through_the_engine(
     setup = json.loads(next(ln for ln in out
                             if ln.startswith("SETUP "))[6:])
     assert all(c["rel"] <= c["tol"] for c in setup["reference"])
+
+
+# ---- what the reference cannot decide is left out, counted and bounded
+
+N_PROMPT, N_STEPS = 10, 4
+TOY_SEED = 2 ** 31 + 29
+
+
+@pytest.fixture(scope="module")
+def toy():
+    import perf_harness as H
+
+    return H.load_file(os.path.join(perf_testlib.FIXTURES,
+                                    "toy_router_arch.py"), "perf_arch_")
+
+
+def _toy_conf(**correct):
+    return {"correct": dict({
+        "logits_rel_tol": 0.025, "tie_eps": 1e-3,
+        "why": "tie_eps 1e-3: bfloat16 moves a score by up to 3e-2"},
+        **correct)}
+
+
+def _seeded_rows(seed, rows, vocab):
+    """``serve_check``'s own draw, as it has been since PR 23."""
+    rng = np.random.default_rng([seed & (2 ** 63 - 1), 77])
+    return rng.integers(0, vocab, (rows, N_PROMPT + N_STEPS + 1)
+                        ).astype(np.int32)
+
+
+def _without_decidable(toy):
+    import types
+
+    return types.SimpleNamespace(**{
+        k: getattr(toy, k) for k in ("vocab", "served_logits",
+                                     "reference")})
+
+
+def _check(arch, engine, conf, served=None):
+    import perf_reference_check as C
+
+    return C.serve_check(arch, engine, None, conf, TOY_SEED, N_PROMPT,
+                         N_STEPS, served)
+
+
+def test_rows_2_and_no_decidable_compares_what_the_parent_compared(toy):
+    """The numbers of a module without ``decidable`` are PR 27's to the
+    last digit: its formula, written out here, on the same draw."""
+    import jax
+    import jax.numpy as jnp
+
+    engine = toy.Engine(toy.init_params(5))
+    got = _check(_without_decidable(toy), engine, _toy_conf())
+    seqs = _seeded_rows(TOY_SEED, 2, toy.V)
+    served = toy.served_logits(engine, None, seqs, N_PROMPT, N_STEPS)
+    ref = np.asarray(jax.jit(toy.reference(None)[1])(
+        engine.params, jnp.asarray(seqs[:, :-1])), np.float32)
+    for c, (i, pos) in zip(got["checks"], (
+            (0, N_PROMPT - 1), (N_STEPS, N_PROMPT + N_STEPS - 1))):
+        err = float(np.abs(served[i] - ref[:, pos]).max())
+        scale = float(np.abs(ref[:, pos]).max())
+        assert (c["max_abs_err"], c["max_abs_ref"], c["rel"]) == \
+            (err, scale, err / scale)
+        assert (c["compared"], c["left_out"]) == (2, 0)
+        assert c["rel"] <= c["tol"]
+    assert got["ok"] and got["vectors"] == {
+        "compared": 4, "left_out": 0, "needed": 4}
+    # with decidable and no near-tie among the four: the same numbers
+    with_dec = _check(toy, engine, _toy_conf())
+    assert with_dec["checks"] == got["checks"] and with_dec["ok"]
+
+
+def test_a_planted_near_tie_is_left_out_and_counted(toy):
+    """Row 1's last compared position is made a near-tie of the two
+    experts: program and reference pick differently there, the logits
+    part by far more than the tolerance, and the harness leaves that
+    one vector out, says so, and needs ``min_compared`` to allow it."""
+    seqs = _seeded_rows(TOY_SEED, 2, toy.V)[:, :-1]
+    pos = N_PROMPT + N_STEPS - 1
+    params = toy.plant_near_tie(toy.init_params(5), seqs, 1, pos)
+    ref_s = toy.scores(params, seqs, program=False)
+    prog_s = toy.scores(params, seqs, program=True)
+    assert abs(ref_s[1, pos, 0] - ref_s[1, pos, 1]) < 1e-4
+    assert ref_s[1, pos].argmax() != prog_s[1, pos].argmax()
+    engine = toy.Engine(params)
+    blind = _check(_without_decidable(toy), engine, _toy_conf())
+    assert not blind["ok"]
+    assert blind["checks"][1]["rel"] > 2 * blind["checks"][1]["tol"]
+    got = _check(toy, engine, _toy_conf(min_compared=0.5))
+    assert got["ok"], got
+    assert [(c["compared"], c["left_out"]) for c in got["checks"]] == \
+        [(2, 0), (1, 1)]
+    assert got["checks"][1]["rel"] <= got["checks"][1]["tol"]
+    assert got["vectors"] == {"compared": 3, "left_out": 1, "needed": 2}
+    # by default every vector has to be compared: too few is not correct
+    strict = _check(toy, engine, _toy_conf())
+    assert not strict["ok"] and strict["vectors"]["needed"] == 4
+    assert strict["checks"] == got["checks"]
+
+
+def test_a_decidable_that_says_no_everywhere_is_not_correct(toy):
+    engine = toy.Engine(toy.init_params(5))
+    prompt = _seeded_rows(TOY_SEED + 1, 1, toy.V)[0, :N_PROMPT]
+    answer = toy.greedy(engine, prompt, 6)
+    got = _check(toy, engine, _toy_conf(tie_eps=1e9, min_compared=0.01),
+                 (prompt, [answer]))
+    assert not got["ok"] and not got["served"]["ok"]
+    assert [c["compared"] for c in got["checks"]] == [0, 0]
+    assert all(c["rel"] is None for c in got["checks"])
+    assert got["served"]["compared"] == 0
+    assert got["served"]["left_out"] == 6
+
+
+def test_a_faulty_expert_at_a_decidable_position_is_not_correct(toy):
+    """The program multiplies with expert weights that are off by half;
+    what is left out does not change, since no call into the program
+    decides it, and what is compared fails by far."""
+    import copy
+
+    params = toy.init_params(5)
+    bad = copy.deepcopy(params)
+    bad["down"] = bad["down"] * 1.5
+    sound = _check(toy, toy.Engine(params), _toy_conf())
+    got = _check(toy, toy.Engine(params, program_params=bad),
+                 _toy_conf())
+    assert sound["ok"] and not got["ok"]
+    assert [(c["compared"], c["left_out"]) for c in got["checks"]] == \
+        [(c["compared"], c["left_out"]) for c in sound["checks"]]
+    assert max(c["rel"] for c in got["checks"]) > 2 * 0.025
+
+
+def test_a_served_token_at_a_near_tie_is_left_out_of_its_verdict(toy):
+    """The answer's first token is chosen where the reference calls a
+    near-tie: the program took the other expert's logits, the reference
+    ranks its token far below its best, and the verdict leaves that one
+    token out, counts it, and still fails the control."""
+    prompt = _seeded_rows(TOY_SEED + 1, 1, toy.V)[:, :N_PROMPT]
+    params = None
+    for seed in range(5, 40):       # a seed whose parted token differs
+        params = toy.plant_near_tie(toy.init_params(seed), prompt, 0,
+                                    N_PROMPT - 1)
+        engine = toy.Engine(params)
+        answer = toy.greedy(engine, prompt[0], 6)
+        blind = _check(_without_decidable(toy), engine, _toy_conf(),
+                       (prompt[0], [answer]))["served"]
+        if blind["max_gap"] > blind["margin"]:
+            break
+    assert not blind["ok"] and blind["left_out"] == 0
+    got = _check(toy, engine, _toy_conf(min_compared=0.5),
+                 (prompt[0], [answer]))["served"]
+    assert got["ok"], got
+    assert (got["tokens"], got["compared"], got["left_out"]) == (6, 5, 1)
+    assert got["max_gap"] <= got["margin"] < blind["max_gap"]
+    assert got["control_max_gap"] > got["control_margin"]
+    assert got["min_compared"] == 0.5
+    # all of an answer's tokens by default: one left out is too few
+    strict = _check(toy, engine, _toy_conf(), (prompt[0], [answer]))
+    assert not strict["served"]["ok"]
