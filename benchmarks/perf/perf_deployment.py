@@ -4,11 +4,13 @@
 
 It is the benchmark's, so that the one process which holds the chip can
 do for the benchmark what nothing else can: make the weights from the
-seed on the device, start and stop ``jax.profiler`` and reduce the
-trace, sample what its engine's driver thread is doing, compare the
-served arithmetic with the plain reference, and hand out the engine's
-counters. Everything it reads from the program is public
-(``engine.stats()``) except where a comment says otherwise.
+seed on the device, start and stop ``jax.profiler`` around a slice
+counted in the engine's chunk launches, sample what its engine's driver
+thread is doing, compare the served arithmetic with the plain
+reference, and hand out the engine's counters. The trace's file is
+written here and read elsewhere (``trace_reduce.reduce_in_child``).
+Everything it reads from the program is public (``engine.stats()``)
+except where a comment says otherwise.
 """
 from __future__ import annotations
 
@@ -112,22 +114,63 @@ class HostSampler:
         return self.samples
 
 
-class Tracer:
-    """``jax.profiler`` around a window, in the process that holds the
-    chip, with the host sampler beside it and one marker event that
-    ties the host's clock to the trace's. What tracing cost is part of
-    the result (``run["trace"]["cost"]``): the seconds
-    ``stop_trace()`` took (a serving cell calls it inside the window),
-    the file's size, the device events loaded and the seconds of
-    loading and reducing."""
+def wait_slice(advanced, launches: int, until: float, period_s: float,
+               clock=time.monotonic, sleep=time.sleep) -> str:
+    """Wait for the end of a slice counted in launches: until
+    ``advanced()``, the counter's advance since the slice began, has
+    reached ``launches`` (returns ``"launches"``), or the clock has
+    reached ``until`` (``"seconds"``), whichever comes first. The
+    counter is read every ``period_s`` and no more often, so the slice
+    ends within one reading of either."""
+    while True:
+        sleep(period_s)
+        if advanced() >= launches:
+            return "launches"
+        if clock() >= until:
+            return "seconds"
 
-    def __init__(self, log_dir: str, anchor: str, entry: str):
+
+class Tracer:
+    """``jax.profiler`` around a slice, in the process that holds the
+    chip, with the host sampler beside it and one marker event that
+    ties the host's clock to the trace's. The file is written here and
+    not read: :meth:`handoff` is what only this process knows, and
+    ``trace_reduce.reduce_handoff`` makes the numbers from it and the
+    file, in this process once its window is over (training,
+    :meth:`result`) or in a child of the driver (serving, whose
+    replica then holds the interpreter for nothing after
+    ``stop_trace()``).
+
+    A slice is as long as its caller makes it (``start()`` ...
+    ``stop()``: training's three steps, a mix without
+    ``trace_launches``) or is counted in launches: given ``counter``
+    (the engine's chunk dispatches so far), ``start(launches,
+    limit_s)`` sets a watcher beside the trace that stops it when the
+    counter has advanced by ``launches`` or after ``limit_s`` seconds,
+    whichever comes first; ``stop()`` then waits for the watcher. What
+    cost a traced slice follows the device EVENTS in it, which follow
+    the launches and not the seconds: counted so, a program whose step
+    is n times shorter is traced for a slice n times shorter that
+    holds the same events. What tracing cost is part of the result
+    (``run["trace"]["cost"]``): the seconds ``stop_trace()`` took (a
+    serving cell calls it inside the window), the slice as it was
+    (``slice_s``, ``launches``, ``ended_by``), the file's size, the
+    device events loaded and the seconds of loading and reducing."""
+
+    #: how often the watcher reads the counter
+    WATCH_PERIOD_S = 0.1
+
+    def __init__(self, log_dir: str, anchor: str, entry: str,
+                 counter=None):
         self.log_dir, self.anchor, self.entry = log_dir, anchor, entry
-        self.sampler = None
+        self.counter = counter
+        self.sampler = self.samples = None
         self.sync_host_ns = None
         self.t_start = self.t_stop = self.stop_s = None
+        self.launches = self.ended_by = None
+        self._n0 = self._watcher = self._watch_error = None
 
-    def start(self):
+    def start(self, launches: int = None, limit_s: float = None):
         import jax
 
         # the profiler's defaults, Python tracer included: without it
@@ -138,52 +181,71 @@ class Tracer:
         self.t_start = time.monotonic_ns()
         with jax.profiler.TraceAnnotation("perfbench_sync"):
             self.sync_host_ns = time.monotonic_ns()
+        if self.counter is not None:
+            self._n0 = self.counter()
         self.sampler = HostSampler(self.anchor, self.entry)
         self.sampler.start()
+        if launches:
+            self._watcher = threading.Thread(
+                target=self._watch, daemon=True,
+                args=(int(launches), self.t_start / 1e9 + limit_s))
+            self._watcher.start()
 
-    def stop(self):
-        """Stop tracing and sampling; the file is written, not read."""
+    def _watch(self, launches: int, until: float):
+        try:
+            self._stop(wait_slice(lambda: self.counter() - self._n0,
+                                  launches, until, self.WATCH_PERIOD_S))
+        except Exception as e:  # noqa: BLE001 - raised by stop()
+            self._watch_error = e
+
+    def stop(self) -> dict:
+        """Stop tracing and sampling, or wait for the watcher of a
+        slice counted in launches to have done so; the file is written,
+        not read. Returns the slice as it was."""
+        if self._watcher is None:
+            self._stop("seconds" if self.counter is not None else None)
+        else:
+            self._watcher.join()
+            if self._watch_error is not None:
+                raise self._watch_error
+        return self.slice()
+
+    def _stop(self, ended_by):
         import jax
 
         self.samples = self.sampler.stop()
         self.t_stop = time.monotonic_ns()
+        if self.counter is not None:
+            self.launches = self.counter() - self._n0
+        self.ended_by = ended_by
         jax.profiler.stop_trace()
         self.stop_s = (time.monotonic_ns() - self.t_stop) / 1e9
 
+    def slice(self) -> dict:
+        """The slice as it was, on the host's monotonic clock in
+        seconds (one clock for every process of a machine)."""
+        return {"t_start_s": self.t_start / 1e9,
+                "t_stop_s": self.t_stop / 1e9,
+                "mid_s": (self.t_start + self.t_stop) / 2e9,
+                "slice_s": (self.t_stop - self.t_start) / 1e9,
+                "launches": self.launches, "ended_by": self.ended_by,
+                "trace_stop_s": self.stop_s}
+
+    def handoff(self) -> dict:
+        """What only the tracing process knows, for
+        ``trace_reduce.reduce_handoff``: the slice, and what ties the
+        host's clock to the trace's. Reads no file."""
+        return dict(self.slice(), log_dir=self.log_dir,
+                    samples=self.samples, t_start=self.t_start,
+                    t_stop=self.t_stop, sync_host_ns=self.sync_host_ns)
+
     def result(self, describe: bool = False) -> dict:
-        """Reduce the trace (seconds of Python: call it once the
-        measured window is over)."""
+        """Reduce the trace here (seconds of Python: only once the
+        measured window is over, and never inside a replica)."""
         import trace_reduce
 
-        t0 = time.monotonic()
-        samples = self.samples
-        path = trace_reduce.find_xplane(self.log_dir)
-        trace = trace_reduce.load_xplane(path)
-        sync = trace_reduce.sync_event_ns(trace)
-        offset = None if sync is None else sync - self.sync_host_ns
-        window = None
-        if offset is not None:
-            window = (self.t_start + offset, self.t_stop + offset)
-        red = trace_reduce.reduce(trace, window=window, samples=samples,
-                                  host_offset_ns=offset)
-        red["host_window_s"] = (self.t_stop - self.t_start) / 1e9
-        red["samples"] = len(samples)
-        red["cost"] = {
-            "trace_stop_s": self.stop_s,
-            "xplane_bytes": os.path.getsize(path),
-            "device_events": sum(
-                len(ln["events"]) for p in trace["planes"]
-                if trace_reduce.DEVICE_PLANE.match(p["name"])
-                for ln in p["lines"]),
-            "reduce_s": time.monotonic() - t0}
-        if describe:
-            red["describe"] = trace_reduce.describe(path)
-            if window is not None:
-                red["cut"] = trace_reduce.cut(
-                    trace, (window[0] + 10 ** 9,
-                            window[0] + 10 ** 9 + 7 * 10 ** 8),
-                    samples, offset)
-        return red
+        return trace_reduce.reduce_handoff(
+            dict(self.handoff(), describe=describe))
 
 
 def make_deployment(conf: dict, seed: int, require_tpu: bool,
@@ -260,21 +322,31 @@ def make_deployment(conf: dict, seed: int, require_tpu: bool,
                 self.arch, self.engine, self.cfg, conf, seed, n_prompt,
                 n_steps, served)
 
-        def trace_start(self) -> bool:
+        def trace_start(self, launches: int = None,
+                        limit_s: float = None) -> bool:
+            """Begin the traced slice. With ``launches`` it ends by
+            itself (``Tracer``), watched through the engine's public
+            counter of chunk dispatches alone."""
             import program_names
 
-            self.tracer = Tracer(trace_dir, program_names.ENGINE_FILE,
-                                 program_names.DRIVER_ENTRY)
-            self.tracer.start()
+            self.tracer = Tracer(
+                trace_dir, program_names.ENGINE_FILE,
+                program_names.DRIVER_ENTRY,
+                counter=lambda: self.engine.stats()["dispatches"])
+            self.tracer.start(launches, limit_s)
             return True
 
-        def trace_stop(self) -> bool:
-            self.tracer.stop()
-            return True
+        def trace_stop(self) -> dict:
+            """End the slice, or wait for its end where it is counted
+            in launches: the slice as it was."""
+            return self.tracer.stop()
 
-        def trace_result(self, describe: bool = False) -> dict:
+        def trace_result(self) -> dict:
+            """What only the replica knows of the slice. No file is
+            read here: the driver has the trace reduced in a child of
+            its own once the window has closed."""
             tracer, self.tracer = self.tracer, None
-            return tracer.result(describe)
+            return tracer.handoff()
 
     return PerfGPT
 

@@ -1,6 +1,18 @@
 """One run of a serving cell: ``rt.init`` → ``serve.run`` → the mix's
 traffic through the handle → the result. This process is the driver and
 the load generator; it never imports jax (the replica holds the chip).
+
+A traced run (``--trace 1``) traces a slice of the window inside the
+replica. The slice begins ``trace_after_s`` into the window and is
+counted in LAUNCHES where the mix states ``trace_launches``: it ends
+when the engine has dispatched that many chunks, or after ``trace_s``
+seconds, whichever comes first (``perf_deployment.Tracer``); a mix
+without the key is traced for ``trace_s``. The replica hands out what
+only it knows of the slice and reads no file; once the window has
+closed and the system is shut down, the trace is reduced in a child of
+this process (``trace_reduce.reduce_in_child``). A slice that was not
+brought home ends the run with a ``BenchError`` that says so
+(``lost_trace``), before any per-layer reader runs.
 """
 from __future__ import annotations
 
@@ -136,7 +148,7 @@ def run(found: dict, seed: int, seconds: float, trace: int,
         setup_s = t0 - H.PROCESS_START
         side = threading.Thread(
             target=_window_side, daemon=True,
-            args=(handle, t0, t1, trace, mix, tracer, describe))
+            args=(handle, t0, t1, trace, mix, tracer))
         side.start()
         if mix["loop"] == "open":
             t_close = perf_loadgen.run_open(
@@ -152,6 +164,10 @@ def run(found: dict, seed: int, seconds: float, trace: int,
                                f"loop")
         closed["t"] = t_close
         side.join(120.0)
+        if side.is_alive():
+            tracer["error"] = (f"the window's side thread was still at "
+                               f"{tracer.get('at')!r} 120 s after the "
+                               f"load had ended")
         after = tracer.get("at_close") or handle.report.remote().result()
         final = handle.report.remote().result()
         arrivals = handle.arrivals_log.remote().result()
@@ -173,6 +189,14 @@ def run(found: dict, seed: int, seconds: float, trace: int,
         e2e = perf_metrics.closed_loop(rows, t0, t1)
     e2e["setup_s"] = setup_s
     st0, st1, st2 = before["stats"], after["stats"], final["stats"]
+    if trace and not tracer.get("error"):
+        import trace_reduce
+
+        try:
+            tracer["red"] = trace_reduce.reduce_in_child(
+                dict(tracer["handoff"], describe=describe), out)
+        except Exception as e:  # noqa: BLE001 - the run fails below
+            tracer["error"] = _one_line(e)
     delta = {k: st1[k] - st0[k] for k in st1
              if isinstance(st1.get(k), (int, float))
              and isinstance(st0.get(k), (int, float))
@@ -197,6 +221,8 @@ def run(found: dict, seed: int, seconds: float, trace: int,
         "e2e": e2e, "stats_before": st0, "stats_after": st1,
         "stats_delta": delta, "arrivals": arrivals,
         "trace": tracer["red"], "trace_mid": tracer.get("mid"),
+        "trace_slice": tracer.get("slice"),
+        "trace_error": tracer.get("error"),
         "polls": tracer.get("polls"), "device": final["device"],
         "memory_peak_bytes": final["memory_peak_bytes"],
         "peaks": H.peaks(final["device"]["kind"]) if require_tpu
@@ -219,6 +245,10 @@ def run(found: dict, seed: int, seconds: float, trace: int,
         "fill_requests": len(fill), "health": health,
         "trace_cost": (tracer["red"] or {}).get("cost"),
         "memory_stats": final.get("memory_stats")}), flush=True)
+    if trace:
+        lost = lost_trace(tracer, health)
+        if lost:
+            raise H.BenchError(lost)
     return {"run": run_data, "correct": correct,
             "attempted": e2e["attempted"], "failed": e2e["failed"],
             "device": H.device_entry(final["device"],
@@ -271,9 +301,38 @@ def _served_check(conf: dict, mix: dict, served: list,
     return out
 
 
-def _window_side(handle, t0, t1, trace, mix, tracer, describe):
+def lost_trace(tracer: dict, health: dict):
+    """Why a traced run has no trace to read, in one line, or None:
+    the slice was not brought home (whatever the window's side or the
+    reduction raised), or it was and the replica that answers at the
+    end is not the one the run began with, so that the counters'
+    readings are two engines'. Each with what is known of the slice."""
+    pids = f"pid {health['pid_before']} -> {health['pid_after']}"
+    replaced = health["pid_before"] != health["pid_after"]
+    known = dict(tracer.get("slice") or {})
+    known.update((tracer.get("red") or {}).get("cost") or {})
+    known["driver_restarts"] = health["driver_restarts"]
+    facts = ", ".join(
+        f"{k} {known[k]}" for k in ("trace_stop_s", "slice_s", "launches",
+                                    "ended_by", "device_events",
+                                    "driver_restarts")
+        if known.get(k) is not None)
+    if tracer.get("error"):
+        return (f"the traced slice was lost: {tracer['error']} ({facts}"
+                + (f"; the replica was replaced, {pids}" if replaced
+                   else "") + ")")
+    if replaced:
+        return (f"the replica was replaced during the run ({pids}); "
+                f"{facts}")
+    return None
+
+
+def _window_side(handle, t0, t1, trace, mix, tracer):
     """Beside the load: the traced slice of the window, the engine's
-    counters every two seconds and as the window closes."""
+    counters every two seconds and as the window closes. The slice ends
+    by the mix's ``trace_launches`` (the replica watches its engine's
+    counter) or after ``trace_s``, whichever comes first; ``mid`` is
+    its middle as it was."""
     polls = tracer.setdefault("polls", [])
 
     def poll_until(t):
@@ -290,18 +349,30 @@ def _window_side(handle, t0, t1, trace, mix, tracer, describe):
         if trace:
             a = t0 + float(mix.get("trace_after_s", 5.0))
             b = min(a + float(mix.get("trace_s", 4.0)), t1 - 0.5)
+            launches = mix.get("trace_launches")
             poll_until(a)
-            handle.trace_start.remote().result()
-            _sleep_until(b)
-            handle.trace_stop.remote().result()
-            tracer["mid"] = (a + b) / 2
+            tracer["at"] = "trace_start"
+            handle.trace_start.remote(launches, b - a).result()
+            if not launches:
+                _sleep_until(b)
+            tracer["at"] = "trace_stop"
+            tracer["slice"] = handle.trace_stop.remote().result()
+            tracer["mid"] = tracer["slice"]["mid_s"]
+        tracer["at"] = "the window's close"
         poll_until(t1)
         tracer["at_close"] = handle.report.remote().result()
         if trace:
-            tracer["red"] = handle.trace_result.remote(describe).result()
+            tracer["at"] = "trace_result"
+            tracer["handoff"] = handle.trace_result.remote().result()
     except Exception as e:  # noqa: BLE001 - reported, and the run fails
-        tracer["error"] = repr(e)
+        tracer["error"] = f"{tracer.get('at')}: {_one_line(e)}"
         print(f"WINDOW-SIDE ERROR {e!r}", flush=True)
+
+
+def _one_line(exc: Exception, chars: int = 400) -> str:
+    """An exception as ``Class(message)`` on one line: an error that
+    came through the handle carries the replica's traceback."""
+    return f"{type(exc).__name__}({' '.join(str(exc).split())[:chars]})"
 
 
 def _sleep_until(t):

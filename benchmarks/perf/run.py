@@ -27,6 +27,15 @@ This process never imports jax: the chip belongs to the replica
 (serving) or the training child. No TPU, or fewer chips than the cell
 asks for: a non-zero exit and no result line.
 
+What cannot run as asked ends with exit 2 and its cause as the last
+line of standard error (``perfbench: ...``). A traced run has two
+causes of its own, told apart: the traced slice was not brought home
+(``the traced slice was lost: ...``, ``the replica was replaced during
+the run ...``: ``perf_serve_cell.lost_trace``, checked before any
+reader runs), or it was and a listed per-layer metric's reader finds
+nothing in it (``... no longer matches what the program or the trace
+offers``: the reader is stale).
+
 Two more modes help whoever defines a cell (they print tables, not a
 result line): ``--sweep r1,r2,...`` runs an open-loop mix at each rate
 and reports queue growth and lateness, to find the knee; ``--soak``

@@ -18,8 +18,19 @@ make). Without it, or without ``paths`` (the recordings of PRs 23 and
 24), the operation is unscoped.
 
 :func:`load_xplane` makes that form from the ``.xplane.pb`` the JAX
-profiler writes (needs jax; only the process that holds the chip calls
-it). Everything else is plain Python.
+profiler writes (needs jax for ``jax.profiler.ProfileData``, and no
+device). Everything else is plain Python.
+
+Who reduces. The process that traced hands out what only it knows
+(``perf_deployment.Tracer.handoff``: the trace's directory, the host
+samples, the slice's ends and the marker's time on the host's clock)
+and :func:`reduce_handoff` makes the numbers from that and the file. A
+training child calls it itself once its window is over. A serving
+replica never does: its driver, which imports no jax, calls
+:func:`reduce_in_child` once the window has closed, and this file then
+runs as a child process on the CPU (``python trace_reduce.py
+<handoff.json> <result.json>``, ``JAX_PLATFORMS=cpu``), so that
+nothing holds the replica's interpreter after ``stop_trace()``.
 
 A TPU device is a plane ``/device:TPU:<n>``; its line ``XLA Ops`` holds
 one event per executed HLO operation (nested for control flow: a
@@ -43,8 +54,12 @@ from __future__ import annotations
 
 import bisect
 import glob
+import json
 import os
 import re
+import subprocess
+import sys
+import time
 from typing import Dict, List, Optional, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
@@ -486,3 +501,80 @@ def _attribute(gaps, samples, offset_ns, top: int) -> List[list]:
             by[cur] = by.get(cur, 0) + b - edge
     return [[k, v / 1e9] for k, v in sorted(
         by.items(), key=lambda kv: -kv[1])[:top]]
+
+
+def load_trace(path: str) -> dict:
+    """The neutral form of a trace file: of an ``.xplane.pb``, or of a
+    ``.json`` that holds the form already (``recorded/``)."""
+    if path.endswith(".json"):
+        with open(path) as f:
+            return json.load(f)
+    return load_xplane(path)
+
+
+def reduce_handoff(h: dict) -> dict:
+    """``run["trace"]``, from what the tracing process handed out
+    (``perf_deployment.Tracer.handoff``, with ``"describe"``) and the
+    file it wrote: :func:`reduce` over the slice, with what tracing
+    cost. ``h["path"]`` names the file where it is not the newest
+    under ``h["log_dir"]``."""
+    t0 = time.monotonic()
+    path = h.get("path") or find_xplane(h["log_dir"])
+    trace = load_trace(path)
+    samples = h["samples"]
+    sync = sync_event_ns(trace)
+    offset = None if sync is None else sync - h["sync_host_ns"]
+    window = None
+    if offset is not None:
+        window = (h["t_start"] + offset, h["t_stop"] + offset)
+    red = reduce(trace, window=window, samples=samples,
+                 host_offset_ns=offset)
+    red["host_window_s"] = (h["t_stop"] - h["t_start"]) / 1e9
+    red["samples"] = len(samples)
+    red["cost"] = {
+        "trace_stop_s": h["trace_stop_s"], "slice_s": h["slice_s"],
+        "launches": h["launches"], "ended_by": h["ended_by"],
+        "xplane_bytes": os.path.getsize(path),
+        "device_events": sum(
+            len(ln["events"]) for p in trace["planes"]
+            if DEVICE_PLANE.match(p["name"]) for ln in p["lines"])}
+    if h.get("describe"):
+        red["describe"] = describe(path)
+        if window is not None:
+            red["cut"] = cut(trace, (window[0] + 10 ** 9,
+                                     window[0] + 10 ** 9 + 7 * 10 ** 8),
+                             samples, offset)
+    red["cost"]["reduce_s"] = time.monotonic() - t0
+    return red
+
+
+def reduce_in_child(handoff: dict, workdir: str) -> dict:
+    """:func:`reduce_handoff` in a child process on the CPU: for a
+    caller that may not import jax (a serving cell's driver) and whose
+    replica is not to read a file. The same function on the same file
+    with the same arguments gives the same dictionary, as JSON carries
+    it; ``reduce_s`` is the child's. Raises RuntimeError with the end
+    of the child's standard error where it fails."""
+    src, dst = (os.path.join(workdir, n) for n in
+                ("trace_handoff.json", "trace_reduced.json"))
+    with open(src, "w") as f:
+        json.dump(handoff, f)
+    if os.path.exists(dst):
+        os.remove(dst)
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), src, dst],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True)
+    if proc.returncode != 0 or not os.path.exists(dst):
+        raise RuntimeError(
+            f"the reduction's child exited {proc.returncode}: "
+            f"{proc.stderr.strip()[-600:]}")
+    with open(dst) as f:
+        return json.load(f)
+
+
+if __name__ == "__main__":
+    with open(sys.argv[1]) as _f:
+        _red = reduce_handoff(json.load(_f))
+    with open(sys.argv[2], "w") as _f:
+        json.dump(_red, _f)

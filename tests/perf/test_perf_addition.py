@@ -71,9 +71,13 @@ def test_whole_run_of_the_added_chat_cell_ends_in_one_result_line(
                             if ln.startswith("SETUP "))[6:])
     cost = setup["trace_cost"]
     assert set(cost) == {"trace_stop_s", "xplane_bytes", "device_events",
-                         "reduce_s"}
+                         "reduce_s", "slice_s", "launches", "ended_by"}
     assert cost["trace_stop_s"] > 0 and cost["xplane_bytes"] > 0
     assert cost["device_events"] == 0          # a CPU has no device plane
+    # nano-chat states no trace_launches: its slice is its trace_s, as
+    # before PR 31, and the launches it held are counted all the same
+    assert cost["ended_by"] == "seconds" and cost["launches"] > 0
+    assert 0.8 <= cost["slice_s"] < 1.5
     # each number compared stands beside its limit at the end of stderr
     tail = [ln for ln in err.strip().splitlines()
             if ln.startswith("perfbench ")]

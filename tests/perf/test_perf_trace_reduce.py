@@ -369,3 +369,78 @@ def test_recorded_scoped_trace_names_the_kernel_by_its_path():
     names = {k.split("(")[0] for k in red["programs"]}
     assert {"jit_decode_chunk_slots_paged",
             "jit_prefill_into_slot_paged"} <= names
+
+
+# ---- the reduction outside the process that traced (PR 31)
+
+def _handoff_for(name, tmp_path):
+    """What a tracing process would hand out for a file: a recording
+    (its own window and samples, the marker event added to a copy so
+    that the two clocks tie as in a run), or the hand-made
+    ``.xplane.pb``."""
+    if name == "xplane":
+        path, _shared = _xplane_file(tmp_path)
+        return {"path": path, "log_dir": "unused", "samples": [
+            [10, "engine.py:_run"], [200, "engine.py:_dispatch_chunk"],
+            [1150, "engine.py:_prefill_paged"]],
+            "t_start": 50, "t_stop": 1500, "sync_host_ns": 0,
+            "trace_stop_s": 0.25, "slice_s": 1.45e-6, "launches": 1,
+            "ended_by": "launches", "describe": False}
+    with open(os.path.join(perf_testlib.PERF, "recorded",
+                           name + ".json")) as f:
+        rec = json.load(f)
+    rec["planes"].append({"name": "/host:CPU", "lines": [
+        {"name": "python", "events": [[R.SYNC_EVENT, 7000, 1]]}]})
+    path = os.path.join(str(tmp_path), name + "-synced.json")
+    with open(path, "w") as f:
+        json.dump(rec, f)
+    a, b = rec["window"]
+    # host clock = trace clock - 7000 + 4000: an offset of 3000
+    return {"path": path, "log_dir": "unused",
+            "samples": [[t - 3000, lab] for t, lab in rec["samples"]],
+            "t_start": a - 3000, "t_stop": b - 3000,
+            "sync_host_ns": 4000, "trace_stop_s": 7.5,
+            "slice_s": (b - a) / 1e9, "launches": 3,
+            "ended_by": "seconds", "describe": False}
+
+
+@pytest.mark.parametrize("name", ["trace_small", "trace_scoped",
+                                  "xplane"])
+def test_reduction_in_a_child_gives_the_in_process_dictionary(
+        name, tmp_path):
+    """``reduce_in_child`` (a serving cell's driver, which imports no
+    jax, calls it once the window has closed) runs the same function on
+    the same file with the same arguments in a child on the CPU; only
+    ``reduce_s`` is the child's own."""
+    h = _handoff_for(name, tmp_path)
+    here = R.reduce_handoff(h)
+    child = R.reduce_in_child(h, str(tmp_path))
+    assert child["cost"].pop("reduce_s") > 0
+    assert here["cost"].pop("reduce_s") > 0
+    assert child == json.loads(json.dumps(here))
+    assert here["cost"] == {
+        "trace_stop_s": h["trace_stop_s"], "slice_s": h["slice_s"],
+        "launches": h["launches"], "ended_by": h["ended_by"],
+        "xplane_bytes": os.path.getsize(h["path"]),
+        "device_events": sum(len(ln["events"]) for ln in next(
+            p for p in R.load_trace(h["path"])["planes"]
+            if R.DEVICE_PLANE.match(p["name"]))["lines"])}
+    if name != "xplane":
+        # and it is the reduction the recording's own tests make
+        with open(os.path.join(perf_testlib.PERF, "recorded",
+                               name + ".json")) as f:
+            rec = json.load(f)
+        plain = R.reduce(rec, window=tuple(rec["window"]),
+                         samples=[tuple(s) for s in rec["samples"]],
+                         host_offset_ns=rec["host_offset_ns"])
+        for key, val in plain.items():
+            assert here[key] == val, key
+    else:
+        assert here["devices"] == 1 and here["window_s"] == 1450e-9
+        assert here["launches_by_host"]["engine.py:_dispatch_chunk"][
+            "launches"] == 1
+
+
+def test_a_child_that_fails_says_so(tmp_path):
+    with pytest.raises(RuntimeError, match="(?s)child exited 1.*No such"):
+        R.reduce_in_child({"path": "/nonexistent/x.json"}, str(tmp_path))
