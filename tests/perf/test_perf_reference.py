@@ -11,6 +11,7 @@ import pytest
 import perf_testlib
 
 import kernel_costs
+import perf_harness
 import reference_gpt2
 
 
@@ -129,10 +130,13 @@ def _fp8(x):
                       .astype(jnp.float32))
 
 
+def _serve_conf():
+    return perf_harness.load_json(os.path.join(
+        perf_testlib.PERF, "configs", "cerebras-gpt-1.3b-serve.json"))
+
+
 def _serve_tol():
-    with open(os.path.join(perf_testlib.PERF, "configs",
-                           "cerebras-gpt-1.3b-serve.json")) as f:
-        return json.load(f)["correct"]["logits_rel_tol"]
+    return _serve_conf()["correct"]["logits_rel_tol"]
 
 
 def test_bfloat16_program_is_inside_the_logits_tolerance(nano):
@@ -206,17 +210,97 @@ def test_loss_agrees_with_the_program(nano):
 
 def test_parameter_count_is_the_programs_and_costs_follow_shapes(nano):
     gpt, cfg, _params, _tokens = nano
-    with open(os.path.join(perf_testlib.PERF, "configs",
-                           "cerebras-gpt-1.3b-serve.json")) as f:
-        model = json.load(f)["model"]
+    model = _serve_conf()["model"]
     assert kernel_costs.n_params(model) == gpt.CONFIGS["1b"].num_params()
     assert 1.2e9 < kernel_costs.n_params(model) < 1.45e9
-    b0 = kernel_costs.decode_step_bytes(model, 4, 2, 0)
-    b1 = kernel_costs.decode_step_bytes(model, 4, 2, 1000)
-    assert b0 == 4 * kernel_costs.n_params(model)
+    # a decode step multiplies by everything but the position table, and
+    # is charged each such weight once in the dtype it is multiplied in
+    assert kernel_costs.decode_weight_params(model) == \
+        kernel_costs.n_params(model) - 2048 * 2048
+    b0 = kernel_costs.decode_step_bytes(model, 2, 2, 0)
+    b1 = kernel_costs.decode_step_bytes(model, 2, 2, 1000)
+    assert b0 == 2 * kernel_costs.decode_weight_params(model)
+    assert 2.60e9 < b0 < 2.65e9
     assert b1 - b0 == 2 * 24 * 2048 * 2 * 1000
     assert kernel_costs.train_flops_per_token(model, 2048) == \
         6 * kernel_costs.n_params(model) + 12 * 24 * 2048 * 2048
+
+
+def _decode_run(step_ms, live, numerics=None):
+    """What ``decode_roofline_pct`` reads of a traced serving run, made
+    by hand at the 1.3B configuration: eleven chunk launches of
+    ``step_ms`` a token step while the driver waited for a chunk, and
+    one stream whose prompt and delivered tokens sum to ``live`` at the
+    slice's middle, beside one that ended before it and one that
+    started after."""
+    from program_names import CHUNK_WAIT
+
+    conf = _serve_conf()
+    if numerics is not None:
+        conf["numerics"] = numerics
+    launches = 11
+    seconds = step_ms * 1e-3 * conf["engine"]["chunk"] * launches
+    return {
+        "conf": conf, "trace_mid": 10.0,
+        "peaks": perf_harness.peaks("TPU v5 lite"),
+        "trace": {"launches_by_host": {CHUNK_WAIT: {
+            "launches": launches, "seconds": seconds,
+            "programs": {"jit_decode_chunk_slots_paged": {
+                "launches": launches, "seconds": seconds}}}}},
+        "rows": [
+            {"prompt_len": live - 24, "end": None,
+             "slices": [[8.0, 16], [9.5, 8], [10.5, 8]]},
+            {"prompt_len": 300, "end": 9.0, "slices": [[7.0, 64]]},
+            {"prompt_len": 300, "end": None, "slices": [[10.2, 8]]}]}
+
+
+@pytest.mark.parametrize("step_ms,live,want", [
+    (36.666, 966, 9.4), (119.51, 13102, 5.3),     # the tree (ledger, PR 31)
+    (5.7223, 466, 57.9), (9.1292, 11969, 66.5)],  # PR 32's change (ledger)
+    ids=["tree-chat", "tree-batch", "pr32-chat", "pr32-batch"])
+def test_decode_roofline_reads_the_ledgers_steps(step_ms, live, want):
+    """The ledger's four steps under the count of PR 33 (weights once in
+    the compute dtype, 2.62 GB = 3.2 ms, and the live keys and values):
+    the two that read 114.2 and 101.8 under 4 bytes a weight are a sound
+    58 and 67."""
+    run = _decode_run(step_ms, live)
+    got = perf_harness.load_reader("decode_roofline_pct").read(run)
+    assert got == pytest.approx(want, abs=0.3)
+    assert got == pytest.approx(100 * kernel_costs.decode_step_bytes(
+        run["conf"]["model"], 2, 2, live) / 819e9 / (step_ms / 1e3))
+
+
+@pytest.mark.parametrize("held", ["float32", "bfloat16"])
+def test_decode_roofline_ignores_how_the_weights_are_held(held):
+    """Two configurations that differ only in ``param_dtype`` read the
+    same: the bytes are any program's, not this one's."""
+    base = _decode_run(36.666, 966)
+    other = _decode_run(36.666, 966, numerics={
+        "param_dtype": held, "compute_dtype": "bfloat16",
+        "kv_dtype": "bfloat16"})
+    read = perf_harness.load_reader("decode_roofline_pct").read
+    assert read(other) == read(base)
+
+
+@pytest.mark.parametrize("case", ["a_step_no_chip_can_make",
+                                  "no_compute_dtype", "the_sat_twin"])
+def test_decode_roofline_still_bites(case):
+    read = perf_harness.load_reader("decode_roofline_pct").read
+    if case == "a_step_no_chip_can_make":
+        # 2.5 ms at PR 32's live tokens: layers left out, or time lost.
+        # The driver refuses a share any run reads above 105
+        got = read(_decode_run(2.5, 466))
+        assert got > 105 and got == pytest.approx(132.5, abs=0.5)
+    elif case == "no_compute_dtype":
+        run = _decode_run(36.666, 966, numerics={
+            "param_dtype": "float32", "kv_dtype": "bfloat16"})
+        with pytest.raises(KeyError, match="compute_dtype"):
+            read(run)
+    else:
+        run = _decode_run(9.1292, 11969)
+        sat = perf_harness.load_reader("decode_roofline_pct.sat")
+        assert sat.read(run) == read(run)
+        assert (sat.LAYER, sat.UNIT) == ("kernels", "%")
 
 
 def _synthetic(rng, n=9, rows=300, best=4.0):
