@@ -50,24 +50,12 @@ completion latency, total decoded tok/s, and — continuous only — slot
 occupancy and dispatches/token from the engine's own accounting.
 ``--smoke`` shrinks the load so the A/B runs inside tier-1 CI.
 
-``--paged`` (ISSUE 6) switches to the paged-KV A/B: a flat slot pool
-and a paged pool built from the SAME KV-byte budget (``n_pages *
-page_size == flat_slots * max_len`` positions) are driven with an
-identical saturating burst of mixed-length requests sharing a common
-system prompt. Reports, per pool: decoded tok/s, TTFT/completion
-percentiles, and PEAK CONCURRENT SLOTS (the paged pool runs ~3x the
-lanes on the same bytes because real sequences are shorter than
-max_len); then a shared-prefix TTFT probe — median TTFT of a request
-whose system prompt is prefix-cached (page-table copy + short-suffix
-prefill) vs the flat pool's full prefill. ``--smoke`` shrinks it for
-tier-1 CI.
-
 ``--spec`` (ISSUE 9) switches to the speculative-decoding A/B: the
 SAME saturating burst of repetitive-suffix prompts is driven through
 three engines built on identical weights — spec off, the n-gram
 drafter, and the tied-embedding model drafter — off/ngram driven
 back-to-back in every pass with best-of-5 per mode, the same one-sided
-noise discipline as ``--paged``/``--continuous``. The workload is
+noise discipline as ``--continuous``. The workload is
 SCREENED: candidate prompts' greedy continuations are simulated once
 against the n-gram drafter and the most predictable drive the A/B.
 Reports, per mode: decoded tok/s, TTFT p50, TPOT p50/p95, and — spec
@@ -152,11 +140,6 @@ def main():
                              "@serve.batch vs the slot-pool DecodeEngine "
                              "under the same Poisson arrivals with mixed "
                              "output lengths")
-    parser.add_argument("--paged", action="store_true",
-                        help="paged-KV A/B: flat slot pool vs paged "
-                             "pool at the SAME KV-byte budget, plus a "
-                             "shared-prefix TTFT probe (direct engine "
-                             "drive, no serve stack)")
     parser.add_argument("--chaos", action="store_true",
                         help="crash-safety run: kill a replica of a "
                              "2-replica engine deployment mid-load and "
@@ -183,18 +166,6 @@ def main():
                              "are nearly free and locked-in repetitive "
                              "streams commit k+1 tokens per forward)")
     parser.add_argument("--page-size", type=int, default=8)
-    parser.add_argument("--kv-dtype", default="fp",
-                        choices=("fp", "int8"),
-                        help="with --paged: int8 adds an equal-HBM-byte "
-                             "fp-vs-int8 A/B arm (concurrent lanes, "
-                             "TTFT p50, tok/s) after the flat/paged "
-                             "rows (ISSUE 16)")
-    parser.add_argument("--attn-kernel", default="gather",
-                        choices=("gather", "pallas"),
-                        help="with --paged: pallas adds a kernel-on vs "
-                             "kernel-off TPOT A/B arm (CPU runs the "
-                             "kernel in interpret mode — correctness "
-                             "plumbing, not speed) (ISSUE 16)")
     parser.add_argument("--tp", type=int, default=1,
                         help="tensor-parallel A/B (ISSUE 20): the same "
                              "saturating burst through a tp=1 engine "
@@ -205,7 +176,7 @@ def main():
                              "CPU the mesh is forced host devices — "
                              "plumbing and exactness, not speed)")
     parser.add_argument("--smoke", action="store_true",
-                        help="with --continuous/--paged: shrunk load "
+                        help="shrunk load "
                              "for tier-1 CI (fewer requests, shorter "
                              "outputs)")
     parser.add_argument("--slots", type=int, default=8,
@@ -234,13 +205,6 @@ def main():
                 f"{max(8, args.tp)}").strip()
         cfg_name = args.config
         run_tp_ab(args, np, cfg_name, f"gpt_{cfg_name}")
-        return
-
-    if args.paged:
-        # Direct engine drive: the A/B isolates the pool architecture
-        # (flat reservation vs pages) from the serve transport.
-        cfg_name = args.config
-        run_paged_ab(args, np, cfg_name, f"gpt_{cfg_name}")
         return
 
     if args.spec:
@@ -1027,177 +991,6 @@ def run_continuous_ab(args, serve, np, cfg_name, model):
     }))
 
 
-def run_paged_ab(args, np, cfg_name, model):
-    """ISSUE 6 acceptance A/B: flat slot pool vs paged pool on the SAME
-    KV-byte budget (``n_pages * page_size == flat_slots * max_len``
-    cache positions), identical burst workload with a shared system
-    prompt; then a shared-prefix TTFT probe (prefix-cached admission vs
-    full prefill). Drives the engines directly — no serve stack — so
-    the rows measure pool architecture, not transport."""
-    import threading as _th
-
-    import jax
-
-    from ray_tpu.models import gpt
-    from ray_tpu.serve.engine import DecodeEngine
-
-    cfg = gpt.CONFIGS[cfg_name]
-    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-    ps = args.page_size
-    chunk = 8
-    flat_slots = 4 if args.smoke else max(4, args.slots // 2)
-    max_len = 96 if args.smoke else min(192, cfg.max_seq)
-    if ps < 1 or max_len % ps:
-        sys.exit(f"--page-size {ps} must be a positive divisor of "
-                 f"max_len={max_len} so the flat and paged pools can "
-                 f"hold the same KV bytes (try one of "
-                 f"{[d for d in (4, 8, 12, 16, 24, 32, 48) if max_len % d == 0]})")
-    lanes = 3 * flat_slots            # paged lane count, same KV bytes
-    n_pages = flat_slots * (max_len // ps)
-    sys_len = 16 if args.smoke else 64
-    tail_len = 8
-    plen = sys_len + tail_len
-    mix = [8, 16, 24] if args.smoke else [16, 32, 48]
-    n_req = 4 * flat_slots if args.smoke else 6 * flat_slots
-    buckets = tuple(b for b in (8, 16, 32, 64, 128)
-                    if b <= max_len and b >= tail_len) or (max_len,)
-    buckets = tuple(sorted(set(buckets) | {
-        next(b for b in (8, 16, 32, 64, 128, max_len) if b >= plen)}))
-    kv_positions = flat_slots * max_len
-    assert n_pages * ps == kv_positions, "budgets must match"
-
-    rng = np.random.default_rng(42)
-    sysp = rng.integers(0, cfg.vocab_size, (sys_len,)).astype(np.int32)
-
-    def mk_prompt(rid):
-        tail = np.random.default_rng(500 + rid).integers(
-            0, cfg.vocab_size, (tail_len,)).astype(np.int32)
-        return np.concatenate([sysp, tail])
-
-    max_news = np.random.default_rng(7).choice(mix, size=n_req)
-
-    def build(paged):
-        if paged:
-            return DecodeEngine(
-                params, cfg, slots=lanes, chunk=chunk, max_len=max_len,
-                prompt_buckets=buckets, paged=True, page_size=ps,
-                n_pages=n_pages, prefix_cache=True,
-                deployment="paged_bench")
-        return DecodeEngine(params, cfg, slots=flat_slots, chunk=chunk,
-                            max_len=max_len, prompt_buckets=buckets,
-                            deployment="flat_bench")
-
-    def drive(eng):
-        """Saturating burst: all n_req requests queued at t=0."""
-        ttfts = [None] * n_req
-        comps = [None] * n_req
-        toks = [0] * n_req
-
-        def one(i):
-            t0 = time.perf_counter()
-            first = None
-            n = 0
-            for s in eng.stream(mk_prompt(i), int(max_news[i]), seed=i):
-                if first is None:
-                    first = time.perf_counter() - t0
-                n += s.shape[0]
-            ttfts[i] = first
-            comps[i] = time.perf_counter() - t0
-            toks[i] = n
-
-        threads = [_th.Thread(target=one, args=(i,))
-                   for i in range(n_req)]
-        t0 = time.perf_counter()
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        wall = time.perf_counter() - t0
-        bad = [(i, toks[i], int(max_news[i]))
-               for i in range(n_req) if toks[i] != max_news[i]]
-        assert not bad, f"short streams (i, got, want): {bad}"
-        return ttfts, comps, wall, sum(toks)
-
-    def ttft_probe(eng, repeats=7):
-        """Median TTFT of a lone request on an idle engine (the paged
-        engine's prefix cache is warm by now: admission is a page-table
-        copy + tail-bucket prefill instead of a full-prompt prefill)."""
-        outs = []
-        for r in range(repeats):
-            t0 = time.perf_counter()
-            it = eng.stream(mk_prompt(1000 + r), 2, seed=r)
-            next(iter(it))
-            outs.append(time.perf_counter() - t0)
-            list(it)
-        return pct(outs, 0.5)
-
-    results = {}
-    for mode in ("flat", "paged"):
-        eng = build(mode == "paged")
-        try:
-            # Warm every compile path (and, paged, the prefix cache)
-            # before the clock starts.
-            for r in range(2):
-                list(eng.stream(mk_prompt(0), max(mix), seed=0))
-            ttfts, comps, wall, total = drive(eng)
-            st = eng.stats()
-            probe_ms = ttft_probe(eng) * 1000
-            row = {
-                "metric": f"serve_{model}_paged_{mode}_mode",
-                "value": round(total / wall, 1), "unit": "tokens/s",
-                "ttft_p50_ms": round(pct(ttfts, 0.5) * 1000, 2),
-                "ttft_p95_ms": round(pct(ttfts, 0.95) * 1000, 2),
-                "completion_p50_ms": round(pct(comps, 0.5) * 1000, 2),
-                "completion_p95_ms": round(pct(comps, 0.95) * 1000, 2),
-                "lone_ttft_p50_ms": round(probe_ms, 2),
-                "slots_configured": st["slots"],
-                "peak_concurrent_slots": st["peak_active"],
-                "avg_occupancy": round(st["avg_occupancy"], 3),
-                "dispatches_per_token": round(
-                    st["dispatches_per_token"], 4),
-                "kv_budget_positions": kv_positions,
-                "requests": n_req, "chunk": chunk,
-                "output_len_mix": [int(m) for m in mix],
-                "prompt_len": plen, "shared_prefix_len": sys_len,
-            }
-            if mode == "paged":
-                row.update({
-                    "page_size": ps, "n_pages": n_pages,
-                    "prefix_hits": st["prefix_hits"],
-                    "prefix_tokens_reused": st["prefix_tokens_reused"],
-                    "cow_copies": st["cow_copies"],
-                    "lane_parks": st["lane_parks"],
-                    "admissions_deferred": st["admissions_deferred"],
-                    "preempted": st["preempted"],
-                    "pages_free": st["pages_free"],
-                })
-            print(json.dumps(row))
-            results[mode] = row
-        finally:
-            eng.shutdown()
-    fl, pg = results["flat"], results["paged"]
-    print(json.dumps({
-        "metric": f"serve_{model}_paged_ab",
-        "value": round(pg["peak_concurrent_slots"]
-                       / max(fl["slots_configured"], 1), 2),
-        "unit": "x_concurrent_slots_equal_kv_bytes",
-        "tok_s_ratio": round(pg["value"] / max(fl["value"], 1e-9), 2),
-        "ttft_p50_ratio": round(fl["ttft_p50_ms"]
-                                / max(pg["ttft_p50_ms"], 1e-9), 2),
-        "prefix_hit_ttft_ms": pg["lone_ttft_p50_ms"],
-        "full_prefill_ttft_ms": fl["lone_ttft_p50_ms"],
-        "prefix_ttft_speedup": round(
-            fl["lone_ttft_p50_ms"]
-            / max(pg["lone_ttft_p50_ms"], 1e-9), 2),
-        "kv_budget_positions": kv_positions,
-        "smoke": bool(args.smoke),
-    }))
-    if args.kv_dtype == "int8":
-        _run_kv_dtype_arm(args, np, cfg, params, model)
-    if args.attn_kernel == "pallas":
-        _run_attn_kernel_arm(args, np, cfg, params, model)
-
-
 def _drive_burst(eng, prompts, max_new, *, np):
     """Saturating burst shared by the ISSUE 16 arms: every request
     queued at t=0, one thread per request. Returns per-request
@@ -1235,170 +1028,6 @@ def _drive_burst(eng, prompts, max_new, *, np):
     return ttfts, comps, wall, streams
 
 
-def _run_kv_dtype_arm(args, np, cfg, params, model):
-    """ISSUE 16 A/B: fp-paged vs int8-paged pools on the SAME HBM byte
-    budget, prefix cache OFF so every lane pays for its own pages. The
-    binding resource is page BYTES: an int8 page (codes + amortized
-    per-page scales) costs about half a bf16 page, so the equal-byte
-    int8 pool holds ~2x the pages and admits ~2x the concurrent lanes.
-    The workload is sized so a lane's admission-time page demand equals
-    its lifetime demand (the prompt's last page absorbs the whole
-    generation), making measured peak concurrency the page-capacity
-    ratio rather than an admission-timing artifact."""
-    from ray_tpu.models import gpt_decode
-    from ray_tpu.serve.engine import DecodeEngine
-
-    ps = args.page_size
-    # plen one short of a page boundary; max_new fills the rest of the
-    # final page: admit-time pages == lifetime pages == T.
-    T = 4 if args.smoke else 6
-    plen = (T - 1) * ps + 1
-    max_new = T * ps - plen
-    max_len = T * ps
-    base_lanes = 3 if args.smoke else 4      # fp lane capacity
-    fp_bytes = gpt_decode.kv_bytes_per_page(cfg, ps)
-    i8_bytes = gpt_decode.kv_bytes_per_page(cfg, ps, "int8")
-    n_pages_fp = base_lanes * T
-    n_pages_i8 = (n_pages_fp * fp_bytes) // i8_bytes   # equal bytes
-    cap_fp = n_pages_fp // T
-    cap_i8 = n_pages_i8 // T
-    slots = cap_i8 + 2                        # pages bind, not slots
-    n_req = 3 * cap_i8
-    rng = np.random.default_rng(11)
-    prompts = [rng.integers(0, cfg.vocab_size, (plen,)).astype(np.int32)
-               for _ in range(n_req)]
-
-    rows = {}
-    for dt, n_pages in (("fp", n_pages_fp), ("int8", n_pages_i8)):
-        eng = DecodeEngine(
-            params, cfg, slots=slots, chunk=8, max_len=max_len,
-            prompt_buckets=(plen,), paged=True, page_size=ps,
-            n_pages=n_pages, prefix_cache=False, kv_dtype=dt,
-            deployment=f"kv_{dt}_bench")
-        try:
-            list(eng.stream(prompts[0], max_new, seed=0))   # warm
-            ttfts, comps, wall, streams = _drive_burst(
-                eng, prompts, max_new, np=np)
-            st = eng.stats()
-            rows[dt] = {
-                "metric": f"serve_{model}_kv_{dt}_mode",
-                "value": round(n_req * max_new / wall, 1),
-                "unit": "tokens/s",
-                "ttft_p50_ms": round(pct(ttfts, 0.5) * 1000, 2),
-                "completion_p50_ms": round(pct(comps, 0.5) * 1000, 2),
-                "peak_concurrent_slots": st["peak_active"],
-                "lane_capacity": n_pages // T,
-                "n_pages": n_pages, "page_size": ps,
-                "kv_bytes_per_page": fp_bytes if dt == "fp"
-                else i8_bytes,
-                "kv_bytes_per_token": st["kv_bytes_per_token"],
-                "kv_budget_bytes": n_pages_fp * fp_bytes,
-                "admissions_deferred": st["admissions_deferred"],
-                "requests": n_req, "max_new": max_new,
-                "prompt_len": plen,
-            }
-            print(json.dumps(rows[dt]))
-        finally:
-            eng.shutdown()
-    # The sizing-fix satellite, shown live: an int8 engine left to the
-    # DEFAULT n_pages computes its budget from the int8 element size
-    # and gets ~2x the pages of the same-slot fp default.
-    dflt = DecodeEngine(params, cfg, slots=base_lanes, chunk=8,
-                        max_len=max_len, prompt_buckets=(plen,),
-                        paged=True, page_size=ps, prefix_cache=False,
-                        kv_dtype="int8", deployment="kv_dflt_bench")
-    default_n_pages = dflt.n_pages
-    dflt.shutdown()
-    fp_row, i8_row = rows["fp"], rows["int8"]
-    print(json.dumps({
-        "metric": f"serve_{model}_kv_dtype_ab",
-        "value": round(i8_row["peak_concurrent_slots"]
-                       / max(fp_row["peak_concurrent_slots"], 1), 2),
-        "unit": "x_concurrent_lanes_equal_kv_bytes",
-        "lane_capacity_ratio": round(cap_i8 / max(cap_fp, 1), 2),
-        "tok_s_ratio": round(i8_row["value"]
-                             / max(fp_row["value"], 1e-9), 2),
-        "ttft_p50_ratio": round(fp_row["ttft_p50_ms"]
-                                / max(i8_row["ttft_p50_ms"], 1e-9), 2),
-        "bytes_per_token_ratio": round(
-            fp_row["kv_bytes_per_token"]
-            / max(i8_row["kv_bytes_per_token"], 1e-9), 2),
-        "default_n_pages_int8": int(default_n_pages),
-        "default_n_pages_fp_equiv": base_lanes * T,
-        "kv_budget_bytes": n_pages_fp * fp_bytes,
-        "smoke": bool(args.smoke),
-    }))
-
-
-def _run_attn_kernel_arm(args, np, cfg, params, model):
-    """ISSUE 16 A/B: paged decode with the fused paged-attention kernel
-    on vs off (XLA gather reference), same engine geometry and burst.
-    Reports TPOT p50 per arm and checks the exactness contract live:
-    at temperature 0 the two arms must emit IDENTICAL token streams.
-    On CPU the kernel runs in Pallas interpret mode — the arm proves
-    plumbing and exactness there, not speed; the TPOT ratio is the
-    headline only when lowered to a real TPU."""
-    from ray_tpu.serve.engine import DecodeEngine
-
-    ps = args.page_size
-    plen = 2 * ps                             # two pages of history
-    max_new = 8 if args.smoke else 16
-    max_len = plen + max_new + ps
-    slots = 2 if args.smoke else 4
-    n_req = slots + 1                         # one lane reuses a slot
-    rng = np.random.default_rng(13)
-    prompts = [rng.integers(0, cfg.vocab_size, (plen,)).astype(np.int32)
-               for _ in range(n_req)]
-
-    rows = {}
-    token_streams = {}
-    for kern in ("gather", "pallas"):
-        eng = DecodeEngine(
-            params, cfg, slots=slots, chunk=4, max_len=max_len,
-            prompt_buckets=(plen,), paged=True, page_size=ps,
-            prefix_cache=False, attn_kernel=kern,
-            deployment=f"attn_{kern}_bench")
-        try:
-            list(eng.stream(prompts[0], max_new, seed=0))   # warm
-            ttfts, comps, wall, streams = _drive_burst(
-                eng, prompts, max_new, np=np)
-            token_streams[kern] = streams
-            tpots = [(comps[i] - ttfts[i]) / max(max_new - 1, 1)
-                     for i in range(n_req)]
-            st = eng.stats()
-            rows[kern] = {
-                "metric": f"serve_{model}_attn_{kern}_mode",
-                "value": round(pct(tpots, 0.5) * 1000, 3),
-                "unit": "tpot_p50_ms",
-                "ttft_p50_ms": round(pct(ttfts, 0.5) * 1000, 2),
-                "tok_s": round(n_req * max_new / wall, 1),
-                "kernel_dispatches": st.get("attn_kernel_dispatches",
-                                            0),
-                "requests": n_req, "max_new": max_new,
-                "prompt_len": plen,
-            }
-            print(json.dumps(rows[kern]))
-        finally:
-            eng.shutdown()
-    identical = all(
-        np.array_equal(token_streams["gather"][i],
-                       token_streams["pallas"][i])
-        for i in range(n_req))
-    assert identical, "kernel arm diverged from gather at temp 0"
-    from ray_tpu._private.chip import pallas_interpret
-
-    print(json.dumps({
-        "metric": f"serve_{model}_attn_kernel_ab",
-        "value": round(rows["gather"]["value"]
-                       / max(rows["pallas"]["value"], 1e-9), 2),
-        "unit": "x_tpot_gather_vs_kernel",
-        "token_identical_temp0": identical,
-        "kernel_dispatches": rows["pallas"]["kernel_dispatches"],
-        "interpret_mode": pallas_interpret(),
-        "smoke": bool(args.smoke),
-    }))
-
-
 def run_tp_ab(args, np, cfg_name, model):
     """ISSUE 20 acceptance A/B: the SAME saturating burst through a
     single-chip engine and one whose weights + paged KV are sharded
@@ -1433,7 +1062,7 @@ def run_tp_ab(args, np, cfg_name, model):
     for tp in (1, args.tp):
         eng = DecodeEngine(
             params, cfg, slots=slots, chunk=4, max_len=max_len,
-            prompt_buckets=(plen,), paged=True, page_size=ps,
+            prompt_buckets=(plen,), page_size=ps,
             prefix_cache=False, tp=tp, deployment=f"tp{tp}_bench")
         try:
             list(eng.stream(prompts[0], max_new, seed=0))   # warm
@@ -1487,7 +1116,7 @@ def run_spec_ab(args, np, cfg_name, model):
     repetitive-suffix prompts through three engines on the same
     weights — spec off, n-gram drafter, tied-embedding model drafter —
     INTERLEAVED passes with best-of-N per mode (same discipline as
-    --continuous/--paged: noise on a shared host is one-sided). The
+    --continuous: noise on a shared host is one-sided). The
     workload is the one speculative decoding exists for — locally
     repetitive continuations — and is SCREENED for it: candidate
     repetitive-suffix prompts are generated, their greedy
@@ -1730,7 +1359,7 @@ def run_disagg_ab(args, serve, np, cfg_name, model):
     import jax
 
     import ray_tpu as rt
-    from ray_tpu.models import gpt, gpt_decode
+    from ray_tpu.models import gpt
     from ray_tpu.testing import _serve_replica_handles
 
     # Slots exceed the steady decode lanes so burst admissions always
@@ -1762,7 +1391,7 @@ def run_disagg_ab(args, serve, np, cfg_name, model):
             # to return EXACTLY to baseline, with no cache pins.
             self.engine = DecodeEngine(
                 p, self.cfg, slots=slots, chunk=chunk, max_len=max_len,
-                prompt_buckets=tuple(buckets), paged=True, page_size=8,
+                prompt_buckets=tuple(buckets), page_size=8,
                 prefix_cache=False, deployment="gpt_disagg")
 
         @serve.batch(continuous=True)
@@ -1782,10 +1411,22 @@ def run_disagg_ab(args, serve, np, cfg_name, model):
         def __call__(self, request):
             return self.decode(request)
 
-    refs = {i: np.concatenate([s[0] for s in gpt_decode.generate_chunked(
-        params, _mk_prompt(1000 + i, plen_dec, cfg.vocab_size)[None],
-        cfg, dec_new, chunk=chunk, max_len=max_len)])
-        for i in range(n_dec)}
+    # The uninterrupted run a stream must equal is the same engine's,
+    # in this process: generate_chunked multiplies at batch 1 where the
+    # pool multiplies at batch `slots`, and where two logits lie within
+    # their rounding (prompt 1002, step 47) the two orders part.
+    from ray_tpu.serve.engine import DecodeEngine
+
+    ref_eng = DecodeEngine(
+        params, cfg, slots=slots, chunk=chunk, max_len=max_len,
+        prompt_buckets=(plen_dec, plen_burst), page_size=8,
+        prefix_cache=False, deployment="gpt_disagg_ref")
+    try:
+        refs = {i: np.concatenate(list(ref_eng.stream(
+            _mk_prompt(1000 + i, plen_dec, cfg.vocab_size), dec_new,
+            seed=1000 + i))) for i in range(n_dec)}
+    finally:
+        ref_eng.shutdown()
 
     def run_mode(disagg: bool):
         name = "gpt_disagg"
@@ -1905,8 +1546,7 @@ def run_disagg_ab(args, serve, np, cfg_name, model):
                 est = (m.get("engines") or [{}])[0]
                 for k in agg:
                     agg[k] += int(est.get("handoff", {}).get(k, 0))
-                if est.get("paged"):
-                    leaked_pages += int(est.get("pages_used", 0))
+                leaked_pages += int(est.get("pages_used", 0))
             leaks = agg["leases_outstanding"] + leaked_pages
             if leaks == 0:
                 break

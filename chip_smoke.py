@@ -89,7 +89,7 @@ def _engine_shape(max_seq: int) -> dict:
     prompt buckets, eight slots, the paged pool with the Pallas kernel."""
     return dict(slots=8, chunk=8, max_len=max_seq,
                 prompt_buckets=(max_seq // 16, max_seq // 4),
-                paged=True, page_size=16, attn_kernel="pallas")
+                page_size=16, attn_kernel="pallas")
 
 
 def _requests(max_seq: int):
@@ -193,19 +193,24 @@ def _tp_logits_check(engine) -> dict:
     token = jnp.asarray([11, 12], jnp.int32)
     active = jnp.ones((2,), jnp.bool_)
 
+    # a dense two-slot cache: one 32-position page per slot
+    pt = jnp.arange(2, dtype=jnp.int32)[:, None]
+
     def first_logits(params, n):
-        cache = gd.init_slot_cache(cfg, 2, 32, n)
+        cache = gd.init_paged_cache(cfg, 2, 2, 32, tp=n)
         if n == 1:
-            fn = jax.jit(functools.partial(gd._slot_decode_step, cfg=cfg))
+            fn = jax.jit(functools.partial(gd._slot_decode_step_paged,
+                                           cfg=cfg, page_size=32))
         else:
-            inner = functools.partial(gd._slot_decode_step, cfg=cfg,
-                                      tp_axis="tp")
+            inner = functools.partial(gd._slot_decode_step_paged, cfg=cfg,
+                                      page_size=32, tp_axis="tp")
             cs = gd._tp_cache_specs(cache)
             fn = jax.jit(shard_map(
                 inner, mesh=decode_mesh(n),
-                in_specs=(gd._tp_param_specs(params), cs, P(), P()),
+                in_specs=(gd._tp_param_specs(params), cs, P(), P(), P()),
                 out_specs=(P(), cs)))
-        return np.asarray(fn(params, cache, token, active)[0], np.float32)
+        return np.asarray(fn(params, cache, token, active, pt)[0],
+                          np.float32)
 
     sharded = first_logits(engine._params_dev, tp)
     single = first_logits(engine.params, 1)

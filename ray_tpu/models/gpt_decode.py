@@ -46,41 +46,41 @@ identical to the per-token :func:`decode_step` loop (see
 ``tests/test_models_gpt_decode_chunk.py``).
 
 Slot-pool primitives (the continuous-batching engine's device half,
-ISSUE 5): :func:`init_slot_cache` allocates ONE long-lived cache
-``[L, B_slots, max_len, H, hd]`` whose ``pos`` is per-slot ``[B_slots]``
-instead of a batch-wide scalar, so every slot decodes at its own depth.
-:func:`prefill_into_slot` writes a (right-padded) prompt's K/V into one
-slot via ``lax.dynamic_update_slice`` — one compiled program per prompt
-bucket, with the TRUE prompt length traced dynamically, so any length
-within a bucket reuses the bucket's program. :func:`decode_chunk_slots`
-is the masked twin of :func:`decode_chunk`: k fused steps over the whole
-pool in one dispatch, with inactive slots' cache writes and position
-advances masked out (their rows compute garbage that the host ignores,
-which is cheaper than a dynamic-shape gather/compact on TPU). Per-slot
-PRNG lanes keep each stream's sampling chain independent of admission
-order. Right-padding is exact, not approximate: padded positions'
-K/V land beyond ``pos`` and every decode step overwrites position
-``pos`` BEFORE attention reads it, so pad keys are never attended —
-the engine's greedy output is asserted token-identical to
-:func:`generate_chunked` (see ``tests/test_serve_engine.py``).
+ISSUE 5; paged since ISSUE 6, and since ISSUE 34 the only pool):
+:func:`init_paged_cache` allocates ONE long-lived pool of fixed-size
+pages ``[L, n_pages, page_size, H, hd]`` whose ``pos`` is per-slot
+``[B_slots]`` instead of a batch-wide scalar, so every slot decodes at
+its own depth. A slot's sequence lives wherever its **page table**
+points — a ``[max_pages]`` int32 row of physical page indices, padded
+with :data:`PT_SENTINEL`. The page table is *traced data*, never a
+shape: :func:`prefill_into_slot_paged` and
+:func:`_slot_decode_step_paged` gather K/V through it
+(``pool[clip(pt)]`` → a virtual ``[max_pages * page_size]`` sequence;
+sentinel entries clamp to an arbitrary real page whose garbage the
+``<= pos`` mask hides) and write new tokens by scatter at
+``(pt[pos // page_size], pos % page_size)`` with out-of-bounds **drop**
+semantics — a sentinel write target (a position the host never mapped a
+page for) is silently discarded, never clamped into another slot's
+page. A dense per-slot cache is the special case of the fixed table
+``pt[s, j] = s * max_pages + j`` (the model drafter's,
+:mod:`ray_tpu.serve.draft`).
 
-Paged-pool primitives (ISSUE 6): the flat slot pool reserves
-``max_len`` KV per slot up front, so slot count is capped by the
-worst-case sequence. The paged twin replaces the per-slot reservation
-with a pool of fixed-size pages ``[L, n_pages, page_size, H, hd]``
-(:func:`init_paged_cache`) plus a per-slot **page table** — a
-``[max_pages]`` int32 row of physical page indices, padded with
-:data:`PT_SENTINEL`. The page table is *traced data*, never a shape:
-:func:`prefill_into_slot_paged` and :func:`_slot_decode_step_paged`
-gather K/V through it (``pool[clip(pt)]`` → a virtual
-``[max_pages * page_size]`` sequence; sentinel entries clamp to an
-arbitrary real page whose garbage the ``<= pos`` mask hides) and write
-new tokens by scatter at ``(pt[pos // page_size], pos % page_size)``
-with out-of-bounds **drop** semantics — a sentinel write target (a
-position the host never mapped a page for) is silently discarded, never
-clamped into another slot's page. The compiled-program set therefore
-stays exactly as flat: one prefill program per (suffix) prompt bucket +
-one chunk program, for ANY page-table contents.
+:func:`prefill_into_slot_paged` writes a (right-padded) prompt's K/V
+into one slot's pages — one compiled program per prompt bucket, with
+the TRUE prompt length traced dynamically, so any length within a
+bucket reuses the bucket's program. :func:`decode_chunk_slots_paged`
+is the masked twin of :func:`decode_chunk`: k fused steps over the
+whole pool in one dispatch, with inactive slots' cache writes and
+position advances masked out (their rows compute garbage that the host
+ignores, which is cheaper than a dynamic-shape gather/compact on TPU).
+Per-slot PRNG lanes keep each stream's sampling chain independent of
+admission order. Right-padding is exact, not approximate: pad
+positions' writes are dropped, and every decode step writes position
+``pos`` BEFORE attention reads ``<= pos``, so pad keys are never
+attended — the engine's greedy output is asserted token-identical to
+:func:`generate_chunked` (see ``tests/test_serve_engine.py``). The
+compiled-program set is one prefill program per (suffix) prompt bucket
++ one chunk program, for ANY page-table contents.
 
 The pool is CARRIED through the decode and verify steps' layer scans,
 not scanned: as ``xs``/``ys`` of the scan XLA slices each layer's pool
@@ -105,16 +105,17 @@ page table, and the one copy-on-write fork a lane may need (when the
 cached prefix ends mid-page) is fused into the same prefill program as
 a masked page copy, so prefix hits add ZERO compiled programs.
 
-Token identity with the flat pool holds bitwise on CPU: the gathered
-virtual sequence contains the same K/V values at the same virtual
-positions, extra masked positions contribute exact zeros to the softmax
-(``exp(-1e30 - max)`` underflows to 0.0), and the per-slot PRNG lanes
-are untouched — asserted at temperature 0 AND seeded temperature > 0 in
+Token identity with a dense cache (:func:`generate_chunked`) holds
+bitwise on CPU: the gathered virtual sequence contains the same K/V
+values at the same virtual positions, extra masked positions contribute
+exact zeros to the softmax (``exp(-1e30 - max)`` underflows to 0.0),
+and the per-slot PRNG lanes split as the batch-wide key does — asserted
+at temperature 0 AND seeded temperature > 0 in
 ``tests/test_serve_engine_paged.py``.
 
 Speculative verify (ISSUE 9): chunked decode pays one TARGET forward
-per token (k sequential steps fused per dispatch). The verify twins —
-:func:`verify_chunk_slots` / :func:`verify_chunk_slots_paged` — replace
+per token (k sequential steps fused per dispatch).
+:func:`verify_chunk_slots_paged` replaces
 those k sequential forwards with ONE batched forward over the k tokens
 a cheap drafter proposed per slot: the kernel feeds ``[last, d_1..d_k]``
 (k+1 positions), writes their K/V at each slot's own ``pos..pos+k``,
@@ -131,16 +132,16 @@ one verify program per (pool shape, k) on top of the usual
 ``len(prompt_buckets) + 1``, for any acceptance pattern.
 
 KV handoff (ISSUE 14): disaggregated prefill/decode ships a prefilled
-slot between engines. :func:`export_slot_kv` / :func:`export_slot_kv_paged`
-extract one slot's K/V into contiguous ship order (the host trims to the
+slot between engines. :func:`export_slot_kv_paged`
+extracts one slot's K/V into contiguous ship order (the host trims to the
 true ``pos`` — pad/stale garbage never crosses the wire, so the shipped
-bytes are identical whichever pool mode produced them), and
-:func:`import_slot_kv` / :func:`import_slot_kv_paged` scatter a
-host-padded ship buffer into a target pool's flat row or mapped pages
-and set the slot's ``pos``. Slot index, page table, and length are all
+fp bytes are identical whichever page size produced them), and
+:func:`import_slot_kv_paged` scatters a
+host-padded ship buffer into a target pool's mapped pages
+and sets the slot's ``pos``. Slot index, page table, and length are all
 traced: the whole handoff plane adds exactly TWO compiled programs per
 engine (one export, one import) on top of the usual set, for any
-prompt length and any flat/paged pairing.
+prompt length and any pairing of fp page sizes.
 
 Tensor-parallel decode (ISSUE 20): every slot-pool primitive above has
 a mesh-aware twin path selected by the factories' trailing ``tp``
@@ -153,7 +154,7 @@ projections ``wo``/``w2`` are row-parallel with the f32 partial sums
 ``lax.psum``-reduced BEFORE the compute-dtype cast (:func:`_mm_row` —
 the only tp-introduced arithmetic difference is f32 summation order,
 far below the compute dtype's resolution, the same argument as the
-pallas kernel above), and the pooled KV cache (flat AND paged, fp AND
+pallas kernel above), and the pooled KV cache (fp AND
 int8) is sharded over the HEAD axis so attention stays embarrassingly
 head-parallel. Sampling runs replicated on the psum'd logits with the
 same PRNG lanes on every device, so every device commits the same
@@ -274,9 +275,9 @@ def _tp_param_specs(params):
 
 
 def _tp_cache_specs(cache):
-    """PartitionSpec dict for a pool cache (flat or paged, fp or int8)
-    under a ``("tp",)`` mesh: K/V pages shard their HEAD axis (axis 3
-    in both layouts), int8 per-page scales their head axis (last), and
+    """PartitionSpec dict for a pool cache (fp or int8)
+    under a ``("tp",)`` mesh: K/V pages shard their HEAD axis (axis
+    3), int8 per-page scales their head axis (last), and
     ``pos`` replicates."""
     P = jax.sharding.PartitionSpec
     out = {}
@@ -645,124 +646,6 @@ def _shard_cache(cache: Cache, mesh) -> Cache:
         for name, v in cache.items()}
 
 
-def init_slot_cache(cfg: GPTConfig, slots: int, max_len: int,
-                    tp: int = 1) -> Cache:
-    """Persistent pooled KV cache for the continuous-batching engine:
-    ``pos`` is per-slot ``[slots]`` so each lane decodes at its own
-    depth. Allocated ONCE per engine — slots are recycled by
-    re-prefilling, never by reallocating. ``tp > 1`` lays the pool out
-    head-sharded over :func:`decode_mesh` (the layout every sharded
-    program consumes and produces)."""
-    shape = (cfg.n_layer, slots, max_len, cfg.n_head, cfg.head_dim)
-    cache = {
-        "k": jnp.zeros(shape, cfg.dtype),
-        "v": jnp.zeros(shape, cfg.dtype),
-        "pos": jnp.zeros((slots,), jnp.int32),
-    }
-    mesh = _tp_mesh(cfg, tp)
-    return cache if mesh is None else _shard_cache(cache, mesh)
-
-
-def prefill_into_slot(params: Params, cache: Cache, tokens: jax.Array,
-                      length: jax.Array, slot: jax.Array, rng: jax.Array,
-                      *, cfg: GPTConfig, temperature: float = 0.0,
-                      tp_axis=None
-                      ) -> Tuple[jax.Array, Cache, jax.Array]:
-    """Run one right-padded prompt and write its K/V into slot ``slot``
-    of the pool.
-
-    ``tokens`` is ``[1, S_bucket]`` (prompt right-padded to its bucket;
-    the bucket size is the only shape XLA sees, so one program per
-    bucket serves every length within it); ``length`` is the TRUE prompt
-    length (traced scalar); ``slot`` is the target slot index (traced).
-    Returns ``(first_token, cache', rng')`` where ``first_token`` is the
-    prompt's next-token sample (the TTFT token — sampling is fused into
-    the prefill program so admission is one dispatch).
-
-    Padding is exact: positions ``< length`` attend only causally to
-    true prompt tokens, the last-token logits are sliced at
-    ``length - 1``, and the pad positions' K/V are overwritten by decode
-    steps before ``pos`` ever reaches them (decode writes position
-    ``pos`` before attending over ``<= pos``)."""
-    B, S = tokens.shape
-    scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
-    x = params["embed"]["kernel"].astype(cfg.dtype)[tokens]
-    x = x + params["pos_embed"][:S].astype(cfg.dtype)[None]
-    mask = jnp.tril(jnp.ones((S, S), jnp.bool_))
-
-    def body(carry, layer):
-        x = carry
-        p = layer
-        q, k, v = _block_kv(x, p, cfg)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                            preferred_element_type=jnp.float32) * scale
-        logits = jnp.where(mask, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        att = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
-                         preferred_element_type=jnp.float32
-                         ).astype(q.dtype).reshape(B, S, -1)
-        x = x + _mm_row(att, p["wo"]["kernel"], cfg.dtype, tp_axis)
-        x = _ffn(x, p, cfg, tp_axis)
-        return x, (k, v)
-
-    x, (k_new, v_new) = lax.scan(body, x, params["block"])
-    x = _rmsnorm(x, params["ln_f_scale"])
-    x_last = lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, cfg.d_model))
-    logits = _project_vocab(x_last, params["embed"]["kernel"], cfg)
-    token, rng = _sample(logits[:, 0], temperature, rng)
-    kp = lax.dynamic_update_slice(cache["k"], k_new, (0, slot, 0, 0, 0))
-    vp = lax.dynamic_update_slice(cache["v"], v_new, (0, slot, 0, 0, 0))
-    pos = lax.dynamic_update_slice(cache["pos"],
-                                   jnp.reshape(length, (1,)), (slot,))
-    return token[0], {"k": kp, "v": vp, "pos": pos}, rng
-
-
-def _slot_decode_step(params: Params, cache: Cache, token: jax.Array,
-                      active: jax.Array, cfg: GPTConfig, tp_axis=None
-                      ) -> Tuple[jax.Array, Cache]:
-    """One masked decode step over the whole slot pool: each slot writes
-    its new K/V at ITS OWN ``pos[b]`` (one-hot select — positions differ
-    per slot, so a single ``dynamic_update_slice`` can't express the
-    scatter) and attends over ``<= pos[b]``. Inactive slots neither
-    write nor advance; their logits rows are garbage the host must
-    ignore."""
-    B = token.shape[0]
-    max_len = cache["k"].shape[2]
-    pos = cache["pos"]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
-    x = params["embed"]["kernel"].astype(cfg.dtype)[token][:, None]
-    x = x + jnp.take(params["pos_embed"], pos, axis=0
-                     ).astype(cfg.dtype)[:, None]
-    ar = jnp.arange(max_len)
-    valid = (ar[None, :] <= pos[:, None])[:, None, None, :]
-    write = (active[:, None] & (ar[None, :] == pos[:, None])
-             )[:, :, None, None]
-
-    def body(carry, layer):
-        x = carry
-        p, kc, vc = layer
-        q, k, v = _block_kv(x, p, cfg)   # [B, 1, H, hd]
-        kc = jnp.where(write, k, kc)
-        vc = jnp.where(write, v, vc)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, kc,
-                            preferred_element_type=jnp.float32) * scale
-        logits = jnp.where(valid, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        att = jnp.einsum("bhqk,bkhd->bqhd", probs, vc,
-                         preferred_element_type=jnp.float32
-                         ).astype(q.dtype).reshape(B, 1, -1)
-        x = x + _mm_row(att, p["wo"]["kernel"], cfg.dtype, tp_axis)
-        x = _ffn(x, p, cfg, tp_axis)
-        return x, (kc, vc)
-
-    x, (k_new, v_new) = lax.scan(
-        body, x, (params["block"], cache["k"], cache["v"]))
-    x = _rmsnorm(x, params["ln_f_scale"])
-    logits = _project_vocab(x, params["embed"]["kernel"], cfg)
-    return logits[:, 0], {"k": k_new, "v": v_new,
-                          "pos": pos + active.astype(jnp.int32)}
-
-
 def _sample_slots(logits, temperature: float, keys):
     """Per-slot sampling with independent PRNG lanes: each slot's key
     chain splits exactly like :func:`_sample`'s, so a slot's stream is
@@ -776,109 +659,6 @@ def _sample_slots(logits, temperature: float, keys):
     else:
         token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     return token, keys
-
-
-def decode_chunk_slots(params: Params, cache: Cache, token: jax.Array,
-                       rngs: jax.Array, active: jax.Array, *,
-                       cfg: GPTConfig, k: int, temperature: float = 0.0,
-                       eos_token: int = -1, tp_axis=None):
-    """Masked twin of :func:`decode_chunk` over a slot pool: k fused
-    steps in ONE program, decoding only slots where ``active`` is set.
-
-    ``token`` ``[B_slots]`` is each slot's last emitted token, ``rngs``
-    ``[B_slots, 2]`` its PRNG lane, ``active`` ``[B_slots]`` the
-    chunk-static admission mask (admission happens at chunk boundaries,
-    so the mask never changes inside a dispatch). Returns
-    ``(tokens [B_slots, k], cache', done [B_slots], rngs')``; rows of
-    inactive slots are garbage. EOS lanes mask-and-carry exactly like
-    :func:`decode_chunk` — the ENGINE frees the slot at the chunk
-    boundary, which is what turns mask-and-carry into slot reuse."""
-    B = token.shape[0]
-    eos = jnp.asarray(eos_token, jnp.int32)
-    done0 = (active & (token == eos)) if eos_token >= 0 \
-        else jnp.zeros((B,), jnp.bool_)
-
-    def body(carry, _):
-        cache, tok, done, keys = carry
-        logits, cache = _slot_decode_step(params, cache, tok, active,
-                                          cfg, tp_axis)
-        nxt, keys = _sample_slots(logits, temperature, keys)
-        if eos_token >= 0:
-            nxt = jnp.where(done, eos, nxt)
-            done = done | (active & (nxt == eos))
-        return (cache, nxt, done, keys), nxt
-
-    (cache, _, done, rngs), toks = lax.scan(
-        body, (cache, token, done0, rngs), None, length=k)
-    return jnp.moveaxis(toks, 0, 1), cache, done, rngs
-
-
-# rtlint: program-budget: len(prompt_buckets)
-@_knob_cache
-def jit_prefill_into_slot(cfg: GPTConfig, temperature: float = 0.0,
-                          tp: int = 1):
-    """Jitted :func:`prefill_into_slot`; retraces once per padded-prompt
-    SHAPE, so the compiled-program count equals the engine's prompt
-    bucket count — per (cfg, temperature, tp) key: each mesh shape has
-    its own wrapper and its own ``len(prompt_buckets)`` budget. Cached
-    on the static knobs so every engine for the same knobs shares one
-    wrapper (and its trace cache). The pool cache is donated: the
-    engine holds the only reference and immediately rebinds the
-    returned cache, so on TPU the update is in-place instead of a
-    full-pool copy (CPU ignores donation). ``tp > 1`` runs the same
-    inner function under shard_map on :func:`decode_mesh` with weights
-    column/row-parallel and the pool head-sharded."""
-    mesh = _tp_mesh(cfg, tp)
-    if mesh is None:
-        return jax.jit(_program(prefill_into_slot, cfg=cfg,
-                                         temperature=temperature),
-                       donate_argnums=(1,))
-    P = jax.sharding.PartitionSpec
-    inner = functools.partial(prefill_into_slot, cfg=cfg,
-                              temperature=temperature, tp_axis="tp")
-
-    def fn(params, cache, tokens, length, slot, rng):
-        cspec = _tp_cache_specs(cache)
-        return shard_map(
-            inner, mesh=mesh,
-            in_specs=(_tp_param_specs(params), cspec,
-                      P(), P(), P(), P()),
-            out_specs=(P(), cspec, P()))(
-                params, cache, tokens, length, slot, rng)
-
-    return jax.jit(_program(fn, "prefill_into_slot"), donate_argnums=(1,))
-
-
-# rtlint: program-budget: 1
-@_knob_cache
-def jit_decode_chunk_slots(cfg: GPTConfig, k: int,
-                           temperature: float = 0.0, eos_token: int = -1,
-                           tp: int = 1):
-    """Jitted :func:`decode_chunk_slots`: ONE compiled program per
-    (pool shape, k, tp) — admission patterns, per-request max_new, and
-    slot choice are all runtime values, never retrace triggers (pinned
-    by the recompile-guard test). The pool cache is donated (see
-    :func:`jit_prefill_into_slot`)."""
-    mesh = _tp_mesh(cfg, tp)
-    if mesh is None:
-        return jax.jit(_program(decode_chunk_slots, cfg=cfg,
-                                         k=k, temperature=temperature,
-                                         eos_token=eos_token),
-                       donate_argnums=(1,))
-    P = jax.sharding.PartitionSpec
-    inner = functools.partial(decode_chunk_slots, cfg=cfg, k=k,
-                              temperature=temperature,
-                              eos_token=eos_token, tp_axis="tp")
-
-    def fn(params, cache, token, rngs, active):
-        cspec = _tp_cache_specs(cache)
-        return shard_map(
-            inner, mesh=mesh,
-            in_specs=(_tp_param_specs(params), cspec, P(), P(), P()),
-            out_specs=(P(), cspec, P(), P()))(
-                params, cache, token, rngs, active)
-
-    return jax.jit(_program(fn, "decode_chunk_slots"), donate_argnums=(1,))
 
 
 # -------------------------------------------------------------- paged pool
@@ -1041,7 +821,7 @@ def init_paged_cache(cfg: GPTConfig, slots: int, n_pages: int,
     """Paged KV pool for the continuous-batching engine: physical
     storage is page-granular (``[L, n_pages, page_size, H, hd]``), a
     slot's sequence lives wherever its page table points. ``pos`` stays
-    per-slot ``[slots]`` (virtual position, exactly as flat). With
+    per-slot ``[slots]`` (virtual position). With
     ``kv_dtype="int8"`` the page arrays hold quantized codes and the
     pool grows ``"ks"``/``"vs"`` per-(layer, page, head) float32
     scales. The layer axis leads and the page axis follows it, so the
@@ -1316,7 +1096,7 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     over (a) the history read through the page table, valid where the
     virtual position ``< hist_len``, and (b) themselves, causally. With
     ``hist_len == 0`` the history lanes are fully masked and the math
-    reduces bitwise to :func:`prefill_into_slot` (masked keys contribute
+    reduces bitwise to :func:`prefill`'s (masked keys contribute
     exact zeros). Returns ``(first_token, cache', rng')``; pad-position
     writes are dropped, not written."""
     B, S = tokens.shape
@@ -1423,8 +1203,9 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                             page_size: int, kv_dtype: str = "fp",
                             attn_kernel: str = "gather", tp_axis=None
                             ) -> Tuple[jax.Array, Cache]:
-    """Paged twin of :func:`_slot_decode_step`: each active slot writes
-    its new K/V at ``(pt[b, pos[b] // ps], pos[b] % ps)`` (scatter with
+    """One masked decode step over the whole slot pool: each active
+    slot writes its new K/V at ITS OWN position,
+    ``(pt[b, pos[b] // ps], pos[b] % ps)`` (scatter with
     drop semantics — an unmapped write target is discarded, never
     clamped into another slot's page; int8 pools merge through
     :func:`_merge_span_int8` instead) and attends over its virtual
@@ -1496,11 +1277,20 @@ def decode_chunk_slots_paged(params: Params, cache: Cache,
                              eos_token: int = -1,
                              kv_dtype: str = "fp",
                              attn_kernel: str = "gather", tp_axis=None):
-    """Paged twin of :func:`decode_chunk_slots`: k fused steps in ONE
-    program with the page table held constant through the chunk (the
-    engine maps pages covering ``pos + k`` before dispatching — a slot
-    that cannot be covered is parked out of ``active`` instead). EOS
-    mask-and-carry and per-slot PRNG lanes are identical to flat.
+    """Masked twin of :func:`decode_chunk` over the slot pool: k fused
+    steps in ONE program, decoding only slots where ``active`` is set,
+    with the page table held constant through the chunk (the engine
+    maps pages covering ``pos + k`` before dispatching — a slot that
+    cannot be covered is parked out of ``active`` instead).
+
+    ``token`` ``[B_slots]`` is each slot's last emitted token, ``rngs``
+    ``[B_slots, 2]`` its PRNG lane, ``active`` ``[B_slots]`` the
+    chunk-static admission mask (admission happens at chunk boundaries,
+    so the mask never changes inside a dispatch). Returns
+    ``(tokens [B_slots, k], cache', done [B_slots], rngs')``; rows of
+    inactive slots are garbage. EOS lanes mask-and-carry exactly like
+    :func:`decode_chunk` — the ENGINE frees the slot at the chunk
+    boundary, which is what turns mask-and-carry into slot reuse.
     ``kv_dtype``/``attn_kernel`` select the pool layout and attention
     implementation per :func:`paged_attention` — both are STATIC knobs
     baked into the compiled program, never retrace triggers."""
@@ -1536,8 +1326,14 @@ def jit_prefill_into_slot_paged(cfg: GPTConfig, page_size: int,
     prefix-hit depth (``hist_len``), page-table contents, and COW
     source are all traced, so shared-prefix admission never retraces.
     ``kv_dtype`` is an engine-level static baked into the same program
-    set (it changes the pool layout, not the program COUNT). Pool
-    donated as in :func:`jit_prefill_into_slot`."""
+    set (it changes the pool layout, not the program COUNT). Cached on
+    the static knobs so every engine for the same knobs shares one
+    wrapper (and its trace cache). The pool cache is donated: the
+    engine holds the only reference and immediately rebinds the
+    returned cache, so on TPU the update is in-place instead of a
+    full-pool copy (CPU ignores donation). ``tp > 1`` runs the same
+    inner function under shard_map on :func:`decode_mesh` with weights
+    column/row-parallel and the pool head-sharded."""
     mesh = _tp_mesh(cfg, tp)
     if mesh is None:
         return jax.jit(_program(prefill_into_slot_paged,
@@ -1696,25 +1492,25 @@ def _spec_accept(logits, draft, keys, temperature: float, k: int):
     return committed, n_acc.astype(jnp.int32), keys
 
 
-def verify_chunk_slots(params: Params, cache: Cache, token: jax.Array,
-                       draft: jax.Array, rngs: jax.Array,
-                       active: jax.Array, *, cfg: GPTConfig, k: int,
-                       temperature: float = 0.0, tp_axis=None):
+def verify_chunk_slots_paged(params: Params, cache: Cache,
+                             token: jax.Array, draft: jax.Array,
+                             rngs: jax.Array, active: jax.Array,
+                             pt: jax.Array, *, cfg: GPTConfig, k: int,
+                             page_size: int, temperature: float = 0.0,
+                             kv_dtype: str = "fp", tp_axis=None):
     """ONE batched target forward verifying k drafted tokens per active
     slot (ISSUE 9 tentpole; the draft-k-verify-once step).
 
     ``token`` ``[B]`` is each slot's last committed token, ``draft``
     ``[B, k]`` its drafter proposals, ``rngs``/``active`` as in
-    :func:`decode_chunk_slots`. The kernel feeds ``[last, d_1..d_k]``
-    (k+1 positions per slot), writes their K/V at the slot's own
-    ``pos..pos+k`` (scatter; inactive slots and positions past
-    ``max_len`` are dropped, never clamped), scores all k+1 logit rows
-    against the proposals (:func:`_spec_accept`), and advances ``pos``
-    by ``1 + n_acc`` per active slot — the write cursor rolls back past
-    rejected positions in-program. Garbage K/V beyond the new ``pos``
-    is overwritten before any later query attends it (every decode and
-    verify step writes position ``pos`` before reading ``<= pos``), the
-    same exactness argument as prompt right-padding.
+    :func:`decode_chunk_slots_paged`. The kernel feeds
+    ``[last, d_1..d_k]`` (k+1 positions per slot), scores all k+1 logit
+    rows against the proposals (:func:`_spec_accept`), and advances
+    ``pos`` by ``1 + n_acc`` per active slot — the write cursor rolls
+    back past rejected positions in-program. Garbage K/V beyond the new
+    ``pos`` is overwritten before any later query attends it (every
+    decode and verify step writes position ``pos`` before reading
+    ``<= pos``), the same exactness argument as prompt right-padding.
 
     Returns ``(committed [B, k+1], n_acc [B], cache', rngs')``; rows of
     inactive slots are garbage. The host delivers
@@ -1722,68 +1518,15 @@ def verify_chunk_slots(params: Params, cache: Cache, token: jax.Array,
     the LAST DELIVERED token next. EOS needs no in-kernel
     mask-and-carry here: there is no sequential feedback inside the
     verify (all inputs were proposed up front), and the engine frees
-    the lane at the chunk boundary where it trims."""
-    B = token.shape[0]
-    S = k + 1
-    max_len = cache["k"].shape[2]
-    pos = cache["pos"]
-    scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
-    seq = jnp.concatenate([token[:, None], draft], axis=1)     # [B, S]
-    positions = pos[:, None] + jnp.arange(S)[None, :]          # [B, S]
-    x = params["embed"]["kernel"].astype(cfg.dtype)[seq]
-    x = x + jnp.take(params["pos_embed"],
-                     jnp.clip(positions, 0,
-                              params["pos_embed"].shape[0] - 1),
-                     axis=0).astype(cfg.dtype)
-    ar = jnp.arange(max_len)
-    # Query i attends <= pos + i: the history plus the drafted prefix
-    # written at pos..pos+i this dispatch — causal within the block.
-    valid = ar[None, None, None, :] <= positions[:, None, :, None]
-    bidx = jnp.broadcast_to(jnp.arange(B)[:, None], (B, S))
-    # Inactive slots write at max_len: out of bounds, dropped.
-    wpos = jnp.where(active[:, None], positions, jnp.int32(max_len))
+    the lane at the chunk boundary where it trims.
 
-    def body(carry, layer):
-        x = carry
-        p, kc, vc = layer                    # [B, max_len, H, hd]
-        q, kk, vv = _block_kv(x, p, cfg)     # [B, S, H, hd]
-        kc = kc.at[bidx, wpos].set(kk, mode="drop")
-        vc = vc.at[bidx, wpos].set(vv, mode="drop")
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, kc,
-                            preferred_element_type=jnp.float32) * scale
-        logits = jnp.where(valid, logits, -1e30)
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        att = jnp.einsum("bhqk,bkhd->bqhd", probs, vc,
-                         preferred_element_type=jnp.float32
-                         ).astype(q.dtype).reshape(B, S, -1)
-        x = x + _mm_row(att, p["wo"]["kernel"], cfg.dtype, tp_axis)
-        x = _ffn(x, p, cfg, tp_axis)
-        return x, (kc, vc)
-
-    x, (k_new, v_new) = lax.scan(
-        body, x, (params["block"], cache["k"], cache["v"]))
-    x = _rmsnorm(x, params["ln_f_scale"])
-    logits = _project_vocab(x, params["embed"]["kernel"], cfg)
-    committed, n_acc, rngs = _spec_accept(logits, draft, rngs,
-                                          temperature, k)
-    pos2 = pos + (1 + n_acc) * active.astype(jnp.int32)
-    return committed, n_acc, {"k": k_new, "v": v_new, "pos": pos2}, rngs
-
-
-def verify_chunk_slots_paged(params: Params, cache: Cache,
-                             token: jax.Array, draft: jax.Array,
-                             rngs: jax.Array, active: jax.Array,
-                             pt: jax.Array, *, cfg: GPTConfig, k: int,
-                             page_size: int, temperature: float = 0.0,
-                             kv_dtype: str = "fp", tp_axis=None):
-    """Paged twin of :func:`verify_chunk_slots`: K/V writes scatter at
+    K/V writes scatter at
     ``(pt[b, (pos+i) // ps], (pos+i) % ps)`` with drop semantics (an
     unmapped or inactive target is discarded, never clamped into
     another slot's page — the engine never un-maps a page that still
     holds committed tokens, so rollback is just the smaller ``pos``),
     and each query attends its virtual sequence gathered through its
-    page-table row, valid ``<= pos + i``. Acceptance math, variable
-    advance, and PRNG discipline are identical to flat. int8 pools
+    page-table row, valid ``<= pos + i``. int8 pools
     merge the k+1 drafted rows through :func:`_merge_span_int8` and
     read them back dequantized — so accept/reject decisions are made
     on exactly the K/V any later decode step will see; a rejected
@@ -1863,34 +1606,20 @@ def verify_chunk_slots_paged(params: Params, cache: Cache,
 
 
 # ------------------------------------------------------- KV handoff (ship)
-def export_slot_kv(cache: Cache, slot: jax.Array, *, cfg: GPTConfig
-                   ) -> Tuple[jax.Array, jax.Array]:
-    """Extract one slot's K/V rows from a FLAT pool for a prefill →
-    decode handoff (ISSUE 14): ``(k, v)`` each ``[L, max_len, H, hd]``.
-
-    ``slot`` is traced, so ONE compiled program serves every slot; the
-    host trims the returned rows to the slot's true ``pos`` before
-    shipping (positions past ``pos`` hold pad/stale garbage that the
-    attention mask never read — shipping them would make the digest
-    depend on pool history). The cache is NOT donated: the exporting
-    engine keeps serving out of it."""
-    L, B, M, H, hd = cache["k"].shape
-    k = lax.dynamic_slice(cache["k"], (0, slot, 0, 0, 0),
-                          (L, 1, M, H, hd))[:, 0]
-    v = lax.dynamic_slice(cache["v"], (0, slot, 0, 0, 0),
-                          (L, 1, M, H, hd))[:, 0]
-    return k, v
-
-
 def export_slot_kv_paged(cache: Cache, pt_row: jax.Array, *,
                          cfg: GPTConfig, page_size: int,
                          kv_dtype: str = "fp"):
-    """Paged twin of :func:`export_slot_kv`: gather the slot's pages
-    through its page-table row into virtual order — ``(k, v)`` each
+    """Extract one slot's K/V for a prefill → decode handoff
+    (ISSUE 14): gather the slot's pages through its page-table row
+    into virtual order — ``(k, v)`` each
     ``[L, max_pages * page_size, H, hd]``. Sentinel entries clip to a
-    real page whose garbage sits past ``pos`` and is trimmed by the
-    host before shipping, exactly like flat pad positions. The
-    page-table CONTENTS are traced data: one program per pool shape.
+    real page whose garbage sits past ``pos``; the host trims the
+    returned rows to the slot's true ``pos`` before shipping (positions
+    past ``pos`` hold pad/stale garbage that the attention mask never
+    read — shipping them would make the digest depend on pool
+    history). The page-table CONTENTS are traced data: one program per
+    pool shape. The cache is NOT donated: the exporting engine keeps
+    serving out of it.
     int8 pools additionally return the gathered per-page scales
     ``(k, v, ks, vs)`` — the handoff ships codes + scales and the
     digest covers both."""
@@ -1907,34 +1636,14 @@ def export_slot_kv_paged(cache: Cache, pt_row: jax.Array, *,
     return k, v
 
 
-def import_slot_kv(cache: Cache, k_row: jax.Array, v_row: jax.Array,
-                   slot: jax.Array, length: jax.Array, *, cfg: GPTConfig
-                   ) -> Cache:
-    """Scatter a shipped prefill's K/V into slot ``slot`` of a FLAT
-    pool and set its ``pos`` to ``length`` (the inverse of
-    :func:`export_slot_kv`). ``k_row``/``v_row`` are ``[L, max_len, H,
-    hd]`` — the host pads the trimmed ship buffer back out to the
-    TARGET pool's length, so ONE compiled program serves every handoff
-    regardless of prompt length. Positions past ``length`` land as
-    zeros, which is exactly the flat prefill's pad discipline: decode
-    overwrites position ``pos`` before attention ever reads ``<= pos``.
-    """
-    kp = lax.dynamic_update_slice(cache["k"], k_row[:, None],
-                                  (0, slot, 0, 0, 0))
-    vp = lax.dynamic_update_slice(cache["v"], v_row[:, None],
-                                  (0, slot, 0, 0, 0))
-    pos = lax.dynamic_update_slice(cache["pos"],
-                                   jnp.reshape(length, (1,)), (slot,))
-    return {"k": kp, "v": vp, "pos": pos}
-
-
 def import_slot_kv_paged(cache: Cache, k_pages: jax.Array,
                          v_pages: jax.Array, pt_row: jax.Array,
                          slot: jax.Array, length: jax.Array, *,
                          cfg: GPTConfig, page_size: int,
                          ks_pages=None, vs_pages=None) -> Cache:
-    """Paged twin of :func:`import_slot_kv`: scatter shipped K/V into
-    the pool pages mapped by ``pt_row``. ``k_pages``/``v_pages`` are
+    """Scatter a shipped slot's K/V (a handoff import, ISSUE 14) into
+    the pool pages mapped by ``pt_row`` and set the slot's ``pos`` to
+    ``length``. ``k_pages``/``v_pages`` are
     ``[L, max_pages, page_size, H, hd]`` (host-padded to the full table
     width — one program per pool shape); pages the host never mapped
     (``pt_row`` sentinel, or wholly past ``length``) are DROPPED, never
@@ -1960,37 +1669,15 @@ def import_slot_kv_paged(cache: Cache, k_pages: jax.Array,
 
 # rtlint: program-budget: 1
 @_knob_cache
-def jit_export_slot_kv(cfg: GPTConfig, tp: int = 1):
-    """Jitted :func:`export_slot_kv`: ONE program per flat pool shape
-    (slot index is traced). NOT donated — the exporter keeps its pool.
-    Under tp the returned rows are head-sharded device arrays whose
-    host gather (``np.asarray``) is the CANONICAL ``[L, max_len, H,
-    hd]`` layout — identical bytes for any exporter tp, which is what
-    makes the handoff digest layout-independent."""
-    mesh = _tp_mesh(cfg, tp)
-    if mesh is None:
-        return jax.jit(_program(export_slot_kv, cfg=cfg))
-    P = jax.sharding.PartitionSpec
-    inner = functools.partial(export_slot_kv, cfg=cfg)
-    hspec = P(None, None, "tp", None)
-
-    def fn(cache, slot):
-        return shard_map(
-            inner, mesh=mesh,
-            in_specs=(_tp_cache_specs(cache), P()),
-            out_specs=(hspec, hspec))(cache, slot)
-
-    return jax.jit(_program(fn, "export_slot_kv"))
-
-
-# rtlint: program-budget: 1
-@_knob_cache
 def jit_export_slot_kv_paged(cfg: GPTConfig, page_size: int,
                              kv_dtype: str = "fp", tp: int = 1):
     """Jitted :func:`export_slot_kv_paged`: ONE program per (pool
     shape, page_size, kv_dtype, tp) — the page table is data. NOT
-    donated. See :func:`jit_export_slot_kv` for the tp canonical-layout
-    contract."""
+    donated — the exporter keeps its pool. Under tp the returned rows
+    are head-sharded device arrays whose host gather (``np.asarray``)
+    is the CANONICAL ``[L, max_pages * page_size, H, hd]`` layout —
+    identical bytes for any exporter tp, which is what makes the
+    handoff digest layout-independent."""
     mesh = _tp_mesh(cfg, tp)
     if mesh is None:
         return jax.jit(_program(export_slot_kv_paged, cfg=cfg,
@@ -2015,39 +1702,15 @@ def jit_export_slot_kv_paged(cfg: GPTConfig, page_size: int,
 
 # rtlint: program-budget: 1
 @_knob_cache
-def jit_import_slot_kv(cfg: GPTConfig, tp: int = 1):
-    """Jitted :func:`import_slot_kv`: ONE program per flat pool shape
-    (slot and length are traced). Pool donated as in
-    :func:`jit_prefill_into_slot` — the importer immediately rebinds.
-    Under tp the host-canonical ship buffer is scattered into THIS
-    engine's mesh — the resharding half of the handoff boundary, so an
-    N-way exporter feeds an M-way importer with no layout coupling."""
-    mesh = _tp_mesh(cfg, tp)
-    if mesh is None:
-        return jax.jit(_program(import_slot_kv, cfg=cfg),
-                       donate_argnums=(0,))
-    P = jax.sharding.PartitionSpec
-    inner = functools.partial(import_slot_kv, cfg=cfg)
-    hspec = P(None, None, "tp", None)
-
-    def fn(cache, k_row, v_row, slot, length):
-        cspec = _tp_cache_specs(cache)
-        return shard_map(
-            inner, mesh=mesh,
-            in_specs=(cspec, hspec, hspec, P(), P()),
-            out_specs=cspec)(cache, k_row, v_row, slot, length)
-
-    return jax.jit(_program(fn, "import_slot_kv"), donate_argnums=(0,))
-
-
-# rtlint: program-budget: 1
-@_knob_cache
 def jit_import_slot_kv_paged(cfg: GPTConfig, page_size: int,
                              kv_dtype: str = "fp", tp: int = 1):
     """Jitted :func:`import_slot_kv_paged`: ONE program per (pool
     shape, page_size, kv_dtype, tp) — int8 wrappers take the shipped
-    scales as trailing positional args. Pool donated. See
-    :func:`jit_import_slot_kv` for the tp resharding contract."""
+    scales as trailing positional args. Pool donated — the importer
+    immediately rebinds. Under tp the host-canonical ship buffer is
+    scattered into THIS engine's mesh — the resharding half of the
+    handoff boundary, so an N-way exporter feeds an M-way importer with
+    no layout coupling."""
     mesh = _tp_mesh(cfg, tp)
     if kv_dtype == "int8":
         def raw(cache, k_pages, v_pages, ks_pages, vs_pages, pt_row,
@@ -2093,36 +1756,6 @@ def jit_import_slot_kv_paged(cfg: GPTConfig, page_size: int,
                              length)
 
     return jax.jit(_program(fn, "import_slot_kv_paged"), donate_argnums=(0,))
-
-
-# rtlint: program-budget: 1
-@_knob_cache
-def jit_verify_chunk_slots(cfg: GPTConfig, k: int,
-                           temperature: float = 0.0, tp: int = 1):
-    """Jitted :func:`verify_chunk_slots`: ONE compiled program per
-    (pool shape, k, tp) — draft contents, acceptance pattern, and
-    per-slot positions are all traced data, never retrace triggers
-    (pinned by the spec recompile-guard test). Pool donated as in
-    :func:`jit_prefill_into_slot`."""
-    mesh = _tp_mesh(cfg, tp)
-    if mesh is None:
-        return jax.jit(_program(verify_chunk_slots, cfg=cfg,
-                                         k=k, temperature=temperature),
-                       donate_argnums=(1,))
-    P = jax.sharding.PartitionSpec
-    inner = functools.partial(verify_chunk_slots, cfg=cfg, k=k,
-                              temperature=temperature, tp_axis="tp")
-
-    def fn(params, cache, token, draft, rngs, active):
-        cspec = _tp_cache_specs(cache)
-        return shard_map(
-            inner, mesh=mesh,
-            in_specs=(_tp_param_specs(params), cspec,
-                      P(), P(), P(), P()),
-            out_specs=(P(), P(), cspec, P()))(
-                params, cache, token, draft, rngs, active)
-
-    return jax.jit(_program(fn, "verify_chunk_slots"), donate_argnums=(1,))
 
 
 # rtlint: program-budget: 1

@@ -595,7 +595,7 @@ class ServeController:
         # Live-replica lifecycle totals (expired / overloaded / served),
         # piggybacked on the health pass and surfaced via status().
         life = {"expired": 0, "overloaded": 0, "total": 0, "drains": 0}
-        # Engine page/prefix totals (paged decode engines only),
+        # Engine page/prefix totals (decode engines only),
         # summed across replicas, same piggyback.
         engine: dict = {}
         for rid, ref, mref in probes:

@@ -390,12 +390,12 @@ def batch(_fn: Optional[Callable] = None, *, max_batch_size: int = 8,
             return self.decode(request)       # iterator of [j] slices
 
     ``page_size=`` / ``prefix_cache=`` / ``attn_kernel=`` /
-    ``kv_dtype=`` (continuous only) are the paged KV-cache knobs, and
+    ``kv_dtype=`` (continuous only) are the KV page pool's knobs, and
     ``spec_decode=`` / ``draft_k=`` the speculative
     decoding knobs, applied to the handler's engine via
-    :meth:`~.engine.DecodeEngine.apply_config` on first use: a
-    flat-constructed engine is repaged / given a drafter before traffic
-    (a matching engine just validates), so deployments can opt in
+    :meth:`~.engine.DecodeEngine.apply_config` on first use: an
+    engine built with other values is repaged / given a drafter before
+    traffic (a matching engine just validates), so deployments can opt in
     declaratively without touching their ``__init__``.
     """
     if continuous and (stream or pad_to_bucket or buckets is not None):

@@ -3,8 +3,8 @@
 
 A drafter proposes ``draft_k`` candidate tokens per active slot at every
 chunk boundary; the target model then verifies all of them in ONE
-batched forward (:func:`~ray_tpu.models.gpt_decode.verify_chunk_slots`)
-and commits the accepted prefix plus its own correction/bonus token.
+batched forward
+(:func:`~ray_tpu.models.gpt_decode.verify_chunk_slots_paged`) and commits the accepted prefix plus its own correction/bonus token.
 Because acceptance is exact (greedy match at temperature 0, lossless
 rejection sampling above it), a drafter can NEVER change the committed
 stream — only how many target forwards it takes to produce it — so the
@@ -34,7 +34,7 @@ Two implementations ship:
   decoding falls into).
 - :class:`ModelDrafter` — a small GPT (typically sharing the target's
   embedding, see :func:`tied_drafter_params`) decoding greedily into
-  its own flat slot pool that mirrors the engine's slots. Wins when a
+  its own dense cache that mirrors the engine's slots. Wins when a
   trained/distilled draft model actually approximates the target;
   costs ``len(prompt_buckets) + 2`` extra compiled programs (its own
   prefill per bucket, a k-step draft chunk, and a 1-token ingest).
@@ -242,13 +242,16 @@ class NGramDrafter(Drafter):
 
 
 class ModelDrafter(Drafter):
-    """Device drafter: a small GPT decoding greedily into its OWN flat
-    slot pool whose slots mirror the engine's 1:1 (same ``max_len``,
-    same prompt buckets, so positions track the target exactly).
+    """Device drafter: a small GPT decoding greedily into its OWN
+    dense cache whose slots mirror the engine's 1:1 (same ``max_len``,
+    same prompt buckets, so positions track the target exactly). The
+    cache is the page pool with one ``max_len`` page per slot and the
+    fixed table ``pt[s] = [s]``: the engine's programs, no allocator,
+    no prefix cache.
 
     Per verify round the drafter runs one fused k-step greedy chunk
-    (:func:`~ray_tpu.models.gpt_decode.decode_chunk_slots` of its own
-    model) to propose, and after the verify it rolls its write cursor
+    (:func:`~ray_tpu.models.gpt_decode.decode_chunk_slots_paged` of its
+    own model) to propose, and after the verify it rolls its write cursor
     back past rejected positions — host-authoritative ``pos`` is
     re-uploaded wholesale each round, and garbage K/V beyond it is
     overwritten before it is ever attended (the engine's standard
@@ -278,16 +281,18 @@ class ModelDrafter(Drafter):
         from ..models import gpt_decode
 
         self._gd = gpt_decode
-        self._prefill = gpt_decode.jit_prefill_into_slot(self.cfg, 0.0)
-        self._step = gpt_decode.jit_decode_chunk_slots(
-            self.cfg, self.draft_k, 0.0, -1)
-        self._ingest = gpt_decode.jit_decode_chunk_slots(
-            self.cfg, 1, 0.0, -1)
+        self._pt = np.arange(self.slots, dtype=np.int32)[:, None]
+        self._prefill = gpt_decode.jit_prefill_into_slot_paged(
+            self.cfg, self.max_len, 0.0)
+        self._step = gpt_decode.jit_decode_chunk_slots_paged(
+            self.cfg, self.draft_k, self.max_len, 0.0, -1)
+        self._ingest = gpt_decode.jit_decode_chunk_slots_paged(
+            self.cfg, 1, self.max_len, 0.0, -1)
         self.reset()
 
     def reset(self):
-        self._cache = self._gd.init_slot_cache(self.cfg, self.slots,
-                                               self.max_len)
+        self._cache = self._gd.init_paged_cache(
+            self.cfg, self.slots, self.slots, self.max_len)
         self._pos = np.zeros((self.slots,), np.int32)
         self._pending = np.full((self.slots,), -1, np.int64)
         self._rngs = np.zeros((self.slots, 2), np.uint32)
@@ -307,7 +312,8 @@ class ModelDrafter(Drafter):
         # The fused first-token sample is the TARGET's job; the
         # drafter's is discarded — only the prompt K/V matters here.
         _tok, cache, _key = self._prefill(
-            self.params, self._cache, padded, np.int32(S),
+            self.params, self._cache, padded, np.int32(S), np.int32(0),
+            self._pt[slot], np.int32(self._gd.PT_SENTINEL),
             np.int32(slot), jax.random.PRNGKey(0))
         self._cache = cache
         self._pos[slot] = S
@@ -319,19 +325,22 @@ class ModelDrafter(Drafter):
 
         # Host-authoritative write cursor: rejected draft positions
         # were rolled back in observe(), so upload pos wholesale (tiny
-        # [slots] int32 against the draft forward).
-        self._cache["pos"] = jnp.asarray(self._pos)
+        # [slots] int32 against the draft forward). A private host copy:
+        # the transfer queues behind the admission's prefill, and
+        # ``_pos`` advances below before it has run.
+        self._cache["pos"] = jnp.asarray(self._pos.copy())
         pend = active & (self._pending >= 0)
         if pend.any():
             ptok = np.where(pend, self._pending, 0).astype(np.int32)
             _t, cache, _d, _r = self._ingest(
-                self.params, self._cache, ptok, self._rngs, pend)
+                self.params, self._cache, ptok, self._rngs, pend,
+                self._pt)
             self._cache = cache
             self._pos[pend] += 1
             self._pending[pend] = -1
         toks, cache, _done, _rngs = self._step(
             self.params, self._cache, np.asarray(last, np.int32),
-            self._rngs, active)
+            self._rngs, active, self._pt)
         self._cache = cache
         self._pos[active] += self.draft_k
         # The drafted tokens must reach the host: the verify dispatch

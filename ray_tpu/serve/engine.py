@@ -1,5 +1,6 @@
 """Continuous-batching decode engine: one driver thread, one persistent
-slot pool (ISSUE 5 tentpole).
+KV page pool (ISSUE 5 tentpole; paged since ISSUE 6, the only pool
+since ISSUE 34).
 
 ``@serve.batch(stream=True)`` gang-schedules: a batch forms once, runs
 its whole generation off a freshly allocated KV cache, and a request
@@ -9,8 +10,8 @@ replaces gang scheduling with **slot scheduling** — the standard
 continuous-batching design of production inference stacks, mapped onto
 TPU-friendly static shapes:
 
-- ONE long-lived pooled KV cache (``[L, B_slots, max_len, H, hd]``,
-  :func:`~ray_tpu.models.gpt_decode.init_slot_cache`) allocated at
+- ONE long-lived pool of KV pages (``[L, n_pages, page_size, H, hd]``,
+  :func:`~ray_tpu.models.gpt_decode.init_paged_cache`) allocated at
   construction. No per-request ``init_cache``; slots are recycled by
   re-prefilling in place.
 - A single driver thread owns every device dispatch, so concurrent
@@ -18,15 +19,15 @@ TPU-friendly static shapes:
   (device-concurrency discipline per the TPU concurrency study in
   PAPERS.md).
 - Admission happens at **chunk boundaries**:
-  :func:`~ray_tpu.models.gpt_decode.prefill_into_slot` writes the
-  prompt's K/V into a free slot (one compiled program per prompt
-  bucket; the TRUE length is traced, so any length within a bucket
-  shares the program) and the first sampled token streams out
+  :func:`~ray_tpu.models.gpt_decode.prefill_into_slot_paged` writes
+  the prompt's K/V into a free slot's pages (one compiled program per
+  prompt bucket; the TRUE length is traced, so any length within a
+  bucket shares the program) and the first sampled token streams out
   immediately — TTFT is one prefill dispatch away from admission, not
   one full gang generation.
-- :func:`~ray_tpu.models.gpt_decode.decode_chunk_slots` then decodes
-  ALL active slots in one fused k-step dispatch; a slot frees the
-  moment its lane samples EOS, exhausts ``max_new``, passes its
+- :func:`~ray_tpu.models.gpt_decode.decode_chunk_slots_paged` then
+  decodes ALL active slots in one fused k-step dispatch; a slot frees
+  the moment its lane samples EOS, exhausts ``max_new``, passes its
   deadline, or its consumer walks away — instead of riding out the
   batch.
 
@@ -40,12 +41,12 @@ queues the batched streaming path uses, so replicas, handles, and the
 HTTP proxy need no new transport: ``engine.submit(...)`` returns a lane,
 ``engine.stream(...)`` an iterator of per-chunk ``np.int32[j]`` slices.
 
-**Paged KV cache + shared-prefix reuse** (ISSUE 6 tentpole,
-``paged=True``): the flat pool reserves ``max_len`` KV per slot up
-front, so concurrency is capped by the WORST-CASE sequence even when
-every live request is short. Paged mode splits the same byte budget
-into fixed-size pages (``[L, n_pages, page_size, H, hd]``) handed out
-by a host-side allocator:
+**KV pages + shared-prefix reuse** (ISSUE 6 tentpole): reserving
+``max_len`` KV per slot up front would cap concurrency by the
+WORST-CASE sequence even when every live request is short. The pool
+holds fixed-size pages handed out by a host-side allocator; its default
+budget (``n_pages=0``) is the bytes of ``slots * max_len`` fp
+positions, so a default engine can hold every slot at full length:
 
 - Each slot carries a page-table row (``[max_pages]`` int32, sentinel
   padded) that the device programs gather/scatter through — the table
@@ -62,24 +63,25 @@ by a host-side allocator:
   re-admission the deterministic per-request PRNG lane replays the
   exact same tokens with the already-delivered prefix suppressed — the
   consumer sees a stall, never an error or a duplicate token.
-- A **prefix cache** (``prefix_cache=True``) hashes prompt prefixes at
-  page granularity: a request whose prompt prefix is already resident
-  maps the cached pages into its table (refcounted), prefills only the
-  suffix, and — when the cached prefix ends mid-page — forks that one
-  page copy-on-write inside the same prefill program. TTFT for a
-  cached system prompt becomes a page-table copy plus a short-suffix
-  prefill. Cache entries are evicted LRU when the allocator runs dry.
+- A **prefix cache** (``prefix_cache=True``, the default) hashes prompt
+  prefixes at page granularity: a request whose prompt prefix is
+  already resident maps the cached pages into its table (refcounted),
+  prefills only the suffix, and — when the cached prefix ends mid-page
+  — forks that one page copy-on-write inside the same prefill program.
+  TTFT for a cached system prompt becomes a page-table copy plus a
+  short-suffix prefill. Cache entries are evicted LRU when the
+  allocator runs dry.
 
-Flat slots remain the default; paged engines are asserted
-token-identical to flat (temp 0 AND seeded temp > 0) in
-``tests/test_serve_engine_paged.py``.
+Streams are asserted token-identical to
+:func:`~ray_tpu.models.gpt_decode.generate_chunked` (temp 0 AND seeded
+temp > 0) in ``tests/test_serve_engine_paged.py``.
 
 **Crash-safe streaming** (ISSUE 7 tentpole): the recompute-preemption
 replay above generalizes ACROSS engines — a stream is fully determined
 by (prompt, sampling knobs, seed, delivered-token count), so any engine
 holding the same weights can reconstruct a lane killed elsewhere:
 ``submit(resume_from=n)`` replays the generation and suppresses the
-first ``n`` tokens (on a paged engine whose prefix cache holds the
+first ``n`` tokens (on an engine whose prefix cache holds the
 prompt, the replay prefill is near-free). The serve layers lean on it
 three ways:
 
@@ -109,8 +111,8 @@ the driver interleaves **draft → verify** per chunk boundary instead:
   n-gram table, or a small GPT on its own slot pool — see
   :mod:`~.draft`);
 - ONE batched target forward
-  (:func:`~ray_tpu.models.gpt_decode.verify_chunk_slots`, paged twin
-  included) scores all ``draft_k + 1`` logit rows, computes each
+  (:func:`~ray_tpu.models.gpt_decode.verify_chunk_slots_paged`)
+  scores all ``draft_k + 1`` logit rows, computes each
   slot's accepted length with exact rejection sampling (greedy match
   at temperature 0; point-mass residual resampling above it — the
   committed stream is the target's own distribution for ANY drafter,
@@ -138,17 +140,17 @@ prefill is compute-bound and bursty, decode bandwidth-bound and steady
 bursts inflate decode TPOT. ``role="prefill"`` turns an engine into a
 prefill-only front: :meth:`handoff` runs the prompt into a transient
 slot, samples the first token, EXPORTS the slot's K/V into a contiguous
-ship buffer (:func:`~ray_tpu.models.gpt_decode.export_slot_kv`, paged
-twin included; trimmed to the true prompt length so the bytes are
-identical whichever pool mode produced them), frees the slot
+ship buffer (:func:`~ray_tpu.models.gpt_decode.export_slot_kv_paged`;
+trimmed to the true prompt length so the bytes are identical whichever
+page layout produced them), frees the slot
 immediately — no slot-pool steady state — and returns a descriptor
 under an epoch-stamped **lease** (:mod:`~.handoff`). ``role="decode"``
 engines own the slot pools: :meth:`admit_prefilled` resolves the
 descriptor (inline or an object-plane chunked pull), BYTE-VERIFIES the
 shipped pages against the stamped digest, and imports them into a free
-slot/pages (:func:`~ray_tpu.models.gpt_decode.import_slot_kv`), so the
-first decode chunk continues bit-exactly where the prefill engine
-stopped. Every failure mode degrades to a cheap re-prefill, never a
+slot's pages
+(:func:`~ray_tpu.models.gpt_decode.import_slot_kv_paged`), so the first
+decode chunk continues bit-exactly where the prefill engine stopped. Every failure mode degrades to a cheap re-prefill, never a
 broken stream: a missing/corrupt payload falls back to a local prefill
 from the descriptor's prompt+seed (token-identical by determinism); a
 decode side that never claims lets the lease expire, and the prefill
@@ -213,7 +215,6 @@ class _EngineRequest:
     slot and route its stream."""
 
     prompt: np.ndarray            # [S] int32
-    bucket: int                   # padded prompt length (compile shape)
     max_new: int
     lane: _StreamLane
     deadline_s: Optional[float]
@@ -260,7 +261,7 @@ class _Slot:
     req: Optional[_EngineRequest] = None   # for recompute preemption
     emitted: int = 1              # tokens DELIVERED to the lane
     admitted_t: float = field(default_factory=time.time)
-    # -------- paged-mode bookkeeping (empty/ignored for flat pools)
+    # -------- page bookkeeping
     pos: int = 0                  # virtual write position (mirrors device)
     pages: List[int] = field(default_factory=list)
     parked: bool = False          # out of pages: excluded from dispatch
@@ -447,7 +448,7 @@ class DecodeEngine:
                  eos_token: int = -1,
                  prompt_buckets: Optional[Sequence[int]] = None,
                  deployment: str = "", auto_start: bool = True,
-                 paged: bool = False, page_size: int = 16,
+                 paged: bool = True, page_size: int = 16,
                  n_pages: int = 0, prefix_cache: bool = True,
                  wedge_timeout_s: float = 30.0,
                  max_driver_restarts: int = 1,
@@ -536,11 +537,10 @@ class DecodeEngine:
             raise ValueError(
                 f"unknown kv_dtype {kv_dtype!r}; expected one of "
                 f"{gpt_decode.KV_DTYPES}")
-        if not paged and (attn_kernel != "gather" or kv_dtype != "fp"):
+        if not paged:
             raise ValueError(
-                "attn_kernel/kv_dtype are paged-pool knobs; construct "
-                "the engine with paged=True (or pass page_size through "
-                "the config plane)")
+                "paged=False: the flat slot pool is gone; the engine "
+                "has one KV pool, the page pool (page_size, n_pages)")
         self.attn_kernel = attn_kernel
         self.kv_dtype = kv_dtype
         # ---- tensor parallelism (ISSUE 20): ENGINE-STATIC mesh width.
@@ -557,7 +557,7 @@ class DecodeEngine:
         # every _build_pool call site can hold it (its holds= contract).
         self._admit_lock = threading.Lock()
         with self._admit_lock:
-            self._build_pool(paged, page_size, n_pages, prefix_cache)
+            self._build_pool(page_size, n_pages, prefix_cache)
         # Per-slot host state; index i mirrors pool row i. ``_token`` /
         # ``_rngs`` are the host copies uploaded with each dispatch
         # (tiny against the chunk compute; keeping them host-side avoids
@@ -567,7 +567,7 @@ class DecodeEngine:
         self._rngs = np.zeros((self.slots, 2), np.uint32)
         self._queue: "queue.SimpleQueue" = queue.SimpleQueue()
         # Driver-local FIFO fed from the submit queue; the head defers
-        # in place when paged admission runs out of pages, preserving
+        # in place when admission runs out of pages, preserving
         # arrival order across the backpressure boundary.
         self._pending: "collections.deque[_EngineRequest]" = \
             collections.deque()
@@ -642,10 +642,10 @@ class DecodeEngine:
     # reachable from here; the budget-vs-actual test pins it to the
     # jit cache sizes on nano CPU.
     # rtlint: program-budget: len(prompt_buckets) + 3
-    def _build_pool(self, paged: bool, page_size: int, n_pages: int,
+    def _build_pool(self, page_size: int, n_pages: int,
                     prefix_cache: bool):  # rtlint: holds=_admit_lock
-        """Allocate THE persistent pool (flat or paged) and bind the
-        matching jitted programs. Called once at construction, by
+        """Allocate THE persistent page pool and bind its jitted
+        programs. Called once at construction, by
         :meth:`ensure_paging` on a never-used engine, and by
         :meth:`_restart_driver` — EVERY call site holds ``_admit_lock``
         (rtlint RT101 real finding: the restart path used to swap
@@ -653,38 +653,18 @@ class DecodeEngine:
         racing a concurrent ``ensure_paging`` config push)."""
         gpt_decode = self._gd
         cfg = self.cfg
-        self.paged = bool(paged)
         # The dispatch-side weights: placed once per pool build (a
         # NamedSharding scatter when tp > 1, the raw host pytree when
         # tp == 1 — shard_params is an identity there). The drafter
         # keeps ``self.params``: it runs its own single-chip programs.
         self._params_dev = gpt_decode.shard_params(
             self.params, cfg, self.tp)
-        if not self.paged:
-            self.page_size = 0
-            self.n_pages = 0
-            self.max_pages = 0
-            self._pool = None
-            self._prefix = None
-            self._pt = None
-            self._prefill = gpt_decode.jit_prefill_into_slot(
-                cfg, self.temperature, self.tp)
-            self._step = gpt_decode.jit_decode_chunk_slots(
-                cfg, self.chunk, self.temperature, self.eos_token,
-                self.tp)
-            self._export = gpt_decode.jit_export_slot_kv(cfg, self.tp)
-            self._import = gpt_decode.jit_import_slot_kv(cfg, self.tp)
-            self._cache = gpt_decode.init_slot_cache(cfg, self.slots,
-                                                     self.max_len,
-                                                     self.tp)
-            self._bind_verify()
-            return
         self.page_size = int(page_size)
         if self.page_size < 1:
             raise ValueError("page_size must be >= 1")
         self.max_pages = -(-self.max_len // self.page_size)   # ceil
-        # Default budget: the SAME KV **bytes** as the flat fp pool
-        # ([slots, max_len] worth of positions), re-cut into pages of
+        # Default budget: the KV **bytes** of [slots, max_len] fp
+        # positions (every slot at full length), re-cut into pages of
         # the configured kv_dtype — an int8 pool's page is ~half the
         # bytes, so the same budget holds ~2x the pages (the ISSUE 16
         # sizing fix: counting pages in positions instead of bytes left
@@ -722,19 +702,15 @@ class DecodeEngine:
     def _bind_verify(self):  # rtlint: holds=_admit_lock
         """(Re)bind the verify program to the current pool layout and
         drafter — ONE compiled program per (pool shape, draft_k), or
-        None with speculative decoding off (the flat/paged bindings are
-        branch-exclusive, so the RT109 budget is 1, not 2). Called from
+        None with speculative decoding off. Called from
         :meth:`_build_pool` and :meth:`ensure_spec`, both of which hold
         ``_admit_lock``."""
         if self._drafter is None:
             self._verify = None
-        elif self.paged:
+        else:
             self._verify = self._gd.jit_verify_chunk_slots_paged(
                 self.cfg, self.draft_k, self.page_size,
                 self.temperature, self.kv_dtype, self.tp)
-        else:
-            self._verify = self._gd.jit_verify_chunk_slots(
-                self.cfg, self.draft_k, self.temperature, self.tp)
 
     def ensure_paging(self, page_size: Optional[int] = None,
                       prefix_cache: Optional[bool] = None,
@@ -764,26 +740,14 @@ class DecodeEngine:
                 f"unknown kv_dtype {kv_dtype!r}; expected one of "
                 f"{self._gd.KV_DTYPES}")
         with self._admit_lock:
-            if want_ps is None and not self.paged and (
-                    prefix_cache or n_pages is not None or
-                    (attn_kernel or "gather") != "gather" or
-                    (kv_dtype or "fp") != "fp"):
-                # Silently no-opping would leave the operator believing
-                # prefix caching / pool sizing / the kernel / int8 KV
-                # is active on a flat pool.
-                raise ValueError(
-                    "prefix_cache/n_pages/attn_kernel/kv_dtype are "
-                    "paged-pool knobs; this engine is flat — pass "
-                    "page_size to repage it")
             knob_change = (
                 (attn_kernel is not None and
                  attn_kernel != self.attn_kernel) or
                 (kv_dtype is not None and kv_dtype != self.kv_dtype))
-            if want_ps is None and self.paged and (
-                    n_pages is not None or knob_change):
-                want_ps = self.page_size   # rebuild keeps the page size
-            need_rebuild = want_ps is not None and (
-                not self.paged or self.page_size != want_ps or
+            if want_ps is None:
+                want_ps = self.page_size   # as constructed
+            need_rebuild = (
+                want_ps != self.page_size or
                 (n_pages is not None and int(n_pages) != self.n_pages) or
                 knob_change)
             if need_rebuild:
@@ -793,17 +757,17 @@ class DecodeEngine:
                         any(s is not None for s in self._state):
                     raise ValueError(
                         f"cannot repage a live engine (page_size="
-                        f"{self.page_size or None} -> {want_ps}); "
-                        f"construct it paged or apply the config "
-                        f"before traffic")
+                        f"{self.page_size} -> {want_ps}); construct it "
+                        f"with the knobs or apply the config before "
+                        f"traffic")
                 if attn_kernel is not None:
                     self.attn_kernel = attn_kernel
                 if kv_dtype is not None:
                     self.kv_dtype = kv_dtype
-                self._build_pool(True, want_ps, int(n_pages or 0),
+                self._build_pool(want_ps, int(n_pages or 0),
                                  prefix_cache if prefix_cache is not None
                                  else self._prefix is not None)
-            elif prefix_cache is not None and self.paged:
+            elif prefix_cache is not None:
                 if prefix_cache and self._prefix is None:
                     self._prefix = _PrefixCache(self._pool,
                                                 self.page_size)
@@ -917,7 +881,7 @@ class DecodeEngine:
             # Validate (divisibility + visible devices) BEFORE mutating.
             self._gd._tp_mesh(self.cfg, want)
             self.tp = want
-            self._build_pool(self.paged, self.page_size, self.n_pages,
+            self._build_pool(self.page_size, self.n_pages,
                              self._prefix is not None)
         return self
 
@@ -966,14 +930,14 @@ class DecodeEngine:
     def _validate_admission(self, prompt, max_new: int):
         """Shared admission-time validation for every entry point that
         prefills from a prompt (``submit`` and ``handoff``):
-        canonicalize the prompt, pick its compile bucket, and bound the
-        generation against the cache. Returns ``(prompt, bucket)``."""
+        canonicalize the prompt, check that a compile bucket holds it,
+        and bound the generation against the cache. Returns the
+        prompt."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         S = prompt.shape[0]
         if S < 1:
             raise ValueError("empty prompt")
-        bucket = next((b for b in self.prompt_buckets if b >= S), None)
-        if bucket is None:
+        if S > self.prompt_buckets[-1]:
             raise ValueError(
                 f"prompt length {S} exceeds largest prompt bucket "
                 f"{self.prompt_buckets[-1]}")
@@ -981,7 +945,7 @@ class DecodeEngine:
             raise ValueError(
                 f"prompt ({S}) + max_new ({max_new}) exceeds cache "
                 f"length {self.max_len}")
-        return prompt, bucket
+        return prompt
 
     def _new_req_id(self) -> str:
         """Flight-recorder correlation id for this admission: the
@@ -1007,7 +971,7 @@ class DecodeEngine:
         caller already holds the first ``n`` tokens of this exact
         (prompt, knobs, seed) stream — delivered by another replica
         before it died — so the engine replays the generation (the
-        per-request PRNG lane is deterministic; a paged engine's prefix
+        per-request PRNG lane is deterministic; the engine's prefix
         cache makes the prompt prefill near-free) and suppresses the
         first ``n`` tokens from the lane."""
         if self.role == "prefill":
@@ -1015,7 +979,7 @@ class DecodeEngine:
                 "prefill-role engine only exports handoffs (use "
                 "handoff()); decode streams need a decode-capable "
                 "engine")
-        prompt, bucket = self._validate_admission(prompt, max_new)
+        prompt = self._validate_admission(prompt, max_new)
         resume_from = int(resume_from)
         if resume_from < 0 or resume_from > max_new:
             raise ValueError(
@@ -1039,7 +1003,7 @@ class DecodeEngine:
                     "engine is not accepting requests (draining or shut "
                     "down); resubmit on another replica")
             self._queue.put(_EngineRequest(
-                prompt=prompt, bucket=bucket, max_new=int(max_new),
+                prompt=prompt, max_new=int(max_new),
                 lane=lane, deadline_s=deadline_s, trace_ctx=trace_ctx,
                 seed=int(seed), enq_ns=time.monotonic_ns(), skip=resume_from,
                 req_id=req_id))
@@ -1080,7 +1044,7 @@ class DecodeEngine:
             raise ValueError(
                 "decode-role engine cannot export handoffs; use a "
                 "prefill or both-role engine")
-        prompt, bucket = self._validate_admission(prompt, max_new)
+        prompt = self._validate_admission(prompt, max_new)
         if max_new < 1:
             raise ValueError("handoff needs max_new >= 1 (the first "
                              "token is sampled at prefill)")
@@ -1092,7 +1056,7 @@ class DecodeEngine:
                     "engine is not accepting requests (draining or "
                     "shut down); resubmit on another replica")
             self._queue.put(_EngineRequest(
-                prompt=prompt, bucket=bucket, max_new=int(max_new),
+                prompt=prompt, max_new=int(max_new),
                 lane=lane, deadline_s=deadline_s, trace_ctx=trace_ctx,
                 seed=int(seed), enq_ns=time.monotonic_ns(), export=True,
                 ttl_s=float(ttl_s or 0.0), req_id=req_id))
@@ -1185,11 +1149,10 @@ class DecodeEngine:
             # fp->int8, or a different page cut) degrades to the local
             # re-prefill, which is token-identical by determinism.
             ship_dt = payload.get("kv_dtype", "fp")
-            mine = self.kv_dtype if self.paged else "fp"
-            if ship_dt != mine:
+            if ship_dt != self.kv_dtype:
                 raise HandoffError(
                     f"shipped kv_dtype {ship_dt!r} does not match this "
-                    f"engine's ({mine!r})")
+                    f"engine's ({self.kv_dtype!r})")
             if ship_dt == "int8" and \
                     int(payload.get("page_size", 0)) != self.page_size:
                 raise HandoffError(
@@ -1227,11 +1190,6 @@ class DecodeEngine:
             raise ValueError(
                 f"resume_from {resume_from} outside [0, max_new="
                 f"{max_new}]")
-        # The preemption-replay fallback needs a bucket only when the
-        # payload is lost mid-flight; an over-long prompt just pins the
-        # import path (re-import replays it fine).
-        bucket = next((b for b in self.prompt_buckets
-                       if b >= prompt.shape[0]), self.prompt_buckets[-1])
         lane = _StreamLane()
         req_id = self._new_req_id()
         with self._admit_lock:
@@ -1240,7 +1198,7 @@ class DecodeEngine:
                     "engine is not accepting requests (draining or "
                     "shut down); resubmit on another replica")
             self._queue.put(_EngineRequest(
-                prompt=prompt, bucket=bucket, max_new=max_new,
+                prompt=prompt, max_new=max_new,
                 lane=lane, deadline_s=deadline_s, trace_ctx=trace_ctx,
                 seed=seed, enq_ns=time.monotonic_ns(), skip=resume_from,
                 handoff={"payload": payload,
@@ -1295,21 +1253,19 @@ class DecodeEngine:
         try:
             key = jax.random.PRNGKey(0)
             active = np.zeros((self.slots,), bool)
-            # The paged programs' extra operands: no history, an
+            # The programs' paging operands: no history, an
             # all-sentinel page table (every write drops), no COW.
             none = np.full((self.max_pages,), gd.PT_SENTINEL, np.int32)
-            mid = (np.int32(0), none, np.int32(gd.PT_SENTINEL)) \
-                if self.paged else ()
-            tail = (self._pt,) if self.paged else ()
+            mid = (np.int32(0), none, np.int32(gd.PT_SENTINEL))
             for b in self.prompt_buckets:
                 _, self._cache, _ = timed(
                     f"prefill_{b}", self._prefill, self._params_dev,
                     self._cache, np.zeros((1, b), np.int32), np.int32(1),
                     *mid, np.int32(0), key)
             step_args = (self._params_dev, self._cache, self._token,
-                         self._rngs, active, *tail)
+                         self._rngs, active, self._pt)
             mode = None
-            if self.paged and self.attn_kernel == "pallas":
+            if self.attn_kernel == "pallas":
                 from .._private.chip import compiled_by_mosaic
 
                 mode = "compiled" if compiled_by_mosaic(
@@ -1321,10 +1277,9 @@ class DecodeEngine:
                     "verify", self._verify, self._params_dev, self._cache,
                     self._token,
                     np.zeros((self.slots, self.draft_k), np.int32),
-                    self._rngs, active, *tail)
+                    self._rngs, active, self._pt)
             if self.role == "prefill":
-                timed("export", self._export, self._cache,
-                      none if self.paged else np.int32(0))
+                timed("export", self._export, self._cache, none)
             self._warm_report = {"programs": secs,
                                  "total_s": round(sum(secs.values()), 3),
                                  "attn_kernel_mode": mode}
@@ -1461,8 +1416,8 @@ class DecodeEngine:
             # push racing this restart must see either the old pool or
             # the new one — never a half-built mix.
             with self._admit_lock:
-                self._build_pool(self.paged, self.page_size or 16,
-                                 self.n_pages, self._prefix is not None)
+                self._build_pool(self.page_size, self.n_pages,
+                                 self._prefix is not None)
                 if self._drafter is not None:
                     # The pool was rebuilt from scratch and every lane
                     # failed; per-slot drafter state must follow.
@@ -1547,7 +1502,7 @@ class DecodeEngine:
                     for ph, ns in self._driver_ns.items()})
         out["compiles"] = self._compiles["n"]
         out["compile_ns"] = self._compiles["ns"]
-        out["paged"] = self.paged
+        out["paged"] = True     # one pool; _controller.py folds the key
         out["deployment"] = self.deployment
         out["tp"] = self.tp
         sp_r = out.pop("spec_rounds")
@@ -1601,25 +1556,19 @@ class DecodeEngine:
         _rtsan = _sys.modules.get("tools.rtsan")
         if _rtsan is not None and _rtsan.is_active():
             out["sanitizer"] = _rtsan.stats_block("serve/")
-        if self.paged:
-            out["page_size"] = self.page_size
-            out["n_pages"] = self.n_pages
-            out["pages_free"] = self._pool.available()
-            out["pages_used"] = self.n_pages - self._pool.available()
-            out["parked_slots"] = sum(
-                s is not None and s.parked for s in self._state)
-            if self._prefix is not None:
-                out["prefix_cache_entries"] = len(self._prefix)
-                out["prefix_evictions"] = self._prefix.evictions
-            out["attn_kernel"] = self.attn_kernel
-            out["kv_dtype"] = self.kv_dtype
-            out["kv_bytes_per_token"] = self._gd.kv_bytes_per_page(
-                self.cfg, self.page_size, self.kv_dtype) / self.page_size
-        else:
-            for k in ("prefix_hits", "prefix_tokens_reused",
-                      "cow_copies", "admissions_deferred", "lane_parks",
-                      "preempted", "attn_kernel_dispatches"):
-                out.pop(k, None)
+        out["page_size"] = self.page_size
+        out["n_pages"] = self.n_pages
+        out["pages_free"] = self._pool.available()
+        out["pages_used"] = self.n_pages - self._pool.available()
+        out["parked_slots"] = sum(
+            s is not None and s.parked for s in self._state)
+        if self._prefix is not None:
+            out["prefix_cache_entries"] = len(self._prefix)
+            out["prefix_evictions"] = self._prefix.evictions
+        out["attn_kernel"] = self.attn_kernel
+        out["kv_dtype"] = self.kv_dtype
+        out["kv_bytes_per_token"] = self._gd.kv_bytes_per_page(
+            self.cfg, self.page_size, self.kv_dtype) / self.page_size
         return out
 
     def _count(self, **deltas):
@@ -1791,8 +1740,6 @@ class DecodeEngine:
         return pages
 
     def _observe_pages(self, sm=None):
-        if not self.paged:
-            return
         if sm is None:
             from .._private.metrics import serve_metrics
             sm = serve_metrics()
@@ -1828,7 +1775,7 @@ class DecodeEngine:
     def _admit_pending(self, epoch: int = -1):  # rtlint: owner=driver
         """Chunk-boundary admission: fill every free slot in FIFO order.
         Expired / abandoned requests are failed out without spending a
-        prefill; a paged admission that cannot get pages DEFERS — it
+        prefill; an admission that cannot get pages DEFERS — it
         stays at the queue head (order preserved) and retries next
         boundary, by which time a lane may have freed pages."""
         if epoch >= 0 and epoch != self._epoch:
@@ -1891,7 +1838,7 @@ class DecodeEngine:
     # rtlint: owner=driver
     def _admit_one(self, req: _EngineRequest, epoch: int = -1) -> bool:
         """Prefill ``req`` into a free slot; returns False to defer
-        (paged mode, no pages). Lane-closed/expired checks happen in
+        (no pages). Lane-closed/expired checks happen in
         :meth:`_admit_pending` before any resources are taken. A stale
         driver (the supervisor restarted past it while its prefill was
         stuck on the device) drops the result at the epoch guard instead
@@ -1905,31 +1852,10 @@ class DecodeEngine:
         sm = serve_metrics()
         if req.handoff is not None:
             return self._admit_import(req, slot, sm, epoch)
-        if self.paged:
-            admitted = self._prefill_paged(req, slot, P, sm, jax, epoch)
-            if admitted is None:
-                return False
-            first, pages, hist, bucket = admitted
-        else:
-            hist, bucket = 0, req.bucket
-            with self._phases.phase("prefill", bucket=bucket,
-                                    hist_len=0) as ph:
-                padded = np.zeros((1, bucket), np.int32)
-                padded[0, :P] = req.prompt
-                tok, cache, key = self._prefill(
-                    self._params_dev, self._cache, padded, np.int32(P),
-                    np.int32(slot), jax.random.PRNGKey(req.seed))
-                # One transfer per admission — THE TTFT point.
-                # rtlint: sync-ok=ttft first token streams from the host
-                first = int(np.asarray(tok))
-            req.granted_ns, req.first_ns = ph.t0, ph.t1
-            if epoch >= 0 and epoch != self._epoch:
-                return True          # stale driver: drop on the floor
-            self._cache = cache
-            # Host mirror of the slot's PRNG lane (tiny [2] uint32).
-            # rtlint: sync-ok=prng-mirror re-uploaded per dispatch
-            self._rngs[slot] = np.asarray(key)
-            pages = []
+        admitted = self._prefill_paged(req, slot, P, sm, jax, epoch)
+        if admitted is None:
+            return False
+        first, pages, hist, bucket = admitted
         fresh = req.skip == 0 and not req.export
         self._note_admission(req, slot, sm, fresh)
         if req.trace_ctx is not None:
@@ -1994,9 +1920,8 @@ class DecodeEngine:
                                 and first == self.eos_token):
             req.lane.q.put((_STREAM_END, None))
             self._count(completed=1)
-            if pages:
-                self._pool.unref(pages)
-                self._pt[slot, :] = self._gd.PT_SENTINEL
+            self._pool.unref(pages)
+            self._pt[slot, :] = self._gd.PT_SENTINEL
             self._observe_pages(sm)
             return True
         self._state[slot] = _Slot(
@@ -2123,15 +2048,13 @@ class DecodeEngine:
         """
         from . import handoff as _ho
 
-        quant = self.paged and self.kv_dtype == "int8"
+        quant = self.kv_dtype == "int8"
         ks = vs = None
         if quant:
             k_dev, v_dev, ks_dev, vs_dev = self._export(
                 self._cache, self._pt[slot])
-        elif self.paged:
-            k_dev, v_dev = self._export(self._cache, self._pt[slot])
         else:
-            k_dev, v_dev = self._export(self._cache, np.int32(slot))
+            k_dev, v_dev = self._export(self._cache, self._pt[slot])
         # Trim to pos BEFORE hashing/shipping: positions past P hold
         # pad/stale garbage the mask never read — shipping them would
         # make the digest depend on pool history.
@@ -2152,9 +2075,8 @@ class DecodeEngine:
             # rtlint: sync-ok=ship per-page V scales ride the payload
             vs = np.asarray(vs_dev)[:, :n_cover].copy()
         rng = np.asarray(self._rngs[slot], np.uint32).copy()
-        if pages:
-            self._pool.unref(pages)
-            self._pt[slot, :] = self._gd.PT_SENTINEL
+        self._pool.unref(pages)
+        self._pt[slot, :] = self._gd.PT_SENTINEL
         payload = _ho.build_payload(k=k, v=v, prompt=req.prompt, pos=P,
                                     first=first, rng=rng, seed=req.seed,
                                     max_new=req.max_new, ks=ks, vs=vs,
@@ -2194,12 +2116,11 @@ class DecodeEngine:
     def _admit_import(self, req: _EngineRequest, slot: int, sm,
                       epoch: int = -1) -> bool:
         """Handoff import admission (ISSUE 14): scatter the verified
-        ship buffer into a free slot (flat) or freshly mapped pages
-        (paged), restore the slot's PRNG lane and fed token, and enter
-        steady-state decode exactly where the prefill engine stopped.
-        Returns False to defer (paged mode, no pages). A recompute
-        preemption re-enqueues the request WITH its payload, so the
-        replay is a re-import, not a re-prefill."""
+        ship buffer into freshly mapped pages, restore the slot's PRNG
+        lane and fed token, and enter steady-state decode exactly where
+        the prefill engine stopped. Returns False to defer (no pages).
+        A recompute preemption re-enqueues the request WITH its
+        payload, so the replay is a re-import, not a re-prefill."""
         payload = req.handoff["payload"]
         P = int(payload["pos"])
         gd = self._gd
@@ -2207,63 +2128,46 @@ class DecodeEngine:
         H, hd = self.cfg.n_head, self.cfg.head_dim
         dt = payload["k"].dtype
         req.granted_ns = time.monotonic_ns()
-        if self.paged:
-            ps = self.page_size
-            # ONE pool snapshot for the whole admission (see
-            # _prefill_paged): a supervisor restart must never split
-            # page accounting across two pool objects.
-            pool = self._pool
-            prefix = self._prefix
-            n_cover = -(-P // ps)
-            pages = self._alloc_pages(n_cover, pool, prefix)
-            if pages is None:
-                return False          # out of pages: defer, keep FIFO
-            pt_row = np.full((self.max_pages,), gd.PT_SENTINEL,
-                             np.int32)
-            pt_row[:len(pages)] = pages
-            self._pt[slot] = pt_row
-            k_pad = np.zeros((L, self.max_pages * ps, H, hd), dt)
-            v_pad = np.zeros((L, self.max_pages * ps, H, hd), dt)
-            k_pad[:, :P] = payload["k"]
-            v_pad[:, :P] = payload["v"]
-            if self.kv_dtype == "int8":
-                # Quantized handoff: the codes pad/reshape exactly like
-                # fp K/V; the per-page scales pad to the full table
-                # width and scatter under the same page mask.
-                ks_pad = np.zeros((L, self.max_pages, H), np.float32)
-                vs_pad = np.zeros((L, self.max_pages, H), np.float32)
-                ks_pad[:, :n_cover] = payload["ks"]
-                vs_pad[:, :n_cover] = payload["vs"]
-                cache = self._import(
-                    self._cache,
-                    k_pad.reshape(L, self.max_pages, ps, H, hd),
-                    v_pad.reshape(L, self.max_pages, ps, H, hd),
-                    ks_pad, vs_pad,
-                    pt_row, np.int32(slot), np.int32(P))
-            else:
-                cache = self._import(
-                    self._cache,
-                    k_pad.reshape(L, self.max_pages, ps, H, hd),
-                    v_pad.reshape(L, self.max_pages, ps, H, hd),
-                    pt_row, np.int32(slot), np.int32(P))
-            if epoch >= 0 and epoch != self._epoch:
-                pool.unref(pages)     # stale driver: hand pages back
-                return True
-            # Shipped pages cover the WHOLE prompt: register them so
-            # later local admissions of the same prompt prefix map the
-            # imported pages instead of re-prefilling.
-            if prefix is not None and P == req.prompt.shape[0]:
-                prefix.insert(req.prompt, pages)
-        else:
-            pages = []
-            k_pad = np.zeros((L, self.max_len, H, hd), dt)
-            v_pad = np.zeros((L, self.max_len, H, hd), dt)
-            k_pad[:, :P] = payload["k"]
-            v_pad[:, :P] = payload["v"]
-            cache = self._import(self._cache, k_pad, v_pad,
-                                 np.int32(slot), np.int32(P))
-            if epoch >= 0 and epoch != self._epoch:
-                return True           # stale driver: drop on the floor
+        ps = self.page_size
+        # ONE pool snapshot for the whole admission (see
+        # _prefill_paged): a supervisor restart must never split
+        # page accounting across two pool objects.
+        pool = self._pool
+        prefix = self._prefix
+        n_cover = -(-P // ps)
+        pages = self._alloc_pages(n_cover, pool, prefix)
+        if pages is None:
+            return False          # out of pages: defer, keep FIFO
+        pt_row = np.full((self.max_pages,), gd.PT_SENTINEL, np.int32)
+        pt_row[:len(pages)] = pages
+        self._pt[slot] = pt_row
+        k_pad = np.zeros((L, self.max_pages * ps, H, hd), dt)
+        v_pad = np.zeros((L, self.max_pages * ps, H, hd), dt)
+        k_pad[:, :P] = payload["k"]
+        v_pad[:, :P] = payload["v"]
+        scales = ()
+        if self.kv_dtype == "int8":
+            # Quantized handoff: the codes pad/reshape exactly like
+            # fp K/V; the per-page scales pad to the full table
+            # width and scatter under the same page mask.
+            ks_pad = np.zeros((L, self.max_pages, H), np.float32)
+            vs_pad = np.zeros((L, self.max_pages, H), np.float32)
+            ks_pad[:, :n_cover] = payload["ks"]
+            vs_pad[:, :n_cover] = payload["vs"]
+            scales = (ks_pad, vs_pad)
+        cache = self._import(
+            self._cache,
+            k_pad.reshape(L, self.max_pages, ps, H, hd),
+            v_pad.reshape(L, self.max_pages, ps, H, hd),
+            *scales, pt_row, np.int32(slot), np.int32(P))
+        if epoch >= 0 and epoch != self._epoch:
+            pool.unref(pages)     # stale driver: hand pages back
+            return True
+        # Shipped pages cover the WHOLE prompt: register them so
+        # later local admissions of the same prompt prefix map the
+        # imported pages instead of re-prefilling.
+        if prefix is not None and P == req.prompt.shape[0]:
+            prefix.insert(req.prompt, pages)
         self._cache = cache
         first = int(payload["first"])
         self._token[slot] = first
@@ -2284,7 +2188,7 @@ class DecodeEngine:
         return self._enter_steady_state(req, slot, first, P, pages, sm)
 
     def _cover_pages(self) -> bool:  # rtlint: owner=driver
-        """Allocate-on-advance (paged mode, chunk boundary): every
+        """Allocate-on-advance (chunk boundary): every
         occupied slot must have pages mapped through the positions this
         chunk will write (``pos + min(chunk, remaining)``). A slot that
         cannot be covered PARKS — it keeps its state and pages but sits
@@ -2394,7 +2298,7 @@ class DecodeEngine:
             # it against the NEW driver's pool would preempt a healthy
             # restarted lane.
             return
-        if cover and self.paged:
+        if cover:
             with self._phases.phase("cover"):
                 runnable = self._cover_pages()
             if not runnable:
@@ -2404,14 +2308,9 @@ class DecodeEngine:
         n_active = int(active.sum())
         with self._phases.phase("decode", slots_active=n_active) as ph:
             self._note_decode_gap(ph.t0)
-            if self.paged:
-                toks, cache, _done, rngs = self._step(
-                    self._params_dev, self._cache, self._token,
-                    self._rngs, active, self._pt)
-            else:
-                toks, cache, _done, rngs = self._step(
-                    self._params_dev, self._cache, self._token,
-                    self._rngs, active)
+            toks, cache, _done, rngs = self._step(
+                self._params_dev, self._cache, self._token,
+                self._rngs, active, self._pt)
             # ONE transfer per fused k-step chunk — the engine's
             # designed streaming granularity.
             # rtlint: sync-ok=chunk-boundary one transfer per chunk
@@ -2440,9 +2339,8 @@ class DecodeEngine:
                 # engine.dispatch — one pair per chunk boundary.
                 _driver_emit("shard.dispatch", epoch=self._epoch,
                              mesh=[("tp", self.tp)],
-                             program="chunk_paged" if self.paged
-                             else "chunk")
-            if self.paged and self.attn_kernel == "pallas":
+                             program="chunk_paged")
+            if self.attn_kernel == "pallas":
                 # One fused-kernel dispatch per chunk program launch (the
                 # kernel runs k times per layer inside it).
                 sm["engine_attn_kernel_dispatches"].inc(
@@ -2546,11 +2444,10 @@ class DecodeEngine:
 
         if epoch >= 0 and epoch != self._epoch:
             return
-        if self.paged:
-            with self._phases.phase("cover"):
-                runnable = self._cover_pages()
-            if not runnable:
-                return                # re-run admission/coverage pass
+        with self._phases.phase("cover"):
+            runnable = self._cover_pages()
+        if not runnable:
+            return                    # re-run admission/coverage pass
         active = np.array([s is not None and not s.parked
                            for s in self._state], bool)
         n_active = int(active.sum())
@@ -2572,14 +2469,9 @@ class DecodeEngine:
         with self._phases.phase("decode", slots_active=n_active,
                                 spec=True) as ph:
             self._note_decode_gap(ph.t0)
-            if self.paged:
-                committed, n_acc, cache, rngs = self._verify(
-                    self._params_dev, self._cache, self._token, draft,
-                    self._rngs, active, self._pt)
-            else:
-                committed, n_acc, cache, rngs = self._verify(
-                    self._params_dev, self._cache, self._token, draft,
-                    self._rngs, active)
+            committed, n_acc, cache, rngs = self._verify(
+                self._params_dev, self._cache, self._token, draft,
+                self._rngs, active, self._pt)
             # ONE transfer per verify round: committed tokens, accept
             # counts, and PRNG lanes come back together.
             # rtlint: sync-ok=verify-boundary one transfer per round
@@ -2611,8 +2503,7 @@ class DecodeEngine:
             if self.tp > 1:
                 _driver_emit("shard.dispatch", epoch=self._epoch,
                              mesh=[("tp", self.tp)],
-                             program="verify_paged" if self.paged
-                             else "verify")
+                             program="verify_paged")
             with self._stats_lock:
                 self._stats["peak_active"] = max(self._stats["peak_active"],
                                                  n_active)

@@ -62,9 +62,9 @@ def _meta_bytes(payload: Dict[str, Any]) -> bytes:
 def payload_digest(payload: Dict[str, Any]) -> str:
     """SHA-256 over the shipped K/V bytes AND every replay-relevant
     field — byte-verification of the shipped pages, not just a length
-    check. Deterministic across flat/paged exporters because both trim
-    to the true prompt length before hashing. Quantized payloads
-    (ISSUE 16) additionally fold the per-page scales and the layout
+    check. Deterministic across exporters' page sizes because every
+    exporter trims to the true prompt length before hashing. Quantized
+    payloads (ISSUE 16) additionally fold the per-page scales and the layout
     identity (``kv_dtype``, ``page_size``) into the hash — ONLY when
     present, so fp digests are byte-for-byte what they were before the
     int8 plane existed."""

@@ -273,7 +273,7 @@ def test_abandoned_pipeline_frees_engine(nano, nano_params):
     boundary, and it remains admissible for the next run."""
     from ray_tpu import data as rd
 
-    eng = _make_engine(nano, nano_params, paged=True, page_size=8,
+    eng = _make_engine(nano, nano_params, page_size=8,
                        prefix_cache=False)
     n_pages = eng.n_pages
     try:

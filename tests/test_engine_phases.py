@@ -16,9 +16,9 @@ from ray_tpu.util import tracing
 PHASES = ("idle", "admit", "prefill", "cover", "decode", "deliver",
           "other")
 KINDS = {
-    "flat": dict(),
-    "paged": dict(paged=True, page_size=8, prefix_cache=True),
-    "spec": dict(paged=True, page_size=8, spec_decode="ngram", draft_k=2),
+    "int8": dict(page_size=8, prefix_cache=True, kv_dtype="int8"),
+    "paged": dict(page_size=8, prefix_cache=True),
+    "spec": dict(page_size=8, spec_decode="ngram", draft_k=2),
 }
 
 
@@ -44,7 +44,7 @@ def make(nano, nano_params):
 
     made = []
 
-    def _make(kind="flat", **kw):
+    def _make(kind="paged", **kw):
         kw = {**KINDS[kind], **kw}
         kw.setdefault("slots", 2)
         kw.setdefault("chunk", 4)
@@ -120,6 +120,111 @@ def test_lowered_module_is_named(make, tp):
     assert eng._prefill.__name__ == "prefill_into_slot_paged"
 
 
+# --------------------------------------- what the benchmark reads by name
+def _benchmark_file(rel):
+    """A file under ``benchmarks/perf`` as a module, so each case below
+    holds the engine to the names its consumer itself holds."""
+    import importlib.util
+    import os
+    import sys
+
+    perf = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "perf")
+    sys.path.insert(0, perf)        # layer metrics import trace_reduce
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "_bench_" + os.path.basename(rel).replace(".", "_"),
+            os.path.join(perf, rel))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+    finally:
+        sys.path.remove(perf)
+
+
+#: ``engine.stats()`` keys that ``benchmarks/perf`` (the cell, its layer
+#: metrics, the poller) and ``serve/_controller.py`` index by name.
+_STATS_READ = (
+    "abandoned", "active_slots", "admission_wait_ns_sum", "admitted",
+    "avg_occupancy", "compiles", "completed", "decode_gap_ns_sum",
+    "dispatches", "driver_ns_decode", "driver_ns_idle",
+    "driver_ns_total", "driver_restarts", "expired", "n_pages",
+    "paged", "pages_used", "preempted", "prefill_ns_sum", "prefills",
+    "prefix_evictions", "queued", "resumed", "tokens")
+
+
+@pytest.mark.parametrize("consumer", ["program_names", "program_readers",
+                                      "stats", "architecture"])
+def test_names_the_benchmark_reads(make, nano, consumer):
+    """``benchmarks/perf`` finds pieces of ``ray_tpu`` by name; a
+    rename shows here, on the CPU, and not as ``output_malformed`` on
+    the chip. One case per consumer."""
+    from ray_tpu.models import gpt_decode as gd
+    from ray_tpu.serve.engine import DecodeEngine
+
+    if consumer == "program_names":
+        # the sampler labels the driver thread by frame: the driver's
+        # entry, and the two functions it blocks in on a result
+        names = _benchmark_file("program_names.py")
+        # (unwrap: under the suite rtsan wraps the annotated methods)
+        entry = inspect.unwrap(getattr(DecodeEngine, names.DRIVER_ENTRY))
+        assert entry.__code__.co_filename.endswith(names.ENGINE_FILE)
+        for wait, read in ((names.CHUNK_WAIT, "np.asarray(toks)"),
+                           (names.PREFILL_WAIT, "np.asarray(tok)")):
+            file, func = wait.split(":")
+            assert names.ENGINE_FILE.endswith(file)
+            fn = inspect.unwrap(getattr(DecodeEngine, func))
+            assert fn.__code__.co_filename.endswith(names.ENGINE_FILE)
+            assert read in inspect.getsource(fn), (func, read)
+    elif consumer == "program_readers":
+        # decode_prog_dev_ms finds the chunk program by the module's
+        # name, attn_kernel_share_pct the kernel by its scope under it
+        import jax
+
+        eng = make("paged", attn_kernel="pallas")
+        args = (eng._params_dev, eng._cache, eng._token, eng._rngs,
+                np.zeros((eng.slots,), bool), eng._pt)
+        text = eng._step.lower(*args).as_text()
+        program = _benchmark_file(
+            "layer_metrics/decode_prog_dev_ms.py").PROGRAM
+        assert program == "jit_decode_chunk_slots_paged("
+        assert f"module @{program[:-1]} " in text
+        assert eng._step.__name__ == "decode_chunk_slots_paged"
+        assert eng._prefill.__name__ == "prefill_into_slot_paged"
+        scope = _benchmark_file(
+            "layer_metrics/attn_kernel_share_pct.py").SCOPE
+        assert scope == "pallas_call"
+        assert scope in str(jax.make_jaxpr(eng._step)(*args))
+    elif consumer == "stats":
+        eng = make("paged")
+        list(eng.stream(_prompt(nano, 5), 6))
+        st = eng.stats()
+        assert not [k for k in _STATS_READ if k not in st]
+        assert st["paged"] is True
+        assert all(f"driver_ns_{p}" in st for p in PHASES + ("total",))
+    else:
+        # architectures/gpt2.py: the constructor's keywords, the
+        # attributes it reads back, and the gpt_decode names it calls
+        eng = make("paged", paged=True, page_size=8, n_pages=16,
+                   prefix_cache=True, attn_kernel="gather",
+                   kv_dtype="fp")
+        for attr in ("params", "kv_dtype", "attn_kernel", "page_size",
+                     "prompt_buckets"):
+            assert hasattr(eng, attr), attr
+        assert eng.page_size == 8 and eng.prompt_buckets == [8, 16]
+        for name in ("init_paged_cache", "jit_prefill_into_slot_paged",
+                     "_slot_decode_step_paged"):
+            assert callable(getattr(gd, name)), name
+        assert gd.PT_SENTINEL == 2 ** 30
+        want = ["params", "cache", "token", "active", "pt", "cfg",
+                "page_size", "kv_dtype", "attn_kernel"]
+        assert list(inspect.signature(
+            gd._slot_decode_step_paged).parameters)[:len(want)] == want
+        assert list(inspect.signature(
+            gd.init_paged_cache).parameters) == [
+                "cfg", "slots", "n_pages", "page_size", "kv_dtype", "tp"]
+
+
 def test_train_programs_are_named(nano):
     import jax
     from jax.sharding import Mesh
@@ -165,7 +270,7 @@ def test_idle_engine_is_idle(make):
         assert d[k] == 0, k
 
 
-@pytest.mark.parametrize("kind", ["flat", "paged"])
+@pytest.mark.parametrize("kind", ["int8", "paged"])
 def test_compiles_stand_still_after_first_use(make, nano, kind):
     """After a bucket's first request nothing compiles, however many
     requests follow; the next bucket's first use builds one program."""
@@ -185,7 +290,7 @@ def test_compiles_stand_still_after_first_use(make, nano, kind):
 
 
 # ---------------------------------------------------------- decode gap
-@pytest.mark.parametrize("kind", ["flat", "paged"])
+@pytest.mark.parametrize("kind", ["int8", "paged"])
 def test_decode_gap_is_what_a_prefill_costs_running_lanes(make, nano,
                                                           kind):
     eng = make(kind)

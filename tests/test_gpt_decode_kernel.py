@@ -54,7 +54,6 @@ def _make(nano, nano_params, **kw):
     kw.setdefault("chunk", 4)
     kw.setdefault("max_len", 64)
     kw.setdefault("prompt_buckets", (8, 16))
-    kw.setdefault("paged", True)
     kw.setdefault("page_size", 8)
     return DecodeEngine(nano_params, nano, **kw)
 
@@ -294,13 +293,12 @@ def test_quantized_handoff_roundtrip(nano, nano_params):
     engine. Tampering with a shipped scale fails byte-verification and
     degrades to the counted local re-prefill; so does landing the int8
     payload on an fp engine (layout mismatch)."""
-    kw = dict(paged=True, page_size=8, prefix_cache=False,
+    kw = dict(page_size=8, prefix_cache=False,
               kv_dtype="int8")
     pre = _make(nano, nano_params, role="prefill", **kw)
     dec = _make(nano, nano_params, role="decode", **kw)
     ref_eng = _make(nano, nano_params, **kw)
-    fp_dec = _make(nano, nano_params, role="decode", paged=True,
-                   page_size=8, prefix_cache=False, kv_dtype="fp")
+    fp_dec = _make(nano, nano_params, role="decode", page_size=8, prefix_cache=False, kv_dtype="fp")
     try:
         rng = np.random.default_rng(8)
         prompt = rng.integers(0, nano.vocab_size, (11,)).astype(np.int32)
@@ -339,7 +337,7 @@ def test_quantized_handoff_roundtrip(nano, nano_params):
 
 
 def _ref_fp_stream(nano, nano_params, prompt):
-    eng = _make(nano, nano_params, paged=True, page_size=8,
+    eng = _make(nano, nano_params, page_size=8,
                 prefix_cache=False, kv_dtype="fp")
     try:
         return list(eng.stream(prompt, 10, seed=3))
@@ -410,9 +408,6 @@ def test_knob_validation_and_plumbing(nano, nano_params):
         _make(nano, nano_params, attn_kernel="fused")
     with pytest.raises(ValueError, match="kv_dtype"):
         _make(nano, nano_params, kv_dtype="int4")
-    with pytest.raises(ValueError, match="paged"):
-        _make(nano, nano_params, paged=False, page_size=None,
-              kv_dtype="int8")
     with pytest.raises(ValueError, match="continuous"):
         batching.batch(kv_dtype="int8")(lambda xs: xs)
     with pytest.raises(ValueError, match="continuous"):
@@ -425,13 +420,16 @@ def test_knob_validation_and_plumbing(nano, nano_params):
         DeploymentSchema.from_dict({"name": "d",
                                     "engine": {"kv_dtyp": "int8"}})
     # Live reconfigure through the same applier the deployment path
-    # uses: flat engine + knobs repages; knob change rebuilds the pool.
-    eng = _make(nano, nano_params, paged=False, page_size=None)
+    # uses: a fresh engine + knobs repages; knob change rebuilds the
+    # pool.
+    eng = _make(nano, nano_params, page_size=16)
     try:
+        fp_pages = eng.n_pages
         eng.apply_config(page_size=8, kv_dtype="int8",
                          attn_kernel="pallas")
-        assert eng.paged and eng.kv_dtype == "int8"
+        assert eng.page_size == 8 and eng.kv_dtype == "int8"
         assert eng.attn_kernel == "pallas"
+        assert eng.n_pages > 2 * fp_pages       # half the page, ~half the bytes
         st = eng.stats()
         assert st["kv_dtype"] == "int8"
         assert st["kv_bytes_per_token"] < 2 * nano.n_layer * \

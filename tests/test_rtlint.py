@@ -585,14 +585,14 @@ def test_engine_declared_budget_matches_actual_nano():
         assert parse_budget(
             decls["DecodeEngine._bind_verify"][1]).evaluate(env) == 1
         # And the factory-level declarations in gpt_decode parse and
-        # cover the flat factories' per-site bounds.
+        # cover the factories' per-site bounds.
         gsrc = open(os.path.join(REPO, "ray_tpu", "models",
                                  "gpt_decode.py")).read()
         gdecls = declared_budgets(
             Module("gpt_decode.py", "models/gpt_decode.py", gsrc))
-        assert parse_budget(gdecls["jit_prefill_into_slot"][1]
+        assert parse_budget(gdecls["jit_prefill_into_slot_paged"][1]
                             ).evaluate(env) == len(buckets)
-        assert parse_budget(gdecls["jit_decode_chunk_slots"][1]
+        assert parse_budget(gdecls["jit_decode_chunk_slots_paged"][1]
                             ).evaluate(env) == 1
     finally:
         eng.shutdown()

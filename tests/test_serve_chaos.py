@@ -4,7 +4,7 @@ replica failure, driver failure, and planned restarts.
 - A mid-stream engine-driver death re-routes the stream through the
   retry path with a replay token (``resume_from``); the resumed stream
   is TOKEN-IDENTICAL to an uninterrupted run (temp 0 and seeded
-  temp > 0, flat and paged engines).
+  temp > 0, fp and int8 pages).
 - Resume respects the ORIGINAL deadline and withdraws from the retry
   budget; a second crash during replay fails cleanly with a typed
   error after the budget runs dry.
@@ -49,7 +49,7 @@ def _mk_prompt(rid: int, vocab: int, n: int = 8):
         0, vocab, (n,)).astype(np.int32)
 
 
-def _chaos_deployment(serve, *, paged=False, temperature=0.0,
+def _chaos_deployment(serve, *, kv_dtype="fp", temperature=0.0,
                       deployment="chaos", num_replicas=2):
     """Continuous-engine deployment; every stream is a deterministic
     function of (rid, max_new) — identical weights and per-request
@@ -59,7 +59,7 @@ def _chaos_deployment(serve, *, paged=False, temperature=0.0,
                       health_check_period_s=0.3,
                       graceful_shutdown_timeout_s=10.0)
     class ChaosGPT:
-        def __init__(self, paged: bool, temperature: float,
+        def __init__(self, kv_dtype: str, temperature: float,
                      deployment: str):
             import jax
 
@@ -71,7 +71,12 @@ def _chaos_deployment(serve, *, paged=False, temperature=0.0,
             self.engine = DecodeEngine(
                 params, self.cfg, slots=2, chunk=4, max_len=64,
                 prompt_buckets=(8,), deployment=deployment,
-                temperature=temperature, paged=paged, page_size=8,
+                temperature=temperature, page_size=8,
+                kv_dtype=kv_dtype,
+                # int8 pages: a prefix hit reads the prompt's keys
+                # dequantized where a whole prefill has them exact, so
+                # a replay is the same stream only from the same path
+                prefix_cache=kv_dtype == "fp",
                 wedge_timeout_s=2.0)
             # Compile every program NOW, before the replica registers:
             # health probes start at registration, and a first-dispatch
@@ -97,7 +102,7 @@ def _chaos_deployment(serve, *, paged=False, temperature=0.0,
 
     # One name end to end: app, deployment, and engine metric label.
     return ChaosGPT.options(name=deployment).bind(
-        paged, temperature, deployment)
+        kv_dtype, temperature, deployment)
 
 
 def _req(rid: int, max_new: int, vocab: int) -> dict:
@@ -129,35 +134,44 @@ def _warm(handle, req, ref):
         assert (base == ref).all(), (base, ref)
 
 
-@pytest.mark.parametrize("paged,temperature",
-                         [(False, 0.0), (False, 1.0), (True, 0.0),
-                          (True, 1.0)])
+@pytest.mark.parametrize("kv_dtype,temperature",
+                         [("int8", 0.0), ("int8", 1.0), ("fp", 0.0),
+                          ("fp", 1.0)])
 def test_resume_after_driver_death_token_identical(
-        rt_cluster, nano, nano_params, paged, temperature):
+        rt_cluster, nano, nano_params, kv_dtype, temperature):
     """Kill the serving engine's driver mid-stream: the client stream
     stalls, resumes on the other replica, and the concatenation is
-    token-identical to an uninterrupted run — flat AND paged engines,
-    greedy AND seeded sampling."""
+    token-identical to an uninterrupted run — fp AND int8 pages,
+    greedy AND seeded sampling. fp streams are ``generate_chunked``'s;
+    int8 pages round, so their reference is the deployment's own
+    uninterrupted stream."""
     import jax
 
     import ray_tpu as rt
     from ray_tpu import serve
     from ray_tpu.testing import _serve_replica_handles, inject_engine_fault
 
-    name = f"chaos_{int(paged)}_{int(temperature)}"
+    name = f"chaos_{kv_dtype}_{int(temperature)}"
     serve.start(proxy=False)
     try:
         handle = serve.run(
-            _chaos_deployment(serve, paged=paged, temperature=temperature,
-                              deployment=name),
+            _chaos_deployment(serve, kv_dtype=kv_dtype,
+                              temperature=temperature, deployment=name),
             name=name, route_prefix=None)
         rid, max_new = 3, 40
         kw = {"chunk": 4, "max_len": 64}
         if temperature:
             kw.update(temperature=1.0, rng=jax.random.PRNGKey(rid))
         req = _req(rid, max_new, nano.vocab_size)
-        ref = _ref_chunked(nano_params, _mk_prompt(rid, nano.vocab_size),
-                           nano, max_new, **kw)
+        if kv_dtype == "fp":
+            ref = _ref_chunked(nano_params,
+                               _mk_prompt(rid, nano.vocab_size), nano,
+                               max_new, **kw)
+        else:
+            ref = np.concatenate([
+                np.asarray(x).ravel()
+                for x in handle.options(stream=True).remote(req)])
+            assert ref.shape == (max_new,)
         _warm(handle, req, ref)
         handles = _serve_replica_handles(name, name)
         assert len(handles) == 2
@@ -228,7 +242,8 @@ def test_resume_respects_deadline_and_budget():
             pass
 
         def _submit_stream_raw(self, method, args, kwargs, deadline_s,
-                               model_id, flatten_chunks, resume_from=0):
+                               model_id, flatten_chunks, resume_from=0,
+                               request_id=None):
             self.submissions.append(
                 {"resume_from": resume_from, "deadline_s": deadline_s})
             return "rid2", iter(())

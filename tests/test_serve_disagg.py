@@ -4,7 +4,7 @@
 - A prefill-role engine exports a prefilled slot (K/V + pos + first
   token + PRNG lane) under an epoch-stamped lease; a decode-role engine
   byte-verifies and imports it — the continued stream is
-  TOKEN-IDENTICAL to a colocated run for every flat/paged pairing, at
+  TOKEN-IDENTICAL to a colocated run for every pairing of page sizes, at
   temperature 0 AND seeded temperature > 0.
 - The compiled-program set stays bounded: the whole handoff plane adds
   exactly one export + one import program per engine.
@@ -71,23 +71,25 @@ def _drain(lane):
 
 
 # ------------------------------------------------------------ engine level
-@pytest.mark.parametrize("src_paged,dst_paged,temperature",
-                         [(False, False, 0.0), (False, True, 0.0),
-                          (True, False, 0.0), (True, True, 0.0),
-                          (False, False, 1.0), (True, True, 1.0)])
-def test_handoff_identity(nano, nano_params, src_paged, dst_paged,
-                          temperature):
+@pytest.mark.parametrize("src_ps,dst_ps,prefix_cache,temperature",
+                         [(8, 16, True, 0.0), (16, 8, True, 0.0),
+                          (8, 8, False, 0.0), (8, 8, True, 0.0),
+                          (8, 8, False, 1.0), (8, 8, True, 1.0)])
+def test_handoff_identity(nano, nano_params, src_ps, dst_ps,
+                          prefix_cache, temperature):
     """Export on one engine, import on another: the decode-side stream
     (first token included) is token-identical to an uninterrupted
-    colocated run — every flat/paged pairing, greedy AND seeded
-    sampling — and the handoff counters balance."""
+    colocated run — equal and unequal page sizes (fp K/V ships in
+    position order, whatever pages held it), the prefix cache on and
+    off, greedy AND seeded sampling — and the handoff counters
+    balance."""
     import jax
 
     pre = _make_engine(nano, nano_params, role="prefill",
-                       paged=src_paged, page_size=8,
+                       page_size=src_ps, prefix_cache=prefix_cache,
                        temperature=temperature)
     dec = _make_engine(nano, nano_params, role="decode",
-                       paged=dst_paged, page_size=8,
+                       page_size=dst_ps, prefix_cache=prefix_cache,
                        temperature=temperature)
     try:
         prompt = _mk_prompt(1, nano.vocab_size)
@@ -167,7 +169,7 @@ def test_lease_expiry_sweeps_orphans(nano, nano_params):
     claim) is reclaimed on the prefill driver's lease clock: leases
     drop to zero, the reclaim is counted, and the prefill engine's
     pages are all free — a crash can never pin the pool."""
-    pre = _make_engine(nano, nano_params, role="prefill", paged=True,
+    pre = _make_engine(nano, nano_params, role="prefill",
                        page_size=8, prefix_cache=False,
                        handoff_ttl_s=0.3)
     try:
@@ -392,8 +394,8 @@ def test_router_role_filtering_and_locality():
 
 
 # ------------------------------------------------------------- serve level
-def _disagg_deployment(serve, *, deployment, roles, paged=False,
-                       ttl_s=30.0, num_replicas=None):
+def _disagg_deployment(serve, *, deployment, roles, ttl_s=30.0,
+                       num_replicas=None):
     @serve.deployment(num_replicas=num_replicas or
                       sum(roles.values()),
                       max_ongoing_requests=16,
@@ -402,7 +404,7 @@ def _disagg_deployment(serve, *, deployment, roles, paged=False,
                       engine_config={"roles": dict(roles),
                                      "handoff_ttl_s": ttl_s})
     class DisaggGPT:
-        def __init__(self, paged: bool, deployment: str):
+        def __init__(self, deployment: str):
             import jax
 
             from ray_tpu.models import gpt
@@ -413,7 +415,7 @@ def _disagg_deployment(serve, *, deployment, roles, paged=False,
             self.engine = DecodeEngine(
                 params, self.cfg, slots=2, chunk=4, max_len=64,
                 prompt_buckets=(8,), deployment=deployment,
-                paged=paged, page_size=8)
+                page_size=8)
 
         @serve.batch(continuous=True)
         def decode(self, request):
@@ -427,7 +429,7 @@ def _disagg_deployment(serve, *, deployment, roles, paged=False,
         def __call__(self, request):
             return self.decode(request)
 
-    return DisaggGPT.options(name=deployment).bind(paged, deployment)
+    return DisaggGPT.options(name=deployment).bind(deployment)
 
 
 def _req(rid: int, max_new: int, vocab: int) -> dict:
@@ -557,7 +559,7 @@ def test_role_transition_reaps_stray_replicas(rt_cluster, nano,
         # _reap_stray_roles can retire the role-less replicas.
         plain = app_roles.deployment.options(num_replicas=2,
                                              engine_config={})
-        handle = serve.run(plain.bind(False, name), name=name,
+        handle = serve.run(plain.bind(name), name=name,
                            route_prefix=None)
         ctrl = rt.get_actor(SERVE_CONTROLLER_NAME, timeout=10)
         info = rt.get(ctrl.get_replicas.remote(name, name), timeout=10)

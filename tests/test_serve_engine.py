@@ -249,8 +249,8 @@ def test_engine_recompile_guard(nano, nano_params):
     admission pattern: after one warm pass over the buckets, a storm of
     varied prompts/output lengths/arrival orders adds ZERO XLA programs
     — no retrace per admitted request."""
-    from ray_tpu.models.gpt_decode import (jit_decode_chunk_slots,
-                                           jit_prefill_into_slot)
+    from ray_tpu.models.gpt_decode import (jit_decode_chunk_slots_paged,
+                                           jit_prefill_into_slot_paged)
 
     eng = _make_engine(nano, nano_params, slots=3, max_len=48,
                        prompt_buckets=(8, 16))
@@ -282,9 +282,9 @@ def test_engine_recompile_guard(nano, nano_params):
         assert eng._step._cache_size() == pre_step
         # the lru wrappers are shared per static-knob tuple, so repeated
         # engine construction reuses (not duplicates) the programs
-        assert jit_prefill_into_slot.cache_info().currsize <= 64
-        assert jit_decode_chunk_slots.cache_info().currsize <= 64
-        assert jit_prefill_into_slot(nano, 0.0) is eng._prefill
+        assert jit_prefill_into_slot_paged.cache_info().currsize <= 64
+        assert jit_decode_chunk_slots_paged.cache_info().currsize <= 64
+        assert jit_prefill_into_slot_paged(nano, 16, 0.0) is eng._prefill
     finally:
         eng.shutdown()
 

@@ -3,8 +3,7 @@ once with per-slot variable advance.
 
 - At temperature 0 spec-decoded streams are token-identical to
   ``generate_chunked`` for ANY drafter — n-gram, model, and an
-  adversarial always-wrong drafter (acceptance 0, output still exact)
-  — flat AND paged.
+  adversarial always-wrong drafter (acceptance 0, output still exact).
 - Seeded temperature>0 streams are reproducible and ``resume_from``
   replay through a mid-stream driver kill (chaos harness) delivers the
   exact uninterrupted stream.
@@ -57,20 +56,37 @@ def _make_engine(nano, nano_params, **kw):
     return DecodeEngine(nano_params, nano, **kw)
 
 
-def _always_wrong_drafter():
-    """Adversarial drafter: proposes tokens shifted off the committed
-    stream, so essentially nothing is ever accepted — the committed
-    stream must STILL be exact (the correction token is the target's
-    own sample)."""
+def _always_wrong_drafter(prompts, refs, vocab):
+    """Adversarial drafter, wrong by construction: it holds the
+    reference streams and proposes, at every position, a token that
+    DIFFERS from the one the target will commit there, so nothing is
+    ever accepted — the committed stream must STILL be exact (the
+    correction token is the target's own sample)."""
     from ray_tpu.serve.draft import Drafter
 
     class AlwaysWrongDrafter(Drafter):
         name = "always_wrong"
 
+        def configure(self, **kw):
+            super().configure(**kw)
+            self._ref, self._n = {}, {}
+
+        def admit(self, slot, prompt, first_token):
+            self._ref[slot] = next(
+                r for p, r in zip(prompts, refs)
+                if p.shape == prompt.shape and (p == prompt).all())
+            self._n[slot] = 1            # tokens committed so far
+
+        def observe(self, slot, tokens, accepted):
+            self._n[slot] += len(tokens)
+
         def propose(self, active, last):
             out = np.zeros((self.slots, self.draft_k), np.int32)
-            for j in range(self.draft_k):
-                out[:, j] = (np.asarray(last) + 1 + j) % 512
+            for i in np.flatnonzero(active):
+                ref, n = self._ref[i], self._n[i]
+                for j in range(self.draft_k):
+                    nxt = ref[n + j] if n + j < len(ref) else 0
+                    out[i, j] = (int(nxt) + 1) % vocab
             return out
 
     return AlwaysWrongDrafter()
@@ -97,17 +113,16 @@ def test_spec_greedy_identity_any_drafter(nano, nano_params, drafter):
     """Temp-0 token identity holds for ANY drafter — acceptance only
     changes how many verify forwards the stream takes, never its
     tokens. The adversarial drafter pins the acceptance-0 edge."""
-    spec = _always_wrong_drafter() if drafter == "adversarial" \
-        else drafter
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, nano.vocab_size, (n,)).astype(np.int32)
+               for n in (5, 8, 16)]
+    max_news = [10, 14, 7]
+    refs = [_ref_chunked(nano_params, p, nano, mn, chunk=4, max_len=64)
+            for p, mn in zip(prompts, max_news)]
+    spec = _always_wrong_drafter(prompts, refs, nano.vocab_size) \
+        if drafter == "adversarial" else drafter
     eng = _make_engine(nano, nano_params, spec_decode=spec)
     try:
-        rng = np.random.default_rng(0)
-        prompts = [rng.integers(0, nano.vocab_size, (n,)).astype(np.int32)
-                   for n in (5, 8, 16)]
-        max_news = [10, 14, 7]
-        refs = [_ref_chunked(nano_params, p, nano, mn, chunk=4,
-                             max_len=64)
-                for p, mn in zip(prompts, max_news)]
         outs = _drive_concurrent(eng, prompts, max_news)
         for i, r in enumerate(refs):
             assert (outs[i] == r).all(), (drafter, i, outs[i], r)
@@ -124,32 +139,6 @@ def test_spec_greedy_identity_any_drafter(nano, nano_params, drafter):
         assert sp["accepted_per_forward"] >= 1.0
     finally:
         eng.shutdown()
-
-
-def test_spec_paged_identity_matches_flat_accounting(nano, nano_params):
-    """Paged spec decoding is token-identical to generate_chunked AND
-    byte-for-byte the same acceptance accounting as the flat engine on
-    the same workload — the page table changes layout, not math."""
-    rng = np.random.default_rng(3)
-    prompts = [rng.integers(0, nano.vocab_size, (n,)).astype(np.int32)
-               for n in (5, 8, 16)]
-    max_news = [10, 14, 7]
-    refs = [_ref_chunked(nano_params, p, nano, mn, chunk=4, max_len=64)
-            for p, mn in zip(prompts, max_news)]
-    accounting = {}
-    for mode in ("flat", "paged"):
-        kw = dict(paged=True, page_size=8) if mode == "paged" else {}
-        eng = _make_engine(nano, nano_params, **kw)
-        try:
-            outs = _drive_concurrent(eng, prompts, max_news)
-            for i, r in enumerate(refs):
-                assert (outs[i] == r).all(), (mode, i, outs[i], r)
-            sp = eng.stats()["spec"]
-            accounting[mode] = (sp["rounds"], sp["proposed"],
-                                sp["accepted"])
-        finally:
-            eng.shutdown()
-    assert accounting["flat"] == accounting["paged"], accounting
 
 
 def test_spec_temperature_determinism_and_resume(nano, nano_params):
@@ -341,19 +330,21 @@ def test_spec_recompile_guard(nano, nano_params):
     varied prompts/lengths adds ZERO retraces. Unique static knobs
     (max_len=56, draft_k=5) isolate this engine's programs from the
     shared lru wrappers' other users."""
-    from ray_tpu.models.gpt_decode import (jit_decode_chunk_slots,
-                                           jit_prefill_into_slot,
-                                           jit_verify_chunk_slots)
+    from ray_tpu.models.gpt_decode import (jit_decode_chunk_slots_paged,
+                                           jit_prefill_into_slot_paged,
+                                           jit_verify_chunk_slots_paged)
 
     buckets = (8, 24)
-    pf = jit_prefill_into_slot(nano, 0.0)
+    pf = jit_prefill_into_slot_paged(nano, 16, 0.0)
     n_pf0 = pf._cache_size()
     eng = _make_engine(nano, nano_params, slots=3, max_len=56,
                        prompt_buckets=buckets, draft_k=5)
     try:
         assert eng._prefill is pf
-        assert eng._step is jit_decode_chunk_slots(nano, 4, 0.0, -1)
-        assert eng._verify is jit_verify_chunk_slots(nano, 5, 0.0)
+        assert eng._step is jit_decode_chunk_slots_paged(
+            nano, 4, 16, 0.0, -1)
+        assert eng._verify is jit_verify_chunk_slots_paged(
+            nano, 5, 16, 0.0)
         rng = np.random.default_rng(6)
 
         def storm(n, lens):
@@ -406,6 +397,62 @@ def test_spec_model_drafter_program_set_bounded(nano, nano_params):
         # Tied embedding: the drafter SHARES the target's arrays.
         assert d.params["embed"] is nano_params["embed"]
         assert d.params["pos_embed"] is nano_params["pos_embed"]
+    finally:
+        eng.shutdown()
+
+
+# What the parent's drafter (a dense [slots, max_len] cache of its own,
+# PR 33's tree) proposed for this prompt and seed when its cursor upload
+# did not race its prefill; the greedy rows are also the reference
+# model's own continuation, since this drafter IS the target.
+_DRAFTED = {
+    0.0: ([2, 91, 2, 330, 2, 100, 335, 218, 330, 330, 330, 330, 330, 330,
+           335, 260],
+          [[91, 2, 330, 2], [335, 218, 330, 330], [330, 330, 330, 335]]),
+    1.0: ([254, 59, 101, 433, 154, 14, 221, 494, 453, 80, 360, 48, 39,
+           421, 191, 331],
+          [[2, 22, 132, 2], [95, 22, 132, 16], [218, 132, 132, 132],
+           [22, 132, 330, 325], [496, 22, 132, 220], [22, 132, 22, 132],
+           [445, 324, 445, 461], [95, 22, 218, 132], [2, 22, 218, 171],
+           [22, 218, 155, 22], [132, 16, 58, 458], [155, 22, 218, 22],
+           [240, 446, 22, 132], [22, 446, 22, 446], [22, 218, 132, 330]]),
+}
+
+
+@pytest.mark.parametrize("temperature", [0.0, 1.0])
+def test_model_drafter_proposals_on_identity_table(nano, nano_params,
+                                                   temperature):
+    """The model drafter's cache is the page pool under the fixed table
+    ``pt[s] = [s]`` (one ``max_len`` page a slot). Drafting with the
+    target's own weights, it proposes token for token what the dense
+    cache it replaced proposed: every round accepted at temperature 0
+    (the lazy ingest after a full accept), every round rolled back at
+    seeded temperature 1 (the cursor rollback)."""
+    from ray_tpu.serve.draft import ModelDrafter
+
+    stream, proposals = _DRAFTED[temperature]
+    prompt = np.random.default_rng(11).integers(
+        0, nano.vocab_size, (8,)).astype(np.int32)
+    drafter = ModelDrafter(nano_params, nano)
+    eng = _make_engine(nano, nano_params, spec_decode=drafter,
+                       temperature=temperature)
+    try:
+        assert drafter._pt.tolist() == [[0], [1]]
+        assert drafter._cache["k"].shape[1:3] == (2, 64)
+        seen = []
+        propose = drafter.propose
+
+        def recording(active, last):
+            out = propose(active, last)
+            seen.append(out[np.flatnonzero(active)[0]].tolist())
+            return out
+
+        drafter.propose = recording
+        got = np.concatenate(list(eng.stream(prompt, 16, seed=7)))
+        assert got.tolist() == stream
+        assert seen == proposals
+        sp = eng.stats()["spec"]
+        assert sp["accepted"] == (12 if temperature == 0.0 else 0)
     finally:
         eng.shutdown()
 

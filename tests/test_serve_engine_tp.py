@@ -4,7 +4,7 @@ multi-chip mesh.
 - ``DecodeEngine(tp=2)`` on a REAL 2-device host-platform mesh
   (conftest forces 8 virtual CPU devices) is TOKEN-IDENTICAL to the
   single-chip engine at temperature 0 AND seeded temperature > 0,
-  flat and paged, with speculative decoding on — the sharded compute
+  fp and int8 pages, with speculative decoding on — the sharded compute
   graph (column/row-parallel weights, head-sharded KV, psum'd
   partials) commits the same tokens the canonical graph does.
 - The compiled-program set stays ``len(prompt_buckets) + 3`` PER MESH
@@ -64,15 +64,16 @@ def _mk_prompt(rid: int, vocab: int, n: int = 7):
 
 
 # ------------------------------------------------------- token identity
-@pytest.mark.parametrize("paged,temperature,attn_kernel",
-                         [(False, 0.0, "gather"), (True, 0.0, "gather"),
-                          (False, 1.0, "gather"), (True, 1.0, "gather"),
-                          (True, 0.0, "pallas")])
-def test_tp2_token_identity(nano, nano_params, paged, temperature,
+@pytest.mark.parametrize("kv_dtype,temperature,attn_kernel",
+                         [("int8", 0.0, "gather"), ("fp", 0.0, "gather"),
+                          ("int8", 1.0, "gather"), ("fp", 1.0, "gather"),
+                          ("fp", 0.0, "pallas")])
+def test_tp2_token_identity(nano, nano_params, kv_dtype, temperature,
                             attn_kernel):
     """tp=2 output == tp=1 output, stream for stream, at temp 0 and
-    seeded temp>0, flat and paged — concurrent mixed-length requests
-    through both pools. The pallas row pins the kernel under the tp
+    seeded temp>0, fp and int8 pages (a page's scale is per head, and
+    the mesh cuts between heads) — concurrent mixed-length requests
+    through both meshes. The pallas row pins the kernel under the tp
     shard_map (ISSUE 21: it did not trace there — pallas_call's output
     has no vma annotation)."""
     prompts = [_mk_prompt(i, nano.vocab_size, n)
@@ -80,9 +81,9 @@ def test_tp2_token_identity(nano, nano_params, paged, temperature,
     max_news = [10, 7, 12, 3]
 
     def run(tp):
-        kw = {"attn_kernel": attn_kernel} if paged else {}
-        eng = _make_engine(nano, nano_params, paged=paged, page_size=8,
-                           temperature=temperature, tp=tp, **kw)
+        eng = _make_engine(nano, nano_params, page_size=8,
+                           kv_dtype=kv_dtype, attn_kernel=attn_kernel,
+                           temperature=temperature, tp=tp)
         try:
             outs = {}
 
@@ -113,7 +114,7 @@ def test_tp2_spec_decode_identity(nano, nano_params):
     prompt = np.tile(np.arange(4, dtype=np.int32) % nano.vocab_size, 2)
 
     def run(tp):
-        eng = _make_engine(nano, nano_params, paged=True, page_size=8,
+        eng = _make_engine(nano, nano_params, page_size=8,
                            spec_decode="ngram", draft_k=4, tp=tp)
         try:
             out = np.concatenate(list(eng.stream(prompt, 16, seed=1)))
@@ -133,8 +134,8 @@ def test_tp_recompile_guard(nano, nano_params):
     prefill per prompt bucket + 1 chunk + 2 handoff programs on ITS OWN
     wrappers (distinct lru keys from tp=1), and an admission storm adds
     zero programs."""
-    from ray_tpu.models.gpt_decode import (jit_decode_chunk_slots,
-                                           jit_prefill_into_slot)
+    from ray_tpu.models.gpt_decode import (jit_decode_chunk_slots_paged,
+                                           jit_prefill_into_slot_paged)
 
     eng = _make_engine(nano, nano_params, slots=3, max_len=48,
                        prompt_buckets=(8, 16), tp=2)
@@ -164,9 +165,12 @@ def test_tp_recompile_guard(nano, nano_params):
         assert eng._step._cache_size() == pre_step
         # Mesh shape is part of the wrapper key: the tp=2 engine shares
         # the tp=2 wrapper, never the tp=1 one.
-        assert jit_prefill_into_slot(nano, 0.0, 2) is eng._prefill
-        assert jit_prefill_into_slot(nano, 0.0) is not eng._prefill
-        assert jit_decode_chunk_slots(nano, 4, 0.0, -1, 2) is eng._step
+        assert jit_prefill_into_slot_paged(
+            nano, 16, 0.0, "fp", 2) is eng._prefill
+        assert jit_prefill_into_slot_paged(nano, 16, 0.0) \
+            is not eng._prefill
+        assert jit_decode_chunk_slots_paged(
+            nano, 4, 16, 0.0, -1, "fp", "gather", 2) is eng._step
     finally:
         eng.shutdown()
 
@@ -193,11 +197,10 @@ def test_tp_validation_and_config_plane(nano, nano_params):
 
 
 # ------------------------------------------------ resharding handoff
-@pytest.mark.parametrize("src_tp,dst_tp,src_paged,dst_paged",
-                         [(2, 1, False, False), (1, 2, True, True),
-                          (2, 4, True, False)])
+@pytest.mark.parametrize("src_tp,dst_tp,src_ps,dst_ps",
+                         [(2, 1, 16, 8), (1, 2, 8, 8), (2, 4, 8, 16)])
 def test_handoff_resharding_roundtrip(nano, nano_params, src_tp, dst_tp,
-                                      src_paged, dst_paged):
+                                      src_ps, dst_ps):
     """N-way prefill -> M-way decode: the exporter gathers to the
     canonical host layout, the importer scatters into its own mesh, the
     digest verifies the layout-independent bytes, and the continued
@@ -215,9 +218,9 @@ def test_handoff_resharding_roundtrip(nano, nano_params, src_tp, dst_tp,
         nano = dataclasses.replace(nano, n_head=4)
         params = gpt.init_params(jax.random.PRNGKey(0), nano)
     pre = _make_engine(nano, params, role="prefill", tp=src_tp,
-                       paged=src_paged, page_size=8)
+                       page_size=src_ps)
     dec = _make_engine(nano, params, role="decode", tp=dst_tp,
-                       paged=dst_paged, page_size=8)
+                       page_size=dst_ps)
     ref_eng = _make_engine(nano, params)
     try:
         prompt = _mk_prompt(3, nano.vocab_size)
@@ -368,8 +371,7 @@ def test_shard_dispatch_event_and_stats(nano, nano_params, tmp_path):
     ev._reset_for_tests()
     try:
         ev.init(str(tmp_path), proc="tp-test")
-        eng = _make_engine(nano, nano_params, tp=2, paged=True,
-                           page_size=8)
+        eng = _make_engine(nano, nano_params, tp=2, page_size=8)
         try:
             list(eng.stream(_mk_prompt(10, nano.vocab_size), 8))
         finally:
