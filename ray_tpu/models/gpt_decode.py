@@ -173,15 +173,19 @@ tp>1: :func:`ray_tpu.models.moe.moe_ffn` is not tp-aware.
 Paged-attention kernel + int8 KV (ISSUE 16): two orthogonal,
 engine-static knobs on the paged hot path. ``attn_kernel="pallas"``
 swaps the decode step's gather-then-mask attention for
-:func:`paged_attention`'s fused Pallas kernel — block-parallel over
-``(slot, pass, page)`` with the page table scalar-prefetched into the
-BlockSpec index maps, so each block streams ONE physical page from HBM
-and :data:`PT_SENTINEL`/past-``pos`` blocks are skipped outright;
-off-TPU the same kernel runs in interpret mode, so CPU tier-1
-exercises the shipping block program. The kernel is two-pass so its
-probabilities quantize to the compute dtype AFTER normalization —
-exactly where the gather path casts — which keeps kernel-on vs
-kernel-off token-identical at temp 0 and under seeded sampling.
+:func:`paged_attention`'s fused Pallas kernel: one grid step a slot,
+whose work is that slot's OWN live pages — the page table and the live
+lengths ride as scalar-prefetch operands, the pool stays in HBM, and
+the kernel copies each live page ONCE into a ring of VMEM pages, ahead
+of the arithmetic, and folds it into one running-max softmax pass.
+Pages that are :data:`PT_SENTINEL`-unmapped or past ``pos`` cost
+nothing: no grid step, no fetch. Off-TPU the same kernel runs in
+interpret mode, so CPU tier-1 exercises the shipping kernel body. The
+kernel rounds its probabilities to the compute dtype before they meet
+V, as the gather path does, but before the division by the sum instead
+of after it: the two paths agree to :data:`ATTN_KERNEL_ULPS` bf16 ulp
+of the largest output, not to the bit, and streams are held to the
+reference by margin, not by token identity (ROADMAP D10).
 ``kv_dtype="int8"`` stores pages as symmetric int8 codes with one f32
 scale per (layer, page, head) per side (~2x the pages in the same
 HBM at bf16): scatters become page-granular requantize-and-merge
@@ -677,9 +681,20 @@ KV_DTYPES = ("fp", "int8")
 
 #: Decode attention implementations for the paged pool. ``"gather"`` is
 #: the stock-XLA page-table gather + masked full-length attention;
-#: ``"pallas"`` is the fused block-parallel kernel (interpret mode off
-#: TPU). Both are token-identical at any temperature.
+#: ``"pallas"`` is the fused kernel over each slot's live pages
+#: (interpret mode off TPU). They agree to :data:`ATTN_KERNEL_ULPS`.
 ATTN_KERNELS = ("gather", "pallas")
+#: The written bound on |kernel - gather| of :func:`paged_attention`, in
+#: ulps (2**-8, relative) of the LARGEST output of the call. Both paths
+#: round every probability once to the compute dtype (half an ulp of
+#: each term of a sum of like-weighted terms) — the gather path after
+#: dividing by the sum, the kernel before — and round the float32
+#: result once: read 0.5-1.6 over the shapes of
+#: ``tests/test_gpt_decode_kernel.py`` on the CPU and 0.5-1.1 (fp) /
+#: 1.1-1.5 (int8) at the serving shape on a TPU v5e (PR 35). 4 leaves
+#: room for a call whose largest output is an average far below its
+#: largest value.
+ATTN_KERNEL_ULPS = 4
 
 #: Quantization scale floor: an all-zero page quantizes (and
 #: dequantizes) to exact zeros instead of dividing by zero.
@@ -872,24 +887,30 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     into virtual order and run masked full-length attention (sentinel
     entries clip to an arbitrary real page whose garbage the mask
     hides). ``kernel="pallas"`` fuses the gather, the length masking,
-    and the softmax into one block-parallel kernel over the grid
-    ``(B, 2, max_pages)`` with the page table scalar-prefetched: each
-    block reads ONE physical page straight from the pool (no gathered
-    copy), and blocks whose page is :data:`PT_SENTINEL`-unmapped or
-    wholly past ``pos[b]`` are skipped entirely, so the kernel does
-    O(pages actually held) work instead of O(max_pages).
+    and the softmax into one kernel whose grid is ``(B,)``: a slot's
+    step loops over THAT slot's live pages (``pos[b] // page_size + 1``
+    inside the mapped prefix of its row, none for a row of
+    :data:`PT_SENTINEL`), copies each from the pool in HBM into a ring
+    of VMEM pages ahead of the arithmetic, steered by the
+    scalar-prefetched page table, and reads it once. ``max_pages``
+    multiplies nothing: the kernel does O(pages actually held) work.
 
-    The kernel is two-pass (pass 0: running max + rescaled exp-sum;
-    pass 1: normalize, cast the probabilities to the compute dtype,
-    accumulate p·v in f32) — the SAME quantize-after-normalize order as
-    the gather path's ``softmax(...).astype(dtype)``, so the two paths
-    differ only by f32 summation order, far below the compute dtype's
-    resolution. That is what makes kernel-on vs kernel-off
-    token-identical in practice at temp 0 AND under seeded sampling.
+    One softmax pass (flash decoding): a running max, a running sum
+    and a float32 accumulator, rescaled as the max moves, divided once
+    at the slot's end. q·k is accumulated in float32, max / exp / sum
+    are float32, the probabilities are rounded to the compute dtype
+    before they meet V — as the gather path's
+    ``softmax(...).astype(dtype)`` rounds them, but before the division
+    by the sum, not after — and p·v is summed in float32 and rounded
+    once. So the two paths are NOT bit-identical: they agree to
+    :data:`ATTN_KERNEL_ULPS` ulps of the largest output. Every live
+    position enters the softmax; a position past ``pos[b]`` or in an
+    unmapped page contributes exactly 0 whatever bytes lie there (inf
+    and NaN included); a slot with no live page returns zeros.
 
     With int8 pools pass ``ks``/``vs`` (per-(page, head) scales); both
     paths dequantize through :func:`_deq_page` semantics at the point
-    of use, so the kernel/gather identity holds quantized too."""
+    of use, so the same bound holds quantized."""
     if kernel == "pallas":
         return _paged_attention_pallas(q, kc, vc, pt, pos, page_size,
                                        ks, vs)
@@ -924,34 +945,73 @@ def _paged_attention_gather(q, kc, vc, pt, pos, page_size, ks, vs):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
+#: VMEM the kernel's page ring may take for K (and again for V): with
+#: 64 KB pages (the serving cells') that is 32 pages = 512 tokens.
+_ATTN_RING_BYTES = 2 << 20
+#: Most tokens the kernel multiplies at once: a ``[tokens, H, hd]``
+#: float32 value of this many tokens is 64 vregs at the cells' ``H``,
+#: ``hd``, the whole register file.
+_ATTN_CHUNK_TOKENS = 32
+
+
+def _attn_schedule(page_size: int, max_pages: int, H: int, hd: int,
+                   itemsize: int) -> Tuple[int, int, int]:
+    """How :func:`_paged_attention_pallas` cuts its work, from what it
+    can see: ``(ring, chunk, ring_bytes)``. ``ring`` pages of K (and of
+    V) are in flight or in use at once — as many as fit
+    :data:`_ATTN_RING_BYTES`, at least two (the fetch of one hides
+    behind the arithmetic of the other), never more than a lane can
+    hold (``max_pages``; ONE where a page is a lane's whole
+    ``max_len``). ``chunk`` tokens are multiplied at once: a whole page
+    where it is small, else the largest divisor of ``page_size`` up to
+    :data:`_ATTN_CHUNK_TOKENS`. ``ring_bytes`` is what one ring takes
+    of VMEM, whose tiles hold ``32 / itemsize`` rows of 128 lanes."""
+    rows = 32 // itemsize
+    page_bytes = page_size * -(-H // rows) * rows * -(-hd // 128) * 128 \
+        * itemsize
+    ring = min(max(2, _ATTN_RING_BYTES // page_bytes), max_pages)
+    chunk = max(c for c in range(1, min(page_size, _ATTN_CHUNK_TOKENS) + 1)
+                if page_size % c == 0)
+    return ring, chunk, ring * page_bytes
+
+
 def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
     """Fused paged-attention kernel (see :func:`paged_attention`).
 
-    Grid ``(B, 2, max_pages)``: slot-major, two softmax passes, one
-    block per page-table column. ``pt``/``pos`` ride as scalar-prefetch
-    operands so the BlockSpec index maps can steer each block's HBM
-    read to the physical page — an unmapped column still *indexes* page
-    0 (clipped) but its block body is skipped, so only the (cheap,
-    unread) prefetch touches it. VMEM scratch carries the running max
-    ``m [H, 1]``, exp-sum ``l [H, 1]`` and f32 accumulator
-    ``acc [H, hd]`` across the slot's grid steps; the output block is
-    written once, on the slot's last step.
+    Grid ``(B,)``: one step a lane, and inside it a loop over THAT
+    lane's live tokens, ``length[b]`` of them, where ``length[b]`` is
+    ``pos[b] + 1`` cut to the mapped prefix of the lane's table row (0
+    for a row of :data:`PT_SENTINEL`). ``pt`` and ``length`` ride as
+    scalar-prefetch operands; the pool stays in HBM and the kernel
+    copies the pages the table names into a ring of ``ring`` VMEM pages
+    (:func:`_attn_schedule`), one semaphore a page and side: the first
+    ``ring`` at the lane's start, each later one into the slot of the
+    page just read. A page is fetched ONCE, and its fetch hides behind
+    the arithmetic of the ``ring`` pages before it.
 
-    The block body is written for what Mosaic lowers, not for the
-    shortest jnp spelling: a page is walked ROW BY ROW (``k_ref[0, p]``
-    indexes the block's untiled leading axis), so every value in the
-    kernel is a 2-D ``[H, hd]`` or ``[H, 1]`` tile — q·k is a VPU
-    multiply and a lane reduction, p·v a lane broadcast and an
-    accumulate. One query row per head has no use for the MXU, and the
-    obvious ``einsum("hd,phd->hp")`` is a batched contraction whose
-    batch axis sits in the MIDDLE of the page operand, which Mosaic
-    refuses. Products of two compute-dtype values are exact in f32, so
-    the arithmetic differs from the gather path's einsum only in f32
-    summation order, as before. int8 scales arrive gathered per
-    (slot, column) and shaped ``[.., H, 1]``: a ``(1, H)`` block over
-    the pool's ``[n_pages, H]`` scale array has a second-minor block
-    dim that is neither 8-aligned nor the full dim, and the ``[H, 1]``
-    layout is the one the per-row dequant broadcasts from."""
+    One softmax pass, in base 2: the running max ``m [H, 1]``, the
+    running sum ``l [H, 1]`` and the accumulator ``acc [H, hd]``
+    (float32) are carried through the loop and rescaled as the max
+    moves; ``acc / l`` is written once, at the lane's end (zeros where
+    ``l`` is 0). The probabilities are rounded to the compute dtype
+    before they meet V, as the gather path rounds them, but BEFORE the
+    division by ``l``: that is the whole numeric difference
+    (:data:`ATTN_KERNEL_ULPS`).
+
+    The loop's unit is a ``chunk`` of tokens (a page, at the cells'
+    shape): its scores (q·k: a VPU multiply and a lane reduction), then
+    its share of the sums (exp, p·v: a lane broadcast and an accumulate
+    over the leading axis). Whole chunks need no mask; the lane's last,
+    partial chunk masks K's side AND V's (0 * inf is NaN). One query
+    row a head has no use for the MXU, and the contraction
+    ``einsum("hd,thd->ht")`` has its batch axis in the MIDDLE of the
+    page operand, which Mosaic refuses. Products of two compute-dtype
+    values are exact in float32; the query carries ``log2(e) /
+    sqrt(hd)``, one float32 rounding away from the gather path's
+    scaling of the sums. int8 scales arrive gathered per (lane, column)
+    as ``[B, H, max_pages]``: a page's ``[H, 1]`` column is picked by a
+    lane mask and a lane reduction, which needs no dynamic lane
+    slice."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -964,101 +1024,149 @@ def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
     max_pages = pt.shape[1]
     quant = ks is not None
     dtype = q.dtype
-    # Python float (f32-exact) so the kernel closure stays constant-free;
-    # matches the gather path's f32(1/sqrt(hd)) bit-for-bit.
-    scale = float(np.float32(1.0) / np.sqrt(np.float32(hd)))
+    ring, chunk, ring_bytes = _attn_schedule(
+        ps, max_pages, H, hd, jnp.dtype(kc.dtype).itemsize)
+    cpp = ps // chunk                          # chunks a page
+    interpret = pallas_interpret()
+    if not interpret and (hd % 128 or (H % 8 and H != 4)):
+        # Mosaic addresses a page of the pool in whole (8, 128) tiles.
+        raise ValueError(
+            f"attn_kernel='pallas' fetches pages of [page_size, H, hd] "
+            f"by DMA, which on a TPU needs hd a multiple of 128 and H a "
+            f"multiple of 8 (or 4); got H={H}, hd={hd}: use "
+            f"attn_kernel='gather' for this model")
+    # Python float (f32-exact) so the kernel closure stays constant-free.
+    scale = float(np.float32(np.log2(np.e)) / np.sqrt(np.float32(hd)))
+    # The live length of a lane: positions <= pos inside the mapped
+    # prefix of its row. The engine maps a lane's pages from column 0
+    # without holes, so this is pos + 1 for a lane it steps and 0 for a
+    # row of sentinels.
+    mapped = jnp.min(jnp.where(pt == PT_SENTINEL,
+                               jnp.arange(max_pages, dtype=jnp.int32),
+                               jnp.int32(max_pages)), axis=1)
+    length = jnp.minimum(pos.astype(jnp.int32) + 1, mapped * ps)
 
-    def kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, *rest):
+    def kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest):
         if quant:
-            ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+            ks_ref, vs_ref, o_ref, k_buf, v_buf, sems = rest
         else:
-            o_ref, m_ref, l_ref, acc_ref = rest
+            ks_ref = vs_ref = None
+            o_ref, k_buf, v_buf, sems = rest
         b = pl.program_id(0)
-        phase = pl.program_id(1)
-        j = pl.program_id(2)
+        n_live = len_ref[b]
+        n = (n_live + ps - 1) // ps            # this lane's live pages
 
-        @pl.when((phase == 0) & (j == 0))
-        def _init():
-            m_ref[...] = jnp.full_like(m_ref, -1e30)
-            l_ref[...] = jnp.zeros_like(l_ref)
-            acc_ref[...] = jnp.zeros_like(acc_ref)
+        def copy(side, g):
+            hbm, buf = ((k_hbm, k_buf), (v_hbm, v_buf))[side]
+            page = jnp.clip(pt_ref[b, g], 0, n_pages - 1)
+            return pltpu.make_async_copy(hbm.at[page], buf.at[g % ring],
+                                         sems.at[side, g % ring])
 
-        # Skip condition: unmapped column, or page wholly past pos[b].
-        # A processed page always holds >= 1 valid position.
-        live = (pt_ref[b, j] != PT_SENTINEL) & (j * ps <= pos_ref[b])
-        qf = q_ref[0].astype(jnp.float32)              # [H, hd]
+        def start(g):
+            copy(0, g).start()
+            copy(1, g).start()
 
-        def row(ref, s_ref, p):
-            """Page row p as f32 ``[H, hd]``, through the compute dtype
-            (int8 rows dequantize exactly as :func:`_deq_page`)."""
-            r = ref[0, p]
+        lax.fori_loop(0, jnp.minimum(n, ring), lambda g, _: start(g), None)
+        qf = q_ref[0].astype(jnp.float32) * scale      # [H, hd]
+
+        def rows(side, g, c):
+            """Chunk ``c`` of page ``g`` as f32 ``[chunk, H, hd]``,
+            through the compute dtype (int8 rows dequantize exactly as
+            :func:`_deq_page`)."""
+            buf, s_ref = ((k_buf, ks_ref), (v_buf, vs_ref))[side]
+            r = buf[g % ring] if cpp == 1 else \
+                buf[g % ring, pl.ds(c * chunk, chunk)]
             if quant:
-                r = (r.astype(jnp.float32) * s_ref[0, 0]).astype(dtype)
+                # Column g of this lane's [H, max_pages] scales, [H, 1].
+                col = lax.broadcasted_iota(jnp.int32, (H, max_pages), 1)
+                sc = jnp.sum(jnp.where(col == g, s_ref[0], 0.0), axis=1,
+                             keepdims=True)
+                r = (r.astype(jnp.float32) * sc).astype(dtype)
             return r.astype(jnp.float32)
 
-        def logit(p):
-            lg = jnp.sum(qf * row(k_ref, ks_ref if quant else None, p),
-                         axis=1, keepdims=True) * scale         # [H, 1]
-            return jnp.where(j * ps + p <= pos_ref[b], lg, -1e30)
+        def fold(u, carry, whole=True):
+            """Chunk ``u`` of the lane into ``(m, l, acc)``. A copy's
+            wait and its start fence the vector slots, so the waits
+            come first (on a page's first chunk) and the refill last
+            (behind its last chunk the slot is free: the page ``ring``
+            further on starts), with all the arithmetic between them
+            in one block."""
+            def on_chunk(which, fn):
+                fn() if cpp == 1 else pl.when(c == which)(fn)
 
-        @pl.when(live & (phase == 0))
-        def _stats():
-            def body(p, carry):
-                m, l = carry
-                lg = logit(p)
-                m_new = jnp.maximum(m, lg)
-                return m_new, l * jnp.exp(m - m_new) + jnp.exp(lg - m_new)
+            g, c = (u, 0) if cpp == 1 else (u // cpp, u % cpp)
+            m, l, acc = carry
 
-            m, l = lax.fori_loop(0, ps, body, (m_ref[...], l_ref[...]))
-            m_ref[...] = m
-            l_ref[...] = l
+            @functools.partial(on_chunk, 0)
+            def _():
+                copy(0, g).wait()
+                copy(1, g).wait()
 
-        @pl.when(live & (phase == 1))
-        def _accum():
-            m, l = m_ref[...], l_ref[...]
+            s = jnp.sum(qf * rows(0, g, c), axis=2,
+                        keepdims=True)                   # [chunk, H, 1]
+            v = rows(1, g, c)
+            if not whole:
+                valid = u * chunk + lax.broadcasted_iota(
+                    jnp.int32, (chunk, H, 1), 0) < n_live
+                s = jnp.where(valid, s, -1e30)
+                v = jnp.where(valid, v, 0.0)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0))
+            alpha = jnp.exp2(m - m_new)
+            p = jnp.exp2(s - m_new)                      # 0 where masked
+            l = alpha * l + jnp.sum(p, axis=0)
+            acc = alpha * acc + jnp.sum(
+                p.astype(dtype).astype(jnp.float32) * v, axis=0)
+            if whole:
+                on_chunk(cpp - 1, lambda: pl.when(g + ring < n)(
+                    lambda: start(g + ring)))
+            return m_new, l, acc
 
-            def body(p, acc):
-                pr = (jnp.exp(logit(p) - m) / l).astype(dtype)  # [H, 1]
-                return acc + pr.astype(jnp.float32) * row(
-                    v_ref, vs_ref if quant else None, p)
+        n_whole = n_live // chunk              # chunks that need no mask
+        carry = lax.fori_loop(
+            0, n_whole, fold,
+            (jnp.full((H, 1), -1e30, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32),
+             jnp.zeros((H, hd), jnp.float32)))
+        m, l, acc = lax.cond(
+            n_live > n_whole * chunk,
+            lambda carry: fold(n_whole, carry, whole=False),
+            lambda carry: carry, carry)
+        o_ref[0] = (acc / jnp.where(l > 0.0, l, 1.0)).astype(dtype)
 
-            acc_ref[...] = lax.fori_loop(0, ps, body, acc_ref[...])
-
-        @pl.when((phase == 1) & (j == max_pages - 1))
-        def _emit():
-            o_ref[0] = acc_ref[...].astype(dtype)
-
-    def page_map(b, phase, j, pt_s, pos_s):
-        return (jnp.clip(pt_s[b, j], 0, n_pages - 1), 0, 0, 0)
-
-    def scale_map(b, phase, j, pt_s, pos_s):
-        return (b, j, 0, 0)
-
-    def slot_map(b, phase, j, pt_s, pos_s):
+    def lane_map(b, pt_s, len_s):
         return (b, 0, 0)
 
-    in_specs = [pl.BlockSpec((1, H, hd), slot_map),
-                pl.BlockSpec((1, ps, H, hd), page_map),
-                pl.BlockSpec((1, ps, H, hd), page_map)]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec((1, H, hd), lane_map), hbm, hbm]
     inputs = [q[:, 0], kc, vc]
     if quant:
         ptc = jnp.clip(pt, 0, n_pages - 1)
-        in_specs += [pl.BlockSpec((1, 1, H, 1), scale_map),
-                     pl.BlockSpec((1, 1, H, 1), scale_map)]
-        inputs += [ks[ptc][..., None], vs[ptc][..., None]]
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B, 2, max_pages),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, H, hd), slot_map),
-            scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, hd), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), dtype),
-        interpret=pallas_interpret(),
-    )(pt, pos, *inputs)
+        in_specs += [pl.BlockSpec((1, H, max_pages), lane_map)] * 2
+        inputs += [ks[ptc].transpose(0, 2, 1), vs[ptc].transpose(0, 2, 1)]
+    # The scope names the kernel's path for a trace's readers
+    # (".../paged_attention/pallas_call"); `name` names the device
+    # operation itself ("paged_attention.N").
+    with jax.named_scope("paged_attention"):
+        out = pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(B,),
+                in_specs=in_specs,
+                out_specs=pl.BlockSpec((1, H, hd), lane_map),
+                scratch_shapes=[pltpu.VMEM((ring, ps, H, hd), kc.dtype),
+                                pltpu.VMEM((ring, ps, H, hd), vc.dtype),
+                                pltpu.SemaphoreType.DMA((2, ring))]),
+            out_shape=jax.ShapeDtypeStruct((B, H, hd), dtype),
+            # Every index a copy takes is clipped (page) or a remainder
+            # (slot): the bounds checks Mosaic adds to a dynamic slice
+            # are a fifth of a page's instructions and cannot fire.
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=2 * ring_bytes + (12 << 20),
+                disable_bounds_checks=True),
+            interpret=interpret,
+            name="paged_attention",
+        )(pt, length, *inputs)
     return out[:, None]
 
 

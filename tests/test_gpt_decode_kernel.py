@@ -1,12 +1,23 @@
 """Fused paged-attention kernel + int8 quantized KV cache (ISSUE 16).
 
-The exactness contract under test:
+The contract under test (ROADMAP D10, restated by PR 35):
 
 - ``attn_kernel="pallas"`` (Pallas ``pallas_call`` on TPU, interpret
-  mode on CPU — tier-1 exercises the REAL kernel body either way) is
-  token-identical to the XLA gather reference at temperature 0 AND
-  under seeded sampling, across sentinel-padded page tables, mid-page
-  COW prefix forks, and the int8 cache layout.
+  mode on CPU — tier-1 exercises the REAL kernel body either way) reads
+  each slot's live pages once, in one softmax pass, and rounds its
+  probabilities before the division by their sum where the XLA gather
+  reference rounds them after it. So the two agree to a WRITTEN BOUND,
+  ``gpt_decode.ATTN_KERNEL_ULPS`` bf16 ulps of the largest output, not
+  to the bit — across page sizes, table widths, lengths, out-of-order
+  and sentinel-padded tables, fp and int8 pages, heads sharded — and
+  nothing outside a slot's live positions can move an output bit,
+  ``inf`` and ``NaN`` included. Streams are judged as the benchmark
+  judges them: every token within the margin of the reference path's
+  best logit on the same history (seeded sampling: of its best
+  PERTURBED logit). Identity is asserted only where it holds by
+  construction: the same engine twice, the prefill's first token.
+- The kernel's work does not scale with the table's width: its grid is
+  one step a slot, and K and V are not blocked operands.
 - ``kv_dtype="int8"`` (per-page-per-head scales, quantize on scatter /
   dequantize at attention) bounds its round-trip error by one quantum
   (``1/127`` relative to the page's absmax) and documents a temp-0
@@ -88,14 +99,66 @@ def _prefix_prompts(nano, rng, n_fresh=2):
 
 
 # --------------------------------------------------- kernel vs reference
+#: The benchmark's rule for a served token (``PERF.md`` section 2): it
+#: lies within 2 x ``logits_rel_tol`` x max|ref| of the reference's best
+#: logit — a token chosen from logits off by e lies at most 2e below.
+LOGITS_REL_TOL = 0.025
+
+
+def _ulps(got, ref):
+    """max|got - ref| in bf16 ulps of the largest reference output."""
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    return float(np.abs(got - ref).max() / (2.0 ** -8 * np.abs(ref).max()))
+
+
+def _pool(rng, n_pages, ps, H, hd, dtype, quant):
+    import jax.numpy as jnp
+
+    shape = (n_pages, ps, H, hd)
+    if quant:
+        return (jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+                jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+                jnp.asarray(rng.uniform(.005, .03, (n_pages, H)),
+                            jnp.float32),
+                jnp.asarray(rng.uniform(.005, .03, (n_pages, H)),
+                            jnp.float32))
+    return (jnp.asarray(rng.standard_normal(shape), dtype),
+            jnp.asarray(rng.standard_normal(shape), dtype), None, None)
+
+
+def _poison(kc, vc, ks, vs, pt, pos, ps, bad):
+    """The pool with ``bad`` in every page no table maps and in every
+    position past a lane's ``pos`` inside its last page — codes at
+    their extreme and the scale ``bad`` where the pool is int8."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode
+
+    live = {int(p) for p in np.asarray(pt).ravel()
+            if p != gpt_decode.PT_SENTINEL}
+    dead = np.array([p not in live for p in range(kc.shape[0])])
+    k2, v2 = np.array(kc, np.float32), np.array(vc, np.float32)
+    fill = 127 if ks is not None else bad
+    k2[dead] = fill
+    v2[dead] = fill
+    for row, last in zip(np.asarray(pt), np.asarray(pos)):
+        if row[0] != gpt_decode.PT_SENTINEL:
+            k2[row[last // ps], last % ps + 1:] = fill
+            v2[row[last // ps], last % ps + 1:] = fill
+    if ks is not None:
+        ks = jnp.where(dead[:, None], bad, ks)
+        vs = jnp.where(dead[:, None], bad, vs)
+    return jnp.asarray(k2, kc.dtype), jnp.asarray(v2, vc.dtype), ks, vs
+
+
 def test_paged_attention_matches_gather_direct(nano, nano_params):
     """Direct kernel-vs-reference on a hand-built pool: random pages,
     page tables with SENTINEL padding and out-of-order mappings, per
-    -slot lengths that end mid-page. The fused kernel must match the
-    gather reference to f32-accumulation-reorder noise (well below one
-    bf16 ulp of the output scale) — and garbage in pages past a slot's
-    pos must not leak in (the length mask and the sentinel skip are
-    fused into the kernel)."""
+    -slot lengths that end mid-page. The fused kernel agrees with the
+    gather reference to the written bound — and ``inf`` and ``NaN`` in
+    every page the tables never map and past a slot's pos inside its
+    last page do not move an output bit (the length mask covers V's
+    side too, and an unmapped page is never fetched)."""
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt_decode
@@ -103,10 +166,7 @@ def test_paged_attention_matches_gather_direct(nano, nano_params):
     H, hd, ps, n_pages, max_pages, B = nano.n_head, nano.head_dim, 8, \
         16, 4, 3
     rng = np.random.default_rng(21)
-    kc = jnp.asarray(rng.standard_normal((n_pages, ps, H, hd)),
-                     nano.dtype)
-    vc = jnp.asarray(rng.standard_normal((n_pages, ps, H, hd)),
-                     nano.dtype)
+    kc, vc, _, _ = _pool(rng, n_pages, ps, H, hd, nano.dtype, False)
     q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), nano.dtype)
     pt = np.full((B, max_pages), gpt_decode.PT_SENTINEL, np.int32)
     pt[0, :2] = [5, 3]            # out of order, 2 pages + sentinels
@@ -117,85 +177,321 @@ def test_paged_attention_matches_gather_direct(nano, nano_params):
                                      page_size=ps, kernel="gather")
     out = gpt_decode.paged_attention(q, kc, vc, jnp.asarray(pt), pos,
                                      page_size=ps, kernel="pallas")
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=0, atol=1e-2)
-    # Sentinel/length fusion: clobber every page the tables never map
-    # AND the tail of slot 2's single page past pos=4 — outputs for
-    # the mapped slots must not move at all.
-    live = {5, 3, 7, 0, 9, 2, 11}
-    kc2, vc2 = np.array(kc, np.float32), np.array(vc, np.float32)
-    for p in range(n_pages):
-        if p not in live:
-            kc2[p] = 1e4
-            vc2[p] = 1e4
-    kc2[11, 5:] = 1e4             # past slot 2's pos, same page
-    vc2[11, 5:] = 1e4
-    out2 = gpt_decode.paged_attention(
-        jnp.asarray(q), jnp.asarray(kc2, nano.dtype),
-        jnp.asarray(vc2, nano.dtype), jnp.asarray(pt), pos,
-        page_size=ps, kernel="pallas")
-    assert np.array_equal(np.asarray(out2, np.float32),
-                          np.asarray(out, np.float32))
+    assert _ulps(out, ref) <= gpt_decode.ATTN_KERNEL_ULPS
+    for bad in (1e4, np.inf, np.nan):
+        kc2, vc2, _, _ = _poison(kc, vc, None, None, pt, pos, ps, bad)
+        out2 = gpt_decode.paged_attention(
+            q, kc2, vc2, jnp.asarray(pt), pos, page_size=ps,
+            kernel="pallas")
+        assert np.array_equal(np.asarray(out2, np.float32),
+                              np.asarray(out, np.float32)), bad
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+@pytest.mark.parametrize("ps,max_pages", [
+    (8, 4), (16, 4), (8, 128), (16, 128), (8, 1), (64, 1)],
+    ids=lambda v: str(v))
+def test_kernel_adapts_to_pool_shape(nano, ps, max_pages, kv_dtype):
+    """What the kernel's ring and chunk adapt to — ``page_size`` 8 / 16
+    / a lane's whole ``max_len`` (64, one page a lane), tables 1 / 4 /
+    128 columns wide — over lanes at ``pos`` 0, mid-page, page-exact
+    and full, an out-of-order table, and a lane whose row is all
+    sentinels (finite zeros): within the written bound of the gather
+    path, and not a bit moved by ``inf`` or ``NaN`` in every unmapped
+    page and past ``pos`` inside a live page."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode as gd
+
+    H, hd = nano.n_head, nano.head_dim
+    quant = kv_dtype == "int8"
+    rng = np.random.default_rng(41 + ps + max_pages)
+    full = max_pages * ps - 1
+    lanes = [0, min(ps // 2, full), ps - 1, full,       # the four pos
+             min(ps + 2, full)]                         # out of order
+    B = len(lanes) + 1                                  # + all sentinel
+    n_pages = sum(p // ps + 1 for p in lanes) + 5
+    perm, off = rng.permutation(n_pages), 0
+    pt = np.full((B, max_pages), gd.PT_SENTINEL, np.int32)
+    for b, p in enumerate(lanes):
+        n = p // ps + 1
+        pt[b, :n] = perm[off:off + n] if b < 4 else \
+            np.sort(perm[off:off + n])[::-1]
+        off += n
+    pos = jnp.asarray(lanes + [0], jnp.int32)
+    kc, vc, ks, vs = _pool(rng, n_pages, ps, H, hd, nano.dtype, quant)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), nano.dtype)
+
+    def run(kernel, kc, vc, ks, vs):
+        return np.asarray(gd.paged_attention(
+            q, kc, vc, jnp.asarray(pt), pos, page_size=ps, kernel=kernel,
+            ks=ks, vs=vs), np.float32)
+
+    out = run("pallas", kc, vc, ks, vs)
+    ref = run("gather", kc, vc, ks, vs)
+    assert np.isfinite(out).all() and (out[-1] == 0).all()
+    assert _ulps(out[:-1], ref[:-1]) <= gd.ATTN_KERNEL_ULPS
+    for bad in (np.inf, np.nan):
+        assert np.array_equal(
+            run("pallas", *_poison(kc, vc, ks, vs, pt, pos, ps, bad)),
+            out), bad
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_kernel_under_sharded_heads(nano, kv_dtype):
+    """``H`` sharded by 2, as ``tp=2`` runs it (under ``shard_map``,
+    unchecked): each device's kernel sees one head, and the result is
+    the unsharded kernel's to the bit — a head's arithmetic does not
+    depend on how many heads share the page."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu._private.jax_compat import decode_mesh, shard_map
+    from ray_tpu.models import gpt_decode as gd
+
+    H, hd, ps, B = nano.n_head, nano.head_dim, 8, 3
+    quant = kv_dtype == "int8"
+    rng = np.random.default_rng(43)
+    kc, vc, ks, vs = _pool(rng, 12, ps, H, hd, nano.dtype, quant)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), nano.dtype)
+    pt = np.full((B, 4), gd.PT_SENTINEL, np.int32)
+    pt[0, :3] = [4, 9, 1]
+    pt[2, :1] = [7]
+    pt, pos = jnp.asarray(pt), jnp.asarray([20, 0, 7], jnp.int32)
+    heads = P(None, None, "tp")
+    scales = (P(None, "tp"),) * 2 if quant else ()
+
+    def attend(q, kc, vc, pt, pos, *sc):
+        return gd.paged_attention(q, kc, vc, pt, pos, page_size=ps,
+                                  kernel="pallas", ks=sc[0] if sc else None,
+                                  vs=sc[1] if sc else None)
+
+    args = (q, kc, vc, pt, pos) + ((ks, vs) if quant else ())
+    sharded = jax.jit(shard_map(
+        attend, mesh=decode_mesh(2),
+        in_specs=(heads, heads, heads, P(), P()) + scales,
+        out_specs=heads, check_vma=False))(*args)
+    assert np.array_equal(np.asarray(sharded, np.float32),
+                          np.asarray(attend(*args), np.float32))
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+def test_kernel_work_does_not_scale_with_table_width(nano, kv_dtype):
+    """The guard against dead grid steps coming back: from the jaxpr
+    of ``paged_attention(kernel="pallas")``, the ``pallas_call``'s grid
+    is one step a slot — no ``max_pages`` axis, no factor 2, the same
+    at 4 and at 128 columns — and K and V are whole-pool operands left
+    in place (memory space ``any``), not blocks of a per-column
+    ``BlockSpec``: the kernel fetches what a slot's own length asks
+    for."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode as gd
+
+    H, hd, ps, n_pages, B = nano.n_head, nano.head_dim, 8, 16, 3
+    quant = kv_dtype == "int8"
+    kc, vc, ks, vs = _pool(np.random.default_rng(0), n_pages, ps, H, hd,
+                           nano.dtype, quant)
+    q = jnp.zeros((B, 1, H, hd), nano.dtype)
+    grids = []
+    for max_pages in (4, 128):
+        pt = jnp.zeros((B, max_pages), jnp.int32)
+        jaxpr = jax.make_jaxpr(lambda q, kc, vc, pt, pos: gd.paged_attention(
+            q, kc, vc, pt, pos, page_size=ps, kernel="pallas", ks=ks,
+            vs=vs))(q, kc, vc, pt, jnp.zeros((B,), jnp.int32))
+        (call,) = _eqns(jaxpr.jaxpr, "pallas_call")
+        gm = call.params["grid_mapping"]
+        grids.append(tuple(gm.grid))
+        pools = [bm.transformed_block_aval for bm in gm.block_mappings
+                 if bm.array_aval.shape == kc.shape]
+        assert len(pools) == 2                          # K and V
+        for block in pools:
+            assert str(block.memory_space) == "any", block
+            assert block.shape == kc.shape, block
+        assert "paged_attention" in str(call.source_info.name_stack)
+    assert grids == [(B,), (B,)]
+
+
+def _judged_case(nano, nano_params, kv_dtype, prompts, ps=8):
+    """A pool with ``prompts`` prefilled into it by the engine's own
+    prefill program, one lane each, contiguous pages. Returns ``(cache,
+    pt, first)``: ``first`` is each lane's first token (temperature
+    0)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode as gd
+
+    B, max_pages = len(prompts), 64 // ps
+    cache = gd.init_paged_cache(nano, B, B * max_pages, ps, kv_dtype)
+    pt = np.arange(B * max_pages, dtype=np.int32).reshape(B, max_pages)
+    first = []
+    for b, prompt in enumerate(prompts):
+        tokens = np.zeros((1, 16), np.int32)
+        tokens[0, :len(prompt)] = prompt
+        tok, cache, _ = gd.prefill_into_slot_paged(
+            nano_params, cache, jnp.asarray(tokens),
+            jnp.asarray(len(prompt)), jnp.asarray(0), jnp.asarray(pt[b]),
+            jnp.asarray(gd.PT_SENTINEL), jnp.asarray(b),
+            jax.random.PRNGKey(0), cfg=nano, page_size=ps,
+            kv_dtype=kv_dtype)
+        first.append(int(np.asarray(tok).ravel()[0]))
+    return cache, jnp.asarray(pt), jnp.asarray(first, jnp.int32)
+
+
+def _reference_logits(nano, nano_params, kv_dtype, cache, pt, first,
+                      forced, ps=8):
+    """The REFERENCE path's logits on a given history: ``forced [B,
+    k]`` is fed token by token through the gather path's decode step
+    from ``cache``; ``out[:, j]`` are the logits that choose
+    ``forced[:, j]``."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode as gd
+
+    step = jax.jit(lambda c, tok: gd._slot_decode_step_paged(
+        nano_params, c, tok, jnp.ones(tok.shape, bool), pt, nano, ps,
+        kv_dtype, "gather"))
+    out, tok = [], first
+    for j in range(forced.shape[1]):
+        logits, cache = step(cache, tok)
+        out.append(np.asarray(logits, np.float32))
+        tok = jnp.asarray(forced[:, j], jnp.int32)
+    return np.stack(out, axis=1)
+
+
+def _gaps(ref, tokens, noise=None):
+    """How far below the reference's best (perturbed) logit each token
+    lies, in units of the benchmark's margin; <= 1 passes."""
+    ref = ref + (0.0 if noise is None else noise)
+    margin = 2 * LOGITS_REL_TOL * np.abs(ref).max()
+    chosen = np.take_along_axis(ref, np.asarray(tokens)[..., None],
+                                axis=-1)[..., 0]
+    return (ref.max(axis=-1) - chosen) / margin
 
 
 @pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
 def test_kernel_token_identity_greedy(nano, nano_params, kv_dtype):
-    """Kernel on vs off at temperature 0: identical token streams for
-    every lane — mixed prompt lengths (sentinel-padded tables), a
-    shared prefix hit that forks mid-page (COW), concurrent slots —
-    on BOTH cache layouts. The kernel's exactness contract is against
-    the gather reference on the SAME cache bytes, so it holds for int8
-    exactly as for fp."""
-    ref = _make(nano, nano_params, prefix_cache=True,
-                prompt_buckets=(8, 16), kv_dtype=kv_dtype,
-                attn_kernel="gather")
+    """Kernel on at temperature 0, on BOTH cache layouts, judged as the
+    benchmark judges a served stream: every token of every lane of the
+    kernel's program lies within the margin of the REFERENCE path's
+    best logit on the same history (the gather step, teacher-forced
+    through the kernel's own tokens on the same cache bytes); the
+    control — another lane's tokens against these logits — fails. And
+    the engine serves through it: mixed prompt lengths
+    (sentinel-padded tables), a shared prefix hit that forks mid-page
+    (COW), concurrent slots; whole streams with ids in the table, the
+    FIRST token of each the gather engine's (the prefill runs no
+    kernel: identity by construction)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode as gd
+
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, nano.vocab_size, (n,)).astype(np.int32)
+               for n in (5, 11, 16)] + _prefix_prompts(nano, rng)
+    k = 12
+    cache, pt, first = _judged_case(nano, nano_params, kv_dtype, prompts)
+    B = len(prompts)
+    toks, _, _, _ = gd.decode_chunk_slots_paged(
+        nano_params, cache, first, jnp.zeros((B, 2), jnp.uint32),
+        jnp.ones((B,), bool), pt, cfg=nano, k=k, page_size=8,
+        kv_dtype=kv_dtype, attn_kernel="pallas")
+    toks = np.asarray(toks)
+    ref = _reference_logits(nano, nano_params, kv_dtype, cache, pt,
+                            first, toks)
+    assert _gaps(ref, toks).max() <= 1.0, _gaps(ref, toks).max()
+    assert _gaps(ref, np.roll(toks, 1, axis=0)).max() > 1.0   # control
+
+    ref_eng = _make(nano, nano_params, prefix_cache=True,
+                    prompt_buckets=(8, 16), kv_dtype=kv_dtype,
+                    attn_kernel="gather")
     ker = _make(nano, nano_params, prefix_cache=True,
                 prompt_buckets=(8, 16), kv_dtype=kv_dtype,
                 attn_kernel="pallas")
     try:
-        rng = np.random.default_rng(3)
-        prompts = [rng.integers(0, nano.vocab_size,
-                                (n,)).astype(np.int32)
-                   for n in (5, 11, 16)] + _prefix_prompts(nano, rng)
         max_news = [9, 7, 12, 8, 8]
-        of = _drain_concurrent(ref, prompts, max_news)
+        of = _drain_concurrent(ref_eng, prompts, max_news)
         ok = _drain_concurrent(ker, prompts, max_news)
         for i in range(len(prompts)):
-            assert (of[i] == ok[i]).all(), (i, of[i], ok[i])
+            assert ok[i].shape == (max_news[i],)
+            assert ((ok[i] >= 0) & (ok[i] < nano.vocab_size)).all()
+            assert of[i][0] == ok[i][0], (i, of[i], ok[i])
         st = ker.stats()
         assert st["attn_kernel"] == "pallas"
         assert st["attn_kernel_dispatches"] > 0
-        assert ref.stats()["attn_kernel_dispatches"] == 0
+        assert ref_eng.stats()["attn_kernel_dispatches"] == 0
     finally:
-        ref.shutdown()
+        ref_eng.shutdown()
         ker.shutdown()
 
 
 @pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
 def test_kernel_token_identity_temperature(nano, nano_params, kv_dtype):
-    """Seeded sampling (temp 1.0): kernel on vs off reproduces the
-    same per-slot PRNG chains token-for-token; a different seed still
-    diverges (the identity is not an artifact of a dead sampler)."""
-    ref = _make(nano, nano_params, temperature=1.0, prefix_cache=False,
-                kv_dtype=kv_dtype, attn_kernel="gather")
+    """Seeded sampling (temp 1.0). A sampled token is the argmax of
+    ``logits / T`` plus the Gumbel noise of the lane's PRNG chain, so
+    it is judged like a greedy one: every token the kernel's program
+    samples lies within the margin of the reference path's best
+    PERTURBED logit on the same history (the chain is rebuilt here, and
+    checked: it reproduces the gather program's own tokens exactly).
+    By construction: the same seed gives the same stream twice through
+    the kernel, a different seed diverges (the sampler is live), and
+    the first token is the gather engine's."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode as gd
+
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, nano.vocab_size, (n,)).astype(np.int32)
+               for n in (8, 13)]
+    k, B = 10, 2
+    cache, pt, first = _judged_case(nano, nano_params, kv_dtype, prompts)
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.asarray([7, 11]))
+    noise, chain = [], keys
+    for _ in range(k):
+        split = jax.vmap(jax.random.split)(chain)
+        chain = split[:, 0]
+        noise.append(np.asarray(jax.vmap(lambda s: jax.random.gumbel(
+            s, (nano.vocab_size,), jnp.float32))(split[:, 1])))
+    noise = np.stack(noise, axis=1)                     # [B, k, V]
+    sampled = {}
+    for kernel in gd.ATTN_KERNELS:
+        toks, _, _, _ = gd.decode_chunk_slots_paged(
+            nano_params, cache, first, keys, jnp.ones((B,), bool), pt,
+            cfg=nano, k=k, page_size=8, temperature=1.0,
+            kv_dtype=kv_dtype, attn_kernel=kernel)
+        sampled[kernel] = np.asarray(toks)
+    own = _reference_logits(nano, nano_params, kv_dtype, cache, pt, first,
+                            sampled["gather"])
+    assert ((own + noise).argmax(-1) == sampled["gather"]).all()
+    ref = _reference_logits(nano, nano_params, kv_dtype, cache, pt, first,
+                            sampled["pallas"])
+    assert _gaps(ref, sampled["pallas"], noise).max() <= 1.0
+    assert _gaps(ref, sampled["pallas"][::-1], noise).max() > 1.0
+
+    ref_eng = _make(nano, nano_params, temperature=1.0,
+                    prefix_cache=False, kv_dtype=kv_dtype,
+                    attn_kernel="gather")
     ker = _make(nano, nano_params, temperature=1.0, prefix_cache=False,
                 kv_dtype=kv_dtype, attn_kernel="pallas")
     try:
-        rng = np.random.default_rng(4)
-        prompts = [rng.integers(0, nano.vocab_size,
-                                (n,)).astype(np.int32)
-                   for n in (8, 13)]
         max_news = [8, 10]
         seeds = [7, 11]
-        of = _drain_concurrent(ref, prompts, max_news, seeds)
+        of = _drain_concurrent(ref_eng, prompts, max_news, seeds)
         ok = _drain_concurrent(ker, prompts, max_news, seeds)
+        again = _drain_concurrent(ker, prompts, max_news, seeds)
         for i in range(2):
-            assert (of[i] == ok[i]).all(), (i, of[i], ok[i])
+            assert (again[i] == ok[i]).all(), (i, again[i], ok[i])
+            assert of[i][0] == ok[i][0], (i, of[i], ok[i])
         other = np.concatenate(list(ker.stream(prompts[0], 8, seed=8)))
         assert not (other == ok[0]).all()
     finally:
-        ref.shutdown()
+        ref_eng.shutdown()
         ker.shutdown()
 
 
@@ -491,16 +787,17 @@ def _pool_case(nano, kv_dtype, n_pages, rng=None):
     return cache, token, active, jnp.asarray(pt), ps
 
 
-def _scans(jaxpr):
-    """Every ``scan`` equation of a jaxpr, nested ones included."""
+def _eqns(jaxpr, primitive):
+    """Every equation of a jaxpr that binds ``primitive``, nested ones
+    included."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
+        if eqn.primitive.name == primitive:
             yield eqn
         for sub in eqn.params.values():
             for j in sub if isinstance(sub, (list, tuple)) else (sub,):
                 inner = getattr(j, "jaxpr", j)
                 if hasattr(inner, "eqns"):
-                    yield from _scans(inner)
+                    yield from _eqns(inner, primitive)
 
 
 @pytest.mark.parametrize("program,kv_dtype,attn_kernel", [
@@ -539,7 +836,7 @@ def test_pool_is_carried_not_scanned(nano, nano_params, program,
     scale_shapes = {cache[n].shape[i:] for n in ("ks", "vs")
                     if n in cache for i in (0, 1)}
     carried = False
-    for eqn in _scans(jaxpr.jaxpr):
+    for eqn in _eqns(jaxpr.jaxpr, "scan"):
         n_fixed = eqn.params["num_consts"] + eqn.params["num_carry"]
         scanned = list(eqn.invars[n_fixed:]) \
             + list(eqn.outvars[eqn.params["num_carry"]:])
