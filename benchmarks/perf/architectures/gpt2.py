@@ -4,7 +4,9 @@ files of ``architectures/`` import the program's model or engine code,
 and each names no plain reference but its own (this one's is
 ``reference_gpt2.py``, beside the harness since PR 23; a new one lies
 beside its module as ``<name>_reference.py``). ``tests/perf`` holds the
-tree and a rehearsal copy with a second architecture to both.
+tree, a rehearsal copy with a second architecture and, since PR 36, a
+copy of the tree with a third planted IN it (``perf_testlib.
+planted_tree``, held by the copy's own tests) to all of it.
 
 An architecture module is found by the configuration file's
 ``"architecture"`` (``perf_harness.load_architecture``; absent means
@@ -49,6 +51,29 @@ a module provides, all of it functions of the configuration file
     served tokens at undecidable positions out of ``correct``, counts
     them (``compared``, ``left_out``) and refuses a run that compared
     too few. A module without it leaves nothing out.
+``decode_step_bytes(conf, weight_bytes, kv_bytes, live_tokens, stats_delta)``  (optional)
+    plain Python, as ``vocab`` is (the reader runs in ``run.py``'s own
+    process, which stays off the chip): the numerator of the whole
+    serving step's share of the chip's bandwidth, ``decode_roofline_pct``
+    (``.sat``), which asks the configuration's architecture and has no
+    other source since PR 36. THE RULE (PR 33): the FEWEST bytes ANY
+    program with the configuration's numerics moves in one decode
+    step. Every weight the step multiplies by, once, at
+    ``weight_bytes`` a parameter (the reader's table of
+    ``numerics.compute_dtype``); for routed experts, those that at
+    least one live token was routed to, from a counter the engine
+    keeps (``stats_delta``: ``engine.stats()`` differenced over the
+    window), never all of them by assumption; and the live cache
+    (``live_tokens`` summed over the active lanes, whatever the model
+    keeps a token a layer: keys and values, a latent) at ``kv_bytes``
+    a value (the reader's table of ``engine.kv_dtype``). Never how a
+    program HOLDS either: float32 masters, a cast once a launch,
+    padding and copies are overhead and read as distance from 100. A
+    count that over-reckons reads over 100, which no chip does: the
+    driver refuses it as ``impossible_reading``. A module without the
+    function has no such count: the reader returns nothing and the
+    metric is not listed for that architecture's cells. This module's
+    is ``kernel_costs.decode_step_bytes`` on the ``model`` block.
 ``train_program(cfg, conf, devices)``
     a training configuration's mesh, jitted ``init(key) -> state`` with
     ``state["params"]``, ``step(state, {"tokens": t}) -> (state,
@@ -202,6 +227,14 @@ def served_logits(engine, cfg, seqs, n_prompt: int, n_steps: int) -> dict:
         if i in (0, n_steps):
             got[i] = np.asarray(logits, np.float32)
     return got
+
+
+def decode_step_bytes(conf: dict, weight_bytes: int, kv_bytes: int,
+                      live_tokens: float, stats_delta: dict) -> float:
+    import kernel_costs
+
+    return kernel_costs.decode_step_bytes(conf["model"], weight_bytes,
+                                          kv_bytes, live_tokens)
 
 
 def reference(cfg):

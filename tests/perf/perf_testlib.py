@@ -1,8 +1,13 @@
 """Shared by the benchmark's tests: where the benchmark lives, a
 temporary copy of it to which a test adds files (never edits one), and
-the two structural checks that every root has to pass: the tree, and
-the rehearsal's copy that holds a second, CUT configuration whose
-architecture file imports the program openly (``rehearsal_copy``)."""
+the structural checks that every root has to pass: the tree, the
+rehearsal's copy that holds a second, CUT configuration whose
+architecture file imports the program openly (``rehearsal_copy``), and
+the PLANTED tree (``planted_tree``, PR 36): a copy of the benchmark AND
+of these tests into which an architecture, a cut configuration, a mix,
+a reader and a serving cell are planted in place, as a ``model_config``
+PR's diff would, and in which the copy's own structural tests then run.
+``ROOT`` follows this file's place, so a copy's tests hold the copy."""
 import ast
 import json
 import os
@@ -34,18 +39,25 @@ def fixture(name):
     return os.path.join(FIXTURES, name)
 
 
-def copy_with_additions(tmp, *, configs=(), mixes=(), readers=(),
-                        architectures=(), cells=(), metrics=(),
-                        join=None):
+def copy_with_additions(tmp, **additions):
     """A copy of BENCHMARK.json and benchmarks/perf under ``tmp``, plus
-    new files and new entries only. Returns the copy's root. A
+    new files and new entries only (``add_in_place``). Returns the
+    copy's root."""
+    root = os.path.join(str(tmp), "co")
+    shutil.copytree(PERF, perf_dir(root),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    return add_in_place(root, **additions)
+
+
+def add_in_place(root, *, configs=(), mixes=(), readers=(),
+                 architectures=(), cells=(), metrics=(), join=None):
+    """New files under ``root``'s benchmarks/perf and new entries in its
+    BENCHMARK.json (this tree's, with the entries added), as a PR that
+    adds and edits nothing writes them. Returns ``root``. A
     configuration's entry takes ``source`` and ``reduced`` from the
     configuration's own file, as a PR that adds one writes them.
     ``architectures``: (file name under ``architectures/``, path of the
     file to copy there): a model and the reference beside it."""
-    root = os.path.join(str(tmp), "co")
-    shutil.copytree(PERF, perf_dir(root),
-                    ignore=shutil.ignore_patterns("__pycache__"))
     bench = benchmark()
     for name, path in configs:
         dst = os.path.join(perf_dir(root), "configs", name + ".json")
@@ -137,6 +149,159 @@ def root_of(kind, tmp_path_factory):
     if kind == "tree":
         return ROOT
     return rehearsal_copy(tmp_path_factory.mktemp("perf_rehearsal"))
+
+
+# ---- the planted tree (PR 36)
+
+#: the planted architecture, configuration, cell and reader
+PLANTED = "planted"
+PLANTED_CONFIG, PLANTED_CELL = PLANTED + "-serve", PLANTED + "-batch"
+PLANTED_READER = PLANTED + "_attempted"
+#: the cell whose metrics the planted cell joins, every list of them
+PLANTED_LIKE = "cgpt1b3-batch-offline"
+
+#: what the planted architecture has beyond the dummy fixture: a
+#: ``decidable`` (nothing in it chooses, so every position is decided;
+#: its configuration then has to state ``correct.tie_eps``), and the
+#: bytes of a decode step under key names of its own
+PLANTED_MORE = '''
+
+def decidable(cfg, conf):
+    assert conf["correct"]["tie_eps"] > 0
+
+    def fn(weights, tokens):
+        import jax.numpy as jnp
+
+        return jnp.ones(tokens.shape, bool)
+
+    return fn
+
+
+def decode_step_bytes(conf, weight_bytes, kv_bytes, live_tokens,
+                      stats_delta):
+    m = conf["model"]
+    d, f, n = m["width"], m["ffn"], m["layers"]
+    weights = m["rows"] * d + n * (4 * d * d + 2 * d * f + 2 * d) + d
+    return weights * weight_bytes + 2 * n * d * kv_bytes * live_tokens
+'''
+
+PLANTED_FAULTS = (
+    "the_cell_in_a_list_whose_moves_it_does_not_report",
+    "a_cell_taken_out_of_a_counter_entrys_list",
+    "a_cell_put_before_the_cells_that_were_there",
+    "a_reference_without_its_module",
+    "an_architecture_without_its_reference",
+    "the_planted_architecture_names_reference_gpt2")
+
+
+def planted_tree(tmp, fault=None, name=PLANTED):
+    """A copy of the tree a PR would change: BENCHMARK.json,
+    benchmarks/perf AND tests/perf, with what a ``model_config`` PR
+    brings planted IN PLACE, as its diff would (``rehearsal_copy``
+    plants beside a copy of benchmarks/perf only, so the tests' own
+    lists of what the tree holds never met an addition until PR 36): a
+    second architecture with its reference under ``architectures/``
+    (the dummy fixtures under another name, with a ``decidable`` and a
+    ``decode_step_bytes``), a CUT configuration that names it (with
+    ``correct.tie_eps``), a closed-loop mix, a reader with its entry,
+    and a serving cell appended to the ``workloads`` list of every
+    end-to-end and per-layer metric ``cgpt1b3-batch-offline`` reports.
+    The copy's own tests then hold the copy (``run_planted_tests``) and
+    its ``run.py`` runs the planted cell (``run_copy``). ``fault``: one
+    of ``PLANTED_FAULTS``, each of which the copy's tests must refuse.
+    ``name``: the planted architecture's; its configuration, cell and
+    reader are named after it. Under another name the copy is a tree
+    that has grown by one, in which ALL of the copy's tests/perf pass,
+    these plantings among them (PR 36 ran it so; CHANGES.md). Returns
+    the copy's root."""
+    config, cell_name, reader = name + "-serve", name + "-batch", \
+        name + "_attempted"
+    assert fault is None or fault in PLANTED_FAULTS, fault
+    root = os.path.join(str(tmp), "tree")
+    ignore = shutil.ignore_patterns("__pycache__")
+    shutil.copytree(PERF, perf_dir(root), ignore=ignore)
+    shutil.copytree(os.path.dirname(FIXTURES),
+                    os.path.join(root, "tests", "perf"), ignore=ignore)
+    made = os.path.join(str(tmp), "planted")
+    os.makedirs(made)
+    with open(fixture("dummy_arch.py")) as f:
+        module = f.read().replace("dummy_reference",
+                                  name + "_reference") + PLANTED_MORE
+    if fault == "the_planted_architecture_names_reference_gpt2":
+        module += ("\n\ndef reference(cfg):\n    import reference_gpt2"
+                   "\n    return reference_gpt2\n")
+    with open(fixture("dummy-serve.json")) as f:
+        conf = json.load(f)
+    conf.update(name=config, architecture=name)
+    conf["correct"].update(
+        tie_eps=1e-3, why=conf["correct"]["why"] + "; tie_eps: the "
+        "planted module has a decidable, so the file states one (the "
+        "model chooses nothing and every position is decided)")
+    for file, text in ((name + ".py", module),
+                       (config + ".json", json.dumps(conf))):
+        with open(os.path.join(made, file), "w") as f:
+            f.write(text)
+    architectures = [
+        (name + ".py", os.path.join(made, name + ".py")),
+        (name + "_reference.py", fixture("dummy_arch_reference.py"))]
+    if fault == "a_reference_without_its_module":
+        architectures.append(("orphan_reference.py",
+                              fixture("dummy_arch_reference.py")))
+    if fault == "an_architecture_without_its_reference":
+        architectures.pop()
+    add_in_place(
+        root,
+        configs=[(config, os.path.join(made, config + ".json"))],
+        mixes=[(cell_name, fixture("nano-batch.json"))],
+        readers=[(reader, READER.replace(
+            "tpot_mean_ms", "out_tokens_per_s"))],
+        architectures=architectures,
+        cells=[cell(cell_name, config, cell_name)],
+        metrics=[("per_layer", {
+            "name": reader, "unit": "1", "better": "higher",
+            "source": "host_clock", "layer": "load generator",
+            "moves": "out_tokens_per_s",
+            "workloads": [cell_name]})],
+        join={cell_name: PLANTED_LIKE})
+    in_a_list = {
+        "the_cell_in_a_list_whose_moves_it_does_not_report":
+            lambda ls: ls["admit_queue_mean_ms"].append(cell_name),
+        "a_cell_taken_out_of_a_counter_entrys_list":
+            lambda ls: ls["decode_stall_pct.sat"].remove(PLANTED_LIKE),
+        "a_cell_put_before_the_cells_that_were_there":
+            lambda ls: ls["driver_host_pct.sat"].reverse()}
+    if fault in in_a_list:
+        bench = benchmark(root)
+        in_a_list[fault]({m["name"]: m["workloads"]
+                          for m in bench["per_layer"]})
+        with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+            json.dump(bench, f)
+    return root
+
+
+#: the copy's own structural tests: BENCHMARK.json against the contract
+#: and its files, who knows the model, the counter entries' contract
+PLANTED_TESTS = (
+    "test_perf_benchmark_json.py",
+    "test_perf_reference.py"
+    "::test_only_the_architecture_file_knows_the_model",
+    "test_perf_reference.py"
+    "::test_a_planted_file_that_knows_the_model_is_refused",
+    "test_perf_engine_counters.py::test_entries_keep_the_contract")
+
+
+def run_planted_tests(root, timeout=300):
+    """The structural tests OF THE COPY at ``root``, on the copy, in a
+    process of their own; returns (returncode, stdout)."""
+    import subprocess
+
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:randomly", "-p", "no:xdist", "-rf",
+         *(os.path.join("tests", "perf", t) for t in PLANTED_TESTS)],
+        cwd=root, env=_cpu_env(1), capture_output=True, text=True,
+        timeout=timeout)
+    return proc.returncode, proc.stdout + proc.stderr
 
 
 # ---- what every configuration file states (the contract is in
@@ -255,18 +420,50 @@ def who_knows_the_model(perf):
     return knows, references
 
 
-def run_copy(root, *args, devices=1, timeout=300):
-    """Run the copy's run.py as a rehearsal on the CPU; returns
-    (returncode, stdout lines, stderr)."""
-    import subprocess
+def architectures_and_references(perf):
+    """The names of the architectures under ``perf``, by the rule that
+    holds for any number of them: the files that know the model
+    (``who_knows_the_model``) are exactly the modules under
+    ``architectures/`` that are no reference, and the references are
+    ``reference_gpt2.py`` plus exactly ``architectures/<name>_
+    reference.py`` for every ``architectures/<name>.py`` but gpt2's: no
+    module without its reference, no reference without its module.
+    Raises AssertionError."""
+    knows, references = who_knows_the_model(perf)
+    arch = os.path.join("architectures", "")
+    names = sorted(
+        os.path.splitext(n)[0]
+        for n in os.listdir(os.path.join(perf, "architectures"))
+        if n.endswith(".py")
+        and not REFERENCE.match(os.path.splitext(n)[0]))
+    assert knows == [f"{arch}{n}.py" for n in names], \
+        "every architecture file knows the model and nothing else does"
+    assert references == [f"{arch}{n}_reference.py" for n in names
+                          if n != "gpt2"] + ["reference_gpt2.py"], \
+        "one reference beside every architecture file, and no other"
+    return names
 
+
+def _cpu_env(devices):
+    """A child's environment: the CPU with ``devices`` virtual devices,
+    and ``ray_tpu`` found from this tree (a planted copy holds the
+    benchmark and its tests, not the program: its children find the
+    program through the path they inherit)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=ROOT + os.pathsep + os.environ.get(
                    "PYTHONPATH", ""))
     env["XLA_FLAGS"] = \
         f"--xla_force_host_platform_device_count={devices}"
+    return env
+
+
+def run_copy(root, *args, devices=1, timeout=300):
+    """Run the copy's run.py as a rehearsal on the CPU; returns
+    (returncode, stdout lines, stderr)."""
+    import subprocess
+
     proc = subprocess.run(
         [sys.executable, os.path.join(perf_dir(root), "run.py"), *args],
-        cwd=root, env=env, capture_output=True, text=True,
+        cwd=root, env=_cpu_env(devices), capture_output=True, text=True,
         timeout=timeout)
     return proc.returncode, proc.stdout.strip().splitlines(), proc.stderr

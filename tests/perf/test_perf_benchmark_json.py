@@ -136,9 +136,13 @@ def test_configuration_files_state_source_sizes_and_cuts(bench, root,
                 if c["source"] == perf_testlib.CEREBRAS_1B3]
     assert len(cerebras) == 2
     if root != perf_testlib.ROOT:
-        cut = [c for c in bench["configs"] if c["reduced"]]
-        assert [c["name"] for c in cut] == ["dummy-serve"]
-        conf = H.load_config(cut[0], root)
+        # the rehearsal's cut configuration, found BY NAME: the tree may
+        # hold cut configurations of its own, each held to its own
+        # statement above
+        dummy = next(c for c in bench["configs"]
+                     if c["name"] == "dummy-serve")
+        assert dummy["reduced"] == ["layers", "vocab"]
+        conf = H.load_config(dummy, root)
         assert conf["cut"]["layers"] == {"published": 4, "held": 2}
         assert conf["cut_stands_for"]["chips_sharing_a_layer"] == 2
 

@@ -51,18 +51,21 @@ def test_only_the_architecture_file_knows_the_model(root):
     or name a reference module, all lie under ``architectures/``, each
     ``architectures/<name>.py`` names no reference but its own, and no
     reference imports the program (``perf_testlib.
-    who_knows_the_model``). Held on the tree and on the rehearsal's
-    copy, whose second architecture imports ``ray_tpu.models`` openly."""
-    knows, references = perf_testlib.who_knows_the_model(
+    who_knows_the_model``). A RULE for any number of architectures (PR
+    36; it was a list of the one file, which a second one tripped):
+    every architecture file knows the model and nothing else does;
+    beside ``reference_gpt2.py`` (pinned above) there is exactly one
+    ``architectures/<name>_reference.py`` for every
+    ``architectures/<name>.py`` but gpt2's, and no reference without
+    its module. Held on the tree and on the rehearsal's copy, which is
+    the tree's set plus ``dummy``, whose module imports
+    ``ray_tpu.models`` openly."""
+    held = perf_testlib.architectures_and_references(
         perf_testlib.perf_dir(root))
-    arch = os.path.join("architectures", "")
-    if root == perf_testlib.ROOT:
-        assert knows == [arch + "gpt2.py"]
-        assert references == ["reference_gpt2.py"]
-    else:
-        assert knows == [arch + "dummy.py", arch + "gpt2.py"]
-        assert references == [arch + "dummy_reference.py",
-                              "reference_gpt2.py"]
+    assert "gpt2" in held
+    if root != perf_testlib.ROOT:
+        assert held == sorted(perf_testlib.architectures_and_references(
+            perf_testlib.PERF) + ["dummy"])
 
 
 @pytest.mark.parametrize("fault", [
@@ -301,6 +304,75 @@ def test_decode_roofline_still_bites(case):
         sat = perf_harness.load_reader("decode_roofline_pct.sat")
         assert sat.read(run) == read(run)
         assert (sat.LAYER, sat.UNIT) == ("kernels", "%")
+
+
+STUB = '''"""A stub architecture: a dense part, and eight routed experts of
+which a step reads those a live token was routed to, by the engine's
+counter."""
+
+
+def decode_step_bytes(conf, weight_bytes, kv_bytes, live_tokens,
+                      stats_delta):
+    m = conf["model"]
+    touched = stats_delta.get("experts_touched_mean", m["experts"])
+    return (m["dense"] + touched * m["expert"]) * weight_bytes \\
+        + m["latent"] * kv_bytes * live_tokens
+'''
+
+
+@pytest.mark.parametrize("case", [
+    "bytes_from_the_stub", "routed_experts_from_the_counter",
+    "a_module_without_the_function", "no_such_architecture_file"])
+def test_decode_roofline_asks_the_configurations_architecture(tmp_path,
+                                                              case):
+    """The reader's path since PR 36, on a hand-made run: the numerator
+    is ``decode_step_bytes`` of ``architectures/<conf["architecture"]>
+    .py`` beside the reader, given the configuration, the two byte
+    widths, the live tokens and the window's counters; a module without
+    the function has no such count and the reader returns nothing."""
+    import shutil
+
+    here = str(tmp_path)
+    for sub in ("layer_metrics", "architectures"):
+        os.mkdir(os.path.join(here, sub))
+    shutil.copy(os.path.join(perf_testlib.PERF, "layer_metrics",
+                             "decode_roofline_pct.py"),
+                os.path.join(here, "layer_metrics"))
+    for name, text in (("stub", STUB), ("bare", "def vocab(conf):\n"
+                                                "    return 1, 1\n")):
+        with open(os.path.join(here, "architectures", name + ".py"),
+                  "w") as f:
+            f.write(text)
+    run = _decode_run(10.0, 1024)
+    model = {"dense": 10 ** 9, "expert": 10 ** 8, "experts": 8,
+             "latent": 576}
+    run["conf"] = dict(run["conf"], architecture="stub", model=model)
+    read = perf_harness.load_reader("decode_roofline_pct", here).read
+
+    def share(nbytes):
+        return pytest.approx(100 * nbytes / 819e9 / 10e-3)
+
+    kv = 576 * 2 * 1024
+    if case == "bytes_from_the_stub":
+        # no counter in a run without stats_delta: every expert
+        assert read(run) == share(2 * (10 ** 9 + 8 * 10 ** 8) + kv)
+    elif case == "routed_experts_from_the_counter":
+        run["stats_delta"] = {"experts_touched_mean": 5.5}
+        assert read(run) == share(2 * (10 ** 9 + 5.5 * 10 ** 8) + kv)
+        run["conf"]["numerics"] = dict(run["conf"]["numerics"],
+                                       compute_dtype="float32")
+        run["conf"]["engine"] = dict(run["conf"]["engine"],
+                                     kv_dtype="int8")
+        assert read(run) == share(4 * (10 ** 9 + 5.5 * 10 ** 8)
+                                  + kv // 2)
+    elif case == "a_module_without_the_function":
+        run["conf"]["architecture"] = "bare"
+        assert read(run) is None
+    else:
+        run["conf"]["architecture"] = "absent"
+        with pytest.raises(perf_harness.BenchError,
+                           match="no architecture file"):
+            read(run)
 
 
 def _synthetic(rng, n=9, rows=300, best=4.0):
