@@ -74,6 +74,13 @@ class GPTConfig:
         # 6ND approximation per forward+backward token.
         return 6 * self.num_params()
 
+    def decode_programs(self):
+        """This model's description for the serving engine
+        (:mod:`ray_tpu.models.serving`)."""
+        from . import gpt_decode
+
+        return gpt_decode
+
 
 # sizes used by benchmarks / examples
 CONFIGS = {

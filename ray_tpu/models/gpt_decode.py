@@ -209,6 +209,7 @@ from jax import lax
 
 from .._private.jax_compat import decode_mesh, shard_map
 from .gpt import (GPTConfig, Params, _mm, _project_vocab, _rmsnorm)
+from .serving import PT_SENTINEL, CacheEntry, CacheSpec, init_paged_pool
 
 Cache = Dict[str, jax.Array]
 
@@ -293,6 +294,10 @@ def _tp_cache_specs(cache):
         else:
             out[name] = P()
     return out
+
+
+#: The engine's name for the (cfg, tp) validation.
+check_tp = _tp_mesh
 
 
 def shard_params(params: Params, cfg: GPTConfig, tp: int) -> Params:
@@ -666,12 +671,8 @@ def _sample_slots(logits, temperature: float, keys):
 
 
 # -------------------------------------------------------------- paged pool
-#: Page-table padding value. Positive and far beyond any real pool size,
-#: so a sentinel is out-of-bounds for scatter (write DROPPED, never
-#: clamped into someone else's page) while reads clip it to a real page
-#: whose garbage the attention mask hides. Never use a negative
-#: sentinel: traced negative indices WRAP in jnp indexing.
-PT_SENTINEL = 2 ** 30
+#: Page-table padding (:data:`ray_tpu.models.serving.PT_SENTINEL`,
+#: imported above): out of bounds for a scatter, clipped for a read.
 
 #: KV-pool storage dtypes. ``"fp"`` stores pages in the model compute
 #: dtype; ``"int8"`` stores symmetric per-page-per-head int8 codes plus
@@ -701,20 +702,45 @@ ATTN_KERNEL_ULPS = 4
 _KV_EPS = 1e-8
 
 
+def cache_spec(cfg: GPTConfig, kv_dtype: str = "fp") -> CacheSpec:
+    """What a token leaves in a page, per layer: keys and values per
+    head, ``[H, hd]`` each, in the compute dtype (``"fp"``) or as int8
+    codes with one float32 scale per (page, head) per side
+    (``"int8"``). The pool's shapes, its page cost, the engine's
+    handoff checks and ``kv_bytes_per_token`` all come from here."""
+    if kv_dtype not in KV_DTYPES:
+        raise ValueError(
+            f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}")
+    row = (cfg.n_head, cfg.head_dim)
+    if kv_dtype == "int8":
+        entries = (CacheEntry("k", "token", row, jnp.int8),
+                   CacheEntry("v", "token", row, jnp.int8),
+                   CacheEntry("ks", "page", row[:1], jnp.float32),
+                   CacheEntry("vs", "page", row[:1], jnp.float32))
+    else:
+        entries = (CacheEntry("k", "token", row, cfg.dtype),
+                   CacheEntry("v", "token", row, cfg.dtype))
+    return CacheSpec(cfg.n_layer, entries)
+
+
 def kv_bytes_per_page(cfg: GPTConfig, page_size: int,
                       kv_dtype: str = "fp") -> int:
     """HBM bytes ONE physical page costs across all layers, K and V
     sides together — the unit the engine's page budget is denominated
-    in. ``"fp"`` pages hold ``page_size * H * hd`` elements of the
-    model compute dtype per side; ``"int8"`` pages hold the same
-    element count as 1-byte codes plus one float32 scale per head per
-    side."""
-    elems = page_size * cfg.n_head * cfg.head_dim
-    if kv_dtype == "int8":
-        per_layer = 2 * (elems + 4 * cfg.n_head)
-    else:
-        per_layer = 2 * elems * jnp.dtype(cfg.dtype).itemsize
-    return cfg.n_layer * per_layer
+    in (:meth:`CacheSpec.bytes_per_page` of :func:`cache_spec`)."""
+    return cache_spec(cfg, kv_dtype).bytes_per_page(page_size)
+
+
+def max_positions(cfg: GPTConfig) -> int:
+    """The longest sequence the model can place: the rows of its
+    learned position table."""
+    return cfg.max_seq
+
+
+#: What the engine offers and this model does not take: nothing.
+UNSUPPORTED: Dict[str, str] = {}
+#: The chunk program returns tokens, cache, done and keys, no counters.
+STEP_COUNTERS: Tuple[str, ...] = ()
 
 
 def _deq_page(codes: jax.Array, scales: jax.Array, dtype) -> jax.Array:
@@ -843,25 +869,8 @@ def init_paged_cache(cfg: GPTConfig, slots: int, n_pages: int,
     decode and verify steps can carry the whole pool through their
     layer scans as ``L * n_pages`` pages (:func:`_flat_pool`) and never
     copy a layer's pool out of it."""
-    if kv_dtype not in KV_DTYPES:
-        raise ValueError(
-            f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}")
-    shape = (cfg.n_layer, n_pages, page_size, cfg.n_head, cfg.head_dim)
-    if kv_dtype == "int8":
-        sshape = (cfg.n_layer, n_pages, cfg.n_head)
-        cache = {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
-            "ks": jnp.zeros(sshape, jnp.float32),
-            "vs": jnp.zeros(sshape, jnp.float32),
-            "pos": jnp.zeros((slots,), jnp.int32),
-        }
-    else:
-        cache = {
-            "k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "pos": jnp.zeros((slots,), jnp.int32),
-        }
+    cache = init_paged_pool(cache_spec(cfg, kv_dtype), slots, n_pages,
+                            page_size)
     mesh = _tp_mesh(cfg, tp)
     return cache if mesh is None else _shard_cache(cache, mesh)
 

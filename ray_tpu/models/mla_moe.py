@@ -1,0 +1,644 @@
+"""A latent-attention, expert-routed decoder and its paged serving
+programs: the second block :class:`~ray_tpu.serve.engine.DecodeEngine`
+serves (the first is :mod:`ray_tpu.models.gpt_decode`'s GPT-2 block).
+This module IS the model's description in the sense of
+:mod:`ray_tpu.models.serving`.
+
+The block (pre-norm, RMSNorm, no biases, untied head)::
+
+    x += Attn(RMSNorm(x));  x += FFN(RMSNorm(x))
+
+**Latent attention.** Queries go through a low rank (``q_rank``); keys
+and values are up-projections of ONE ``kv_rank``-wide latent a token,
+beside ONE ``rope_dim``-wide rotary key shared by every head::
+
+    c_q = RMSNorm(x W_qa);   [q_n | q_r] = c_q W_qb        (per head)
+    [c | k_r] = x W_kva;     c = RMSNorm(c)
+    [k_n | v] = c W_kvb                                    (per head)
+    scores = (q_n . k_n + rot(q_r) . rot(k_r)) * scale
+
+so a token leaves ``kv_rank + rope_dim`` values a layer in the cache
+(``c`` after its norm, ``k_r`` after rotation: 576 for 512 + 64), with
+no head axis: a page is ``[page_size, 640]`` once (576 values and
+zeros up to the 128-lane tile they occupy anyway:
+:attr:`MLAMoEConfig.latent_row`), not ``[page_size, H, hd]`` twice
+(:func:`cache_spec`). Two attention paths read it:
+
+- **prefill** (:func:`prefill_into_slot_paged`, scope ``mla.prefill``)
+  materialises ``k_n`` and ``v`` from the latents of the cached prefix
+  (read through the page table) and of the prompt's own tokens, and
+  attends causally per head: hundreds of queries share each
+  materialised key, so the up-projection is paid once a key;
+- **decode** (:func:`_slot_decode_step_paged`, scope ``mla.attention``)
+  ABSORBS the up-projections: ``q~ = q_n W_uk`` (``W_uk`` the ``k_n``
+  columns of ``W_kvb``), ``scores = q~ . c + q_r . k_r``, ``o = ((p c)
+  W_uv) W_o``: all 64 query heads of a lane meet ONE 576-wide key a
+  token, a matrix product over the gathered latent pages. It is plain
+  XLA over pages gathered through the page table, not a variant of the
+  Pallas kernel of :mod:`gpt_decode`: that kernel's DMA moves whole
+  (8/16, 128) tiles and 576 is 4.5 lane tiles (PERF.md section 7).
+
+Rotary positions use YaRN frequencies (:func:`yarn_inv_freq`): each of
+the ``rope_dim / 2`` frequencies blends ``theta^(-2i/dim)`` with the
+same over ``factor`` by a linear ramp between two correction
+dimensions, at EVERY position; the attention scale carries ``mscale``
+squared. Pairing: halves (dimension ``i`` rotates with ``i + dim/2``).
+
+**FFN.** The first ``n_dense`` layers have a gated SiLU FFN of width
+``d_ff``; the others a routed expert layer
+(:func:`ray_tpu.models.moe.dropless_moe`: sigmoid scores over
+``n_routed`` experts, group-limited top k, DROPLESS, told the
+``experts_held`` experts from ``expert_offset`` that live here) plus a
+shared expert of the same width (scope ``moe.shared``) that every
+token takes. What absent experts would add is left out: another chip
+of the deployment computes it.
+
+Layers are NOT stacked: ``params["layers"]`` is a list of per-layer
+trees (a dense layer and an expert layer differ, and per-layer leaves
+keep any one leaf small) and the programs unroll it; the pool is
+addressed as ``l * n_pages + page`` in its flat view, as gpt_decode's
+carried pool is, so no layer's pool is ever sliced out.
+
+The chunk program returns, beside the tokens, the expert layers'
+counters summed over its steps (:data:`STEP_COUNTERS`).
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from . import moe
+from .gpt import _mm
+from .gpt_decode import (_knob_cache, _program, _sample, _sample_slots)
+from .serving import PT_SENTINEL, CacheEntry, CacheSpec, init_paged_pool
+
+Params = Dict[str, Any]
+Cache = Dict[str, jax.Array]
+
+KV_DTYPES = ("fp",)
+ATTN_KERNELS = ("gather",)
+#: What the engine offers and this model does not take, with the reason
+#: the engine raises at construction.
+UNSUPPORTED = {
+    "int8": "the latent page pool has no quantised layout: a latent's "
+            "512 values and its rotary key have no per-head scale to "
+            "share",
+    "tp": "latent attention and the expert layer have no tensor-"
+          "parallel programs: the deployment shares a layer by EXPERTS "
+          "(experts_held / expert_offset), one engine a chip",
+    "spec_decode": "there is no verify program for latent pages",
+    "roles": "there are no export/import programs for latent pages, so "
+             "no prefill/decode roles and no KV handoff",
+}
+#: int32 counters the chunk program returns, summed over its steps:
+#: decode steps x expert layers; over those, the held experts with at
+#: least one token, the token-choices that landed on held experts, and
+#: the fullest held expert's tokens.
+STEP_COUNTERS = ("moe_steps", "moe_experts_touched_sum",
+                 "moe_tokens_here_sum", "moe_expert_peak_sum")
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAMoEConfig:
+    vocab_size: int = 512            # rows of the table and head HELD
+    n_layer: int = 3                 # n_dense leading + expert layers
+    n_dense: int = 1
+    d_model: int = 64
+    n_head: int = 4
+    q_rank: int = 48
+    kv_rank: int = 32
+    nope_dim: int = 16
+    rope_dim: int = 8
+    v_dim: int = 16
+    d_ff: int = 160                  # the dense layers' FFN
+    d_expert: int = 32               # a routed or shared expert's FFN
+    n_routed: int = 16               # the router's width
+    experts_held: int = 16           # of which live here ...
+    expert_offset: int = 0           # ... from this one
+    n_group: int = 4
+    topk_group: int = 2
+    top_k: int = 4
+    norm_topk: bool = True
+    route_scale: float = 2.5
+    shared_expert: bool = True
+    rope_theta: float = 10000.0
+    rope_factor: float = 32.0        # YaRN; 1.0: plain rotary
+    rope_orig_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    mscale_all_dim: float = 1.0
+    max_seq: int = 131072            # positions the rotary reaches
+    eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    moe_block_rows: int = 32
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def latent_row(self) -> int:
+        """A token's row in a page: ``latent_dim`` values and zeros up
+        to the next multiple of 128 lanes (576 -> 640). The TPU tiles
+        the two minor dimensions by (8/16, 128), so a 576-wide row
+        occupies 640 lanes in HBM whatever the shape says; held as 576
+        the compiler saw the padding, chose another (compact) layout
+        for the pool where it could, and copied the whole pool between
+        the two, twice a prefill and ten times a decode step (PERF.md,
+        PR 37). Held as 640 the default layout has nothing to save."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def attn_scale(self) -> float:
+        m = 1.0
+        if self.rope_factor > 1.0:
+            m = 0.1 * self.mscale_all_dim * math.log(self.rope_factor) + 1.0
+        return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+
+    def decode_programs(self):
+        """This model's description for the serving engine
+        (:mod:`ray_tpu.models.serving`)."""
+        import sys
+
+        return sys.modules[__name__]
+
+
+# sizes used by the CPU tests
+CONFIGS = {
+    "nano": MLAMoEConfig(),
+}
+
+
+def init_params(rng: jax.Array, cfg: MLAMoEConfig, std: Optional[dict] = None
+                ) -> Params:
+    """Seeded weights, one tree a layer. ``std`` overrides a kind's
+    standard deviation (``"router"``, ``"down"``, ``"wo"``, ...; default
+    1/sqrt(fan-in))."""
+    std = std or {}
+    pd = cfg.param_dtype
+    d, H = cfg.d_model, cfg.n_head
+    n = [0]
+
+    def w(name, *shape):
+        n[0] += 1
+        s = std.get(name, 1.0 / math.sqrt(shape[-2]))
+        return (jax.random.normal(jax.random.fold_in(rng, n[0]), shape)
+                * s).astype(pd)
+
+    def ffn(f, lead=()):
+        return {"gate": w("gate", *lead, d, f), "up": w("up", *lead, d, f),
+                "down": w("down", *lead, f, d)}
+
+    layers = []
+    for l in range(cfg.n_layer):
+        p = {"ln1_scale": jnp.ones((d,), pd),
+             "ln2_scale": jnp.ones((d,), pd),
+             "wqa": {"kernel": w("wqa", d, cfg.q_rank)},
+             "q_norm_scale": jnp.ones((cfg.q_rank,), pd),
+             "wqb": {"kernel": w("wqb", cfg.q_rank,
+                                 H * (cfg.nope_dim + cfg.rope_dim))},
+             "wkva": {"kernel": w("wkva", d, cfg.latent_dim)},
+             "kv_norm_scale": jnp.ones((cfg.kv_rank,), pd),
+             "wkvb": {"kernel": w("wkvb", cfg.kv_rank,
+                                  H * (cfg.nope_dim + cfg.v_dim))},
+             "wo": {"kernel": w("wo", H * cfg.v_dim, d)}}
+        if l < cfg.n_dense:
+            p["ffn"] = ffn(cfg.d_ff)
+        else:
+            p["router"] = {"kernel": w("router", d, cfg.n_routed)}
+            p["experts"] = ffn(cfg.d_expert, (cfg.experts_held,))
+            if cfg.shared_expert:
+                p["shared"] = ffn(cfg.d_expert)
+        layers.append(p)
+    return {"embed": {"kernel": w("embed", cfg.vocab_size, d)},
+            "head": {"kernel": w("head", d, cfg.vocab_size)},
+            "ln_f_scale": jnp.ones((d,), pd), "layers": layers}
+
+
+# ------------------------------------------------------------ block math
+def _rmsnorm(x, scale, eps, dtype=None):
+    """RMSNorm in float32; the result in ``dtype`` (``x``'s own if
+    absent), ready to be multiplied."""
+    dtype = dtype or x.dtype
+    x = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return (x * lax.rsqrt(var + eps)).astype(dtype) * scale.astype(dtype)
+
+
+def yarn_inv_freq(cfg: MLAMoEConfig) -> jax.Array:
+    """The ``rope_dim / 2`` rotary frequencies, float32. YaRN: below
+    the correction dimension of ``beta_fast`` rotations over the
+    original context a frequency is kept, above that of ``beta_slow``
+    it is divided by ``factor``, and between the two it is blended
+    linearly."""
+    dim = cfg.rope_dim
+    i = jnp.arange(0, dim, 2, dtype=jnp.float32)
+    extra = cfg.rope_theta ** (-i / dim)
+    if cfg.rope_factor <= 1.0:
+        return extra
+
+    def correction_dim(rotations):
+        return dim * math.log(cfg.rope_orig_max
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(cfg.rope_theta))
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return extra / cfg.rope_factor * ramp + extra * (1.0 - ramp)
+
+
+def _rope(x, positions, cfg: MLAMoEConfig):
+    """Rotate ``x`` [..., S, dim] (or [..., S, H, dim] with
+    ``positions`` broadcast over H) by its positions [..., S]; halves
+    pairing; float32 inside, ``x``'s dtype out."""
+    ang = positions.astype(jnp.float32)[..., None] * yarn_inv_freq(cfg)
+    if x.ndim == ang.ndim + 1:
+        ang = ang[..., None, :]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _latent_qkv(x, p, positions, cfg: MLAMoEConfig):
+    """x [B, S, d] at ``positions`` [B, S] -> (q_n [B, S, H, nope],
+    q_r [B, S, H, rope] rotated, entry [B, S, latent_row]: the token's
+    cache row, ``c`` after its norm, ``k_r`` rotated, zeros to the
+    row's width)."""
+    B, S, _ = x.shape
+    H = cfg.n_head
+    h = _rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+    cq = _rmsnorm(_mm(h, p["wqa"]["kernel"], cfg.dtype),
+                  p["q_norm_scale"], cfg.eps)
+    q = _mm(cq, p["wqb"]["kernel"], cfg.dtype).reshape(
+        B, S, H, cfg.nope_dim + cfg.rope_dim)
+    qn, qr = q[..., :cfg.nope_dim], q[..., cfg.nope_dim:]
+    ckv = _mm(h, p["wkva"]["kernel"], cfg.dtype)
+    c = _rmsnorm(ckv[..., :cfg.kv_rank], p["kv_norm_scale"], cfg.eps)
+    kr = _rope(ckv[..., cfg.kv_rank:], positions, cfg)
+    pad = jnp.zeros(c.shape[:-1] + (cfg.latent_row - cfg.latent_dim,),
+                    c.dtype)
+    return qn, _rope(qr, positions, cfg), \
+        jnp.concatenate([c, kr, pad], axis=-1)
+
+
+def _wkvb(p, cfg: MLAMoEConfig):
+    """``W_kvb`` as (W_uk [kv_rank, H, nope], W_uv [kv_rank, H, v])."""
+    w = p["wkvb"]["kernel"].astype(cfg.dtype).reshape(
+        cfg.kv_rank, cfg.n_head, cfg.nope_dim + cfg.v_dim)
+    return w[..., :cfg.nope_dim], w[..., cfg.nope_dim:]
+
+
+def _ffn(x, p, cfg: MLAMoEConfig, live=None):
+    """x [T, d] -> (x + FFN(RMSNorm(x)), counts int32 [4]): a dense
+    gated FFN, or the held experts' part plus the shared expert."""
+    h = _rmsnorm(x, p["ln2_scale"], cfg.eps, cfg.dtype)
+    if "ffn" in p:
+        return x + moe.gated_ffn(h, p["ffn"], cfg.dtype).astype(x.dtype), \
+            jnp.zeros((4,), jnp.int32)
+    y, counts = moe.dropless_moe(
+        h, p["router"]["kernel"], p["experts"],
+        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
+        n_group=cfg.n_group, topk_group=cfg.topk_group, top_k=cfg.top_k,
+        norm_topk=cfg.norm_topk, route_scale=cfg.route_scale,
+        dtype=cfg.dtype, block_rows=cfg.moe_block_rows, live=live)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + moe.gated_ffn(h, p["shared"], cfg.dtype)
+    return x + y.astype(x.dtype), \
+        jnp.concatenate([jnp.ones((1,), jnp.int32), counts])
+
+
+def _embed(params, tokens):
+    """The residual stream starts, and stays, in float32: every block
+    adds into it unrounded, and only what a matrix multiplies is cast
+    to the compute dtype (a stream held in bfloat16 rounds at every
+    add). What it buys is small: the logits' median distance from the
+    float32 reference 0.020 -> 0.018 of the largest logit (PERF.md,
+    PR 37); what it costs is one float32 row a token."""
+    return params["embed"]["kernel"][tokens].astype(jnp.float32)
+
+
+def _head(x, params, cfg: MLAMoEConfig):
+    x = _rmsnorm(x, params["ln_f_scale"], cfg.eps, cfg.dtype)
+    return lax.dot_general(
+        x.astype(cfg.dtype), params["head"]["kernel"].astype(cfg.dtype),
+        (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def forward(params: Params, tokens: jax.Array, cfg: MLAMoEConfig
+            ) -> jax.Array:
+    """tokens [B, S] -> float32 logits [B, S, rows]: the whole
+    sequence at once, no cache (keys and values materialised, as
+    prefill does)."""
+    B, S = tokens.shape
+    positions = jnp.broadcast_to(jnp.arange(S), (B, S))
+    mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
+    x = _embed(params, tokens)
+    for p in params["layers"]:
+        qn, qr, ent = _latent_qkv(x, p, positions, cfg)
+        att = _attend_materialised(qn, qr, ent, mask, p, cfg)
+        x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
+        x = _ffn(x.reshape(B * S, -1), p, cfg)[0].reshape(B, S, -1)
+    return _head(x, params, cfg)
+
+
+def _attend_materialised(qn, qr, latents, mask, p, cfg: MLAMoEConfig):
+    """Per-head attention of queries [B, S, H, .] over ``latents``
+    [B, K, kv_rank + rope] whose keys and values are materialised:
+    ``mask`` [.., S, K] says which key a query may see. Returns
+    [B, S, H * v]."""
+    B, S = qn.shape[:2]
+    w_uk, w_uv = _wkvb(p, cfg)
+    c = latents[..., :cfg.kv_rank]
+    kr = latents[..., cfg.kv_rank:cfg.latent_dim]
+    kn = jnp.einsum("bkr,rhn->bkhn", c, w_uk,
+                    preferred_element_type=jnp.float32).astype(cfg.dtype)
+    v = jnp.einsum("bkr,rhv->bkhv", c, w_uv,
+                   preferred_element_type=jnp.float32).astype(cfg.dtype)
+    lg = jnp.einsum("bqhn,bkhn->bhqk", qn, kn,
+                    preferred_element_type=jnp.float32) \
+        + jnp.einsum("bqhr,bkr->bhqk", qr, kr,
+                     preferred_element_type=jnp.float32)
+    lg = jnp.where(mask, lg * cfg.attn_scale, -1e30)
+    probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
+    return jnp.einsum("bhqk,bkhv->bqhv", probs, v,
+                      preferred_element_type=jnp.float32
+                      ).astype(cfg.dtype).reshape(B, S, -1)
+
+
+# ----------------------------------------------------------- description
+def cache_spec(cfg: MLAMoEConfig, kv_dtype: str = "fp") -> CacheSpec:
+    """What a token leaves in a page, per layer: ONE latent row in the
+    compute dtype, no head axis: ``kv_rank + rope_dim`` values (576)
+    in a row of ``latent_row`` (640: the lanes they occupy)."""
+    if kv_dtype not in KV_DTYPES:
+        raise ValueError(
+            f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}: "
+            + UNSUPPORTED["int8"])
+    return CacheSpec(cfg.n_layer, (CacheEntry(
+        "latent", "token", (cfg.latent_row,), cfg.dtype),))
+
+
+def kv_bytes_per_page(cfg: MLAMoEConfig, page_size: int,
+                      kv_dtype: str = "fp") -> int:
+    return cache_spec(cfg, kv_dtype).bytes_per_page(page_size)
+
+
+def init_paged_cache(cfg: MLAMoEConfig, slots: int, n_pages: int,
+                     page_size: int, kv_dtype: str = "fp",
+                     tp: int = 1) -> Cache:
+    """The latent page pool ``[L, n_pages, page_size, latent_row]`` and
+    the per-slot ``pos``."""
+    check_tp(cfg, tp)
+    return init_paged_pool(cache_spec(cfg, kv_dtype), slots, n_pages,
+                           page_size)
+
+
+def max_positions(cfg: MLAMoEConfig) -> int:
+    """Rotary positions need no table: the model's declared reach."""
+    return cfg.max_seq
+
+
+def check_tp(cfg: MLAMoEConfig, tp: int):
+    if int(tp) > 1:
+        raise ValueError(f"tp={tp}: " + UNSUPPORTED["tp"])
+    return None
+
+
+def shard_params(params: Params, cfg: MLAMoEConfig, tp: int) -> Params:
+    check_tp(cfg, tp)
+    return params
+
+
+# -------------------------------------------------------------- programs
+def _flat(pool: jax.Array) -> jax.Array:
+    """``[L, n_pages, ...]`` viewed as ``[L * n_pages, ...]``: layer
+    ``l`` addresses page ``p`` at ``l * n_pages + p`` and no layer's
+    pool is sliced out of the stacked one."""
+    return pool.reshape((-1,) + pool.shape[2:])
+
+
+def _at_layer(pages, l: int, n_pages: int):
+    """Page ids of one layer in the flat pool; sentinels (and anything
+    out of bounds) stay out of bounds."""
+    return jnp.where((pages >= 0) & (pages < n_pages),
+                     pages + l * n_pages, jnp.int32(PT_SENTINEL))
+
+
+def prefill_into_slot_paged(params: Params, cache: Cache,
+                            tokens: jax.Array, length: jax.Array,
+                            hist_len: jax.Array, pt_row: jax.Array,
+                            cow_src: jax.Array, slot: jax.Array,
+                            rng: jax.Array, *, cfg: MLAMoEConfig,
+                            page_size: int, temperature: float = 0.0,
+                            kv_dtype: str = "fp"
+                            ) -> Tuple[jax.Array, Cache, jax.Array]:
+    """Prefill one prompt SUFFIX into its pages, with the optional
+    copy-on-write fork and the first token's sample: the contract of
+    :func:`ray_tpu.models.gpt_decode.prefill_into_slot_paged`, on
+    latent pages. Suffix token ``i`` sits at position ``hist_len + i``
+    and attends over the cached prefix (latents read through
+    ``pt_row``, valid below ``hist_len``) and the suffix, causally;
+    keys and values are materialised from the latents per head. Pad
+    positions' writes are dropped."""
+    B, S = tokens.shape
+    ps = page_size
+    L, n_pages = cache["latent"].shape[:2]
+    max_pages = pt_row.shape[0]
+    V = max_pages * ps
+    positions = hist_len + jnp.arange(S)
+    x = _embed(params, tokens)
+
+    # COW fork first, every layer's page at once, in the pool's FLAT
+    # view like every other access (a fork written as ``pool[:, dst]``
+    # made XLA hold the pool in a second layout and copy all of it
+    # twice a prefill: PERF.md, PR 37); no fork copies to an
+    # out-of-bounds page and is dropped.
+    pool = _flat(cache["latent"])
+    layers = jnp.arange(L, dtype=jnp.int32) * n_pages
+    dst = pt_row[jnp.clip(hist_len // ps, 0, max_pages - 1)]
+    dst_w = jnp.where((cow_src < n_pages) & (dst < n_pages),
+                      dst + layers, jnp.int32(PT_SENTINEL))
+    pool = pool.at[dst_w].set(
+        pool[jnp.clip(cow_src, 0, n_pages - 1) + layers], mode="drop")
+
+    ptc = jnp.clip(pt_row, 0, n_pages - 1)
+    seen = jnp.concatenate([
+        jnp.broadcast_to(jnp.arange(V) < hist_len, (S, V)),
+        jnp.tril(jnp.ones((S, S), jnp.bool_))], axis=1)[None, None]
+    live = jnp.arange(S) < length
+    wpos = hist_len + jnp.arange(S)
+    vp = wpos // ps
+    page_w = jnp.where(live & (vp < max_pages),
+                       pt_row[jnp.clip(vp, 0, max_pages - 1)],
+                       jnp.int32(PT_SENTINEL))
+    for l, p in enumerate(params["layers"]):
+        qn, qr, ent = _latent_qkv(x, p, positions[None], cfg)
+        with jax.named_scope("mla.prefill"):
+            hist = pool[ptc + l * n_pages].reshape(1, V, -1)
+            att = _attend_materialised(
+                qn, qr, jnp.concatenate([hist, ent], axis=1), seen, p,
+                cfg)
+        x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
+        x = _ffn(x[0], p, cfg, live)[0][None]
+        pool = pool.at[_at_layer(page_w, l, n_pages), wpos % ps].set(
+            ent[0], mode="drop")
+    x_last = lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, cfg.d_model))
+    token, rng = _sample(_head(x_last, params, cfg)[:, 0], temperature,
+                         rng)
+    pos = lax.dynamic_update_slice(
+        cache["pos"], jnp.reshape(hist_len + length, (1,)), (slot,))
+    return token[0], {"latent": pool.reshape(cache["latent"].shape),
+                      "pos": pos}, rng
+
+
+def _slot_decode_step_paged(params: Params, cache: Cache,
+                            token: jax.Array, active: jax.Array,
+                            pt: jax.Array, cfg: MLAMoEConfig,
+                            page_size: int, kv_dtype: str = "fp",
+                            attn_kernel: str = "gather"):
+    """One masked decode step over the whole slot pool: each active
+    lane writes its latent row at its own position and attends, in the
+    latent space with the up-projections absorbed, over its own pages
+    up to it. Inactive lanes neither write, advance nor route. Returns
+    ``(logits [B, rows], cache', counts)``: the expert layers' counters
+    int32 [4] (:data:`STEP_COUNTERS`)."""
+    B = token.shape[0]
+    ps = page_size
+    max_pages = pt.shape[1]
+    V = max_pages * ps
+    pos = cache["pos"]
+    L, n_pages = cache["latent"].shape[:2]
+    x = _embed(params, token)[:, None]
+    vp = pos // ps
+    page_w = jnp.where(
+        active & (vp < max_pages),
+        jnp.take_along_axis(pt, jnp.clip(vp, 0, max_pages - 1)[:, None],
+                            axis=1)[:, 0], jnp.int32(PT_SENTINEL))
+    ptc = jnp.clip(pt, 0, n_pages - 1)
+    seen = (jnp.arange(V)[None] <= pos[:, None])[:, None]    # [B, 1, V]
+    pool = _flat(cache["latent"])
+    counts = jnp.zeros((4,), jnp.int32)
+    # the step's own scope: a reader tells the decode program's
+    # expert and attention time from prefill's by it
+    with jax.named_scope("decode_step"):
+        for l, p in enumerate(params["layers"]):
+            qn, qr, ent = _latent_qkv(x, p, pos[:, None], cfg)
+            pool = pool.at[_at_layer(page_w, l, n_pages), pos % ps].set(
+                ent[:, 0], mode="drop")
+            w_uk, w_uv = _wkvb(p, cfg)
+            q = jnp.concatenate([
+                jnp.einsum("bhn,rhn->bhr", qn[:, 0], w_uk,
+                           preferred_element_type=jnp.float32
+                           ).astype(cfg.dtype), qr[:, 0],
+                jnp.zeros((B, cfg.n_head, cfg.latent_row
+                           - cfg.latent_dim), cfg.dtype)], axis=-1)
+            with jax.named_scope("mla.attention"):
+                lat = pool[ptc + l * n_pages].reshape(B, V, -1)
+                lg = jnp.einsum("bhc,bvc->bhv", q, lat,
+                                preferred_element_type=jnp.float32)
+                lg = jnp.where(seen, lg * cfg.attn_scale, -1e30)
+                probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
+                # over the whole row, the rotary key's lanes too, and
+                # cut afterwards: slicing the gathered pages first is
+                # another copy of them
+                o = jnp.einsum("bhv,bvr->bhr", probs, lat,
+                               preferred_element_type=jnp.float32
+                               )[..., :cfg.kv_rank].astype(cfg.dtype)
+            att = jnp.einsum("bhr,rhv->bhv", o, w_uv,
+                             preferred_element_type=jnp.float32
+                             ).astype(cfg.dtype).reshape(B, 1, -1)
+            x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
+            y, c = _ffn(x[:, 0], p, cfg, active)
+            x, counts = y[:, None], counts + c
+    cache_out = {"latent": pool.reshape(cache["latent"].shape),
+                 "pos": pos + active.astype(jnp.int32)}
+    return _head(x, params, cfg)[:, 0], cache_out, counts
+
+
+def decode_chunk_slots_paged(params: Params, cache: Cache,
+                             token: jax.Array, rngs: jax.Array,
+                             active: jax.Array, pt: jax.Array, *,
+                             cfg: MLAMoEConfig, k: int, page_size: int,
+                             temperature: float = 0.0,
+                             eos_token: int = -1, kv_dtype: str = "fp",
+                             attn_kernel: str = "gather"):
+    """k fused decode steps over the slot pool in ONE program: the
+    frame of :func:`ray_tpu.models.gpt_decode.decode_chunk_slots_paged`
+    (the page table constant through the chunk, per-slot PRNG lanes,
+    EOS mask-and-carry) around this model's step. Returns ``(tokens
+    [B, k], cache', done [B], rngs', counts int32 [4])``: the expert
+    layers' counters summed over the k steps come out with the tokens,
+    at no launch of their own."""
+    B = token.shape[0]
+    eos = jnp.asarray(eos_token, jnp.int32)
+    done0 = (active & (token == eos)) if eos_token >= 0 \
+        else jnp.zeros((B,), jnp.bool_)
+
+    def body(carry, _):
+        cache, tok, done, keys, counts = carry
+        logits, cache, c = _slot_decode_step_paged(
+            params, cache, tok, active, pt, cfg, page_size, kv_dtype,
+            attn_kernel)
+        nxt, keys = _sample_slots(logits, temperature, keys)
+        if eos_token >= 0:
+            nxt = jnp.where(done, eos, nxt)
+            done = done | (active & (nxt == eos))
+        return (cache, nxt, done, keys, counts + c), nxt
+
+    (cache, _, done, rngs, counts), toks = lax.scan(
+        body, (cache, token, done0, rngs, jnp.zeros((4,), jnp.int32)),
+        None, length=k)
+    return jnp.moveaxis(toks, 0, 1), cache, done, rngs, counts
+
+
+# rtlint: program-budget: len(prompt_buckets)
+@_knob_cache
+def jit_prefill_into_slot_paged(cfg: MLAMoEConfig, page_size: int,
+                                temperature: float = 0.0,
+                                kv_dtype: str = "fp", tp: int = 1):
+    """Jitted :func:`prefill_into_slot_paged`: one compiled program per
+    SUFFIX bucket per (cfg, page_size, temperature) key; the prefix
+    hit's depth, the page table and the COW source are traced. The
+    pool is donated."""
+    check_tp(cfg, tp)
+    cache_spec(cfg, kv_dtype)
+    return jax.jit(_program(prefill_into_slot_paged, cfg=cfg,
+                            page_size=page_size,
+                            temperature=temperature, kv_dtype=kv_dtype),
+                   donate_argnums=(1,))
+
+
+# rtlint: program-budget: 1
+@_knob_cache
+def jit_decode_chunk_slots_paged(cfg: MLAMoEConfig, k: int,
+                                 page_size: int,
+                                 temperature: float = 0.0,
+                                 eos_token: int = -1,
+                                 kv_dtype: str = "fp",
+                                 attn_kernel: str = "gather",
+                                 tp: int = 1):
+    """Jitted :func:`decode_chunk_slots_paged`: ONE program per (pool
+    shape, k, page_size); the page table is data. Pool donated."""
+    check_tp(cfg, tp)
+    cache_spec(cfg, kv_dtype)
+    if attn_kernel not in ATTN_KERNELS:
+        raise ValueError(
+            f"attn_kernel must be one of {ATTN_KERNELS}, got "
+            f"{attn_kernel!r}")
+    return jax.jit(_program(decode_chunk_slots_paged, cfg=cfg, k=k,
+                            page_size=page_size,
+                            temperature=temperature,
+                            eos_token=eos_token, kv_dtype=kv_dtype,
+                            attn_kernel=attn_kernel),
+                   donate_argnums=(1,))

@@ -110,3 +110,148 @@ def moe_ffn(x: jax.Array, router_kernel: jax.Array, w_up: jax.Array,
     y = jnp.einsum("tec,ecd->td", combine.astype(dtype), ye,
                    preferred_element_type=jnp.float32).astype(dtype)
     return y.reshape(B, S, d), aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------- dropless
+# The serving-side expert layer (ROADMAP M1, D9): nothing is dropped,
+# so a token's result does not depend on who shares its batch, and the
+# layer is TOLD which of the routed experts it holds (expert
+# parallelism's share of one chip): it routes over all ``n_routed``
+# experts and computes its own experts' part for the tokens routed to
+# them. What the absent experts would add is another chip's to compute
+# and is not stood in for.
+
+def group_limited_top_k(scores: jax.Array, n_group: int, topk_group: int,
+                        top_k: int) -> Tuple[jax.Array, jax.Array]:
+    """scores [T, E] (each in (0, 1]) -> (ids [T, k] int32, their
+    scores [T, k]), best first. The E experts lie in ``n_group``
+    contiguous groups; a group's score is the sum of its two highest
+    scores, the ``topk_group`` best groups stay, and the ``top_k``
+    highest scores among their experts are chosen. ``topk_group ==
+    n_group`` is a plain top k."""
+    T, E = scores.shape
+    if topk_group < n_group:
+        per = E // n_group
+        best2 = lax.top_k(scores.reshape(T, n_group, per), 2)[0]
+        _, keep = lax.top_k(best2.sum(-1), topk_group)      # [T, kg]
+        kept = jnp.any(keep[:, :, None] == jnp.arange(n_group), axis=1)
+        scores = jnp.where(jnp.repeat(kept, per, axis=1), scores, -1.0)
+    chosen, ids = lax.top_k(scores, top_k)
+    return ids.astype(jnp.int32), chosen
+
+
+def route_sigmoid(x: jax.Array, router_kernel: jax.Array, *, n_group: int,
+                  topk_group: int, top_k: int, norm_topk: bool,
+                  route_scale: float, dtype
+                  ) -> Tuple[jax.Array, jax.Array]:
+    """x [T, d] -> (ids [T, k], weights [T, k] float32): sigmoid scores
+    over ALL routed experts (the router keeps its published width),
+    group-limited selection, the chosen scores normalised to sum to one
+    where ``norm_topk``, times ``route_scale``."""
+    logits = lax.dot_general(x.astype(dtype), router_kernel.astype(dtype),
+                             (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    ids, s = group_limited_top_k(jax.nn.sigmoid(logits), n_group,
+                                 topk_group, top_k)
+    if norm_topk:
+        s = s / (jnp.sum(s, axis=-1, keepdims=True) + 1e-20)
+    return ids, s * route_scale
+
+
+def gated_ffn(x: jax.Array, p, dtype) -> jax.Array:
+    """``down(silu(x gate) * (x up))``, matmuls in ``dtype`` with
+    float32 accumulation."""
+    def mm(a, w):
+        return lax.dot_general(a.astype(dtype), w.astype(dtype),
+                               (((a.ndim - 1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+    h = (jax.nn.silu(mm(x, p["gate"])) * mm(x, p["up"])).astype(dtype)
+    return mm(h, p["down"]).astype(dtype)
+
+
+def dropless_moe(x: jax.Array, router_kernel: jax.Array, experts, *,
+                 experts_held: int, expert_offset: int, n_group: int,
+                 topk_group: int, top_k: int, norm_topk: bool,
+                 route_scale: float, dtype, block_rows: int = 32,
+                 live: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of a routed layer, dropless.
+
+    ``x`` [T, d]; ``router_kernel`` [d, n_routed]; ``experts`` holds
+    ``gate``/``up`` [held, d, f] and ``down`` [held, f, d] for experts
+    ``expert_offset .. expert_offset + experts_held`` of the
+    ``n_routed``. Returns ``(y [T, d] in dtype, counts int32 [3])``:
+    ``y[t] = sum over the experts e that t chose AND that are held of
+    w[t, e] * FFN_e(x[t])``; ``counts`` = (held experts with at least
+    one token, token-choices that landed on held experts, the fullest
+    held expert's tokens). ``live`` [T] bool masks rows that are no
+    tokens (padding, idle lanes): they are routed nowhere and counted
+    nowhere.
+
+    One formulation for a prefill's hundreds of rows and a decode
+    step's one row a lane. The token-choices that land here are sorted
+    by expert and cut into blocks of ``block_rows`` rows of ONE expert
+    each (a group's last block is padded); a loop over the blocks THAT
+    EXIST multiplies each by its expert's three matrices. So an expert
+    nobody chose is never read, every choice is computed whatever the
+    load (no capacity, nothing dropped), and a row's result is the
+    same rows-of-a-matmul arithmetic whoever shares the batch: each
+    token then sums its own choices in its own order of choice."""
+    T, d = x.shape
+    k, held, bm = top_k, experts_held, block_rows
+    n_max = -(-T * k // bm) + held          # blocks there can be at most
+    with jax.named_scope("moe.route"):
+        ids, w = route_sigmoid(x, router_kernel, n_group=n_group,
+                               topk_group=topk_group, top_k=k,
+                               norm_topk=norm_topk,
+                               route_scale=route_scale, dtype=dtype)
+        local = ids - expert_offset
+        here = (local >= 0) & (local < held)
+        if live is not None:
+            here = here & live[:, None]
+        key = jnp.where(here, local, held).reshape(-1)       # [T * k]
+        sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0,
+                        dtype=jnp.int32)                     # [held]
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        rank = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))              # place sorted
+        start = jnp.cumsum(sizes) - sizes                    # of a group
+        blocks = -(-sizes // bm)
+        first = jnp.cumsum(blocks) - blocks                  # block of a group
+        n_blocks = jnp.sum(blocks)
+        # which expert a block is of, and the sorted places of its rows
+        blk = jnp.arange(n_max, dtype=jnp.int32)
+        owner = jnp.clip(jnp.searchsorted(jnp.cumsum(blocks), blk,
+                                          side="right"), 0, held - 1
+                         ).astype(jnp.int32)
+        place = start[owner][:, None] + (blk - first[owner])[:, None] * bm \
+            + jnp.arange(bm, dtype=jnp.int32)[None]          # [n_max, bm]
+        filled = (place < (start + sizes)[owner][:, None]) \
+            & (blk < n_blocks)[:, None]
+        tok = order[jnp.clip(place, 0, T * k - 1)] // k
+        xs = jnp.where(filled[..., None], x.astype(dtype)[tok], 0)
+    with jax.named_scope("moe.experts"):
+        def mm(a, m, e):
+            return lax.dot_general(a, m[e].astype(dtype),
+                                   (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+        def one_block(j, out):
+            e, xb = owner[j], xs[j]
+            h = (jax.nn.silu(mm(xb, experts["gate"], e))
+                 * mm(xb, experts["up"], e)).astype(dtype)
+            return out.at[j].set(mm(h, experts["down"], e).astype(dtype))
+
+        out = lax.fori_loop(0, n_blocks, one_block,
+                            jnp.zeros((n_max, bm, d), dtype))
+    with jax.named_scope("moe.route"):
+        # combine: where a token's choice lies among the blocks
+        loc = jnp.clip(local, 0, held - 1)
+        r = rank.reshape(T, k) - start[loc]
+        at = jnp.where(here, (first[loc] + r // bm) * bm + r % bm, 0)
+        part = out.reshape(n_max * bm, d)[at].astype(jnp.float32)
+        y = jnp.sum(jnp.where(here, w, 0.0)[..., None] * part, axis=1)
+        counts = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32),
+                            jnp.sum(sizes), jnp.max(sizes)])
+    return y.astype(dtype), counts

@@ -1,0 +1,117 @@
+"""What the serving engine asks of a model: its DESCRIPTION.
+
+:class:`~ray_tpu.serve.engine.DecodeEngine` serves any decoder whose
+config object answers ``cfg.decode_programs()`` with a module (or
+namespace) of the paged slot-pool programs: the engine binds no model
+module by name. Two decoders answer today: the GPT-2 block
+(:mod:`ray_tpu.models.gpt_decode`: a page holds keys and values per
+head) and the latent-attention expert decoder
+(:mod:`ray_tpu.models.mla_moe`: a page holds one 576-wide latent a
+token, no head axis).
+
+A description provides, under these names:
+
+``cache_spec(cfg, kv_dtype) -> CacheSpec``
+    what one token leaves in a page, per layer (below). The ONE place
+    the pool's shapes come from: :func:`init_paged_pool`,
+    :func:`CacheSpec.bytes_per_page`, the engine's handoff shape
+    checks and ``stats()["kv_bytes_per_token"]`` all read it.
+``init_paged_cache``, ``kv_bytes_per_page``, ``shard_params``,
+``check_tp``
+    the pool, its page cost, the weights' placement and the (cfg, tp)
+    validation.
+``jit_prefill_into_slot_paged``, ``jit_decode_chunk_slots_paged``
+    the two programs every model has, under the names a device trace
+    shows (``jit_prefill_into_slot_paged(…``).
+``jit_verify_chunk_slots_paged``, ``jit_export_slot_kv_paged``,
+``jit_import_slot_kv_paged``
+    speculative verify and the KV handoff, or absent.
+``max_positions(cfg)``
+    the longest sequence the model can place (a learned table's rows;
+    a rotary model's declared reach).
+``KV_DTYPES``, ``ATTN_KERNELS``
+    the pool storage types and decode attention paths it has.
+``UNSUPPORTED``
+    ``{engine capability: reason}`` for what this model does not get
+    (``"int8"``, ``"tp"``, ``"spec_decode"``, ``"roles"``): the engine
+    raises the reason at construction.
+``STEP_COUNTERS``
+    names of the int32 counters the chunk program returns as a fifth
+    output, summed over the chunk's steps (``()``: four outputs); the
+    engine adds them into ``stats()`` under those names.
+"""
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Dict, Tuple
+
+import jax.numpy as jnp
+
+#: Page-table padding value. Positive and far beyond any real pool size,
+#: so a sentinel is out-of-bounds for scatter (write DROPPED, never
+#: clamped into someone else's page) while reads clip it to a real page
+#: whose garbage the attention mask hides. Never use a negative
+#: sentinel: traced negative indices WRAP in jnp indexing.
+PT_SENTINEL = 2 ** 30
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheEntry:
+    """One array of the pool: ``per`` ``"token"`` (a row for each of a
+    page's positions: ``[L, n_pages, page_size, *shape]``) or
+    ``"page"`` (one row a page, e.g. a quantisation scale:
+    ``[L, n_pages, *shape]``)."""
+    name: str
+    per: str
+    shape: Tuple[int, ...]
+    dtype: Any
+
+    def bytes_per_page(self, page_size: int) -> int:
+        n = page_size if self.per == "token" else 1
+        for s in self.shape:
+            n *= s
+        return n * jnp.dtype(self.dtype).itemsize
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """Per layer, what a token leaves in a page: the entries' trailing
+    shapes and dtypes, and how many layers keep one."""
+    n_layer: int
+    entries: Tuple[CacheEntry, ...]
+
+    def entry(self, name: str) -> CacheEntry:
+        return next(e for e in self.entries if e.name == name)
+
+    def bytes_per_page(self, page_size: int) -> int:
+        """HBM bytes ONE physical page costs across all layers — the
+        unit the engine's page budget is denominated in."""
+        return self.n_layer * sum(e.bytes_per_page(page_size)
+                                  for e in self.entries)
+
+    def token_shape(self, name: str, tokens: int) -> Tuple[int, ...]:
+        """``[L, tokens, *shape]``: a per-token entry laid out over a
+        contiguous run of tokens (the handoff's ship order)."""
+        return (self.n_layer, tokens) + self.entry(name).shape
+
+
+def init_paged_pool(spec: CacheSpec, slots: int, n_pages: int,
+                    page_size: int) -> Dict[str, Any]:
+    """Zeroed pool arrays for ``spec`` plus the per-slot ``pos``."""
+    cache = {}
+    for e in spec.entries:
+        lead = (spec.n_layer, n_pages) \
+            + ((page_size,) if e.per == "token" else ())
+        cache[e.name] = jnp.zeros(lead + e.shape, e.dtype)
+    cache["pos"] = jnp.zeros((slots,), jnp.int32)
+    return cache
+
+
+def decode_programs(cfg):
+    """The description of ``cfg``'s model (module docstring)."""
+    try:
+        return cfg.decode_programs()
+    except AttributeError:
+        raise TypeError(
+            f"{type(cfg).__name__} does not describe a decoder the "
+            f"engine can serve: it has no decode_programs()") from None

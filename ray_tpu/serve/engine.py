@@ -457,23 +457,28 @@ class DecodeEngine:
                  role: str = "both", handoff_ttl_s: float = 30.0,
                  attn_kernel: str = "gather", kv_dtype: str = "fp",
                  tp: int = 1):
-        from ..models import gpt_decode
+        from ..models import serving
         from .draft import make_drafter
         from .handoff import LeaseTable
 
         self.params = params
         self.cfg = cfg
+        # The model's DESCRIPTION (models/serving.py): the paged
+        # programs, the cache spec and what the model does not take
+        # all come from the config object; no model module is bound
+        # here by name.
+        model = self._model = serving.decode_programs(cfg)
         self.slots = int(slots)
         self.chunk = int(chunk)
-        self.max_len = int(max_len or cfg.max_seq)
+        self.max_len = int(max_len or model.max_positions(cfg))
         self.temperature = float(temperature)
         self.eos_token = int(eos_token)
         self.deployment = deployment or "engine"
         if self.slots < 1 or self.chunk < 1:
             raise ValueError("slots and chunk must be >= 1")
-        if self.max_len > cfg.max_seq:
+        if self.max_len > model.max_positions(cfg):
             raise ValueError(f"max_len {self.max_len} exceeds model "
-                             f"max_seq {cfg.max_seq}")
+                             f"max_seq {model.max_positions(cfg)}")
         buckets = sorted(set(int(b) for b in (
             prompt_buckets or default_prompt_buckets(self.max_len))))
         if not buckets or buckets[0] < 1:
@@ -483,7 +488,6 @@ class DecodeEngine:
                 f"largest prompt bucket {buckets[-1]} exceeds cache "
                 f"length {self.max_len}")
         self.prompt_buckets = buckets
-        self._gd = gpt_decode
         # ---- disaggregation role (ISSUE 14): "prefill" engines only
         # export handoffs (no slot-pool steady state), "decode" engines
         # additionally import them; "both" serves every path. The lease
@@ -492,6 +496,11 @@ class DecodeEngine:
         if role not in ("both", "prefill", "decode"):
             raise ValueError(f"unknown engine role {role!r}; expected "
                              f"'prefill', 'decode', or 'both'")
+        self._refuse("roles", role != "both", f"role={role!r}")
+        self._refuse("spec_decode", spec_decode is not None,
+                     f"spec_decode={spec_decode!r}")
+        self._refuse("int8", kv_dtype == "int8", "kv_dtype='int8'")
+        self._refuse("tp", int(tp) > 1, f"tp={tp}")
         self.role = role
         self._leases = LeaseTable(ttl_s=float(handoff_ttl_s))
         # ---- speculative decoding (ISSUE 9): an optional drafter turns
@@ -529,14 +538,14 @@ class DecodeEngine:
         # are ENGINE-STATIC knobs baked into the compiled programs at
         # pool build — never retrace triggers. Stored before _build_pool
         # (which reads them) and re-read verbatim on driver restart.
-        if attn_kernel not in gpt_decode.ATTN_KERNELS:
+        if attn_kernel not in model.ATTN_KERNELS:
             raise ValueError(
                 f"unknown attn_kernel {attn_kernel!r}; expected one of "
-                f"{gpt_decode.ATTN_KERNELS}")
-        if kv_dtype not in gpt_decode.KV_DTYPES:
+                f"{model.ATTN_KERNELS}")
+        if kv_dtype not in model.KV_DTYPES:
             raise ValueError(
                 f"unknown kv_dtype {kv_dtype!r}; expected one of "
-                f"{gpt_decode.KV_DTYPES}")
+                f"{model.KV_DTYPES}")
         if not paged:
             raise ValueError(
                 "paged=False: the flat slot pool is gone; the engine "
@@ -550,7 +559,7 @@ class DecodeEngine:
         # fewer than N devices are visible — on CPU, force host devices
         # via XLA_FLAGS before importing jax.
         self.tp = int(tp)
-        gpt_decode._tp_mesh(cfg, self.tp)
+        model.check_tp(cfg, self.tp)
         # Guards the put-vs-final-drain race: once _fail_all flips
         # _draining under this lock, no new submission can land in a
         # queue nobody will ever read again. Created BEFORE the pool so
@@ -597,6 +606,9 @@ class DecodeEngine:
                        # tokens were read while a lane stayed occupied
                        "admission_wait_ns_sum": 0, "prefill_ns_sum": 0,
                        "prefill_tokens_sum": 0, "decode_gap_ns_sum": 0}
+        # Counters the model's chunk program returns with its tokens
+        # (an expert layer's load), summed per dispatch.
+        self._stats.update(dict.fromkeys(model.STEP_COUNTERS, 0))
         # What the driver thread is doing, by phase (self time, ns):
         # written by the driver alone through its PhaseClock, read
         # racily by stats(). Outlives driver restarts.
@@ -651,13 +663,12 @@ class DecodeEngine:
         (rtlint RT101 real finding: the restart path used to swap
         ``_pool``/``_prefix``/``_cache`` under only ``_fail_lock``,
         racing a concurrent ``ensure_paging`` config push)."""
-        gpt_decode = self._gd
         cfg = self.cfg
         # The dispatch-side weights: placed once per pool build (a
         # NamedSharding scatter when tp > 1, the raw host pytree when
         # tp == 1 — shard_params is an identity there). The drafter
         # keeps ``self.params``: it runs its own single-chip programs.
-        self._params_dev = gpt_decode.shard_params(
+        self._params_dev = self._model.shard_params(
             self.params, cfg, self.tp)
         self.page_size = int(page_size)
         if self.page_size < 1:
@@ -669,8 +680,8 @@ class DecodeEngine:
         # bytes, so the same budget holds ~2x the pages (the ISSUE 16
         # sizing fix: counting pages in positions instead of bytes left
         # half an int8 engine's HBM budget unused).
-        fp_bytes = gpt_decode.kv_bytes_per_page(cfg, self.page_size)
-        kv_bytes = gpt_decode.kv_bytes_per_page(cfg, self.page_size,
+        fp_bytes = self._model.kv_bytes_per_page(cfg, self.page_size)
+        kv_bytes = self._model.kv_bytes_per_page(cfg, self.page_size,
                                                 self.kv_dtype)
         self.n_pages = int(n_pages) or \
             (self.slots * self.max_pages * fp_bytes) // kv_bytes
@@ -682,18 +693,20 @@ class DecodeEngine:
         self._prefix = _PrefixCache(self._pool, self.page_size) \
             if prefix_cache else None
         self._pt = np.full((self.slots, self.max_pages),
-                           gpt_decode.PT_SENTINEL, np.int32)
-        self._prefill = gpt_decode.jit_prefill_into_slot_paged(
+                           self._model.PT_SENTINEL, np.int32)
+        self._prefill = self._model.jit_prefill_into_slot_paged(
             cfg, self.page_size, self.temperature, self.kv_dtype,
             self.tp)
-        self._step = gpt_decode.jit_decode_chunk_slots_paged(
+        self._step = self._model.jit_decode_chunk_slots_paged(
             cfg, self.chunk, self.page_size, self.temperature,
             self.eos_token, self.kv_dtype, self.attn_kernel, self.tp)
-        self._export = gpt_decode.jit_export_slot_kv_paged(
-            cfg, self.page_size, self.kv_dtype, self.tp)
-        self._import = gpt_decode.jit_import_slot_kv_paged(
-            cfg, self.page_size, self.kv_dtype, self.tp)
-        self._cache = gpt_decode.init_paged_cache(
+        self._export = self._import = None
+        if "roles" not in self._model.UNSUPPORTED:
+            self._export = self._model.jit_export_slot_kv_paged(
+                cfg, self.page_size, self.kv_dtype, self.tp)
+            self._import = self._model.jit_import_slot_kv_paged(
+                cfg, self.page_size, self.kv_dtype, self.tp)
+        self._cache = self._model.init_paged_cache(
             cfg, self.slots, self.n_pages, self.page_size,
             self.kv_dtype, self.tp)
         self._bind_verify()
@@ -708,9 +721,19 @@ class DecodeEngine:
         if self._drafter is None:
             self._verify = None
         else:
-            self._verify = self._gd.jit_verify_chunk_slots_paged(
+            self._verify = self._model.jit_verify_chunk_slots_paged(
                 self.cfg, self.draft_k, self.page_size,
                 self.temperature, self.kv_dtype, self.tp)
+
+    def _refuse(self, capability: str, asked: bool, what: str):
+        """Raise, with the model's own reason, where ``asked`` is for
+        something the served model's description lists under
+        ``UNSUPPORTED``."""
+        reason = self._model.UNSUPPORTED.get(capability)
+        if asked and reason is not None:
+            raise ValueError(
+                f"{type(self.cfg).__name__} cannot be served with "
+                f"{what}: {reason}")
 
     def ensure_paging(self, page_size: Optional[int] = None,
                       prefix_cache: Optional[bool] = None,
@@ -731,14 +754,15 @@ class DecodeEngine:
         if want_ps is not None and want_ps < 1:
             raise ValueError("page_size must be >= 1")
         if attn_kernel is not None and \
-                attn_kernel not in self._gd.ATTN_KERNELS:
+                attn_kernel not in self._model.ATTN_KERNELS:
             raise ValueError(
                 f"unknown attn_kernel {attn_kernel!r}; expected one of "
-                f"{self._gd.ATTN_KERNELS}")
-        if kv_dtype is not None and kv_dtype not in self._gd.KV_DTYPES:
+                f"{self._model.ATTN_KERNELS}")
+        self._refuse("int8", kv_dtype == "int8", "kv_dtype='int8'")
+        if kv_dtype is not None and kv_dtype not in self._model.KV_DTYPES:
             raise ValueError(
                 f"unknown kv_dtype {kv_dtype!r}; expected one of "
-                f"{self._gd.KV_DTYPES}")
+                f"{self._model.KV_DTYPES}")
         with self._admit_lock:
             knob_change = (
                 (attn_kernel is not None and
@@ -789,6 +813,8 @@ class DecodeEngine:
 
         if draft_k is not None and int(draft_k) < 1:
             raise ValueError("draft_k must be >= 1")
+        self._refuse("spec_decode", spec_decode is not None,
+                     f"spec_decode={spec_decode!r}")
         with self._admit_lock:
             want_k = int(draft_k) if draft_k is not None else self.draft_k
             cur = self._drafter
@@ -840,6 +866,7 @@ class DecodeEngine:
         if role is not None and role not in ("both", "prefill",
                                              "decode"):
             raise ValueError(f"unknown engine role {role!r}")
+        self._refuse("roles", role not in (None, "both"), f"role={role!r}")
         with self._admit_lock:
             if role is not None and role != self.role:
                 with self._stats_lock:
@@ -879,7 +906,7 @@ class DecodeEngine:
                     f"live engine; construct it with tp= or apply the "
                     f"config before traffic")
             # Validate (divisibility + visible devices) BEFORE mutating.
-            self._gd._tp_mesh(self.cfg, want)
+            self._model.check_tp(self.cfg, want)
             self.tp = want
             self._build_pool(self.page_size, self.n_pages,
                              self._prefix is not None)
@@ -1135,8 +1162,8 @@ class DecodeEngine:
                 raise HandoffError(
                     f"shipped pos {payload['pos']} + max_new "
                     f"{max_new} exceeds cache length {self.max_len}")
-            want = (self.cfg.n_layer, int(payload["pos"]),
-                    self.cfg.n_head, self.cfg.head_dim)
+            want = self._model.cache_spec(self.cfg, self.kv_dtype) \
+                .token_shape("k", int(payload["pos"]))
             if tuple(payload["k"].shape) != want \
                     or tuple(payload["v"].shape) != want:
                 raise HandoffError(
@@ -1240,7 +1267,7 @@ class DecodeEngine:
     def _run_warm_up(self, req: dict):
         import jax
 
-        gd = self._gd
+        gd = self._model
         secs = {}
 
         def timed(name, fn, *args):
@@ -1271,7 +1298,7 @@ class DecodeEngine:
                 mode = "compiled" if compiled_by_mosaic(
                     self._step.lower(*step_args).as_text()) \
                     else "interpret"
-            _, self._cache, _, _ = timed("chunk", self._step, *step_args)
+            self._cache = timed("chunk", self._step, *step_args)[1]
             if self._verify is not None:
                 _, _, self._cache, _ = timed(
                     "verify", self._verify, self._params_dev, self._cache,
@@ -1567,8 +1594,9 @@ class DecodeEngine:
             out["prefix_evictions"] = self._prefix.evictions
         out["attn_kernel"] = self.attn_kernel
         out["kv_dtype"] = self.kv_dtype
-        out["kv_bytes_per_token"] = self._gd.kv_bytes_per_page(
-            self.cfg, self.page_size, self.kv_dtype) / self.page_size
+        out["kv_bytes_per_token"] = self._model.cache_spec(
+            self.cfg, self.kv_dtype).bytes_per_page(self.page_size) \
+            / self.page_size
         return out
 
     def _count(self, **deltas):
@@ -1713,7 +1741,7 @@ class DecodeEngine:
         st = self._state[i]
         if st is not None and st.pages:
             self._pool.unref(st.pages)
-            self._pt[i, :] = self._gd.PT_SENTINEL
+            self._pt[i, :] = self._model.PT_SENTINEL
         if st is not None and self._drafter is not None:
             self._drafter.free(i)
         self._state[i] = None
@@ -1921,7 +1949,7 @@ class DecodeEngine:
             req.lane.q.put((_STREAM_END, None))
             self._count(completed=1)
             self._pool.unref(pages)
-            self._pt[slot, :] = self._gd.PT_SENTINEL
+            self._pt[slot, :] = self._model.PT_SENTINEL
             self._observe_pages(sm)
             return True
         self._state[slot] = _Slot(
@@ -1947,7 +1975,7 @@ class DecodeEngine:
         unavailable even after LRU eviction — or when a supervisor
         restart retired this driver's epoch while its prefill ran (the
         stale result must not touch the rebuilt pool)."""
-        gd = self._gd
+        gd = self._model
         ps = self.page_size
         # ONE pool/prefix snapshot for the whole admission: a supervisor
         # restart swaps self._pool wholesale, and page accounting split
@@ -2076,7 +2104,7 @@ class DecodeEngine:
             vs = np.asarray(vs_dev)[:, :n_cover].copy()
         rng = np.asarray(self._rngs[slot], np.uint32).copy()
         self._pool.unref(pages)
-        self._pt[slot, :] = self._gd.PT_SENTINEL
+        self._pt[slot, :] = self._model.PT_SENTINEL
         payload = _ho.build_payload(k=k, v=v, prompt=req.prompt, pos=P,
                                     first=first, rng=rng, seed=req.seed,
                                     max_new=req.max_new, ks=ks, vs=vs,
@@ -2123,9 +2151,9 @@ class DecodeEngine:
         payload, so the replay is a re-import, not a re-prefill."""
         payload = req.handoff["payload"]
         P = int(payload["pos"])
-        gd = self._gd
-        L = self.cfg.n_layer
-        H, hd = self.cfg.n_head, self.cfg.head_dim
+        gd = self._model
+        L, _, H, hd = gd.cache_spec(self.cfg, self.kv_dtype).token_shape(
+            "k", 0)
         dt = payload["k"].dtype
         req.granted_ns = time.monotonic_ns()
         ps = self.page_size
@@ -2308,7 +2336,7 @@ class DecodeEngine:
         n_active = int(active.sum())
         with self._phases.phase("decode", slots_active=n_active) as ph:
             self._note_decode_gap(ph.t0)
-            toks, cache, _done, rngs = self._step(
+            toks, cache, _done, rngs, *more = self._step(
                 self._params_dev, self._cache, self._token,
                 self._rngs, active, self._pt)
             # ONE transfer per fused k-step chunk — the engine's
@@ -2317,6 +2345,9 @@ class DecodeEngine:
             toks_np = np.asarray(toks)
             # rtlint: sync-ok=chunk-boundary PRNG lanes ride the same sync
             rngs_np = np.asarray(rngs)
+            # rtlint: sync-ok=chunk-boundary the model's counters, same sync
+            counted = [int(c) for c in np.asarray(more[0])] \
+                if more else []
         if epoch >= 0 and epoch != self._epoch:
             return                    # stale driver: drop on the floor
         with self._phases.phase("deliver", slots_active=n_active):
@@ -2327,7 +2358,8 @@ class DecodeEngine:
                 labels={"deployment": self.deployment})
             sm["engine_dispatches"].inc(
                 labels={"deployment": self.deployment})
-            self._count(dispatches=1, occupancy_sum=n_active / self.slots)
+            self._count(dispatches=1, occupancy_sum=n_active / self.slots,
+                        **dict(zip(self._model.STEP_COUNTERS, counted)))
             # Rate-capped: under a dispatch-per-token storm the cap drops
             # the excess (counted) instead of flooding the ring.
             _driver_emit("engine.dispatch", epoch=self._epoch,

@@ -15,8 +15,12 @@ rtlint analysis, and resolves exactly the idioms this repo uses:
   and its bases (bases matched by terminal name across the analyzed
   set, first definition wins — the same convention RT105 uses);
 - **module aliases on self**: ``self._gd.f(...)`` where some method
-  assigned ``self._gd = <imported module>`` (the engine's
-  ``self._gd = gpt_decode`` idiom);
+  assigned ``self._gd = <imported module>`` (the drafter's
+  ``self._gd = gpt_decode`` idiom), and ``self._model.f(...)`` where
+  it assigned ``self._model = <x>.decode_programs(cfg)``: the served
+  model's DESCRIPTION (``models/serving.py``), which every model
+  answers with a module of the same factory names and budgets, so the
+  call resolves against the first of :data:`DESCRIPTION_MODULES`;
 - **constructors**: ``Cls(...)`` → ``Cls.__init__``;
 - **driver registration**: ``threading.Thread(target=self._run)`` (and
   any ``*Thread(target=...)``) becomes an edge of ``kind="thread"`` —
@@ -46,6 +50,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 #: definition in annotations so rtflow and rtsan can never disagree.
 from .annotations import LOCKISH_RE
 from .core import Module
+
+
+#: Modules a config object's ``decode_programs()`` may answer with, in
+#: the order a ``self.X = <...>.decode_programs(cfg)`` alias tries them.
+DESCRIPTION_MODULES = ("models/gpt_decode.py", "models/mla_moe.py")
 
 
 def self_attr(node) -> Optional[str]:
@@ -254,6 +263,13 @@ class CallGraph:
                         ent = table.get(value.id)
                         if ent and ent[0] == "mod":
                             cn.module_aliases[attr] = ent[1]
+                    elif isinstance(value, ast.Call) and terminal_name(
+                            value.func) == "decode_programs":
+                        known = set(self._by_dotted.values())
+                        for rel in DESCRIPTION_MODULES:
+                            if rel in known:
+                                cn.module_aliases[attr] = rel
+                                break
 
     # --------------------------------------------------------- resolution
     def _module_func(self, relpath: str, name: str) -> Optional[str]:

@@ -795,17 +795,19 @@ class Sanitizer:
 
     def _wrap_jit_factories(self):
         try:
-            from ray_tpu.models import gpt_decode
+            from ray_tpu.models import gpt_decode, mla_moe
         except Exception:  # noqa: BLE001 - gated: no device surface here
             return
-        for name in dir(gpt_decode):
-            if not name.startswith("jit_"):
-                continue
-            orig = getattr(gpt_decode, name)
-            if not callable(orig) or getattr(orig, "__rtsan__", False):
-                continue
-            setattr(gpt_decode, name, _DispatchFactory(orig, name, self))
-            self._factory_patches.append((gpt_decode, name, orig))
+        # every model description the engine may be handed
+        for module in (gpt_decode, mla_moe):
+            for name in dir(module):
+                if not name.startswith("jit_"):
+                    continue
+                orig = getattr(module, name)
+                if not callable(orig) or getattr(orig, "__rtsan__", False):
+                    continue
+                setattr(module, name, _DispatchFactory(orig, name, self))
+                self._factory_patches.append((module, name, orig))
 
     # -------------------------------------------------------------- lifecycle
     def enable(self, modules=DEFAULT_MODULES, active: bool = True,
