@@ -33,10 +33,17 @@ zeros up to the 128-lane tile they occupy anyway:
   ABSORBS the up-projections: ``q~ = q_n W_uk`` (``W_uk`` the ``k_n``
   columns of ``W_kvb``), ``scores = q~ . c + q_r . k_r``, ``o = ((p c)
   W_uv) W_o``: all 64 query heads of a lane meet ONE 576-wide key a
-  token, a matrix product over the gathered latent pages. It is plain
-  XLA over pages gathered through the page table, not a variant of the
-  Pallas kernel of :mod:`gpt_decode`: that kernel's DMA moves whole
-  (8/16, 128) tiles and 576 is 4.5 lane tiles (PERF.md section 7).
+  token, a matrix product over the lane's latent pages. ONE path, named
+  ``"gather"`` (:data:`ATTN_KERNELS`), in two bodies chosen by what the
+  program can see (:func:`decode_attention_fused`): a Pallas kernel
+  (:func:`_latent_attention_pallas`) that copies a lane's LIVE pages
+  from the pool once, by DMA, and multiplies them on the MXU, wherever
+  Mosaic can address a page (a row is 640 = five whole 128-lane tiles;
+  compiled for a TPU a page must also be whole sublane tiles, 16 rows
+  of bfloat16; off the TPU the kernel is interpreted); else plain XLA
+  over each lane's whole virtual sequence gathered through the page
+  table (:func:`_latent_attention_gather`, also the tests' oracle). The
+  two agree to :data:`ATTN_KERNEL_ULPS`.
 
 Rotary positions use YaRN frequencies (:func:`yarn_inv_freq`): each of
 the ``rope_dim / 2`` frequencies blends ``theta^(-2i/dim)`` with the
@@ -101,6 +108,26 @@ UNSUPPORTED = {
 #: the fullest held expert's tokens.
 STEP_COUNTERS = ("moe_steps", "moe_experts_touched_sum",
                  "moe_tokens_here_sum", "moe_expert_peak_sum")
+#: The written bound on |kernel - gather| of decode's latent attention,
+#: in ulps (2**-8, relative) of the LARGEST output of the call: both
+#: bodies round every probability once to the compute dtype, the XLA
+#: body after the division by the sum and the kernel before it, sum
+#: p . c in float32 and round the result once (as
+#: :data:`ray_tpu.models.gpt_decode.ATTN_KERNEL_ULPS`, the same
+#: difference). Read 0.5-1.5 over ``tests/test_mla_attention_kernel.py``
+#: on the CPU.
+ATTN_KERNEL_ULPS = 4
+#: Tokens the kernel multiplies at once (``512 // page_size`` pages;
+#: one page where a page is larger). A block costs 0.38 us and 1.05 ns
+#: a token on a v5e whether its tokens are live or not (two dependent
+#: MXU products with a softmax between them), so small blocks pay the
+#: first too often and large ones the second for nothing: 128 / 256 /
+#: 512 / 1024 read 3.6 / 2.7 / 2.4 / 2.6 ms a step of 7 layers at 128
+#: lanes of 130-1,280 tokens (PERF.md, PR 38).
+_ATTN_BLOCK_TOKENS = 512
+#: Blocks in flight or in use at once: the fetch of one hides behind
+#: the arithmetic of the other (4 and 8 read the same).
+_ATTN_RING_BLOCKS = 2
 
 
 @dataclasses.dataclass(frozen=True)
@@ -502,6 +529,206 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
                       "pos": pos}, rng
 
 
+def decode_attention_fused(cfg: MLAMoEConfig, page_size: int,
+                           attn_kernel: str = "gather") -> bool:
+    """Whether the chunk program built with these knobs holds the
+    Pallas kernel (the description's optional entry,
+    :mod:`ray_tpu.models.serving`): wherever Mosaic can address a page
+    of the pool. A row is whole 128-lane tiles by construction
+    (:attr:`MLAMoEConfig.latent_row`); compiled for a TPU a page must
+    also be whole sublane tiles of the pool's dtype (16 rows of
+    bfloat16, 8 of float32). Interpreted, off the TPU, any page is
+    addressable. ``attn_kernel`` has one value and no say."""
+    from .._private.chip import pallas_interpret
+
+    rows = 32 // jnp.dtype(cfg.dtype).itemsize
+    return pallas_interpret() or page_size % rows == 0
+
+
+def _latent_attention_gather(q, pool, pages, pos, cfg: MLAMoEConfig,
+                             page_size: int):
+    """Decode's latent attention in plain XLA, the fallback and the
+    tests' oracle: ``q`` [B, H, latent_row] (absorbed queries, zeros in
+    the pad lanes) over each lane's WHOLE virtual sequence, gathered
+    from the flat ``pool`` through ``pages`` [B, max_pages] (in bounds)
+    and masked past ``pos``. Returns ``o`` [B, H, kv_rank]."""
+    B = q.shape[0]
+    V = pages.shape[1] * page_size
+    seen = (jnp.arange(V)[None] <= pos[:, None])[:, None]    # [B, 1, V]
+    lat = pool[pages].reshape(B, V, -1)
+    lg = jnp.einsum("bhc,bvc->bhv", q, lat,
+                    preferred_element_type=jnp.float32)
+    lg = jnp.where(seen, lg * cfg.attn_scale, -1e30)
+    probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
+    # over the whole row, the rotary key's lanes too, and cut
+    # afterwards: slicing the gathered pages first is another copy
+    return jnp.einsum("bhv,bvr->bhr", probs, lat,
+                      preferred_element_type=jnp.float32
+                      )[..., :cfg.kv_rank].astype(cfg.dtype)
+
+
+def _latent_attention_pallas(q, pool, pages, length, cfg: MLAMoEConfig,
+                             page_size: int):
+    """Decode's latent attention as ONE kernel that reads what is live,
+    once: ``q`` [B, H, latent_row] against the first ``length[b]``
+    tokens of lane ``b``, whose pages ``pages`` [B, max_pages] names in
+    the flat ``pool`` [pages, page_size, latent_row]. Returns ``o``
+    [B, H, kv_rank]; zeros for a lane of length 0.
+
+    Grid ``(B,)``: one step a lane, and inside it a loop over THAT
+    lane's live tokens in blocks of :data:`_ATTN_BLOCK_TOKENS`.
+    ``pages``, ``length`` and ``first`` (the blocks before each lane:
+    the lanes' blocks in order are one STREAM) ride as scalar-prefetch
+    operands; the pool stays in HBM, never sliced, and the kernel
+    copies the pages the table names into a ring of
+    :data:`_ATTN_RING_BLOCKS` VMEM blocks (one DMA a page, one
+    semaphore a block). Block ``i`` of the stream lives in buffer ``i
+    % ring``: the first ``ring`` are started at the first lane's
+    start, each later one behind the arithmetic of the block whose
+    buffer it takes, so a lane's last blocks fetch the NEXT lane's
+    first and no lane waits for an idle DMA engine (that wait was a
+    fifth of the kernel's time on a v5e). A page is fetched ONCE; a
+    page past the live length never.
+
+    A block is rows that are key and value at once, so a lane's step
+    is two MXU products a block: ``s = q . block^T`` ([H, 640] x [640,
+    T], float32 sums, scaled in float32) and ``acc += p . block`` ([H,
+    T] x [T, 640]), around one running-max softmax pass in float32 (the
+    max ``m``, the sum ``l`` and ``acc`` are carried and rescaled as the
+    max moves). The probabilities are rounded to the compute dtype
+    before they meet the latents, as the XLA body rounds them, but
+    BEFORE the division by ``l``: the whole numeric difference
+    (:data:`ATTN_KERNEL_ULPS`). Whole blocks need no mask; the lane's
+    last, partial block masks the scores AND the latents (a block's
+    unfetched rows hold whatever was there, and 0 * inf is NaN). The
+    first ``kv_rank`` lanes of ``acc / l`` are written once, at the
+    lane's end."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .._private.chip import pallas_interpret
+
+    B, H, R = q.shape
+    ps = page_size
+    bp = max(1, _ATTN_BLOCK_TOKENS // ps)          # pages a block
+    T = bp * ps
+    ring = _ATTN_RING_BLOCKS
+    dtype = q.dtype
+    scale = cfg.attn_scale         # a Python float: no captured constant
+    first = jnp.concatenate([
+        jnp.zeros((1,), jnp.int32),
+        jnp.cumsum((length + T - 1) // T, dtype=jnp.int32)])
+
+    def kernel(pt_ref, len_ref, first_ref, q_ref, pool_hbm, o_ref, buf,
+               sems):
+        b = pl.program_id(0)
+        n_live = len_ref[b]
+        base, total = first_ref[b], first_ref[B]
+
+        def each_page(lane, j, i, what):
+            """``what`` (start or wait) on the copy of every live page
+            of ``lane``'s block ``j``, block ``i`` of the stream."""
+            n = (len_ref[lane] + ps - 1) // ps         # its live pages
+
+            def page(g, _):
+                what(pltpu.make_async_copy(
+                    pool_hbm.at[pt_ref[lane, g]],
+                    buf.at[i % ring, g - j * bp], sems.at[i % ring]))
+
+            lax.fori_loop(j * bp, jnp.minimum((j + 1) * bp, n), page, None)
+
+        def start(i, lane):
+            """Fetch block ``i`` of the stream, which is ``lane``'s or a
+            later lane's."""
+            lane = lax.while_loop(lambda c: first_ref[c + 1] <= i,
+                                  lambda c: c + 1, lane)
+            each_page(lane, i - first_ref[lane], i,
+                      lambda copy: copy.start())
+
+        @pl.when(b == 0)
+        def _():
+            lax.fori_loop(0, jnp.minimum(total, ring),
+                          lambda i, _: start(i, 0), None)
+
+        qv = q_ref[0]                                        # [H, R]
+
+        def fold(j, carry, whole=True):
+            """Block ``j`` of the lane into ``(m, l, acc)``: the waits
+            first, the refill last (behind the second product the
+            block's buffer is free), the arithmetic between them."""
+            m, l, acc = carry
+            i = base + j
+            each_page(b, j, i, lambda copy: copy.wait())
+            blk = buf[i % ring].reshape(T, R)
+            s = lax.dot_general(qv, blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            if not whole:
+                s = jnp.where(j * T + lax.broadcasted_iota(
+                    jnp.int32, (1, T), 1) < n_live, s, -1e30)
+                blk = jnp.where(j * T + lax.broadcasted_iota(
+                    jnp.int32, (T, 1), 0) < n_live, blk,
+                    jnp.zeros_like(blk))
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)                       # 0 where masked
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + jnp.dot(
+                p.astype(dtype), blk, preferred_element_type=jnp.float32)
+            pl.when(i + ring < total)(lambda: start(i + ring, b))
+            return m_new, l, acc
+
+        n_whole = n_live // T                  # blocks that need no mask
+        carry = lax.fori_loop(
+            0, n_whole, fold,
+            (jnp.full((H, 1), -1e30, jnp.float32),
+             jnp.zeros((H, 1), jnp.float32),
+             jnp.zeros((H, R), jnp.float32)))
+        m, l, acc = lax.cond(
+            n_live > n_whole * T,
+            lambda carry: fold(n_whole, carry, whole=False),
+            lambda carry: carry, carry)
+        o_ref[0] = (acc / jnp.where(l > 0.0, l, 1.0)
+                    )[:, :cfg.kv_rank].astype(dtype)
+
+    def lane_map(b, *prefetched):
+        return (b, 0, 0)
+
+    # `name` names the device operation ("latent_attention.N") and the
+    # last component of its path before "pallas_call"; the rest of the
+    # path is the caller's scope.
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, R), lane_map),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, H, cfg.kv_rank), lane_map),
+            scratch_shapes=[pltpu.VMEM((ring, bp, ps, R), pool.dtype),
+                            pltpu.SemaphoreType.DMA((ring,))]),
+        out_shape=jax.ShapeDtypeStruct((B, H, cfg.kv_rank), dtype),
+        # Every index a copy takes is in bounds (``pages``) or a
+        # remainder (the ring): the checks Mosaic adds cannot fire.
+        compiler_params=pltpu.CompilerParams(disable_bounds_checks=True),
+        interpret=pallas_interpret(),
+        name="latent_attention",
+    )(pages, length, first, q, pool)
+
+
+def _live_length(pt, pos, active, n_pages: int, page_size: int):
+    """Tokens of each lane the kernel reads: positions <= ``pos`` inside
+    the mapped prefix of the lane's table row (the engine maps a lane's
+    pages from column 0 without holes); 0 for an inactive lane or a row
+    of sentinels."""
+    max_pages = pt.shape[1]
+    mapped = jnp.min(jnp.where((pt >= 0) & (pt < n_pages),
+                               jnp.int32(max_pages),
+                               jnp.arange(max_pages, dtype=jnp.int32)),
+                     axis=1)
+    return jnp.where(active, jnp.minimum(pos.astype(jnp.int32) + 1,
+                                         mapped * page_size), 0)
+
+
 def _slot_decode_step_paged(params: Params, cache: Cache,
                             token: jax.Array, active: jax.Array,
                             pt: jax.Array, cfg: MLAMoEConfig,
@@ -510,13 +737,13 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     """One masked decode step over the whole slot pool: each active
     lane writes its latent row at its own position and attends, in the
     latent space with the up-projections absorbed, over its own pages
-    up to it. Inactive lanes neither write, advance nor route. Returns
-    ``(logits [B, rows], cache', counts)``: the expert layers' counters
-    int32 [4] (:data:`STEP_COUNTERS`)."""
+    up to it (the kernel wherever :func:`decode_attention_fused`, else
+    the XLA body). Inactive lanes neither write, advance nor route.
+    Returns ``(logits [B, rows], cache', counts)``: the expert layers'
+    counters int32 [4] (:data:`STEP_COUNTERS`)."""
     B = token.shape[0]
     ps = page_size
     max_pages = pt.shape[1]
-    V = max_pages * ps
     pos = cache["pos"]
     L, n_pages = cache["latent"].shape[:2]
     x = _embed(params, token)[:, None]
@@ -526,7 +753,8 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
         jnp.take_along_axis(pt, jnp.clip(vp, 0, max_pages - 1)[:, None],
                             axis=1)[:, 0], jnp.int32(PT_SENTINEL))
     ptc = jnp.clip(pt, 0, n_pages - 1)
-    seen = (jnp.arange(V)[None] <= pos[:, None])[:, None]    # [B, 1, V]
+    fused = decode_attention_fused(cfg, ps, attn_kernel)
+    length = _live_length(pt, pos, active, n_pages, ps) if fused else None
     pool = _flat(cache["latent"])
     counts = jnp.zeros((4,), jnp.int32)
     # the step's own scope: a reader tells the decode program's
@@ -544,17 +772,10 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                 jnp.zeros((B, cfg.n_head, cfg.latent_row
                            - cfg.latent_dim), cfg.dtype)], axis=-1)
             with jax.named_scope("mla.attention"):
-                lat = pool[ptc + l * n_pages].reshape(B, V, -1)
-                lg = jnp.einsum("bhc,bvc->bhv", q, lat,
-                                preferred_element_type=jnp.float32)
-                lg = jnp.where(seen, lg * cfg.attn_scale, -1e30)
-                probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
-                # over the whole row, the rotary key's lanes too, and
-                # cut afterwards: slicing the gathered pages first is
-                # another copy of them
-                o = jnp.einsum("bhv,bvr->bhr", probs, lat,
-                               preferred_element_type=jnp.float32
-                               )[..., :cfg.kv_rank].astype(cfg.dtype)
+                pages = ptc + l * n_pages
+                o = _latent_attention_pallas(q, pool, pages, length, cfg,
+                                             ps) if fused else \
+                    _latent_attention_gather(q, pool, pages, pos, cfg, ps)
             att = jnp.einsum("bhr,rhv->bhv", o, w_uv,
                              preferred_element_type=jnp.float32
                              ).astype(cfg.dtype).reshape(B, 1, -1)

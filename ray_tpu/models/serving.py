@@ -7,7 +7,7 @@ module by name. Two decoders answer today: the GPT-2 block
 (:mod:`ray_tpu.models.gpt_decode`: a page holds keys and values per
 head) and the latent-attention expert decoder
 (:mod:`ray_tpu.models.mla_moe`: a page holds one 576-wide latent a
-token, no head axis).
+token in a row of 640 lanes, no head axis).
 
 A description provides, under these names:
 
@@ -30,7 +30,21 @@ A description provides, under these names:
     the longest sequence the model can place (a learned table's rows;
     a rotary model's declared reach).
 ``KV_DTYPES``, ``ATTN_KERNELS``
-    the pool storage types and decode attention paths it has.
+    the pool storage types it has, and the NAMES of its decode
+    attention paths: what an ``attn_kernel`` knob may say. A name is
+    the model's own and says nothing of how the path is built:
+    ``gpt_decode`` has two (``"gather"`` plain XLA, ``"pallas"`` the
+    fused kernel); ``mla_moe`` has ONE decode attention under one
+    name, ``"gather"``, which is a Pallas kernel over each lane's live
+    latent pages wherever Mosaic can address a page and plain XLA over
+    the gathered pages where it cannot (the model chooses, by shape).
+``decode_attention_fused(cfg, page_size, attn_kernel) -> bool``
+    OPTIONAL: whether the chunk program built with these knobs holds a
+    fused attention kernel. The engine asks it for
+    ``warm_up()["attn_kernel_mode"]`` (``"compiled"`` / ``"interpret"``,
+    read off the lowered program, or ``None`` without a kernel) and for
+    ``stats()["attn_kernel_dispatches"]``; a description without it
+    (``gpt_decode``) is asked by name: ``attn_kernel == "pallas"``.
 ``UNSUPPORTED``
     ``{engine capability: reason}`` for what this model does not get
     (``"int8"``, ``"tp"``, ``"spec_decode"``, ``"roles"``): the engine

@@ -700,6 +700,12 @@ class DecodeEngine:
         self._step = self._model.jit_decode_chunk_slots_paged(
             cfg, self.chunk, self.page_size, self.temperature,
             self.eos_token, self.kv_dtype, self.attn_kernel, self.tp)
+        # Whether that program holds a fused attention kernel: the
+        # description's word where it has one (models/serving.py), else
+        # the knob's name.
+        fused = getattr(self._model, "decode_attention_fused", None)
+        self._attn_fused = self.attn_kernel == "pallas" if fused is None \
+            else bool(fused(cfg, self.page_size, self.attn_kernel))
         self._export = self._import = None
         if "roles" not in self._model.UNSUPPORTED:
             self._export = self._model.jit_export_slot_kv_paged(
@@ -1292,7 +1298,7 @@ class DecodeEngine:
             step_args = (self._params_dev, self._cache, self._token,
                          self._rngs, active, self._pt)
             mode = None
-            if self.attn_kernel == "pallas":
+            if self._attn_fused:
                 from .._private.chip import compiled_by_mosaic
 
                 mode = "compiled" if compiled_by_mosaic(
@@ -2372,7 +2378,7 @@ class DecodeEngine:
                 _driver_emit("shard.dispatch", epoch=self._epoch,
                              mesh=[("tp", self.tp)],
                              program="chunk_paged")
-            if self.attn_kernel == "pallas":
+            if self._attn_fused:
                 # One fused-kernel dispatch per chunk program launch (the
                 # kernel runs k times per layer inside it).
                 sm["engine_attn_kernel_dispatches"].inc(

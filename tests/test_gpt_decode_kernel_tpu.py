@@ -1,8 +1,10 @@
-"""The paged-attention kernel compiled by the TPU's own compiler, for a
-chip that is described and not attached (v5e), at the shapes the chip
-runs: what interpret mode cannot see — a slice off the tiling, a DMA
-Mosaic cannot address, more VMEM than a kernel may take — fails here,
-at no chip time. Nothing runs: a compile that passes is not a chip run.
+"""The decode attention kernels (``gpt_decode``'s over pages of keys
+and values per head, ``mla_moe``'s over latent pages) compiled by the
+TPU's own compiler, for a chip that is described and not attached
+(v5e), at the shapes the chip runs: what interpret mode cannot see —
+a slice off the tiling, a DMA Mosaic cannot address, more VMEM than a
+kernel may take — fails here, at no chip time. Nothing runs: a compile
+that passes is not a chip run.
 
 The topology is described inside a fixture (only the worker that is
 given this file loads the TPU's library), and every such test lives in
@@ -95,3 +97,98 @@ def test_heads_off_the_tiling_are_refused_by_name(compiled_mode):
         gd.paged_attention(q, pool, pool, jnp.zeros((2, 4), jnp.int32),
                            jnp.zeros((2,), jnp.int32), page_size=16,
                            kernel="pallas")
+
+
+# ---------------------------------------------- latent attention (S5e)
+def _latent_cfg():
+    """``mla_moe`` at the served attention's widths (64 heads over rows
+    of 512 + 64 values in 640 lanes) around a small everything else:
+    what the kernel's shapes depend on."""
+    import dataclasses
+
+    from ray_tpu.models import mla_moe
+
+    return dataclasses.replace(
+        mla_moe.CONFIGS["nano"], n_layer=2, d_model=256, n_head=64,
+        q_rank=128, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        experts_held=8)
+
+
+#: (B, page_size, pages in the flat pool, max_pages)
+LATENT_SHAPES = {
+    # the cell: 128 lanes, 7 layers x 18,432 pages flat, max_len 2,048
+    "cell": (128, 16, 7 * 18432, 128),
+    # the benchmark's reference check: 96 rows on a pool of their own
+    "check-5": (96, 16, 7 * 96 * 5, 5),
+    "check-16": (96, 16, 7 * 96 * 16, 16),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(LATENT_SHAPES))
+def test_latent_attention_compiles_for_v5e(one_chip, compiled_mode, shape):
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import mla_moe
+
+    cfg = _latent_cfg()
+    B, ps, n_pages, max_pages = LATENT_SHAPES[shape]
+    assert cfg.latent_row == 640
+    assert mla_moe.decode_attention_fused(cfg, ps)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(
+        lambda q, pool, pages, length: mla_moe._latent_attention_pallas(
+            q, pool, pages, length, cfg, ps)).lower(
+        arg((B, cfg.n_head, 640), jnp.bfloat16),
+        arg((n_pages, ps, 640), jnp.bfloat16),
+        arg((B, max_pages), jnp.int32), arg((B,), jnp.int32))
+    assert chip.compiled_by_mosaic(lowered.as_text())
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    # the pool is an operand of the kernel as it lies: no copy of it
+    assert f"[{n_pages},{ps},640]" in text
+    assert "latent_attention/pallas_call" in text
+
+
+@pytest.mark.parametrize("ps,fused", [(16, True), (4, False)])
+def test_the_latent_step_chooses_by_the_page_for_v5e(one_chip,
+                                                    compiled_mode, ps,
+                                                    fused):
+    """The whole decode step as a TPU process builds it: with pages of
+    16 rows the kernel, under the path the benchmark's readers look
+    for; with pages of 4 (a quarter of a bfloat16 tile, which Mosaic's
+    DMA cannot address) the XLA body, and nothing raises."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import mla_moe
+
+    cfg = _latent_cfg()
+    B, n_pages, max_pages = 8, 64, 64 // ps
+
+    def arg(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda k: mla_moe.init_params(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(
+        lambda: mla_moe.init_paged_cache(cfg, B, n_pages, ps))
+    args = (params, cache, jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((B, max_pages), jnp.int32))
+    assert mla_moe.decode_attention_fused(cfg, ps) is fused
+    lowered = jax.jit(functools.partial(
+        mla_moe._slot_decode_step_paged, cfg=cfg, page_size=ps),
+        donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
+    assert chip.compiled_by_mosaic(lowered.as_text()) is fused
+    text = lowered.compile().as_text()
+    assert ("decode_step/mla.attention/latent_attention/pallas_call"
+            in text) is fused
+    assert ("tpu_custom_call" in text) is fused

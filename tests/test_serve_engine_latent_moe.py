@@ -240,3 +240,65 @@ def test_the_programs_keep_the_names_a_trace_shows(model):
             c, 4, 4).__wrapped__.__name__ == "decode_chunk_slots_paged"
         assert desc.jit_prefill_into_slot_paged(
             c, 4).__wrapped__.__name__ == "prefill_into_slot_paged"
+
+
+def test_the_engine_reports_the_kernel_its_chunk_program_holds(model,
+                                                               engine):
+    """ROADMAP S5e: this model's one decode attention is a Pallas
+    kernel wherever Mosaic can address a page, and the engine learns it
+    from the description (``decode_attention_fused``), not from the
+    knob's name: ``attn_kernel`` stays ``"gather"``, ``warm_up()`` reads
+    how the kernel was built off the lowered program, and every launch
+    of the chunk program counts as a kernel dispatch."""
+    cfg, _ = model
+    assert engine.attn_kernel == "gather"
+    assert engine.warm_up()["attn_kernel_mode"] == "interpret"
+    before = engine.stats()
+    list(engine.stream(_prompts(cfg, (11,), seed=3)[0], 6))
+    after = engine.stats()
+    ran = after["dispatches"] - before["dispatches"]
+    assert ran > 0
+    assert after["attn_kernel_dispatches"] \
+        - before["attn_kernel_dispatches"] == ran
+    assert after["warm_up"]["attn_kernel_mode"] == "interpret"
+
+
+def test_a_page_mosaic_cannot_address_takes_the_fallback(model,
+                                                         monkeypatch):
+    """Compiled for a TPU a page of 4 bfloat16 rows is a quarter of a
+    tile: the XLA body runs, nothing raises, and the engine says so
+    (``None``, no kernel dispatches). THE one decision is steered here,
+    as ``tests/test_gpt_decode_kernel_tpu.py`` steers it; the knobs
+    (chunk 3, 2 slots) are no other test's, because a built program is
+    cached by its knobs."""
+    from ray_tpu._private import chip
+
+    cfg, params = model
+    monkeypatch.setattr(chip, "pallas_interpret", lambda: False)
+    eng = DecodeEngine(params, cfg, slots=2, chunk=3, max_len=48,
+                       prompt_buckets=(16,), page_size=4, n_pages=40)
+    try:
+        assert eng.warm_up()["attn_kernel_mode"] is None
+        out = np.concatenate(list(eng.stream(
+            _prompts(cfg, (9,), seed=4)[0], 7)))
+        st = eng.stats()
+        assert len(out) == 7
+        assert st["dispatches"] > 0 and st["attn_kernel_dispatches"] == 0
+    finally:
+        eng.shutdown()
+
+
+def test_a_description_without_the_entry_is_asked_by_name():
+    """``gpt_decode`` provides no ``decode_attention_fused``: its
+    engine's test of the knob's name stands."""
+    assert not hasattr(gpt_decode, "decode_attention_fused")
+    cfg = gpt.CONFIGS["nano"]
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    for kernel, fused in (("gather", False), ("pallas", True)):
+        eng = DecodeEngine(params, cfg, slots=2, chunk=2, max_len=32,
+                           prompt_buckets=(8,), page_size=8,
+                           attn_kernel=kernel, auto_start=False)
+        try:
+            assert eng._attn_fused is fused
+        finally:
+            eng.shutdown()
