@@ -44,12 +44,22 @@ def spawn(coro, loop=None) -> asyncio.Task:
     task = (loop or asyncio.get_running_loop()).create_task(coro)
     _background_tasks.add(task)
     task.add_done_callback(_background_tasks.discard)
-    if len(_background_tasks) > 512:
+    if len(_background_tasks) % 512 == 0:
         # A loop closed with tasks still pending never runs their done
         # callbacks — prune those so the strong-ref set can't grow
-        # without bound across cluster create/teardown cycles.
-        for t in [t for t in _background_tasks if t.get_loop().is_closed()]:
-            _background_tasks.discard(t)
+        # without bound across cluster create/teardown cycles: once
+        # every 512 tasks of growth, not on every spawn past 512 (a
+        # replica with 512 streams in flight would walk the whole set
+        # for each message it receives). The set
+        # is shared by every loop of the process, each in its own
+        # thread: walk a copy (``list(set)`` is one step under the
+        # interpreter lock), never the set, or a task added or ended on
+        # another loop meanwhile raises "Set changed size during
+        # iteration" HERE, inside the caller's receive loop, and the
+        # connection is lost (512 streams in flight on one replica did).
+        for t in list(_background_tasks):
+            if t.get_loop().is_closed():
+                _background_tasks.discard(t)
     return task
 
 

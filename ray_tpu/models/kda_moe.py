@@ -1,0 +1,769 @@
+"""A hybrid linear-attention, expert-routed decoder and its paged
+serving programs: the third block
+:class:`~ray_tpu.serve.engine.DecodeEngine` serves (beside
+:mod:`ray_tpu.models.gpt_decode`'s GPT-2 block and
+:mod:`ray_tpu.models.mla_moe`'s latent-attention decoder). This module
+IS the model's description in the sense of
+:mod:`ray_tpu.models.serving`.
+
+The block (pre-norm, RMSNorm, no biases, no positions anywhere, untied
+head)::
+
+    x += Mixer_l(RMSNorm(x));  x += MoE(RMSNorm(x))
+
+``Mixer_l`` is a softmax GQA layer where ``l`` is in
+:attr:`KDAMoEConfig.gqa_layers` and a gated delta-rule linear-attention
+layer (KDA) everywhere else. The two kinds keep DIFFERENT things of a
+sequence, and :func:`cache_spec` describes both in one
+:class:`~ray_tpu.models.serving.CacheSpec`:
+
+- **GQA** (``n_head`` query heads over ``n_kv_head`` key/value heads,
+  query head ``j`` with KV head ``j // (n_head / n_kv_head)``; no
+  rotary; an elementwise output gate ``sigmoid(x W_z)``): a token leaves
+  ``n_kv_head x head_dim`` keys and values in a PAGE (entries ``k``,
+  ``v``, per token, ``n_gqa`` layers). Prefill attends causally over
+  the prompt (scope ``gqa.prefill``); decode attends over the lane's
+  pages gathered through the page table, plain XLA in blocks of
+  :data:`_GQA_LANE_BLOCK` lanes (scope ``gqa.attention``). The GPT-2
+  block's Pallas kernel is NOT adapted: its pool is ``[.., H, hd]`` with
+  one query a head on the VPU, and eight queries a KV head want the MXU:
+  a kernel of its own, later.
+- **KDA** (``kda_heads`` heads of ``kda_head_dim`` keys and values): a
+  width-``conv_size`` causal depthwise convolution and SiLU on the
+  ``q``/``k``/``v`` projections, L2-normalised ``q`` (scaled) and ``k``
+  a head, a decay PER CHANNEL ``alpha = exp(-exp(A_log) softplus(low
+  rank(x) + dt_bias))``, a step size ``beta = 2 sigmoid(x W_b)`` (the 2
+  where ``neg_eigval``) and the recurrence, a head::
+
+      S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+      o_t = S_t^T q_t
+
+  then a per-head RMSNorm and a low-rank sigmoid gate on ``o``. A
+  sequence keeps, whatever its length, ``S`` (``[heads, dk, dv]`` in
+  :attr:`KDAMoEConfig.state_dtype`) and the last ``conv_size - 1`` rows
+  of the three projections: entries ``state`` and ``conv``, ``per
+  "slot"``, ``n_kda`` layers. They belong to the SLOT: every prefill
+  into a slot rebuilds them from zero (:func:`prefill_into_slot_paged`,
+  the chunked form, scope ``kda.prefill``), every decode step reads and
+  writes an active lane's state whole, in place (:func:`_kda_step`, scope
+  ``kda.state``), and an idle or parked lane's is left as it is. So no
+  page hash shares them and nothing rolls back: :data:`UNSUPPORTED`.
+
+**MoE**, every layer: :func:`ray_tpu.models.moe.dropless_moe` with one
+group (sigmoid scores over ``n_routed`` experts, plain top k,
+normalised), told the ``experts_held`` experts from ``expert_offset``
+that live here, plus a shared expert: :func:`ray_tpu.models.mla_moe._ffn`
+as it stands.
+
+Layers are a list of per-layer trees, unrolled; the chunk program
+returns the expert layers' counters and the live lanes, summed over its
+steps (:data:`STEP_COUNTERS`).
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.scipy.linalg import solve_triangular
+
+from .gpt_decode import (_knob_cache, _program, _sample, _sample_slots)
+from .mla_moe import _at_layer, _embed, _ffn, _flat, _head, _rmsnorm
+from .serving import PT_SENTINEL, CacheEntry, CacheSpec, init_paged_pool
+
+Params = Dict[str, Any]
+Cache = Dict[str, jax.Array]
+
+KV_DTYPES = ("fp",)
+ATTN_KERNELS = ("gather",)
+#: What the engine offers and this model does not take, with the reason
+#: the engine raises at construction.
+UNSUPPORTED = {
+    "prefix_cache": "a linear-attention layer's recurrent state belongs "
+                    "to the slot, not to a page: reusing cached pages "
+                    "needs a snapshot of the state at the page boundary "
+                    "the hit ends on, and none is kept",
+    "spec_decode": "a recurrent state does not roll back past rejected "
+                   "positions, and there is no verify program",
+    "roles": "the handoff payload has no part for the per-slot state, "
+             "and there are no export/import programs",
+    "int8": "the key/value pages of the one attention layer in four "
+            "have no quantised layout",
+    "tp": "there are no tensor-parallel programs: the deployment shares "
+          "a layer by EXPERTS (experts_held / expert_offset), one engine "
+          "a chip",
+}
+#: int32 counters the chunk program returns, summed over its steps: the
+#: expert layers' four (:data:`ray_tpu.models.mla_moe.STEP_COUNTERS`)
+#: and the lanes whose state a step read and wrote.
+STEP_COUNTERS = ("moe_steps", "moe_experts_touched_sum",
+                 "moe_tokens_here_sum", "moe_expert_peak_sum",
+                 "state_lanes_sum")
+#: Lanes whose pages decode's attention gathers at once: the gathered
+#: keys and values of ALL lanes (``slots x max_len`` rows) would be a
+#: temporary as large as the pool.
+_GQA_LANE_BLOCK = 32
+_HI = lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass(frozen=True)
+class KDAMoEConfig:
+    vocab_size: int = 512            # rows of the table and head HELD
+    n_layer: int = 4
+    gqa_layers: Tuple[int, ...] = (0,)   # the others are KDA layers
+    d_model: int = 64
+    n_head: int = 4                  # GQA query heads
+    n_kv_head: int = 2
+    head_dim: int = 16
+    gqa_gate: bool = True
+    kda_heads: int = 4
+    kda_head_dim: int = 16           # keys and values alike
+    conv_size: int = 4
+    kda_rank: int = 16               # of the decay and gate projections
+    neg_eigval: bool = True          # beta in (0, 2)
+    kda_chunk: int = 64              # prefill's chunk
+    d_expert: int = 32
+    n_routed: int = 16               # the router's width
+    experts_held: int = 16           # of which live here ...
+    expert_offset: int = 0           # ... from this one
+    top_k: int = 4
+    norm_topk: bool = True
+    route_scale: float = 1.0
+    shared_expert: bool = True
+    max_seq: int = 1048576           # no positions: the declared reach
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    state_dtype: Any = jnp.float32
+    moe_block_rows: int = 32
+
+    # one group: ``dropless_moe``'s group limit is a plain top k
+    n_group = 1
+    topk_group = 1
+
+    @property
+    def n_gqa(self) -> int:
+        return sum(l in self.gqa_layers for l in range(self.n_layer))
+
+    @property
+    def n_kda(self) -> int:
+        return self.n_layer - self.n_gqa
+
+    @property
+    def kda_width(self) -> int:
+        return self.kda_heads * self.kda_head_dim
+
+    def decode_programs(self):
+        """This model's description for the serving engine
+        (:mod:`ray_tpu.models.serving`)."""
+        import sys
+
+        return sys.modules[__name__]
+
+
+# sizes used by the CPU tests
+CONFIGS = {
+    "nano": KDAMoEConfig(),
+}
+
+#: Means of the leaves that are not drawn around zero: ``dt_bias`` sits
+#: where ``softplus`` is small, so that the decay ``alpha`` spreads over
+#: (0.5, 0.999) instead of around ``exp(-ln 2)``.
+INIT_MEAN = {"dt_bias": -3.5}
+INIT_STD = {"embed": 1.0, "A_log": 0.5, "dt_bias": 1.2, "conv": 0.5}
+
+
+def init_params(rng: jax.Array, cfg: KDAMoEConfig,
+                std: Optional[dict] = None) -> Params:
+    """Seeded weights, one tree a layer. ``std`` overrides a kind's
+    standard deviation (default :data:`INIT_STD`, else 1/sqrt(fan-in));
+    :data:`INIT_MEAN` is added."""
+    std = dict(INIT_STD, **(std or {}))
+    pd = cfg.param_dtype
+    d, W = cfg.d_model, cfg.kda_width
+    n = [0]
+
+    def w(name, *shape):
+        n[0] += 1
+        s = next((v for k, v in std.items() if k in name),
+                 1.0 / math.sqrt(shape[-2] if len(shape) > 1 else 1.0))
+        return (jax.random.normal(jax.random.fold_in(rng, n[0]), shape) * s
+                + INIT_MEAN.get(name, 0.0)).astype(pd)
+
+    def ffn(f, lead=()):
+        return {"gate": w("gate", *lead, d, f), "up": w("up", *lead, d, f),
+                "down": w("down", *lead, f, d)}
+
+    layers = []
+    for l in range(cfg.n_layer):
+        p = {"ln1_scale": jnp.ones((d,), pd),
+             "ln2_scale": jnp.ones((d,), pd)}
+        if l in cfg.gqa_layers:
+            hq, hkv = cfg.n_head * cfg.head_dim, cfg.n_kv_head * cfg.head_dim
+            p.update(wq={"kernel": w("wq", d, hq)},
+                     wk={"kernel": w("wk", d, hkv)},
+                     wv={"kernel": w("wv", d, hkv)},
+                     wo={"kernel": w("wo", hq, d)})
+            if cfg.gqa_gate:
+                p["wz"] = {"kernel": w("wz", d, hq)}
+        else:
+            p.update(wq={"kernel": w("wq", d, W)},
+                     wk={"kernel": w("wk", d, W)},
+                     wv={"kernel": w("wv", d, W)},
+                     conv_q=w("conv_q", cfg.conv_size, W),
+                     conv_k=w("conv_k", cfg.conv_size, W),
+                     conv_v=w("conv_v", cfg.conv_size, W),
+                     A_log=w("A_log", cfg.kda_heads),
+                     dt_bias=w("dt_bias", W),
+                     wf_down={"kernel": w("wf_down", d, cfg.kda_rank)},
+                     wf_up={"kernel": w("wf_up", cfg.kda_rank, W)},
+                     wb={"kernel": w("wb", d, cfg.kda_heads)},
+                     wg_down={"kernel": w("wg_down", d, cfg.kda_rank)},
+                     wg_up={"kernel": w("wg_up", cfg.kda_rank, W)},
+                     o_norm_scale=jnp.ones((cfg.kda_head_dim,), pd),
+                     wo={"kernel": w("wo", W, d)})
+        p["router"] = {"kernel": w("router", d, cfg.n_routed)}
+        p["experts"] = ffn(cfg.d_expert, (cfg.experts_held,))
+        if cfg.shared_expert:
+            p["shared"] = ffn(cfg.d_expert)
+        layers.append(p)
+    return {"embed": {"kernel": w("embed", cfg.vocab_size, d)},
+            "head": {"kernel": w("head", d, cfg.vocab_size)},
+            "ln_f_scale": jnp.ones((d,), pd), "layers": layers}
+
+
+# ------------------------------------------------------------ block math
+def _dot(x, w, dtype):
+    """``x @ w`` in ``dtype`` with float32 sums, the float32 result."""
+    return lax.dot_general(x.astype(dtype), w.astype(dtype),
+                           (((x.ndim - 1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _kda_proj(h, p, cfg: KDAMoEConfig):
+    """``h`` [..., d] (normed) -> (pre [..., 3 W]: the ``q | k | v``
+    projections BEFORE the convolution, in the compute dtype (what the
+    convolution's tail keeps); g [..., H, dk]: the log of the decay, <=
+    0; beta [..., H]; gate [..., H, dv]: the output gate; float32)."""
+    H, D = cfg.kda_heads, cfg.kda_head_dim
+    dt = cfg.dtype
+    pre = jnp.concatenate([_dot(h, p[n]["kernel"], dt).astype(dt)
+                           for n in ("wq", "wk", "wv")], axis=-1)
+
+    def low_rank(name):
+        return _dot(_dot(h, p[name + "_down"]["kernel"], dt),
+                    p[name + "_up"]["kernel"], dt)
+
+    f = low_rank("wf") + p["dt_bias"].astype(jnp.float32)
+    g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] \
+        * jax.nn.softplus(f).reshape(f.shape[:-1] + (H, D))
+    beta = jax.nn.sigmoid(_dot(h, p["wb"]["kernel"], dt))
+    if cfg.neg_eigval:
+        beta = 2.0 * beta
+    gate = jax.nn.sigmoid(low_rank("wg")).reshape(f.shape[:-1] + (H, D))
+    return pre, g, beta, gate
+
+
+def _kda_qkv(window, p, cfg: KDAMoEConfig):
+    """The short convolution's output for positions whose ``conv_size``
+    input rows are ``window[i]`` (a list of ``[..., 3 W]`` arrays,
+    oldest first): SiLU of the depthwise sum, then per head ``q``
+    L2-normalised and scaled by ``dk^-1/2``, ``k`` L2-normalised, ``v``
+    as it is. Returns float32 ``[..., H, dk]`` each."""
+    H, D = cfg.kda_heads, cfg.kda_head_dim
+    taps = jnp.concatenate([p["conv_q"], p["conv_k"], p["conv_v"]],
+                           axis=-1).astype(jnp.float32)      # [conv, 3 W]
+    y = sum(taps[i] * window[i].astype(jnp.float32)
+            for i in range(cfg.conv_size))
+    y = jax.nn.silu(y)
+    q, k, v = (a.reshape(a.shape[:-1] + (H, D))
+               for a in jnp.split(y, 3, axis=-1))
+
+    def unit(a):
+        return a * lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+    return unit(q) * D ** -0.5, unit(k), v
+
+
+def _kda_out(o, gate, p, cfg: KDAMoEConfig):
+    """``o`` [..., H, dv] float32 -> the mixer's output [..., d]: a
+    per-head RMSNorm (one learned scale, ``dv`` wide), the gate, W_o."""
+    o = _rmsnorm(o, p["o_norm_scale"], cfg.eps, jnp.float32) * gate
+    return _dot(o.reshape(o.shape[:-2] + (-1,)), p["wo"]["kernel"],
+                cfg.dtype)
+
+
+def _kda_step(S, q, k, v, g, beta):
+    """The recurrence, one token a lane: ``S`` [B, H, dk, dv], ``q``
+    ``k`` ``g`` [B, H, dk], ``v`` [B, H, dv], ``beta`` [B, H], float32.
+    Returns ``(S', o [B, H, dv])``. Written as elementwise products and
+    sums over ``S`` (a matrix-vector product a head is no work for the
+    MXU): ``S^T (alpha k)`` and ``S^T (alpha q)`` each read ``S`` (XLA
+    makes them two reductions, not one), a third pass reads it again
+    and writes ``S'``; ``o = S'^T q`` follows from the two sums without
+    a fourth. A kernel that keeps a lane's state in fast memory for the
+    step would read it once (``kda_state_roofline_pct``)."""
+    a = jnp.exp(g)
+    u = jnp.sum(S * (k * a)[..., None], axis=-2)            # S^T (a k)
+    w = jnp.sum(S * (q * a)[..., None], axis=-2)            # S^T (a q)
+    du = beta[..., None] * (v - u)
+    S = S * a[..., None] + k[..., None] * du[..., None, :]
+    return S, w + jnp.sum(k * q, axis=-1, keepdims=True) * du
+
+
+def _kda_chunked(q, k, v, g, beta, S0, chunk: int):
+    """The same recurrence over a whole sequence in chunks: ``q`` ``k``
+    ``g`` [T, H, dk], ``v`` [T, H, dv], ``beta`` [T, H], ``S0`` [H, dk,
+    dv], float32; ``T`` a multiple of ``chunk``. Returns ``(o [T, H,
+    dv], S_T)``. Rows with ``beta = 0`` and ``g = 0`` leave the state
+    as it is (a prompt's padding).
+
+    Within a chunk, with ``G_t`` the running sum of ``g`` from the
+    chunk's start and ``S`` the state before it, the updates ``u_t =
+    beta_t (v_t - S_{t-1}^T (alpha_t k_t))`` solve the unit lower
+    triangular system ``(I + Diag(beta) A) U = Diag(beta) (V - (K e^G)
+    S)``, ``A[t, i] = sum_c k_t[c] k_i[c] e^{G_t[c] - G_i[c]}`` for ``i
+    < t``; then ``o_t = S^T (q_t e^{G_t}) + sum_{i <= t} (sum_c q_t[c]
+    k_i[c] e^{G_t[c] - G_i[c]}) u_i`` and the state after the chunk is
+    ``Diag(e^{G_C}) S + (K e^{G_C - G})^T U``. Every exponent taken is
+    <= 0: ``e^{-G_i}`` alone overflows where a channel decays fast for
+    a whole chunk, so the two decay-weighted Gram matrices are summed
+    over channels with the difference in the exponent (the exponentials
+    are fused into the reduction; nothing ``[C, C, dk]`` is kept)."""
+    T, H, dk = q.shape
+    C = chunk
+    N = T // C
+
+    def chunks(a):                                  # [T, H, .] -> [N, H, C, .]
+        return jnp.moveaxis(a.reshape((N, C) + a.shape[1:]), 1, 2)
+
+    lower = jnp.tril(jnp.ones((C, C), jnp.bool_))
+    eye = jnp.eye(C, dtype=jnp.float32)
+
+    def mm(spec, a, b):
+        return jnp.einsum(spec, a, b, precision=_HI,
+                          preferred_element_type=jnp.float32)
+
+    def one(S, xs):
+        q, k, v, g, beta = xs                       # [H, C, .], beta [H, C]
+        G = jnp.cumsum(g, axis=1)
+        E = jnp.exp(jnp.where(lower[None, :, :, None],
+                              G[:, :, None] - G[:, None], -jnp.inf))
+        kk = jnp.sum(k[:, :, None] * k[:, None] * E, axis=-1)   # [H, C, C]
+        qk = jnp.sum(q[:, :, None] * k[:, None] * E, axis=-1)
+        eG = jnp.exp(G)
+        rhs = beta[..., None] * (v - mm("hck,hkv->hcv", k * eG, S))
+        U = solve_triangular(
+            eye + beta[..., None] * jnp.where(lower & ~eye.astype(bool),
+                                              kk, 0.0),
+            rhs, lower=True, unit_diagonal=True)
+        o = mm("hck,hkv->hcv", q * eG, S) + mm("hct,htv->hcv", qk, U)
+        last = G[:, -1]                                         # [H, dk]
+        S = jnp.exp(last)[..., None] * S + mm(
+            "hck,hcv->hkv", k * jnp.exp(last[:, None] - G), U)
+        return S, o
+
+    S, o = lax.scan(one, S0, (chunks(q), chunks(k), chunks(v), chunks(g),
+                              chunks(beta[..., None])[..., 0]))
+    return jnp.moveaxis(o, 1, 2).reshape(T, H, -1), S
+
+
+def _kda_sequence(h, p, cfg: KDAMoEConfig, live):
+    """A KDA mixer over one whole sequence from a zero state: ``h`` [S,
+    d] (normed), ``live`` [S] bool (rows past the prompt advance
+    nothing). Returns ``(y [S, d] float32, S_end [H, dk, dv], padded
+    [conv_size - 1 + S, 3 W]: the projections before the convolution
+    behind the zero rows that stand before the sequence's start)``."""
+    S = h.shape[0]
+    with jax.named_scope("kda.proj"):
+        pre, g, beta, gate = _kda_proj(h, p, cfg)
+        back = cfg.conv_size - 1
+        padded = jnp.concatenate(
+            [jnp.zeros((back, pre.shape[-1]), pre.dtype), pre])
+        q, k, v = _kda_qkv([padded[i:i + S] for i in range(cfg.conv_size)],
+                           p, cfg)
+        g = jnp.where(live[:, None, None], g, 0.0)
+        beta = jnp.where(live[:, None], beta, 0.0)
+    with jax.named_scope("kda.prefill"):
+        C = min(cfg.kda_chunk, S)
+        pad = -S % C
+        if pad:
+            q, k, v, g, beta = (jnp.concatenate(
+                [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)])
+                for a in (q, k, v, g, beta))
+        o, S_end = _kda_chunked(
+            q, k, v, g, beta, jnp.zeros(
+                (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim),
+                jnp.float32), C)
+    with jax.named_scope("kda.proj"):
+        y = _kda_out(o[:S], gate, p, cfg)
+    return y, S_end, padded
+
+
+def _gqa_qkvz(h, p, cfg: KDAMoEConfig):
+    """``h`` [..., d] -> (q [..., Hq, hd], k, v [..., Hkv, hd] in the
+    compute dtype, gate [..., Hq * hd] float32 or None)."""
+    dt = cfg.dtype
+
+    def heads(name, n):
+        a = _dot(h, p[name]["kernel"], dt).astype(dt)
+        return a.reshape(a.shape[:-1] + (n, cfg.head_dim))
+
+    z = jax.nn.sigmoid(_dot(h, p["wz"]["kernel"], dt)) if "wz" in p \
+        else None
+    return heads("wq", cfg.n_head), heads("wk", cfg.n_kv_head), \
+        heads("wv", cfg.n_kv_head), z
+
+
+def _gqa_out(att, z, p, cfg: KDAMoEConfig):
+    """``att`` [..., Hq, hd] float32 -> the mixer's output [..., d]."""
+    att = att.reshape(att.shape[:-2] + (-1,))
+    if z is not None:
+        att = att * z
+    return _dot(att, p["wo"]["kernel"], cfg.dtype)
+
+
+def _gqa_causal(q, k, v, cfg: KDAMoEConfig):
+    """Causal softmax attention of one sequence, no positions: ``q`` [S,
+    Hq, hd] over ``k``, ``v`` [S, Hkv, hd]. Returns float32 [S, Hq,
+    hd]."""
+    S = q.shape[0]
+    G = cfg.n_head // cfg.n_kv_head
+    qg = q.reshape(S, cfg.n_kv_head, G, cfg.head_dim)
+    lg = jnp.einsum("qkgd,tkd->kgqt", qg, k,
+                    preferred_element_type=jnp.float32) \
+        * cfg.head_dim ** -0.5
+    lg = jnp.where(jnp.tril(jnp.ones((S, S), jnp.bool_)), lg, -1e30)
+    probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
+    return jnp.einsum("kgqt,tkd->qkgd", probs, v,
+                      preferred_element_type=jnp.float32
+                      ).reshape(S, cfg.n_head, cfg.head_dim)
+
+
+def _gqa_attention_gather(q, kpool, vpool, pages, pos, cfg: KDAMoEConfig,
+                          page_size: int):
+    """Decode's attention in plain XLA: ``q`` [B, Hq, hd] over each
+    lane's whole virtual sequence, gathered from the flat pools [pages,
+    page_size, Hkv, hd] through ``pages`` [B, max_pages] (in bounds) and
+    masked past ``pos``; :data:`_GQA_LANE_BLOCK` lanes at a time.
+    Returns float32 [B, Hq, hd]."""
+    B = q.shape[0]
+    V = pages.shape[1] * page_size
+    G = cfg.n_head // cfg.n_kv_head
+
+    def lane(args):
+        q, pages, pos = args
+        k = kpool[pages].reshape(V, cfg.n_kv_head, cfg.head_dim)
+        v = vpool[pages].reshape(V, cfg.n_kv_head, cfg.head_dim)
+        lg = jnp.einsum("kgd,tkd->kgt",
+                        q.reshape(cfg.n_kv_head, G, cfg.head_dim), k,
+                        preferred_element_type=jnp.float32) \
+            * cfg.head_dim ** -0.5
+        lg = jnp.where(jnp.arange(V) <= pos, lg, -1e30)
+        probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
+        return jnp.einsum("kgt,tkd->kgd", probs, v,
+                          preferred_element_type=jnp.float32
+                          ).reshape(cfg.n_head, cfg.head_dim)
+
+    return lax.map(lane, (q, pages, pos),
+                   batch_size=min(B, _GQA_LANE_BLOCK))
+
+
+def forward(params: Params, tokens: jax.Array, cfg: KDAMoEConfig
+            ) -> jax.Array:
+    """tokens [B, S] -> float32 logits [B, S, rows]: each sequence
+    whole, no cache (the chunked KDA form from a zero state, causal
+    attention), one sequence at a time."""
+    S = tokens.shape[1]
+    live = jnp.ones((S,), jnp.bool_)
+
+    def row(toks):
+        x = _embed(params, toks)
+        for l, p in enumerate(params["layers"]):
+            h = _rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+            if l in cfg.gqa_layers:
+                q, k, v, z = _gqa_qkvz(h, p, cfg)
+                y = _gqa_out(_gqa_causal(q, k, v, cfg), z, p, cfg)
+            else:
+                y = _kda_sequence(h, p, cfg, live)[0]
+            x = _ffn(x + y.astype(x.dtype), p, cfg)[0]
+        return _head(x, params, cfg)
+
+    return lax.map(row, tokens)
+
+
+# ----------------------------------------------------------- description
+def cache_spec(cfg: KDAMoEConfig, kv_dtype: str = "fp") -> CacheSpec:
+    """What a token leaves in a page (keys and values of the GQA layers,
+    ``[Hkv, hd]`` each) and what a sequence keeps in its SLOT (a KDA
+    layer's state ``[H, dk, dv]`` in the state dtype and the
+    convolution's last ``conv_size - 1`` input rows ``[conv - 1, 3 W]``
+    in the compute dtype), each with the count of layers that keep it."""
+    if kv_dtype not in KV_DTYPES:
+        raise ValueError(
+            f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}: "
+            + UNSUPPORTED["int8"])
+    row = (cfg.n_kv_head, cfg.head_dim)
+    D = cfg.kda_head_dim
+    return CacheSpec(cfg.n_layer, (
+        CacheEntry("k", "token", row, cfg.dtype, cfg.n_gqa),
+        CacheEntry("v", "token", row, cfg.dtype, cfg.n_gqa),
+        CacheEntry("state", "slot", (cfg.kda_heads, D, D), cfg.state_dtype,
+                   cfg.n_kda),
+        CacheEntry("conv", "slot", (cfg.conv_size - 1, 3 * cfg.kda_width),
+                   cfg.dtype, cfg.n_kda)))
+
+
+def kv_bytes_per_page(cfg: KDAMoEConfig, page_size: int,
+                      kv_dtype: str = "fp") -> int:
+    return cache_spec(cfg, kv_dtype).bytes_per_page(page_size)
+
+
+def init_paged_cache(cfg: KDAMoEConfig, slots: int, n_pages: int,
+                     page_size: int, kv_dtype: str = "fp",
+                     tp: int = 1) -> Cache:
+    """The key/value page pools ``[n_gqa, n_pages, page_size, Hkv, hd]``,
+    the per-slot ``state`` ``[n_kda, slots, H, dk, dv]`` and ``conv``
+    ``[n_kda, slots, conv - 1, 3 W]``, and the per-slot ``pos``."""
+    check_tp(cfg, tp)
+    return init_paged_pool(cache_spec(cfg, kv_dtype), slots, n_pages,
+                           page_size)
+
+
+def max_positions(cfg: KDAMoEConfig) -> int:
+    """No positions are encoded: the model's declared reach."""
+    return cfg.max_seq
+
+
+def check_tp(cfg: KDAMoEConfig, tp: int):
+    if int(tp) > 1:
+        raise ValueError(f"tp={tp}: " + UNSUPPORTED["tp"])
+    return None
+
+
+def shard_params(params: Params, cfg: KDAMoEConfig, tp: int) -> Params:
+    check_tp(cfg, tp)
+    return params
+
+
+# -------------------------------------------------------------- programs
+def _put(pool, rows, *start):
+    """``rows`` written at ``pool[start]`` in place (the leading
+    indices; a traced slot among them)."""
+    lead = len(start)
+    return lax.dynamic_update_slice(
+        pool, rows.astype(pool.dtype)[(None,) * lead],
+        tuple(start) + (0,) * (pool.ndim - lead))
+
+
+def prefill_into_slot_paged(params: Params, cache: Cache,
+                            tokens: jax.Array, length: jax.Array,
+                            hist_len: jax.Array, pt_row: jax.Array,
+                            cow_src: jax.Array, slot: jax.Array,
+                            rng: jax.Array, *, cfg: KDAMoEConfig,
+                            page_size: int, temperature: float = 0.0,
+                            kv_dtype: str = "fp"
+                            ) -> Tuple[jax.Array, Cache, jax.Array]:
+    """Prefill one WHOLE prompt into its pages and its slot, with the
+    first token's sample: the frame of
+    :func:`ray_tpu.models.gpt_decode.prefill_into_slot_paged`. The GQA
+    layers' keys and values go to the pages ``pt_row`` names; every KDA
+    layer's state and convolution tail are rebuilt FROM ZERO and written
+    over whatever slot ``slot`` held (the last request's, a preempted
+    lane's, the warm-up's): a prefill is the one way a slot's state
+    begins. Rows past ``length`` (the bucket's padding) write no page
+    and advance neither state nor tail. ``hist_len`` and ``cow_src`` are
+    the frame's and have no meaning here: without a prefix cache
+    (:data:`UNSUPPORTED`) the engine's are always 0 and the sentinel."""
+    del hist_len, cow_src
+    S = tokens.shape[1]
+    ps = page_size
+    n_pages = cache["k"].shape[1]
+    max_pages = pt_row.shape[0]
+    x = _embed(params, tokens)[0]                            # [S, d]
+    live = jnp.arange(S) < length
+    wpos = jnp.arange(S)
+    vp = wpos // ps
+    page_w = jnp.where(live & (vp < max_pages),
+                       pt_row[jnp.clip(vp, 0, max_pages - 1)],
+                       jnp.int32(PT_SENTINEL))
+    kpool, vpool = _flat(cache["k"]), _flat(cache["v"])
+    state, conv = cache["state"], cache["conv"]
+    back = cfg.conv_size - 1
+    ig = ik = 0
+    for l, p in enumerate(params["layers"]):
+        h = _rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+        if l in cfg.gqa_layers:
+            q, k, v, z = _gqa_qkvz(h, p, cfg)
+            with jax.named_scope("gqa.prefill"):
+                att = _gqa_causal(q, k, v, cfg)
+            at = (_at_layer(page_w, ig, n_pages), wpos % ps)
+            kpool = kpool.at[at].set(k, mode="drop")
+            vpool = vpool.at[at].set(v, mode="drop")
+            y = _gqa_out(att, z, p, cfg)
+            ig += 1
+        else:
+            y, S_end, padded = _kda_sequence(h, p, cfg, live)
+            state = _put(state, S_end, ik, slot)
+            conv = _put(conv, lax.dynamic_slice(
+                padded, (length, 0), (back, padded.shape[1])), ik, slot)
+            ik += 1
+        x = _ffn(x + y.astype(x.dtype), p, cfg, live)[0]
+    x_last = lax.dynamic_slice(x, (length - 1, 0), (1, cfg.d_model))
+    token, rng = _sample(_head(x_last, params, cfg), temperature, rng)
+    pos = lax.dynamic_update_slice(
+        cache["pos"], jnp.reshape(length, (1,)).astype(jnp.int32), (slot,))
+    return token[0], {"k": kpool.reshape(cache["k"].shape),
+                      "v": vpool.reshape(cache["v"].shape),
+                      "state": state, "conv": conv, "pos": pos}, rng
+
+
+def _slot_decode_step_paged(params: Params, cache: Cache,
+                            token: jax.Array, active: jax.Array,
+                            pt: jax.Array, cfg: KDAMoEConfig,
+                            page_size: int, kv_dtype: str = "fp",
+                            attn_kernel: str = "gather"):
+    """One masked decode step over the whole slot pool: each active lane
+    writes its keys and values at its own position and attends over its
+    pages (GQA layers), and reads and writes its state and convolution
+    tail whole (KDA layers). An inactive lane (idle, or parked for
+    pages) neither writes, advances nor routes: its state and tail come
+    out as they went in. Returns ``(logits [B, rows], cache', counts)``:
+    int32 [5] (:data:`STEP_COUNTERS`)."""
+    ps = page_size
+    max_pages = pt.shape[1]
+    pos = cache["pos"]
+    n_pages = cache["k"].shape[1]
+    x = _embed(params, token)                                # [B, d]
+    vp = pos // ps
+    page_w = jnp.where(
+        active & (vp < max_pages),
+        jnp.take_along_axis(pt, jnp.clip(vp, 0, max_pages - 1)[:, None],
+                            axis=1)[:, 0], jnp.int32(PT_SENTINEL))
+    ptc = jnp.clip(pt, 0, n_pages - 1)
+    kpool, vpool = _flat(cache["k"]), _flat(cache["v"])
+    state, conv = cache["state"], cache["conv"]
+    counts = jnp.zeros((4,), jnp.int32)
+    ig = ik = 0
+    # the step's own scope: a reader tells the decode program's state,
+    # attention and expert time from prefill's by it
+    with jax.named_scope("decode_step"):
+        for l, p in enumerate(params["layers"]):
+            h = _rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+            if l in cfg.gqa_layers:
+                q, k, v, z = _gqa_qkvz(h, p, cfg)
+                at = (_at_layer(page_w, ig, n_pages), pos % ps)
+                kpool = kpool.at[at].set(k, mode="drop")
+                vpool = vpool.at[at].set(v, mode="drop")
+                with jax.named_scope("gqa.attention"):
+                    att = _gqa_attention_gather(
+                        q, kpool, vpool, ptc + ig * n_pages, pos, cfg, ps)
+                y = _gqa_out(att, z, p, cfg)
+                ig += 1
+            else:
+                with jax.named_scope("kda.proj"):
+                    pre, g, beta, gate = _kda_proj(h, p, cfg)
+                    tail = conv[ik]                       # [B, back, 3 W]
+                    window = [tail[:, i] for i in range(tail.shape[1])] \
+                        + [pre]
+                    q, k, v = _kda_qkv(window, p, cfg)
+                    conv = conv.at[ik].set(jnp.where(
+                        active[:, None, None],
+                        jnp.stack(window[1:], axis=1), tail))
+                with jax.named_scope("kda.state"):
+                    S = state[ik].astype(jnp.float32)
+                    S_new, o = _kda_step(S, q, k, v, g, beta)
+                    state = state.at[ik].set(jnp.where(
+                        active[:, None, None, None], S_new, S
+                    ).astype(state.dtype))
+                with jax.named_scope("kda.proj"):
+                    y = _kda_out(o, gate, p, cfg)
+                ik += 1
+            x, c = _ffn(x + y.astype(x.dtype), p, cfg, active)
+            counts = counts + c
+    cache_out = {"k": kpool.reshape(cache["k"].shape),
+                 "v": vpool.reshape(cache["v"].shape),
+                 "state": state, "conv": conv,
+                 "pos": pos + active.astype(jnp.int32)}
+    counts = jnp.concatenate(
+        [counts, jnp.sum(active, dtype=jnp.int32)[None]])
+    return _head(x, params, cfg), cache_out, counts
+
+
+def decode_chunk_slots_paged(params: Params, cache: Cache,
+                             token: jax.Array, rngs: jax.Array,
+                             active: jax.Array, pt: jax.Array, *,
+                             cfg: KDAMoEConfig, k: int, page_size: int,
+                             temperature: float = 0.0,
+                             eos_token: int = -1, kv_dtype: str = "fp",
+                             attn_kernel: str = "gather"):
+    """k fused decode steps over the slot pool in ONE program: the
+    frame of :func:`ray_tpu.models.gpt_decode.decode_chunk_slots_paged`
+    around this model's step. The cache (pages AND per-slot state) is
+    the scan's carry, donated: a step updates it in place. Returns
+    ``(tokens [B, k], cache', done [B], rngs', counts int32 [5])``."""
+    B = token.shape[0]
+    eos = jnp.asarray(eos_token, jnp.int32)
+    done0 = (active & (token == eos)) if eos_token >= 0 \
+        else jnp.zeros((B,), jnp.bool_)
+
+    def body(carry, _):
+        cache, tok, done, keys, counts = carry
+        logits, cache, c = _slot_decode_step_paged(
+            params, cache, tok, active, pt, cfg, page_size, kv_dtype,
+            attn_kernel)
+        nxt, keys = _sample_slots(logits, temperature, keys)
+        if eos_token >= 0:
+            nxt = jnp.where(done, eos, nxt)
+            done = done | (active & (nxt == eos))
+        return (cache, nxt, done, keys, counts + c), nxt
+
+    (cache, _, done, rngs, counts), toks = lax.scan(
+        body, (cache, token, done0, rngs,
+               jnp.zeros((len(STEP_COUNTERS),), jnp.int32)),
+        None, length=k)
+    return jnp.moveaxis(toks, 0, 1), cache, done, rngs, counts
+
+
+# rtlint: program-budget: len(prompt_buckets)
+@_knob_cache
+def jit_prefill_into_slot_paged(cfg: KDAMoEConfig, page_size: int,
+                                temperature: float = 0.0,
+                                kv_dtype: str = "fp", tp: int = 1):
+    """Jitted :func:`prefill_into_slot_paged`: one compiled program per
+    prompt bucket per (cfg, page_size, temperature) key. The cache is
+    donated."""
+    check_tp(cfg, tp)
+    cache_spec(cfg, kv_dtype)
+    return jax.jit(_program(prefill_into_slot_paged, cfg=cfg,
+                            page_size=page_size,
+                            temperature=temperature, kv_dtype=kv_dtype),
+                   donate_argnums=(1,))
+
+
+# rtlint: program-budget: 1
+@_knob_cache
+def jit_decode_chunk_slots_paged(cfg: KDAMoEConfig, k: int,
+                                 page_size: int,
+                                 temperature: float = 0.0,
+                                 eos_token: int = -1,
+                                 kv_dtype: str = "fp",
+                                 attn_kernel: str = "gather",
+                                 tp: int = 1):
+    """Jitted :func:`decode_chunk_slots_paged`: ONE program per (pool
+    shape, k, page_size); the page table is data. Cache donated."""
+    check_tp(cfg, tp)
+    cache_spec(cfg, kv_dtype)
+    if attn_kernel not in ATTN_KERNELS:
+        raise ValueError(
+            f"attn_kernel must be one of {ATTN_KERNELS}, got "
+            f"{attn_kernel!r}")
+    return jax.jit(_program(decode_chunk_slots_paged, cfg=cfg, k=k,
+                            page_size=page_size,
+                            temperature=temperature,
+                            eos_token=eos_token, kv_dtype=kv_dtype,
+                            attn_kernel=attn_kernel),
+                   donate_argnums=(1,))

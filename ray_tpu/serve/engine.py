@@ -63,7 +63,9 @@ positions, so a default engine can hold every slot at full length:
   re-admission the deterministic per-request PRNG lane replays the
   exact same tokens with the already-delivered prefix suppressed — the
   consumer sees a stall, never an error or a duplicate token.
-- A **prefix cache** (``prefix_cache=True``, the default) hashes prompt
+- A **prefix cache** (the default wherever the model's description can
+  have one; ``prefix_cache=True`` for one that cannot, a model with
+  per-slot state, raises its reason) hashes prompt
   prefixes at page granularity: a request whose prompt prefix is
   already resident maps the cached pages into its table (refcounted),
   prefills only the suffix, and — when the cached prefix ends mid-page
@@ -449,7 +451,7 @@ class DecodeEngine:
                  prompt_buckets: Optional[Sequence[int]] = None,
                  deployment: str = "", auto_start: bool = True,
                  paged: bool = True, page_size: int = 16,
-                 n_pages: int = 0, prefix_cache: bool = True,
+                 n_pages: int = 0, prefix_cache: Optional[bool] = None,
                  wedge_timeout_s: float = 30.0,
                  max_driver_restarts: int = 1,
                  spec_decode=None, draft_k: int = 4,
@@ -501,6 +503,11 @@ class DecodeEngine:
                      f"spec_decode={spec_decode!r}")
         self._refuse("int8", kv_dtype == "int8", "kv_dtype='int8'")
         self._refuse("tp", int(tp) > 1, f"tp={tp}")
+        self._refuse("prefix_cache", prefix_cache is True,
+                     "prefix_cache=True")
+        if prefix_cache is None:
+            # the default follows what the model can have
+            prefix_cache = "prefix_cache" not in model.UNSUPPORTED
         self.role = role
         self._leases = LeaseTable(ttl_s=float(handoff_ttl_s))
         # ---- speculative decoding (ISSUE 9): an optional drafter turns
@@ -765,6 +772,8 @@ class DecodeEngine:
                 f"unknown attn_kernel {attn_kernel!r}; expected one of "
                 f"{self._model.ATTN_KERNELS}")
         self._refuse("int8", kv_dtype == "int8", "kv_dtype='int8'")
+        self._refuse("prefix_cache", prefix_cache is True,
+                     "prefix_cache=True")
         if kv_dtype is not None and kv_dtype not in self._model.KV_DTYPES:
             raise ValueError(
                 f"unknown kv_dtype {kv_dtype!r}; expected one of "
@@ -1600,9 +1609,14 @@ class DecodeEngine:
             out["prefix_evictions"] = self._prefix.evictions
         out["attn_kernel"] = self.attn_kernel
         out["kv_dtype"] = self.kv_dtype
-        out["kv_bytes_per_token"] = self._model.cache_spec(
-            self.cfg, self.kv_dtype).bytes_per_page(self.page_size) \
-            / self.page_size
+        spec = self._model.cache_spec(self.cfg, self.kv_dtype)
+        out["kv_bytes_per_token"] = \
+            spec.bytes_per_page(self.page_size) / self.page_size
+        # What a sequence keeps in its SLOT whatever its length (a
+        # recurrent state; 0 for a model that keeps all in pages), and
+        # what the pool gives to it rather than to pages.
+        out["state_bytes_per_slot"] = spec.bytes_per_slot()
+        out["state_bytes"] = self.slots * out["state_bytes_per_slot"]
         return out
 
     def _count(self, **deltas):

@@ -54,7 +54,8 @@ from .core import Module
 
 #: Modules a config object's ``decode_programs()`` may answer with, in
 #: the order a ``self.X = <...>.decode_programs(cfg)`` alias tries them.
-DESCRIPTION_MODULES = ("models/gpt_decode.py", "models/mla_moe.py")
+DESCRIPTION_MODULES = ("models/gpt_decode.py", "models/mla_moe.py",
+                       "models/kda_moe.py")
 
 
 def self_attr(node) -> Optional[str]:

@@ -104,7 +104,7 @@ BUCKETS_RE = re.compile(r"(buckets|tps|meshes)$")
 #: results here are sync-audited (RT111, minus gpt_decode whose host
 #: loops are the library surface, not the engine driver).
 BUDGET_SCOPE = ("models/gpt_decode.py", "models/mla_moe.py",
-                "serve/engine.py",
+                "models/kda_moe.py", "serve/engine.py",
                 "serve/draft.py", "serve/handoff.py", "data/llm.py")
 SYNC_SCOPE = ("serve/engine.py", "serve/draft.py", "serve/handoff.py",
               "data/llm.py")

@@ -795,11 +795,11 @@ class Sanitizer:
 
     def _wrap_jit_factories(self):
         try:
-            from ray_tpu.models import gpt_decode, mla_moe
+            from ray_tpu.models import gpt_decode, kda_moe, mla_moe
         except Exception:  # noqa: BLE001 - gated: no device surface here
             return
         # every model description the engine may be handed
-        for module in (gpt_decode, mla_moe):
+        for module in (gpt_decode, mla_moe, kda_moe):
             for name in dir(module):
                 if not name.startswith("jit_"):
                     continue
