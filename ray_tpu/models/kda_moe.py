@@ -44,10 +44,18 @@ sequence, and :func:`cache_spec` describes both in one
   of the three projections: entries ``state`` and ``conv``, ``per
   "slot"``, ``n_kda`` layers. They belong to the SLOT: every prefill
   into a slot rebuilds them from zero (:func:`prefill_into_slot_paged`,
-  the chunked form, scope ``kda.prefill``), every decode step reads and
-  writes an active lane's state whole, in place (:func:`_kda_step`, scope
-  ``kda.state``), and an idle or parked lane's is left as it is. So no
+  the chunked form, scope ``kda.prefill``), every decode step reads an
+  active lane's state ONCE and writes it once, in place (scope
+  ``kda.state``), and moves no byte of an idle or parked lane's. So no
   page hash shares them and nothing rolls back: :data:`UNSUPPORTED`.
+  The step's recurrence is a Pallas kernel (:func:`_kda_step_pallas`:
+  a block of a live lane's heads in VMEM, both sums, the decay, the
+  rank-one update and ``o`` from that one copy in float32, the whole
+  per-slot entry aliased in and out) wherever Mosaic can address a
+  head's state (:func:`decode_attention_fused`: ``kda_head_dim`` a
+  multiple of 128 on a TPU, any width interpreted off it), chosen by
+  shape under the model's one ``attn_kernel`` name; elsewhere
+  :func:`_kda_step` in plain XLA, which is also the tests' oracle.
 
 **MoE**, every layer: :func:`ray_tpu.models.moe.dropless_moe` with one
 group (sigmoid scores over ``n_routed`` experts, plain top k,
@@ -106,6 +114,14 @@ STEP_COUNTERS = ("moe_steps", "moe_experts_touched_sum",
 #: keys and values of ALL lanes (``slots x max_len`` rows) would be a
 #: temporary as large as the pool.
 _GQA_LANE_BLOCK = 32
+#: Heads of a lane's state the recurrence's kernel holds in VMEM at
+#: once (the largest common divisor with ``kda_heads``): 32 heads of
+#: [128, 128] float32 are 2 MiB, 8 MiB with the block before and the
+#: block after in flight, in and out (of the 16 MiB a kernel may take
+#: on a v5e), and their four columns a head fill one 128-lane tile.
+#: Measured there: 8 / 16 / 32 heads 4.16 / 3.63 / 3.46 ms a layer of
+#: 254 lanes, the copies alone 3.30 (PERF.md section 6, PR 40).
+_KDA_BLOCK_HEADS = 32
 _HI = lax.Precision.HIGHEST
 
 
@@ -297,21 +313,154 @@ def _kda_out(o, gate, p, cfg: KDAMoEConfig):
 
 
 def _kda_step(S, q, k, v, g, beta):
-    """The recurrence, one token a lane: ``S`` [B, H, dk, dv], ``q``
-    ``k`` ``g`` [B, H, dk], ``v`` [B, H, dv], ``beta`` [B, H], float32.
-    Returns ``(S', o [B, H, dv])``. Written as elementwise products and
-    sums over ``S`` (a matrix-vector product a head is no work for the
-    MXU): ``S^T (alpha k)`` and ``S^T (alpha q)`` each read ``S`` (XLA
-    makes them two reductions, not one), a third pass reads it again
-    and writes ``S'``; ``o = S'^T q`` follows from the two sums without
-    a fourth. A kernel that keeps a lane's state in fast memory for the
-    step would read it once (``kda_state_roofline_pct``)."""
+    """The recurrence, one token a lane, in plain XLA: the fallback
+    where :func:`decode_attention_fused` is false, and the oracle the
+    kernel (:func:`_kda_step_pallas`) is tested against. ``S`` [B, H,
+    dk, dv], ``q`` ``k`` ``g`` [B, H, dk], ``v`` [B, H, dv], ``beta``
+    [B, H], float32. Returns ``(S', o [B, H, dv])``. Written as
+    elementwise products and sums over ``S`` (a matrix-vector product
+    a head is no work for the MXU): ``S^T (alpha k)`` and ``S^T (alpha
+    q)`` each read ``S`` (XLA makes them two reductions, not one), a
+    third pass reads it again and writes ``S'``; ``o = S'^T q`` follows
+    from the two sums without a fourth. Three reads where the kernel
+    has one: 42% of the state's bandwidth bound on a v5e."""
     a = jnp.exp(g)
     u = jnp.sum(S * (k * a)[..., None], axis=-2)            # S^T (a k)
     w = jnp.sum(S * (q * a)[..., None], axis=-2)            # S^T (a q)
     du = beta[..., None] * (v - u)
     S = S * a[..., None] + k[..., None] * du[..., None, :]
     return S, w + jnp.sum(k * q, axis=-1, keepdims=True) * du
+
+
+def decode_attention_fused(cfg: KDAMoEConfig, page_size: int,
+                           attn_kernel: str = "gather") -> bool:
+    """Whether the chunk program built with these knobs holds a Pallas
+    kernel (the description's optional entry,
+    :mod:`ray_tpu.models.serving`). This model's is the RECURRENCE
+    (:func:`_kda_step_pallas`), taken wherever Mosaic can address a
+    head's state as whole float32 tiles with ``dk`` on sublanes and
+    ``dv`` on lanes: ``kda_head_dim`` a multiple of 128 compiled for a
+    TPU, any width interpreted off it; elsewhere :func:`_kda_step`. The
+    GQA layer's attention is plain XLA either way; ``page_size`` and
+    ``attn_kernel`` (one value) have no say."""
+    from .._private.chip import pallas_interpret
+
+    return pallas_interpret() or cfg.kda_head_dim % 128 == 0
+
+
+def _live_lanes(active):
+    """``active`` [B] bool -> ``(lanes int32 [B], n int32 [1])``: the
+    live lanes' indices in lane order, then the last of them repeated
+    (lane 0 where none is live), and their count: the scalar operands
+    of :func:`_kda_step_pallas` (the same for every layer of a step:
+    XLA keeps one of them)."""
+    B = active.shape[0]
+    n = jnp.sum(active, dtype=jnp.int32)
+    lanes = jnp.nonzero(active, size=B, fill_value=0)[0].astype(jnp.int32)
+    return jnp.where(jnp.arange(B) < n, lanes,
+                     lanes[jnp.maximum(n - 1, 0)]), n[None]
+
+
+def _kda_step_pallas(state, layer: int, q, k, v, g, beta, active):
+    """:func:`_kda_step` on layer ``layer`` of the whole per-slot entry
+    ``state`` [n_kda, B, H, dk, dv], IN PLACE, as one kernel that reads
+    a live lane's state once and writes it once. ``q`` ``k`` ``g`` [B,
+    H, dk], ``v`` [B, H, dv], ``beta`` [B, H] float32, ``active`` [B]
+    bool. Returns ``(state', o [B, H, dv])`` with zeros in ``o`` for an
+    inactive lane, whose state no byte of is moved.
+
+    Grid ``(B, H / hb)``: step ``(i, j)`` is block ``j``
+    (:data:`_KDA_BLOCK_HEADS` heads, ``[hb, dk, dv]``) of the ``i``-th
+    LIVE lane, named by the scalar-prefetched :func:`_live_lanes`; the
+    steps past
+    the last live lane name the block before them again, which the
+    pipeline neither fetches nor writes twice, and do nothing. The
+    state is the kernel's input AND output (``input_output_aliases`` on
+    the whole entry, the layer in the index map): a block comes into
+    VMEM, both sums ``S^T (alpha k)`` and ``S^T (alpha q)``, the decay,
+    the rank-one update and ``o`` are taken from that one copy in
+    float32 on the VPU (``dk`` lies on sublanes: a sum over it adds
+    whole vregs), and the block goes back where it came from. The
+    vector operands are laid out by the caller's XLA, under the same
+    scope: ``alpha k``, ``alpha q``, ``alpha`` and ``k`` TRANSPOSED
+    ``[B, H / hb, dk, 4 hb]`` so that a head's is a column over ``dk``
+    (one lane, broadcast over ``dv``), and ``v``, ``beta`` and ``k . q``
+    as rows over ``dv``. Where no lane is live the one block named is
+    copied through, so that the aliased entry comes out as it went
+    in."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .._private.chip import pallas_interpret
+
+    B, H, dk = q.shape
+    dv = v.shape[-1]
+    hb = math.gcd(H, _KDA_BLOCK_HEADS)
+    nb = H // hb
+    a = jnp.exp(g)
+    cols = jnp.stack([k * a, q * a, a, k], axis=2)           # [B, H, 4, dk]
+    cols = cols.reshape(B, nb, hb, 4, dk).transpose(0, 1, 4, 3, 2) \
+        .reshape(B, nb, dk, 4 * hb)
+    rows = jnp.stack(
+        [jnp.broadcast_to(r, v.shape).reshape(B, nb, hb, dv) for r in
+         (v, beta[..., None], jnp.sum(k * q, axis=-1, keepdims=True))],
+        axis=2)                                      # [B, nb, 3, hb, dv]
+    lanes, n = _live_lanes(active)
+
+    def kernel(lanes_ref, n_ref, s_ref, cols_ref, rows_ref, s_out, o_ref):
+        i, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(i < n_ref[0])
+        def _():
+            c = cols_ref[...]                                # [dk, 4 hb]
+            for h in range(hb):
+                S = s_ref[h].astype(jnp.float32)             # [dk, dv]
+                ak, aq, al, kk = (c[:, m * hb + h:m * hb + h + 1]
+                                  for m in range(4))         # [dk, 1]
+                u = jnp.sum(S * ak, axis=0, keepdims=True)   # [1, dv]
+                w = jnp.sum(S * aq, axis=0, keepdims=True)
+                vv, bb, kq = (rows_ref[m, h:h + 1] for m in range(3))
+                du = bb * (vv - u)
+                s_out[h] = (S * al + kk * du).astype(s_out.dtype)
+                o_ref[h:h + 1] = w + kq * du
+
+        @pl.when((n_ref[0] == 0) & (i == 0) & (j == 0))
+        def _():
+            s_out[...] = s_ref[...]
+
+    def at(*lead, rest=2):
+        """The index map of an operand whose leading indices are
+        ``lead`` (statics), then the lane and the block of heads, then
+        ``rest`` whole dimensions."""
+        def index(i, j, lanes_ref, n_ref):
+            return lead + (lanes_ref[i],
+                           jnp.where(i < n_ref[0], j, nb - 1)) + (0,) * rest
+        return index
+
+    state, o = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, nb),
+            in_specs=[
+                pl.BlockSpec((None, None, hb, dk, dv), at(layer)),
+                pl.BlockSpec((None, None, dk, 4 * hb), at()),
+                pl.BlockSpec((None, None, 3, hb, dv), at(rest=3)),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, None, hb, dk, dv), at(layer)),
+                pl.BlockSpec((None, None, hb, dv), at()),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((B, nb, hb, dv), jnp.float32)],
+        # operand 2 (after the two scalar operands) is the state
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=pallas_interpret(),
+        name="kda_state",
+    )(lanes, n, state, cols, rows)
+    return state, jnp.where(active[:, None, None], o.reshape(B, H, dv), 0.0)
 
 
 def _kda_chunked(q, k, v, g, beta, S0, chunk: int):
@@ -629,10 +778,12 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     """One masked decode step over the whole slot pool: each active lane
     writes its keys and values at its own position and attends over its
     pages (GQA layers), and reads and writes its state and convolution
-    tail whole (KDA layers). An inactive lane (idle, or parked for
-    pages) neither writes, advances nor routes: its state and tail come
-    out as they went in. Returns ``(logits [B, rows], cache', counts)``:
-    int32 [5] (:data:`STEP_COUNTERS`)."""
+    tail whole (KDA layers: the recurrence as the kernel wherever
+    :func:`decode_attention_fused`, else :func:`_kda_step`). An
+    inactive lane (idle, or parked for pages) neither writes, advances
+    nor routes: its state and tail come out as they went in. Returns
+    ``(logits [B, rows], cache', counts)``: int32 [5]
+    (:data:`STEP_COUNTERS`)."""
     ps = page_size
     max_pages = pt.shape[1]
     pos = cache["pos"]
@@ -648,6 +799,7 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     state, conv = cache["state"], cache["conv"]
     counts = jnp.zeros((4,), jnp.int32)
     ig = ik = 0
+    fused = decode_attention_fused(cfg, ps, attn_kernel)
     # the step's own scope: a reader tells the decode program's state,
     # attention and expert time from prefill's by it
     with jax.named_scope("decode_step"):
@@ -674,11 +826,15 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                         active[:, None, None],
                         jnp.stack(window[1:], axis=1), tail))
                 with jax.named_scope("kda.state"):
-                    S = state[ik].astype(jnp.float32)
-                    S_new, o = _kda_step(S, q, k, v, g, beta)
-                    state = state.at[ik].set(jnp.where(
-                        active[:, None, None, None], S_new, S
-                    ).astype(state.dtype))
+                    if fused:
+                        state, o = _kda_step_pallas(
+                            state, ik, q, k, v, g, beta, active)
+                    else:
+                        S = state[ik].astype(jnp.float32)
+                        S_new, o = _kda_step(S, q, k, v, g, beta)
+                        state = state.at[ik].set(jnp.where(
+                            active[:, None, None, None], S_new, S
+                        ).astype(state.dtype))
                 with jax.named_scope("kda.proj"):
                     y = _kda_out(o, gate, p, cfg)
                 ik += 1

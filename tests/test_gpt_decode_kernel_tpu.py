@@ -1,5 +1,6 @@
-"""The decode attention kernels (``gpt_decode``'s over pages of keys
-and values per head, ``mla_moe``'s over latent pages) compiled by the
+"""The decode step's kernels (``gpt_decode``'s attention over pages of
+keys and values per head, ``mla_moe``'s over latent pages, ``kda_moe``'s
+recurrence on the per-slot state) compiled by the
 TPU's own compiler, for a chip that is described and not attached
 (v5e), at the shapes the chip runs: what interpret mode cannot see —
 a slice off the tiling, a DMA Mosaic cannot address, more VMEM than a
@@ -192,3 +193,91 @@ def test_the_latent_step_chooses_by_the_page_for_v5e(one_chip,
     assert ("decode_step/mla.attention/latent_attention/pallas_call"
             in text) is fused
     assert ("tpu_custom_call" in text) is fused
+
+
+# ------------------------------- the gated delta-rule recurrence (S5f)
+#: (n_kda, lanes): the cell's per-slot entry, and the benchmark's
+#: reference check (48 rows on a pool of their own)
+KDA_SHAPES = {"cell": (3, 256), "check": (3, 48)}
+
+
+@pytest.mark.parametrize("shape", sorted(KDA_SHAPES))
+def test_kda_state_kernel_compiles_for_v5e(one_chip, compiled_mode, shape):
+    """The recurrence's kernel at the served widths (64 heads of a
+    [128, 128] float32 state): Mosaic takes a head's columns as one
+    lane of the transposed operand, the whole per-slot entry is the
+    kernel's operand AND result (aliased: no copy of it, whichever
+    layer is named), one ``tpu_custom_call`` a layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import kda_moe
+
+    L, B = KDA_SHAPES[shape]
+    H, D = 64, 128
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(state, q, k, v, g, beta, active):
+        for layer in range(L):
+            state, o = kda_moe._kda_step_pallas(state, layer, q, k, v, g,
+                                                beta, active)
+            q = q + o
+        return state, q
+
+    lowered = jax.jit(step, donate_argnums=(0,)).lower(
+        arg((L, B, H, D, D)), arg((B, H, D)), arg((B, H, D)),
+        arg((B, H, D)), arg((B, H, D)), arg((B, H)),
+        arg((B,), jnp.bool_))
+    assert chip.compiled_by_mosaic(lowered.as_text())
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == L
+    assert "kda_state/pallas_call" in text
+    state_bytes = L * B * H * D * D * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 16
+
+
+def test_the_kda_step_chooses_by_the_head_for_v5e(one_chip, compiled_mode):
+    """The whole decode step as a TPU process builds it: with heads of
+    128 the kernel, under the path the benchmark's readers look for
+    (``decode_step/kda.state``); with heads of 64 (half a lane tile)
+    ``_kda_step``, and nothing raises."""
+    import dataclasses
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import kda_moe
+
+    for hd, fused in ((128, True), (64, False)):
+        cfg = dataclasses.replace(kda_moe.CONFIGS["nano"], d_model=256,
+                                  kda_heads=16, kda_head_dim=hd,
+                                  head_dim=128, experts_held=8)
+        B, ps, n_pages = 8, 16, 32
+
+        def arg(s):
+            return jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                        sharding=one_chip)
+
+        params = jax.eval_shape(
+            lambda k: kda_moe.init_params(k, cfg), jax.random.PRNGKey(0))
+        cache = jax.eval_shape(
+            lambda: kda_moe.init_paged_cache(cfg, B, n_pages, ps))
+        args = (params, cache, jax.ShapeDtypeStruct((B,), jnp.int32),
+                jax.ShapeDtypeStruct((B,), jnp.bool_),
+                jax.ShapeDtypeStruct((B, n_pages // B), jnp.int32))
+        assert kda_moe.decode_attention_fused(cfg, ps) is fused
+        lowered = jax.jit(functools.partial(
+            kda_moe._slot_decode_step_paged, cfg=cfg, page_size=ps),
+            donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
+        assert chip.compiled_by_mosaic(lowered.as_text()) is fused
+        text = lowered.compile().as_text()
+        assert ("decode_step/kda.state/kda_state/pallas_call"
+                in text) is fused

@@ -312,9 +312,10 @@ def test_warm_up_leaves_nothing_a_request_reads(model):
         report = b.warm_up()
         assert set(report["programs"]) == {"prefill_16", "prefill_32",
                                            "prefill_64", "chunk"}
-        assert report["attn_kernel_mode"] is None     # plain XLA
+        # the recurrence's kernel, interpreted off the TPU
+        assert report["attn_kernel_mode"] == "interpret"
         assert np.array_equal(_answer(a, prompt, 9), _answer(b, prompt, 9))
-        assert b.stats()["attn_kernel_dispatches"] == 0
+        assert b.stats()["attn_kernel_dispatches"] > 0
     finally:
         a.shutdown()
         b.shutdown()
@@ -461,4 +462,4 @@ def test_the_programs_keep_the_names_a_trace_shows(model):
         cfg, 4, 4).__wrapped__.__name__ == "decode_chunk_slots_paged"
     assert kda_moe.jit_prefill_into_slot_paged(
         cfg, 4).__wrapped__.__name__ == "prefill_into_slot_paged"
-    assert not hasattr(kda_moe, "decode_attention_fused")
+    assert kda_moe.decode_attention_fused(cfg, 4)     # interpreted here
