@@ -190,6 +190,17 @@ from .request import (RequestDeadlineExceeded, deadline_expired,
 #: trimming, frees); and the rest of the loop.
 _PHASES = ("idle", "admit", "prefill", "cover", "decode", "deliver",
            "other")
+#: The counted parts of a phase (``stats()["driver_ns_<phase>_<step>"]``,
+#: inside the phase's self time): a prefill's request key, the call into
+#: the compiled program and the first token's read; a decode (or verify)
+#: launch's call into the compiled program from the phase's start to its
+#: return, the wait for the device, and the results' read.
+_STEPS = ("prefill.key", "prefill.dispatch", "prefill.read",
+          "decode.enqueue", "decode.wait", "decode.read")
+#: The phases in which the driver holds no dispatch, so that wall minus
+#: CPU time (``stats()["driver_cpu_ns_<phase>"]``) is the thread waiting
+#: for the interpreter lock or a processor, not for the device.
+_HOST_PHASES = ("admit", "cover", "deliver", "other")
 
 
 def default_prompt_buckets(max_len: int) -> List[int]:
@@ -610,20 +621,28 @@ class DecodeEngine:
                        # host and the suffix tokens prefilled over
                        # `prefills`; and, before each decode/verify
                        # dispatch, the time since the previous one's
-                       # tokens were read while a lane stayed occupied
+                       # tokens were read while a lane stayed occupied,
+                       # and the part of it spent in prefill phases
                        "admission_wait_ns_sum": 0, "prefill_ns_sum": 0,
-                       "prefill_tokens_sum": 0, "decode_gap_ns_sum": 0}
+                       "prefill_tokens_sum": 0, "decode_gap_ns_sum": 0,
+                       "decode_gap_prefill_ns_sum": 0}
         # Counters the model's chunk program returns with its tokens
         # (an expert layer's load), summed per dispatch.
         self._stats.update(dict.fromkeys(model.STEP_COUNTERS, 0))
-        # What the driver thread is doing, by phase (self time, ns):
-        # written by the driver alone through its PhaseClock, read
-        # racily by stats(). Outlives driver restarts.
-        self._driver_ns = dict.fromkeys(_PHASES + ("total",), 0)
+        # What the driver thread is doing, by phase (self time, ns) and
+        # by counted step of a phase, and the thread's CPU time in the
+        # host's phases (``cpu.<phase>``): written by the driver alone
+        # through its PhaseClock, read racily by stats(). Outlives
+        # driver restarts.
+        self._driver_ns = dict.fromkeys(
+            _PHASES + ("total",) + _STEPS
+            + tuple(f"cpu.{ph}" for ph in _HOST_PHASES), 0)
         self._phases: Optional[tracing.PhaseClock] = None
         # monotonic ns at which the last decode/verify dispatch's tokens
-        # were read, while a lane has stayed occupied since; else None
+        # were read, while a lane has stayed occupied since; else None.
+        # Beside it what the prefill phases had taken by then.
         self._decode_read_ns: Optional[int] = None
+        self._decode_read_prefill_ns = 0
         self._compiles = tracing.compile_counts()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -1540,7 +1559,8 @@ class DecodeEngine:
         out["avg_occupancy"] = out.pop("occupancy_sum") / d
         out["dispatches_per_token"] = (
             (out["dispatches"] + out["prefills"]) / max(out["tokens"], 1))
-        out.update({f"driver_ns_{ph}": ns
+        out.update({f"driver_cpu_ns_{ph[4:]}" if ph.startswith("cpu.")
+                    else f"driver_ns_{ph.replace('.', '_')}": ns
                     for ph, ns in self._driver_ns.items()})
         out["compiles"] = self._compiles["n"]
         out["compile_ns"] = self._compiles["ns"]
@@ -1605,7 +1625,6 @@ class DecodeEngine:
         out["parked_slots"] = sum(
             s is not None and s.parked for s in self._state)
         if self._prefix is not None:
-            out["prefix_cache_entries"] = len(self._prefix)
             out["prefix_evictions"] = self._prefix.evictions
         out["attn_kernel"] = self.attn_kernel
         out["kv_dtype"] = self.kv_dtype
@@ -2004,8 +2023,7 @@ class DecodeEngine:
         prefix = self._prefix
         hist, shared_pages = (0, [])
         if prefix is not None:
-            with self._phases.step("lookup"):
-                hist, shared_pages = prefix.lookup(req.prompt)
+            hist, shared_pages = prefix.lookup(req.prompt)
         shared_full = hist // ps
         partial = hist % ps
         cow_src = shared_pages[shared_full] if partial else \
@@ -2018,8 +2036,7 @@ class DecodeEngine:
             pool.ref([cow_src])
         n_fresh = -(-P // ps) - shared_full
         evicted0 = prefix.evictions if prefix is not None else 0
-        with self._phases.step("alloc", pages=n_fresh):
-            fresh = self._alloc_pages(n_fresh, pool, prefix)
+        fresh = self._alloc_pages(n_fresh, pool, prefix)
         if fresh is None:
             pool.unref(shared)
             if partial:
@@ -2080,8 +2097,7 @@ class DecodeEngine:
             sm["engine_prefix_hits"].inc(
                 labels={"deployment": self.deployment})
         if prefix is not None:
-            with clock.step("register"):
-                prefix.insert(req.prompt, pages)
+            prefix.insert(req.prompt, pages)
         return first, pages, hist, bucket
 
     # rtlint: owner=driver
@@ -2339,6 +2355,8 @@ class DecodeEngine:
         ``cover=False`` serves adaptive speculation: the spec
         dispatcher already ran the coverage pass for this boundary
         before deciding to fall back to a chunk round."""
+        import jax
+
         from .._private.metrics import serve_metrics
 
         if epoch >= 0 and epoch != self._epoch:
@@ -2354,20 +2372,33 @@ class DecodeEngine:
         active = np.array([s is not None and not s.parked
                            for s in self._state], bool)
         n_active = int(active.sum())
-        with self._phases.phase("decode", slots_active=n_active) as ph:
-            self._note_decode_gap(ph.t0)
-            toks, cache, _done, rngs, *more = self._step(
-                self._params_dev, self._cache, self._token,
-                self._rngs, active, self._pt)
+        clock = self._phases
+        with clock.phase("decode", slots_active=n_active) as ph:
+            with clock.step("enqueue"):
+                self._note_decode_gap(ph.t0)
+                toks, cache, _done, rngs, *more = self._step(
+                    self._params_dev, self._cache, self._token,
+                    self._rngs, active, self._pt)
+                # the copies to the host start when the program ends,
+                # not when the wait below has returned
+                for arr in (toks, rngs, *more[:1]):
+                    arr.copy_to_host_async()
+            # The wait and the reads stay in this frame, as the
+            # prefill's do: profilers that label the driver by function
+            # see the device's part here, told from the host's by step.
+            with clock.step("wait"):
+                # rtlint: sync-ok=chunk-boundary the device's part, alone
+                jax.block_until_ready(toks)
             # ONE transfer per fused k-step chunk — the engine's
             # designed streaming granularity.
-            # rtlint: sync-ok=chunk-boundary one transfer per chunk
-            toks_np = np.asarray(toks)
-            # rtlint: sync-ok=chunk-boundary PRNG lanes ride the same sync
-            rngs_np = np.asarray(rngs)
-            # rtlint: sync-ok=chunk-boundary the model's counters, same sync
-            counted = [int(c) for c in np.asarray(more[0])] \
-                if more else []
+            with clock.step("read"):
+                # rtlint: sync-ok=chunk-boundary one transfer per chunk
+                toks_np = np.asarray(toks)
+                # rtlint: sync-ok=chunk-boundary PRNG lanes ride the same sync
+                rngs_np = np.asarray(rngs)
+                # rtlint: sync-ok=chunk-boundary the model's counters, same sync
+                counted = [int(c) for c in np.asarray(more[0])] \
+                    if more else []
         if epoch >= 0 and epoch != self._epoch:
             return                    # stale driver: drop on the floor
         with self._phases.phase("deliver", slots_active=n_active):
@@ -2459,17 +2490,30 @@ class DecodeEngine:
                     emitted, labels={"deployment": self.deployment})
                 self._count(tokens=emitted)
             self._observe_pages(sm)
-            self._decode_read_ns = ph.t1 if any(
-                s is not None for s in self._state) else None
+            self._note_decode_read(ph.t1)
+
+    # rtlint: owner=driver
+    def _note_decode_read(self, read_ns: int):
+        """After a decode/verify dispatch's delivery: where its tokens
+        were read, if a lane is still occupied, and what the prefill
+        phases have taken so far — the two the next gap is counted
+        from."""
+        self._decode_read_ns = read_ns if any(
+            s is not None for s in self._state) else None
+        self._decode_read_prefill_ns = self._driver_ns["prefill"]
 
     # rtlint: owner=driver
     def _note_decode_gap(self, now_ns: int):
         """At the start of a decode/verify dispatch: the time since the
         previous one's tokens were read, if a lane stayed occupied all
         the while — what running lanes lost to whatever came between
-        (delivery, admission, another request's prefill)."""
+        (delivery, admission, another request's prefill) — and the part
+        of it in which the driver was in a prefill phase."""
         if self._decode_read_ns is not None:
-            self._count(decode_gap_ns_sum=now_ns - self._decode_read_ns)
+            self._count(
+                decode_gap_ns_sum=now_ns - self._decode_read_ns,
+                decode_gap_prefill_ns_sum=self._driver_ns["prefill"]
+                - self._decode_read_prefill_ns)
 
     def _dispatch_spec(self, epoch: int = -1):  # rtlint: owner=driver
         """Draft-k-verify-once twin of :meth:`_dispatch_chunk`
@@ -2492,6 +2536,8 @@ class DecodeEngine:
         constructor enforces it): the decision depends on pool
         composition, which is replay-safe only when sampling consumes
         no randomness."""
+        import jax
+
         from .._private.metrics import serve_metrics
 
         if epoch >= 0 and epoch != self._epoch:
@@ -2518,20 +2564,28 @@ class DecodeEngine:
                 self._dispatch_chunk(epoch, cover=False)
                 return
         draft = self._drafter.propose(active, self._token)
-        with self._phases.phase("decode", slots_active=n_active,
-                                spec=True) as ph:
-            self._note_decode_gap(ph.t0)
-            committed, n_acc, cache, rngs = self._verify(
-                self._params_dev, self._cache, self._token, draft,
-                self._rngs, active, self._pt)
+        clock = self._phases
+        with clock.phase("decode", slots_active=n_active,
+                         spec=True) as ph:
+            with clock.step("enqueue"):
+                self._note_decode_gap(ph.t0)
+                committed, n_acc, cache, rngs = self._verify(
+                    self._params_dev, self._cache, self._token, draft,
+                    self._rngs, active, self._pt)
+                for arr in (committed, n_acc, rngs):
+                    arr.copy_to_host_async()
+            with clock.step("wait"):
+                # rtlint: sync-ok=verify-boundary the device's part, alone
+                jax.block_until_ready(committed)
             # ONE transfer per verify round: committed tokens, accept
             # counts, and PRNG lanes come back together.
-            # rtlint: sync-ok=verify-boundary one transfer per round
-            com_np = np.asarray(committed)
-            # rtlint: sync-ok=verify-boundary same round-trip
-            acc_np = np.asarray(n_acc)
-            # rtlint: sync-ok=verify-boundary same round-trip
-            rngs_np = np.asarray(rngs)
+            with clock.step("read"):
+                # rtlint: sync-ok=verify-boundary one transfer per round
+                com_np = np.asarray(committed)
+                # rtlint: sync-ok=verify-boundary same round-trip
+                acc_np = np.asarray(n_acc)
+                # rtlint: sync-ok=verify-boundary same round-trip
+                rngs_np = np.asarray(rngs)
         if epoch >= 0 and epoch != self._epoch:
             return                    # stale driver: drop on the floor
         with self._phases.phase("deliver", slots_active=n_active):
@@ -2616,5 +2670,4 @@ class DecodeEngine:
                 sm["engine_tokens"].inc(emitted, labels=labels)
                 self._count(tokens=emitted)
             self._observe_pages(sm)
-            self._decode_read_ns = ph.t1 if any(
-                s is not None for s in self._state) else None
+            self._note_decode_read(ph.t1)

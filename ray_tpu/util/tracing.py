@@ -318,12 +318,17 @@ class PhaseClock:
     nest, and a phase is charged its SELF time (its span minus what the
     phases opened inside it cover), so the phases of ``table`` sum to
     ``table["total"]`` by construction. Entering and leaving a phase
-    takes one ``time.monotonic_ns()`` stamp each and does three things
-    with the pair:
+    takes one ``time.monotonic_ns()`` and one ``time.thread_time_ns()``
+    stamp each and does four things with the pairs:
 
     - adds the self time to ``table[name]``: plain ints owned by the
       driver thread; other threads read them racily (a phase in flight
       is not counted yet);
+    - adds the self CPU time (this thread on a processor, kept the way
+      the wall time is) to ``table["cpu.<name>"]`` where the table was
+      seeded with that key: wall minus cpu of a phase is the thread OFF
+      the processor in it: blocked on the device, waiting for the
+      interpreter lock, descheduled;
     - holds a ``jax.profiler.TraceAnnotation("<prefix>.<name>", ...)``
       open over the same interval, so that in any ``jax.profiler`` trace
       the phases lie in the host plane on the trace's own clock, beside
@@ -333,6 +338,9 @@ class PhaseClock:
       are held until the outermost phase closes, and dropped if that
       one was ``muted``: a loop that wakes twenty times a second to find
       nothing to do must not fill the span buffers with it.
+
+    A :meth:`step` is a named PART of the phase that is open: counted
+    beside the phase (``table["<phase>.<name>"]``), never taken from it.
 
     One clock per driver run: the table outlives it (the engine's), the
     stack of open phases does not.
@@ -349,6 +357,7 @@ class PhaseClock:
         self.attrs = attrs              # on every span of this clock
         self.trace_id = _new_id(16)
         self._open: List["_Phase"] = []
+        self._step: Optional["_Step"] = None
         self._spans: List[tuple] = []   # of the outermost open phase
 
     def phase(self, name: str, **args) -> "_Phase":
@@ -357,40 +366,75 @@ class PhaseClock:
         go to the annotation and the span."""
         return _Phase(self, name, args)
 
-    def step(self, name: str, **args):
+    def step(self, name: str, **args) -> "_Step":
         """A named part of the phase that is open: an annotation in the
-        profiler's trace (``<prefix>.<phase>.<name>``), nothing else."""
-        top = self._open[-1].name if self._open else "loop"
-        return self._annotation(f"{self.prefix}.{top}.{name}", **args)
+        profiler's trace (``<prefix>.<phase>.<name>``) and, from one
+        ``time.monotonic_ns()`` stamp at each end, its duration added to
+        ``table["<phase>.<name>"]``. Steps do not nest, and no phase
+        opens inside one."""
+        return _Step(self, name, args)
+
+
+class _Step:
+    __slots__ = ("clock", "key", "t0", "_ann")
+
+    def __init__(self, clock: PhaseClock, name: str, args: dict):
+        assert clock._open, f"step {name!r} outside any phase"
+        self.clock = clock
+        self.key = f"{clock._open[-1].name}.{name}"
+        self._ann = clock._annotation(f"{clock.prefix}.{self.key}", **args)
+
+    def __enter__(self):
+        c = self.clock
+        assert c._step is None, f"step {self.key!r} inside {c._step.key!r}"
+        self._ann.__enter__()
+        c._step = self
+        self.t0 = time.monotonic_ns()
+        return self
+
+    def __exit__(self, et, ev, tb):
+        t1 = time.monotonic_ns()
+        c = self.clock
+        c.table[self.key] = c.table.get(self.key, 0) + t1 - self.t0
+        c._step = None
+        self._ann.__exit__(et, ev, tb)
+        return False
 
 
 class _Phase:
     __slots__ = ("clock", "name", "args", "t0", "t1", "span_id",
-                 "muted", "_inner", "_ann")
+                 "muted", "_inner", "_cpu0", "_cpu_inner", "_ann")
 
     def __init__(self, clock: PhaseClock, name: str, args: dict):
         self.clock, self.name, self.args = clock, name, args
-        self.t0 = self.t1 = self._inner = 0
+        self.t0 = self.t1 = self._inner = self._cpu_inner = 0
         self.span_id = _new_id(8) if _enabled else None
         self.muted = False      # set on an outermost phase: no spans
 
     def __enter__(self):
         c = self.clock
+        assert c._step is None, \
+            f"phase {self.name!r} inside step {c._step.key!r}"
         self._ann = c._annotation(f"{c.prefix}.{self.name}", **self.args)
         self._ann.__enter__()
         c._open.append(self)
+        self._cpu0 = time.thread_time_ns()
         self.t0 = time.monotonic_ns()
         return self
 
     def __exit__(self, et, ev, tb):
         self.t1 = time.monotonic_ns()
+        cpu = time.thread_time_ns() - self._cpu0
         c = self.clock
         dur = self.t1 - self.t0
         stack = c._open
         stack.pop()             # ``with`` blocks of one thread: LIFO
         c.table[self.name] = c.table.get(self.name, 0) + dur - self._inner
+        if "cpu." + self.name in c.table:
+            c.table["cpu." + self.name] += cpu - self._cpu_inner
         if stack:
             stack[-1]._inner += dur
+            stack[-1]._cpu_inner += cpu
         else:
             c.table["total"] = c.table.get("total", 0) + dur
         self._ann.__exit__(et, ev, tb)
