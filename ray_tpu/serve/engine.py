@@ -40,6 +40,10 @@ Results stream back through the same :class:`~.batching._StreamLane`
 queues the batched streaming path uses, so replicas, handles, and the
 HTTP proxy need no new transport: ``engine.submit(...)`` returns a lane,
 ``engine.stream(...)`` an iterator of per-chunk ``np.int32[j]`` slices.
+A launch's slices reach their lanes behind the NEXT decode enqueue
+(``_flush_kept``): the consumers they wake run under the interpreter
+lock while the driver is blocked on the device, not between two
+launches; where no launch follows they go at once.
 
 **KV pages + shared-prefix reuse** (ISSUE 6 tentpole): reserving
 ``max_len`` KV per slot up front would cap concurrency by the
@@ -194,9 +198,11 @@ _PHASES = ("idle", "admit", "prefill", "cover", "decode", "deliver",
 #: inside the phase's self time): a prefill's request key, the call into
 #: the compiled program and the first token's read; a decode (or verify)
 #: launch's call into the compiled program from the phase's start to its
-#: return, the wait for the device, and the results' read.
+#: return, the previous launch's tokens handed to their lanes behind it
+#: (``flush``: the consumers they wake run while the device does), the
+#: wait for the device, and the results' read.
 _STEPS = ("prefill.key", "prefill.dispatch", "prefill.read",
-          "decode.enqueue", "decode.wait", "decode.read")
+          "decode.enqueue", "decode.flush", "decode.wait", "decode.read")
 #: The phases in which the driver holds no dispatch, so that wall minus
 #: CPU time (``stats()["driver_cpu_ns_<phase>"]``) is the thread waiting
 #: for the interpreter lock or a processor, not for the device.
@@ -600,6 +606,15 @@ class DecodeEngine:
             collections.deque()
         self._draining = False
         self._fail_lock = threading.Lock()
+        # What the last launch's state pass would have put on its lanes
+        # (slices, ends, deadline errors), in order, until the next
+        # program is enqueued: see _flush_kept. The driver appends; one
+        # thread at a time hands over (the lock), so that a lane's
+        # messages stay in order and a thread failing the lanes puts
+        # its error BEHIND the kept slices.
+        self._kept: "collections.deque[Tuple[_StreamLane, tuple]]" = \
+            collections.deque()
+        self._kept_lock = threading.Lock()
         self._stats_lock = threading.Lock()
         self._stats = {"admitted": 0, "completed": 0, "expired": 0,
                        "abandoned": 0, "prefills": 0, "dispatches": 0,
@@ -615,6 +630,10 @@ class DecodeEngine:
                        "handoff_import_fallbacks": 0,
                        "handoff_ship_bytes": 0,
                        "attn_kernel_dispatches": 0,
+                       # messages handed to lanes by the deferred
+                       # delivery, and those of them handed over with
+                       # a device program in flight
+                       "deliver_puts": 0, "deliver_puts_overlapped": 0,
                        # request lifecycle, summed where it happens
                        # (monotonic ns): queued -> slot granted over
                        # `admitted`; slot granted -> first token on the
@@ -1671,6 +1690,7 @@ class DecodeEngine:
                     self._beat = time.monotonic()
                     warm = self._warm
                     if warm is not None:
+                        self._flush_now(epoch)  # not behind a compile
                         self._run_warm_up(warm)
                         continue
                     self._check_fault()
@@ -1685,6 +1705,8 @@ class DecodeEngine:
                         self._sweep_leases()
                     self._observe_queue_depth()
                     if not any(s is not None for s in self._state):
+                        # no lane left: no launch to ride behind
+                        self._flush_now(epoch)
                         if self._pending:
                             # Deferred head with an empty pool and ZERO
                             # running lanes cannot happen (n_pages holds
@@ -1747,6 +1769,10 @@ class DecodeEngine:
     def _fail_all_locked(self, exc: BaseException, free_state: bool):
         with self._admit_lock:
             self._draining = True    # no put can land past this point
+        # The kept slices BEFORE the error: a client that resubmits
+        # counts what it received, and so must have received what the
+        # state pass counted as delivered.
+        self._flush_kept(in_flight=False)
         for i, st in enumerate(self._state):
             if st is not None:
                 st.lane.q.put(("err", exc))
@@ -1769,6 +1795,38 @@ class DecodeEngine:
             except queue.Empty:
                 return
             req.lane.q.put(("err", exc))
+
+    def _flush_kept(self, in_flight: bool, epoch: int = -1):
+        """Hand the last launch's kept messages to their lanes, in the
+        order the state pass kept them. ``in_flight``: the next decode
+        (or verify) program has just been enqueued and the driver is
+        about to block on it for the whole of a launch (where it needs
+        no interpreter lock), so the consumers these puts wake run
+        while the device does and not between two launches. Not behind
+        a prefill's enqueue: its wait is shorter than the consumers'
+        work at 256 lanes, and what spills contends with the driver
+        before the next launch (``PERF.md`` section 6, PR 43: both
+        rules measured). A stale driver (``epoch`` moved on) hands
+        nothing over: the supervisor already did, before its error."""
+        kept = self._kept
+        if not kept or (epoch >= 0 and epoch != self._epoch):
+            return
+        n = 0
+        with self._kept_lock:
+            while kept:
+                lane, msg = kept.popleft()
+                lane.q.put(msg)
+                n += 1
+        self._count(deliver_puts=n,
+                    deliver_puts_overlapped=n if in_flight else 0)
+
+    def _flush_now(self, epoch: int):  # rtlint: owner=driver
+        """Nothing will be enqueued for the kept messages to ride
+        behind (no lane left or none runnable, the loop going idle):
+        hand them over at once, as delivery the device does not hide."""
+        if self._kept:
+            with self._phases.phase("deliver"):
+                self._flush_kept(in_flight=False, epoch=epoch)
 
     # Ownership transfers to the failing thread only once the driver is
     # confirmed dead — see _fail_all's free_state contract.
@@ -2368,6 +2426,7 @@ class DecodeEngine:
             with self._phases.phase("cover"):
                 runnable = self._cover_pages()
             if not runnable:
+                self._flush_now(epoch)    # no launch to ride behind
                 return                # re-run admission/coverage pass
         active = np.array([s is not None and not s.parked
                            for s in self._state], bool)
@@ -2383,6 +2442,11 @@ class DecodeEngine:
                 # not when the wait below has returned
                 for arr in (toks, rngs, *more[:1]):
                     arr.copy_to_host_async()
+            # The previous launch's tokens reach their lanes HERE: the
+            # consumers they wake take the interpreter lock while this
+            # thread is blocked in the wait below.
+            with clock.step("flush"):
+                self._flush_kept(in_flight=True, epoch=epoch)
             # The wait and the reads stay in this frame, as the
             # prefill's do: profilers that label the driver by function
             # see the device's part here, told from the host's by step.
@@ -2432,65 +2496,89 @@ class DecodeEngine:
             with self._stats_lock:
                 self._stats["peak_active"] = max(self._stats["peak_active"],
                                                  n_active)
-            emitted = 0
-            for i, st in enumerate(self._state):
-                if st is None or st.parked:
-                    continue                     # parked: nothing advanced
-                self._token[i] = toks_np[i, -1]
-                self._rngs[i] = rngs_np[i]
-                st.pos += self.chunk             # mirrors the device pos
-                if st.lane.closed:               # consumer left: free now
-                    self._free_slot(i)
-                    self._count(abandoned=1)
-                    continue
-                if deadline_expired(st.deadline_s):
-                    st.lane.q.put(("err", RequestDeadlineExceeded(
-                        "request deadline passed mid-generation")))
-                    self._free_slot(i)
-                    self._count(expired=1)
-                    sm["requests_expired"].inc(
-                        labels={"where": "engine",
-                                "deployment": self.deployment})
-                    continue
-                row = toks_np[i]
-                j = min(self.chunk, st.remaining)
-                finished = st.remaining <= self.chunk
-                if self.eos_token >= 0:
-                    hits = np.flatnonzero(row[:j] == self.eos_token)
-                    if hits.size:                # free at the EOS, not the
-                        j = int(hits[0]) + 1     # end of the gang batch
-                        finished = True
-                if st.trace_ctx is not None:
-                    tracing.record_span("decode.chunk",
-                                        mono_ns=(ph.t0, ph.t1),
-                                        parent_ctx=st.trace_ctx, slot=i,
-                                        active_slots=n_active, tokens=j,
-                                        deployment=self.deployment)
-                # Recompute replay: the first ``skip`` regenerated tokens
-                # were already delivered before the preemption — suppress
-                # them, stream only the new tail.
-                cut = min(st.skip, j)
-                st.skip -= cut
-                if j > cut:
-                    st.lane.q.put(("item", row[cut:j].copy()))
-                    st.emitted += j - cut
-                    emitted += j - cut
-                st.remaining -= j
-                if finished:
-                    st.lane.q.put((_STREAM_END, None))
-                    self._free_slot(i)
-                    self._count(completed=1)
-                elif self._drafter is not None:
-                    # Adaptive fallback round: keep the drafter's history
-                    # (and its self-assessment) current; -1 marks "nothing
-                    # was proposed this round".
-                    self._drafter.observe(i, row[:j], -1)
-            if emitted:
-                sm["engine_tokens"].inc(
-                    emitted, labels={"deployment": self.deployment})
-                self._count(tokens=emitted)
-            self._observe_pages(sm)
-            self._note_decode_read(ph.t1)
+            self._advance_lanes(toks_np, rngs_np, None, ph, n_active, sm)
+
+    # rtlint: owner=driver
+    def _advance_lanes(self, rows, rngs, acc, ph, n_active: int, sm):
+        """The STATE pass over the lanes a decode or verify launch
+        advanced, inside its ``deliver`` phase: the host's lane state
+        brought up to date (the fed token, the PRNG lane, ``pos``,
+        ``remaining``, the EOS cut, the replay's ``skip``, the frees,
+        the counters) before admission and coverage read it. What a
+        lane is owed (its slice, its end, a deadline error) is KEPT, in
+        order, for ``_flush_kept`` behind the next enqueue. ``rows[i]``
+        holds lane i's tokens; ``acc[i]`` how many proposals its verify
+        accepted (the lane advances by one more: the correction or
+        bonus token), None after a chunk launch, where every lane
+        advances by ``chunk``."""
+        labels = {"deployment": self.deployment}
+        keep = self._kept.append
+        emitted = 0
+        for i, st in enumerate(self._state):
+            if st is None or st.parked:
+                continue                     # parked: nothing advanced
+            if acc is None:
+                na, adv = -1, self.chunk     # -1: nothing was proposed
+            else:
+                na = int(acc[i])
+                adv = na + 1
+                sm["engine_spec_accept_len"].observe(na, labels=labels)
+            self._rngs[i] = rngs[i]
+            st.pos += adv                    # mirrors the device pos
+            if st.lane.closed:               # consumer left: free now
+                self._free_slot(i)
+                self._count(abandoned=1)
+                continue
+            if deadline_expired(st.deadline_s):
+                keep((st.lane, ("err", RequestDeadlineExceeded(
+                    "request deadline passed mid-generation"))))
+                self._free_slot(i)
+                self._count(expired=1)
+                sm["requests_expired"].inc(
+                    labels={"where": "engine",
+                            "deployment": self.deployment})
+                continue
+            row = rows[i]
+            j = min(adv, st.remaining)
+            finished = st.remaining <= adv
+            if self.eos_token >= 0:
+                hits = np.flatnonzero(row[:j] == self.eos_token)
+                if hits.size:                # free at the EOS, not the
+                    j = int(hits[0]) + 1     # end of the gang batch
+                    finished = True
+            self._token[i] = row[j - 1]      # last DELIVERED token
+            if st.trace_ctx is not None:
+                tracing.record_span("decode.chunk",
+                                    mono_ns=(ph.t0, ph.t1),
+                                    parent_ctx=st.trace_ctx, slot=i,
+                                    active_slots=n_active, tokens=j,
+                                    deployment=self.deployment,
+                                    **({} if acc is None
+                                       else {"accepted": na}))
+            # Recompute replay: the first ``skip`` regenerated tokens
+            # were already delivered before the preemption — suppress
+            # them, stream only the new tail. It counts DELIVERED
+            # tokens: a variable advance changes nothing about it.
+            cut = min(st.skip, j)
+            st.skip -= cut
+            if j > cut:
+                keep((st.lane, ("item", row[cut:j].copy())))
+                st.emitted += j - cut
+                emitted += j - cut
+            st.remaining -= j
+            if finished:
+                keep((st.lane, (_STREAM_END, None)))
+                self._free_slot(i)           # drafter.free rides along
+                self._count(completed=1)
+            elif self._drafter is not None:
+                # Keep the drafter's history (and its self-assessment)
+                # current, after an adaptive fallback's chunk round too.
+                self._drafter.observe(i, row[:j], na)
+        if emitted:
+            sm["engine_tokens"].inc(emitted, labels=labels)
+            self._count(tokens=emitted)
+        self._observe_pages(sm)
+        self._note_decode_read(ph.t1)
 
     # rtlint: owner=driver
     def _note_decode_read(self, read_ns: int):
@@ -2545,12 +2633,11 @@ class DecodeEngine:
         with self._phases.phase("cover"):
             runnable = self._cover_pages()
         if not runnable:
+            self._flush_now(epoch)        # no launch to ride behind
             return                    # re-run admission/coverage pass
         active = np.array([s is not None and not s.parked
                            for s in self._state], bool)
         n_active = int(active.sum())
-        if not n_active:
-            return
         if self.spec_threshold > 0.0:
             ests = [self._drafter.estimate(i)
                     for i in range(self.slots) if active[i]]
@@ -2574,6 +2661,8 @@ class DecodeEngine:
                     self._rngs, active, self._pt)
                 for arr in (committed, n_acc, rngs):
                     arr.copy_to_host_async()
+            with clock.step("flush"):     # as _dispatch_chunk's
+                self._flush_kept(in_flight=True, epoch=epoch)
             with clock.step("wait"):
                 # rtlint: sync-ok=verify-boundary the device's part, alone
                 jax.block_until_ready(committed)
@@ -2613,61 +2702,4 @@ class DecodeEngine:
             with self._stats_lock:
                 self._stats["peak_active"] = max(self._stats["peak_active"],
                                                  n_active)
-            emitted = 0
-            for i, st in enumerate(self._state):
-                if st is None or st.parked or not active[i]:
-                    continue                     # parked or chunk-mode slot
-                na = int(acc_np[i])
-                adv = na + 1
-                sm["engine_spec_accept_len"].observe(na, labels=labels)
-                self._rngs[i] = rngs_np[i]
-                st.pos += adv                    # mirrors the device pos
-                if st.lane.closed:               # consumer left: free now
-                    self._free_slot(i)
-                    self._count(abandoned=1)
-                    continue
-                if deadline_expired(st.deadline_s):
-                    st.lane.q.put(("err", RequestDeadlineExceeded(
-                        "request deadline passed mid-generation")))
-                    self._free_slot(i)
-                    self._count(expired=1)
-                    sm["requests_expired"].inc(
-                        labels={"where": "engine",
-                                "deployment": self.deployment})
-                    continue
-                row = com_np[i]
-                j = min(adv, st.remaining)
-                finished = st.remaining <= adv
-                if self.eos_token >= 0:
-                    hits = np.flatnonzero(row[:j] == self.eos_token)
-                    if hits.size:                # free at the EOS
-                        j = int(hits[0]) + 1
-                        finished = True
-                self._token[i] = row[j - 1]      # last DELIVERED token
-                if st.trace_ctx is not None:
-                    tracing.record_span("decode.chunk",
-                                        mono_ns=(ph.t0, ph.t1),
-                                        parent_ctx=st.trace_ctx, slot=i,
-                                        active_slots=n_active, tokens=j,
-                                        accepted=na,
-                                        deployment=self.deployment)
-                # Replay suppression counts DELIVERED tokens — variable
-                # advance changes nothing about the token arithmetic.
-                cut = min(st.skip, j)
-                st.skip -= cut
-                if j > cut:
-                    st.lane.q.put(("item", row[cut:j].copy()))
-                    st.emitted += j - cut
-                    emitted += j - cut
-                st.remaining -= j
-                if finished:
-                    st.lane.q.put((_STREAM_END, None))
-                    self._free_slot(i)           # drafter.free rides along
-                    self._count(completed=1)
-                else:
-                    self._drafter.observe(i, row[:j], na)
-            if emitted:
-                sm["engine_tokens"].inc(emitted, labels=labels)
-                self._count(tokens=emitted)
-            self._observe_pages(sm)
-            self._note_decode_read(ph.t1)
+            self._advance_lanes(com_np, rngs_np, acc_np, ph, n_active, sm)

@@ -2,7 +2,9 @@
 driver phases in ``engine.stats()``, the request lifecycle counted where
 it happens, compiles counted, and spans on the monotonic clock. And a
 decode launch accounts for itself (ISSUE 42): counted steps, the
-thread's CPU time beside the wall's, the prefills inside a gap.
+thread's CPU time beside the wall's, the prefills inside a gap. Since
+ISSUE 43 a launch has a fourth step, ``flush``: the previous launch's
+tokens handed to their lanes behind the enqueue.
 
 CPU, ``nano``: these are counts, names and orderings, never a speed.
 """
@@ -443,7 +445,8 @@ def test_phase_clock_self_times():
 
 # ------------------------------------- a launch accounts for itself (42)
 STEPS = ("prefill_key", "prefill_dispatch", "prefill_read",
-         "decode_enqueue", "decode_wait", "decode_read")
+         "decode_enqueue", "decode_flush", "decode_wait", "decode_read")
+DECODE_STEPS = ("enqueue", "flush", "wait", "read")
 #: the phases in which the driver holds no dispatch: their CPU time is
 #: kept beside their wall time
 HOST_PHASES = ("admit", "cover", "deliver", "other")
@@ -557,13 +560,14 @@ LONGER = {"int8": dict(chunk=8), "paged": dict(chunk=8),
 
 
 @pytest.mark.parametrize("kind", list(KINDS))
-def test_a_decode_launch_is_its_three_steps(make, nano, kind):
-    """enqueue + wait + read are the decode phase but for the stamps
+def test_a_decode_launch_is_its_four_steps(make, nano, kind):
+    """enqueue + flush + wait + read are the decode phase but for the stamps
     between them: never more, and within 5% of it. What else lands
     between two stamps is the machine's (another worker of the suite
     given the thread's processor) and only ever lowers the sum, so the
     5% is asked of the best of three windows and 20% of each. A
-    prefill's three steps lie inside its phase; every host phase's CPU
+    prefill's three steps lie inside its phase (it hands nothing over:
+    a launch's tokens ride the next DECODE enqueue); every host phase's CPU
     time is at most its wall time. (That the seven phases still sum to the
     total with the steps in the same table: ``test_phases_sum_to_
     total``, which sums the phases alone.)"""
@@ -577,10 +581,8 @@ def test_a_decode_launch_is_its_three_steps(make, nano, kind):
         time.sleep(0.12)
         d = _delta(a, eng.stats())
         assert d["dispatches"] >= 6
-        assert all(d[f"driver_ns_decode_{s}"] > 0
-                   for s in ("enqueue", "wait", "read"))
-        parts = sum(d[f"driver_ns_decode_{s}"]
-                    for s in ("enqueue", "wait", "read"))
+        assert all(d[f"driver_ns_decode_{s}"] > 0 for s in DECODE_STEPS)
+        parts = sum(d[f"driver_ns_decode_{s}"] for s in DECODE_STEPS)
         assert parts <= d["driver_ns_decode"]
         ratios.append(parts / d["driver_ns_decode"])
         pre = sum(d[f"driver_ns_prefill_{s}"]
@@ -593,14 +595,17 @@ def test_a_decode_launch_is_its_three_steps(make, nano, kind):
 
 def _shares(d):
     """The three-way split of lane time, by the benchmark's readers for
-    the two stalls (``run["stats_delta"]`` is such a difference)."""
+    the two stalls (``run["stats_delta"]`` is such a difference); the
+    device's part is the flush that rides behind the enqueue and the
+    wait."""
     run = {"stats_delta": d}
     lane = d["decode_gap_ns_sum"] + d["driver_ns_decode"]
     return (_benchmark_file(
                 "layer_metrics/lane_prefill_stall_pct.sat.py").read(run),
             _benchmark_file(
                 "layer_metrics/lane_host_stall_pct.sat.py").read(run),
-            100.0 * d["driver_ns_decode_wait"] / lane)
+            100.0 * (d["driver_ns_decode_flush"]
+                     + d["driver_ns_decode_wait"]) / lane)
 
 
 @pytest.mark.parametrize("kind", list(KINDS))
@@ -621,7 +626,7 @@ def test_lane_time_splits_three_ways(make, nano, kind):
     prefill, host, device = _shares(d)
     assert prefill > 0 and host > device > 0
     between = d["driver_ns_decode"] - sum(
-        d[f"driver_ns_decode_{s}"] for s in ("enqueue", "wait", "read"))
+        d[f"driver_ns_decode_{s}"] for s in DECODE_STEPS)
     lane = d["decode_gap_ns_sum"] + d["driver_ns_decode"]
     assert prefill + host + device + 100.0 * between / lane == \
         pytest.approx(100, abs=1e-6)
@@ -661,3 +666,73 @@ def test_the_gap_knows_its_prefills(make, nano, kind):
     # the throttle (10 ms a loop) is the host's, not the prefill's
     assert d["decode_gap_ns_sum"] - d["decode_gap_prefill_ns_sum"] \
         >= 0.01e9
+
+
+# ------------------------- a launch's tokens ride the next enqueue (43)
+def _taken(lane, n):
+    """``n`` slices of a lane's stream, as a list of tokens."""
+    out = []
+    for _ in range(n):
+        out += [int(t) for t in next(lane)]
+    return out
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_at_full_slots_every_put_rides_behind_a_launch(make, nano, kind):
+    """While every slot is taken each launch is followed by another, and
+    so whatever its state pass kept is handed over with a program in
+    flight: ``deliver_puts_overlapped == deliver_puts`` over such
+    launches, and the step ``flush`` is where the time went."""
+    eng = make(kind)
+    list(eng.stream(_prompt(nano, 5), 6))           # compiled
+    eng.inject_fault("driver_slow", wedge_s=0.01)
+    lanes = [eng.stream(_prompt(nano, 5, i), 40) for i in (1, 2)]
+    for lane in lanes:
+        _taken(lane, 2)                             # both are decoding
+    a = eng.stats()
+    for lane in lanes:
+        _taken(lane, 3)
+    b = eng.stats()                                 # and still are
+    assert b["active_slots"] == 2 == eng.slots
+    d = _delta(a, b)
+    assert d["dispatches"] >= 2
+    assert d["deliver_puts"] == d["deliver_puts_overlapped"] >= 4
+    assert d["driver_ns_decode_flush"] > 0
+    assert d["completed"] == 0
+    for lane in lanes:
+        list(lane)
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_driver_death_behind_the_state_pass_loses_no_token(make, nano,
+                                                             kind):
+    """The driver dies at the loop's top (the throttle holds it there):
+    BETWEEN a state pass and the flush of what it kept. The kept slices
+    reach the lane before the retryable error, so what the client
+    counted is what the engine counted as delivered; the resubmit
+    resumes there: no token lost, none twice."""
+    from ray_tpu.serve.engine import EngineRestartError
+
+    prompt = _prompt(nano, 5, 7)
+    eng = make(kind)
+    ref = [int(t) for c in eng.stream(prompt, 30) for t in c]
+    eng.inject_fault("driver_slow", wedge_s=0.01)
+    a = eng.stats()
+    eng.inject_fault("driver_die", at_tokens=a["tokens"] + 9)
+    got = []
+    with pytest.raises(EngineRestartError):
+        for c in eng.stream(prompt, 30):
+            got += [int(t) for t in c]
+    d = _delta(a, eng.stats())
+    assert 9 <= len(got) < 30 and got == ref[:len(got)]
+    assert d["tokens"] == len(got)          # counted = handed over
+    assert d["deliver_puts"] > d["deliver_puts_overlapped"] > 0
+    assert not eng._kept
+    deadline = time.monotonic() + 10.0
+    while eng.stats()["driver_restarts"] == 0:
+        assert eng.supervise()
+        assert time.monotonic() < deadline, "supervisor never restarted"
+        time.sleep(0.05)
+    for c in eng.stream(prompt, 30, resume_from=len(got)):
+        got += [int(t) for t in c]
+    assert got == ref

@@ -302,3 +302,18 @@ def test_a_description_without_the_entry_is_asked_by_name():
             assert eng._attn_fused is fused
         finally:
             eng.shutdown()
+
+
+def test_deferred_delivery_hands_every_lane_the_walks_messages(model,
+                                                               engine):
+    """ISSUE 43 through this model's programs: the slices, the ends and
+    a replay's ``skip`` on the deferring engine are those of a walk that
+    hands over at once, and a lone request's end waits for nobody."""
+    from test_serve_engine_deliver import check_deferred_against_at_once
+
+    cfg, params = model
+    oracle = DecodeEngine(params, cfg, slots=8, chunk=4, max_len=96,
+                          prompt_buckets=(16, 32, 64), page_size=4,
+                          n_pages=200)
+    check_deferred_against_at_once(
+        engine, oracle, _prompts(cfg, (9, 17, 30, 12), seed=5))

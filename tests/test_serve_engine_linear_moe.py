@@ -463,3 +463,16 @@ def test_the_programs_keep_the_names_a_trace_shows(model):
     assert kda_moe.jit_prefill_into_slot_paged(
         cfg, 4).__wrapped__.__name__ == "prefill_into_slot_paged"
     assert kda_moe.decode_attention_fused(cfg, 4)     # interpreted here
+
+
+def test_deferred_delivery_hands_every_lane_the_walks_messages(model,
+                                                               engine):
+    """ISSUE 43 through this model's programs: the slices, the ends and
+    a replay's ``skip`` on the deferring engine are those of a walk that
+    hands over at once, and a lone request's end waits for nobody."""
+    from test_serve_engine_deliver import check_deferred_against_at_once
+
+    cfg, _ = model
+    check_deferred_against_at_once(
+        engine, _engine(model),
+        _prompts(cfg, (9, 17, 30, 12), seed=5))

@@ -567,6 +567,50 @@ def test_spec_eos_frees_slot(nano, nano_params):
         eng.shutdown()
 
 
+def test_spec_walk_defers_through_the_chunk_walks_helper(nano,
+                                                        nano_params):
+    """ISSUE 43: the verify round's walk is the chunk round's
+    (``_advance_lanes``), so what it owes the lanes is kept and handed
+    over behind the NEXT verify's enqueue: no ``q.put`` of its own, and
+    while a lane keeps running every put rides a program in flight."""
+    import inspect
+
+    from ray_tpu.serve.engine import DecodeEngine
+
+    for fn in (DecodeEngine._dispatch_spec, DecodeEngine._dispatch_chunk):
+        src = inspect.getsource(inspect.unwrap(fn))
+        assert "self._advance_lanes(" in src and "q.put" not in src
+        assert src.index('step("enqueue")') < src.index('step("flush")') \
+            < src.index('step("wait")')
+    eng = _make_engine(nano, nano_params)
+    try:
+        calls = []
+        inner = eng._advance_lanes
+
+        def walk(rows, rngs, acc, *a, **kw):
+            before = len(eng._kept)
+            inner(rows, rngs, acc, *a, **kw)
+            calls.append((acc is not None, len(eng._kept) - before))
+
+        eng._advance_lanes = walk
+        prompt = np.random.default_rng(4).integers(
+            0, nano.vocab_size, (8,)).astype(np.int32)
+        ref = _ref_chunked(nano_params, prompt, nano, 20, chunk=4,
+                           max_len=64)
+        out = np.concatenate(list(eng.stream(prompt, 20)))
+        assert (out == ref).all()
+        time.sleep(0.12)
+        st = eng.stats()
+        assert calls and all(spec for spec, _ in calls)
+        assert all(kept >= 1 for _, kept in calls)
+        assert st["deliver_puts"] == sum(kept for _, kept in calls)
+        # all but the last round's slice and the end rode a verify
+        assert st["deliver_puts"] - st["deliver_puts_overlapped"] == 2
+        assert st["driver_ns_decode_flush"] > 0
+    finally:
+        eng.shutdown()
+
+
 def test_spec_smoke_benchmark():
     """Satellite CI hook: the benchmark's --spec --smoke A/B runs end
     to end (spec off vs the n-gram drafter under the same burst) and
