@@ -23,11 +23,25 @@ sequence, and :func:`cache_spec` describes both in one
   ``n_kv_head x head_dim`` keys and values in a PAGE (entries ``k``,
   ``v``, per token, ``n_gqa`` layers). Prefill attends causally over
   the prompt (scope ``gqa.prefill``); decode attends over the lane's
-  pages gathered through the page table, plain XLA in blocks of
-  :data:`_GQA_LANE_BLOCK` lanes (scope ``gqa.attention``). The GPT-2
-  block's Pallas kernel is NOT adapted: its pool is ``[.., H, hd]`` with
-  one query a head on the VPU, and eight queries a KV head want the MXU:
-  a kernel of its own, later.
+  pages (scope ``gqa.attention``): ONE path, named ``"gather"``
+  (:data:`ATTN_KERNELS`), in two bodies chosen by what the program can
+  see (:func:`_gqa_kernel`). A Pallas kernel
+  (:func:`_gqa_attention_pallas`) copies a lane's LIVE pages from the
+  pools once, by DMA, and never a page past the live length, and
+  multiplies a block's rows, ``(token, KV head)`` as a page holds
+  them, by ALL the query heads on the MXU with the foreign heads'
+  columns masked (eight queries a KV head are no work for the VPU, and
+  the head axis in the middle of a page is no batch axis for Mosaic),
+  wherever Mosaic can address a page and a head (``head_dim`` whole
+  128-lane tiles and ``page_size x n_kv_head`` rows whole sublane tiles
+  compiled for a TPU; interpreted off it). Else plain XLA over each
+  lane's whole virtual sequence gathered through the page table, in
+  blocks of :data:`_GQA_LANE_BLOCK` lanes
+  (:func:`_gqa_attention_gather`, also the tests' oracle). The two
+  agree to :data:`ATTN_KERNEL_ULPS`. The GPT-2 block's kernel (one
+  query a head on the VPU) and the latent decoder's (64 absorbed
+  queries over one row a token) are other inner loops around the same
+  ring of copies, which is copied here, not shared (ROADMAP D13).
 - **KDA** (``kda_heads`` heads of ``kda_head_dim`` keys and values): a
   width-``conv_size`` causal depthwise convolution and SiLU on the
   ``q``/``k``/``v`` projections, L2-normalised ``q`` (scaled) and ``k``
@@ -52,10 +66,10 @@ sequence, and :func:`cache_spec` describes both in one
   a block of a live lane's heads in VMEM, both sums, the decay, the
   rank-one update and ``o`` from that one copy in float32, the whole
   per-slot entry aliased in and out) wherever Mosaic can address a
-  head's state (:func:`decode_attention_fused`: ``kda_head_dim`` a
-  multiple of 128 on a TPU, any width interpreted off it), chosen by
-  shape under the model's one ``attn_kernel`` name; elsewhere
-  :func:`_kda_step` in plain XLA, which is also the tests' oracle.
+  head's state (:func:`_state_kernel`: ``kda_head_dim`` a multiple of
+  128 on a TPU, any width interpreted off it), chosen by shape under
+  the model's one ``attn_kernel`` name; elsewhere :func:`_kda_step` in
+  plain XLA, which is also the tests' oracle.
 
 **MoE**, every layer: :func:`ray_tpu.models.moe.dropless_moe` with one
 group (sigmoid scores over ``n_routed`` experts, plain top k,
@@ -64,8 +78,8 @@ that live here, plus a shared expert: :func:`ray_tpu.models.mla_moe._ffn`
 as it stands.
 
 Layers are a list of per-layer trees, unrolled; the chunk program
-returns the expert layers' counters and the live lanes, summed over its
-steps (:data:`STEP_COUNTERS`).
+returns the expert layers' counters, the live lanes and the positions
+its attention fetched, summed over its steps (:data:`STEP_COUNTERS`).
 """
 from __future__ import annotations
 
@@ -79,7 +93,8 @@ from jax import lax
 from jax.scipy.linalg import solve_triangular
 
 from .gpt_decode import (_knob_cache, _program, _sample, _sample_slots)
-from .mla_moe import _at_layer, _embed, _ffn, _flat, _head, _rmsnorm
+from .mla_moe import (_at_layer, _embed, _ffn, _flat, _head, _live_length,
+                      _rmsnorm)
 from .serving import PT_SENTINEL, CacheEntry, CacheSpec, init_paged_pool
 
 Params = Dict[str, Any]
@@ -105,15 +120,38 @@ UNSUPPORTED = {
           "a chip",
 }
 #: int32 counters the chunk program returns, summed over its steps: the
-#: expert layers' four (:data:`ray_tpu.models.mla_moe.STEP_COUNTERS`)
-#: and the lanes whose state a step read and wrote.
+#: expert layers' four (:data:`ray_tpu.models.mla_moe.STEP_COUNTERS`),
+#: the lanes whose state a step read and wrote, and the positions whose
+#: keys and values a step's attention fetched from the pools, all GQA
+#: layers (the kernel: each lane's live tokens rounded up to whole
+#: pages; the gather: ``slots x max_pages x page_size`` whatever is
+#: live). Readers go by name or by the first five's places: append.
 STEP_COUNTERS = ("moe_steps", "moe_experts_touched_sum",
                  "moe_tokens_here_sum", "moe_expert_peak_sum",
-                 "state_lanes_sum")
+                 "state_lanes_sum", "gqa_tokens_read_sum")
 #: Lanes whose pages decode's attention gathers at once: the gathered
 #: keys and values of ALL lanes (``slots x max_len`` rows) would be a
 #: temporary as large as the pool.
 _GQA_LANE_BLOCK = 32
+#: The written bound on |kernel - gather| of decode's GQA attention, in
+#: ulps (2**-8, relative) of the LARGEST output of the call: both bodies
+#: round every probability once to the compute dtype, the XLA body
+#: after the division by the sum and the kernel before it, and sum p . v
+#: in float32 (as :data:`ray_tpu.models.mla_moe.ATTN_KERNEL_ULPS`, the
+#: same difference).
+ATTN_KERNEL_ULPS = 4
+#: Tokens the GQA kernel multiplies at once (``// page_size`` pages; one
+#: page where a page is larger), and the blocks of keys (and as many of
+#: values) in flight or in use at once. Measured on a v5e at the cell's
+#: shapes (254 lanes of 128-1,792 live tokens, 253 k in all, 1.05 GB of
+#: pages; the gather 9.54 ms): blocks of 128 / 192 / 256 / 384 / 512 /
+#: 1,024 tokens read 1.62 / 1.46 / 1.44 / 1.48 / 1.47 / 1.67 ms at the
+#: best ring depth of each; 256 with a ring of 2 / 3 / 4 / 6 / 8 reads
+#: 1.56 / 1.48 / 1.44 / 1.48 / 1.45 (PERF.md section 6, PR 46): the
+#: copies bound it (725 GB/s), so what counts is how many are in
+#: flight, and a ring that is a power of two indexes with a mask.
+_GQA_BLOCK_TOKENS = 256
+_GQA_RING_BLOCKS = 4
 #: Heads of a lane's state the recurrence's kernel holds in VMEM at
 #: once (the largest common divisor with ``kda_heads``): 32 heads of
 #: [128, 128] float32 are 2 MiB, 8 MiB with the block before and the
@@ -332,20 +370,45 @@ def _kda_step(S, q, k, v, g, beta):
     return S, w + jnp.sum(k * q, axis=-1, keepdims=True) * du
 
 
+def _state_kernel(cfg: KDAMoEConfig) -> bool:
+    """Whether the step's recurrence is :func:`_kda_step_pallas`:
+    wherever Mosaic can address a head's state as whole float32 tiles
+    with ``dk`` on sublanes and ``dv`` on lanes, ``kda_head_dim`` a
+    multiple of 128 compiled for a TPU, any width interpreted off it;
+    elsewhere :func:`_kda_step`."""
+    from .._private.chip import pallas_interpret
+
+    return pallas_interpret() or cfg.kda_head_dim % 128 == 0
+
+
+def _gqa_kernel(cfg: KDAMoEConfig, page_size: int) -> bool:
+    """Whether the step's GQA attention is :func:`_gqa_attention_pallas`:
+    wherever Mosaic can address a page of the pool as the kernel views
+    it, ``[page_size * n_kv_head, head_dim]`` rows: compiled for a TPU
+    ``head_dim`` must be whole 128-lane tiles and a page's rows whole
+    sublane tiles of the pool's dtype (16 of bfloat16, 8 of float32);
+    interpreted, off the TPU, any page is addressable. Elsewhere
+    :func:`_gqa_attention_gather`."""
+    from .._private.chip import pallas_interpret
+
+    rows = 32 // jnp.dtype(cfg.dtype).itemsize
+    return pallas_interpret() or (
+        cfg.head_dim % 128 == 0 and (page_size * cfg.n_kv_head) % rows == 0)
+
+
 def decode_attention_fused(cfg: KDAMoEConfig, page_size: int,
                            attn_kernel: str = "gather") -> bool:
     """Whether the chunk program built with these knobs holds a Pallas
     kernel (the description's optional entry,
-    :mod:`ray_tpu.models.serving`). This model's is the RECURRENCE
-    (:func:`_kda_step_pallas`), taken wherever Mosaic can address a
-    head's state as whole float32 tiles with ``dk`` on sublanes and
-    ``dv`` on lanes: ``kda_head_dim`` a multiple of 128 compiled for a
-    TPU, any width interpreted off it; elsewhere :func:`_kda_step`. The
-    GQA layer's attention is plain XLA either way; ``page_size`` and
-    ``attn_kernel`` (one value) have no say."""
-    from .._private.chip import pallas_interpret
-
-    return pallas_interpret() or cfg.kda_head_dim % 128 == 0
+    :mod:`ray_tpu.models.serving`). This model has TWO, each taken by
+    what the program can see of its own shapes: the RECURRENCE
+    (:func:`_kda_step_pallas`, :func:`_state_kernel`: from the state's
+    head) and the GQA layers' ATTENTION (:func:`_gqa_attention_pallas`,
+    :func:`_gqa_kernel`: from the page and the head); the answer is
+    for the program, so either one makes it true. ``attn_kernel`` (one
+    value) has no say."""
+    return _state_kernel(cfg) or (cfg.n_gqa > 0
+                                  and _gqa_kernel(cfg, page_size))
 
 
 def _live_lanes(active):
@@ -621,6 +684,175 @@ def _gqa_attention_gather(q, kpool, vpool, pages, pos, cfg: KDAMoEConfig,
                    batch_size=min(B, _GQA_LANE_BLOCK))
 
 
+def _gqa_attention_pallas(q, kpool, vpool, pages, length,
+                          cfg: KDAMoEConfig, page_size: int):
+    """Decode's attention as ONE kernel that reads what is live, once:
+    ``q`` [B, Hq, hd] against the first ``length[b]`` tokens of lane
+    ``b``, whose pages ``pages`` [B, max_pages] names in the flat pools
+    [pages, page_size, Hkv, hd]. Returns float32 [B, Hq, hd]; zeros for
+    a lane of length 0, which costs no byte.
+
+    The frame is :func:`ray_tpu.models.mla_moe._latent_attention_pallas`'s
+    (its schedule copied, not shared: ROADMAP D13): grid ``(B,)``, one
+    step a lane and inside it a loop over THAT lane's live tokens in
+    blocks of :data:`_GQA_BLOCK_TOKENS`; ``pages``, ``length`` and
+    ``first`` (the blocks before each lane: the lanes' blocks in order
+    are one STREAM) ride as scalar-prefetch operands; the pools stay in
+    HBM, viewed ``[pages, page_size * Hkv, hd]`` (a page's rows as they
+    lie, token-major: no copy) and never sliced; a block's live pages
+    come by DMA, one copy a page and pool, into buffer ``i % ring`` of
+    :data:`_GQA_RING_BLOCKS` VMEM blocks of keys and as many of values,
+    each later block started behind the arithmetic of the block whose
+    buffer it takes, so a lane's last blocks fetch the NEXT lane's
+    first. A page is fetched ONCE; a page past the live length never.
+
+    A page's rows are ``(token, KV head)`` with the head in the MIDDLE
+    of ``[page_size, Hkv, hd]``, which Mosaic takes neither as a
+    contraction's batch axis nor as a strided slice of packed rows. So
+    a block is ONE operand ``[T * Hkv, hd]`` and a lane's step two MXU
+    products a block over ALL heads at once: ``s = q . K^T`` ([Hq, hd] x
+    [hd, T * Hkv], float32 sums, scaled in float32) plus ``own``, which
+    is 0 where the row's KV head is the column's and -1e30 elsewhere
+    (an operand laid out by the caller's XLA, fetched once), then ``acc
+    += p . V`` ([Hq, T * Hkv] x [T * Hkv, hd]) around one running-max
+    softmax pass in float32: a foreign head's probability is exactly 0,
+    so the ``Hkv`` times more columns add MXU passes and exponentials
+    (both far under the bytes' time at eight KV heads) and nothing to
+    the sums. The probabilities are rounded to the compute dtype before
+    they meet V, as the XLA body rounds them, but BEFORE the division
+    by the sum: the whole numeric difference
+    (:data:`ATTN_KERNEL_ULPS`). Whole blocks take no other mask; the
+    lane's last, partial block masks the scores AND the values past
+    the live length (a block's unfetched rows hold whatever was there,
+    and 0 * inf is NaN)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .._private.chip import pallas_interpret
+
+    B, Hq, hd = q.shape
+    Hkv = cfg.n_kv_head
+    G = Hq // Hkv
+    ps = page_size
+    rows = ps * Hkv                                # rows a page
+    bp = max(1, _GQA_BLOCK_TOKENS // ps)           # pages a block
+    T = bp * ps
+    ring = _GQA_RING_BLOCKS
+    dtype = q.dtype
+    scale = cfg.head_dim ** -0.5   # a Python float: no captured constant
+    kpool = kpool.reshape(-1, rows, hd)
+    vpool = vpool.reshape(-1, rows, hd)
+    first = jnp.concatenate([
+        jnp.zeros((1,), jnp.int32),
+        jnp.cumsum((length + T - 1) // T, dtype=jnp.int32)])
+    own = jnp.where(
+        (jnp.arange(T * Hkv) % Hkv)[None] == (jnp.arange(Hq) // G)[:, None],
+        0.0, -1e30).astype(jnp.float32)            # [Hq, T * Hkv]
+
+    def kernel(pt_ref, len_ref, first_ref, q_ref, own_ref, k_hbm, v_hbm,
+               o_ref, kbuf, vbuf, sems):
+        b = pl.program_id(0)
+        n_live = len_ref[b]
+        base, total = first_ref[b], first_ref[B]
+
+        def each_page(lane, j, i, what):
+            """``what`` (start or wait) on the two copies of every live
+            page of ``lane``'s block ``j``, block ``i`` of the stream."""
+            n = (len_ref[lane] + ps - 1) // ps         # its live pages
+
+            def page(g, _):
+                for side, (hbm, buf) in enumerate(((k_hbm, kbuf),
+                                                   (v_hbm, vbuf))):
+                    what(pltpu.make_async_copy(
+                        hbm.at[pt_ref[lane, g]],
+                        buf.at[i % ring, g - j * bp],
+                        sems.at[side, i % ring]))
+
+            lax.fori_loop(j * bp, jnp.minimum((j + 1) * bp, n), page, None)
+
+        def start(i, lane):
+            """Fetch block ``i`` of the stream, which is ``lane``'s or a
+            later lane's."""
+            lane = lax.while_loop(lambda c: first_ref[c + 1] <= i,
+                                  lambda c: c + 1, lane)
+            each_page(lane, i - first_ref[lane], i,
+                      lambda copy: copy.start())
+
+        @pl.when(b == 0)
+        def _():
+            lax.fori_loop(0, jnp.minimum(total, ring),
+                          lambda i, _: start(i, 0), None)
+
+        qv = q_ref[0]                                        # [Hq, hd]
+
+        def fold(j, carry, whole=True):
+            """Block ``j`` of the lane into ``(m, l, acc)``: the waits
+            first, the refill last (behind the second product the
+            block's buffers are free), the arithmetic between them."""
+            m, l, acc = carry
+            i = base + j
+            each_page(b, j, i, lambda copy: copy.wait())
+            kb = kbuf[i % ring].reshape(T * Hkv, hd)
+            vb = vbuf[i % ring].reshape(T * Hkv, hd)
+            s = lax.dot_general(qv, kb, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) \
+                * scale + own_ref[...]
+            if not whole:
+                live = (n_live - j * T) * Hkv          # rows of the block
+                s = jnp.where(lax.broadcasted_iota(
+                    jnp.int32, (1, T * Hkv), 1) < live, s, -1e30)
+                vb = jnp.where(lax.broadcasted_iota(
+                    jnp.int32, (T * Hkv, 1), 0) < live, vb,
+                    jnp.zeros_like(vb))
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)                       # 0 where masked
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + jnp.dot(
+                p.astype(dtype), vb, preferred_element_type=jnp.float32)
+            pl.when(i + ring < total)(lambda: start(i + ring, b))
+            return m_new, l, acc
+
+        n_whole = n_live // T                  # blocks that need no mask
+        carry = lax.fori_loop(
+            0, n_whole, fold,
+            (jnp.full((Hq, 1), -1e30, jnp.float32),
+             jnp.zeros((Hq, 1), jnp.float32),
+             jnp.zeros((Hq, hd), jnp.float32)))
+        m, l, acc = lax.cond(
+            n_live > n_whole * T,
+            lambda carry: fold(n_whole, carry, whole=False),
+            lambda carry: carry, carry)
+        o_ref[0] = acc / jnp.where(l > 0.0, l, 1.0)
+
+    def lane_map(b, *prefetched):
+        return (b, 0, 0)
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # `name` names the device operation ("gqa_attention.N") and the last
+    # component of its path before "pallas_call"; the rest of the path
+    # is the caller's scope.
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(B,),
+            in_specs=[pl.BlockSpec((1, Hq, hd), lane_map),
+                      pl.BlockSpec((Hq, T * Hkv), lambda b, *_: (0, 0)),
+                      hbm, hbm],
+            out_specs=pl.BlockSpec((1, Hq, hd), lane_map),
+            scratch_shapes=[pltpu.VMEM((ring, bp, rows, hd), kpool.dtype),
+                            pltpu.VMEM((ring, bp, rows, hd), vpool.dtype),
+                            pltpu.SemaphoreType.DMA((2, ring))]),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, hd), jnp.float32),
+        # Every index a copy takes is in bounds (``pages``) or a
+        # remainder (the ring): the checks Mosaic adds cannot fire.
+        compiler_params=pltpu.CompilerParams(disable_bounds_checks=True),
+        interpret=pallas_interpret(),
+        name="gqa_attention",
+    )(pages, length, first, q, own, kpool, vpool)
+
+
 def forward(params: Params, tokens: jax.Array, cfg: KDAMoEConfig
             ) -> jax.Array:
     """tokens [B, S] -> float32 logits [B, S, rows]: each sequence
@@ -777,13 +1009,14 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                             attn_kernel: str = "gather"):
     """One masked decode step over the whole slot pool: each active lane
     writes its keys and values at its own position and attends over its
-    pages (GQA layers), and reads and writes its state and convolution
+    pages (GQA layers: the kernel over its live pages wherever
+    :func:`_gqa_kernel`, else :func:`_gqa_attention_gather` over its
+    whole table row), and reads and writes its state and convolution
     tail whole (KDA layers: the recurrence as the kernel wherever
-    :func:`decode_attention_fused`, else :func:`_kda_step`). An
-    inactive lane (idle, or parked for pages) neither writes, advances
-    nor routes: its state and tail come out as they went in. Returns
-    ``(logits [B, rows], cache', counts)``: int32 [5]
-    (:data:`STEP_COUNTERS`)."""
+    :func:`_state_kernel`, else :func:`_kda_step`). An inactive lane
+    (idle, or parked for pages) neither writes, advances nor routes:
+    its state and tail come out as they went in. Returns ``(logits [B,
+    rows], cache', counts)``: int32 [6] (:data:`STEP_COUNTERS`)."""
     ps = page_size
     max_pages = pt.shape[1]
     pos = cache["pos"]
@@ -799,7 +1032,13 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     state, conv = cache["state"], cache["conv"]
     counts = jnp.zeros((4,), jnp.int32)
     ig = ik = 0
-    fused = decode_attention_fused(cfg, ps, attn_kernel)
+    state_kernel, gqa_kernel = _state_kernel(cfg), _gqa_kernel(cfg, ps)
+    if gqa_kernel:
+        # the step writes a token's keys and values before it attends
+        length = _live_length(pt, pos, active, n_pages, ps)
+        fetched = jnp.sum((length + ps - 1) // ps * ps, dtype=jnp.int32)
+    else:
+        fetched = jnp.int32(pt.shape[0] * max_pages * ps)
     # the step's own scope: a reader tells the decode program's state,
     # attention and expert time from prefill's by it
     with jax.named_scope("decode_step"):
@@ -811,8 +1050,11 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                 kpool = kpool.at[at].set(k, mode="drop")
                 vpool = vpool.at[at].set(v, mode="drop")
                 with jax.named_scope("gqa.attention"):
-                    att = _gqa_attention_gather(
-                        q, kpool, vpool, ptc + ig * n_pages, pos, cfg, ps)
+                    pages = ptc + ig * n_pages
+                    att = _gqa_attention_pallas(
+                        q, kpool, vpool, pages, length, cfg, ps) \
+                        if gqa_kernel else _gqa_attention_gather(
+                            q, kpool, vpool, pages, pos, cfg, ps)
                 y = _gqa_out(att, z, p, cfg)
                 ig += 1
             else:
@@ -826,7 +1068,7 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                         active[:, None, None],
                         jnp.stack(window[1:], axis=1), tail))
                 with jax.named_scope("kda.state"):
-                    if fused:
+                    if state_kernel:
                         state, o = _kda_step_pallas(
                             state, ik, q, k, v, g, beta, active)
                     else:
@@ -845,7 +1087,8 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                  "state": state, "conv": conv,
                  "pos": pos + active.astype(jnp.int32)}
     counts = jnp.concatenate(
-        [counts, jnp.sum(active, dtype=jnp.int32)[None]])
+        [counts, jnp.sum(active, dtype=jnp.int32)[None],
+         (cfg.n_gqa * fetched)[None]])
     return _head(x, params, cfg), cache_out, counts
 
 
@@ -860,7 +1103,7 @@ def decode_chunk_slots_paged(params: Params, cache: Cache,
     frame of :func:`ray_tpu.models.gpt_decode.decode_chunk_slots_paged`
     around this model's step. The cache (pages AND per-slot state) is
     the scan's carry, donated: a step updates it in place. Returns
-    ``(tokens [B, k], cache', done [B], rngs', counts int32 [5])``."""
+    ``(tokens [B, k], cache', done [B], rngs', counts int32 [6])``."""
     B = token.shape[0]
     eos = jnp.asarray(eos_token, jnp.int32)
     done0 = (active & (token == eos)) if eos_token >= 0 \

@@ -43,15 +43,18 @@ A description provides, under these names:
     name, ``"gather"``, which is a Pallas kernel over each lane's live
     latent pages wherever Mosaic can address a page and plain XLA over
     the gathered pages where it cannot (the model chooses, by shape);
-    ``kda_moe`` has one too, ``"gather"`` (its GQA layer's attention
-    over gathered pages), and chooses its recurrence's kernel by shape
-    the same way.
+    ``kda_moe`` has one too, ``"gather"`` (its GQA layers' attention:
+    a Pallas kernel over each lane's live pages of keys and values
+    wherever Mosaic can address a page and a head, plain XLA over the
+    gathered pages where it cannot), and chooses its recurrence's
+    kernel by shape the same way.
 ``decode_attention_fused(cfg, page_size, attn_kernel) -> bool``
     OPTIONAL: whether the chunk program built with these knobs holds a
     fused kernel of its decode step's sequence mixing, WHICHEVER that
     is: ``mla_moe`` answers for its attention over latent pages,
-    ``kda_moe`` for the recurrence on its per-slot state (its one GQA
-    layer's attention is plain XLA either way). The engine asks it for
+    ``kda_moe`` for TWO kernels, the recurrence on its per-slot state
+    and its GQA layers' attention over pages, each taken by its own
+    shapes: either one makes the answer true. The engine asks it for
     ``warm_up()["attn_kernel_mode"]`` (``"compiled"`` / ``"interpret"``,
     read off the lowered program, or ``None`` without a kernel) and for
     ``stats()["attn_kernel_dispatches"]``; a description without it

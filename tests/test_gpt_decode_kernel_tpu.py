@@ -1,6 +1,7 @@
 """The decode step's kernels (``gpt_decode``'s attention over pages of
 keys and values per head, ``mla_moe``'s over latent pages, ``kda_moe``'s
-recurrence on the per-slot state) compiled by the
+recurrence on the per-slot state and its grouped-query attention over
+pages of ``(token, KV head)`` rows) compiled by the
 TPU's own compiler, for a chip that is described and not attached
 (v5e), at the shapes the chip runs: what interpret mode cannot see —
 a slice off the tiling, a DMA Mosaic cannot address, more VMEM than a
@@ -242,13 +243,71 @@ def test_kda_state_kernel_compiles_for_v5e(one_chip, compiled_mode, shape):
     assert memory.temp_size_in_bytes < state_bytes // 16
 
 
-def test_the_kda_step_chooses_by_the_head_for_v5e(one_chip, compiled_mode):
-    """The whole decode step as a TPU process builds it: with heads of
-    128 the kernel, under the path the benchmark's readers look for
-    (``decode_step/kda.state``); with heads of 64 (half a lane tile)
-    ``_kda_step``, and nothing raises."""
+def _kda_step_lowered(one_chip, **widths):
+    """``kda_moe``'s whole decode step as a TPU process builds it, at
+    ``nano`` around the given widths: ``(cfg, page_size, lowered)``."""
     import dataclasses
     import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import kda_moe
+
+    cfg = dataclasses.replace(kda_moe.CONFIGS["nano"], d_model=256,
+                              kda_heads=16, experts_held=8, **widths)
+    B, ps, n_pages = 8, 16, 32
+
+    def arg(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda k: kda_moe.init_params(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(
+        lambda: kda_moe.init_paged_cache(cfg, B, n_pages, ps))
+    args = (params, cache, jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((B, n_pages // B), jnp.int32))
+    return cfg, ps, jax.jit(functools.partial(
+        kda_moe._slot_decode_step_paged, cfg=cfg, page_size=ps),
+        donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
+
+
+@pytest.mark.parametrize("kda_hd,fused", [(128, True), (64, False)])
+def test_the_kda_step_chooses_by_the_head_for_v5e(one_chip, compiled_mode,
+                                                  kda_hd, fused):
+    """The whole decode step as a TPU process builds it: with heads of
+    128 the recurrence's kernel, under the path the benchmark's readers
+    look for (``decode_step/kda.state``); with heads of 64 (half a lane
+    tile) ``_kda_step``, and nothing raises. (The GQA layer's heads are
+    of 64 in both: no kernel there, so the program holds one or none.)"""
+    from ray_tpu._private import chip
+    from ray_tpu.models import kda_moe
+
+    cfg, ps, lowered = _kda_step_lowered(one_chip, kda_head_dim=kda_hd,
+                                         head_dim=64)
+    assert kda_moe._state_kernel(cfg) is fused
+    assert kda_moe.decode_attention_fused(cfg, ps) is fused
+    assert chip.compiled_by_mosaic(lowered.as_text()) is fused
+    text = lowered.compile().as_text()
+    assert ("decode_step/kda.state/kda_state/pallas_call" in text) is fused
+    assert "gqa_attention/pallas_call" not in text
+
+
+# ------------------------------------- grouped-query attention (S5g)
+#: (lanes, pages in the flat pool, max_pages): the cell (one GQA layer
+#: of 20,480 pages, 256 lanes of 128 pages) and the benchmark's
+#: reference check (48 rows on a pool of their own)
+GQA_SHAPES = {"cell": (256, 20480, 128), "check": (48, 48 * 16, 16)}
+
+
+@pytest.mark.parametrize("shape", sorted(GQA_SHAPES))
+def test_gqa_attention_compiles_for_v5e(one_chip, compiled_mode, shape):
+    """The attention's kernel at the served widths (64 query heads over
+    8 KV heads of 128, pages of 16): Mosaic takes a page as 128 rows of
+    ``(token, KV head)``, both pools are the kernel's operands as they
+    lie (no copy, no transposed page), one ``tpu_custom_call``."""
+    import dataclasses
 
     import jax
     import jax.numpy as jnp
@@ -256,28 +315,49 @@ def test_the_kda_step_chooses_by_the_head_for_v5e(one_chip, compiled_mode):
     from ray_tpu._private import chip
     from ray_tpu.models import kda_moe
 
-    for hd, fused in ((128, True), (64, False)):
-        cfg = dataclasses.replace(kda_moe.CONFIGS["nano"], d_model=256,
-                                  kda_heads=16, kda_head_dim=hd,
-                                  head_dim=128, experts_held=8)
-        B, ps, n_pages = 8, 16, 32
+    cfg = dataclasses.replace(kda_moe.CONFIGS["nano"], n_head=64,
+                              n_kv_head=8, head_dim=128)
+    B, n_pages, max_pages = GQA_SHAPES[shape]
+    ps = 16
+    assert kda_moe._gqa_kernel(cfg, ps)
 
-        def arg(s):
-            return jax.ShapeDtypeStruct(s.shape, s.dtype,
-                                        sharding=one_chip)
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-        params = jax.eval_shape(
-            lambda k: kda_moe.init_params(k, cfg), jax.random.PRNGKey(0))
-        cache = jax.eval_shape(
-            lambda: kda_moe.init_paged_cache(cfg, B, n_pages, ps))
-        args = (params, cache, jax.ShapeDtypeStruct((B,), jnp.int32),
-                jax.ShapeDtypeStruct((B,), jnp.bool_),
-                jax.ShapeDtypeStruct((B, n_pages // B), jnp.int32))
-        assert kda_moe.decode_attention_fused(cfg, ps) is fused
-        lowered = jax.jit(functools.partial(
-            kda_moe._slot_decode_step_paged, cfg=cfg, page_size=ps),
-            donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
-        assert chip.compiled_by_mosaic(lowered.as_text()) is fused
-        text = lowered.compile().as_text()
-        assert ("decode_step/kda.state/kda_state/pallas_call"
-                in text) is fused
+    pool = arg((n_pages, ps, 8, 128))
+    lowered = jax.jit(
+        lambda q, k, v, pages, length: kda_moe._gqa_attention_pallas(
+            q, k, v, pages, length, cfg, ps)).lower(
+        arg((B, 64, 128)), pool, pool, arg((B, max_pages), jnp.int32),
+        arg((B,), jnp.int32))
+    assert chip.compiled_by_mosaic(lowered.as_text())
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "gqa_attention/pallas_call" in text
+    # the pools go in as they lie: no temporary the size of a gather
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+@pytest.mark.parametrize("hd,fused", [(128, True), (64, False)])
+def test_the_gqa_step_chooses_by_the_page_and_head_for_v5e(
+        one_chip, compiled_mode, hd, fused):
+    """The whole decode step as a TPU process builds it: with heads of
+    128 (pages of 16 x 2 KV heads: two bfloat16 tiles of rows) the
+    kernel, under the path the benchmark's readers look for
+    (``decode_step/gqa.attention``); with heads of 64 (half a lane
+    tile, which Mosaic cannot address) the gather, and nothing raises.
+    The recurrence's heads are of 64 in both: no kernel there."""
+    from ray_tpu._private import chip
+    from ray_tpu.models import kda_moe
+
+    cfg, ps, lowered = _kda_step_lowered(one_chip, kda_head_dim=64,
+                                         head_dim=hd)
+    assert kda_moe._gqa_kernel(cfg, ps) is fused
+    assert kda_moe.decode_attention_fused(cfg, ps) is fused
+    assert chip.compiled_by_mosaic(lowered.as_text()) is fused
+    text = lowered.compile().as_text()
+    assert ("decode_step/gqa.attention/gqa_attention/pallas_call"
+            in text) is fused
+    assert ("tpu_custom_call" in text) is fused
+    assert "kda_state/pallas_call" not in text

@@ -146,14 +146,14 @@ def _step_pair(cfg, monkeypatch):
     active = np.array([True, False, True])
     out = []
     for fused in (True, False):
-        monkeypatch.setattr(km, "decode_attention_fused",
-                            lambda *a, fused=fused, **k: fused)
+        monkeypatch.setattr(km, "_state_kernel",
+                            lambda cfg, fused=fused: fused)
         step = jax.jit(functools.partial(km._slot_decode_step_paged,
                                          cfg=cfg, page_size=ps))
         logits, after, counts = step(params, dict(cache),
                                      jnp.asarray([5, 7, 9]), active,
                                      jnp.asarray(pt))
-        assert int(counts[-1]) == 2
+        assert int(counts[4]) == 2
         out.append((np.asarray(logits),
                     jax.tree_util.tree_map(np.asarray, after)))
     return out[0], out[1], held

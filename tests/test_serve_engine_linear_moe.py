@@ -204,7 +204,7 @@ def test_an_idle_or_parked_lanes_state_does_not_change(model):
         assert not np.array_equal(after[name][:, 0], before[name][:, 0])
     assert np.array_equal(after["k"][:, 24:], held["k"][:, 24:])
     assert list(after["pos"]) == [14, 13, 0]
-    assert int(counts[-1]) == 1                  # one live lane
+    assert int(counts[4]) == 1                  # one live lane
 
 
 def test_a_requests_tokens_are_the_same_alone_and_among_others(model,
@@ -334,6 +334,31 @@ def test_the_live_lanes_come_out_with_the_tokens(model, engine):
     assert moved["state_lanes_sum"] == launches * engine.chunk
     assert 0 < moved["moe_experts_touched_sum"] \
         <= moved["moe_tokens_here_sum"]
+
+
+def test_the_positions_attention_fetched_come_out_with_the_tokens(model,
+                                                                  engine):
+    """ISSUE 46: ``stats()`` carries ``gqa_tokens_read_sum``, the
+    positions whose keys and values the steps' attention fetched. With
+    the kernel (interpreted here) that is the one live lane's tokens in
+    whole pages, step by step, far below the ``slots x max_len`` a
+    step the gather copies."""
+    cfg, _ = model
+    assert kda_moe.STEP_COUNTERS[4:] == ("state_lanes_sum",
+                                         "gqa_tokens_read_sum")
+    before = engine.stats()
+    assert "gqa_tokens_read_sum" in before
+    n, new = 13, 9
+    _answer(engine, _prompts(cfg, (n,), seed=5)[0], new)
+    after = engine.stats()
+    steps = (after["dispatches"] - before["dispatches"]) * engine.chunk
+    moved = after["gqa_tokens_read_sum"] - before["gqa_tokens_read_sum"]
+    ps = 4
+    # the prefill made the first token; decode step i writes position
+    # n + i and attends 0..n + i (8 steps in two chunks of 4)
+    assert moved == cfg.n_gqa * sum(-(-(n + i + 1) // ps) * ps
+                                    for i in range(steps))
+    assert moved < 4 * 96 * steps              # slots x max_len x steps
 
 
 # ---- what the model does not get
