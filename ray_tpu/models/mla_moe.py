@@ -165,6 +165,11 @@ class MLAMoEConfig:
     param_dtype: Any = jnp.bfloat16
     moe_block_rows: int = 32
 
+    # no factor on ``c_q`` or on ``c`` after their norms; a model that
+    # scales them (:mod:`ray_tpu.models.scmoe`) states its own
+    q_gain = 1.0
+    kv_gain = 1.0
+
     @property
     def latent_dim(self) -> int:
         return self.kv_rank + self.rope_dim
@@ -249,13 +254,17 @@ def init_params(rng: jax.Array, cfg: MLAMoEConfig, std: Optional[dict] = None
 
 
 # ------------------------------------------------------------ block math
-def _rmsnorm(x, scale, eps, dtype=None):
+def _rmsnorm(x, scale, eps, dtype=None, gain: float = 1.0):
     """RMSNorm in float32; the result in ``dtype`` (``x``'s own if
-    absent), ready to be multiplied."""
+    absent), ready to be multiplied. ``gain``: a constant factor on
+    the normed values, applied in float32 before the one rounding."""
     dtype = dtype or x.dtype
     x = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return (x * lax.rsqrt(var + eps)).astype(dtype) * scale.astype(dtype)
+    y = x * lax.rsqrt(var + eps)
+    if gain != 1.0:
+        y = y * gain
+    return y.astype(dtype) * scale.astype(dtype)
 
 
 def yarn_inv_freq(cfg: MLAMoEConfig) -> jax.Array:
@@ -299,17 +308,22 @@ def _latent_qkv(x, p, positions, cfg: MLAMoEConfig):
     """x [B, S, d] at ``positions`` [B, S] -> (q_n [B, S, H, nope],
     q_r [B, S, H, rope] rotated, entry [B, S, latent_row]: the token's
     cache row, ``c`` after its norm, ``k_r`` rotated, zeros to the
-    row's width)."""
+    row's width). ``p`` is ONE attention's tree (``ln1_scale`` ..
+    ``wo``). ``cfg.q_gain`` scales ``c_q`` after its norm (so both
+    parts of the query: ``W_qb`` is linear) and ``cfg.kv_gain`` the
+    latent ``c`` after its norm, NOT ``k_r``; 1.0 is no factor at
+    all."""
     B, S, _ = x.shape
     H = cfg.n_head
     h = _rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
     cq = _rmsnorm(_mm(h, p["wqa"]["kernel"], cfg.dtype),
-                  p["q_norm_scale"], cfg.eps)
+                  p["q_norm_scale"], cfg.eps, gain=cfg.q_gain)
     q = _mm(cq, p["wqb"]["kernel"], cfg.dtype).reshape(
         B, S, H, cfg.nope_dim + cfg.rope_dim)
     qn, qr = q[..., :cfg.nope_dim], q[..., cfg.nope_dim:]
     ckv = _mm(h, p["wkva"]["kernel"], cfg.dtype)
-    c = _rmsnorm(ckv[..., :cfg.kv_rank], p["kv_norm_scale"], cfg.eps)
+    c = _rmsnorm(ckv[..., :cfg.kv_rank], p["kv_norm_scale"], cfg.eps,
+                 gain=cfg.kv_gain)
     kr = _rope(ckv[..., cfg.kv_rank:], positions, cfg)
     pad = jnp.zeros(c.shape[:-1] + (cfg.latent_row - cfg.latent_dim,),
                     c.dtype)
@@ -462,37 +476,34 @@ def _at_layer(pages, l: int, n_pages: int):
                      pages + l * n_pages, jnp.int32(PT_SENTINEL))
 
 
-def prefill_into_slot_paged(params: Params, cache: Cache,
-                            tokens: jax.Array, length: jax.Array,
-                            hist_len: jax.Array, pt_row: jax.Array,
-                            cow_src: jax.Array, slot: jax.Array,
-                            rng: jax.Array, *, cfg: MLAMoEConfig,
-                            page_size: int, temperature: float = 0.0,
-                            kv_dtype: str = "fp"
-                            ) -> Tuple[jax.Array, Cache, jax.Array]:
-    """Prefill one prompt SUFFIX into its pages, with the optional
-    copy-on-write fork and the first token's sample: the contract of
-    :func:`ray_tpu.models.gpt_decode.prefill_into_slot_paged`, on
-    latent pages. Suffix token ``i`` sits at position ``hist_len + i``
-    and attends over the cached prefix (latents read through
-    ``pt_row``, valid below ``hist_len``) and the suffix, causally;
-    keys and values are materialised from the latents per head. Pad
-    positions' writes are dropped."""
-    B, S = tokens.shape
+def _prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
+                       cow_src, cfg, page_size: int):
+    """The paged prefill's frame around ANY model whose attentions
+    leave latent rows: the copy-on-write fork, then one attention
+    sub-block at a time. ``cache["latent"]`` is ``[A, n_pages, ps,
+    row]`` over the model's ``A`` attentions (one a layer here; a model
+    with two a layer counts both, :mod:`ray_tpu.models.scmoe`). Returns
+    ``(pool, live, attend)``: the pool in its flat view after the fork,
+    which of the ``S`` rows are tokens, and ``attend(x, p, a, pool) ->
+    (x + Attn_a(x), pool')`` for attention ``a`` with tree ``p`` (scope
+    ``mla.prefill``): suffix token ``i`` sits at position ``hist_len +
+    i`` and attends over the cached prefix (latents read through
+    ``pt_row``, valid below ``hist_len``) and the suffix, causally,
+    keys and values materialised from the latents per head; the rows'
+    own latents go into their pages, pad positions' writes dropped."""
     ps = page_size
-    L, n_pages = cache["latent"].shape[:2]
+    A, n_pages = cache["latent"].shape[:2]
     max_pages = pt_row.shape[0]
     V = max_pages * ps
     positions = hist_len + jnp.arange(S)
-    x = _embed(params, tokens)
 
-    # COW fork first, every layer's page at once, in the pool's FLAT
-    # view like every other access (a fork written as ``pool[:, dst]``
-    # made XLA hold the pool in a second layout and copy all of it
-    # twice a prefill: PERF.md, PR 37); no fork copies to an
+    # COW fork first, every attention's page at once, in the pool's
+    # FLAT view like every other access (a fork written as ``pool[:,
+    # dst]`` made XLA hold the pool in a second layout and copy all of
+    # it twice a prefill: PERF.md, PR 37); no fork copies to an
     # out-of-bounds page and is dropped.
     pool = _flat(cache["latent"])
-    layers = jnp.arange(L, dtype=jnp.int32) * n_pages
+    layers = jnp.arange(A, dtype=jnp.int32) * n_pages
     dst = pt_row[jnp.clip(hist_len // ps, 0, max_pages - 1)]
     dst_w = jnp.where((cow_src < n_pages) & (dst < n_pages),
                       dst + layers, jnp.int32(PT_SENTINEL))
@@ -504,22 +515,29 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
         jnp.broadcast_to(jnp.arange(V) < hist_len, (S, V)),
         jnp.tril(jnp.ones((S, S), jnp.bool_))], axis=1)[None, None]
     live = jnp.arange(S) < length
-    wpos = hist_len + jnp.arange(S)
-    vp = wpos // ps
+    vp = positions // ps
     page_w = jnp.where(live & (vp < max_pages),
                        pt_row[jnp.clip(vp, 0, max_pages - 1)],
                        jnp.int32(PT_SENTINEL))
-    for l, p in enumerate(params["layers"]):
+
+    def attend(x, p, a: int, pool):
         qn, qr, ent = _latent_qkv(x, p, positions[None], cfg)
         with jax.named_scope("mla.prefill"):
-            hist = pool[ptc + l * n_pages].reshape(1, V, -1)
+            hist = pool[ptc + a * n_pages].reshape(1, V, -1)
             att = _attend_materialised(
                 qn, qr, jnp.concatenate([hist, ent], axis=1), seen, p,
                 cfg)
         x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
-        x = _ffn(x[0], p, cfg, live)[0][None]
-        pool = pool.at[_at_layer(page_w, l, n_pages), wpos % ps].set(
-            ent[0], mode="drop")
+        return x, pool.at[_at_layer(page_w, a, n_pages),
+                          positions % ps].set(ent[0], mode="drop")
+
+    return pool, live, attend
+
+
+def _prefill_result(x, pool, params: Params, cache: Cache, length,
+                    hist_len, slot, rng, cfg, temperature: float):
+    """The first token's sample from the last live row of ``x`` [1, S,
+    d], and the cache with ``pool`` and the slot's ``pos``."""
     x_last = lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, cfg.d_model))
     token, rng = _sample(_head(x_last, params, cfg)[:, 0], temperature,
                          rng)
@@ -527,6 +545,29 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
         cache["pos"], jnp.reshape(hist_len + length, (1,)), (slot,))
     return token[0], {"latent": pool.reshape(cache["latent"].shape),
                       "pos": pos}, rng
+
+
+def prefill_into_slot_paged(params: Params, cache: Cache,
+                            tokens: jax.Array, length: jax.Array,
+                            hist_len: jax.Array, pt_row: jax.Array,
+                            cow_src: jax.Array, slot: jax.Array,
+                            rng: jax.Array, *, cfg: MLAMoEConfig,
+                            page_size: int, temperature: float = 0.0,
+                            kv_dtype: str = "fp"
+                            ) -> Tuple[jax.Array, Cache, jax.Array]:
+    """Prefill one prompt SUFFIX into its pages, with the optional
+    copy-on-write fork and the first token's sample: the contract of
+    :func:`ray_tpu.models.gpt_decode.prefill_into_slot_paged`, on
+    latent pages (:func:`_prefill_attention`)."""
+    pool, live, attend = _prefill_attention(
+        cache, tokens.shape[1], length, hist_len, pt_row, cow_src, cfg,
+        page_size)
+    x = _embed(params, tokens)
+    for l, p in enumerate(params["layers"]):
+        x, pool = attend(x, p, l, pool)
+        x = _ffn(x[0], p, cfg, live)[0][None]
+    return _prefill_result(x, pool, params, cache, length, hist_len, slot,
+                           rng, cfg, temperature)
 
 
 def decode_attention_fused(cfg: MLAMoEConfig, page_size: int,
@@ -729,24 +770,21 @@ def _live_length(pt, pos, active, n_pages: int, page_size: int):
                                          mapped * page_size), 0)
 
 
-def _slot_decode_step_paged(params: Params, cache: Cache,
-                            token: jax.Array, active: jax.Array,
-                            pt: jax.Array, cfg: MLAMoEConfig,
-                            page_size: int, kv_dtype: str = "fp",
-                            attn_kernel: str = "gather"):
-    """One masked decode step over the whole slot pool: each active
-    lane writes its latent row at its own position and attends, in the
-    latent space with the up-projections absorbed, over its own pages
-    up to it (the kernel wherever :func:`decode_attention_fused`, else
-    the XLA body). Inactive lanes neither write, advance nor route.
-    Returns ``(logits [B, rows], cache', counts)``: the expert layers'
-    counters int32 [4] (:data:`STEP_COUNTERS`)."""
-    B = token.shape[0]
+def _decode_attention(cache: Cache, active, pt, cfg, page_size: int,
+                      attn_kernel: str = "gather"):
+    """One decode step's frame around ANY model whose attentions leave
+    latent rows (``cache["latent"]`` ``[A, n_pages, ps, row]`` over its
+    ``A`` attentions). Returns ``(pool, attend)``: the pool in its flat
+    view and ``attend(x, p, a, pool) -> (x + Attn_a(x), pool')`` for
+    ``x`` [B, 1, d] (scope ``mla.attention``): each active lane writes
+    its latent row at its own position and attends, in the latent
+    space with the up-projections absorbed, over its own pages up to
+    it (the kernel wherever :func:`decode_attention_fused`, else the
+    XLA body). Inactive lanes do not write."""
     ps = page_size
-    max_pages = pt.shape[1]
+    B, max_pages = pt.shape
     pos = cache["pos"]
-    L, n_pages = cache["latent"].shape[:2]
-    x = _embed(params, token)[:, None]
+    n_pages = cache["latent"].shape[1]
     vp = pos // ps
     page_w = jnp.where(
         active & (vp < max_pages),
@@ -755,35 +793,55 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     ptc = jnp.clip(pt, 0, n_pages - 1)
     fused = decode_attention_fused(cfg, ps, attn_kernel)
     length = _live_length(pt, pos, active, n_pages, ps) if fused else None
-    pool = _flat(cache["latent"])
+
+    def attend(x, p, a: int, pool):
+        qn, qr, ent = _latent_qkv(x, p, pos[:, None], cfg)
+        pool = pool.at[_at_layer(page_w, a, n_pages), pos % ps].set(
+            ent[:, 0], mode="drop")
+        w_uk, w_uv = _wkvb(p, cfg)
+        q = jnp.concatenate([
+            jnp.einsum("bhn,rhn->bhr", qn[:, 0], w_uk,
+                       preferred_element_type=jnp.float32
+                       ).astype(cfg.dtype), qr[:, 0],
+            jnp.zeros((B, cfg.n_head, cfg.latent_row - cfg.latent_dim),
+                      cfg.dtype)], axis=-1)
+        with jax.named_scope("mla.attention"):
+            pages = ptc + a * n_pages
+            o = _latent_attention_pallas(q, pool, pages, length, cfg,
+                                         ps) if fused else \
+                _latent_attention_gather(q, pool, pages, pos, cfg, ps)
+        att = jnp.einsum("bhr,rhv->bhv", o, w_uv,
+                         preferred_element_type=jnp.float32
+                         ).astype(cfg.dtype).reshape(B, 1, -1)
+        return x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype), \
+            pool
+
+    return _flat(cache["latent"]), attend
+
+
+def _slot_decode_step_paged(params: Params, cache: Cache,
+                            token: jax.Array, active: jax.Array,
+                            pt: jax.Array, cfg: MLAMoEConfig,
+                            page_size: int, kv_dtype: str = "fp",
+                            attn_kernel: str = "gather"):
+    """One masked decode step over the whole slot pool
+    (:func:`_decode_attention`, then the layer's FFN). Inactive lanes
+    neither write, advance nor route. Returns ``(logits [B, rows],
+    cache', counts)``: the expert layers' counters int32 [4]
+    (:data:`STEP_COUNTERS`)."""
+    pool, attend = _decode_attention(cache, active, pt, cfg, page_size,
+                                     attn_kernel)
+    x = _embed(params, token)[:, None]
     counts = jnp.zeros((4,), jnp.int32)
     # the step's own scope: a reader tells the decode program's
     # expert and attention time from prefill's by it
     with jax.named_scope("decode_step"):
         for l, p in enumerate(params["layers"]):
-            qn, qr, ent = _latent_qkv(x, p, pos[:, None], cfg)
-            pool = pool.at[_at_layer(page_w, l, n_pages), pos % ps].set(
-                ent[:, 0], mode="drop")
-            w_uk, w_uv = _wkvb(p, cfg)
-            q = jnp.concatenate([
-                jnp.einsum("bhn,rhn->bhr", qn[:, 0], w_uk,
-                           preferred_element_type=jnp.float32
-                           ).astype(cfg.dtype), qr[:, 0],
-                jnp.zeros((B, cfg.n_head, cfg.latent_row
-                           - cfg.latent_dim), cfg.dtype)], axis=-1)
-            with jax.named_scope("mla.attention"):
-                pages = ptc + l * n_pages
-                o = _latent_attention_pallas(q, pool, pages, length, cfg,
-                                             ps) if fused else \
-                    _latent_attention_gather(q, pool, pages, pos, cfg, ps)
-            att = jnp.einsum("bhr,rhv->bhv", o, w_uv,
-                             preferred_element_type=jnp.float32
-                             ).astype(cfg.dtype).reshape(B, 1, -1)
-            x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
+            x, pool = attend(x, p, l, pool)
             y, c = _ffn(x[:, 0], p, cfg, active)
             x, counts = y[:, None], counts + c
     cache_out = {"latent": pool.reshape(cache["latent"].shape),
-                 "pos": pos + active.astype(jnp.int32)}
+                 "pos": cache["pos"] + active.astype(jnp.int32)}
     return _head(x, params, cfg)[:, 0], cache_out, counts
 
 
@@ -793,14 +851,17 @@ def decode_chunk_slots_paged(params: Params, cache: Cache,
                              cfg: MLAMoEConfig, k: int, page_size: int,
                              temperature: float = 0.0,
                              eos_token: int = -1, kv_dtype: str = "fp",
-                             attn_kernel: str = "gather"):
+                             attn_kernel: str = "gather",
+                             step=_slot_decode_step_paged,
+                             counters: int = len(STEP_COUNTERS)):
     """k fused decode steps over the slot pool in ONE program: the
     frame of :func:`ray_tpu.models.gpt_decode.decode_chunk_slots_paged`
     (the page table constant through the chunk, per-slot PRNG lanes,
-    EOS mask-and-carry) around this model's step. Returns ``(tokens
-    [B, k], cache', done [B], rngs', counts int32 [4])``: the expert
-    layers' counters summed over the k steps come out with the tokens,
-    at no launch of their own."""
+    EOS mask-and-carry) around a model's ``step`` (this one's, or a
+    sibling's with ``counters`` of its own). Returns ``(tokens [B, k],
+    cache', done [B], rngs', counts int32 [counters])``: the model's
+    counters summed over the k steps come out with the tokens, at no
+    launch of their own."""
     B = token.shape[0]
     eos = jnp.asarray(eos_token, jnp.int32)
     done0 = (active & (token == eos)) if eos_token >= 0 \
@@ -808,7 +869,7 @@ def decode_chunk_slots_paged(params: Params, cache: Cache,
 
     def body(carry, _):
         cache, tok, done, keys, counts = carry
-        logits, cache, c = _slot_decode_step_paged(
+        logits, cache, c = step(
             params, cache, tok, active, pt, cfg, page_size, kv_dtype,
             attn_kernel)
         nxt, keys = _sample_slots(logits, temperature, keys)
@@ -818,8 +879,8 @@ def decode_chunk_slots_paged(params: Params, cache: Cache,
         return (cache, nxt, done, keys, counts + c), nxt
 
     (cache, _, done, rngs, counts), toks = lax.scan(
-        body, (cache, token, done0, rngs, jnp.zeros((4,), jnp.int32)),
-        None, length=k)
+        body, (cache, token, done0, rngs,
+               jnp.zeros((counters,), jnp.int32)), None, length=k)
     return jnp.moveaxis(toks, 0, 1), cache, done, rngs, counts
 
 

@@ -119,7 +119,12 @@ def moe_ffn(x: jax.Array, router_kernel: jax.Array, w_up: jax.Array,
 # parallelism's share of one chip): it routes over all ``n_routed``
 # experts and computes its own experts' part for the tokens routed to
 # them. What the absent experts would add is another chip's to compute
-# and is not stood in for.
+# and is not stood in for. ROUTING and DISPATCH are apart: a router
+# (``route_sigmoid``: sigmoid scores, group-limited, normalised;
+# ``route_softmax_bias``: softmax scores, a selection bias, ids that may
+# lie past the routed experts) gives ids and weights, and ONE dispatch,
+# block loop and combine (``dropless_experts``) serves them all;
+# ``zero_experts`` is the part of the ids that cost nothing.
 
 def group_limited_top_k(scores: jax.Array, n_group: int, topk_group: int,
                         top_k: int) -> Tuple[jax.Array, jax.Array]:
@@ -140,6 +145,14 @@ def group_limited_top_k(scores: jax.Array, n_group: int, topk_group: int,
     return ids.astype(jnp.int32), chosen
 
 
+def _router_logits(x: jax.Array, router_kernel: jax.Array, dtype
+                   ) -> jax.Array:
+    """x [T, d] times the router [d, width] in ``dtype``, float32 sums."""
+    return lax.dot_general(x.astype(dtype), router_kernel.astype(dtype),
+                           (((1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
 def route_sigmoid(x: jax.Array, router_kernel: jax.Array, *, n_group: int,
                   topk_group: int, top_k: int, norm_topk: bool,
                   route_scale: float, dtype
@@ -148,11 +161,9 @@ def route_sigmoid(x: jax.Array, router_kernel: jax.Array, *, n_group: int,
     over ALL routed experts (the router keeps its published width),
     group-limited selection, the chosen scores normalised to sum to one
     where ``norm_topk``, times ``route_scale``."""
-    logits = lax.dot_general(x.astype(dtype), router_kernel.astype(dtype),
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ids, s = group_limited_top_k(jax.nn.sigmoid(logits), n_group,
-                                 topk_group, top_k)
+    ids, s = group_limited_top_k(
+        jax.nn.sigmoid(_router_logits(x, router_kernel, dtype)), n_group,
+        topk_group, top_k)
     if norm_topk:
         s = s / (jnp.sum(s, axis=-1, keepdims=True) + 1e-20)
     return ids, s * route_scale
@@ -170,18 +181,75 @@ def gated_ffn(x: jax.Array, p, dtype) -> jax.Array:
     return mm(h, p["down"]).astype(dtype)
 
 
+def route_softmax_bias(x: jax.Array, router_kernel: jax.Array,
+                       bias: jax.Array, *, top_k: int, route_scale: float,
+                       dtype) -> Tuple[jax.Array, jax.Array]:
+    """x [T, d] -> (ids [T, k], weights [T, k] float32): softmax scores
+    ``p`` in float32 over the router's WHOLE width (which may reach
+    past the routed experts: ids from ``n_routed`` on are zero-compute
+    experts, :func:`zero_experts`), the ``top_k`` highest ``p + bias``
+    chosen (``bias`` [width] float32 steers the SELECTION only: a
+    buffer that training moves and serving reads), no groups, and the
+    weights the UNBIASED scores times ``route_scale``, not
+    renormalised."""
+    p = jax.nn.softmax(_router_logits(x, router_kernel, dtype), axis=-1)
+    _, ids = lax.top_k(p + bias.astype(jnp.float32), top_k)
+    ids = ids.astype(jnp.int32)
+    return ids, jnp.take_along_axis(p, ids, axis=1) * route_scale
+
+
+def zero_experts(x: jax.Array, ids: jax.Array, w: jax.Array, *,
+                 n_routed: int, live: Optional[jax.Array] = None
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """The identity experts' part of a routed layer: ``y[t] = (sum of
+    w[t, j] over the choices with ids[t, j] >= n_routed) * x[t]``,
+    float32. They hold no weight and are computed where the token
+    lives, whoever holds the routed experts: every such choice of every
+    row here, once. Also the count of those choices, int32 (``live``
+    [T] bool masks rows that are no tokens)."""
+    with jax.named_scope("moe.zero"):
+        zero = ids >= n_routed
+        if live is not None:
+            zero = zero & live[:, None]
+        y = jnp.sum(jnp.where(zero, w, 0.0), axis=1, keepdims=True) \
+            * x.astype(jnp.float32)
+    return y, jnp.sum(zero, dtype=jnp.int32)
+
+
 def dropless_moe(x: jax.Array, router_kernel: jax.Array, experts, *,
                  experts_held: int, expert_offset: int, n_group: int,
                  topk_group: int, top_k: int, norm_topk: bool,
                  route_scale: float, dtype, block_rows: int = 32,
                  live: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, jax.Array]:
-    """The held experts' part of a routed layer, dropless.
+    """The held experts' part of a sigmoid-routed layer:
+    :func:`route_sigmoid` over ``router_kernel`` [d, n_routed] (scope
+    ``moe.route``), then :func:`dropless_experts` on its choices."""
+    with jax.named_scope("moe.route"):
+        ids, w = route_sigmoid(x, router_kernel, n_group=n_group,
+                               topk_group=topk_group, top_k=top_k,
+                               norm_topk=norm_topk,
+                               route_scale=route_scale, dtype=dtype)
+    return dropless_experts(x, ids, w, experts, experts_held=experts_held,
+                            expert_offset=expert_offset, dtype=dtype,
+                            block_rows=block_rows, live=live)
 
-    ``x`` [T, d]; ``router_kernel`` [d, n_routed]; ``experts`` holds
-    ``gate``/``up`` [held, d, f] and ``down`` [held, f, d] for experts
-    ``expert_offset .. expert_offset + experts_held`` of the
-    ``n_routed``. Returns ``(y [T, d] in dtype, counts int32 [3])``:
+
+def dropless_experts(x: jax.Array, ids: jax.Array, w: jax.Array, experts,
+                     *, experts_held: int, expert_offset: int, dtype,
+                     block_rows: int = 32,
+                     live: Optional[jax.Array] = None
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of a routed layer, dropless, for a
+    ROUTING its caller made: ``ids`` [T, k] int32 and ``w`` [T, k]
+    float32 from whichever router the model has
+    (:func:`route_sigmoid`, :func:`route_softmax_bias`); an id outside
+    the held range, another chip's expert or no routed expert at all,
+    lands nowhere here.
+
+    ``x`` [T, d]; ``experts`` holds ``gate``/``up`` [held, d, f] and
+    ``down`` [held, f, d] for experts ``expert_offset .. expert_offset
+    + experts_held``. Returns ``(y [T, d] in dtype, counts int32 [3])``:
     ``y[t] = sum over the experts e that t chose AND that are held of
     w[t, e] * FFN_e(x[t])``; ``counts`` = (held experts with at least
     one token, token-choices that landed on held experts, the fullest
@@ -199,13 +267,9 @@ def dropless_moe(x: jax.Array, router_kernel: jax.Array, experts, *,
     same rows-of-a-matmul arithmetic whoever shares the batch: each
     token then sums its own choices in its own order of choice."""
     T, d = x.shape
-    k, held, bm = top_k, experts_held, block_rows
+    k, held, bm = ids.shape[1], experts_held, block_rows
     n_max = -(-T * k // bm) + held          # blocks there can be at most
     with jax.named_scope("moe.route"):
-        ids, w = route_sigmoid(x, router_kernel, n_group=n_group,
-                               topk_group=topk_group, top_k=k,
-                               norm_topk=norm_topk,
-                               route_scale=route_scale, dtype=dtype)
         local = ids - expert_offset
         here = (local >= 0) & (local < held)
         if live is not None:

@@ -3,14 +3,17 @@
 :class:`~ray_tpu.serve.engine.DecodeEngine` serves any decoder whose
 config object answers ``cfg.decode_programs()`` with a module (or
 namespace) of the paged slot-pool programs: the engine binds no model
-module by name. Three decoders answer today: the GPT-2 block
+module by name. Four decoders answer today: the GPT-2 block
 (:mod:`ray_tpu.models.gpt_decode`: a page holds keys and values per
 head), the latent-attention expert decoder
 (:mod:`ray_tpu.models.mla_moe`: a page holds one 576-wide latent a
-token in a row of 640 lanes, no head axis) and the hybrid
+token in a row of 640 lanes, no head axis), the hybrid
 linear-attention expert decoder (:mod:`ray_tpu.models.kda_moe`: one
 layer in four keeps grouped keys and values in pages, the others a
-fixed recurrent state and a short convolution's tail PER SLOT).
+fixed recurrent state and a short convolution's tail PER SLOT) and the
+shortcut-connected expert decoder (:mod:`ray_tpu.models.scmoe`: TWO
+latent attentions a layer, so the latent entry counts ``2 * n_layer``
+layers of the one pool; the attention is ``mla_moe``'s, imported).
 
 A description provides, under these names:
 

@@ -123,6 +123,9 @@ LATENT_SHAPES = {
     # the benchmark's reference check: 96 rows on a pool of their own
     "check-5": (96, 16, 7 * 96 * 5, 5),
     "check-16": (96, 16, 7 * 96 * 16, 16),
+    # the shortcut model's cell (``scmoe``: the same kernel, imported):
+    # 8 attentions x 12,288 pages flat
+    "scmoe-cell": (128, 16, 8 * 12288, 128),
 }
 
 
@@ -194,6 +197,50 @@ def test_the_latent_step_chooses_by_the_page_for_v5e(one_chip,
     assert ("decode_step/mla.attention/latent_attention/pallas_call"
             in text) is fused
     assert ("tpu_custom_call" in text) is fused
+
+
+def test_the_shortcut_models_step_holds_the_imported_kernel_for_v5e(
+        one_chip, compiled_mode):
+    """``scmoe``'s decode step as a TPU process builds it: the latent
+    attention is ``mla_moe``'s kernel, once an ATTENTION (two a layer),
+    under the path the benchmark's readers look for, over ONE pool that
+    counts both attentions of every layer."""
+    import dataclasses
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import scmoe
+
+    cfg = dataclasses.replace(
+        scmoe.CONFIGS["nano"], n_layer=2, d_model=256, n_head=64,
+        q_rank=128, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        experts_held=8)
+    B, ps, n_pages = 8, 16, 64
+
+    def arg(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda k: scmoe.init_params(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(
+        lambda: scmoe.init_paged_cache(cfg, B, n_pages, ps))
+    assert cache["latent"].shape == (4, n_pages, ps, 640)
+    args = (params, cache, jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((B, n_pages // ps), jnp.int32))
+    assert scmoe.decode_attention_fused(cfg, ps)
+    lowered = jax.jit(functools.partial(
+        scmoe._slot_decode_step_paged, cfg=cfg, page_size=ps),
+        donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
+    assert chip.compiled_by_mosaic(lowered.as_text())
+    text = lowered.compile().as_text()
+    assert "decode_step/mla.attention/latent_attention/pallas_call" in text
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 4
+    for scope in ("scmoe.dense", "moe.route", "moe.experts", "moe.zero"):
+        assert f"decode_step/{scope}/" in text, scope
 
 
 # ------------------------------- the gated delta-rule recurrence (S5f)

@@ -118,6 +118,110 @@ def test_rows_that_are_no_tokens_are_routed_nowhere():
     assert float(jnp.abs(y[7:]).max()) == 0.0
 
 
+def _dropless_moe_before(x, router_kernel, experts, *, experts_held,
+                         expert_offset, n_group, topk_group, top_k,
+                         norm_topk, route_scale, dtype, block_rows=32,
+                         live=None):
+    """``moe.dropless_moe`` as it stood before ISSUE 47 split the
+    routing from the dispatch, line for line (commit e105427): the
+    oracle of the test below and nothing else."""
+    from jax import lax
+
+    T, d = x.shape
+    k, held, bm = top_k, experts_held, block_rows
+    n_max = -(-T * k // bm) + held
+    ids, w = moe.route_sigmoid(x, router_kernel, n_group=n_group,
+                               topk_group=topk_group, top_k=k,
+                               norm_topk=norm_topk,
+                               route_scale=route_scale, dtype=dtype)
+    local = ids - expert_offset
+    here = (local >= 0) & (local < held)
+    if live is not None:
+        here = here & live[:, None]
+    key = jnp.where(here, local, held).reshape(-1)
+    sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0,
+                    dtype=jnp.int32)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    rank = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32))
+    start = jnp.cumsum(sizes) - sizes
+    blocks = -(-sizes // bm)
+    first = jnp.cumsum(blocks) - blocks
+    n_blocks = jnp.sum(blocks)
+    blk = jnp.arange(n_max, dtype=jnp.int32)
+    owner = jnp.clip(jnp.searchsorted(jnp.cumsum(blocks), blk,
+                                      side="right"), 0, held - 1
+                     ).astype(jnp.int32)
+    place = start[owner][:, None] + (blk - first[owner])[:, None] * bm \
+        + jnp.arange(bm, dtype=jnp.int32)[None]
+    filled = (place < (start + sizes)[owner][:, None]) \
+        & (blk < n_blocks)[:, None]
+    tok = order[jnp.clip(place, 0, T * k - 1)] // k
+    xs = jnp.where(filled[..., None], x.astype(dtype)[tok], 0)
+
+    def mm(a, m, e):
+        return lax.dot_general(a, m[e].astype(dtype),
+                               (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+    def one_block(j, out):
+        e, xb = owner[j], xs[j]
+        h = (jax.nn.silu(mm(xb, experts["gate"], e))
+             * mm(xb, experts["up"], e)).astype(dtype)
+        return out.at[j].set(mm(h, experts["down"], e).astype(dtype))
+
+    out = lax.fori_loop(0, n_blocks, one_block,
+                        jnp.zeros((n_max, bm, d), dtype))
+    loc = jnp.clip(local, 0, held - 1)
+    r = rank.reshape(T, k) - start[loc]
+    at = jnp.where(here, (first[loc] + r // bm) * bm + r % bm, 0)
+    part = out.reshape(n_max * bm, d)[at].astype(jnp.float32)
+    y = jnp.sum(jnp.where(here, w, 0.0)[..., None] * part, axis=1)
+    counts = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32),
+                        jnp.sum(sizes), jnp.max(sizes)])
+    return y.astype(dtype), counts
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("routing", [
+    # A.X-K1: 8 groups of which 4, top 8, normalised, times 2.5; the
+    # share is experts 12-23 of 48
+    dict(n_group=8, topk_group=4, top_k=8, norm_topk=True,
+         route_scale=2.5, experts_held=12, expert_offset=12, E=48),
+    # Solar-Open2: one group (a plain top 8), normalised, times 1;
+    # the share is experts 0-9 of 40
+    dict(n_group=1, topk_group=1, top_k=8, norm_topk=True,
+         route_scale=1.0, experts_held=10, expert_offset=0, E=40)],
+    ids=["axk1", "solar_open2"])
+def test_the_sigmoid_routed_layer_is_unchanged_to_the_bit(routing, dtype):
+    """ISSUE 47 made the dispatch take its routing from the caller
+    (``dropless_experts``) so that a softmax-with-bias router whose ids
+    reach past the routed experts shares it; ``dropless_moe`` is now
+    ``route_sigmoid`` and that dispatch, and gives A.X-K1's and
+    Solar-Open2's routings the bits it gave: result and counters, with
+    idle rows and without."""
+    kw = dict(routing)
+    E = kw.pop("E")
+    rng = np.random.default_rng(7)
+    T, d, f, held = 37, 32, 24, kw["experts_held"]
+    x = jnp.asarray(rng.normal(size=(T, d)), dtype)
+    router = jnp.asarray(rng.normal(size=(d, E)) * 0.3, dtype)
+    experts = {n: jnp.asarray(rng.normal(size=s) * 0.2, dtype)
+               for n, s in (("gate", (held, d, f)), ("up", (held, d, f)),
+                            ("down", (held, f, d)))}
+    for live in (None, jnp.arange(T) % 5 != 0):
+        want = jax.jit(lambda x: _dropless_moe_before(
+            x, router, experts, dtype=dtype, block_rows=8, live=live,
+            **kw))(x)
+        got = jax.jit(lambda x: moe.dropless_moe(
+            x, router, experts, dtype=dtype, block_rows=8, live=live,
+            **kw))(x)
+        assert int(want[1][1]) > 0
+        for a, b in zip(got, want):
+            assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_the_experts_load_comes_out_with_the_tokens(model, engine):
     cfg, _ = model
     before = engine.stats()
