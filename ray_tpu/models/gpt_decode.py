@@ -101,7 +101,8 @@ Shared-prefix reuse rides the same machinery: a prompt whose prefix is
 already resident (the engine's prefix cache) maps the cached pages into
 its page table and prefills only the **suffix** — ``hist_len`` is a
 traced scalar, the suffix attends over history K/V read through the
-page table, and the one copy-on-write fork a lane may need (when the
+page table a block at a time (as many blocks as the hit is long, never
+``max_len``: :func:`_attend_history`), and the one copy-on-write fork a lane may need (when the
 cached prefix ends mid-page) is fused into the same prefill program as
 a masked page copy, so prefix hits add ZERO compiled programs.
 
@@ -1179,6 +1180,87 @@ def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
     return out[:, None]
 
 
+#: Tokens of cached prefix a paged prefill reads at once
+#: (:func:`_attend_history`; ``256 // page_size`` pages, one page where a
+#: page is larger). A prefill pays for whole blocks, so a hit costs at
+#: most 255 masked keys more than it is long; larger blocks bought
+#: nothing on a v5e: three latent attentions at A.X-K1's widths, bucket
+#: 512, hits of 192 / 512 / 1,024 / 1,536 tokens took 11.1 / 11.9 / 13.3
+#: / 14.8 ms at 256 and 12.2 / 12.2 / 13.7 / 15.3 at 512 (10.4 without
+#: a hit; the ``max_len``-wide view 16.6 whatever the hit), six layers
+#: of the GPT block 6.7-7.3 at either (PERF.md, PR 48).
+_HIST_BLOCK_TOKENS = 256
+
+
+def _hist_blocks(pt_row: jax.Array, n_pages: int, page_size: int):
+    """How a prefill reads its cached prefix: ``(T, pages)``, the tokens
+    a block holds (:data:`_HIST_BLOCK_TOKENS` in whole pages, at most
+    the row) and ``pages(j, layer)``, block ``j``'s pages of one layer
+    in the stacked pool's flat view. The page table's row is clipped
+    into the pool (sentinels name page ``n_pages - 1``: their positions
+    are past any ``hist_len`` and masked) and padded to whole blocks."""
+    bp = max(1, min(_HIST_BLOCK_TOKENS // page_size, pt_row.shape[0]))
+    ptc = jnp.pad(jnp.clip(pt_row, 0, n_pages - 1),
+                  (0, -pt_row.shape[0] % bp))
+
+    def pages(j, layer):
+        return lax.dynamic_slice(ptc, (j * bp,), (bp,)) + layer * n_pages
+
+    return bp * page_size, pages
+
+
+def _attend_history(lg_s, v_s, hist_len, block_tokens: int, block):
+    """A prefill's attention over its own rows AND the ``hist_len``
+    cached tokens before them, in ONE softmax: ``lg_s`` ``[B, H, S, S]``
+    float32 are the rows' scaled scores against themselves, masked
+    causally, ``v_s`` ``[B, S, H, v]`` their values. The prefix is read
+    in blocks of ``block_tokens``: ``block(j) -> (scores [B, H, S, T]
+    float32, scaled; values [B, T, H, v])`` of tokens ``j * T ..``,
+    each block's scores masked at ``hist_len``, under loops of
+    ``ceil(hist_len / T)`` trips. Two passes, so that every probability
+    is the one softmax's own, divided by the whole sum BEFORE it is
+    rounded to the values' dtype (a running weighted sum rounds first,
+    the decode kernels' difference; it parted a hit's greedy tokens
+    from the whole prefill's): the first folds the blocks' scores into
+    the running (max, sum) that start at the rows' own max, the second
+    adds each block's ``probs . V`` to the rows' own in float32. A hit
+    differs from the view that gathered ``max_len`` keys by the order of
+    float32 sums. NO trip without a hit: nothing is read, and the result
+    is ``softmax(lg_s) . v_s`` to the bit. Returns ``[B, S, H, v]``
+    float32."""
+    T = block_tokens
+    n = (hist_len + T - 1) // T
+
+    def scores(j):
+        s, v = block(j)
+        return jnp.where(j * T + jnp.arange(T) < hist_len, s, -1e30), v
+
+    def stats(j, carry):
+        m, l = carry
+        with jax.named_scope("prefill.history"):
+            s, _ = scores(j)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            l = jnp.exp(m - m_new) * l + jnp.sum(
+                jnp.exp(s - m_new), axis=-1, keepdims=True)
+        return m_new, l
+
+    m_s = jnp.max(lg_s, axis=-1, keepdims=True)
+    m, l_h = lax.fori_loop(0, n, stats, (m_s, jnp.zeros_like(m_s)))
+    e_s = jnp.exp(lg_s - m)
+    l = jnp.sum(e_s, axis=-1, keepdims=True) + l_h
+
+    def weigh(j, acc):
+        with jax.named_scope("prefill.history"):
+            s, v = scores(j)
+            return acc + jnp.einsum(
+                "bhqk,bkhd->bqhd", (jnp.exp(s - m) / l).astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+
+    return lax.fori_loop(0, n, weigh, jnp.einsum(
+        "bhqk,bkhd->bqhd", (e_s / l).astype(v_s.dtype), v_s,
+        preferred_element_type=jnp.float32))
+
+
 def prefill_into_slot_paged(params: Params, cache: Cache,
                             tokens: jax.Array, length: jax.Array,
                             hist_len: jax.Array, pt_row: jax.Array,
@@ -1210,19 +1292,20 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     quantizer.
 
     Suffix tokens sit at absolute positions ``hist_len + i`` and attend
-    over (a) the history read through the page table, valid where the
-    virtual position ``< hist_len``, and (b) themselves, causally. With
-    ``hist_len == 0`` the history lanes are fully masked and the math
-    reduces bitwise to :func:`prefill`'s (masked keys contribute
-    exact zeros). Returns ``(first_token, cache', rng')``; pad-position
-    writes are dropped, not written."""
+    over (a) themselves, causally, and (b) the ``hist_len`` cached
+    tokens before them, read through the page table a block of
+    :data:`_HIST_BLOCK_TOKENS` at once inside the layer's body
+    (:func:`_attend_history`, scope ``prefill.history``): what a prefill
+    reads, dequantizes and multiplies of its history follows the hit,
+    not ``max_len``. With ``hist_len == 0`` that loop makes no trip and
+    the math is bitwise :func:`prefill`'s. Returns ``(first_token,
+    cache', rng')``; pad-position writes are dropped, not written."""
     B, S = tokens.shape
     L = cfg.n_layer
     H, hd = cfg.n_head, cfg.head_dim
     n_pages = cache["k"].shape[1]
     ps = page_size
     max_pages = pt_row.shape[0]
-    V = max_pages * ps
     scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
     positions = hist_len + jnp.arange(S)
     x = params["embed"]["kernel"].astype(cfg.dtype)[tokens]
@@ -1248,42 +1331,40 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
         vscale = cache["vs"].at[:, dst_w].set(cache["vs"][:, src_c],
                                               mode="drop")
 
-    # History view through the page table: [L, V, H, hd] in virtual
-    # order. Sentinel entries clip to page n_pages-1; their positions
-    # are >= hist_len and masked below.
-    ptc = jnp.clip(pt_row, 0, n_pages - 1)
-    if quant:
-        hk = _deq_page(kpool[:, ptc], kscale[:, ptc],
-                       cfg.dtype).reshape(L, V, -1, hd)
-        hv = _deq_page(vpool[:, ptc], vscale[:, ptc],
-                       cfg.dtype).reshape(L, V, -1, hd)
-    else:
-        hk = kpool[:, ptc].reshape(L, V, -1, hd)
-        hv = vpool[:, ptc].reshape(L, V, -1, hd)
-    hist_valid = (jnp.arange(V) < hist_len)[None, None, None, :]
+    # The cached prefix as the layers read it: a block of pages at
+    # (layer, pages) of the stacked pool's flat view, after the fork.
+    T, hist_pages = _hist_blocks(pt_row, n_pages, ps)
+    hist = _flat_pool({"k": kpool, "v": vpool,
+                       **({"ks": kscale, "vs": vscale} if quant else {})})
     self_mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
 
     def body(carry, layer):
         x = carry
-        p, hk_l, hv_l = layer
+        p, l = layer
         q, k, v = _block_kv(x, p, cfg)          # [1, S, H, hd]
-        lg_h = jnp.einsum("bqhd,khd->bhqk", q, hk_l,
-                          preferred_element_type=jnp.float32) * scale
-        lg_h = jnp.where(hist_valid, lg_h, -1e30)
+
+        def block(j):
+            pages = hist_pages(j, l)
+            if quant:
+                hk = _deq_page(hist["k"][pages], hist["ks"][pages], q.dtype)
+                hv = _deq_page(hist["v"][pages], hist["vs"][pages], q.dtype)
+            else:
+                hk, hv = hist["k"][pages], hist["v"][pages]
+            lg_h = jnp.einsum("bqhd,khd->bhqk", q, hk.reshape(T, -1, hd),
+                              preferred_element_type=jnp.float32) * scale
+            return lg_h, hv.reshape(1, T, -1, hd)
+
         lg_s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                           preferred_element_type=jnp.float32) * scale
-        lg_s = jnp.where(self_mask, lg_s, -1e30)
-        logits = jnp.concatenate([lg_h, lg_s], axis=-1)  # [1,H,S,V+S]
-        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-        vv = jnp.concatenate([hv_l[None].astype(q.dtype), v], axis=1)
-        att = jnp.einsum("bhqk,bkhd->bqhd", probs, vv,
-                         preferred_element_type=jnp.float32
-                         ).astype(q.dtype).reshape(B, S, -1)
+        att = _attend_history(jnp.where(self_mask, lg_s, -1e30), v,
+                              hist_len, T, block
+                              ).astype(q.dtype).reshape(B, S, -1)
         x = x + _mm_row(att, p["wo"]["kernel"], cfg.dtype, tp_axis)
         x = _ffn(x, p, cfg, tp_axis)
         return x, (k[0], v[0])
 
-    x, (k_new, v_new) = lax.scan(body, x, (params["block"], hk, hv))
+    x, (k_new, v_new) = lax.scan(body, x,
+                                 (params["block"], jnp.arange(L)))
     x = _rmsnorm(x, params["ln_f_scale"])
     x_last = lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, cfg.d_model))
     logits = _project_vocab(x_last, params["embed"]["kernel"], cfg)

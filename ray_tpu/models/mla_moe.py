@@ -81,7 +81,8 @@ from jax import lax
 
 from . import moe
 from .gpt import _mm
-from .gpt_decode import (_knob_cache, _program, _sample, _sample_slots)
+from .gpt_decode import (_attend_history, _hist_blocks, _knob_cache,
+                         _program, _sample, _sample_slots)
 from .serving import PT_SENTINEL, CacheEntry, CacheSpec, init_paged_pool
 
 Params = Dict[str, Any]
@@ -393,12 +394,10 @@ def forward(params: Params, tokens: jax.Array, cfg: MLAMoEConfig
     return _head(x, params, cfg)
 
 
-def _attend_materialised(qn, qr, latents, mask, p, cfg: MLAMoEConfig):
-    """Per-head attention of queries [B, S, H, .] over ``latents``
-    [B, K, kv_rank + rope] whose keys and values are materialised:
-    ``mask`` [.., S, K] says which key a query may see. Returns
-    [B, S, H * v]."""
-    B, S = qn.shape[:2]
+def _materialised(qn, qr, latents, p, cfg: MLAMoEConfig):
+    """Queries [B, S, H, .] against ``latents`` [B, K, kv_rank + rope]
+    whose keys and values are materialised per head: ``(scores [B, H,
+    S, K] float32, scaled; values [B, K, H, v])``."""
     w_uk, w_uv = _wkvb(p, cfg)
     c = latents[..., :cfg.kv_rank]
     kr = latents[..., cfg.kv_rank:cfg.latent_dim]
@@ -410,8 +409,18 @@ def _attend_materialised(qn, qr, latents, mask, p, cfg: MLAMoEConfig):
                     preferred_element_type=jnp.float32) \
         + jnp.einsum("bqhr,bkr->bhqk", qr, kr,
                      preferred_element_type=jnp.float32)
-    lg = jnp.where(mask, lg * cfg.attn_scale, -1e30)
-    probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
+    return lg * cfg.attn_scale, v
+
+
+def _attend_materialised(qn, qr, latents, mask, p, cfg: MLAMoEConfig):
+    """Per-head attention of queries [B, S, H, .] over ``latents``
+    [B, K, kv_rank + rope] whose keys and values are materialised:
+    ``mask`` [.., S, K] says which key a query may see. Returns
+    [B, S, H * v]."""
+    B, S = qn.shape[:2]
+    lg, v = _materialised(qn, qr, latents, p, cfg)
+    probs = jax.nn.softmax(jnp.where(mask, lg, -1e30),
+                           axis=-1).astype(cfg.dtype)
     return jnp.einsum("bhqk,bkhv->bqhv", probs, v,
                       preferred_element_type=jnp.float32
                       ).astype(cfg.dtype).reshape(B, S, -1)
@@ -487,14 +496,18 @@ def _prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
     which of the ``S`` rows are tokens, and ``attend(x, p, a, pool) ->
     (x + Attn_a(x), pool')`` for attention ``a`` with tree ``p`` (scope
     ``mla.prefill``): suffix token ``i`` sits at position ``hist_len +
-    i`` and attends over the cached prefix (latents read through
-    ``pt_row``, valid below ``hist_len``) and the suffix, causally,
-    keys and values materialised from the latents per head; the rows'
-    own latents go into their pages, pad positions' writes dropped."""
+    i`` and attends over the suffix, causally, and over the ``hist_len``
+    cached tokens before it, whose latents are read through ``pt_row``
+    a block of :data:`ray_tpu.models.gpt_decode._HIST_BLOCK_TOKENS` at
+    once (:func:`ray_tpu.models.gpt_decode._attend_history`, scope
+    ``prefill.history`` inside ``mla.prefill``): keys and values are
+    materialised from the latents per head for the suffix and for the
+    blocks a hit is long, none without a hit, never for ``max_len``;
+    the rows' own latents go into their pages, pad positions' writes
+    dropped."""
     ps = page_size
     A, n_pages = cache["latent"].shape[:2]
     max_pages = pt_row.shape[0]
-    V = max_pages * ps
     positions = hist_len + jnp.arange(S)
 
     # COW fork first, every attention's page at once, in the pool's
@@ -510,10 +523,8 @@ def _prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
     pool = pool.at[dst_w].set(
         pool[jnp.clip(cow_src, 0, n_pages - 1) + layers], mode="drop")
 
-    ptc = jnp.clip(pt_row, 0, n_pages - 1)
-    seen = jnp.concatenate([
-        jnp.broadcast_to(jnp.arange(V) < hist_len, (S, V)),
-        jnp.tril(jnp.ones((S, S), jnp.bool_))], axis=1)[None, None]
+    T, hist_pages = _hist_blocks(pt_row, n_pages, ps)
+    causal = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
     live = jnp.arange(S) < length
     vp = positions // ps
     page_w = jnp.where(live & (vp < max_pages),
@@ -522,11 +533,16 @@ def _prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
 
     def attend(x, p, a: int, pool):
         qn, qr, ent = _latent_qkv(x, p, positions[None], cfg)
+
+        def block(j):
+            return _materialised(
+                qn, qr, pool[hist_pages(j, a)].reshape(1, T, -1), p, cfg)
+
         with jax.named_scope("mla.prefill"):
-            hist = pool[ptc + a * n_pages].reshape(1, V, -1)
-            att = _attend_materialised(
-                qn, qr, jnp.concatenate([hist, ent], axis=1), seen, p,
-                cfg)
+            lg, v = _materialised(qn, qr, ent, p, cfg)
+            att = _attend_history(jnp.where(causal, lg, -1e30), v,
+                                  hist_len, T, block
+                                  ).astype(cfg.dtype).reshape(1, S, -1)
         x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
         return x, pool.at[_at_layer(page_w, a, n_pages),
                           positions % ps].set(ent[0], mode="drop")

@@ -18,9 +18,11 @@ beside it (pre-norm, RMSNorm, no biases, untied head)::
 
 **Attention** is :mod:`ray_tpu.models.mla_moe`'s latent attention,
 IMPORTED: the projections and the rotary (``_latent_qkv``), prefill
-materialised (``_prefill_attention``, scope ``mla.prefill``), decode
-absorbed over the lane's live latent pages (``_decode_attention``,
-scope ``mla.attention``: the Pallas kernel ``_latent_attention_pallas``
+materialised (``_prefill_attention``, scope ``mla.prefill``: the
+suffix's own rows, and the blocks of cached prefix a hit is long under
+``prefill.history``, never ``max_len`` of them), decode absorbed over
+the lane's live latent pages (``_decode_attention``, scope
+``mla.attention``: the Pallas kernel ``_latent_attention_pallas``
 wherever Mosaic can address a page, else XLA over the gathered pages).
 What this model adds to it is two constants that the config carries
 and ``_latent_qkv`` reads: ``q_gain = sqrt(d_model / q_rank)`` on the
