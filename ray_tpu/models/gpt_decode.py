@@ -102,7 +102,8 @@ already resident (the engine's prefix cache) maps the cached pages into
 its page table and prefills only the **suffix** — ``hist_len`` is a
 traced scalar, the suffix attends over history K/V read through the
 page table a block at a time (as many blocks as the hit is long, never
-``max_len``: :func:`_attend_history`), and the one copy-on-write fork a lane may need (when the
+``max_len``: :func:`ray_tpu.models.serving.attend_history`), and the
+one copy-on-write fork a lane may need (when the
 cached prefix ends mid-page) is fused into the same prefill program as
 a masked page copy, so prefix hits add ZERO compiled programs.
 
@@ -200,8 +201,8 @@ program each existing factory builds.
 from __future__ import annotations
 
 import functools
-import inspect
-from typing import Dict, Iterator, Optional, Tuple
+import sys
+from typing import Dict, Iterator, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -210,9 +211,11 @@ from jax import lax
 
 from .._private.jax_compat import decode_mesh, shard_map
 from .gpt import (GPTConfig, Params, _mm, _project_vocab, _rmsnorm)
-from .serving import PT_SENTINEL, CacheEntry, CacheSpec, init_paged_pool
+from . import serving
+from .serving import PT_SENTINEL, CacheEntry, CacheSpec
 
 Cache = Dict[str, jax.Array]
+_THIS = sys.modules[__name__]
 
 # ------------------------------------------------------- tensor parallel
 #: Block kernels sharded on their OUTPUT dim (column-parallel): each
@@ -313,6 +316,30 @@ def shard_params(params: Params, cfg: GPTConfig, tp: int) -> Params:
     return jax.tree_util.tree_map(
         lambda x, s: jax.device_put(
             x, jax.sharding.NamedSharding(mesh, s)), params, specs)
+
+
+def shard_program(inner, mesh, n_out: int, cache_out: int = 1,
+                  check_vma: bool = True):
+    """``inner(params, cache, *rest)`` under ``shard_map`` on ``mesh``:
+    the weights by :func:`_tp_param_specs`, the pool by
+    :func:`_tp_cache_specs` (in, and out at ``cache_out`` of its
+    ``n_out`` values), everything else replicated. What a mesh adds to
+    a program of the frame (``models/serving.py``) and of this
+    module."""
+    P = jax.sharding.PartitionSpec
+
+    def fn(params, cache, *rest):
+        cspec = _tp_cache_specs(cache)
+        outs = [P()] * n_out
+        outs[cache_out] = cspec
+        return shard_map(
+            inner, mesh=mesh,
+            in_specs=(_tp_param_specs(params), cspec)
+            + (P(),) * len(rest),
+            out_specs=tuple(outs), check_vma=check_vma)(
+                params, cache, *rest)
+
+    return fn
 
 
 def init_cache(cfg: GPTConfig, batch: int, max_len: int) -> Cache:
@@ -466,17 +493,6 @@ def generate(params: Params, prompt: jax.Array, cfg: GPTConfig,
             logits, cache = step(params, cache, token, cfg)
 
 
-def _sample(logits, temperature: float, key):
-    """One sampling decision; greedy iff temperature == 0 (static)."""
-    if temperature > 0.0:
-        key, sub = jax.random.split(key)
-        token = jax.random.categorical(
-            sub, logits / temperature, axis=-1).astype(jnp.int32)
-    else:
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return token, key
-
-
 def decode_chunk(params: Params, cache: Cache, token: jax.Array,
                  rng: jax.Array = None, *, cfg: GPTConfig, k: int,
                  temperature: float = 0.0, eos_token: int = -1):
@@ -502,7 +518,7 @@ def decode_chunk(params: Params, cache: Cache, token: jax.Array,
     def body(carry, _):
         cache, tok, done, key = carry
         logits, cache = decode_step(params, cache, tok, cfg)
-        nxt, key = _sample(logits, temperature, key)
+        nxt, key = serving.sample(logits, temperature, key)
         if eos_token >= 0:
             nxt = jnp.where(done, eos, nxt)
             done = done | (nxt == eos)
@@ -511,42 +527,6 @@ def decode_chunk(params: Params, cache: Cache, token: jax.Array,
     (cache, _, done, rng), toks = lax.scan(
         body, (cache, token, done0, rng), None, length=k)
     return jnp.moveaxis(toks, 0, 1), cache, done, rng
-
-
-def _knob_cache(fn):
-    """``lru_cache`` with DEFAULT-NORMALIZED keys: ``f(cfg)``,
-    ``f(cfg, tp=1)`` and ``f(cfg, ..., 1)`` all land on the SAME cache
-    entry. The engine threads every static knob positionally (including
-    default-valued ones like ``tp=1``), while tests and external
-    callers omit trailing defaults — a raw ``lru_cache`` would key
-    those spellings separately, silently doubling the compiled-program
-    set and breaking the recompile guards' wrapper ``is``-identity."""
-    sig = inspect.signature(fn)
-    cached = functools.lru_cache(maxsize=64)(fn)
-
-    @functools.wraps(fn)
-    def wrapper(*args, **kwargs):
-        bound = sig.bind(*args, **kwargs)
-        bound.apply_defaults()
-        return cached(*bound.args)
-
-    wrapper.cache_info = cached.cache_info
-    wrapper.cache_clear = cached.cache_clear
-    return wrapper
-
-
-def _program(fn, name: Optional[str] = None, **knobs):
-    """``fn`` with its static knobs bound, under a ``__name__`` of its
-    own: ``jax.jit`` calls the XLA module ``jit_<name>``, and that is
-    what a profile shows for every launch (``jit__unknown`` for a
-    ``functools.partial``, ``jit_fn`` for a local closure). ``name``
-    defaults to ``fn``'s; every ``jit_<x>`` factory below compiles
-    programs named ``jit_<x>``, whatever its mesh."""
-    def program(*args):
-        return fn(*args, **knobs)
-
-    program.__name__ = program.__qualname__ = name or fn.__name__
-    return program
 
 
 # rtlint: program-budget: 1
@@ -559,7 +539,7 @@ def jit_decode_chunk(cfg: GPTConfig, k: int, temperature: float = 0.0,
     Cached on the (hashable) static knobs — repeated calls return the
     SAME jit wrapper, so per-request drivers reuse the compiled program
     instead of retracing (jax keys its cache on wrapper identity)."""
-    return jax.jit(_program(
+    return jax.jit(serving.program(
         decode_chunk, cfg=cfg, k=k, temperature=temperature,
         eos_token=eos_token))
 
@@ -632,7 +612,7 @@ def generate_chunked(params: Params, prompt: jax.Array, cfg: GPTConfig,
         rng = jax.random.PRNGKey(0)
     cache = init_cache(cfg, B, max_len)
     logits, cache = _jitted_prefill()(params, prompt, cfg, cache)
-    token, rng = _sample(logits, temperature,
+    token, rng = serving.sample(logits, temperature,
                          rng if rng is not None else jax.random.PRNGKey(0))
     first = np.asarray(token)[:, None]
     yield first
@@ -646,7 +626,7 @@ def generate_chunked(params: Params, prompt: jax.Array, cfg: GPTConfig,
 
 
 # --------------------------------------------------------------- slot pool
-def _shard_cache(cache: Cache, mesh) -> Cache:
+def shard_cache(cache: Cache, mesh) -> Cache:
     """Device-put a freshly-zeroed pool into its tp layout so the first
     donated dispatch doesn't pay a resharding copy (and donation sees
     matching input/output shardings)."""
@@ -654,21 +634,6 @@ def _shard_cache(cache: Cache, mesh) -> Cache:
     return {name: jax.device_put(
         v, jax.sharding.NamedSharding(mesh, specs[name]))
         for name, v in cache.items()}
-
-
-def _sample_slots(logits, temperature: float, keys):
-    """Per-slot sampling with independent PRNG lanes: each slot's key
-    chain splits exactly like :func:`_sample`'s, so a slot's stream is
-    reproducible from its seed regardless of which other slots share the
-    pool or when it was admitted."""
-    if temperature > 0.0:
-        split = jax.vmap(jax.random.split)(keys)   # [B, 2, 2]
-        keys, subs = split[:, 0], split[:, 1]
-        token = jax.vmap(lambda s, lg: jax.random.categorical(
-            s, lg / temperature, axis=-1))(subs, logits).astype(jnp.int32)
-    else:
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return token, keys
 
 
 # -------------------------------------------------------------- paged pool
@@ -709,9 +674,7 @@ def cache_spec(cfg: GPTConfig, kv_dtype: str = "fp") -> CacheSpec:
     codes with one float32 scale per (page, head) per side
     (``"int8"``). The pool's shapes, its page cost, the engine's
     handoff checks and ``kv_bytes_per_token`` all come from here."""
-    if kv_dtype not in KV_DTYPES:
-        raise ValueError(
-            f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}")
+    serving.check_kv_dtype(_THIS, kv_dtype)
     row = (cfg.n_head, cfg.head_dim)
     if kv_dtype == "int8":
         entries = (CacheEntry("k", "token", row, jnp.int8),
@@ -724,14 +687,6 @@ def cache_spec(cfg: GPTConfig, kv_dtype: str = "fp") -> CacheSpec:
     return CacheSpec(cfg.n_layer, entries)
 
 
-def kv_bytes_per_page(cfg: GPTConfig, page_size: int,
-                      kv_dtype: str = "fp") -> int:
-    """HBM bytes ONE physical page costs across all layers, K and V
-    sides together — the unit the engine's page budget is denominated
-    in (:meth:`CacheSpec.bytes_per_page` of :func:`cache_spec`)."""
-    return cache_spec(cfg, kv_dtype).bytes_per_page(page_size)
-
-
 def max_positions(cfg: GPTConfig) -> int:
     """The longest sequence the model can place: the rows of its
     learned position table."""
@@ -742,6 +697,15 @@ def max_positions(cfg: GPTConfig) -> int:
 UNSUPPORTED: Dict[str, str] = {}
 #: The chunk program returns tokens, cache, done and keys, no counters.
 STEP_COUNTERS: Tuple[str, ...] = ()
+
+
+def decode_attention_fused(cfg: GPTConfig, page_size: int,
+                           attn_kernel: str = "gather") -> bool:
+    """Whether the chunk program built with these knobs holds the
+    Pallas kernel (the description's entry,
+    :mod:`ray_tpu.models.serving`): this model chooses by the knob's
+    NAME, not by shape (:data:`ATTN_KERNELS`)."""
+    return attn_kernel == "pallas"
 
 
 def _deq_page(codes: jax.Array, scales: jax.Array, dtype) -> jax.Array:
@@ -857,23 +821,18 @@ def _merge_span_int8(codes: jax.Array, scales: jax.Array,
     return codes, scales
 
 
-def init_paged_cache(cfg: GPTConfig, slots: int, n_pages: int,
-                     page_size: int, kv_dtype: str = "fp",
-                     tp: int = 1) -> Cache:
-    """Paged KV pool for the continuous-batching engine: physical
-    storage is page-granular (``[L, n_pages, page_size, H, hd]``), a
-    slot's sequence lives wherever its page table points. ``pos`` stays
-    per-slot ``[slots]`` (virtual position). With
-    ``kv_dtype="int8"`` the page arrays hold quantized codes and the
-    pool grows ``"ks"``/``"vs"`` per-(layer, page, head) float32
-    scales. The layer axis leads and the page axis follows it, so the
-    decode and verify steps can carry the whole pool through their
-    layer scans as ``L * n_pages`` pages (:func:`_flat_pool`) and never
-    copy a layer's pool out of it."""
-    cache = init_paged_pool(cache_spec(cfg, kv_dtype), slots, n_pages,
-                            page_size)
-    mesh = _tp_mesh(cfg, tp)
-    return cache if mesh is None else _shard_cache(cache, mesh)
+#: Paged KV pool for the continuous-batching engine, the frame's from
+#: :func:`cache_spec` and placed by :func:`shard_cache` under a mesh:
+#: physical storage is page-granular (``[L, n_pages, page_size, H,
+#: hd]``), a slot's sequence lives wherever its page table points, and
+#: ``pos`` stays per-slot ``[slots]`` (virtual position). The layer axis
+#: leads and the page axis follows it, so the decode and verify steps
+#: can carry the whole pool through their layer scans as ``L * n_pages``
+#: pages (:func:`_flat_pool`) and never copy a layer's pool out of it.
+init_paged_cache = serving.bind(serving.init_paged_cache, _THIS)
+#: HBM bytes ONE physical page costs across all layers, K and V sides
+#: together: the unit the engine's page budget is denominated in.
+kv_bytes_per_page = serving.bind(serving.kv_bytes_per_page, _THIS)
 
 
 def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
@@ -1180,87 +1139,6 @@ def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
     return out[:, None]
 
 
-#: Tokens of cached prefix a paged prefill reads at once
-#: (:func:`_attend_history`; ``256 // page_size`` pages, one page where a
-#: page is larger). A prefill pays for whole blocks, so a hit costs at
-#: most 255 masked keys more than it is long; larger blocks bought
-#: nothing on a v5e: three latent attentions at A.X-K1's widths, bucket
-#: 512, hits of 192 / 512 / 1,024 / 1,536 tokens took 11.1 / 11.9 / 13.3
-#: / 14.8 ms at 256 and 12.2 / 12.2 / 13.7 / 15.3 at 512 (10.4 without
-#: a hit; the ``max_len``-wide view 16.6 whatever the hit), six layers
-#: of the GPT block 6.7-7.3 at either (PERF.md, PR 48).
-_HIST_BLOCK_TOKENS = 256
-
-
-def _hist_blocks(pt_row: jax.Array, n_pages: int, page_size: int):
-    """How a prefill reads its cached prefix: ``(T, pages)``, the tokens
-    a block holds (:data:`_HIST_BLOCK_TOKENS` in whole pages, at most
-    the row) and ``pages(j, layer)``, block ``j``'s pages of one layer
-    in the stacked pool's flat view. The page table's row is clipped
-    into the pool (sentinels name page ``n_pages - 1``: their positions
-    are past any ``hist_len`` and masked) and padded to whole blocks."""
-    bp = max(1, min(_HIST_BLOCK_TOKENS // page_size, pt_row.shape[0]))
-    ptc = jnp.pad(jnp.clip(pt_row, 0, n_pages - 1),
-                  (0, -pt_row.shape[0] % bp))
-
-    def pages(j, layer):
-        return lax.dynamic_slice(ptc, (j * bp,), (bp,)) + layer * n_pages
-
-    return bp * page_size, pages
-
-
-def _attend_history(lg_s, v_s, hist_len, block_tokens: int, block):
-    """A prefill's attention over its own rows AND the ``hist_len``
-    cached tokens before them, in ONE softmax: ``lg_s`` ``[B, H, S, S]``
-    float32 are the rows' scaled scores against themselves, masked
-    causally, ``v_s`` ``[B, S, H, v]`` their values. The prefix is read
-    in blocks of ``block_tokens``: ``block(j) -> (scores [B, H, S, T]
-    float32, scaled; values [B, T, H, v])`` of tokens ``j * T ..``,
-    each block's scores masked at ``hist_len``, under loops of
-    ``ceil(hist_len / T)`` trips. Two passes, so that every probability
-    is the one softmax's own, divided by the whole sum BEFORE it is
-    rounded to the values' dtype (a running weighted sum rounds first,
-    the decode kernels' difference; it parted a hit's greedy tokens
-    from the whole prefill's): the first folds the blocks' scores into
-    the running (max, sum) that start at the rows' own max, the second
-    adds each block's ``probs . V`` to the rows' own in float32. A hit
-    differs from the view that gathered ``max_len`` keys by the order of
-    float32 sums. NO trip without a hit: nothing is read, and the result
-    is ``softmax(lg_s) . v_s`` to the bit. Returns ``[B, S, H, v]``
-    float32."""
-    T = block_tokens
-    n = (hist_len + T - 1) // T
-
-    def scores(j):
-        s, v = block(j)
-        return jnp.where(j * T + jnp.arange(T) < hist_len, s, -1e30), v
-
-    def stats(j, carry):
-        m, l = carry
-        with jax.named_scope("prefill.history"):
-            s, _ = scores(j)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            l = jnp.exp(m - m_new) * l + jnp.sum(
-                jnp.exp(s - m_new), axis=-1, keepdims=True)
-        return m_new, l
-
-    m_s = jnp.max(lg_s, axis=-1, keepdims=True)
-    m, l_h = lax.fori_loop(0, n, stats, (m_s, jnp.zeros_like(m_s)))
-    e_s = jnp.exp(lg_s - m)
-    l = jnp.sum(e_s, axis=-1, keepdims=True) + l_h
-
-    def weigh(j, acc):
-        with jax.named_scope("prefill.history"):
-            s, v = scores(j)
-            return acc + jnp.einsum(
-                "bhqk,bkhd->bqhd", (jnp.exp(s - m) / l).astype(v.dtype), v,
-                preferred_element_type=jnp.float32)
-
-    return lax.fori_loop(0, n, weigh, jnp.einsum(
-        "bhqk,bkhd->bqhd", (e_s / l).astype(v_s.dtype), v_s,
-        preferred_element_type=jnp.float32))
-
-
 def prefill_into_slot_paged(params: Params, cache: Cache,
                             tokens: jax.Array, length: jax.Array,
                             hist_len: jax.Array, pt_row: jax.Array,
@@ -1294,8 +1172,9 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     Suffix tokens sit at absolute positions ``hist_len + i`` and attend
     over (a) themselves, causally, and (b) the ``hist_len`` cached
     tokens before them, read through the page table a block of
-    :data:`_HIST_BLOCK_TOKENS` at once inside the layer's body
-    (:func:`_attend_history`, scope ``prefill.history``): what a prefill
+    :data:`ray_tpu.models.serving.HIST_BLOCK_TOKENS` at once inside the
+    layer's body (:func:`ray_tpu.models.serving.attend_history`, scope
+    ``prefill.history``): what a prefill
     reads, dequantizes and multiplies of its history follows the hit,
     not ``max_len``. With ``hist_len == 0`` that loop makes no trip and
     the math is bitwise :func:`prefill`'s. Returns ``(first_token,
@@ -1333,7 +1212,7 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
 
     # The cached prefix as the layers read it: a block of pages at
     # (layer, pages) of the stacked pool's flat view, after the fork.
-    T, hist_pages = _hist_blocks(pt_row, n_pages, ps)
+    T, hist_pages = serving.hist_blocks(pt_row, n_pages, ps)
     hist = _flat_pool({"k": kpool, "v": vpool,
                        **({"ks": kscale, "vs": vscale} if quant else {})})
     self_mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
@@ -1356,9 +1235,9 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
 
         lg_s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                           preferred_element_type=jnp.float32) * scale
-        att = _attend_history(jnp.where(self_mask, lg_s, -1e30), v,
-                              hist_len, T, block
-                              ).astype(q.dtype).reshape(B, S, -1)
+        att = serving.attend_history(
+            jnp.where(self_mask, lg_s, -1e30), v, hist_len, T, block
+        ).astype(q.dtype).reshape(B, S, -1)
         x = x + _mm_row(att, p["wo"]["kernel"], cfg.dtype, tp_axis)
         x = _ffn(x, p, cfg, tp_axis)
         return x, (k[0], v[0])
@@ -1368,7 +1247,7 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     x = _rmsnorm(x, params["ln_f_scale"])
     x_last = lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, cfg.d_model))
     logits = _project_vocab(x_last, params["embed"]["kernel"], cfg)
-    token, rng = _sample(logits[:, 0], temperature, rng)
+    token, rng = serving.sample(logits[:, 0], temperature, rng)
 
     # Suffix K/V writes, scattered page-wise: token i lands at virtual
     # position hist_len + i → (pt_row[vpos // ps], vpos % ps). Pad
@@ -1467,145 +1346,22 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     return logits[:, 0], cache_out
 
 
-def decode_chunk_slots_paged(params: Params, cache: Cache,
-                             token: jax.Array, rngs: jax.Array,
-                             active: jax.Array, pt: jax.Array, *,
-                             cfg: GPTConfig, k: int, page_size: int,
-                             temperature: float = 0.0,
-                             eos_token: int = -1,
-                             kv_dtype: str = "fp",
-                             attn_kernel: str = "gather", tp_axis=None):
-    """Masked twin of :func:`decode_chunk` over the slot pool: k fused
-    steps in ONE program, decoding only slots where ``active`` is set,
-    with the page table held constant through the chunk (the engine
-    maps pages covering ``pos + k`` before dispatching — a slot that
-    cannot be covered is parked out of ``active`` instead).
-
-    ``token`` ``[B_slots]`` is each slot's last emitted token, ``rngs``
-    ``[B_slots, 2]`` its PRNG lane, ``active`` ``[B_slots]`` the
-    chunk-static admission mask (admission happens at chunk boundaries,
-    so the mask never changes inside a dispatch). Returns
-    ``(tokens [B_slots, k], cache', done [B_slots], rngs')``; rows of
-    inactive slots are garbage. EOS lanes mask-and-carry exactly like
-    :func:`decode_chunk` — the ENGINE frees the slot at the chunk
-    boundary, which is what turns mask-and-carry into slot reuse.
-    ``kv_dtype``/``attn_kernel`` select the pool layout and attention
-    implementation per :func:`paged_attention` — both are STATIC knobs
-    baked into the compiled program, never retrace triggers."""
-    B = token.shape[0]
-    eos = jnp.asarray(eos_token, jnp.int32)
-    done0 = (active & (token == eos)) if eos_token >= 0 \
-        else jnp.zeros((B,), jnp.bool_)
-
-    def body(carry, _):
-        cache, tok, done, keys = carry
-        logits, cache = _slot_decode_step_paged(params, cache, tok,
-                                                active, pt, cfg,
-                                                page_size, kv_dtype,
-                                                attn_kernel, tp_axis)
-        nxt, keys = _sample_slots(logits, temperature, keys)
-        if eos_token >= 0:
-            nxt = jnp.where(done, eos, nxt)
-            done = done | (active & (nxt == eos))
-        return (cache, nxt, done, keys), nxt
-
-    (cache, _, done, rngs), toks = lax.scan(
-        body, (cache, token, done0, rngs), None, length=k)
-    return jnp.moveaxis(toks, 0, 1), cache, done, rngs
-
-
-# rtlint: program-budget: len(prompt_buckets)
-@_knob_cache
-def jit_prefill_into_slot_paged(cfg: GPTConfig, page_size: int,
-                                temperature: float = 0.0,
-                                kv_dtype: str = "fp", tp: int = 1):
-    """Jitted :func:`prefill_into_slot_paged`; one compiled program per
-    SUFFIX bucket per (cfg, page_size, temperature, kv_dtype, tp) key —
-    prefix-hit depth (``hist_len``), page-table contents, and COW
-    source are all traced, so shared-prefix admission never retraces.
-    ``kv_dtype`` is an engine-level static baked into the same program
-    set (it changes the pool layout, not the program COUNT). Cached on
-    the static knobs so every engine for the same knobs shares one
-    wrapper (and its trace cache). The pool cache is donated: the
-    engine holds the only reference and immediately rebinds the
-    returned cache, so on TPU the update is in-place instead of a
-    full-pool copy (CPU ignores donation). ``tp > 1`` runs the same
-    inner function under shard_map on :func:`decode_mesh` with weights
-    column/row-parallel and the pool head-sharded."""
-    mesh = _tp_mesh(cfg, tp)
-    if mesh is None:
-        return jax.jit(_program(prefill_into_slot_paged,
-                                         cfg=cfg, page_size=page_size,
-                                         temperature=temperature,
-                                         kv_dtype=kv_dtype),
-                       donate_argnums=(1,))
-    P = jax.sharding.PartitionSpec
-    inner = functools.partial(prefill_into_slot_paged, cfg=cfg,
-                              page_size=page_size,
-                              temperature=temperature,
-                              kv_dtype=kv_dtype, tp_axis="tp")
-
-    def fn(params, cache, tokens, length, hist_len, pt_row, cow_src,
-           slot, rng):
-        cspec = _tp_cache_specs(cache)
-        return shard_map(
-            inner, mesh=mesh,
-            in_specs=(_tp_param_specs(params), cspec,
-                      P(), P(), P(), P(), P(), P(), P()),
-            out_specs=(P(), cspec, P()))(
-                params, cache, tokens, length, hist_len, pt_row,
-                cow_src, slot, rng)
-
-    return jax.jit(_program(fn, "prefill_into_slot_paged"),
-                   donate_argnums=(1,))
-
-
-# rtlint: program-budget: 1
-@_knob_cache
-def jit_decode_chunk_slots_paged(cfg: GPTConfig, k: int, page_size: int,
-                                 temperature: float = 0.0,
-                                 eos_token: int = -1,
-                                 kv_dtype: str = "fp",
-                                 attn_kernel: str = "gather",
-                                 tp: int = 1):
-    """Jitted :func:`decode_chunk_slots_paged`: ONE program per (pool
-    shape, k, page_size, tp) — the page table is data, and the
-    ``kv_dtype``/``attn_kernel`` knobs are engine-level statics that
-    select WHICH one program is built, never additional ones. Pool
-    donated."""
-    mesh = _tp_mesh(cfg, tp)
-    if mesh is None:
-        return jax.jit(_program(decode_chunk_slots_paged,
-                                         cfg=cfg, k=k,
-                                         page_size=page_size,
-                                         temperature=temperature,
-                                         eos_token=eos_token,
-                                         kv_dtype=kv_dtype,
-                                         attn_kernel=attn_kernel),
-                       donate_argnums=(1,))
-    P = jax.sharding.PartitionSpec
-    inner = functools.partial(decode_chunk_slots_paged, cfg=cfg, k=k,
-                              page_size=page_size,
-                              temperature=temperature,
-                              eos_token=eos_token, kv_dtype=kv_dtype,
-                              attn_kernel=attn_kernel, tp_axis="tp")
-
-    def fn(params, cache, token, rngs, active, pt):
-        cspec = _tp_cache_specs(cache)
-        return shard_map(
-            inner, mesh=mesh,
-            in_specs=(_tp_param_specs(params), cspec,
-                      P(), P(), P(), P()),
-            out_specs=(P(), cspec, P(), P()),
-            # pallas_call's out_shape carries no vma annotation, which
-            # strict shard_map rejects (and the interpreter's own
-            # slicing trips the same check on the CPU): the kernel
-            # program runs unchecked, like the flash kernel's.
-            check_vma=attn_kernel != "pallas")(
-                params, cache, token, rngs, active, pt)
-
-    return jax.jit(_program(fn, "decode_chunk_slots_paged"),
-                   donate_argnums=(1,))
+#: The masked twin of :func:`decode_chunk` over the slot pool: the
+#: frame's chunk program (``models/serving.py``) around this model's
+#: two-valued step, so four outputs. ``kv_dtype``/``attn_kernel`` select
+#: the pool layout and attention implementation per
+#: :func:`paged_attention`; ``tp_axis`` reaches the step under a mesh.
+decode_chunk_slots_paged = functools.partial(
+    serving.decode_chunk_slots_paged, step=_slot_decode_step_paged,
+    counters=len(STEP_COUNTERS))
+#: The frame's two factories for this description. ``tp > 1`` runs the
+#: same inner functions under :func:`shard_program` on
+#: :func:`decode_mesh` with weights column/row-parallel and the pool
+#: head-sharded: that wrapper is all the mesh adds.
+jit_prefill_into_slot_paged = serving.bind(
+    serving.jit_prefill_into_slot_paged, _THIS)
+jit_decode_chunk_slots_paged = serving.bind(
+    serving.jit_decode_chunk_slots_paged, _THIS)
 
 
 # rtlint: program-budget: 1
@@ -1629,7 +1385,7 @@ def jit_paged_attention(cfg: GPTConfig, page_size: int,
             return paged_attention(q, kc, vc, pt, pos,
                                    page_size=page_size,
                                    kernel=attn_kernel)
-    return jax.jit(_program(fn, "paged_attention"))
+    return jax.jit(serving.program(fn, "paged_attention"))
 
 
 # ------------------------------------------------------ speculative verify
@@ -1866,7 +1622,7 @@ def import_slot_kv_paged(cache: Cache, k_pages: jax.Array,
 
 
 # rtlint: program-budget: 1
-@_knob_cache
+@serving.knob_cache
 def jit_export_slot_kv_paged(cfg: GPTConfig, page_size: int,
                              kv_dtype: str = "fp", tp: int = 1):
     """Jitted :func:`export_slot_kv_paged`: ONE program per (pool
@@ -1878,9 +1634,9 @@ def jit_export_slot_kv_paged(cfg: GPTConfig, page_size: int,
     handoff digest layout-independent."""
     mesh = _tp_mesh(cfg, tp)
     if mesh is None:
-        return jax.jit(_program(export_slot_kv_paged, cfg=cfg,
-                                         page_size=page_size,
-                                         kv_dtype=kv_dtype))
+        return jax.jit(serving.program(export_slot_kv_paged, cfg=cfg,
+                                       page_size=page_size,
+                                       kv_dtype=kv_dtype))
     P = jax.sharding.PartitionSpec
     inner = functools.partial(export_slot_kv_paged, cfg=cfg,
                               page_size=page_size, kv_dtype=kv_dtype)
@@ -1895,11 +1651,11 @@ def jit_export_slot_kv_paged(cfg: GPTConfig, page_size: int,
             in_specs=(_tp_cache_specs(cache), P()),
             out_specs=outs)(cache, pt_row)
 
-    return jax.jit(_program(fn, "export_slot_kv_paged"))
+    return jax.jit(serving.program(fn, "export_slot_kv_paged"))
 
 
 # rtlint: program-budget: 1
-@_knob_cache
+@serving.knob_cache
 def jit_import_slot_kv_paged(cfg: GPTConfig, page_size: int,
                              kv_dtype: str = "fp", tp: int = 1):
     """Jitted :func:`import_slot_kv_paged`: ONE program per (pool
@@ -1918,7 +1674,7 @@ def jit_import_slot_kv_paged(cfg: GPTConfig, page_size: int,
                 page_size=page_size, ks_pages=ks_pages,
                 vs_pages=vs_pages)
         if mesh is None:
-            return jax.jit(_program(raw, "import_slot_kv_paged"),
+            return jax.jit(serving.program(raw, "import_slot_kv_paged"),
                            donate_argnums=(0,))
         P = jax.sharding.PartitionSpec
         hspec = P(None, None, None, "tp", None)
@@ -1934,11 +1690,11 @@ def jit_import_slot_kv_paged(cfg: GPTConfig, page_size: int,
                 out_specs=cspec)(cache, k_pages, v_pages, ks_pages,
                                  vs_pages, pt_row, slot, length)
 
-        return jax.jit(_program(fn, "import_slot_kv_paged"),
+        return jax.jit(serving.program(fn, "import_slot_kv_paged"),
                        donate_argnums=(0,))
     if mesh is None:
-        return jax.jit(_program(import_slot_kv_paged, cfg=cfg,
-                                         page_size=page_size),
+        return jax.jit(serving.program(import_slot_kv_paged, cfg=cfg,
+                                       page_size=page_size),
                        donate_argnums=(0,))
     P = jax.sharding.PartitionSpec
     inner = functools.partial(import_slot_kv_paged, cfg=cfg,
@@ -1953,39 +1709,19 @@ def jit_import_slot_kv_paged(cfg: GPTConfig, page_size: int,
             out_specs=cspec)(cache, k_pages, v_pages, pt_row, slot,
                              length)
 
-    return jax.jit(_program(fn, "import_slot_kv_paged"), donate_argnums=(0,))
+    return jax.jit(serving.program(fn, "import_slot_kv_paged"),
+                   donate_argnums=(0,))
 
 
 # rtlint: program-budget: 1
-@_knob_cache
+@serving.knob_cache
 def jit_verify_chunk_slots_paged(cfg: GPTConfig, k: int, page_size: int,
                                  temperature: float = 0.0,
                                  kv_dtype: str = "fp", tp: int = 1):
     """Jitted :func:`verify_chunk_slots_paged`: ONE program per (pool
     shape, k, page_size, kv_dtype, tp) — the page table is data. Pool
     donated."""
-    mesh = _tp_mesh(cfg, tp)
-    if mesh is None:
-        return jax.jit(_program(verify_chunk_slots_paged,
-                                         cfg=cfg, k=k,
-                                         page_size=page_size,
-                                         temperature=temperature,
-                                         kv_dtype=kv_dtype),
-                       donate_argnums=(1,))
-    P = jax.sharding.PartitionSpec
-    inner = functools.partial(verify_chunk_slots_paged, cfg=cfg, k=k,
-                              page_size=page_size,
-                              temperature=temperature,
-                              kv_dtype=kv_dtype, tp_axis="tp")
-
-    def fn(params, cache, token, draft, rngs, active, pt):
-        cspec = _tp_cache_specs(cache)
-        return shard_map(
-            inner, mesh=mesh,
-            in_specs=(_tp_param_specs(params), cspec,
-                      P(), P(), P(), P(), P()),
-            out_specs=(P(), P(), cspec, P()))(
-                params, cache, token, draft, rngs, active, pt)
-
-    return jax.jit(_program(fn, "verify_chunk_slots_paged"),
-                   donate_argnums=(1,))
+    return serving.jit_program(
+        _THIS, verify_chunk_slots_paged, "verify_chunk_slots_paged",
+        _tp_mesh(cfg, tp), 4, cache_out=2, cfg=cfg, k=k,
+        page_size=page_size, temperature=temperature, kv_dtype=kv_dtype)

@@ -74,8 +74,8 @@ sequence, and :func:`cache_spec` describes both in one
 **MoE**, every layer: :func:`ray_tpu.models.moe.dropless_moe` with one
 group (sigmoid scores over ``n_routed`` experts, plain top k,
 normalised), told the ``experts_held`` experts from ``expert_offset``
-that live here, plus a shared expert: :func:`ray_tpu.models.mla_moe._ffn`
-as it stands.
+that live here, plus a shared expert:
+:func:`ray_tpu.models.moe.block_ffn` as it stands.
 
 Layers are a list of per-layer trees, unrolled; the chunk program
 returns the expert layers' counters, the live lanes and the positions
@@ -84,7 +84,9 @@ its attention fetched, summed over its steps (:data:`STEP_COUNTERS`).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -92,10 +94,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.scipy.linalg import solve_triangular
 
-from .gpt_decode import (_knob_cache, _program, _sample, _sample_slots)
-from .mla_moe import (_at_layer, _embed, _ffn, _flat, _head, _live_length,
-                      _rmsnorm)
-from .serving import PT_SENTINEL, CacheEntry, CacheSpec, init_paged_pool
+from . import serving
+from .moe import block_ffn, embed, head, rmsnorm
+from .serving import (PT_SENTINEL, CacheEntry, CacheSpec, at_layer, flat,
+                      live_length)
+
+_THIS = sys.modules[__name__]
 
 Params = Dict[str, Any]
 Cache = Dict[str, jax.Array]
@@ -345,7 +349,7 @@ def _kda_qkv(window, p, cfg: KDAMoEConfig):
 def _kda_out(o, gate, p, cfg: KDAMoEConfig):
     """``o`` [..., H, dv] float32 -> the mixer's output [..., d]: a
     per-head RMSNorm (one learned scale, ``dv`` wide), the gate, W_o."""
-    o = _rmsnorm(o, p["o_norm_scale"], cfg.eps, jnp.float32) * gate
+    o = rmsnorm(o, p["o_norm_scale"], cfg.eps, jnp.float32) * gate
     return _dot(o.reshape(o.shape[:-2] + (-1,)), p["wo"]["kernel"],
                 cfg.dtype)
 
@@ -862,16 +866,16 @@ def forward(params: Params, tokens: jax.Array, cfg: KDAMoEConfig
     live = jnp.ones((S,), jnp.bool_)
 
     def row(toks):
-        x = _embed(params, toks)
+        x = embed(params, toks)
         for l, p in enumerate(params["layers"]):
-            h = _rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+            h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
             if l in cfg.gqa_layers:
                 q, k, v, z = _gqa_qkvz(h, p, cfg)
                 y = _gqa_out(_gqa_causal(q, k, v, cfg), z, p, cfg)
             else:
                 y = _kda_sequence(h, p, cfg, live)[0]
-            x = _ffn(x + y.astype(x.dtype), p, cfg)[0]
-        return _head(x, params, cfg)
+            x = block_ffn(x + y.astype(x.dtype), p, cfg)[0]
+        return head(x, params, cfg)
 
     return lax.map(row, tokens)
 
@@ -882,11 +886,11 @@ def cache_spec(cfg: KDAMoEConfig, kv_dtype: str = "fp") -> CacheSpec:
     ``[Hkv, hd]`` each) and what a sequence keeps in its SLOT (a KDA
     layer's state ``[H, dk, dv]`` in the state dtype and the
     convolution's last ``conv_size - 1`` input rows ``[conv - 1, 3 W]``
-    in the compute dtype), each with the count of layers that keep it."""
-    if kv_dtype not in KV_DTYPES:
-        raise ValueError(
-            f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}: "
-            + UNSUPPORTED["int8"])
+    in the compute dtype), each with the count of layers that keep it:
+    the pools ``[n_gqa, n_pages, page_size, Hkv, hd]``, ``state``
+    ``[n_kda, slots, H, dk, dv]`` and ``conv`` ``[n_kda, slots, conv -
+    1, 3 W]``."""
+    serving.check_kv_dtype(_THIS, kv_dtype)
     row = (cfg.n_kv_head, cfg.head_dim)
     D = cfg.kda_head_dim
     return CacheSpec(cfg.n_layer, (
@@ -898,36 +902,16 @@ def cache_spec(cfg: KDAMoEConfig, kv_dtype: str = "fp") -> CacheSpec:
                    cfg.dtype, cfg.n_kda)))
 
 
-def kv_bytes_per_page(cfg: KDAMoEConfig, page_size: int,
-                      kv_dtype: str = "fp") -> int:
-    return cache_spec(cfg, kv_dtype).bytes_per_page(page_size)
-
-
-def init_paged_cache(cfg: KDAMoEConfig, slots: int, n_pages: int,
-                     page_size: int, kv_dtype: str = "fp",
-                     tp: int = 1) -> Cache:
-    """The key/value page pools ``[n_gqa, n_pages, page_size, Hkv, hd]``,
-    the per-slot ``state`` ``[n_kda, slots, H, dk, dv]`` and ``conv``
-    ``[n_kda, slots, conv - 1, 3 W]``, and the per-slot ``pos``."""
-    check_tp(cfg, tp)
-    return init_paged_pool(cache_spec(cfg, kv_dtype), slots, n_pages,
-                           page_size)
-
-
 def max_positions(cfg: KDAMoEConfig) -> int:
     """No positions are encoded: the model's declared reach."""
     return cfg.max_seq
 
 
-def check_tp(cfg: KDAMoEConfig, tp: int):
-    if int(tp) > 1:
-        raise ValueError(f"tp={tp}: " + UNSUPPORTED["tp"])
-    return None
-
-
-def shard_params(params: Params, cfg: KDAMoEConfig, tp: int) -> Params:
-    check_tp(cfg, tp)
-    return params
+# what follows from the spec and from ``UNSUPPORTED["tp"]``: the frame's
+kv_bytes_per_page = serving.bind(serving.kv_bytes_per_page, _THIS)
+init_paged_cache = serving.bind(serving.init_paged_cache, _THIS)
+check_tp = serving.bind(serving.check_tp, _THIS)
+shard_params = serving.bind(serving.shard_params, _THIS)
 
 
 # -------------------------------------------------------------- programs
@@ -964,24 +948,24 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     ps = page_size
     n_pages = cache["k"].shape[1]
     max_pages = pt_row.shape[0]
-    x = _embed(params, tokens)[0]                            # [S, d]
+    x = embed(params, tokens)[0]                            # [S, d]
     live = jnp.arange(S) < length
     wpos = jnp.arange(S)
     vp = wpos // ps
     page_w = jnp.where(live & (vp < max_pages),
                        pt_row[jnp.clip(vp, 0, max_pages - 1)],
                        jnp.int32(PT_SENTINEL))
-    kpool, vpool = _flat(cache["k"]), _flat(cache["v"])
+    kpool, vpool = flat(cache["k"]), flat(cache["v"])
     state, conv = cache["state"], cache["conv"]
     back = cfg.conv_size - 1
     ig = ik = 0
     for l, p in enumerate(params["layers"]):
-        h = _rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+        h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
         if l in cfg.gqa_layers:
             q, k, v, z = _gqa_qkvz(h, p, cfg)
             with jax.named_scope("gqa.prefill"):
                 att = _gqa_causal(q, k, v, cfg)
-            at = (_at_layer(page_w, ig, n_pages), wpos % ps)
+            at = (at_layer(page_w, ig, n_pages), wpos % ps)
             kpool = kpool.at[at].set(k, mode="drop")
             vpool = vpool.at[at].set(v, mode="drop")
             y = _gqa_out(att, z, p, cfg)
@@ -992,9 +976,10 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
             conv = _put(conv, lax.dynamic_slice(
                 padded, (length, 0), (back, padded.shape[1])), ik, slot)
             ik += 1
-        x = _ffn(x + y.astype(x.dtype), p, cfg, live)[0]
+        x = block_ffn(x + y.astype(x.dtype), p, cfg, live)[0]
     x_last = lax.dynamic_slice(x, (length - 1, 0), (1, cfg.d_model))
-    token, rng = _sample(_head(x_last, params, cfg), temperature, rng)
+    token, rng = serving.sample(head(x_last, params, cfg), temperature,
+                                rng)
     pos = lax.dynamic_update_slice(
         cache["pos"], jnp.reshape(length, (1,)).astype(jnp.int32), (slot,))
     return token[0], {"k": kpool.reshape(cache["k"].shape),
@@ -1021,21 +1006,21 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     max_pages = pt.shape[1]
     pos = cache["pos"]
     n_pages = cache["k"].shape[1]
-    x = _embed(params, token)                                # [B, d]
+    x = embed(params, token)                                # [B, d]
     vp = pos // ps
     page_w = jnp.where(
         active & (vp < max_pages),
         jnp.take_along_axis(pt, jnp.clip(vp, 0, max_pages - 1)[:, None],
                             axis=1)[:, 0], jnp.int32(PT_SENTINEL))
     ptc = jnp.clip(pt, 0, n_pages - 1)
-    kpool, vpool = _flat(cache["k"]), _flat(cache["v"])
+    kpool, vpool = flat(cache["k"]), flat(cache["v"])
     state, conv = cache["state"], cache["conv"]
     counts = jnp.zeros((4,), jnp.int32)
     ig = ik = 0
     state_kernel, gqa_kernel = _state_kernel(cfg), _gqa_kernel(cfg, ps)
     if gqa_kernel:
         # the step writes a token's keys and values before it attends
-        length = _live_length(pt, pos, active, n_pages, ps)
+        length = live_length(pt, pos, active, n_pages, ps)
         fetched = jnp.sum((length + ps - 1) // ps * ps, dtype=jnp.int32)
     else:
         fetched = jnp.int32(pt.shape[0] * max_pages * ps)
@@ -1043,10 +1028,10 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     # attention and expert time from prefill's by it
     with jax.named_scope("decode_step"):
         for l, p in enumerate(params["layers"]):
-            h = _rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+            h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
             if l in cfg.gqa_layers:
                 q, k, v, z = _gqa_qkvz(h, p, cfg)
-                at = (_at_layer(page_w, ig, n_pages), pos % ps)
+                at = (at_layer(page_w, ig, n_pages), pos % ps)
                 kpool = kpool.at[at].set(k, mode="drop")
                 vpool = vpool.at[at].set(v, mode="drop")
                 with jax.named_scope("gqa.attention"):
@@ -1080,7 +1065,7 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                 with jax.named_scope("kda.proj"):
                     y = _kda_out(o, gate, p, cfg)
                 ik += 1
-            x, c = _ffn(x + y.astype(x.dtype), p, cfg, active)
+            x, c = block_ffn(x + y.astype(x.dtype), p, cfg, active)
             counts = counts + c
     cache_out = {"k": kpool.reshape(cache["k"].shape),
                  "v": vpool.reshape(cache["v"].shape),
@@ -1089,80 +1074,16 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     counts = jnp.concatenate(
         [counts, jnp.sum(active, dtype=jnp.int32)[None],
          (cfg.n_gqa * fetched)[None]])
-    return _head(x, params, cfg), cache_out, counts
+    return head(x, params, cfg), cache_out, counts
 
 
-def decode_chunk_slots_paged(params: Params, cache: Cache,
-                             token: jax.Array, rngs: jax.Array,
-                             active: jax.Array, pt: jax.Array, *,
-                             cfg: KDAMoEConfig, k: int, page_size: int,
-                             temperature: float = 0.0,
-                             eos_token: int = -1, kv_dtype: str = "fp",
-                             attn_kernel: str = "gather"):
-    """k fused decode steps over the slot pool in ONE program: the
-    frame of :func:`ray_tpu.models.gpt_decode.decode_chunk_slots_paged`
-    around this model's step. The cache (pages AND per-slot state) is
-    the scan's carry, donated: a step updates it in place. Returns
-    ``(tokens [B, k], cache', done [B], rngs', counts int32 [6])``."""
-    B = token.shape[0]
-    eos = jnp.asarray(eos_token, jnp.int32)
-    done0 = (active & (token == eos)) if eos_token >= 0 \
-        else jnp.zeros((B,), jnp.bool_)
-
-    def body(carry, _):
-        cache, tok, done, keys, counts = carry
-        logits, cache, c = _slot_decode_step_paged(
-            params, cache, tok, active, pt, cfg, page_size, kv_dtype,
-            attn_kernel)
-        nxt, keys = _sample_slots(logits, temperature, keys)
-        if eos_token >= 0:
-            nxt = jnp.where(done, eos, nxt)
-            done = done | (active & (nxt == eos))
-        return (cache, nxt, done, keys, counts + c), nxt
-
-    (cache, _, done, rngs, counts), toks = lax.scan(
-        body, (cache, token, done0, rngs,
-               jnp.zeros((len(STEP_COUNTERS),), jnp.int32)),
-        None, length=k)
-    return jnp.moveaxis(toks, 0, 1), cache, done, rngs, counts
-
-
-# rtlint: program-budget: len(prompt_buckets)
-@_knob_cache
-def jit_prefill_into_slot_paged(cfg: KDAMoEConfig, page_size: int,
-                                temperature: float = 0.0,
-                                kv_dtype: str = "fp", tp: int = 1):
-    """Jitted :func:`prefill_into_slot_paged`: one compiled program per
-    prompt bucket per (cfg, page_size, temperature) key. The cache is
-    donated."""
-    check_tp(cfg, tp)
-    cache_spec(cfg, kv_dtype)
-    return jax.jit(_program(prefill_into_slot_paged, cfg=cfg,
-                            page_size=page_size,
-                            temperature=temperature, kv_dtype=kv_dtype),
-                   donate_argnums=(1,))
-
-
-# rtlint: program-budget: 1
-@_knob_cache
-def jit_decode_chunk_slots_paged(cfg: KDAMoEConfig, k: int,
-                                 page_size: int,
-                                 temperature: float = 0.0,
-                                 eos_token: int = -1,
-                                 kv_dtype: str = "fp",
-                                 attn_kernel: str = "gather",
-                                 tp: int = 1):
-    """Jitted :func:`decode_chunk_slots_paged`: ONE program per (pool
-    shape, k, page_size); the page table is data. Cache donated."""
-    check_tp(cfg, tp)
-    cache_spec(cfg, kv_dtype)
-    if attn_kernel not in ATTN_KERNELS:
-        raise ValueError(
-            f"attn_kernel must be one of {ATTN_KERNELS}, got "
-            f"{attn_kernel!r}")
-    return jax.jit(_program(decode_chunk_slots_paged, cfg=cfg, k=k,
-                            page_size=page_size,
-                            temperature=temperature,
-                            eos_token=eos_token, kv_dtype=kv_dtype,
-                            attn_kernel=attn_kernel),
-                   donate_argnums=(1,))
+# the chunk program and the two factories are the frame's, around this
+# model's step and for this description (``models/serving.py``): the
+# cache the scan carries is pages AND per-slot state
+decode_chunk_slots_paged = functools.partial(
+    serving.decode_chunk_slots_paged, step=_slot_decode_step_paged,
+    counters=len(STEP_COUNTERS))
+jit_prefill_into_slot_paged = serving.bind(
+    serving.jit_prefill_into_slot_paged, _THIS)
+jit_decode_chunk_slots_paged = serving.bind(
+    serving.jit_decode_chunk_slots_paged, _THIS)

@@ -72,18 +72,20 @@ counters summed over its steps (:data:`STEP_COUNTERS`).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import moe
+from . import moe, serving
 from .gpt import _mm
-from .gpt_decode import (_attend_history, _hist_blocks, _knob_cache,
-                         _program, _sample, _sample_slots)
-from .serving import PT_SENTINEL, CacheEntry, CacheSpec, init_paged_pool
+from .serving import PT_SENTINEL, CacheEntry, CacheSpec
+
+_THIS = sys.modules[__name__]
 
 Params = Dict[str, Any]
 Cache = Dict[str, jax.Array]
@@ -255,19 +257,6 @@ def init_params(rng: jax.Array, cfg: MLAMoEConfig, std: Optional[dict] = None
 
 
 # ------------------------------------------------------------ block math
-def _rmsnorm(x, scale, eps, dtype=None, gain: float = 1.0):
-    """RMSNorm in float32; the result in ``dtype`` (``x``'s own if
-    absent), ready to be multiplied. ``gain``: a constant factor on
-    the normed values, applied in float32 before the one rounding."""
-    dtype = dtype or x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    y = x * lax.rsqrt(var + eps)
-    if gain != 1.0:
-        y = y * gain
-    return y.astype(dtype) * scale.astype(dtype)
-
-
 def yarn_inv_freq(cfg: MLAMoEConfig) -> jax.Array:
     """The ``rope_dim / 2`` rotary frequencies, float32. YaRN: below
     the correction dimension of ``beta_fast`` rotations over the
@@ -316,14 +305,14 @@ def _latent_qkv(x, p, positions, cfg: MLAMoEConfig):
     all."""
     B, S, _ = x.shape
     H = cfg.n_head
-    h = _rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
-    cq = _rmsnorm(_mm(h, p["wqa"]["kernel"], cfg.dtype),
+    h = moe.rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+    cq = moe.rmsnorm(_mm(h, p["wqa"]["kernel"], cfg.dtype),
                   p["q_norm_scale"], cfg.eps, gain=cfg.q_gain)
     q = _mm(cq, p["wqb"]["kernel"], cfg.dtype).reshape(
         B, S, H, cfg.nope_dim + cfg.rope_dim)
     qn, qr = q[..., :cfg.nope_dim], q[..., cfg.nope_dim:]
     ckv = _mm(h, p["wkva"]["kernel"], cfg.dtype)
-    c = _rmsnorm(ckv[..., :cfg.kv_rank], p["kv_norm_scale"], cfg.eps,
+    c = moe.rmsnorm(ckv[..., :cfg.kv_rank], p["kv_norm_scale"], cfg.eps,
                  gain=cfg.kv_gain)
     kr = _rope(ckv[..., cfg.kv_rank:], positions, cfg)
     pad = jnp.zeros(c.shape[:-1] + (cfg.latent_row - cfg.latent_dim,),
@@ -339,44 +328,6 @@ def _wkvb(p, cfg: MLAMoEConfig):
     return w[..., :cfg.nope_dim], w[..., cfg.nope_dim:]
 
 
-def _ffn(x, p, cfg: MLAMoEConfig, live=None):
-    """x [T, d] -> (x + FFN(RMSNorm(x)), counts int32 [4]): a dense
-    gated FFN, or the held experts' part plus the shared expert."""
-    h = _rmsnorm(x, p["ln2_scale"], cfg.eps, cfg.dtype)
-    if "ffn" in p:
-        return x + moe.gated_ffn(h, p["ffn"], cfg.dtype).astype(x.dtype), \
-            jnp.zeros((4,), jnp.int32)
-    y, counts = moe.dropless_moe(
-        h, p["router"]["kernel"], p["experts"],
-        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
-        n_group=cfg.n_group, topk_group=cfg.topk_group, top_k=cfg.top_k,
-        norm_topk=cfg.norm_topk, route_scale=cfg.route_scale,
-        dtype=cfg.dtype, block_rows=cfg.moe_block_rows, live=live)
-    if "shared" in p:
-        with jax.named_scope("moe.shared"):
-            y = y + moe.gated_ffn(h, p["shared"], cfg.dtype)
-    return x + y.astype(x.dtype), \
-        jnp.concatenate([jnp.ones((1,), jnp.int32), counts])
-
-
-def _embed(params, tokens):
-    """The residual stream starts, and stays, in float32: every block
-    adds into it unrounded, and only what a matrix multiplies is cast
-    to the compute dtype (a stream held in bfloat16 rounds at every
-    add). What it buys is small: the logits' median distance from the
-    float32 reference 0.020 -> 0.018 of the largest logit (PERF.md,
-    PR 37); what it costs is one float32 row a token."""
-    return params["embed"]["kernel"][tokens].astype(jnp.float32)
-
-
-def _head(x, params, cfg: MLAMoEConfig):
-    x = _rmsnorm(x, params["ln_f_scale"], cfg.eps, cfg.dtype)
-    return lax.dot_general(
-        x.astype(cfg.dtype), params["head"]["kernel"].astype(cfg.dtype),
-        (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-
 def forward(params: Params, tokens: jax.Array, cfg: MLAMoEConfig
             ) -> jax.Array:
     """tokens [B, S] -> float32 logits [B, S, rows]: the whole
@@ -385,13 +336,14 @@ def forward(params: Params, tokens: jax.Array, cfg: MLAMoEConfig
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
-    x = _embed(params, tokens)
+    x = moe.embed(params, tokens)
     for p in params["layers"]:
         qn, qr, ent = _latent_qkv(x, p, positions, cfg)
         att = _attend_materialised(qn, qr, ent, mask, p, cfg)
         x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
-        x = _ffn(x.reshape(B * S, -1), p, cfg)[0].reshape(B, S, -1)
-    return _head(x, params, cfg)
+        x = moe.block_ffn(x.reshape(B * S, -1), p, cfg)[0].reshape(
+            B, S, -1)
+    return moe.head(x, params, cfg)
 
 
 def _materialised(qn, qr, latents, p, cfg: MLAMoEConfig):
@@ -430,28 +382,11 @@ def _attend_materialised(qn, qr, latents, mask, p, cfg: MLAMoEConfig):
 def cache_spec(cfg: MLAMoEConfig, kv_dtype: str = "fp") -> CacheSpec:
     """What a token leaves in a page, per layer: ONE latent row in the
     compute dtype, no head axis: ``kv_rank + rope_dim`` values (576)
-    in a row of ``latent_row`` (640: the lanes they occupy)."""
-    if kv_dtype not in KV_DTYPES:
-        raise ValueError(
-            f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}: "
-            + UNSUPPORTED["int8"])
+    in a row of ``latent_row`` (640: the lanes they occupy). So the
+    pool is ``[L, n_pages, page_size, latent_row]``."""
+    serving.check_kv_dtype(_THIS, kv_dtype)
     return CacheSpec(cfg.n_layer, (CacheEntry(
         "latent", "token", (cfg.latent_row,), cfg.dtype),))
-
-
-def kv_bytes_per_page(cfg: MLAMoEConfig, page_size: int,
-                      kv_dtype: str = "fp") -> int:
-    return cache_spec(cfg, kv_dtype).bytes_per_page(page_size)
-
-
-def init_paged_cache(cfg: MLAMoEConfig, slots: int, n_pages: int,
-                     page_size: int, kv_dtype: str = "fp",
-                     tp: int = 1) -> Cache:
-    """The latent page pool ``[L, n_pages, page_size, latent_row]`` and
-    the per-slot ``pos``."""
-    check_tp(cfg, tp)
-    return init_paged_pool(cache_spec(cfg, kv_dtype), slots, n_pages,
-                           page_size)
 
 
 def max_positions(cfg: MLAMoEConfig) -> int:
@@ -459,33 +394,15 @@ def max_positions(cfg: MLAMoEConfig) -> int:
     return cfg.max_seq
 
 
-def check_tp(cfg: MLAMoEConfig, tp: int):
-    if int(tp) > 1:
-        raise ValueError(f"tp={tp}: " + UNSUPPORTED["tp"])
-    return None
-
-
-def shard_params(params: Params, cfg: MLAMoEConfig, tp: int) -> Params:
-    check_tp(cfg, tp)
-    return params
+# what follows from the spec and from ``UNSUPPORTED["tp"]``: the frame's
+kv_bytes_per_page = serving.bind(serving.kv_bytes_per_page, _THIS)
+init_paged_cache = serving.bind(serving.init_paged_cache, _THIS)
+check_tp = serving.bind(serving.check_tp, _THIS)
+shard_params = serving.bind(serving.shard_params, _THIS)
 
 
 # -------------------------------------------------------------- programs
-def _flat(pool: jax.Array) -> jax.Array:
-    """``[L, n_pages, ...]`` viewed as ``[L * n_pages, ...]``: layer
-    ``l`` addresses page ``p`` at ``l * n_pages + p`` and no layer's
-    pool is sliced out of the stacked one."""
-    return pool.reshape((-1,) + pool.shape[2:])
-
-
-def _at_layer(pages, l: int, n_pages: int):
-    """Page ids of one layer in the flat pool; sentinels (and anything
-    out of bounds) stay out of bounds."""
-    return jnp.where((pages >= 0) & (pages < n_pages),
-                     pages + l * n_pages, jnp.int32(PT_SENTINEL))
-
-
-def _prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
+def prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
                        cow_src, cfg, page_size: int):
     """The paged prefill's frame around ANY model whose attentions
     leave latent rows: the copy-on-write fork, then one attention
@@ -498,8 +415,8 @@ def _prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
     ``mla.prefill``): suffix token ``i`` sits at position ``hist_len +
     i`` and attends over the suffix, causally, and over the ``hist_len``
     cached tokens before it, whose latents are read through ``pt_row``
-    a block of :data:`ray_tpu.models.gpt_decode._HIST_BLOCK_TOKENS` at
-    once (:func:`ray_tpu.models.gpt_decode._attend_history`, scope
+    a block of :data:`ray_tpu.models.serving.HIST_BLOCK_TOKENS` at
+    once (:func:`ray_tpu.models.serving.attend_history`, scope
     ``prefill.history`` inside ``mla.prefill``): keys and values are
     materialised from the latents per head for the suffix and for the
     blocks a hit is long, none without a hit, never for ``max_len``;
@@ -515,7 +432,7 @@ def _prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
     # dst]`` made XLA hold the pool in a second layout and copy all of
     # it twice a prefill: PERF.md, PR 37); no fork copies to an
     # out-of-bounds page and is dropped.
-    pool = _flat(cache["latent"])
+    pool = serving.flat(cache["latent"])
     layers = jnp.arange(A, dtype=jnp.int32) * n_pages
     dst = pt_row[jnp.clip(hist_len // ps, 0, max_pages - 1)]
     dst_w = jnp.where((cow_src < n_pages) & (dst < n_pages),
@@ -523,7 +440,7 @@ def _prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
     pool = pool.at[dst_w].set(
         pool[jnp.clip(cow_src, 0, n_pages - 1) + layers], mode="drop")
 
-    T, hist_pages = _hist_blocks(pt_row, n_pages, ps)
+    T, hist_pages = serving.hist_blocks(pt_row, n_pages, ps)
     causal = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
     live = jnp.arange(S) < length
     vp = positions // ps
@@ -540,23 +457,23 @@ def _prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
 
         with jax.named_scope("mla.prefill"):
             lg, v = _materialised(qn, qr, ent, p, cfg)
-            att = _attend_history(jnp.where(causal, lg, -1e30), v,
-                                  hist_len, T, block
-                                  ).astype(cfg.dtype).reshape(1, S, -1)
+            att = serving.attend_history(
+                jnp.where(causal, lg, -1e30), v, hist_len, T, block
+            ).astype(cfg.dtype).reshape(1, S, -1)
         x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
-        return x, pool.at[_at_layer(page_w, a, n_pages),
+        return x, pool.at[serving.at_layer(page_w, a, n_pages),
                           positions % ps].set(ent[0], mode="drop")
 
     return pool, live, attend
 
 
-def _prefill_result(x, pool, params: Params, cache: Cache, length,
+def prefill_result(x, pool, params: Params, cache: Cache, length,
                     hist_len, slot, rng, cfg, temperature: float):
     """The first token's sample from the last live row of ``x`` [1, S,
     d], and the cache with ``pool`` and the slot's ``pos``."""
     x_last = lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, cfg.d_model))
-    token, rng = _sample(_head(x_last, params, cfg)[:, 0], temperature,
-                         rng)
+    token, rng = serving.sample(moe.head(x_last, params, cfg)[:, 0],
+                                temperature, rng)
     pos = lax.dynamic_update_slice(
         cache["pos"], jnp.reshape(hist_len + length, (1,)), (slot,))
     return token[0], {"latent": pool.reshape(cache["latent"].shape),
@@ -574,22 +491,22 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     """Prefill one prompt SUFFIX into its pages, with the optional
     copy-on-write fork and the first token's sample: the contract of
     :func:`ray_tpu.models.gpt_decode.prefill_into_slot_paged`, on
-    latent pages (:func:`_prefill_attention`)."""
-    pool, live, attend = _prefill_attention(
+    latent pages (:func:`prefill_attention`)."""
+    pool, live, attend = prefill_attention(
         cache, tokens.shape[1], length, hist_len, pt_row, cow_src, cfg,
         page_size)
-    x = _embed(params, tokens)
+    x = moe.embed(params, tokens)
     for l, p in enumerate(params["layers"]):
         x, pool = attend(x, p, l, pool)
-        x = _ffn(x[0], p, cfg, live)[0][None]
-    return _prefill_result(x, pool, params, cache, length, hist_len, slot,
-                           rng, cfg, temperature)
+        x = moe.block_ffn(x[0], p, cfg, live)[0][None]
+    return prefill_result(x, pool, params, cache, length, hist_len, slot,
+                          rng, cfg, temperature)
 
 
 def decode_attention_fused(cfg: MLAMoEConfig, page_size: int,
                            attn_kernel: str = "gather") -> bool:
     """Whether the chunk program built with these knobs holds the
-    Pallas kernel (the description's optional entry,
+    Pallas kernel (the description's entry,
     :mod:`ray_tpu.models.serving`): wherever Mosaic can address a page
     of the pool. A row is whole 128-lane tiles by construction
     (:attr:`MLAMoEConfig.latent_row`); compiled for a TPU a page must
@@ -772,21 +689,7 @@ def _latent_attention_pallas(q, pool, pages, length, cfg: MLAMoEConfig,
     )(pages, length, first, q, pool)
 
 
-def _live_length(pt, pos, active, n_pages: int, page_size: int):
-    """Tokens of each lane the kernel reads: positions <= ``pos`` inside
-    the mapped prefix of the lane's table row (the engine maps a lane's
-    pages from column 0 without holes); 0 for an inactive lane or a row
-    of sentinels."""
-    max_pages = pt.shape[1]
-    mapped = jnp.min(jnp.where((pt >= 0) & (pt < n_pages),
-                               jnp.int32(max_pages),
-                               jnp.arange(max_pages, dtype=jnp.int32)),
-                     axis=1)
-    return jnp.where(active, jnp.minimum(pos.astype(jnp.int32) + 1,
-                                         mapped * page_size), 0)
-
-
-def _decode_attention(cache: Cache, active, pt, cfg, page_size: int,
+def decode_attention(cache: Cache, active, pt, cfg, page_size: int,
                       attn_kernel: str = "gather"):
     """One decode step's frame around ANY model whose attentions leave
     latent rows (``cache["latent"]`` ``[A, n_pages, ps, row]`` over its
@@ -808,12 +711,13 @@ def _decode_attention(cache: Cache, active, pt, cfg, page_size: int,
                             axis=1)[:, 0], jnp.int32(PT_SENTINEL))
     ptc = jnp.clip(pt, 0, n_pages - 1)
     fused = decode_attention_fused(cfg, ps, attn_kernel)
-    length = _live_length(pt, pos, active, n_pages, ps) if fused else None
+    length = serving.live_length(pt, pos, active, n_pages, ps) if fused \
+        else None
 
     def attend(x, p, a: int, pool):
         qn, qr, ent = _latent_qkv(x, p, pos[:, None], cfg)
-        pool = pool.at[_at_layer(page_w, a, n_pages), pos % ps].set(
-            ent[:, 0], mode="drop")
+        pool = pool.at[serving.at_layer(page_w, a, n_pages),
+                       pos % ps].set(ent[:, 0], mode="drop")
         w_uk, w_uv = _wkvb(p, cfg)
         q = jnp.concatenate([
             jnp.einsum("bhn,rhn->bhr", qn[:, 0], w_uk,
@@ -832,7 +736,7 @@ def _decode_attention(cache: Cache, active, pt, cfg, page_size: int,
         return x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype), \
             pool
 
-    return _flat(cache["latent"]), attend
+    return serving.flat(cache["latent"]), attend
 
 
 def _slot_decode_step_paged(params: Params, cache: Cache,
@@ -841,102 +745,32 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                             page_size: int, kv_dtype: str = "fp",
                             attn_kernel: str = "gather"):
     """One masked decode step over the whole slot pool
-    (:func:`_decode_attention`, then the layer's FFN). Inactive lanes
+    (:func:`decode_attention`, then the layer's FFN). Inactive lanes
     neither write, advance nor route. Returns ``(logits [B, rows],
     cache', counts)``: the expert layers' counters int32 [4]
     (:data:`STEP_COUNTERS`)."""
-    pool, attend = _decode_attention(cache, active, pt, cfg, page_size,
-                                     attn_kernel)
-    x = _embed(params, token)[:, None]
+    pool, attend = decode_attention(cache, active, pt, cfg, page_size,
+                                    attn_kernel)
+    x = moe.embed(params, token)[:, None]
     counts = jnp.zeros((4,), jnp.int32)
     # the step's own scope: a reader tells the decode program's
     # expert and attention time from prefill's by it
     with jax.named_scope("decode_step"):
         for l, p in enumerate(params["layers"]):
             x, pool = attend(x, p, l, pool)
-            y, c = _ffn(x[:, 0], p, cfg, active)
+            y, c = moe.block_ffn(x[:, 0], p, cfg, active)
             x, counts = y[:, None], counts + c
     cache_out = {"latent": pool.reshape(cache["latent"].shape),
                  "pos": cache["pos"] + active.astype(jnp.int32)}
-    return _head(x, params, cfg)[:, 0], cache_out, counts
+    return moe.head(x, params, cfg)[:, 0], cache_out, counts
 
 
-def decode_chunk_slots_paged(params: Params, cache: Cache,
-                             token: jax.Array, rngs: jax.Array,
-                             active: jax.Array, pt: jax.Array, *,
-                             cfg: MLAMoEConfig, k: int, page_size: int,
-                             temperature: float = 0.0,
-                             eos_token: int = -1, kv_dtype: str = "fp",
-                             attn_kernel: str = "gather",
-                             step=_slot_decode_step_paged,
-                             counters: int = len(STEP_COUNTERS)):
-    """k fused decode steps over the slot pool in ONE program: the
-    frame of :func:`ray_tpu.models.gpt_decode.decode_chunk_slots_paged`
-    (the page table constant through the chunk, per-slot PRNG lanes,
-    EOS mask-and-carry) around a model's ``step`` (this one's, or a
-    sibling's with ``counters`` of its own). Returns ``(tokens [B, k],
-    cache', done [B], rngs', counts int32 [counters])``: the model's
-    counters summed over the k steps come out with the tokens, at no
-    launch of their own."""
-    B = token.shape[0]
-    eos = jnp.asarray(eos_token, jnp.int32)
-    done0 = (active & (token == eos)) if eos_token >= 0 \
-        else jnp.zeros((B,), jnp.bool_)
-
-    def body(carry, _):
-        cache, tok, done, keys, counts = carry
-        logits, cache, c = step(
-            params, cache, tok, active, pt, cfg, page_size, kv_dtype,
-            attn_kernel)
-        nxt, keys = _sample_slots(logits, temperature, keys)
-        if eos_token >= 0:
-            nxt = jnp.where(done, eos, nxt)
-            done = done | (active & (nxt == eos))
-        return (cache, nxt, done, keys, counts + c), nxt
-
-    (cache, _, done, rngs, counts), toks = lax.scan(
-        body, (cache, token, done0, rngs,
-               jnp.zeros((counters,), jnp.int32)), None, length=k)
-    return jnp.moveaxis(toks, 0, 1), cache, done, rngs, counts
-
-
-# rtlint: program-budget: len(prompt_buckets)
-@_knob_cache
-def jit_prefill_into_slot_paged(cfg: MLAMoEConfig, page_size: int,
-                                temperature: float = 0.0,
-                                kv_dtype: str = "fp", tp: int = 1):
-    """Jitted :func:`prefill_into_slot_paged`: one compiled program per
-    SUFFIX bucket per (cfg, page_size, temperature) key; the prefix
-    hit's depth, the page table and the COW source are traced. The
-    pool is donated."""
-    check_tp(cfg, tp)
-    cache_spec(cfg, kv_dtype)
-    return jax.jit(_program(prefill_into_slot_paged, cfg=cfg,
-                            page_size=page_size,
-                            temperature=temperature, kv_dtype=kv_dtype),
-                   donate_argnums=(1,))
-
-
-# rtlint: program-budget: 1
-@_knob_cache
-def jit_decode_chunk_slots_paged(cfg: MLAMoEConfig, k: int,
-                                 page_size: int,
-                                 temperature: float = 0.0,
-                                 eos_token: int = -1,
-                                 kv_dtype: str = "fp",
-                                 attn_kernel: str = "gather",
-                                 tp: int = 1):
-    """Jitted :func:`decode_chunk_slots_paged`: ONE program per (pool
-    shape, k, page_size); the page table is data. Pool donated."""
-    check_tp(cfg, tp)
-    cache_spec(cfg, kv_dtype)
-    if attn_kernel not in ATTN_KERNELS:
-        raise ValueError(
-            f"attn_kernel must be one of {ATTN_KERNELS}, got "
-            f"{attn_kernel!r}")
-    return jax.jit(_program(decode_chunk_slots_paged, cfg=cfg, k=k,
-                            page_size=page_size,
-                            temperature=temperature,
-                            eos_token=eos_token, kv_dtype=kv_dtype,
-                            attn_kernel=attn_kernel),
-                   donate_argnums=(1,))
+# the chunk program and the two factories are the frame's, around this
+# model's step and for this description (``models/serving.py``)
+decode_chunk_slots_paged = functools.partial(
+    serving.decode_chunk_slots_paged, step=_slot_decode_step_paged,
+    counters=len(STEP_COUNTERS))
+jit_prefill_into_slot_paged = serving.bind(
+    serving.jit_prefill_into_slot_paged, _THIS)
+jit_decode_chunk_slots_paged = serving.bind(
+    serving.jit_decode_chunk_slots_paged, _THIS)

@@ -16,6 +16,10 @@ the native formulation is the GShard/Switch dispatch-einsum pattern:
 The [T, E, C] one-hot dispatch tensor is the classic memory cost of this
 formulation; a sort-based scatter variant can replace it later without
 changing the interface.
+
+Below it: the serving-side DROPLESS expert layer (routing and dispatch
+apart), and the block math the three served expert decoders share
+around it (``rmsnorm``, ``embed``, ``head``, ``block_ffn``).
 """
 from __future__ import annotations
 
@@ -319,3 +323,67 @@ def dropless_experts(x: jax.Array, ids: jax.Array, w: jax.Array, experts,
         counts = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32),
                             jnp.sum(sizes), jnp.max(sizes)])
     return y.astype(dtype), counts
+
+
+# ------------------------------------------- the expert decoders' block
+# What the served expert decoders share around the layer above
+# (``mla_moe``, ``kda_moe``, ``scmoe``: pre-norm, RMSNorm, no biases,
+# a float32 residual stream, an untied head): here, beside the expert
+# layer, because ``block_ffn`` IS that layer behind its norm and the
+# three models already import this module; no model imports another
+# for them.
+
+def rmsnorm(x, scale, eps, dtype=None, gain: float = 1.0):
+    """RMSNorm in float32; the result in ``dtype`` (``x``'s own if
+    absent), ready to be multiplied. ``gain``: a constant factor on
+    the normed values, applied in float32 before the one rounding."""
+    dtype = dtype or x.dtype
+    x = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    y = x * lax.rsqrt(var + eps)
+    if gain != 1.0:
+        y = y * gain
+    return y.astype(dtype) * scale.astype(dtype)
+
+
+def embed(params, tokens):
+    """The residual stream starts, and stays, in float32: every block
+    adds into it unrounded, and only what a matrix multiplies is cast
+    to the compute dtype (a stream held in bfloat16 rounds at every
+    add). What it buys is small: the logits' median distance from the
+    float32 reference 0.020 -> 0.018 of the largest logit (PERF.md,
+    PR 37); what it costs is one float32 row a token."""
+    return params["embed"]["kernel"][tokens].astype(jnp.float32)
+
+
+def head(x, params, cfg):
+    """The final norm and the untied head: float32 logits."""
+    x = rmsnorm(x, params["ln_f_scale"], cfg.eps, cfg.dtype)
+    return lax.dot_general(
+        x.astype(cfg.dtype), params["head"]["kernel"].astype(cfg.dtype),
+        (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
+def block_ffn(x, p, cfg, live=None):
+    """x [T, d] -> (x + FFN(RMSNorm(x)), counts int32 [4]): a dense
+    gated FFN (``p["ffn"]``), or the held experts' part of a
+    sigmoid-routed layer (:func:`dropless_moe`, by ``cfg``'s
+    ``experts_held`` .. ``moe_block_rows``) plus the shared expert
+    every token takes (scope ``moe.shared``). ``counts``: whether this
+    was an expert layer, then :func:`dropless_experts`' three."""
+    h = rmsnorm(x, p["ln2_scale"], cfg.eps, cfg.dtype)
+    if "ffn" in p:
+        return x + gated_ffn(h, p["ffn"], cfg.dtype).astype(x.dtype), \
+            jnp.zeros((4,), jnp.int32)
+    y, counts = dropless_moe(
+        h, p["router"]["kernel"], p["experts"],
+        experts_held=cfg.experts_held, expert_offset=cfg.expert_offset,
+        n_group=cfg.n_group, topk_group=cfg.topk_group, top_k=cfg.top_k,
+        norm_topk=cfg.norm_topk, route_scale=cfg.route_scale,
+        dtype=cfg.dtype, block_rows=cfg.moe_block_rows, live=live)
+    if "shared" in p:
+        with jax.named_scope("moe.shared"):
+            y = y + gated_ffn(h, p["shared"], cfg.dtype)
+    return x + y.astype(x.dtype), \
+        jnp.concatenate([jnp.ones((1,), jnp.int32), counts])

@@ -17,15 +17,15 @@ beside it (pre-norm, RMSNorm, no biases, untied head)::
     x += s                       # ... and rejoins here
 
 **Attention** is :mod:`ray_tpu.models.mla_moe`'s latent attention,
-IMPORTED: the projections and the rotary (``_latent_qkv``), prefill
-materialised (``_prefill_attention``, scope ``mla.prefill``: the
-suffix's own rows, and the blocks of cached prefix a hit is long under
+IMPORTED under its public names: prefill materialised
+(``prefill_attention``, scope ``mla.prefill``: the suffix's own rows,
+and the blocks of cached prefix a hit is long under
 ``prefill.history``, never ``max_len`` of them), decode absorbed over
-the lane's live latent pages (``_decode_attention``, scope
-``mla.attention``: the Pallas kernel ``_latent_attention_pallas``
-wherever Mosaic can address a page, else XLA over the gathered pages).
-What this model adds to it is two constants that the config carries
-and ``_latent_qkv`` reads: ``q_gain = sqrt(d_model / q_rank)`` on the
+the lane's live latent pages (``decode_attention``, scope
+``mla.attention``: the Pallas kernel wherever Mosaic can address a
+page, else XLA over the gathered pages). What this model adds to it is
+two constants that the config carries and the attention's projections
+read: ``q_gain = sqrt(d_model / q_rank)`` on the
 query's low-rank state and ``kv_gain = sqrt(d_model / kv_rank)`` on the
 latent ``c`` (not on the rotary key), both after their norms; rotary is
 plain (``rope_factor`` 1.0). A token leaves one SCALED latent row an
@@ -53,19 +53,22 @@ its steps (:data:`STEP_COUNTERS`).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+import sys
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from . import mla_moe, moe
-from .gpt_decode import _knob_cache, _program
+from . import mla_moe, moe, serving
 # ``decode_attention_fused`` is the description's entry as it stands:
 # this model's one kernel is the imported attention's
-from .mla_moe import (_decode_attention, _embed, _head, _prefill_attention,
-                      _prefill_result, _rmsnorm, decode_attention_fused)
-from .serving import PT_SENTINEL, CacheEntry, CacheSpec, init_paged_pool
+from .mla_moe import (decode_attention, decode_attention_fused,
+                      prefill_attention, prefill_result)
+from .serving import PT_SENTINEL, CacheEntry, CacheSpec
+
+_THIS = sys.modules[__name__]
 
 Params = Dict[str, Any]
 Cache = Dict[str, jax.Array]
@@ -133,7 +136,7 @@ class ScMoEConfig:
 
     @property
     def q_gain(self) -> float:
-        """On ``c_q`` after its norm (``_latent_qkv`` reads it)."""
+        """On ``c_q`` after its norm (the attention reads it)."""
         return math.sqrt(self.d_model / self.q_rank)
 
     @property
@@ -230,7 +233,7 @@ def _dense(x, p, cfg: ScMoEConfig, h=None):
     """x + FFN(RMSNorm(x)) for one dense FFN's tree ``p`` (``h``: the
     normed input where the caller already has it)."""
     if h is None:
-        h = _rmsnorm(x, p["ln2_scale"], cfg.eps, cfg.dtype)
+        h = moe.rmsnorm(x, p["ln2_scale"], cfg.eps, cfg.dtype)
     with jax.named_scope("scmoe.dense"):
         return x + moe.gated_ffn(h, p, cfg.dtype).astype(x.dtype)
 
@@ -242,7 +245,7 @@ def _layer(x, p, a: int, pool, attend, cfg: ScMoEConfig, live):
     ``(x', pool', counts)``."""
     shape = x.shape
     x, pool = attend(x, p["attn"][0], a, pool)
-    u = _rmsnorm(x, p["ffn"][0]["ln2_scale"], cfg.eps, cfg.dtype)
+    u = moe.rmsnorm(x, p["ffn"][0]["ln2_scale"], cfg.eps, cfg.dtype)
     s, counts = _expert_branch(u.reshape(-1, shape[-1]), p, cfg, live)
     x = _dense(x, p["ffn"][0], cfg, u)
     x, pool = attend(x, p["attn"][1], a + 1, pool)
@@ -254,34 +257,20 @@ def _layer(x, p, a: int, pool, attend, cfg: ScMoEConfig, live):
 def cache_spec(cfg: ScMoEConfig, kv_dtype: str = "fp") -> CacheSpec:
     """What a token leaves in a page: ONE latent row in the compute
     dtype an ATTENTION, two a layer (the entry counts its own layers:
-    ``2 * n_layer``)."""
-    if kv_dtype not in KV_DTYPES:
-        raise ValueError(
-            f"kv_dtype must be one of {KV_DTYPES}, got {kv_dtype!r}: "
-            + UNSUPPORTED["int8"])
+    ``2 * n_layer``). So the pool is ``[2 L, n_pages, page_size,
+    latent_row]``."""
+    serving.check_kv_dtype(_THIS, kv_dtype)
     return CacheSpec(cfg.n_layer, (CacheEntry(
         "latent", "token", (cfg.latent_row,), cfg.dtype,
         n_layer=2 * cfg.n_layer),))
 
 
-def kv_bytes_per_page(cfg: ScMoEConfig, page_size: int,
-                      kv_dtype: str = "fp") -> int:
-    return cache_spec(cfg, kv_dtype).bytes_per_page(page_size)
-
-
-def init_paged_cache(cfg: ScMoEConfig, slots: int, n_pages: int,
-                     page_size: int, kv_dtype: str = "fp",
-                     tp: int = 1) -> Cache:
-    """The latent page pool ``[2 L, n_pages, page_size, latent_row]``
-    and the per-slot ``pos``."""
-    check_tp(cfg, tp)
-    return init_paged_pool(cache_spec(cfg, kv_dtype), slots, n_pages,
-                           page_size)
-
-
 max_positions = mla_moe.max_positions
-check_tp = mla_moe.check_tp
-shard_params = mla_moe.shard_params
+# what follows from the spec and from ``UNSUPPORTED["tp"]``: the frame's
+kv_bytes_per_page = serving.bind(serving.kv_bytes_per_page, _THIS)
+init_paged_cache = serving.bind(serving.init_paged_cache, _THIS)
+check_tp = serving.bind(serving.check_tp, _THIS)
+shard_params = serving.bind(serving.shard_params, _THIS)
 
 
 # -------------------------------------------------------------- programs
@@ -296,15 +285,15 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     """Prefill one prompt SUFFIX into its pages: the contract of
     :func:`ray_tpu.models.gpt_decode.prefill_into_slot_paged`, on the
     latent pages of both attentions of every layer
-    (:func:`ray_tpu.models.mla_moe._prefill_attention`)."""
-    pool, live, attend = _prefill_attention(
+    (:func:`ray_tpu.models.mla_moe.prefill_attention`)."""
+    pool, live, attend = prefill_attention(
         cache, tokens.shape[1], length, hist_len, pt_row, cow_src, cfg,
         page_size)
-    x = _embed(params, tokens)
+    x = moe.embed(params, tokens)
     for l, p in enumerate(params["layers"]):
         x, pool, _ = _layer(x, p, 2 * l, pool, attend, cfg, live)
-    return _prefill_result(x, pool, params, cache, length, hist_len, slot,
-                           rng, cfg, temperature)
+    return prefill_result(x, pool, params, cache, length, hist_len, slot,
+                          rng, cfg, temperature)
 
 
 def _slot_decode_step_paged(params: Params, cache: Cache,
@@ -313,13 +302,13 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                             page_size: int, kv_dtype: str = "fp",
                             attn_kernel: str = "gather"):
     """One masked decode step over the whole slot pool
-    (:func:`ray_tpu.models.mla_moe._decode_attention` for each of a
+    (:func:`ray_tpu.models.mla_moe.decode_attention` for each of a
     layer's two attentions). Inactive lanes neither write, advance nor
     route. Returns ``(logits [B, rows], cache', counts)``: the
     counters int32 [6] (:data:`STEP_COUNTERS`)."""
-    pool, attend = _decode_attention(cache, active, pt, cfg, page_size,
-                                     attn_kernel)
-    x = _embed(params, token)[:, None]
+    pool, attend = decode_attention(cache, active, pt, cfg, page_size,
+                                    attn_kernel)
+    x = moe.embed(params, token)[:, None]
     counts = jnp.zeros((len(STEP_COUNTERS),), jnp.int32)
     with jax.named_scope("decode_step"):
         for l, p in enumerate(params["layers"]):
@@ -327,57 +316,15 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
             counts = counts + c
     cache_out = {"latent": pool.reshape(cache["latent"].shape),
                  "pos": cache["pos"] + active.astype(jnp.int32)}
-    return _head(x, params, cfg)[:, 0], cache_out, counts
+    return moe.head(x, params, cfg)[:, 0], cache_out, counts
 
 
-def decode_chunk_slots_paged(params: Params, cache: Cache,
-                             token: jax.Array, rngs: jax.Array,
-                             active: jax.Array, pt: jax.Array, **knobs):
-    """k fused decode steps in ONE program:
-    :func:`ray_tpu.models.mla_moe.decode_chunk_slots_paged`'s frame
-    around this model's step and its six counters."""
-    return mla_moe.decode_chunk_slots_paged(
-        params, cache, token, rngs, active, pt,
-        step=_slot_decode_step_paged, counters=len(STEP_COUNTERS),
-        **knobs)
-
-
-# rtlint: program-budget: len(prompt_buckets)
-@_knob_cache
-def jit_prefill_into_slot_paged(cfg: ScMoEConfig, page_size: int,
-                                temperature: float = 0.0,
-                                kv_dtype: str = "fp", tp: int = 1):
-    """Jitted :func:`prefill_into_slot_paged`: one compiled program per
-    SUFFIX bucket per (cfg, page_size, temperature) key. The pool is
-    donated."""
-    check_tp(cfg, tp)
-    cache_spec(cfg, kv_dtype)
-    return jax.jit(_program(prefill_into_slot_paged, cfg=cfg,
-                            page_size=page_size,
-                            temperature=temperature, kv_dtype=kv_dtype),
-                   donate_argnums=(1,))
-
-
-# rtlint: program-budget: 1
-@_knob_cache
-def jit_decode_chunk_slots_paged(cfg: ScMoEConfig, k: int,
-                                 page_size: int,
-                                 temperature: float = 0.0,
-                                 eos_token: int = -1,
-                                 kv_dtype: str = "fp",
-                                 attn_kernel: str = "gather",
-                                 tp: int = 1):
-    """Jitted :func:`decode_chunk_slots_paged`: ONE program per (pool
-    shape, k, page_size); the page table is data. Pool donated."""
-    check_tp(cfg, tp)
-    cache_spec(cfg, kv_dtype)
-    if attn_kernel not in ATTN_KERNELS:
-        raise ValueError(
-            f"attn_kernel must be one of {ATTN_KERNELS}, got "
-            f"{attn_kernel!r}")
-    return jax.jit(_program(decode_chunk_slots_paged, cfg=cfg, k=k,
-                            page_size=page_size,
-                            temperature=temperature,
-                            eos_token=eos_token, kv_dtype=kv_dtype,
-                            attn_kernel=attn_kernel),
-                   donate_argnums=(1,))
+# the chunk program and the two factories are the frame's, around this
+# model's step and its six counters (``models/serving.py``)
+decode_chunk_slots_paged = functools.partial(
+    serving.decode_chunk_slots_paged, step=_slot_decode_step_paged,
+    counters=len(STEP_COUNTERS))
+jit_prefill_into_slot_paged = serving.bind(
+    serving.jit_prefill_into_slot_paged, _THIS)
+jit_decode_chunk_slots_paged = serving.bind(
+    serving.jit_decode_chunk_slots_paged, _THIS)

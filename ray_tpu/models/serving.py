@@ -1,4 +1,4 @@
-"""What the serving engine asks of a model: its DESCRIPTION.
+"""The serving FRAME and what it asks of a model: its DESCRIPTION.
 
 :class:`~ray_tpu.serve.engine.DecodeEngine` serves any decoder whose
 config object answers ``cfg.decode_programs()`` with a module (or
@@ -13,9 +13,10 @@ layer in four keeps grouped keys and values in pages, the others a
 fixed recurrent state and a short convolution's tail PER SLOT) and the
 shortcut-connected expert decoder (:mod:`ray_tpu.models.scmoe`: TWO
 latent attentions a layer, so the latent entry counts ``2 * n_layer``
-layers of the one pool; the attention is ``mla_moe``'s, imported).
+layers of the one pool; the attention is ``mla_moe``'s, imported under
+its public names).
 
-A description provides, under these names:
+**A description provides** what only the model knows:
 
 ``cache_spec(cfg, kv_dtype) -> CacheSpec``
     what one token leaves in a page and what a sequence keeps in its
@@ -23,17 +24,20 @@ A description provides, under these names:
     shapes come from: :func:`init_paged_pool`,
     :func:`CacheSpec.bytes_per_page`, :func:`CacheSpec.bytes_per_slot`,
     the engine's handoff shape checks and ``stats()``'s
-    ``kv_bytes_per_token`` / ``state_bytes_per_slot`` all read it.
-``init_paged_cache``, ``kv_bytes_per_page``, ``shard_params``,
-``check_tp``
-    the pool, its page cost, the weights' placement and the (cfg, tp)
-    validation.
-``jit_prefill_into_slot_paged``, ``jit_decode_chunk_slots_paged``
-    the two programs every model has, under the names a device trace
-    shows (``jit_prefill_into_slot_paged(…``).
-``jit_verify_chunk_slots_paged``, ``jit_export_slot_kv_paged``,
-``jit_import_slot_kv_paged``
-    speculative verify and the KV handoff, or absent.
+    ``kv_bytes_per_token`` / ``state_bytes_per_slot`` all read it. It
+    refuses a ``kv_dtype`` the model has not (:func:`check_kv_dtype`).
+``prefill_into_slot_paged(params, cache, tokens, length, hist_len,
+pt_row, cow_src, slot, rng, *, cfg, page_size, temperature, kv_dtype)``
+    one prompt suffix into its pages (and its slot), with the first
+    token's sample: ``(token, cache', rng')``.
+``_slot_decode_step_paged(params, cache, token, active, pt, cfg,
+page_size, kv_dtype, attn_kernel)``
+    ONE masked decode step over the slot pool: ``(logits, cache')``,
+    and a third value, its int32 counters, where ``STEP_COUNTERS`` names
+    any. The module binds the frame's chunk program to it
+    (``decode_chunk_slots_paged = functools.partial(
+    serving.decode_chunk_slots_paged, step=_slot_decode_step_paged,
+    counters=len(STEP_COUNTERS))``).
 ``max_positions(cfg)``
     the longest sequence the model can place (a learned table's rows;
     a rotary model's declared reach).
@@ -52,16 +56,17 @@ A description provides, under these names:
     gathered pages where it cannot), and chooses its recurrence's
     kernel by shape the same way.
 ``decode_attention_fused(cfg, page_size, attn_kernel) -> bool``
-    OPTIONAL: whether the chunk program built with these knobs holds a
-    fused kernel of its decode step's sequence mixing, WHICHEVER that
-    is: ``mla_moe`` answers for its attention over latent pages,
-    ``kda_moe`` for TWO kernels, the recurrence on its per-slot state
-    and its GQA layers' attention over pages, each taken by its own
-    shapes: either one makes the answer true. The engine asks it for
+    whether the chunk program built with these knobs holds a fused
+    kernel of its decode step's sequence mixing, WHICHEVER that is:
+    ``gpt_decode`` answers by the knob (``attn_kernel == "pallas"``),
+    ``mla_moe`` (and ``scmoe``, whose attention it is) for its
+    attention over latent pages, by shape, ``kda_moe`` for TWO kernels,
+    the recurrence on its per-slot state and its GQA layers' attention
+    over pages, each taken by its own shapes: either one makes the
+    answer true. The engine asks it for
     ``warm_up()["attn_kernel_mode"]`` (``"compiled"`` / ``"interpret"``,
     read off the lowered program, or ``None`` without a kernel) and for
-    ``stats()["attn_kernel_dispatches"]``; a description without it
-    (``gpt_decode``) is asked by name: ``attn_kernel == "pallas"``.
+    ``stats()["attn_kernel_dispatches"]``; every description answers.
 ``UNSUPPORTED``
     ``{engine capability: reason}`` for what this model does not get
     (``"int8"``, ``"tp"``, ``"spec_decode"``, ``"roles"``,
@@ -69,16 +74,63 @@ A description provides, under these names:
     A model that lists ``"prefix_cache"`` gets no prefix cache unless
     asked, and the reason when asked.
 ``STEP_COUNTERS``
-    names of the int32 counters the chunk program returns as a fifth
-    output, summed over the chunk's steps (``()``: four outputs); the
-    engine adds them into ``stats()`` under those names.
+    names of the int32 counters its step returns, which the chunk
+    program sums over its steps into a fifth output (``()``: four
+    outputs); the engine adds them into ``stats()`` under those names.
+``jit_verify_chunk_slots_paged``, ``jit_export_slot_kv_paged``,
+``jit_import_slot_kv_paged``
+    speculative verify and the KV handoff, or absent (GPT alone).
+``check_tp``, ``shard_params``, ``shard_cache``, ``shard_program``
+    a model WITH tensor-parallel programs (GPT alone) states its own
+    placement: ``check_tp(cfg, tp)`` answers the mesh (``None`` for
+    ``tp == 1``), ``shard_params`` / ``shard_cache`` place the weights
+    and the pool on it, and ``shard_program(fn, mesh, n_out, ...)``
+    wraps a program of ``(params, cache, *rest)`` for it. That wrapper
+    is ALL a mesh adds to the frame's two factories.
+
+**The frame provides**, once, for every description (a module takes
+them under its own names with :func:`bind`, so the engine, the
+benchmark and the tests call ``<module>.jit_prefill_into_slot_paged(
+cfg, ...)`` as before):
+
+- the chunk program (:func:`decode_chunk_slots_paged`): ONE
+  ``lax.scan`` of a description's step with per-slot sampling
+  (:func:`sample_slots`), the EOS mask-and-carry and the counters;
+- the two factories every model has
+  (:func:`jit_prefill_into_slot_paged`,
+  :func:`jit_decode_chunk_slots_paged`): the knobs checked against the
+  description (``KV_DTYPES``, ``ATTN_KERNELS``, ``check_tp``), the
+  program named for a trace (:func:`program`: the XLA modules are
+  ``jit_prefill_into_slot_paged`` and ``jit_decode_chunk_slots_paged``
+  whatever the model), the pool donated, one jit wrapper a key
+  (:func:`knob_cache`; the key holds the description);
+- what follows from the spec: :func:`init_paged_cache`,
+  :func:`kv_bytes_per_page`, and the :func:`check_tp` /
+  :func:`shard_params` of a model that lists ``"tp"`` under
+  ``UNSUPPORTED``;
+- sampling (:func:`sample`, :func:`sample_slots`) and the paged pool's
+  addressing that more than one model uses: :data:`PT_SENTINEL`,
+  :func:`init_paged_pool`, :func:`flat`, :func:`at_layer`,
+  :func:`live_length`, and a prefill's read of its cached prefix
+  (:func:`hist_blocks`, :func:`attend_history`).
+
+No module under ``ray_tpu/models`` imports or reads an underscore name
+of another (``tests/test_models_frame.py`` holds it; the training
+step's ``_mm``, ``_rmsnorm``, ``_project_vocab`` of ``models/gpt.py``
+are the listed exemption). Block math the expert decoders share
+(``rmsnorm``, ``embed``, ``head``, ``block_ffn``) lives beside the
+expert layer in :mod:`ray_tpu.models.moe`.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+import inspect
 from typing import Any, Dict, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 #: Page-table padding value. Positive and far beyond any real pool size,
 #: so a sentinel is out-of-bounds for scatter (write DROPPED, never
@@ -178,3 +230,374 @@ def decode_programs(cfg):
         raise TypeError(
             f"{type(cfg).__name__} does not describe a decoder the "
             f"engine can serve: it has no decode_programs()") from None
+
+
+# ------------------------------------------------- programs and sampling
+def knob_cache(fn):
+    """``lru_cache`` with DEFAULT-NORMALIZED keys: ``f(cfg)``,
+    ``f(cfg, tp=1)`` and ``f(cfg, ..., 1)`` all land on the SAME cache
+    entry. The engine threads every static knob positionally (including
+    default-valued ones like ``tp=1``), while tests and external
+    callers omit trailing defaults — a raw ``lru_cache`` would key
+    those spellings separately, silently doubling the compiled-program
+    set and breaking the recompile guards' wrapper ``is``-identity.
+    256 entries: the frame's two factories hold every description's
+    wrappers (64 each of four)."""
+    sig = inspect.signature(fn)
+    cached = functools.lru_cache(maxsize=256)(fn)
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        bound = sig.bind(*args, **kwargs)
+        bound.apply_defaults()
+        return cached(*bound.args, **bound.kwargs)
+
+    wrapper.cache_info = cached.cache_info
+    wrapper.cache_clear = cached.cache_clear
+    return wrapper
+
+
+def program(fn, name: Optional[str] = None, **knobs):
+    """``fn`` with its static knobs bound, under a ``__name__`` of its
+    own: ``jax.jit`` calls the XLA module ``jit_<name>``, and that is
+    what a profile shows for every launch (``jit__unknown`` for a
+    ``functools.partial``, ``jit_fn`` for a local closure). ``name``
+    defaults to ``fn``'s; every ``jit_<x>`` factory compiles programs
+    named ``jit_<x>``, whatever its model and its mesh."""
+    def program(*args):
+        return fn(*args, **knobs)
+
+    program.__name__ = program.__qualname__ = name or fn.__name__
+    return program
+
+
+def sample(logits, temperature: float, key):
+    """One sampling decision; greedy iff temperature == 0 (static)."""
+    if temperature > 0.0:
+        key, sub = jax.random.split(key)
+        token = jax.random.categorical(
+            sub, logits / temperature, axis=-1).astype(jnp.int32)
+    else:
+        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return token, key
+
+
+def sample_slots(logits, temperature: float, keys):
+    """Per-slot sampling with independent PRNG lanes: each slot's key
+    chain splits exactly like :func:`sample`'s, so a slot's stream is
+    reproducible from its seed regardless of which other slots share the
+    pool or when it was admitted."""
+    if temperature > 0.0:
+        split = jax.vmap(jax.random.split)(keys)   # [B, 2, 2]
+        keys, subs = split[:, 0], split[:, 1]
+        token = jax.vmap(lambda s, lg: jax.random.categorical(
+            s, lg / temperature, axis=-1))(subs, logits).astype(jnp.int32)
+    else:
+        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return token, keys
+
+
+# ------------------------------------------------- the pool's addressing
+def flat(pool: jax.Array) -> jax.Array:
+    """``[L, n_pages, ...]`` viewed as ``[L * n_pages, ...]``: layer
+    ``l`` addresses page ``p`` at ``l * n_pages + p`` and no layer's
+    pool is sliced out of the stacked one."""
+    return pool.reshape((-1,) + pool.shape[2:])
+
+
+def at_layer(pages, l: int, n_pages: int):
+    """Page ids of one layer in the flat pool; sentinels (and anything
+    out of bounds) stay out of bounds."""
+    return jnp.where((pages >= 0) & (pages < n_pages),
+                     pages + l * n_pages, jnp.int32(PT_SENTINEL))
+
+
+def live_length(pt, pos, active, n_pages: int, page_size: int):
+    """Tokens of each lane a decode kernel reads: positions <= ``pos``
+    inside the mapped prefix of the lane's table row (the engine maps a
+    lane's pages from column 0 without holes); 0 for an inactive lane or
+    a row of sentinels."""
+    max_pages = pt.shape[1]
+    mapped = jnp.min(jnp.where((pt >= 0) & (pt < n_pages),
+                               jnp.int32(max_pages),
+                               jnp.arange(max_pages, dtype=jnp.int32)),
+                     axis=1)
+    return jnp.where(active, jnp.minimum(pos.astype(jnp.int32) + 1,
+                                         mapped * page_size), 0)
+
+
+#: Tokens of cached prefix a paged prefill reads at once
+#: (:func:`attend_history`; ``256 // page_size`` pages, one page where a
+#: page is larger). A prefill pays for whole blocks, so a hit costs at
+#: most 255 masked keys more than it is long; larger blocks bought
+#: nothing on a v5e: three latent attentions at A.X-K1's widths, bucket
+#: 512, hits of 192 / 512 / 1,024 / 1,536 tokens took 11.1 / 11.9 / 13.3
+#: / 14.8 ms at 256 and 12.2 / 12.2 / 13.7 / 15.3 at 512 (10.4 without
+#: a hit; the ``max_len``-wide view 16.6 whatever the hit), six layers
+#: of the GPT block 6.7-7.3 at either (PERF.md, PR 48).
+HIST_BLOCK_TOKENS = 256
+
+
+def hist_blocks(pt_row: jax.Array, n_pages: int, page_size: int):
+    """How a prefill reads its cached prefix: ``(T, pages)``, the tokens
+    a block holds (:data:`HIST_BLOCK_TOKENS` in whole pages, at most
+    the row) and ``pages(j, layer)``, block ``j``'s pages of one layer
+    in the stacked pool's flat view. The page table's row is clipped
+    into the pool (sentinels name page ``n_pages - 1``: their positions
+    are past any ``hist_len`` and masked) and padded to whole blocks."""
+    bp = max(1, min(HIST_BLOCK_TOKENS // page_size, pt_row.shape[0]))
+    ptc = jnp.pad(jnp.clip(pt_row, 0, n_pages - 1),
+                  (0, -pt_row.shape[0] % bp))
+
+    def pages(j, layer):
+        return lax.dynamic_slice(ptc, (j * bp,), (bp,)) + layer * n_pages
+
+    return bp * page_size, pages
+
+
+def attend_history(lg_s, v_s, hist_len, block_tokens: int, block):
+    """A prefill's attention over its own rows AND the ``hist_len``
+    cached tokens before them, in ONE softmax: ``lg_s`` ``[B, H, S, S]``
+    float32 are the rows' scaled scores against themselves, masked
+    causally, ``v_s`` ``[B, S, H, v]`` their values. The prefix is read
+    in blocks of ``block_tokens``: ``block(j) -> (scores [B, H, S, T]
+    float32, scaled; values [B, T, H, v])`` of tokens ``j * T ..``,
+    each block's scores masked at ``hist_len``, under loops of
+    ``ceil(hist_len / T)`` trips. Two passes, so that every probability
+    is the one softmax's own, divided by the whole sum BEFORE it is
+    rounded to the values' dtype (a running weighted sum rounds first,
+    the decode kernels' difference; it parted a hit's greedy tokens
+    from the whole prefill's): the first folds the blocks' scores into
+    the running (max, sum) that start at the rows' own max, the second
+    adds each block's ``probs . V`` to the rows' own in float32. A hit
+    differs from the view that gathered ``max_len`` keys by the order of
+    float32 sums. NO trip without a hit: nothing is read, and the result
+    is ``softmax(lg_s) . v_s`` to the bit. Returns ``[B, S, H, v]``
+    float32."""
+    T = block_tokens
+    n = (hist_len + T - 1) // T
+
+    def scores(j):
+        s, v = block(j)
+        return jnp.where(j * T + jnp.arange(T) < hist_len, s, -1e30), v
+
+    def stats(j, carry):
+        m, l = carry
+        with jax.named_scope("prefill.history"):
+            s, _ = scores(j)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            l = jnp.exp(m - m_new) * l + jnp.sum(
+                jnp.exp(s - m_new), axis=-1, keepdims=True)
+        return m_new, l
+
+    m_s = jnp.max(lg_s, axis=-1, keepdims=True)
+    m, l_h = lax.fori_loop(0, n, stats, (m_s, jnp.zeros_like(m_s)))
+    e_s = jnp.exp(lg_s - m)
+    l = jnp.sum(e_s, axis=-1, keepdims=True) + l_h
+
+    def weigh(j, acc):
+        with jax.named_scope("prefill.history"):
+            s, v = scores(j)
+            return acc + jnp.einsum(
+                "bhqk,bkhd->bqhd", (jnp.exp(s - m) / l).astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+
+    return lax.fori_loop(0, n, weigh, jnp.einsum(
+        "bhqk,bkhd->bqhd", (e_s / l).astype(v_s.dtype), v_s,
+        preferred_element_type=jnp.float32))
+
+
+# ------------------------------------------------------ the chunk program
+def decode_chunk_slots_paged(params, cache, token: jax.Array,
+                             rngs: jax.Array, active: jax.Array,
+                             pt: jax.Array, *, step, counters: int, cfg,
+                             k: int, page_size: int,
+                             temperature: float = 0.0,
+                             eos_token: int = -1, kv_dtype: str = "fp",
+                             attn_kernel: str = "gather", **step_knobs):
+    """k fused decode steps over the slot pool in ONE program, whatever
+    the model: a ``lax.scan`` of a description's ``step``
+    (``_slot_decode_step_paged``; ``step_knobs`` are its own, a mesh
+    axis), decoding only slots where ``active`` is set, with the page
+    table held constant through the chunk (the engine maps pages
+    covering ``pos + k`` before dispatching — a slot that cannot be
+    covered is parked out of ``active`` instead).
+
+    ``token`` ``[B_slots]`` is each slot's last emitted token, ``rngs``
+    ``[B_slots, 2]`` its PRNG lane (:func:`sample_slots`), ``active``
+    ``[B_slots]`` the chunk-static admission mask (admission happens at
+    chunk boundaries, so the mask never changes inside a dispatch). The
+    cache (pages, and whatever a sequence keeps in its slot) is the
+    scan's carry, donated: a step updates it in place. EOS lanes
+    mask-and-carry: once a lane samples ``eos_token`` (or was fed it)
+    it keeps emitting it, and the ENGINE frees the slot at the chunk
+    boundary, which is what turns mask-and-carry into slot reuse.
+    Returns ``(tokens [B_slots, k], cache', done [B_slots], rngs')``
+    and, where the description counts (``counters`` of
+    ``STEP_COUNTERS``, a third value of its step), ``counts int32
+    [counters]`` summed over the k steps, at no launch of their own;
+    rows of inactive slots are garbage. ``kv_dtype`` / ``attn_kernel``
+    are STATIC knobs baked into the compiled program, never retrace
+    triggers."""
+    B = token.shape[0]
+    eos = jnp.asarray(eos_token, jnp.int32)
+    done0 = (active & (token == eos)) if eos_token >= 0 \
+        else jnp.zeros((B,), jnp.bool_)
+
+    def body(carry, _):
+        cache, tok, done, keys, *counts = carry
+        logits, cache, *c = step(
+            params, cache, tok, active, pt, cfg, page_size, kv_dtype,
+            attn_kernel, **step_knobs)
+        nxt, keys = sample_slots(logits, temperature, keys)
+        if eos_token >= 0:
+            nxt = jnp.where(done, eos, nxt)
+            done = done | (active & (nxt == eos))
+        return (cache, nxt, done, keys,
+                *(n + m for n, m in zip(counts, c))), nxt
+
+    # a description without counters carries none: four outputs
+    counts0 = (jnp.zeros((counters,), jnp.int32),) if counters else ()
+    (cache, _, done, rngs, *counts), toks = lax.scan(
+        body, (cache, token, done0, rngs, *counts0), None, length=k)
+    return (jnp.moveaxis(toks, 0, 1), cache, done, rngs, *counts)
+
+
+# ----------------------------------- what follows from a description
+def bind(fn, model):
+    """``fn`` of this frame (one that takes ``model=``) as the
+    description ``model``'s own: a module says ``init_paged_cache =
+    bind(serving.init_paged_cache, sys.modules[__name__])`` and is
+    called, and reads to ``inspect.signature``, as before. A cached
+    factory keeps its ``cache_info`` / ``cache_clear``, which are the
+    frame's: every description's wrappers of that factory."""
+    bound = functools.update_wrapper(functools.partial(fn, model=model), fn)
+    sig = inspect.signature(fn)
+    bound.__signature__ = sig.replace(parameters=[
+        p for p in sig.parameters.values() if p.name != "model"])
+    return bound
+
+
+def check_kv_dtype(model, kv_dtype: str):
+    """Refuse a pool storage type the description has not, with its
+    reason for having no quantised layout where it states one."""
+    if kv_dtype not in model.KV_DTYPES:
+        reason = model.UNSUPPORTED.get("int8")
+        raise ValueError(
+            f"kv_dtype must be one of {model.KV_DTYPES}, got {kv_dtype!r}"
+            + (f": {reason}" if reason else ""))
+
+
+def kv_bytes_per_page(cfg, page_size: int, kv_dtype: str = "fp", *,
+                      model) -> int:
+    """HBM bytes ONE physical page costs across all layers — the unit
+    the engine's page budget is denominated in
+    (:meth:`CacheSpec.bytes_per_page` of the description's spec)."""
+    return model.cache_spec(cfg, kv_dtype).bytes_per_page(page_size)
+
+
+def init_paged_cache(cfg, slots: int, n_pages: int, page_size: int,
+                     kv_dtype: str = "fp", tp: int = 1, *, model):
+    """The description's pool, zeroed (:func:`init_paged_pool` of its
+    spec: pages, per-slot entries and ``pos``), placed on its mesh
+    where it has one."""
+    mesh = model.check_tp(cfg, tp)
+    cache = init_paged_pool(model.cache_spec(cfg, kv_dtype), slots,
+                            n_pages, page_size)
+    return cache if mesh is None else model.shard_cache(cache, mesh)
+
+
+def check_tp(cfg, tp: int, *, model):
+    """The (cfg, tp) validation of a model that lists ``"tp"`` under
+    ``UNSUPPORTED``: no mesh, and its reason past one device."""
+    if int(tp) > 1:
+        raise ValueError(f"tp={tp}: " + model.UNSUPPORTED["tp"])
+    return None
+
+
+def shard_params(params, cfg, tp: int, *, model):
+    """The weights' placement of a model without a mesh: as they are."""
+    model.check_tp(cfg, tp)
+    return params
+
+
+# rtlint: program-budget: 1
+def jit_program(model, fn, name: str, mesh, n_out: int, *,
+                cache_out: int = 1, check_vma: bool = True, **knobs):
+    """``fn(params, cache, ...)`` of the description ``model`` jitted
+    with its static ``knobs`` bound, named ``name`` for a trace
+    (:func:`program`) and the pool donated; with a mesh, the same
+    function under the description's ``shard_program`` (``n_out``
+    values, the cache at ``cache_out``), which is all a mesh adds. One
+    jit wrapper a call: the calling factory's cache and budget count
+    the programs."""
+    if mesh is not None:
+        fn, knobs = model.shard_program(
+            functools.partial(fn, **knobs, tp_axis="tp"), mesh, n_out,
+            cache_out, check_vma), {}
+    return jax.jit(program(fn, name, **knobs), donate_argnums=(1,))
+
+
+def _checked_mesh(model, cfg, kv_dtype, tp, attn_kernel=None):
+    """The factories' validation of their knobs against the
+    description; returns its mesh (``None`` without one)."""
+    check_kv_dtype(model, kv_dtype)
+    if attn_kernel is not None and attn_kernel not in model.ATTN_KERNELS:
+        raise ValueError(
+            f"attn_kernel must be one of {model.ATTN_KERNELS}, got "
+            f"{attn_kernel!r}")
+    return model.check_tp(cfg, tp)
+
+
+# rtlint: program-budget: len(prompt_buckets)
+@knob_cache
+def jit_prefill_into_slot_paged(cfg, page_size: int,
+                                temperature: float = 0.0,
+                                kv_dtype: str = "fp", tp: int = 1, *,
+                                model):
+    """Jitted ``model.prefill_into_slot_paged``; one compiled program
+    per SUFFIX bucket per (model, cfg, page_size, temperature, kv_dtype,
+    tp) key — prefix-hit depth (``hist_len``), page-table contents, and
+    COW source are all traced, so shared-prefix admission never
+    retraces. ``kv_dtype`` is an engine-level static baked into the
+    same program set (it changes the pool layout, not the program
+    COUNT). Cached on the static knobs so every engine for the same
+    knobs shares one wrapper (and its trace cache). The pool cache is
+    donated: the engine holds the only reference and immediately
+    rebinds the returned cache, so on TPU the update is in-place
+    instead of a full-pool copy (CPU ignores donation). With a mesh
+    (``tp > 1`` of a description that has one) the same inner function
+    runs under its ``shard_program``."""
+    mesh = _checked_mesh(model, cfg, kv_dtype, tp)
+    return jit_program(
+        model, model.prefill_into_slot_paged, "prefill_into_slot_paged",
+        mesh, 3, cfg=cfg, page_size=page_size, temperature=temperature,
+        kv_dtype=kv_dtype)
+
+
+# rtlint: program-budget: 1
+@knob_cache
+def jit_decode_chunk_slots_paged(cfg, k: int, page_size: int,
+                                 temperature: float = 0.0,
+                                 eos_token: int = -1,
+                                 kv_dtype: str = "fp",
+                                 attn_kernel: str = "gather",
+                                 tp: int = 1, *, model):
+    """Jitted ``model.decode_chunk_slots_paged`` (the frame's chunk
+    program around the description's step): ONE program per (model,
+    pool shape, k, page_size, tp) — the page table is data, and the
+    ``kv_dtype``/``attn_kernel`` knobs are engine-level statics that
+    select WHICH one program is built, never additional ones. Pool
+    donated."""
+    mesh = _checked_mesh(model, cfg, kv_dtype, tp, attn_kernel)
+    return jit_program(
+        model, model.decode_chunk_slots_paged, "decode_chunk_slots_paged",
+        mesh, 4 + bool(model.STEP_COUNTERS),
+        # pallas_call's out_shape carries no vma annotation, which
+        # strict shard_map rejects (and the interpreter's own slicing
+        # trips the same check on the CPU): a program that holds a
+        # kernel runs unchecked, like the flash kernel's.
+        check_vma=not model.decode_attention_fused(cfg, page_size,
+                                                   attn_kernel),
+        cfg=cfg, k=k, page_size=page_size, temperature=temperature,
+        eos_token=eos_token, kv_dtype=kv_dtype, attn_kernel=attn_kernel)

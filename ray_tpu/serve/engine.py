@@ -746,11 +746,9 @@ class DecodeEngine:
             cfg, self.chunk, self.page_size, self.temperature,
             self.eos_token, self.kv_dtype, self.attn_kernel, self.tp)
         # Whether that program holds a fused attention kernel: the
-        # description's word where it has one (models/serving.py), else
-        # the knob's name.
-        fused = getattr(self._model, "decode_attention_fused", None)
-        self._attn_fused = self.attn_kernel == "pallas" if fused is None \
-            else bool(fused(cfg, self.page_size, self.attn_kernel))
+        # description's word (models/serving.py).
+        self._attn_fused = bool(self._model.decode_attention_fused(
+            cfg, self.page_size, self.attn_kernel))
         self._export = self._import = None
         if "roles" not in self._model.UNSUPPORTED:
             self._export = self._model.jit_export_slot_kv_paged(

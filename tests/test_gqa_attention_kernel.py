@@ -33,7 +33,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import kda_moe as km
+from ray_tpu.models import kda_moe as km, serving
 
 #: 8 query heads over 2 KV heads (groups of 4), then the cell's group
 #: of 8 over one head more than a power of two.
@@ -108,7 +108,7 @@ def _both(cfg, q, kpool, vpool, pt, pos, active, ps):
     """(kernel, XLA body, length) on the operands the step gives."""
     n_pages = kpool.shape[0]
     pages = jnp.clip(jnp.asarray(pt), 0, n_pages - 1)
-    length = km._live_length(jnp.asarray(pt), jnp.asarray(pos),
+    length = serving.live_length(jnp.asarray(pt), jnp.asarray(pos),
                              jnp.asarray(active), n_pages, ps)
     out = km._gqa_attention_pallas(q, kpool, vpool, pages, length, cfg, ps)
     ref = km._gqa_attention_gather(q, kpool, vpool, pages,

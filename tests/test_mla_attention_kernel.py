@@ -27,7 +27,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import mla_moe as mm
+from ray_tpu.models import mla_moe as mm, serving
 
 CFG = mm.CONFIGS["nano"]
 T = mm._ATTN_BLOCK_TOKENS
@@ -74,7 +74,7 @@ def _both(q, pool, pt, pos, active, ps):
     """(kernel, XLA body) on the operands the step gives them."""
     n_pages = pool.shape[0]
     pages = jnp.clip(jnp.asarray(pt), 0, n_pages - 1)
-    length = mm._live_length(jnp.asarray(pt), jnp.asarray(pos),
+    length = serving.live_length(jnp.asarray(pt), jnp.asarray(pos),
                              jnp.asarray(active), n_pages, ps)
     out = mm._latent_attention_pallas(q, pool, pages, length, CFG, ps)
     ref = mm._latent_attention_gather(q, pool, pages, jnp.asarray(pos),
@@ -125,7 +125,7 @@ def test_a_mapped_prefix_shorter_than_pos_cuts_the_length():
     pt[0, :2] = [1, 0]
     pt[1, :1] = [2]
     pt[1, 2] = 3                     # behind a hole: never read
-    length = mm._live_length(jnp.asarray(pt), jnp.asarray([20, 9]),
+    length = serving.live_length(jnp.asarray(pt), jnp.asarray([20, 9]),
                              jnp.asarray([True, True]), 5, ps)
     assert list(np.asarray(length)) == [8, 4]
 

@@ -1,5 +1,5 @@
 """A paged prefill's attention reads the history it was given, not
-``max_len`` of it (``gpt_decode._attend_history``): the suffix attends
+``max_len`` of it (``serving.attend_history``): the suffix attends
 over itself, and the cached prefix is read through the page table a
 block at a time under a loop of ``ceil(hist_len / block)`` trips.
 
@@ -22,7 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import gpt, gpt_decode as gd, mla_moe, scmoe
+from ray_tpu.models import (gpt, gpt_decode as gd, mla_moe, scmoe,
+                            serving)
 from ray_tpu.models.serving import PT_SENTINEL
 
 PS = 8                     # tokens a page
@@ -30,7 +31,7 @@ MAX_LEN = 1024             # the slot's reach: 128 pages
 N_PAGES = 3 * MAX_LEN // PS
 BUCKET = 32                # the suffix's bucket
 SUFFIX = 27                # its live rows
-BLOCK = gd._HIST_BLOCK_TOKENS
+BLOCK = serving.HIST_BLOCK_TOKENS
 #: a hit of one partial block, of exactly one block, of several blocks
 #: (and a partial last one), and one that ends mid-page: its last page
 #: is forked, not shared
@@ -110,7 +111,7 @@ def _oracle_gpt(params, cache, tokens, length, hist_len, pt_row, cow_src,
     x = gd._rmsnorm(x, params["ln_f_scale"])
     x_last = lax.dynamic_slice(x, (0, length - 1, 0), (1, 1, cfg.d_model))
     logits = gd._project_vocab(x_last, params["embed"]["kernel"], cfg)
-    token, rng = gd._sample(logits[:, 0], temperature, rng)
+    token, rng = serving.sample(logits[:, 0], temperature, rng)
     pos = lax.dynamic_update_slice(
         cache["pos"], jnp.reshape(hist_len + length, (1,)), (slot,))
     if quant:
@@ -136,7 +137,7 @@ def _oracle_gpt(params, cache, tokens, length, hist_len, pt_row, cow_src,
 
 def _oracle_latent_attention(cache, S, length, hist_len, pt_row, cow_src,
                              cfg, page_size):
-    """``mla_moe._prefill_attention`` as it was: every attention gathers
+    """``mla_moe.prefill_attention`` as it was: every attention gathers
     the slot's whole page table, ``V = max_len`` latent rows, and
     materialises keys and values for all ``V + S`` of them."""
     ps = page_size
@@ -144,7 +145,7 @@ def _oracle_latent_attention(cache, S, length, hist_len, pt_row, cow_src,
     max_pages = pt_row.shape[0]
     V = max_pages * ps
     positions = hist_len + jnp.arange(S)
-    pool = mla_moe._flat(cache["latent"])
+    pool = serving.flat(cache["latent"])
     layers = jnp.arange(A, dtype=jnp.int32) * n_pages
     dst = pt_row[jnp.clip(hist_len // ps, 0, max_pages - 1)]
     dst_w = jnp.where((cow_src < n_pages) & (dst < n_pages),
@@ -183,9 +184,9 @@ def _oracle_latent_attention(cache, S, length, hist_len, pt_row, cow_src,
         att = jnp.einsum("bhqk,bkhv->bqhv", probs, v,
                          preferred_element_type=jnp.float32
                          ).astype(cfg.dtype).reshape(1, S, -1)
-        x = x + mla_moe._mm(att, p["wo"]["kernel"], cfg.dtype
-                            ).astype(x.dtype)
-        return x, pool.at[mla_moe._at_layer(page_w, a, n_pages),
+        x = x + gpt._mm(att, p["wo"]["kernel"], cfg.dtype
+                        ).astype(x.dtype)
+        return x, pool.at[serving.at_layer(page_w, a, n_pages),
                           positions % ps].set(ent[0], mode="drop")
 
     return pool, live, attend
@@ -251,25 +252,23 @@ def _build(name, dtype):
         def old(*args, **kw):
             # the module's own frame around the oracle's attention
             with pytest.MonkeyPatch.context() as mp:
-                mp.setattr(desc, "_prefill_attention",
+                mp.setattr(desc, "prefill_attention",
                            _oracle_latent_attention)
                 return new(*args, **kw)
 
         init = lambda: desc.init_paged_cache(cfg, 2, N_PAGES, PS)
     knobs = dict(cfg=cfg, page_size=PS, kv_dtype=kv_dtype)
     return Case(name, cfg, params, kv_dtype,
-                jax.jit(gd._program(new, "new", **knobs)),
-                jax.jit(gd._program(old, "old", **knobs)), init)
+                jax.jit(serving.program(new, "new", **knobs)),
+                jax.jit(serving.program(old, "old", **knobs)), init)
 
 
 @pytest.fixture(scope="module")
 def logits_out():
-    """While this module's programs are traced, ``_sample`` hands back
-    the logits (``gpt_decode``'s, and ``mla_moe``'s for both latent
-    models)."""
+    """While this module's programs are traced, the frame's ``sample``
+    hands back the logits (every description reads it off ``serving``)."""
     with pytest.MonkeyPatch.context() as mp:
-        for mod in (gd, mla_moe):
-            mp.setattr(mod, "_sample", _logits_for_token)
+        mp.setattr(serving, "sample", _logits_for_token)
         yield
 
 

@@ -397,6 +397,71 @@ def test_rt109_budget_exceeded_then_raised(tmp_path):
     assert not _run_engine_scoped(tmp_path, fixed).findings
 
 
+def test_descriptions_are_found_by_rule_and_the_frame_is_in_scope(
+        tmp_path):
+    """A module of ``ray_tpu/models`` that defines ``cache_spec`` is a
+    description: rtflow's alias resolution and budget scope and rtsan's
+    dispatch wrap find all four by that one rule (``scmoe``, which no
+    list named, among them); the engine's ``self._model.jit_x(...)``
+    resolves to the FRAME's def of a factory a description only binds;
+    and RT109 fires on a factory in the frame without its declaration."""
+    from tools.rtlint.callgraph import (CallGraph, description_names,
+                                        is_description)
+    from tools.rtlint.core import Module, collect_files
+    from tools.rtlint.flow import in_budget_scope
+
+    models = os.path.join(REPO, "ray_tpu", "models")
+    assert description_names(models) == [
+        "gpt_decode", "kda_moe", "mla_moe", "scmoe"]
+    mods = [Module(ap, os.path.relpath(ap, REPO), open(ap).read())
+            for ap, _ in collect_files([os.path.join(REPO, "ray_tpu")])]
+    by_name = {os.path.basename(m.relpath): m for m in mods
+               if "/models/" in m.relpath}
+    assert [n for n, m in sorted(by_name.items()) if is_description(m)] \
+        == ["gpt_decode.py", "kda_moe.py", "mla_moe.py", "scmoe.py"]
+    assert all(in_budget_scope(by_name[n]) for n in (
+        "scmoe.py", "kda_moe.py", "mla_moe.py", "gpt_decode.py",
+        "serving.py"))
+    assert not in_budget_scope(by_name["gpt.py"])
+    g = CallGraph.build(mods)
+    built = [e.callee for e in g.edges if e.caller
+             == "ray_tpu/serve/engine.py::DecodeEngine._build_pool"]
+    for factory in ("jit_prefill_into_slot_paged",
+                    "jit_decode_chunk_slots_paged"):
+        assert f"ray_tpu/models/serving.py::{factory}" in built
+
+    # the sanitizer wraps the same four, each under its own names
+    # (conftest's session sanitizer already has, unless RT_SAN=0)
+    import importlib
+
+    from tools.rtsan.core import Sanitizer
+    san = Sanitizer()
+    try:
+        san._wrap_jit_factories()
+        for name in description_names(models):
+            desc = importlib.import_module(f"ray_tpu.models.{name}")
+            for factory in ("jit_prefill_into_slot_paged",
+                            "jit_decode_chunk_slots_paged"):
+                assert getattr(getattr(desc, factory), "__rtsan__", False)
+    finally:
+        for module, name, orig in reversed(san._factory_patches):
+            setattr(module, name, orig)
+
+    frame = tmp_path / "models"
+    frame.mkdir()
+    (frame / "serving.py").write_text(
+        "import jax\n"
+        "# rtlint: program-budget: 1\n"
+        "def jit_decode_chunk_slots_paged(cfg, *, model):\n"
+        "    return jax.jit(model.decode_chunk_slots_paged)\n"
+        "def jit_prefill_into_slot_paged(cfg, *, model):\n"
+        "    return jax.jit(model.prefill_into_slot_paged)\n")
+    report = run_paths([str(frame / "serving.py")])
+    assert [(f.rule, f.key.rsplit(":", 1)[-1]) for f in report.findings] \
+        == [("RT109", "jit_prefill_into_slot_paged.budget_missing")], \
+        [f.render() for f in report.findings]
+
+
 def test_rt110_holds_checked_at_edges(tmp_path):
     src = (
         "import threading\n"
@@ -584,12 +649,13 @@ def test_engine_declared_budget_matches_actual_nano():
         # The verify budget is declared separately (spec engines).
         assert parse_budget(
             decls["DecodeEngine._bind_verify"][1]).evaluate(env) == 1
-        # And the factory-level declarations in gpt_decode parse and
-        # cover the factories' per-site bounds.
+        # And the factory-level declarations in the frame, where the
+        # two factories every model binds are defined, parse and cover
+        # the factories' per-site bounds.
         gsrc = open(os.path.join(REPO, "ray_tpu", "models",
-                                 "gpt_decode.py")).read()
+                                 "serving.py")).read()
         gdecls = declared_budgets(
-            Module("gpt_decode.py", "models/gpt_decode.py", gsrc))
+            Module("serving.py", "models/serving.py", gsrc))
         assert parse_budget(gdecls["jit_prefill_into_slot_paged"][1]
                             ).evaluate(env) == len(buckets)
         assert parse_budget(gdecls["jit_decode_chunk_slots_paged"][1]

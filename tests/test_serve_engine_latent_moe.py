@@ -392,22 +392,6 @@ def test_a_page_mosaic_cannot_address_takes_the_fallback(model,
         eng.shutdown()
 
 
-def test_a_description_without_the_entry_is_asked_by_name():
-    """``gpt_decode`` provides no ``decode_attention_fused``: its
-    engine's test of the knob's name stands."""
-    assert not hasattr(gpt_decode, "decode_attention_fused")
-    cfg = gpt.CONFIGS["nano"]
-    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-    for kernel, fused in (("gather", False), ("pallas", True)):
-        eng = DecodeEngine(params, cfg, slots=2, chunk=2, max_len=32,
-                           prompt_buckets=(8,), page_size=8,
-                           attn_kernel=kernel, auto_start=False)
-        try:
-            assert eng._attn_fused is fused
-        finally:
-            eng.shutdown()
-
-
 def test_deferred_delivery_hands_every_lane_the_walks_messages(model,
                                                                engine):
     """ISSUE 43 through this model's programs: the slices, the ends and

@@ -255,7 +255,7 @@ def test_the_programs_keep_the_names_a_trace_shows_and_hold_the_kernel(
     assert scmoe.jit_prefill_into_slot_paged(
         cfg, 4).__wrapped__.__name__ == "prefill_into_slot_paged"
     # the attention is mla_moe's own, imported: one kernel body
-    assert scmoe._decode_attention is mla_moe._decode_attention
+    assert scmoe.decode_attention is mla_moe.decode_attention
     eng = DecodeEngine(params, cfg, slots=2, chunk=2, max_len=48,
                        prompt_buckets=(16,), page_size=4, n_pages=30)
     try:

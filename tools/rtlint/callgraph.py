@@ -20,7 +20,10 @@ rtlint analysis, and resolves exactly the idioms this repo uses:
   it assigned ``self._model = <x>.decode_programs(cfg)``: the served
   model's DESCRIPTION (``models/serving.py``), which every model
   answers with a module of the same factory names and budgets, so the
-  call resolves against the first of :data:`DESCRIPTION_MODULES`;
+  call resolves against the first description of the analyzed set
+  (:func:`is_description`: found by rule, not listed), and a name the
+  description only BINDS (``jit_x = serving.bind(serving.jit_x, ...)``)
+  against the frame's def of it (:data:`FRAME_MODULE`);
 - **constructors**: ``Cls(...)`` → ``Cls.__init__``;
 - **driver registration**: ``threading.Thread(target=self._run)`` (and
   any ``*Thread(target=...)``) becomes an edge of ``kind="thread"`` —
@@ -43,6 +46,7 @@ false negatives, not noise.
 from __future__ import annotations
 
 import ast
+import os
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -52,10 +56,36 @@ from .annotations import LOCKISH_RE
 from .core import Module
 
 
-#: Modules a config object's ``decode_programs()`` may answer with, in
-#: the order a ``self.X = <...>.decode_programs(cfg)`` alias tries them.
-DESCRIPTION_MODULES = ("models/gpt_decode.py", "models/mla_moe.py",
-                       "models/kda_moe.py")
+#: The serving frame: where the factories every description binds are
+#: DEFINED, with their ``program-budget`` declarations.
+FRAME_MODULE = "models/serving.py"
+
+
+def is_description(mod: Module) -> bool:
+    """THE rule, stated once: a module of ``ray_tpu/models`` that
+    defines ``cache_spec`` is a model DESCRIPTION, what a config
+    object's ``decode_programs()`` may answer with
+    (``models/serving.py``). rtflow's alias resolution and budget scope
+    and rtsan's dispatch wrap all read it, so a new model is seen by
+    all three the day its module exists."""
+    return os.path.basename(os.path.dirname(mod.relpath)) == "models" \
+        and any(isinstance(n, ast.FunctionDef) and n.name == "cache_spec"
+                for n in mod.tree.body)
+
+
+def description_names(models_dir: str) -> List[str]:
+    """Module names of the descriptions among ``models_dir/*.py``
+    (:func:`is_description`), sorted: for a caller that imports them
+    (rtsan) instead of analyzing them."""
+    out = []
+    for fn in sorted(os.listdir(models_dir)):
+        path = os.path.join(models_dir, fn)
+        if fn.endswith(".py") and os.path.isfile(path):
+            with open(path) as f:
+                mod = Module(path, f"models/{fn}", f.read())
+            if is_description(mod):
+                out.append(fn[:-3])
+    return out
 
 
 def self_attr(node) -> Optional[str]:
@@ -144,6 +174,10 @@ class CallGraph:
         #:                    ("obj", relpath, objname)}
         self.imports: Dict[str, Dict[str, Tuple]] = {}
         self._by_dotted: Dict[str, str] = {}        # dotted -> relpath
+        #: relpaths of the model descriptions in the set, sorted, and
+        #: of the frame whose factories they bind (None: not in the set)
+        self.descriptions: List[str] = []
+        self.frame: Optional[str] = None
 
     # ------------------------------------------------------------- build
     @classmethod
@@ -151,6 +185,10 @@ class CallGraph:
         g = cls()
         for m in mods:
             g._by_dotted[_dotted(m.relpath)] = m.relpath
+        g.descriptions = sorted(m.relpath for m in mods
+                                if is_description(m))
+        g.frame = next((m.relpath for m in mods
+                        if m.relpath.endswith(FRAME_MODULE)), None)
         for m in mods:
             g._index_module(m)
         for m in mods:
@@ -265,18 +303,20 @@ class CallGraph:
                         if ent and ent[0] == "mod":
                             cn.module_aliases[attr] = ent[1]
                     elif isinstance(value, ast.Call) and terminal_name(
-                            value.func) == "decode_programs":
-                        known = set(self._by_dotted.values())
-                        for rel in DESCRIPTION_MODULES:
-                            if rel in known:
-                                cn.module_aliases[attr] = rel
-                                break
+                            value.func) == "decode_programs" and \
+                            self.descriptions:
+                        cn.module_aliases[attr] = self.descriptions[0]
 
     # --------------------------------------------------------- resolution
     def _module_func(self, relpath: str, name: str) -> Optional[str]:
         key = f"{relpath}::{name}"
         if key in self.funcs:
             return key
+        if relpath in self.descriptions and \
+                f"{self.frame}::{name}" in self.funcs:
+            # a description binds the frame's factories under its own
+            # names: the def (and its budget) is the frame's
+            return f"{self.frame}::{name}"
         ck = f"{relpath}::{name}"
         cn = self.classes.get(ck)
         if cn is not None:

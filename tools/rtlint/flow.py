@@ -88,8 +88,8 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Sequence, Set, Tuple
 
-from .callgraph import (CallGraph, ClassNode, FuncNode, self_attr,
-                        terminal_name)
+from .callgraph import (FRAME_MODULE, CallGraph, ClassNode, FuncNode,
+                        is_description, self_attr, terminal_name)
 from .core import Finding, Module, ProjectRule
 
 #: Collections whose elements are compile-shape knobs: the repo's
@@ -101,13 +101,22 @@ BUCKETS_RE = re.compile(r"(buckets|tps|meshes)$")
 
 #: Files under the compiled-program-budget discipline: factory defs and
 #: binding methods here MUST declare budgets (RT109), and dispatch
-#: results here are sync-audited (RT111, minus gpt_decode whose host
-#: loops are the library surface, not the engine driver).
-BUDGET_SCOPE = ("models/gpt_decode.py", "models/mla_moe.py",
-                "models/kda_moe.py", "serve/engine.py",
-                "serve/draft.py", "serve/handoff.py", "data/llm.py")
+#: results here are sync-audited (RT111, minus the models, whose host
+#: loops are the library surface, not the engine driver). The frame,
+#: where the two factories every model has are defined, and beside
+#: these every model DESCRIPTION, found by rule (:func:`in_budget_scope`).
+BUDGET_SCOPE = (FRAME_MODULE, "serve/engine.py", "serve/draft.py",
+                "serve/handoff.py", "data/llm.py")
 SYNC_SCOPE = ("serve/engine.py", "serve/draft.py", "serve/handoff.py",
               "data/llm.py")
+
+
+
+def in_budget_scope(mod: Module) -> bool:
+    """:data:`BUDGET_SCOPE`, or a model description
+    (:func:`~tools.rtlint.callgraph.is_description`: never a list)."""
+    return mod.relpath.endswith(BUDGET_SCOPE) or is_description(mod)
+
 
 #: Array constructors whose first argument is the shape.
 _SHAPE_CTORS = ("zeros", "ones", "full", "empty")
@@ -1062,7 +1071,7 @@ class ProgramBudgetRule(ProjectRule):
 
         # Check 1: missing declarations in the budget-scope files.
         for key, fn in sorted(g.funcs.items()):
-            if not fn.mod.relpath.endswith(BUDGET_SCOPE):
+            if not in_budget_scope(fn.mod):
                 continue
             if key in budgets:
                 continue

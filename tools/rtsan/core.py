@@ -794,12 +794,21 @@ class Sanitizer:
                     raise_violation=True)
 
     def _wrap_jit_factories(self):
+        # every model description the engine may be handed, found by
+        # rtlint's rule (a module of ray_tpu/models that defines
+        # ``cache_spec``), never listed: each binds the frame's
+        # factories under its own ``jit_*`` names
         try:
-            from ray_tpu.models import gpt_decode, kda_moe, mla_moe
+            import importlib
+
+            from ..rtlint.callgraph import description_names
+            modules = [
+                importlib.import_module(f"ray_tpu.models.{name}")
+                for name in description_names(
+                    os.path.join(REPO_ROOT, "ray_tpu", "models"))]
         except Exception:  # noqa: BLE001 - gated: no device surface here
             return
-        # every model description the engine may be handed
-        for module in (gpt_decode, mla_moe, kda_moe):
+        for module in modules:
             for name in dir(module):
                 if not name.startswith("jit_"):
                     continue
