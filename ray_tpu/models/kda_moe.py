@@ -38,7 +38,11 @@ sequence, and :func:`cache_spec` describes both in one
   lane's whole virtual sequence gathered through the page table, in
   blocks of :data:`_GQA_LANE_BLOCK` lanes
   (:func:`_gqa_attention_gather`, also the tests' oracle). The two
-  agree to :data:`ATTN_KERNEL_ULPS`. The GPT-2 block's kernel (one
+  agree to :data:`ATTN_KERNEL_ULPS`. Both bodies take head counts, not
+  this model's config: :func:`gqa_decode_attention` (with
+  :func:`gqa_kernel` and :func:`gqa_decode_reads`) is the PUBLIC entry
+  through which :mod:`ray_tpu.models.ssm_hybrid` runs the same path at
+  20 queries over 4 KV heads, its rotary applied by the caller. The GPT-2 block's kernel (one
   query a head on the VPU) and the latent decoder's (64 absorbed
   queries over one row a token) are other inner loops around the same
   ring of copies, which is copied here, not shared (ROADMAP D13).
@@ -385,19 +389,25 @@ def _state_kernel(cfg: KDAMoEConfig) -> bool:
     return pallas_interpret() or cfg.kda_head_dim % 128 == 0
 
 
-def _gqa_kernel(cfg: KDAMoEConfig, page_size: int) -> bool:
-    """Whether the step's GQA attention is :func:`_gqa_attention_pallas`:
-    wherever Mosaic can address a page of the pool as the kernel views
-    it, ``[page_size * n_kv_head, head_dim]`` rows: compiled for a TPU
-    ``head_dim`` must be whole 128-lane tiles and a page's rows whole
-    sublane tiles of the pool's dtype (16 of bfloat16, 8 of float32);
-    interpreted, off the TPU, any page is addressable. Elsewhere
-    :func:`_gqa_attention_gather`."""
+def gqa_kernel(n_kv_head: int, head_dim: int, dtype, page_size: int
+               ) -> bool:
+    """Whether decode's GQA attention (:func:`gqa_decode_attention`) is
+    the Pallas kernel: wherever Mosaic can address a page of the pool
+    as the kernel views it, ``[page_size * n_kv_head, head_dim]`` rows:
+    compiled for a TPU ``head_dim`` must be whole 128-lane tiles and a
+    page's rows whole sublane tiles of the pool's dtype (16 of
+    bfloat16, 8 of float32); interpreted, off the TPU, any page is
+    addressable. Elsewhere the gather."""
     from .._private.chip import pallas_interpret
 
-    rows = 32 // jnp.dtype(cfg.dtype).itemsize
+    rows = 32 // jnp.dtype(dtype).itemsize
     return pallas_interpret() or (
-        cfg.head_dim % 128 == 0 and (page_size * cfg.n_kv_head) % rows == 0)
+        head_dim % 128 == 0 and (page_size * n_kv_head) % rows == 0)
+
+
+def _gqa_kernel(cfg: KDAMoEConfig, page_size: int) -> bool:
+    """:func:`gqa_kernel` at this model's heads and compute dtype."""
+    return gqa_kernel(cfg.n_kv_head, cfg.head_dim, cfg.dtype, page_size)
 
 
 def decode_attention_fused(cfg: KDAMoEConfig, page_size: int,
@@ -659,37 +669,37 @@ def _gqa_causal(q, k, v, cfg: KDAMoEConfig):
                       ).reshape(S, cfg.n_head, cfg.head_dim)
 
 
-def _gqa_attention_gather(q, kpool, vpool, pages, pos, cfg: KDAMoEConfig,
-                          page_size: int):
+def _gqa_attention_gather(q, kpool, vpool, pages, pos, n_kv_head: int,
+                          dtype, page_size: int):
     """Decode's attention in plain XLA: ``q`` [B, Hq, hd] over each
     lane's whole virtual sequence, gathered from the flat pools [pages,
     page_size, Hkv, hd] through ``pages`` [B, max_pages] (in bounds) and
     masked past ``pos``; :data:`_GQA_LANE_BLOCK` lanes at a time.
     Returns float32 [B, Hq, hd]."""
-    B = q.shape[0]
+    B, n_head, head_dim = q.shape
     V = pages.shape[1] * page_size
-    G = cfg.n_head // cfg.n_kv_head
+    G = n_head // n_kv_head
 
     def lane(args):
         q, pages, pos = args
-        k = kpool[pages].reshape(V, cfg.n_kv_head, cfg.head_dim)
-        v = vpool[pages].reshape(V, cfg.n_kv_head, cfg.head_dim)
+        k = kpool[pages].reshape(V, n_kv_head, head_dim)
+        v = vpool[pages].reshape(V, n_kv_head, head_dim)
         lg = jnp.einsum("kgd,tkd->kgt",
-                        q.reshape(cfg.n_kv_head, G, cfg.head_dim), k,
+                        q.reshape(n_kv_head, G, head_dim), k,
                         preferred_element_type=jnp.float32) \
-            * cfg.head_dim ** -0.5
+            * head_dim ** -0.5
         lg = jnp.where(jnp.arange(V) <= pos, lg, -1e30)
-        probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
+        probs = jax.nn.softmax(lg, axis=-1).astype(dtype)
         return jnp.einsum("kgt,tkd->kgd", probs, v,
                           preferred_element_type=jnp.float32
-                          ).reshape(cfg.n_head, cfg.head_dim)
+                          ).reshape(n_head, head_dim)
 
     return lax.map(lane, (q, pages, pos),
                    batch_size=min(B, _GQA_LANE_BLOCK))
 
 
-def _gqa_attention_pallas(q, kpool, vpool, pages, length,
-                          cfg: KDAMoEConfig, page_size: int):
+def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
+                          page_size: int):
     """Decode's attention as ONE kernel that reads what is live, once:
     ``q`` [B, Hq, hd] against the first ``length[b]`` tokens of lane
     ``b``, whose pages ``pages`` [B, max_pages] names in the flat pools
@@ -735,7 +745,7 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length,
     from .._private.chip import pallas_interpret
 
     B, Hq, hd = q.shape
-    Hkv = cfg.n_kv_head
+    Hkv = n_kv_head
     G = Hq // Hkv
     ps = page_size
     rows = ps * Hkv                                # rows a page
@@ -743,7 +753,7 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length,
     T = bp * ps
     ring = _GQA_RING_BLOCKS
     dtype = q.dtype
-    scale = cfg.head_dim ** -0.5   # a Python float: no captured constant
+    scale = hd ** -0.5             # a Python float: no captured constant
     kpool = kpool.reshape(-1, rows, hd)
     vpool = vpool.reshape(-1, rows, hd)
     first = jnp.concatenate([
@@ -855,6 +865,47 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length,
         interpret=pallas_interpret(),
         name="gqa_attention",
     )(pages, length, first, q, own, kpool, vpool)
+
+
+def gqa_decode_reads(pt, pos, active, n_pages: int, page_size: int,
+                     kernel: bool):
+    """What one decode step's GQA attention reads, the same for every
+    layer of the step: ``(length, fetched)``. With the kernel
+    (:func:`gqa_kernel`) ``length`` int32 [B] is each lane's live
+    tokens (:func:`~ray_tpu.models.serving.live_length`; the step
+    writes a token's keys and values before it attends) and
+    ``fetched`` those rounded up to whole pages, summed; with the
+    gather ``length`` is None and ``fetched`` the whole table,
+    ``slots x max_pages x page_size``, whatever is live."""
+    if not kernel:
+        return None, jnp.int32(pt.shape[0] * pt.shape[1] * page_size)
+    length = live_length(pt, pos, active, n_pages, page_size)
+    return length, jnp.sum((length + page_size - 1) // page_size * page_size,
+                           dtype=jnp.int32)
+
+
+def gqa_decode_attention(q, kpool, vpool, pages, pos, length, *,
+                         n_head: int, n_kv_head: int, head_dim: int,
+                         dtype, page_size: int):
+    """Decode's grouped-query attention over pages, for any model whose
+    pages hold ``[n_kv_head, head_dim]`` keys and values a token: ``q``
+    [B, n_head, head_dim] in ``dtype`` (positions, where the model has
+    any, already applied to it and to the keys in the pool) over the
+    flat pools [pages, page_size, n_kv_head, head_dim] through
+    ``pages`` [B, max_pages] (in bounds), query head ``j`` with KV head
+    ``j // (n_head / n_kv_head)``. ONE path in two bodies
+    (:func:`gqa_decode_reads` says which, by :func:`gqa_kernel`):
+    ``length`` int32 [B], each lane's live tokens, takes the Pallas
+    kernel over the live pages (:func:`_gqa_attention_pallas`); None
+    takes plain XLA over the whole table row masked past ``pos``
+    (:func:`_gqa_attention_gather`). Returns float32 [B, n_head,
+    head_dim]; the two agree to :data:`ATTN_KERNEL_ULPS`."""
+    assert q.shape[1:] == (n_head, head_dim), (q.shape, n_head, head_dim)
+    if length is None:
+        return _gqa_attention_gather(q, kpool, vpool, pages, pos,
+                                     n_kv_head, dtype, page_size)
+    return _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head,
+                                 page_size)
 
 
 def forward(params: Params, tokens: jax.Array, cfg: KDAMoEConfig
@@ -1017,13 +1068,9 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     state, conv = cache["state"], cache["conv"]
     counts = jnp.zeros((4,), jnp.int32)
     ig = ik = 0
-    state_kernel, gqa_kernel = _state_kernel(cfg), _gqa_kernel(cfg, ps)
-    if gqa_kernel:
-        # the step writes a token's keys and values before it attends
-        length = live_length(pt, pos, active, n_pages, ps)
-        fetched = jnp.sum((length + ps - 1) // ps * ps, dtype=jnp.int32)
-    else:
-        fetched = jnp.int32(pt.shape[0] * max_pages * ps)
+    state_kernel = _state_kernel(cfg)
+    length, fetched = gqa_decode_reads(pt, pos, active, n_pages, ps,
+                                       _gqa_kernel(cfg, ps))
     # the step's own scope: a reader tells the decode program's state,
     # attention and expert time from prefill's by it
     with jax.named_scope("decode_step"):
@@ -1035,11 +1082,11 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                 kpool = kpool.at[at].set(k, mode="drop")
                 vpool = vpool.at[at].set(v, mode="drop")
                 with jax.named_scope("gqa.attention"):
-                    pages = ptc + ig * n_pages
-                    att = _gqa_attention_pallas(
-                        q, kpool, vpool, pages, length, cfg, ps) \
-                        if gqa_kernel else _gqa_attention_gather(
-                            q, kpool, vpool, pages, pos, cfg, ps)
+                    att = gqa_decode_attention(
+                        q, kpool, vpool, ptc + ig * n_pages, pos, length,
+                        n_head=cfg.n_head, n_kv_head=cfg.n_kv_head,
+                        head_dim=cfg.head_dim, dtype=cfg.dtype,
+                        page_size=ps)
                 y = _gqa_out(att, z, p, cfg)
                 ig += 1
             else:

@@ -3,18 +3,22 @@
 :class:`~ray_tpu.serve.engine.DecodeEngine` serves any decoder whose
 config object answers ``cfg.decode_programs()`` with a module (or
 namespace) of the paged slot-pool programs: the engine binds no model
-module by name. Four decoders answer today: the GPT-2 block
+module by name. Five decoders answer today: the GPT-2 block
 (:mod:`ray_tpu.models.gpt_decode`: a page holds keys and values per
 head), the latent-attention expert decoder
 (:mod:`ray_tpu.models.mla_moe`: a page holds one 576-wide latent a
 token in a row of 640 lanes, no head axis), the hybrid
 linear-attention expert decoder (:mod:`ray_tpu.models.kda_moe`: one
 layer in four keeps grouped keys and values in pages, the others a
-fixed recurrent state and a short convolution's tail PER SLOT) and the
+fixed recurrent state and a short convolution's tail PER SLOT), the
 shortcut-connected expert decoder (:mod:`ray_tpu.models.scmoe`: TWO
 latent attentions a layer, so the latent entry counts ``2 * n_layer``
 layers of the one pool; the attention is ``mla_moe``'s, imported under
-its public names).
+its public names) and the parallel hybrid decoder
+(:mod:`ray_tpu.models.ssm_hybrid`: a state-space state and a
+convolution's tail per slot BESIDE rotary grouped keys and values in
+pages, in EVERY layer; decode's attention is ``kda_moe``'s, through its
+public entry).
 
 **A description provides** what only the model knows:
 
@@ -63,7 +67,8 @@ page_size, kv_dtype, attn_kernel)``
     attention over latent pages, by shape, ``kda_moe`` for TWO kernels,
     the recurrence on its per-slot state and its GQA layers' attention
     over pages, each taken by its own shapes: either one makes the
-    answer true. The engine asks it for
+    answer true, ``ssm_hybrid`` for that attention's kernel alone (its
+    recurrence is plain XLA). The engine asks it for
     ``warm_up()["attn_kernel_mode"]`` (``"compiled"`` / ``"interpret"``,
     read off the lowered program, or ``None`` without a kernel) and for
     ``stats()["attn_kernel_dispatches"]``; every description answers.
@@ -242,7 +247,7 @@ def knob_cache(fn):
     those spellings separately, silently doubling the compiled-program
     set and breaking the recompile guards' wrapper ``is``-identity.
     256 entries: the frame's two factories hold every description's
-    wrappers (64 each of four)."""
+    wrappers (51 each of five)."""
     sig = inspect.signature(fn)
     cached = functools.lru_cache(maxsize=256)(fn)
 

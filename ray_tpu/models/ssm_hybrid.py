@@ -1,0 +1,761 @@
+"""A PARALLEL hybrid decoder, a selective state-space mixer BESIDE
+rotary grouped-query attention in every layer, and its paged serving
+programs: the fifth block :class:`~ray_tpu.serve.engine.DecodeEngine`
+serves. This module IS the model's description in the sense of
+:mod:`ray_tpu.models.serving`.
+
+The block (pre-norm, RMSNorm, a float32 residual stream, an untied
+head, a constant multiplier on every branch; no bias but the
+convolution's)::
+
+    u  = RMSNorm(x)
+    x += SSM(u) * ssm_out_mult + Attention(u) * attn_out_mult
+    x += MLP(RMSNorm(x))
+
+Both mixers read the SAME normed input and add into the residual
+together, so every layer keeps BOTH kinds of thing of a sequence, and
+:func:`cache_spec` describes them in one
+:class:`~ray_tpu.models.serving.CacheSpec` (``kda_moe`` keeps one or
+the other, by layer):
+
+- **Attention** (``n_head`` query heads over ``n_kv_head`` key/value
+  heads, query head ``j`` with KV head ``j // (n_head / n_kv_head)``;
+  rotary over the whole head, halves pairing; ``q`` scaled by
+  ``attn_in_mult`` and ``k`` by ``key_mult``): a token leaves
+  ``n_kv_head x head_dim`` ROTATED keys and its values in a PAGE
+  (entries ``k``, ``v``, per token, every layer). Prefill attends
+  causally over the prompt (scope ``hgqa.prefill``); decode attends
+  over the lane's pages (scope ``hgqa.attention``) through
+  :func:`ray_tpu.models.kda_moe.gqa_decode_attention`: that model's
+  Pallas kernel over each lane's live pages wherever Mosaic can address
+  a page and a head (:func:`ray_tpu.models.kda_moe.gqa_kernel`), plain
+  XLA over the gathered table row elsewhere, chosen by shape under the
+  one name ``"gather"``.
+- **SSM** (Mamba-2: ``ssm_heads`` heads of ``ssm_head_dim`` channels
+  over a state ``ssm_state`` wide, ``B`` and ``C`` shared by
+  ``ssm_groups`` groups of heads). With ``[z | xBC | dt] = (u W_in) *
+  ssm_in_mult * mup`` (``mup``: one of :attr:`SSMHybridConfig.ssm_mup`
+  a segment ``z | x | B | C | dt``)::
+
+      xBC_t = silu(sum_j w[:, j] xBC_{t-3+j} + b)     width-4, depthwise
+      dt_t  = softplus(dt_t + dt_bias);  a_t = exp(-exp(A_log) dt_t)
+      S_t   = a_t S_{t-1} + dt_t x_t (x) B_t          a head, S [P, N]
+      y_t   = S_t C_t + D x_t
+      out   = (RMSNorm_groups(y_t * silu(z_t)) * w_norm) W_out
+
+  A sequence keeps, whatever its length, ``S`` (``[heads, head_dim,
+  state]`` in :attr:`SSMHybridConfig.state_dtype`) and the last
+  ``conv_size - 1`` rows of ``xBC`` before the convolution: entries
+  ``state<l>`` and ``conv<l>``, ``per "slot"``, ONE ARRAY A LAYER
+  (:func:`cache_spec` says why). They belong to
+  the SLOT: every prefill rebuilds them from zero (the chunked form,
+  scope ``ssm.prefill``: inside a chunk the masked quadratic form with
+  ``exp`` of the decays' running sums, between chunks the state passed
+  on), every decode step reads a live lane's state and writes it in
+  place (scope ``ssm.state``, plain XLA in float32), and an idle or
+  parked lane's comes out as it went in. So no page hash shares them
+  and nothing rolls back: :data:`UNSUPPORTED`.
+
+**MLP**, every layer, dense: ``W_down[silu((v W_gate) * mlp_mults[0])
+* (v W_up)] * mlp_mults[1]`` (scope ``hybrid.mlp``). The table's rows
+are scaled by ``embed_mult`` and the logits by ``head_mult`` (scope
+``lm.head`` around the final norm and the head product).
+
+Layers are a list of per-layer trees, unrolled; the chunk program
+returns the live lanes and the positions its attention fetched, summed
+over its steps (:data:`STEP_COUNTERS`).
+"""
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+import sys
+from typing import Any, Dict, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from . import kda_moe, serving
+from .moe import rmsnorm
+from .serving import PT_SENTINEL, CacheEntry, CacheSpec, at_layer, flat
+
+_THIS = sys.modules[__name__]
+
+Params = Dict[str, Any]
+Cache = Dict[str, jax.Array]
+
+KV_DTYPES = ("fp",)
+ATTN_KERNELS = ("gather",)
+#: What the engine offers and this model does not take, with the reason
+#: the engine raises at construction.
+UNSUPPORTED = {
+    "prefix_cache": "a state-space layer's recurrent state belongs to "
+                    "the slot, not to a page: reusing cached pages needs "
+                    "a snapshot of the state at the page boundary the hit "
+                    "ends on, and none is kept",
+    "spec_decode": "a recurrent state does not roll back past rejected "
+                   "positions, and there is no verify program",
+    "roles": "the handoff payload has no part for the per-slot state, "
+             "and there are no export/import programs",
+    "int8": "the key/value pages beside the state have no quantised "
+            "layout",
+    "tp": "there are no tensor-parallel programs: the state belongs to "
+          "the slot and the deployment cuts the model by depth, one "
+          "engine a chip",
+}
+#: int32 counters the chunk program returns, summed over its steps: the
+#: lanes whose state a step read and wrote (one a lane a step, whatever
+#: the layers), and the positions whose keys and values a step's
+#: attention fetched from the pools, all layers
+#: (:func:`ray_tpu.models.kda_moe.gqa_decode_reads`).
+STEP_COUNTERS = ("state_lanes_sum", "gqa_tokens_read_sum")
+_HI = lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass(frozen=True)
+class SSMHybridConfig:
+    vocab_size: int = 512
+    n_layer: int = 2
+    d_model: int = 64
+    n_head: int = 4                  # query heads
+    n_kv_head: int = 2
+    head_dim: int = 16
+    rope_theta: float = 10000.0
+    ssm_heads: int = 4
+    ssm_head_dim: int = 16           # channels a head (P)
+    ssm_state: int = 32              # the state's width (N)
+    ssm_groups: int = 2              # groups of heads sharing B and C
+    conv_size: int = 4
+    ssm_chunk: int = 16              # prefill's chunk
+    d_ff: int = 128
+    # the branches' constant multipliers
+    embed_mult: float = 2.0
+    ssm_in_mult: float = 0.5
+    ssm_mup: Tuple[float, ...] = (0.7, 0.5, 0.35, 0.5, 0.7)  # z|x|B|C|dt
+    ssm_out_mult: float = 0.5
+    attn_in_mult: float = 1.0
+    key_mult: float = 0.25
+    attn_out_mult: float = 0.5
+    mlp_mults: Tuple[float, float] = (0.5, 0.25)     # gate, down
+    head_mult: float = 0.125
+    max_seq: int = 262144            # positions the rotary reaches
+    eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    state_dtype: Any = jnp.float32
+
+    @property
+    def ssm_width(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def bc_width(self) -> int:
+        """``B`` (and ``C``) over all groups."""
+        return self.ssm_groups * self.ssm_state
+
+    @property
+    def conv_dim(self) -> int:
+        """``x | B | C``: what the convolution runs over."""
+        return self.ssm_width + 2 * self.bc_width
+
+    @property
+    def in_width(self) -> int:
+        """``z | x | B | C | dt``: the input projection's columns."""
+        return self.ssm_width + self.conv_dim + self.ssm_heads
+
+    def decode_programs(self):
+        """This model's description for the serving engine
+        (:mod:`ray_tpu.models.serving`)."""
+        import sys
+
+        return sys.modules[__name__]
+
+
+# sizes used by the CPU tests
+CONFIGS = {
+    "nano": SSMHybridConfig(),
+}
+
+#: Means of the leaves that are not drawn around zero: ``dt_bias`` sits
+#: where ``softplus`` is about 0.02 and ``A_log`` where ``exp`` is 4, so
+#: that the decay ``a`` spreads over (0.2, 0.999) instead of around
+#: ``exp(-ln 2)``; the skip ``D`` and the gated norm's weight around 1.
+INIT_MEAN = {"dt_bias": -4.0, "A_log": 1.4, "D_skip": 1.0, "ssm_norm": 1.0}
+
+
+def init_std(cfg: SSMHybridConfig) -> Dict[str, float]:
+    """Each kind of leaf's standard deviation: a matrix is drawn so
+    that WITH its branch's multiplier it acts as a ``1 / sqrt(fan-in)``
+    matrix (a unit-variance draw under ``key_mult`` makes every softmax
+    uniform and under the three output multipliers drowns the branch in
+    the table): scores about three wide, a unit residual stream at the
+    table, each branch adding to it at a like scale, logits about one
+    wide."""
+    d = cfg.d_model
+
+    def fan(n, mult=1.0, gain=1.0):
+        return gain / (math.sqrt(n) * mult)
+
+    return {
+        "embed": 1.0 / cfg.embed_mult,
+        "head": fan(d, cfg.head_mult),
+        "wq": fan(d, cfg.attn_in_mult, 1.7),
+        "wk": fan(d, cfg.key_mult, 1.7),
+        "wo": fan(cfg.n_head * cfg.head_dim, cfg.attn_out_mult),
+        # one draw for all five segments: ``x`` comes out one wide
+        "in_proj": fan(d, cfg.ssm_in_mult * cfg.ssm_mup[1]),
+        "out_proj": fan(cfg.ssm_width, cfg.ssm_out_mult),
+        "gate": fan(d, cfg.mlp_mults[0]),
+        "down": fan(cfg.d_ff, cfg.mlp_mults[1], 2.0),
+        "conv_w": 0.5, "conv_b": 0.5, "dt_bias": 1.0, "A_log": 0.7,
+        "D_skip": 0.5, "ssm_norm": 0.3,
+    }
+
+
+def init_params(rng: jax.Array, cfg: SSMHybridConfig,
+                std: Optional[dict] = None, vocab_blocks: int = 1) -> Params:
+    """Seeded weights, one tree a layer. ``std`` overrides a kind's
+    standard deviation (default :func:`init_std`, else 1/sqrt(fan-in));
+    :data:`INIT_MEAN` is added. ``vocab_blocks`` > 1 holds the table
+    and the head as that many blocks of vocabulary rows (a list under
+    ``"kernel"``: the programs read either form off the tree), so that
+    no single leaf is 1.3 G values where the vocabulary is 261,120
+    rows: whoever fills the tree under one jit then needs a block's
+    worth of temporaries, not a table's."""
+    std = dict(init_std(cfg), **(std or {}))
+    pd = cfg.param_dtype
+    d, W = cfg.d_model, cfg.ssm_width
+    hq, hkv = cfg.n_head * cfg.head_dim, cfg.n_kv_head * cfg.head_dim
+    n = [0]
+
+    def w(name, *shape):
+        n[0] += 1
+        s = std.get(name, 1.0 / math.sqrt(shape[-2] if len(shape) > 1
+                                          else 1.0))
+        return (jax.random.normal(jax.random.fold_in(rng, n[0]), shape) * s
+                + INIT_MEAN.get(name, 0.0)).astype(pd)
+
+    layers = []
+    for _ in range(cfg.n_layer):
+        layers.append({
+            "ln1_scale": jnp.ones((d,), pd), "ln2_scale": jnp.ones((d,), pd),
+            "wq": {"kernel": w("wq", d, hq)},
+            "wk": {"kernel": w("wk", d, hkv)},
+            "wv": {"kernel": w("wv", d, hkv)},
+            "wo": {"kernel": w("wo", hq, d)},
+            "in_proj": {"kernel": w("in_proj", d, cfg.in_width)},
+            "conv_w": w("conv_w", cfg.conv_size, cfg.conv_dim),
+            "conv_b": w("conv_b", cfg.conv_dim),
+            "dt_bias": w("dt_bias", cfg.ssm_heads),
+            "A_log": w("A_log", cfg.ssm_heads),
+            "D_skip": w("D_skip", cfg.ssm_heads),
+            "ssm_norm": w("ssm_norm", W),
+            "out_proj": {"kernel": w("out_proj", W, d)},
+            "ffn": {"gate": w("gate", d, cfg.d_ff),
+                    "up": w("up", d, cfg.d_ff),
+                    "down": w("down", cfg.d_ff, d)}})
+    rows, rest = divmod(cfg.vocab_size, vocab_blocks)
+    assert not rest, (cfg.vocab_size, vocab_blocks)
+    table = [w("embed", rows, d) for _ in range(vocab_blocks)]
+    head = [w("head", d, rows) for _ in range(vocab_blocks)]
+    return {"embed": {"kernel": table if vocab_blocks > 1 else table[0]},
+            "head": {"kernel": head if vocab_blocks > 1 else head[0]},
+            "ln_f_scale": jnp.ones((d,), pd), "layers": layers}
+
+
+# ------------------------------------------------------------ block math
+def _dot(x, w, dtype):
+    """``x @ w`` in ``dtype`` with float32 sums, the float32 result."""
+    return lax.dot_general(x.astype(dtype), w.astype(dtype),
+                           (((x.ndim - 1,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _vocab_blocks(kernel):
+    """The table or the head as its blocks of vocabulary rows: a tree
+    holds either one array or a list of them (:func:`init_params`)."""
+    return kernel if isinstance(kernel, (list, tuple)) else [kernel]
+
+
+def _embed(params, tokens, cfg: SSMHybridConfig):
+    """The residual stream starts, and stays, in float32
+    (:func:`ray_tpu.models.moe.embed`), scaled by ``embed_mult``. A
+    token's row comes from the block that holds it."""
+    blocks = _vocab_blocks(params["embed"]["kernel"])
+    rows = blocks[0].shape[0]
+    x = blocks[0][tokens] if len(blocks) == 1 else sum(
+        jnp.where((tokens // rows == b)[..., None], blk[tokens % rows], 0)
+        for b, blk in enumerate(blocks))
+    return x.astype(jnp.float32) * cfg.embed_mult
+
+
+def _head(x, params, cfg: SSMHybridConfig):
+    """The final norm and the untied head, scaled: float32 logits (a
+    block of vocabulary rows at a time where the tree holds blocks)."""
+    with jax.named_scope("lm.head"):
+        x = rmsnorm(x, params["ln_f_scale"], cfg.eps, cfg.dtype)
+        return jnp.concatenate(
+            [_dot(x, blk, cfg.dtype)
+             for blk in _vocab_blocks(params["head"]["kernel"])],
+            axis=-1) * cfg.head_mult
+
+
+def _mlp(x, p, cfg: SSMHybridConfig):
+    """x [T, d] -> x + MLP(RMSNorm(x))."""
+    with jax.named_scope("hybrid.mlp"):
+        dt = cfg.dtype
+        h = rmsnorm(x, p["ln2_scale"], cfg.eps, dt)
+        f = p["ffn"]
+        a = (jax.nn.silu(_dot(h, f["gate"], dt) * cfg.mlp_mults[0])
+             * _dot(h, f["up"], dt))
+        return x + _dot(a, f["down"], dt) * cfg.mlp_mults[1]
+
+
+def _rope(x, positions, theta: float):
+    """Rotary over the whole head, halves pairing: ``x`` [..., heads,
+    hd] float32 at ``positions`` [...] (the leading axes of ``x``)."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[..., None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+def _attn_qkv(h, p, positions, cfg: SSMHybridConfig):
+    """``h`` [..., d] at ``positions`` [...] -> (q [..., Hq, hd], k, v
+    [..., Hkv, hd]) in the compute dtype: ``q`` and ``k`` scaled by
+    their multipliers and rotated in float32, then rounded once."""
+    dt = cfg.dtype
+
+    def heads(name, n, mult=1.0):
+        a = _dot(h, p[name]["kernel"], dt)
+        if mult != 1.0:
+            a = a * mult
+        return a.reshape(a.shape[:-1] + (n, cfg.head_dim))
+
+    q = _rope(heads("wq", cfg.n_head, cfg.attn_in_mult), positions,
+              cfg.rope_theta)
+    k = _rope(heads("wk", cfg.n_kv_head, cfg.key_mult), positions,
+              cfg.rope_theta)
+    return q.astype(dt), k.astype(dt), heads("wv", cfg.n_kv_head).astype(dt)
+
+
+def _attn_out(att, p, cfg: SSMHybridConfig):
+    """``att`` [..., Hq, hd] float32 -> the branch's part of the
+    residual [..., d]."""
+    return _dot(att.reshape(att.shape[:-2] + (-1,)), p["wo"]["kernel"],
+                cfg.dtype) * cfg.attn_out_mult
+
+
+def _attn_causal(q, k, v, cfg: SSMHybridConfig):
+    """Causal softmax attention of one sequence: ``q`` [S, Hq, hd] over
+    ``k``, ``v`` [S, Hkv, hd], rotated already. Returns float32 [S, Hq,
+    hd]."""
+    S = q.shape[0]
+    G = cfg.n_head // cfg.n_kv_head
+    qg = q.reshape(S, cfg.n_kv_head, G, cfg.head_dim)
+    lg = jnp.einsum("qkgd,tkd->kgqt", qg, k,
+                    preferred_element_type=jnp.float32) \
+        * cfg.head_dim ** -0.5
+    lg = jnp.where(jnp.tril(jnp.ones((S, S), jnp.bool_)), lg, -1e30)
+    probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
+    return jnp.einsum("kgqt,tkd->qkgd", probs, v,
+                      preferred_element_type=jnp.float32
+                      ).reshape(S, cfg.n_head, cfg.head_dim)
+
+
+def _mup(cfg: SSMHybridConfig):
+    """The input projection's multiplier a column: ``ssm_in_mult`` times
+    its segment's of ``ssm_mup`` (``z | x | B | C | dt``)."""
+    widths = (cfg.ssm_width, cfg.ssm_width, cfg.bc_width, cfg.bc_width,
+              cfg.ssm_heads)
+    return jnp.concatenate([
+        jnp.full((n,), cfg.ssm_in_mult * m, jnp.float32)
+        for n, m in zip(widths, cfg.ssm_mup)])
+
+
+def _ssm_proj(h, p, cfg: SSMHybridConfig):
+    """``h`` [..., d] (normed) -> (z [..., W] float32: the gate; xBC
+    [..., conv_dim]: ``x | B | C`` BEFORE the convolution, in the
+    compute dtype (what the convolution's tail keeps); dt [..., H]
+    float32: the step size, ``softplus`` taken; g [..., H]: the log of
+    the decay, ``-exp(A_log) dt`` <= 0)."""
+    W = cfg.ssm_width
+    zxbcdt = _dot(h, p["in_proj"]["kernel"], cfg.dtype) * _mup(cfg)
+    z, xBC, dt = (zxbcdt[..., :W], zxbcdt[..., W:W + cfg.conv_dim],
+                  zxbcdt[..., W + cfg.conv_dim:])
+    dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
+    g = -jnp.exp(p["A_log"].astype(jnp.float32)) * dt
+    return z, xBC.astype(cfg.dtype), dt, g
+
+
+def _ssm_conv(window, p, cfg: SSMHybridConfig):
+    """The short convolution's output for positions whose ``conv_size``
+    input rows are ``window[i]`` (a list of ``[..., conv_dim]`` arrays,
+    oldest first): SiLU of the depthwise sum plus the bias, in float32,
+    split into ``x`` [..., H, P], ``B`` and ``C`` [..., G, N]."""
+    taps = p["conv_w"].astype(jnp.float32)               # [conv, conv_dim]
+    y = sum(taps[i] * window[i].astype(jnp.float32)
+            for i in range(cfg.conv_size))
+    y = jax.nn.silu(y + p["conv_b"].astype(jnp.float32))
+    W, bc = cfg.ssm_width, cfg.bc_width
+    lead = y.shape[:-1]
+    return (y[..., :W].reshape(lead + (cfg.ssm_heads, cfg.ssm_head_dim)),
+            y[..., W:W + bc].reshape(lead + (cfg.ssm_groups, cfg.ssm_state)),
+            y[..., W + bc:].reshape(lead + (cfg.ssm_groups, cfg.ssm_state)))
+
+
+def _per_head(a, cfg: SSMHybridConfig):
+    """``B`` or ``C`` [..., G, N] -> [..., H, N]: head ``h`` reads
+    group ``h // (H / G)``."""
+    return jnp.repeat(a, cfg.ssm_heads // cfg.ssm_groups, axis=-2)
+
+
+def _ssm_out(y, z, p, cfg: SSMHybridConfig):
+    """``y`` [..., H, P] float32 (the skip added) and the gate ``z``
+    [..., W] -> the branch's part of the residual [..., d]: the gate,
+    then an RMSNorm a GROUP of ``W / ssm_groups`` channels with one
+    learned weight a channel, the output projection, its multiplier."""
+    lead = y.shape[:-2]
+    y = y.reshape(lead + (-1,)) * jax.nn.silu(z)
+    y = y.reshape(lead + (cfg.ssm_groups, -1))
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.eps)
+    y = y.reshape(lead + (-1,)) * p["ssm_norm"].astype(jnp.float32)
+    return _dot(y, p["out_proj"]["kernel"], cfg.dtype) * cfg.ssm_out_mult
+
+
+def _ssm_step(S, x, B, C, dt, g, D):
+    """The recurrence, one token a lane, in plain XLA float32: ``S``
+    [B, H, P, N], ``x`` [B, H, P], ``B`` ``C`` [B, H, N] (per head),
+    ``dt`` ``g`` [B, H], ``D`` [H]. Returns ``(S', y [B, H, P])``.
+    ``y = S' C + D x`` is taken from the state BEFORE the step, ``a (S
+    C) + dt x (B . C)``: the sum and the update both read the old
+    state, one pass each way, and the new state's one reader is the
+    write in place."""
+    a = jnp.exp(g)
+    dx = dt[..., None] * x
+    y = a[..., None] * jnp.sum(S * C[..., None, :], axis=-1) \
+        + dx * jnp.sum(B * C, axis=-1)[..., None] + D[:, None] * x
+    return S * a[..., None, None] + dx[..., None] * B[..., None, :], y
+
+
+def _ssd_chunked(x, B, C, dt, g, S0, chunk: int):
+    """The same recurrence over a whole sequence in chunks (the SSD
+    form): ``x`` [T, H, P], ``B`` ``C`` [T, H, N] (per head), ``dt``
+    ``g`` [T, H], ``S0`` [H, P, N], float32; ``T`` a multiple of
+    ``chunk``. Returns ``(y [T, H, P]`` without the skip, ``S_T)``.
+    Rows with ``dt = 0`` and ``g = 0`` leave the state as it is (a
+    prompt's padding).
+
+    Within a chunk, with ``G_t`` the running sum of ``g`` from the
+    chunk's start and ``S`` the state before it: ``y_t = e^{G_t} S C_t
+    + sum_{s <= t} e^{G_t - G_s} (C_t . B_s) dt_s x_s`` (the masked
+    quadratic form) and the state after the chunk is ``e^{G_C} S +
+    sum_s e^{G_C - G_s} dt_s x_s (x) B_s``. Every exponent taken is
+    <= 0."""
+    T = x.shape[0]
+    Cn = chunk
+    N = T // Cn
+
+    def chunks(a):                           # [T, H, .] -> [N, C, H, .]
+        return a.reshape((N, Cn) + a.shape[1:])
+
+    lower = jnp.tril(jnp.ones((Cn, Cn), jnp.bool_))
+
+    def mm(spec, a, b):
+        return jnp.einsum(spec, a, b, precision=_HI,
+                          preferred_element_type=jnp.float32)
+
+    def one(S, xs):
+        x, B, C, dt, g = xs                  # [C, H, .]; dt, g [C, H]
+        G = jnp.cumsum(g, axis=0)
+        L = jnp.exp(jnp.where(lower[:, :, None],
+                              G[:, None] - G[None], -jnp.inf))  # [t, s, H]
+        xdt = x * dt[..., None]
+        y = mm("tsh,shp->thp", mm("thn,shn->tsh", C, B) * L, xdt) \
+            + mm("thn,hpn->thp", C, S) * jnp.exp(G)[..., None]
+        last = G[-1]                                              # [H]
+        S = jnp.exp(last)[:, None, None] * S + mm(
+            "shp,shn->hpn", xdt * jnp.exp(last[None] - G)[..., None], B)
+        return S, y
+
+    S, y = lax.scan(one, S0, (chunks(x), chunks(B), chunks(C),
+                              chunks(dt), chunks(g)))
+    return y.reshape((T,) + y.shape[2:]), S
+
+
+def _ssm_sequence(h, p, cfg: SSMHybridConfig, live):
+    """The SSM mixer over one whole sequence from a zero state: ``h``
+    [S, d] (normed), ``live`` [S] bool (rows past the prompt advance
+    nothing). Returns ``(out [S, d] float32, S_end [H, P, N], padded
+    [conv_size - 1 + S, conv_dim]: ``xBC`` before the convolution
+    behind the zero rows that stand before the sequence's start)``."""
+    S = h.shape[0]
+    with jax.named_scope("ssm.proj"):
+        z, xBC, dt, g = _ssm_proj(h, p, cfg)
+        back = cfg.conv_size - 1
+        padded = jnp.concatenate(
+            [jnp.zeros((back, xBC.shape[-1]), xBC.dtype), xBC])
+        x, B, C = _ssm_conv([padded[i:i + S] for i in range(cfg.conv_size)],
+                            p, cfg)
+        dt = jnp.where(live[:, None], dt, 0.0)
+        g = jnp.where(live[:, None], g, 0.0)
+    with jax.named_scope("ssm.prefill"):
+        Cn = min(cfg.ssm_chunk, S)
+        pad = -S % Cn
+        ops = (x, _per_head(B, cfg), _per_head(C, cfg), dt, g)
+        if pad:
+            ops = tuple(jnp.concatenate(
+                [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)]) for a in ops)
+        y, S_end = _ssd_chunked(*ops, jnp.zeros(
+            (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32),
+            Cn)
+        y = y[:S] + p["D_skip"].astype(jnp.float32)[:, None] * x
+    with jax.named_scope("ssm.proj"):
+        out = _ssm_out(y, z, p, cfg)
+    return out, S_end, padded
+
+
+def forward(params: Params, tokens: jax.Array, cfg: SSMHybridConfig
+            ) -> jax.Array:
+    """tokens [B, S] -> float32 logits [B, S, rows]: each sequence
+    whole, no cache (the chunked SSD form from a zero state, causal
+    rotary attention), one sequence at a time."""
+    S = tokens.shape[1]
+    live = jnp.ones((S,), jnp.bool_)
+    positions = jnp.arange(S)
+
+    def row(toks):
+        x = _embed(params, toks, cfg)
+        for p in params["layers"]:
+            h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+            q, k, v = _attn_qkv(h, p, positions, cfg)
+            x = _mlp(x + _ssm_sequence(h, p, cfg, live)[0]
+                     + _attn_out(_attn_causal(q, k, v, cfg), p, cfg), p, cfg)
+        return _head(x, params, cfg)
+
+    return lax.map(row, tokens)
+
+
+# ----------------------------------------------------------- description
+def slot_entry(name: str, layer: int) -> str:
+    """The pool's key of a layer's per-slot entry (``state3``)."""
+    return f"{name}{layer}"
+
+
+def cache_spec(cfg: SSMHybridConfig, kv_dtype: str = "fp") -> CacheSpec:
+    """What a token leaves in a page (rotated keys, and values, ``[Hkv,
+    hd]`` each, every layer: the pools ``[L, n_pages, page_size, Hkv,
+    hd]``) and what a sequence keeps in its SLOT, every layer: the
+    state ``[H, P, N]`` in the state dtype and the convolution's last
+    ``conv_size - 1`` input rows ``[conv - 1, conv_dim]`` in the compute
+    dtype, as entries of ONE layer each (:func:`slot_entry`:
+    ``state<l>`` ``[1, slots, H, P, N]``, ``conv<l>`` ``[1, slots, conv
+    - 1, conv_dim]``).
+
+    One array a layer, not one stacked over the layers: a decode step
+    then updates a layer's state as a whole array in place, and no
+    layer's update is a slice written into an array that a LATER layer
+    still reads. Stacked (``[L, slots, ...]``, each layer a
+    ``dynamic_update_slice`` of the last layer's result), every
+    update's result had two readers, the next layer's read of its own
+    slice and the next layer's update, and at the cell's size (14 GB of
+    arguments, the pool donated) XLA for the TPU rematerialised the
+    first layer's in-place update behind the second reader ON THE
+    BUFFER IT HAD ALREADY OVERWRITTEN: that layer's state advanced
+    twice a step, at 128 slots and not at 8 (PERF.md section 6, PR
+    52)."""
+    serving.check_kv_dtype(_THIS, kv_dtype)
+    row = (cfg.n_kv_head, cfg.head_dim)
+    per_layer = [
+        (CacheEntry(slot_entry("state", l), "slot",
+                    (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                    cfg.state_dtype, 1),
+         CacheEntry(slot_entry("conv", l), "slot",
+                    (cfg.conv_size - 1, cfg.conv_dim), cfg.dtype, 1))
+        for l in range(cfg.n_layer)]
+    return CacheSpec(cfg.n_layer, (
+        CacheEntry("k", "token", row, cfg.dtype),
+        CacheEntry("v", "token", row, cfg.dtype),
+        *(e for pair in per_layer for e in pair)))
+
+
+def max_positions(cfg: SSMHybridConfig) -> int:
+    """The positions the rotary is declared to reach."""
+    return cfg.max_seq
+
+
+def decode_attention_fused(cfg: SSMHybridConfig, page_size: int,
+                           attn_kernel: str = "gather") -> bool:
+    """Whether the chunk program built with these knobs holds a Pallas
+    kernel (the description's entry, :mod:`ray_tpu.models.serving`):
+    its attention over pages, taken by what the program can see of the
+    page and the head (:func:`ray_tpu.models.kda_moe.gqa_kernel`).
+    ``attn_kernel`` (one value) has no say; the recurrence is plain
+    XLA."""
+    return kda_moe.gqa_kernel(cfg.n_kv_head, cfg.head_dim, cfg.dtype,
+                              page_size)
+
+
+# what follows from the spec and from ``UNSUPPORTED["tp"]``: the frame's
+kv_bytes_per_page = serving.bind(serving.kv_bytes_per_page, _THIS)
+init_paged_cache = serving.bind(serving.init_paged_cache, _THIS)
+check_tp = serving.bind(serving.check_tp, _THIS)
+shard_params = serving.bind(serving.shard_params, _THIS)
+
+
+# -------------------------------------------------------------- programs
+def _put(pool, rows, *start):
+    """``rows`` written at ``pool[start]`` in place (the leading
+    indices; a traced slot among them)."""
+    lead = len(start)
+    return lax.dynamic_update_slice(
+        pool, rows.astype(pool.dtype)[(None,) * lead],
+        tuple(start) + (0,) * (pool.ndim - lead))
+
+
+def prefill_into_slot_paged(params: Params, cache: Cache,
+                            tokens: jax.Array, length: jax.Array,
+                            hist_len: jax.Array, pt_row: jax.Array,
+                            cow_src: jax.Array, slot: jax.Array,
+                            rng: jax.Array, *, cfg: SSMHybridConfig,
+                            page_size: int, temperature: float = 0.0,
+                            kv_dtype: str = "fp"
+                            ) -> Tuple[jax.Array, Cache, jax.Array]:
+    """Prefill one WHOLE prompt into its pages and its slot, with the
+    first token's sample: the frame of
+    :func:`ray_tpu.models.gpt_decode.prefill_into_slot_paged`. Every
+    layer's rotated keys and values go to the pages ``pt_row`` names,
+    and every layer's state and convolution tail are rebuilt FROM ZERO
+    and written over whatever slot ``slot`` held: a prefill is the one
+    way a slot's state begins. Rows past ``length`` (the bucket's
+    padding) write no page and advance neither state nor tail: the
+    slot holds what the prompt's LAST token left. ``hist_len`` and
+    ``cow_src`` are the frame's and have no meaning here: without a
+    prefix cache (:data:`UNSUPPORTED`) the engine's are always 0 and
+    the sentinel."""
+    del hist_len, cow_src
+    S = tokens.shape[1]
+    ps = page_size
+    n_pages = cache["k"].shape[1]
+    max_pages = pt_row.shape[0]
+    x = _embed(params, tokens, cfg)[0]                      # [S, d]
+    live = jnp.arange(S) < length
+    wpos = jnp.arange(S)
+    vp = wpos // ps
+    page_w = jnp.where(live & (vp < max_pages),
+                       pt_row[jnp.clip(vp, 0, max_pages - 1)],
+                       jnp.int32(PT_SENTINEL))
+    kpool, vpool = flat(cache["k"]), flat(cache["v"])
+    slot_entries = {}
+    back = cfg.conv_size - 1
+    for l, p in enumerate(params["layers"]):
+        h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+        q, k, v = _attn_qkv(h, p, wpos, cfg)
+        with jax.named_scope("hgqa.prefill"):
+            att = _attn_causal(q, k, v, cfg)
+        at = (at_layer(page_w, l, n_pages), wpos % ps)
+        kpool = kpool.at[at].set(k, mode="drop")
+        vpool = vpool.at[at].set(v, mode="drop")
+        y, S_end, padded = _ssm_sequence(h, p, cfg, live)
+        state, conv = slot_entry("state", l), slot_entry("conv", l)
+        slot_entries[state] = _put(cache[state], S_end, 0, slot)
+        slot_entries[conv] = _put(cache[conv], lax.dynamic_slice(
+            padded, (length, 0), (back, padded.shape[1])), 0, slot)
+        x = _mlp(x + y + _attn_out(att, p, cfg), p, cfg)
+    x_last = lax.dynamic_slice(x, (length - 1, 0), (1, cfg.d_model))
+    token, rng = serving.sample(_head(x_last, params, cfg), temperature, rng)
+    pos = lax.dynamic_update_slice(
+        cache["pos"], jnp.reshape(length, (1,)).astype(jnp.int32), (slot,))
+    return token[0], {"k": kpool.reshape(cache["k"].shape),
+                      "v": vpool.reshape(cache["v"].shape),
+                      **slot_entries, "pos": pos}, rng
+
+
+def _slot_decode_step_paged(params: Params, cache: Cache,
+                            token: jax.Array, active: jax.Array,
+                            pt: jax.Array, cfg: SSMHybridConfig,
+                            page_size: int, kv_dtype: str = "fp",
+                            attn_kernel: str = "gather"):
+    """One masked decode step over the whole slot pool: in every layer
+    each active lane writes its rotated key and its value at its own
+    position and attends over its pages
+    (:func:`ray_tpu.models.kda_moe.gqa_decode_attention`: the kernel
+    over its live pages or the gather over its whole table row, by
+    shape), AND reads and writes its state and convolution tail whole.
+    An inactive lane (idle, or parked for pages) neither writes nor
+    advances: its state and tail come out as they went in. Returns
+    ``(logits [B, rows], cache', counts)``: int32 [2]
+    (:data:`STEP_COUNTERS`)."""
+    ps = page_size
+    max_pages = pt.shape[1]
+    pos = cache["pos"]
+    n_pages = cache["k"].shape[1]
+    x = _embed(params, token, cfg)                          # [B, d]
+    vp = pos // ps
+    page_w = jnp.where(
+        active & (vp < max_pages),
+        jnp.take_along_axis(pt, jnp.clip(vp, 0, max_pages - 1)[:, None],
+                            axis=1)[:, 0], jnp.int32(PT_SENTINEL))
+    ptc = jnp.clip(pt, 0, n_pages - 1)
+    kpool, vpool = flat(cache["k"]), flat(cache["v"])
+    slot_entries = {}
+    length, fetched = kda_moe.gqa_decode_reads(
+        pt, pos, active, n_pages, ps,
+        decode_attention_fused(cfg, ps, attn_kernel))
+    # the step's own scope: a reader tells the decode program's state,
+    # attention, MLP and head time from prefill's by it
+    with jax.named_scope("decode_step"):
+        for l, p in enumerate(params["layers"]):
+            h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+            q, k, v = _attn_qkv(h, p, pos, cfg)
+            at = (at_layer(page_w, l, n_pages), pos % ps)
+            kpool = kpool.at[at].set(k, mode="drop")
+            vpool = vpool.at[at].set(v, mode="drop")
+            with jax.named_scope("hgqa.attention"):
+                att = kda_moe.gqa_decode_attention(
+                    q, kpool, vpool, ptc + l * n_pages, pos, length,
+                    n_head=cfg.n_head, n_kv_head=cfg.n_kv_head,
+                    head_dim=cfg.head_dim, dtype=cfg.dtype, page_size=ps)
+            state, conv = slot_entry("state", l), slot_entry("conv", l)
+            with jax.named_scope("ssm.proj"):
+                z, xBC, dt, g = _ssm_proj(h, p, cfg)
+                tail = cache[conv][0]              # [B, back, conv_dim]
+                window = [tail[:, i] for i in range(tail.shape[1])] + [xBC]
+                xs, Bs, Cs = _ssm_conv(window, p, cfg)
+                slot_entries[conv] = jnp.where(
+                    active[:, None, None], jnp.stack(window[1:], axis=1),
+                    tail)[None]
+            with jax.named_scope("ssm.state"):
+                S = cache[state][0].astype(jnp.float32)
+                S_new, y = _ssm_step(
+                    S, xs, _per_head(Bs, cfg), _per_head(Cs, cfg), dt, g,
+                    p["D_skip"].astype(jnp.float32))
+                slot_entries[state] = jnp.where(
+                    active[:, None, None, None], S_new, S
+                ).astype(cache[state].dtype)[None]
+            with jax.named_scope("ssm.proj"):
+                y = _ssm_out(y, z, p, cfg)
+            x = _mlp(x + y + _attn_out(att, p, cfg), p, cfg)
+        logits = _head(x, params, cfg)
+    cache_out = {"k": kpool.reshape(cache["k"].shape),
+                 "v": vpool.reshape(cache["v"].shape), **slot_entries,
+                 "pos": pos + active.astype(jnp.int32)}
+    counts = jnp.stack([jnp.sum(active, dtype=jnp.int32),
+                        cfg.n_layer * fetched])
+    return logits, cache_out, counts
+
+
+# the chunk program and the two factories are the frame's, around this
+# model's step and for this description (``models/serving.py``): the
+# cache the scan carries is pages AND per-slot state, in every layer
+decode_chunk_slots_paged = functools.partial(
+    serving.decode_chunk_slots_paged, step=_slot_decode_step_paged,
+    counters=len(STEP_COUNTERS))
+jit_prefill_into_slot_paged = serving.bind(
+    serving.jit_prefill_into_slot_paged, _THIS)
+jit_decode_chunk_slots_paged = serving.bind(
+    serving.jit_decode_chunk_slots_paged, _THIS)

@@ -345,37 +345,42 @@ def test_the_kda_step_chooses_by_the_head_for_v5e(one_chip, compiled_mode,
 #: (lanes, pages in the flat pool, max_pages): the cell (one GQA layer
 #: of 20,480 pages, 256 lanes of 128 pages) and the benchmark's
 #: reference check (48 rows on a pool of their own)
-GQA_SHAPES = {"cell": (256, 20480, 128), "check": (48, 48 * 16, 16)}
+#: the linear-attention model's cell and check (64 / 8 heads), then the
+#: parallel hybrid's (``models/ssm_hybrid.py``: 20 / 4 heads, five
+#: layers' pools flat, through the PUBLIC entry)
+GQA_SHAPES = {"cell": (256, 20480, 128, 64, 8),
+              "check": (48, 48 * 16, 16, 64, 8),
+              "hybrid-cell": (128, 5 * 10240, 128, 20, 4),
+              "hybrid-check": (8, 5 * 8 * 5, 5, 20, 4)}
 
 
 @pytest.mark.parametrize("shape", sorted(GQA_SHAPES))
 def test_gqa_attention_compiles_for_v5e(one_chip, compiled_mode, shape):
     """The attention's kernel at the served widths (64 query heads over
-    8 KV heads of 128, pages of 16): Mosaic takes a page as 128 rows of
-    ``(token, KV head)``, both pools are the kernel's operands as they
-    lie (no copy, no transposed page), one ``tpu_custom_call``."""
-    import dataclasses
-
+    8 KV heads of 128, and 20 over 4, pages of 16): Mosaic takes a page
+    as ``16 x Hkv`` rows of ``(token, KV head)`` and a lane's 20
+    queries, no whole sublane tile, as one operand; both pools are the
+    kernel's operands as they lie (no copy, no transposed page), one
+    ``tpu_custom_call``, ONE body for both models."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu._private import chip
     from ray_tpu.models import kda_moe
 
-    cfg = dataclasses.replace(kda_moe.CONFIGS["nano"], n_head=64,
-                              n_kv_head=8, head_dim=128)
-    B, n_pages, max_pages = GQA_SHAPES[shape]
+    B, n_pages, max_pages, Hq, Hkv = GQA_SHAPES[shape]
     ps = 16
-    assert kda_moe._gqa_kernel(cfg, ps)
+    assert kda_moe.gqa_kernel(Hkv, 128, jnp.bfloat16, ps)
 
     def arg(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = arg((n_pages, ps, 8, 128))
+    pool = arg((n_pages, ps, Hkv, 128))
     lowered = jax.jit(
-        lambda q, k, v, pages, length: kda_moe._gqa_attention_pallas(
-            q, k, v, pages, length, cfg, ps)).lower(
-        arg((B, 64, 128)), pool, pool, arg((B, max_pages), jnp.int32),
+        lambda q, k, v, pages, length: kda_moe.gqa_decode_attention(
+            q, k, v, pages, None, length, n_head=Hq, n_kv_head=Hkv,
+            head_dim=128, dtype=jnp.bfloat16, page_size=ps)).lower(
+        arg((B, Hq, 128)), pool, pool, arg((B, max_pages), jnp.int32),
         arg((B,), jnp.int32))
     assert chip.compiled_by_mosaic(lowered.as_text())
     compiled = lowered.compile()
@@ -408,3 +413,52 @@ def test_the_gqa_step_chooses_by_the_page_and_head_for_v5e(
             in text) is fused
     assert ("tpu_custom_call" in text) is fused
     assert "kda_state/pallas_call" not in text
+
+
+# ------------------------------- the parallel hybrid's step (ISSUE 52)
+@pytest.mark.parametrize("hd,fused", [(128, True), (64, False)])
+def test_the_hybrid_step_chooses_by_the_page_and_head_for_v5e(
+        one_chip, compiled_mode, hd, fused):
+    """``ssm_hybrid``'s whole decode step as a TPU process builds it,
+    at ``nano`` around 20 query heads over 4 KV heads: with heads of 128
+    (pages of 16 x 4 KV heads) ``kda_moe``'s kernel in EVERY layer,
+    under the path the benchmark's readers look for
+    (``decode_step/hgqa.attention``), the recurrence beside it in plain
+    XLA under ``decode_step/ssm.state``; with heads of 64 the gather,
+    and nothing raises."""
+    import dataclasses
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import ssm_hybrid
+
+    cfg = dataclasses.replace(ssm_hybrid.CONFIGS["nano"], d_model=256,
+                              n_head=20, n_kv_head=4, head_dim=hd,
+                              ssm_heads=8, ssm_head_dim=128, ssm_state=256)
+    B, ps, n_pages = 8, 16, 32
+
+    def arg(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda k: ssm_hybrid.init_params(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(
+        lambda: ssm_hybrid.init_paged_cache(cfg, B, n_pages, ps))
+    args = (params, cache, jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((B, n_pages // B), jnp.int32))
+    lowered = jax.jit(functools.partial(
+        ssm_hybrid._slot_decode_step_paged, cfg=cfg, page_size=ps),
+        donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
+    assert ssm_hybrid.decode_attention_fused(cfg, ps) is fused
+    assert chip.compiled_by_mosaic(lowered.as_text()) is fused
+    text = lowered.compile().as_text()
+    assert ("decode_step/hgqa.attention/gqa_attention/pallas_call"
+            in text) is fused
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == (cfg.n_layer if fused else 0)
+    for scope in ("ssm.state", "ssm.proj", "hybrid.mlp", "lm.head"):
+        assert f"decode_step/{scope}/" in text, scope

@@ -36,11 +36,15 @@ import jax.numpy as jnp
 from ray_tpu.models import kda_moe as km, serving
 
 #: 8 query heads over 2 KV heads (groups of 4), then the cell's group
-#: of 8 over one head more than a power of two.
+#: of 8 over one head more than a power of two, then the parallel
+#: hybrid's heads (``models/ssm_hybrid.py`` through the public entry):
+#: 20 queries, no whole sublane tile, in groups of 5.
 CFGS = {
     "g4": dataclasses.replace(km.CONFIGS["nano"], n_head=8, n_kv_head=2),
     "g8x3": dataclasses.replace(km.CONFIGS["nano"], n_head=24,
                                 n_kv_head=3),
+    "g5x4": dataclasses.replace(km.CONFIGS["nano"], n_head=20,
+                                n_kv_head=4),
 }
 T = 32                        # tokens a block in this file
 #: Positions a lane's table reaches: two whole blocks and half a third.
@@ -110,9 +114,10 @@ def _both(cfg, q, kpool, vpool, pt, pos, active, ps):
     pages = jnp.clip(jnp.asarray(pt), 0, n_pages - 1)
     length = serving.live_length(jnp.asarray(pt), jnp.asarray(pos),
                              jnp.asarray(active), n_pages, ps)
-    out = km._gqa_attention_pallas(q, kpool, vpool, pages, length, cfg, ps)
-    ref = km._gqa_attention_gather(q, kpool, vpool, pages,
-                                   jnp.asarray(pos), cfg, ps)
+    out = km._gqa_attention_pallas(q, kpool, vpool, pages, length,
+                                   cfg.n_kv_head, ps)
+    ref = km._gqa_attention_gather(q, kpool, vpool, pages, jnp.asarray(pos),
+                                   cfg.n_kv_head, cfg.dtype, ps)
     return np.asarray(out), np.asarray(ref), np.asarray(length)
 
 

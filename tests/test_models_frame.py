@@ -1,4 +1,4 @@
-"""The serving frame (``ray_tpu/models/serving.py``) and the four model
+"""The serving frame (``ray_tpu/models/serving.py``) and the five model
 descriptions around it.
 
 - no module under ``ray_tpu/models`` imports, or reads off another
@@ -21,7 +21,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import gpt, gpt_decode, kda_moe, mla_moe, scmoe, serving
+from ray_tpu.models import (gpt, gpt_decode, kda_moe, mla_moe, scmoe,
+                            serving, ssm_hybrid)
 from ray_tpu.serve.engine import DecodeEngine
 
 MODELS = os.path.dirname(os.path.abspath(serving.__file__))
@@ -124,6 +125,7 @@ DESCRIPTIONS = {
     mla_moe: (4, 32, 4, 8),
     kda_moe: (4, 32, 4, 8),
     scmoe: (4, 32, 4, 8),
+    ssm_hybrid: (4, 32, 4, 8),
 }
 
 
@@ -245,7 +247,7 @@ def test_every_description_says_whether_its_chunk_program_holds_a_kernel():
     nano = gpt.CONFIGS["nano"]
     assert [gpt_decode.decode_attention_fused(nano, 8, kernel)
             for kernel in gpt_decode.ATTN_KERNELS] == [False, True]
-    for desc in (mla_moe, kda_moe, scmoe):
+    for desc in (mla_moe, kda_moe, scmoe, ssm_hybrid):
         assert desc.decode_attention_fused(_model(desc)[0], 4, "gather")
     assert scmoe.decode_attention_fused is mla_moe.decode_attention_fused
 
