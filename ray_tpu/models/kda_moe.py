@@ -425,19 +425,6 @@ def decode_attention_fused(cfg: KDAMoEConfig, page_size: int,
                                   and _gqa_kernel(cfg, page_size))
 
 
-def _live_lanes(active):
-    """``active`` [B] bool -> ``(lanes int32 [B], n int32 [1])``: the
-    live lanes' indices in lane order, then the last of them repeated
-    (lane 0 where none is live), and their count: the scalar operands
-    of :func:`_kda_step_pallas` (the same for every layer of a step:
-    XLA keeps one of them)."""
-    B = active.shape[0]
-    n = jnp.sum(active, dtype=jnp.int32)
-    lanes = jnp.nonzero(active, size=B, fill_value=0)[0].astype(jnp.int32)
-    return jnp.where(jnp.arange(B) < n, lanes,
-                     lanes[jnp.maximum(n - 1, 0)]), n[None]
-
-
 def _kda_step_pallas(state, layer: int, q, k, v, g, beta, active):
     """:func:`_kda_step` on layer ``layer`` of the whole per-slot entry
     ``state`` [n_kda, B, H, dk, dv], IN PLACE, as one kernel that reads
@@ -448,9 +435,9 @@ def _kda_step_pallas(state, layer: int, q, k, v, g, beta, active):
 
     Grid ``(B, H / hb)``: step ``(i, j)`` is block ``j``
     (:data:`_KDA_BLOCK_HEADS` heads, ``[hb, dk, dv]``) of the ``i``-th
-    LIVE lane, named by the scalar-prefetched :func:`_live_lanes`; the
-    steps past
-    the last live lane name the block before them again, which the
+    LIVE lane, named by the scalar-prefetched
+    :func:`ray_tpu.models.serving.live_lanes`; the steps past the last
+    live lane name the block before them again, which the
     pipeline neither fetches nor writes twice, and do nothing. The
     state is the kernel's input AND output (``input_output_aliases`` on
     the whole entry, the layer in the index map): a block comes into
@@ -482,7 +469,7 @@ def _kda_step_pallas(state, layer: int, q, k, v, g, beta, active):
         [jnp.broadcast_to(r, v.shape).reshape(B, nb, hb, dv) for r in
          (v, beta[..., None], jnp.sum(k * q, axis=-1, keepdims=True))],
         axis=2)                                      # [B, nb, 3, hb, dv]
-    lanes, n = _live_lanes(active)
+    lanes, n = serving.live_lanes(active)
 
     def kernel(lanes_ref, n_ref, s_ref, cols_ref, rows_ref, s_out, o_ref):
         i, j = pl.program_id(0), pl.program_id(1)

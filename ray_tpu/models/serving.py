@@ -67,8 +67,9 @@ page_size, kv_dtype, attn_kernel)``
     attention over latent pages, by shape, ``kda_moe`` for TWO kernels,
     the recurrence on its per-slot state and its GQA layers' attention
     over pages, each taken by its own shapes: either one makes the
-    answer true, ``ssm_hybrid`` for that attention's kernel alone (its
-    recurrence is plain XLA). The engine asks it for
+    answer true, ``ssm_hybrid`` for two as well, that attention's
+    kernel and its own recurrence's on the per-slot state-space state,
+    each by its own shapes. The engine asks it for
     ``warm_up()["attn_kernel_mode"]`` (``"compiled"`` / ``"interpret"``,
     read off the lowered program, or ``None`` without a kernel) and for
     ``stats()["attn_kernel_dispatches"]``; every description answers.
@@ -116,8 +117,8 @@ cfg, ...)`` as before):
 - sampling (:func:`sample`, :func:`sample_slots`) and the paged pool's
   addressing that more than one model uses: :data:`PT_SENTINEL`,
   :func:`init_paged_pool`, :func:`flat`, :func:`at_layer`,
-  :func:`live_length`, and a prefill's read of its cached prefix
-  (:func:`hist_blocks`, :func:`attend_history`).
+  :func:`live_length`, :func:`live_lanes`, and a prefill's read of its
+  cached prefix (:func:`hist_blocks`, :func:`attend_history`).
 
 No module under ``ray_tpu/models`` imports or reads an underscore name
 of another (``tests/test_models_frame.py`` holds it; the training
@@ -329,6 +330,20 @@ def live_length(pt, pos, active, n_pages: int, page_size: int):
                      axis=1)
     return jnp.where(active, jnp.minimum(pos.astype(jnp.int32) + 1,
                                          mapped * page_size), 0)
+
+
+def live_lanes(active):
+    """``active`` [B] bool -> ``(lanes int32 [B], n int32 [1])``: the
+    live lanes' indices in lane order, then the last of them repeated
+    (lane 0 where none is live), and their count: the scalar operands
+    of a kernel whose grid runs over the live lanes of a per-slot entry
+    (the recurrences of ``kda_moe`` and ``ssm_hybrid``; the same for
+    every layer of a step: XLA keeps one of them)."""
+    B = active.shape[0]
+    n = jnp.sum(active, dtype=jnp.int32)
+    lanes = jnp.nonzero(active, size=B, fill_value=0)[0].astype(jnp.int32)
+    return jnp.where(jnp.arange(B) < n, lanes,
+                     lanes[jnp.maximum(n - 1, 0)]), n[None]
 
 
 #: Tokens of cached prefix a paged prefill reads at once

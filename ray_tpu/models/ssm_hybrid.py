@@ -51,10 +51,13 @@ the other, by layer):
   the SLOT: every prefill rebuilds them from zero (the chunked form,
   scope ``ssm.prefill``: inside a chunk the masked quadratic form with
   ``exp`` of the decays' running sums, between chunks the state passed
-  on), every decode step reads a live lane's state and writes it in
-  place (scope ``ssm.state``, plain XLA in float32), and an idle or
-  parked lane's comes out as it went in. So no page hash shares them
-  and nothing rolls back: :data:`UNSUPPORTED`.
+  on), every decode step reads a live lane's state ONCE and writes it
+  once, in place (scope ``ssm.state``: one Pallas kernel a layer,
+  :func:`_ssm_step_pallas`, in float32, wherever Mosaic can address a
+  head's state, :func:`_state_kernel`; plain XLA, :func:`_ssm_step`,
+  elsewhere), and an idle or parked lane's comes out as it went in, not
+  a byte of it moved. So no page hash shares them and nothing rolls
+  back: :data:`UNSUPPORTED`.
 
 **MLP**, every layer, dense: ``W_down[silu((v W_gate) * mlp_mults[0])
 * (v W_up)] * mlp_mults[1]`` (scope ``hybrid.mlp``). The table's rows
@@ -111,6 +114,15 @@ UNSUPPORTED = {
 #: attention fetched from the pools, all layers
 #: (:func:`ray_tpu.models.kda_moe.gqa_decode_reads`).
 STEP_COUNTERS = ("state_lanes_sum", "gqa_tokens_read_sum")
+#: Heads of a lane's state the recurrence's kernel holds in VMEM at
+#: once (:func:`_block_heads` fits it to the groups): 16 heads of [128,
+#: 256] float32 are 2 MiB, 8 MiB with the block before and the block
+#: after in flight, in and out (of the 16 MiB a kernel may take on a
+#: v5e; 32 heads need the limit raised). Measured there, alone: 8 / 16
+#: / 32 heads 1.73 / 1.68 / 1.71 ms a layer of 127 live lanes, the
+#: copies alone 1.70 / 1.67 / 1.69, the XLA body 2.36 (PERF.md section
+#: 6, PR 53).
+_SSM_BLOCK_HEADS = 16
 _HI = lax.Precision.HIGHEST
 
 
@@ -428,18 +440,158 @@ def _ssm_out(y, z, p, cfg: SSMHybridConfig):
 
 
 def _ssm_step(S, x, B, C, dt, g, D):
-    """The recurrence, one token a lane, in plain XLA float32: ``S``
-    [B, H, P, N], ``x`` [B, H, P], ``B`` ``C`` [B, H, N] (per head),
-    ``dt`` ``g`` [B, H], ``D`` [H]. Returns ``(S', y [B, H, P])``.
-    ``y = S' C + D x`` is taken from the state BEFORE the step, ``a (S
-    C) + dt x (B . C)``: the sum and the update both read the old
-    state, one pass each way, and the new state's one reader is the
-    write in place."""
+    """The recurrence, one token a lane, in plain XLA float32: the
+    fallback where :func:`_state_kernel` is false, and the oracle the
+    kernel (:func:`_ssm_step_pallas`) is tested against. ``S`` [B, H,
+    P, N], ``x`` [B, H, P], ``B`` ``C`` [B, H, N] (per head), ``dt``
+    ``g`` [B, H], ``D`` [H]. Returns ``(S', y [B, H, P])``. ``y = S' C
+    + D x`` is taken from the state BEFORE the step, ``a (S C) + dt x
+    (B . C)``: the sum and the update both read the old state, and the
+    caller's select against the old state for an inactive lane reads it
+    a third time: three passes where the kernel has two, 55.6% of the
+    state's bandwidth bound on a v5e."""
     a = jnp.exp(g)
     dx = dt[..., None] * x
     y = a[..., None] * jnp.sum(S * C[..., None, :], axis=-1) \
         + dx * jnp.sum(B * C, axis=-1)[..., None] + D[:, None] * x
     return S * a[..., None, None] + dx[..., None] * B[..., None, :], y
+
+
+def _state_kernel(cfg: SSMHybridConfig) -> bool:
+    """Whether the step's recurrence is :func:`_ssm_step_pallas`:
+    wherever Mosaic can address a head's state as whole tiles of the
+    state dtype with ``P`` on sublanes and ``N`` on lanes, as the entry
+    lies (``ssm_state`` a multiple of 128 and ``ssm_head_dim`` of 8 in
+    float32, 16 in bfloat16) compiled for a TPU, any width interpreted
+    off it; elsewhere :func:`_ssm_step`."""
+    from .._private.chip import pallas_interpret
+
+    rows = 32 // jnp.dtype(cfg.state_dtype).itemsize
+    return pallas_interpret() or (cfg.ssm_state % 128 == 0
+                                  and cfg.ssm_head_dim % rows == 0)
+
+
+def _block_heads(heads: int, groups: int) -> int:
+    """Heads a block of the kernel: the most, up to
+    :data:`_SSM_BLOCK_HEADS`, that divide the heads into blocks of
+    whole groups or blocks inside one group (a block then names its
+    ``B`` and ``C`` by a block of groups)."""
+    per_group = heads // groups
+    return max(d for d in range(1, min(heads, _SSM_BLOCK_HEADS) + 1)
+               if heads % d == 0
+               and (d % per_group == 0 or per_group % d == 0))
+
+
+def _ssm_step_pallas(state, x, B, C, dt, g, D, active):
+    """:func:`_ssm_step` on a layer's whole per-slot entry ``state``
+    [1, slots, H, P, N], IN PLACE, as one kernel that reads a live
+    lane's state once and writes it once. ``x`` [B, H, P], ``B`` ``C``
+    [B, G, N] (per GROUP: the kernel reads a group's once for all its
+    heads), ``dt`` ``g`` [B, H], ``D`` [H] float32, ``active`` [B]
+    bool. Returns ``(state', y [B, H, P])`` with zeros in ``y`` for an
+    inactive lane, whose state no byte of is moved.
+
+    Grid ``(B, H / hb)``: step ``(i, j)`` is block ``j``
+    (:func:`_block_heads` heads, ``[hb, P, N]``) of the ``i``-th LIVE
+    lane, named by the scalar-prefetched
+    :func:`ray_tpu.models.serving.live_lanes`; the steps past the last
+    live lane name the block before them again, which the pipeline
+    neither fetches nor writes twice, and do nothing. The state is the
+    kernel's input AND output (``input_output_aliases`` on the whole
+    entry): a block comes into VMEM, and the sum ``S C``, the decay
+    ``a S``, the rank-one term ``dt x (x) B`` and ``y`` are taken from
+    that one copy in float32 on the VPU, operation for operation
+    :func:`_ssm_step`'s, and the block goes back where it came from.
+    The state lies as the entry holds it, ``N`` on lanes: the sum over
+    it is a cross-lane reduction of a head's 16 row tiles, which hides
+    under the block's copies (PERF.md section 6, PR 53: within 1.5% of
+    an entry held ``[N, P]``, whose sum adds whole vregs). The small
+    operands are laid out by the caller's XLA, under the same scope:
+    ``dt x``, ``a``, ``B . C`` and ``D x`` TRANSPOSED ``[B, H / hb, P,
+    4 hb]`` so that a head's is a column over ``P`` (one lane,
+    broadcast over ``N``) and so is its ``y``; ``B`` and ``C`` as rows
+    over ``N``, a group's for all its heads. Where no lane is live the
+    one block named is copied through, so that the aliased entry comes
+    out as it went in."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .._private.chip import pallas_interpret
+
+    Bn, H, P = x.shape
+    G, N = B.shape[1:]
+    hb = _block_heads(H, G)
+    nb, per_group = H // hb, H // G
+    gb = max(1, hb // per_group)             # groups a block reads
+    a = jnp.exp(g)
+    dx = dt[..., None] * x
+    cols = jnp.stack([
+        dx, jnp.broadcast_to(a[..., None], x.shape),
+        jnp.broadcast_to(jnp.repeat(jnp.sum(B * C, axis=-1), per_group,
+                                    axis=-1)[..., None], x.shape),
+        D[:, None] * x], axis=2)                             # [B, H, 4, P]
+    cols = cols.reshape(Bn, nb, hb, 4, P).transpose(0, 1, 4, 3, 2) \
+        .reshape(Bn, nb, P, 4 * hb)
+    rows = jnp.stack([B, C], axis=2)                         # [B, G, 2, N]
+    lanes, n = serving.live_lanes(active)
+
+    def kernel(lanes_ref, n_ref, s_ref, cols_ref, rows_ref, s_out, y_ref):
+        i, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(i < n_ref[0])
+        def _():
+            c = cols_ref[...]                                # [P, 4 hb]
+            for h in range(hb):
+                S = s_ref[h].astype(jnp.float32)             # [P, N]
+                Bh, Ch = (rows_ref[h // per_group, m:m + 1]
+                          for m in range(2))                 # [1, N]
+                dxh, ah, bc, Dx = (c[:, m * hb + h:m * hb + h + 1]
+                                   for m in range(4))        # [P, 1]
+                y_ref[:, h:h + 1] = ah * jnp.sum(
+                    S * Ch, axis=-1, keepdims=True) + dxh * bc + Dx
+                s_out[h] = (S * ah + dxh * Bh).astype(s_out.dtype)
+
+        @pl.when((n_ref[0] == 0) & (i == 0) & (j == 0))
+        def _():
+            s_out[...] = s_ref[...]
+
+    def at(*lead, groups=False):
+        """The index map of an operand whose leading indices are
+        ``lead`` (statics), then the lane and the block of heads (or
+        the block of groups that holds those heads), then two whole
+        dimensions."""
+        def index(i, j, lanes_ref, n_ref):
+            j = jnp.where(i < n_ref[0], j, nb - 1)
+            if groups and hb < per_group:
+                j = j * hb // per_group
+            return lead + (lanes_ref[i], j, 0, 0)
+        return index
+
+    state, y = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(Bn, nb),
+            in_specs=[
+                pl.BlockSpec((None, None, hb, P, N), at(0)),
+                pl.BlockSpec((None, None, P, 4 * hb), at()),
+                pl.BlockSpec((None, gb, 2, N), at(groups=True)),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, None, hb, P, N), at(0)),
+                pl.BlockSpec((None, None, P, hb), at()),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((Bn, nb, P, hb), jnp.float32)],
+        # operand 2 (after the two scalar operands) is the state
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=pallas_interpret(),
+        name="ssm_state",
+    )(lanes, n, state, cols, rows)
+    y = y.transpose(0, 1, 3, 2).reshape(Bn, H, P)
+    return state, jnp.where(active[:, None, None], y, 0.0)
 
 
 def _ssd_chunked(x, B, C, dt, g, S0, chunk: int):
@@ -588,16 +740,27 @@ def max_positions(cfg: SSMHybridConfig) -> int:
     return cfg.max_seq
 
 
+def _gqa_kernel(cfg: SSMHybridConfig, page_size: int) -> bool:
+    """:func:`ray_tpu.models.kda_moe.gqa_kernel` at this model's heads
+    and compute dtype: whether decode's attention is the kernel over
+    each lane's live pages (the other reading is the gather over its
+    whole table row)."""
+    return kda_moe.gqa_kernel(cfg.n_kv_head, cfg.head_dim, cfg.dtype,
+                              page_size)
+
+
 def decode_attention_fused(cfg: SSMHybridConfig, page_size: int,
                            attn_kernel: str = "gather") -> bool:
     """Whether the chunk program built with these knobs holds a Pallas
-    kernel (the description's entry, :mod:`ray_tpu.models.serving`):
-    its attention over pages, taken by what the program can see of the
-    page and the head (:func:`ray_tpu.models.kda_moe.gqa_kernel`).
-    ``attn_kernel`` (one value) has no say; the recurrence is plain
-    XLA."""
-    return kda_moe.gqa_kernel(cfg.n_kv_head, cfg.head_dim, cfg.dtype,
-                              page_size)
+    kernel (the description's entry, :mod:`ray_tpu.models.serving`).
+    This model has TWO, each taken by what the program can see of its
+    own shapes: its ATTENTION over pages
+    (:func:`ray_tpu.models.kda_moe.gqa_kernel`: from the page and the
+    head) and the RECURRENCE on the per-slot state
+    (:func:`_ssm_step_pallas`, :func:`_state_kernel`: from the state's
+    head); the answer is for the program, so either one makes it true.
+    ``attn_kernel`` (one value) has no say."""
+    return _state_kernel(cfg) or _gqa_kernel(cfg, page_size)
 
 
 # what follows from the spec and from ``UNSUPPORTED["tp"]``: the frame's
@@ -685,9 +848,11 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     position and attends over its pages
     (:func:`ray_tpu.models.kda_moe.gqa_decode_attention`: the kernel
     over its live pages or the gather over its whole table row, by
-    shape), AND reads and writes its state and convolution tail whole.
-    An inactive lane (idle, or parked for pages) neither writes nor
-    advances: its state and tail come out as they went in. Returns
+    shape), AND reads and writes its state and convolution tail whole
+    (the state through :func:`_ssm_step_pallas` or :func:`_ssm_step`,
+    by shape: :func:`_state_kernel`). An inactive lane (idle, or parked
+    for pages) neither writes nor advances: its state and tail come out
+    as they went in. Returns
     ``(logits [B, rows], cache', counts)``: int32 [2]
     (:data:`STEP_COUNTERS`)."""
     ps = page_size
@@ -704,8 +869,7 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     kpool, vpool = flat(cache["k"]), flat(cache["v"])
     slot_entries = {}
     length, fetched = kda_moe.gqa_decode_reads(
-        pt, pos, active, n_pages, ps,
-        decode_attention_fused(cfg, ps, attn_kernel))
+        pt, pos, active, n_pages, ps, _gqa_kernel(cfg, ps))
     # the step's own scope: a reader tells the decode program's state,
     # attention, MLP and head time from prefill's by it
     with jax.named_scope("decode_step"):
@@ -730,13 +894,18 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                     active[:, None, None], jnp.stack(window[1:], axis=1),
                     tail)[None]
             with jax.named_scope("ssm.state"):
-                S = cache[state][0].astype(jnp.float32)
-                S_new, y = _ssm_step(
-                    S, xs, _per_head(Bs, cfg), _per_head(Cs, cfg), dt, g,
-                    p["D_skip"].astype(jnp.float32))
-                slot_entries[state] = jnp.where(
-                    active[:, None, None, None], S_new, S
-                ).astype(cache[state].dtype)[None]
+                D = p["D_skip"].astype(jnp.float32)
+                if _state_kernel(cfg):
+                    slot_entries[state], y = _ssm_step_pallas(
+                        cache[state], xs, Bs, Cs, dt, g, D, active)
+                else:
+                    S = cache[state][0].astype(jnp.float32)
+                    S_new, y = _ssm_step(
+                        S, xs, _per_head(Bs, cfg), _per_head(Cs, cfg), dt,
+                        g, D)
+                    slot_entries[state] = jnp.where(
+                        active[:, None, None, None], S_new, S
+                    ).astype(cache[state].dtype)[None]
             with jax.named_scope("ssm.proj"):
                 y = _ssm_out(y, z, p, cfg)
             x = _mlp(x + y + _attn_out(att, p, cfg), p, cfg)

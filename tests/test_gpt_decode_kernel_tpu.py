@@ -1,7 +1,8 @@
 """The decode step's kernels (``gpt_decode``'s attention over pages of
 keys and values per head, ``mla_moe``'s over latent pages, ``kda_moe``'s
 recurrence on the per-slot state and its grouped-query attention over
-pages of ``(token, KV head)`` rows) compiled by the
+pages of ``(token, KV head)`` rows, ``ssm_hybrid``'s recurrence on its
+state-space state) compiled by the
 TPU's own compiler, for a chip that is described and not attached
 (v5e), at the shapes the chip runs: what interpret mode cannot see —
 a slice off the tiling, a DMA Mosaic cannot address, more VMEM than a
@@ -416,16 +417,21 @@ def test_the_gqa_step_chooses_by_the_page_and_head_for_v5e(
 
 
 # ------------------------------- the parallel hybrid's step (ISSUE 52)
-@pytest.mark.parametrize("hd,fused", [(128, True), (64, False)])
+@pytest.mark.parametrize("hd,ssm_state,gqa,state", [
+    (128, 256, True, True), (64, 256, False, True),
+    (128, 192, True, False), (64, 192, False, False)])
 def test_the_hybrid_step_chooses_by_the_page_and_head_for_v5e(
-        one_chip, compiled_mode, hd, fused):
+        one_chip, compiled_mode, hd, ssm_state, gqa, state):
     """``ssm_hybrid``'s whole decode step as a TPU process builds it,
-    at ``nano`` around 20 query heads over 4 KV heads: with heads of 128
+    at ``nano`` around 20 query heads over 4 KV heads and 8 state heads
+    of 128 channels, TWO choices by shape: with attention heads of 128
     (pages of 16 x 4 KV heads) ``kda_moe``'s kernel in EVERY layer,
     under the path the benchmark's readers look for
-    (``decode_step/hgqa.attention``), the recurrence beside it in plain
-    XLA under ``decode_step/ssm.state``; with heads of 64 the gather,
-    and nothing raises."""
+    (``decode_step/hgqa.attention``), with heads of 64 the gather; with
+    a state 256 wide (whole 128-lane tiles) the recurrence's kernel
+    (ISSUE 53) once a layer under ``decode_step/ssm.state``, the
+    layer's entry aliased whole and nothing state-sized beside it, with
+    192 (off the tile) ``_ssm_step``; and nothing raises."""
     import dataclasses
     import functools
 
@@ -437,7 +443,8 @@ def test_the_hybrid_step_chooses_by_the_page_and_head_for_v5e(
 
     cfg = dataclasses.replace(ssm_hybrid.CONFIGS["nano"], d_model=256,
                               n_head=20, n_kv_head=4, head_dim=hd,
-                              ssm_heads=8, ssm_head_dim=128, ssm_state=256)
+                              ssm_heads=8, ssm_head_dim=128,
+                              ssm_state=ssm_state)
     B, ps, n_pages = 8, 16, 32
 
     def arg(s):
@@ -453,12 +460,72 @@ def test_the_hybrid_step_chooses_by_the_page_and_head_for_v5e(
     lowered = jax.jit(functools.partial(
         ssm_hybrid._slot_decode_step_paged, cfg=cfg, page_size=ps),
         donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
-    assert ssm_hybrid.decode_attention_fused(cfg, ps) is fused
-    assert chip.compiled_by_mosaic(lowered.as_text()) is fused
-    text = lowered.compile().as_text()
+    assert ssm_hybrid._state_kernel(cfg) is state
+    assert ssm_hybrid.decode_attention_fused(cfg, ps) is (gqa or state)
+    assert chip.compiled_by_mosaic(lowered.as_text()) is (gqa or state)
+    compiled = lowered.compile()
+    text = compiled.as_text()
     assert ("decode_step/hgqa.attention/gqa_attention/pallas_call"
-            in text) is fused
+            in text) is gqa
+    assert ("decode_step/ssm.state/ssm_state/pallas_call" in text) is state
     assert text.count('custom_call_target="tpu_custom_call"') \
-        == (cfg.n_layer if fused else 0)
+        == cfg.n_layer * (gqa + state)
     for scope in ("ssm.state", "ssm.proj", "hybrid.mlp", "lm.head"):
         assert f"decode_step/{scope}/" in text, scope
+    layer_state = B * 8 * 128 * ssm_state * 4
+    memory = compiled.memory_analysis()
+    # every entry of the donated pool comes out in place
+    assert memory.alias_size_in_bytes >= cfg.n_layer * layer_state
+    if state:
+        assert memory.temp_size_in_bytes < layer_state // 2
+
+
+# ---------------------------- the Mamba-2 recurrence's kernel (ISSUE 53)
+#: lanes: the cell's per-slot entries (128 slots, one array a layer) and
+#: the benchmark's reference check (``served_logits`` at its 8 rows)
+SSM_SHAPES = {"cell": 128, "check": 8}
+
+
+@pytest.mark.parametrize("shape", sorted(SSM_SHAPES))
+def test_ssm_state_kernel_compiles_for_v5e(one_chip, compiled_mode, shape):
+    """The recurrence's kernel at the served widths (32 heads of a
+    [128, 256] float32 state in 2 groups, five layers, one entry each):
+    Mosaic takes a head's ``dt x`` as one lane of the transposed
+    operand and reduces ``S C`` across lanes, every layer's whole entry
+    is its kernel's operand AND result (aliased: no copy of it), one
+    ``tpu_custom_call`` a layer."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import ssm_hybrid
+
+    B = SSM_SHAPES[shape]
+    L, H, G, P, N = 5, 32, 2, 128, 256
+    assert ssm_hybrid._block_heads(H, G) == 16
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(states, x, Bs, Cs, dt, g, D, active):
+        out = []
+        for state in states:
+            state, y = ssm_hybrid._ssm_step_pallas(state, x, Bs, Cs, dt, g,
+                                                   D, active)
+            out.append(state)
+            x = x + y
+        return out, x
+
+    lowered = jax.jit(step, donate_argnums=(0,)).lower(
+        [arg((1, B, H, P, N))] * L, arg((B, H, P)), arg((B, G, N)),
+        arg((B, G, N)), arg((B, H)), arg((B, H)), arg((H,)),
+        arg((B,), jnp.bool_))
+    assert chip.compiled_by_mosaic(lowered.as_text())
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == L
+    assert "ssm_state/pallas_call" in text
+    layer_state = B * H * P * N * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == L * layer_state
+    assert memory.temp_size_in_bytes < layer_state // 16

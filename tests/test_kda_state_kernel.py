@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import kda_moe as km
+from ray_tpu.models import serving
 from ray_tpu.serve.engine import DecodeEngine
 
 #: Kernel and oracle hold the same float32 products and add them in
@@ -118,11 +119,11 @@ def test_the_state_keeps_its_dtype_and_the_arithmetic_is_float32(dtype):
 
 
 def test_the_live_lanes_ride_in_lane_order_then_the_last_again():
-    lanes, n = km._live_lanes(jnp.asarray([False, True, False, True,
+    lanes, n = serving.live_lanes(jnp.asarray([False, True, False, True,
                                            True, False]))
     assert list(np.asarray(lanes)) == [1, 3, 4, 4, 4, 4]
     assert list(np.asarray(n)) == [3]
-    lanes, n = km._live_lanes(jnp.zeros((4,), bool))
+    lanes, n = serving.live_lanes(jnp.zeros((4,), bool))
     assert list(np.asarray(lanes)) == [0, 0, 0, 0] and int(n[0]) == 0
 
 

@@ -412,15 +412,24 @@ def test_the_counters_come_out_with_the_tokens(model, engine):
 def test_the_step_takes_the_attention_by_shape_and_both_agree(
         model32, monkeypatch, kernel):
     """No knob: the step asks ``kda_moe.gqa_kernel`` of its page and
-    heads. In float32 the kernel's logits are the gather's to 1e-4, and
-    the counter says what each fetched."""
+    heads and ``_state_kernel`` of the state's head (ISSUE 53: the
+    recurrence's kernel, its own file's subject: the ``gather`` case
+    steers BOTH off, so that the description reads a program without a
+    kernel). In float32 the kernels' logits are the fallbacks' to 1e-4,
+    and the counter says what each attention fetched."""
     cfg, params = model32
     prompt = _prompts(cfg, (13,), seed=9)[0]
     _, cache, pt = _prefilled(model32, prompt, 16)
     if not kernel:
         monkeypatch.setattr(kda_moe, "gqa_kernel",
                             lambda *a, **k: False)
+        monkeypatch.setattr(ssm_hybrid, "_state_kernel", lambda cfg: False)
     assert ssm_hybrid.decode_attention_fused(cfg, 4) is kernel
+    step = str(jax.make_jaxpr(functools.partial(
+        ssm_hybrid._slot_decode_step_paged, cfg=cfg, page_size=4))(
+        params, cache, jnp.zeros((3,), jnp.int32), np.zeros((3,), bool),
+        jnp.asarray(pt)))
+    assert step.count("pallas_call") == (2 * cfg.n_layer if kernel else 0)
     logits, _, counts = ssm_hybrid._slot_decode_step_paged(
         params, cache, jnp.asarray([0, 5, 0]),
         np.array([False, True, False]), jnp.asarray(pt), cfg, 4)

@@ -1,0 +1,304 @@
+"""The Mamba-2 recurrence of a decode step as a Pallas kernel (ISSUE
+53): the kernel of ``ray_tpu/models/ssm_hybrid.py`` against
+``_ssm_step``, the XLA body it replaces wherever Mosaic can address a
+head's state, which stays in the file as the fallback and as this
+file's oracle.
+
+The contract under test:
+
+- the kernel (interpreted here: tier-1 exercises the REAL body) takes
+  the sum ``S C``, the decay, the rank-one term and ``y`` from ONE copy
+  of a block of a live lane's heads, in float32, ``y`` from the state
+  BEFORE the step. It sums over ``N`` in another order than XLA's
+  reduction does, so the two agree to a WRITTEN BOUND, :data:`REL` of
+  the largest value, not bit for bit;
+- an inactive lane's state comes out BIT FOR BIT as it went in and it
+  reads ``y`` = 0; the entry keeps its dtype;
+- a group's ``B`` and ``C`` reach that group's heads and no other,
+  whether a block holds part of a group, one, or several;
+- the step takes the kernel by what it can see (no knob, no new
+  ``attn_kernel`` name) and says which through the description's
+  ``decode_attention_fused``; the engine reports and counts it.
+"""
+import dataclasses
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import kda_moe, serving
+from ray_tpu.models import ssm_hybrid as sh
+from ray_tpu.serve.engine import DecodeEngine
+
+#: Kernel and oracle hold the same float32 products and add them in
+#: another order: ``N`` = 32 addends of one sign-mixed sum, each rounded
+#: to 2^-24 of the partial sum, far under 1e-5 of the largest value; a
+#: bfloat16 product anywhere over ``S`` would read 4e-3.
+REL = 1e-5
+H, G, P, N = 8, 2, 16, 32
+
+
+def _inputs(B, seed, groups=G):
+    rng = np.random.default_rng(seed)
+    state = rng.normal(size=(1, B, H, P, N))
+    x = rng.normal(size=(B, H, P))
+    Bs = rng.normal(size=(B, groups, N))
+    Cs = rng.normal(size=(B, groups, N))
+    dt = rng.uniform(0.001, 0.2, size=(B, H))
+    g = -rng.uniform(0.001, 3.0, size=(B, H))
+    D = rng.normal(size=(H,)) + 1.0
+    return tuple(jnp.asarray(a, jnp.float32)
+                 for a in (state, x, Bs, Cs, dt, g, D))
+
+
+def _oracle(state, x, Bs, Cs, dt, g, D):
+    """``_ssm_step`` on the entry's one layer, ``B`` and ``C`` a head."""
+    per = x.shape[1] // Bs.shape[1]
+    return sh._ssm_step(state[0].astype(jnp.float32), x,
+                        jnp.repeat(Bs, per, axis=1),
+                        jnp.repeat(Cs, per, axis=1), dt, g, D)
+
+
+def _rel(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    return np.abs(got - want).max() / np.abs(want).max()
+
+
+MASKS = {
+    "all-live": lambda B: np.ones((B,), bool),
+    # the first lane and, where there is one, a lane in the middle
+    "some-parked": lambda B: np.arange(B) % 3 != 0 if B > 1
+    else np.zeros((B,), bool),
+    "last-live": lambda B: np.arange(B) == B - 1,
+    "all-idle": lambda B: np.zeros((B,), bool),
+}
+
+
+@pytest.mark.parametrize("mask", sorted(MASKS))
+@pytest.mark.parametrize("heads_a_block", [1, 2, 4, 8])
+@pytest.mark.parametrize("B", [1, 3, 8])
+def test_the_kernel_is_the_recurrence_on_the_live_lanes(
+        monkeypatch, B, heads_a_block, mask):
+    """Blocks of 1 and 2 heads lie inside a group of 4, one of 4 is a
+    group, one of 8 both groups."""
+    monkeypatch.setattr(sh, "_SSM_BLOCK_HEADS", heads_a_block)
+    assert sh._block_heads(H, G) == heads_a_block
+    state, *ops = _inputs(B, seed=B)
+    active = MASKS[mask](B)
+    got_state, got_y = jax.jit(
+        lambda s, a: sh._ssm_step_pallas(s, *ops, a))(
+        state, jnp.asarray(active))
+    want_S, want_y = _oracle(state, *ops)
+    got_state, state = np.asarray(got_state), np.asarray(state)
+    assert got_state.shape == state.shape
+    # an inactive lane: not a bit of its state, and y = 0
+    assert np.array_equal(got_state[0][~active], state[0][~active])
+    assert not np.asarray(got_y)[~active].any()
+    if active.any():
+        assert _rel(got_state[0][active], np.asarray(want_S)[active]) < REL
+        assert _rel(np.asarray(got_y)[active],
+                    np.asarray(want_y)[active]) < REL
+        assert not np.array_equal(got_state[0][active], state[0][active])
+
+
+@pytest.mark.parametrize("block,heads,groups,want", [
+    (16, 32, 2, 16), (8, 32, 2, 8), (32, 32, 2, 32),   # the cell's
+    (16, 4, 2, 4), (16, 6, 2, 6), (4, 12, 4, 3),       # odd groups
+    (2, 12, 4, 1), (16, 24, 1, 12), (16, 24, 8, 12)])
+def test_a_block_is_whole_groups_or_lies_inside_one(
+        monkeypatch, block, heads, groups, want):
+    monkeypatch.setattr(sh, "_SSM_BLOCK_HEADS", block)
+    hb = sh._block_heads(heads, groups)
+    per = heads // groups
+    assert hb == want and heads % hb == 0
+    assert hb % per == 0 or per % hb == 0
+    assert hb <= per or groups % (hb // per) == 0
+
+
+@pytest.mark.parametrize("heads_a_block", [2, 4, 8])
+def test_a_group_of_heads_reads_its_own_B_and_C(monkeypatch,
+                                                heads_a_block):
+    """Moving one group's ``B`` and ``C`` moves that group's heads'
+    state and ``y`` and leaves the other group's bits alone."""
+    monkeypatch.setattr(sh, "_SSM_BLOCK_HEADS", heads_a_block)
+    state, x, Bs, Cs, dt, g, D = _inputs(3, seed=7)
+    active = jnp.ones((3,), bool)
+    s0, y0 = sh._ssm_step_pallas(state, x, Bs, Cs, dt, g, D, active)
+    s1, y1 = sh._ssm_step_pallas(state, x, Bs.at[:, 1].mul(2.0),
+                                 Cs.at[:, 1].mul(-1.0), dt, g, D, active)
+    first, second = slice(0, H // G), slice(H // G, H)
+    assert np.array_equal(np.asarray(s0)[0][:, first],
+                          np.asarray(s1)[0][:, first])
+    assert np.array_equal(np.asarray(y0)[:, first], np.asarray(y1)[:, first])
+    assert not np.array_equal(np.asarray(s0)[0][:, second],
+                              np.asarray(s1)[0][:, second])
+    assert not np.array_equal(np.asarray(y0)[:, second],
+                              np.asarray(y1)[:, second])
+    want_S, want_y = _oracle(state, x, Bs.at[:, 1].mul(2.0),
+                             Cs.at[:, 1].mul(-1.0), dt, g, D)
+    assert _rel(s1[0], want_S) < REL and _rel(y1, want_y) < REL
+
+
+def test_y_is_taken_from_the_state_before_the_step():
+    """``y = a (S C) + dt x (B . C) + D x``: with ``B`` zero the state
+    only decays and ``y`` must still see the OLD state times ``a``,
+    which is the NEW state's sum; with ``C`` zero ``y`` is the skip."""
+    state, x, Bs, Cs, dt, g, D = _inputs(2, seed=3)
+    active = jnp.ones((2,), bool)
+    s, y = sh._ssm_step_pallas(state, x, 0.0 * Bs, Cs, dt, g, D, active)
+    per = H // G
+    after = np.einsum("bhpn,bhn->bhp", np.asarray(s)[0],
+                      np.repeat(np.asarray(Cs), per, axis=1))
+    skip = np.asarray(D)[:, None] * np.asarray(x)
+    assert _rel(np.asarray(y), after + skip) < REL
+    _, y = sh._ssm_step_pallas(state, x, Bs, 0.0 * Cs, dt, g, D, active)
+    assert np.array_equal(np.asarray(y), skip)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_state_keeps_its_dtype_and_the_arithmetic_is_float32(dtype):
+    """A state held in bfloat16 (``state_dtype``) is widened in the
+    kernel, as the XLA path widens it, and rounded once on the way
+    back."""
+    state, *ops = _inputs(3, seed=11)
+    state = state.astype(dtype)
+    active = jnp.asarray([True, False, True])
+    got_state, got_y = sh._ssm_step_pallas(state, *ops, active)
+    want_S, want_y = _oracle(state, *ops)
+    assert got_state.dtype == dtype and got_y.dtype == jnp.float32
+    live = np.asarray(active)
+    assert _rel(np.asarray(got_y)[live], np.asarray(want_y)[live]) < REL
+    ulp = 2.0 ** -8 if dtype == jnp.bfloat16 else REL
+    assert _rel(got_state[0].astype(jnp.float32)[live],
+                np.asarray(want_S)[live]) <= ulp
+    assert np.array_equal(np.asarray(got_state[0, 1], np.float32),
+                          np.asarray(state[0, 1], np.float32))
+
+
+def _step_pair(cfg, monkeypatch):
+    """One decode step over three prefilled lanes with the kernel and
+    with the fallback: ``((logits, cache), (logits, cache), held)``."""
+    params = sh.init_params(jax.random.PRNGKey(0), cfg)
+    slots, ps, max_pages = 3, 4, 8
+    cache = sh.init_paged_cache(cfg, slots, slots * max_pages, ps)
+    pt = np.arange(slots * max_pages, dtype=np.int32).reshape(slots, -1)
+    rng = np.random.default_rng(5)
+    prefill = sh.jit_prefill_into_slot_paged(cfg, ps)
+    for slot in range(slots):
+        padded = np.zeros((1, 16), np.int32)
+        padded[0, :9 + slot] = rng.integers(0, cfg.vocab_size, 9 + slot)
+        _, cache, _ = prefill(params, cache, padded, np.int32(9 + slot),
+                              np.int32(0), pt[slot],
+                              np.int32(serving.PT_SENTINEL), np.int32(slot),
+                              jax.random.PRNGKey(0))
+    held = jax.tree_util.tree_map(np.asarray, cache)
+    active = np.array([True, False, True])
+    out = []
+    for fused in (True, False):
+        monkeypatch.setattr(sh, "_state_kernel",
+                            lambda cfg, fused=fused: fused)
+        step = jax.jit(functools.partial(sh._slot_decode_step_paged,
+                                         cfg=cfg, page_size=ps))
+        logits, after, counts = step(params, dict(cache),
+                                     jnp.asarray([5, 7, 9]), active,
+                                     jnp.asarray(pt))
+        assert int(counts[0]) == 2
+        out.append((np.asarray(logits),
+                    jax.tree_util.tree_map(np.asarray, after)))
+    return out[0], out[1], held
+
+
+def test_the_step_with_the_kernel_stays_by_the_step_with_the_fallback(
+        monkeypatch):
+    """In float32 (nothing rounds what the two sum differently) the
+    live lanes' logits and the whole cache agree to :data:`REL`-sized
+    bounds, and the parked lane's state and tail are the bits that
+    went in, on both paths, in every layer."""
+    cfg = dataclasses.replace(sh.CONFIGS["nano"], dtype=jnp.float32,
+                              param_dtype=jnp.float32)
+    (lg_k, c_k), (lg_x, c_x), held = _step_pair(cfg, monkeypatch)
+    assert _rel(lg_k[[0, 2]], lg_x[[0, 2]]) < 1e-4
+    assert sorted(c_k) == sorted(c_x) == sorted(held)
+    for name in c_k:
+        assert c_k[name].shape == held[name].shape
+        assert c_k[name].dtype == held[name].dtype
+        if name != "pos":
+            assert _rel(c_k[name], c_x[name]) < 1e-5, name
+    for c in (c_k, c_x):
+        for l in range(cfg.n_layer):
+            for name in (sh.slot_entry("state", l), sh.slot_entry("conv", l)):
+                assert np.array_equal(c[name][:, 1], held[name][:, 1])
+                assert not np.array_equal(c[name][:, 0], held[name][:, 0])
+    assert list(c_k["pos"]) == list(c_x["pos"])
+
+
+def test_the_choice_is_made_from_what_the_program_can_see(monkeypatch):
+    """Interpreted (here) any width is addressable; compiled for a TPU
+    a head's state must be whole tiles of the state dtype (``N`` of 128
+    lanes, ``P`` of 8 sublanes in float32 and 16 in bfloat16), and a
+    shape off the tile takes ``_ssm_step``: the description says which,
+    the program holds a ``pallas_call`` or none, and no knob has a
+    say."""
+    from ray_tpu._private import chip
+
+    nano = sh.CONFIGS["nano"]
+    wide = dataclasses.replace(nano, ssm_head_dim=8, ssm_state=128)
+    assert sh.decode_attention_fused(nano, 4)
+    assert sh._state_kernel(nano)
+    monkeypatch.setattr(chip, "pallas_interpret", lambda: False)
+    # neither kernel: heads of 16 (the attention's) and a state of 32
+    assert not sh.decode_attention_fused(nano, 4)
+    assert not sh._state_kernel(nano)
+    assert not sh._state_kernel(dataclasses.replace(wide, ssm_state=192))
+    assert not sh._state_kernel(dataclasses.replace(wide, ssm_head_dim=12))
+    assert not sh._state_kernel(dataclasses.replace(
+        wide, state_dtype=jnp.bfloat16))
+    assert sh._state_kernel(dataclasses.replace(
+        wide, ssm_head_dim=16, state_dtype=jnp.bfloat16))
+    assert sh._state_kernel(wide)
+    # the recurrence's kernel alone makes the program's answer true
+    assert not kda_moe.gqa_kernel(wide.n_kv_head, wide.head_dim,
+                                  wide.dtype, 16)
+    assert sh.decode_attention_fused(wide, 16)
+    assert sh.decode_attention_fused(wide, 16, "gather")
+    assert sh.ATTN_KERNELS == ("gather",)
+    with pytest.raises(ValueError, match="attn_kernel must be one of"):
+        sh.jit_decode_chunk_slots_paged(nano, 4, 4, attn_kernel="pallas")
+
+    # what the description says is what the traced program holds
+    def held(cfg):
+        params = jax.eval_shape(
+            lambda: sh.init_params(jax.random.PRNGKey(0), cfg))
+        cache = jax.eval_shape(lambda: sh.init_paged_cache(cfg, 2, 8, 4))
+        S = jax.ShapeDtypeStruct
+        return str(jax.make_jaxpr(functools.partial(
+            sh._slot_decode_step_paged, cfg=cfg, page_size=4))(
+            params, cache, S((2,), jnp.int32), S((2,), jnp.bool_),
+            S((2, 4), jnp.int32))).count("pallas_call")
+
+    assert held(nano) == 0                   # off the tile: _ssm_step
+    assert held(wide) == wide.n_layer        # the recurrence's, a layer
+    monkeypatch.undo()
+    assert held(nano) == 2 * nano.n_layer    # interpreted: both kernels
+
+
+def test_the_engine_reports_the_kernel_and_counts_the_lanes_it_moved():
+    cfg = sh.CONFIGS["nano"]
+    eng = DecodeEngine(sh.init_params(jax.random.PRNGKey(0), cfg), cfg,
+                       slots=2, chunk=4, max_len=96,
+                       prompt_buckets=(16, 32), page_size=4, n_pages=48)
+    try:
+        assert eng.warm_up()["attn_kernel_mode"] == "interpret"
+        prompt = np.arange(11, dtype=np.int32)
+        got = np.concatenate(list(eng.stream(prompt, 9)))
+        assert got.shape == (9,)
+        st = eng.stats()
+        assert st["attn_kernel_dispatches"] >= 2     # 9 tokens, chunk 4
+        assert st["attn_kernel_dispatches"] == st["dispatches"]
+        # one live lane a step, whatever the layers
+        assert st["state_lanes_sum"] == st["dispatches"] * 4
+    finally:
+        eng.shutdown()
