@@ -1065,6 +1065,7 @@ def run_tp_ab(args, np, cfg_name, model):
             prompt_buckets=(plen,), page_size=ps,
             prefix_cache=False, tp=tp, deployment=f"tp{tp}_bench")
         try:
+            eng.warm_up()           # the group's prefill program too
             list(eng.stream(prompts[0], max_new, seed=0))   # warm
             ttfts, comps, wall, streams = _drive_burst(
                 eng, prompts, max_new, np=np)

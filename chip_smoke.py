@@ -331,8 +331,19 @@ def phase_serve(cfg_name: str, require_tpu: bool = True, tp: int = 1,
             assert st["driver_restarts"] == 0 and st["resumed"] == 0 \
                 and st["preempted"] == 0, st
             assert st["warm_up"]["attn_kernel_mode"] == mode, st["warm_up"]
+            # a bucket's program for one prompt, and a pair of buckets'
+            # for the group of one chunk boundary where both are short
+            # enough
+            from ray_tpu.serve.engine import (PREFILL_GROUP,
+                                              PREFILL_GROUP_BUCKETS,
+                                              PREFILL_GROUP_ROWS)
+
+            wide = [b for b in (b0, b1) if PREFILL_GROUP * b
+                    <= PREFILL_GROUP_ROWS][-PREFILL_GROUP_BUCKETS:]
             assert set(st["warm_up"]["programs"]) == {
-                f"prefill_{b0}", f"prefill_{b1}", "chunk"}, st["warm_up"]
+                f"prefill_{b0}", f"prefill_{b1}", "chunk"} | {
+                f"prefill_{a}+{b}" for a in wide for b in wide
+                if a >= b}, st["warm_up"]
             for k in totals:
                 totals[k] += st[k]
         # One prefill per request: nothing was retried or replayed.

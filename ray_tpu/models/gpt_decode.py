@@ -1139,6 +1139,36 @@ def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
     return out[:, None]
 
 
+def _prefill_attend(q, k, v, hist: Cache, hist_len, T: int, hist_pages,
+                    l, scale, self_mask):
+    """One prompt suffix's attention in a paged prefill: ``q`` ``k``
+    ``v`` ``[1, S, H, hd]`` over themselves, causally, and over the
+    ``hist_len`` cached tokens before them, read from the flat pool
+    ``hist`` (after the fork; with ``ks`` / ``vs`` an int8 pool's, read
+    through :func:`_deq_page`) a block of ``T`` tokens at
+    ``hist_pages(j, l)`` at once
+    (:func:`ray_tpu.models.serving.attend_history`); ``self_mask`` the
+    rows' causal mask ``[1, 1, S, S]``. Returns float32 ``[1, S, H,
+    hd]``."""
+    hd = q.shape[-1]
+
+    def block(j):
+        pages = hist_pages(j, l)
+        if "ks" in hist:
+            hk = _deq_page(hist["k"][pages], hist["ks"][pages], q.dtype)
+            hv = _deq_page(hist["v"][pages], hist["vs"][pages], q.dtype)
+        else:
+            hk, hv = hist["k"][pages], hist["v"][pages]
+        lg_h = jnp.einsum("bqhd,khd->bhqk", q, hk.reshape(T, -1, hd),
+                          preferred_element_type=jnp.float32) * scale
+        return lg_h, hv.reshape(1, T, -1, hd)
+
+    lg_s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                      preferred_element_type=jnp.float32) * scale
+    return serving.attend_history(
+        jnp.where(self_mask, lg_s, -1e30), v, hist_len, T, block)
+
+
 def prefill_into_slot_paged(params: Params, cache: Cache,
                             tokens: jax.Array, length: jax.Array,
                             hist_len: jax.Array, pt_row: jax.Array,
@@ -1181,7 +1211,6 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     cache', rng')``; pad-position writes are dropped, not written."""
     B, S = tokens.shape
     L = cfg.n_layer
-    H, hd = cfg.n_head, cfg.head_dim
     n_pages = cache["k"].shape[1]
     ps = page_size
     max_pages = pt_row.shape[0]
@@ -1221,23 +1250,9 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
         x = carry
         p, l = layer
         q, k, v = _block_kv(x, p, cfg)          # [1, S, H, hd]
-
-        def block(j):
-            pages = hist_pages(j, l)
-            if quant:
-                hk = _deq_page(hist["k"][pages], hist["ks"][pages], q.dtype)
-                hv = _deq_page(hist["v"][pages], hist["vs"][pages], q.dtype)
-            else:
-                hk, hv = hist["k"][pages], hist["v"][pages]
-            lg_h = jnp.einsum("bqhd,khd->bhqk", q, hk.reshape(T, -1, hd),
-                              preferred_element_type=jnp.float32) * scale
-            return lg_h, hv.reshape(1, T, -1, hd)
-
-        lg_s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                          preferred_element_type=jnp.float32) * scale
-        att = serving.attend_history(
-            jnp.where(self_mask, lg_s, -1e30), v, hist_len, T, block
-        ).astype(q.dtype).reshape(B, S, -1)
+        att = _prefill_attend(q, k, v, hist, hist_len, T, hist_pages, l,
+                              scale, self_mask
+                              ).astype(q.dtype).reshape(B, S, -1)
         x = x + _mm_row(att, p["wo"]["kernel"], cfg.dtype, tp_axis)
         x = _ffn(x, p, cfg, tp_axis)
         return x, (k[0], v[0])
@@ -1272,6 +1287,98 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     kpool = kpool.at[:, page_w, off].set(k_new, mode="drop")
     vpool = vpool.at[:, page_w, off].set(v_new, mode="drop")
     return token[0], {"k": kpool, "v": vpool, "pos": pos}, rng
+
+
+def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
+                                   length: jax.Array, hist_len: jax.Array,
+                                   pt_row: jax.Array, cow_src: jax.Array,
+                                   slot: jax.Array, rng: jax.Array, *,
+                                   cfg: GPTConfig, page_size: int,
+                                   temperature: float = 0.0,
+                                   kv_dtype: str = "fp", tp_axis=None
+                                   ) -> Tuple[jax.Array, Cache, jax.Array]:
+    """:func:`prefill_into_slot_paged` for the ``G`` prompts of one
+    chunk boundary in ONE launch (the frame's contract,
+    ``models/serving.py``): ``tokens`` a tuple of ``[1, S_g]`` suffixes,
+    each in its own bucket, ``length`` ``hist_len`` ``cow_src`` ``slot``
+    ``[G]``, ``pt_row`` ``[G, max_pages]``, ``rng`` ``[G, 2]``. The
+    blocks' projections, the FFN and the head run over all the prompts'
+    rows at once (:class:`ray_tpu.models.serving.PromptRows`; the
+    once-a-launch casts of the weights with them); every prompt forks
+    its own page, attends over its own rows and its own cached prefix
+    (:func:`_prefill_attend`, the single prefill's), lands in its own
+    pages and samples with its own key. Returns ``(first tokens [G],
+    cache', rngs [G, 2])``."""
+    rows = serving.PromptRows(tokens, length, hist_len)
+    G, R = rows.G, rows.R
+    L = cfg.n_layer
+    n_pages = cache["k"].shape[1]
+    ps = page_size
+    max_pages = pt_row.shape[1]
+    scale = 1.0 / jnp.sqrt(jnp.asarray(cfg.head_dim, jnp.float32))
+    x = params["embed"]["kernel"].astype(cfg.dtype)[rows.tokens]
+    x = (x + jnp.take(params["pos_embed"],
+                      jnp.clip(rows.positions, 0,
+                               params["pos_embed"].shape[0] - 1),
+                      axis=0).astype(cfg.dtype))[None]        # [1, R, d]
+
+    # every prompt's COW fork first (its dst is a fresh page of its own)
+    dst = jnp.take_along_axis(
+        pt_row, jnp.clip(hist_len // ps, 0, max_pages - 1)[:, None],
+        axis=1)[:, 0]
+    dst_w = jnp.where(cow_src < n_pages, dst, jnp.int32(PT_SENTINEL))
+    src_c = jnp.clip(cow_src, 0, n_pages - 1)
+    quant = kv_dtype == "int8"
+    pool = {n: cache[n].at[:, dst_w].set(cache[n][:, src_c], mode="drop")
+            for n in (("k", "v", "ks", "vs") if quant else ("k", "v"))}
+    hist = _flat_pool(pool)
+    blocks = [serving.hist_blocks(pt_row[g], n_pages, ps) for g in range(G)]
+    masks = [jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
+             for S in rows.sizes]
+
+    def body(carry, layer):
+        x = carry
+        p, l = layer
+        q, k, v = _block_kv(x, p, cfg)          # [1, R, H, hd]
+        att = jnp.concatenate([
+            _prefill_attend(qg, kg, vg, hist, hist_len[g], *blocks[g], l,
+                            scale, masks[g])
+            for g, (qg, kg, vg) in enumerate(zip(
+                rows.split(q, 1), rows.split(k, 1), rows.split(v, 1)))],
+            axis=1).astype(q.dtype).reshape(1, R, -1)
+        x = x + _mm_row(att, p["wo"]["kernel"], cfg.dtype, tp_axis)
+        if cfg.n_experts > 0:
+            # a capacity router drops by who shares its batch: a
+            # prompt keeps the single prefill's batch, itself
+            x = jnp.concatenate([_ffn(xg, p, cfg, tp_axis)
+                                 for xg in rows.split(x, 1)], axis=1)
+        else:
+            x = _ffn(x, p, cfg, tp_axis)
+        return x, (k[0], v[0])
+
+    x, (k_new, v_new) = lax.scan(body, x,
+                                 (params["block"], jnp.arange(L)))
+    x = _rmsnorm(x, params["ln_f_scale"])
+    logits = _project_vocab(x[0, rows.last][:, None],
+                            params["embed"]["kernel"], cfg)
+    token, rng = serving.sample_slots(logits[:, 0], temperature, rng)
+
+    pos = cache["pos"].at[slot].set(hist_len + length)
+    if quant:
+        one = jnp.ones((1,), jnp.bool_)
+        for g, (kg, vg) in enumerate(zip(rows.split(k_new, 1),
+                                         rows.split(v_new, 1))):
+            merge = jax.vmap(lambda c, s, vl, g=g: _merge_span_int8(
+                c, s, vl[None], pt_row[g][None], hist_len[g][None],
+                length[g], one, ps))
+            pool["k"], pool["ks"] = merge(pool["k"], pool["ks"], kg)
+            pool["v"], pool["vs"] = merge(pool["v"], pool["vs"], vg)
+        return token, {**pool, "pos": pos}, rng
+    page_w, off = rows.pages(pt_row, ps)
+    return token, {
+        "k": pool["k"].at[:, page_w, off].set(k_new, mode="drop"),
+        "v": pool["v"].at[:, page_w, off].set(v_new, mode="drop"),
+        "pos": pos}, rng
 
 
 def _slot_decode_step_paged(params: Params, cache: Cache,

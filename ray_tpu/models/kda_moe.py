@@ -99,7 +99,7 @@ from jax import lax
 from jax.scipy.linalg import solve_triangular
 
 from . import serving
-from .moe import block_ffn, embed, head, rmsnorm
+from .moe import block_ffn, embed, group_cfg, head, rmsnorm
 from .serving import (PT_SENTINEL, CacheEntry, CacheSpec, at_layer, flat,
                       live_length)
 
@@ -584,15 +584,16 @@ def _kda_chunked(q, k, v, g, beta, S0, chunk: int):
     return jnp.moveaxis(o, 1, 2).reshape(T, H, -1), S
 
 
-def _kda_sequence(h, p, cfg: KDAMoEConfig, live):
-    """A KDA mixer over one whole sequence from a zero state: ``h`` [S,
-    d] (normed), ``live`` [S] bool (rows past the prompt advance
-    nothing). Returns ``(y [S, d] float32, S_end [H, dk, dv], padded
-    [conv_size - 1 + S, 3 W]: the projections before the convolution
-    behind the zero rows that stand before the sequence's start)``."""
-    S = h.shape[0]
+def _kda_mix(pre, g, beta, p, cfg: KDAMoEConfig, live):
+    """What of a KDA mixer is ONE sequence's, from a zero state: the
+    convolution over its projections ``pre`` [S, 3 W] and the chunked
+    recurrence (``g`` [S, H, dk], ``beta`` [S, H]; ``live`` [S] bool:
+    rows past the prompt advance nothing). Returns ``(o [S, H, dv]
+    float32, S_end [H, dk, dv], padded [conv_size - 1 + S, 3 W]: the
+    projections behind the zero rows that stand before the sequence's
+    start)``."""
+    S = pre.shape[0]
     with jax.named_scope("kda.proj"):
-        pre, g, beta, gate = _kda_proj(h, p, cfg)
         back = cfg.conv_size - 1
         padded = jnp.concatenate(
             [jnp.zeros((back, pre.shape[-1]), pre.dtype), pre])
@@ -611,8 +612,20 @@ def _kda_sequence(h, p, cfg: KDAMoEConfig, live):
             q, k, v, g, beta, jnp.zeros(
                 (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim),
                 jnp.float32), C)
+    return o[:S], S_end, padded
+
+
+def _kda_sequence(h, p, cfg: KDAMoEConfig, live):
+    """A KDA mixer over one whole sequence from a zero state: ``h`` [S,
+    d] (normed), ``live`` [S] bool (rows past the prompt advance
+    nothing). Returns ``(y [S, d] float32, S_end [H, dk, dv], padded
+    [conv_size - 1 + S, 3 W]: the projections before the convolution
+    behind the zero rows that stand before the sequence's start)``."""
     with jax.named_scope("kda.proj"):
-        y = _kda_out(o[:S], gate, p, cfg)
+        pre, g, beta, gate = _kda_proj(h, p, cfg)
+    o, S_end, padded = _kda_mix(pre, g, beta, p, cfg, live)
+    with jax.named_scope("kda.proj"):
+        y = _kda_out(o, gate, p, cfg)
     return y, S_end, padded
 
 
@@ -1023,6 +1036,75 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     return token[0], {"k": kpool.reshape(cache["k"].shape),
                       "v": vpool.reshape(cache["v"].shape),
                       "state": state, "conv": conv, "pos": pos}, rng
+
+
+def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
+                                   length: jax.Array, hist_len: jax.Array,
+                                   pt_row: jax.Array, cow_src: jax.Array,
+                                   slot: jax.Array, rng: jax.Array, *,
+                                   cfg: KDAMoEConfig, page_size: int,
+                                   temperature: float = 0.0,
+                                   kv_dtype: str = "fp"
+                                   ) -> Tuple[jax.Array, Cache, jax.Array]:
+    """:func:`prefill_into_slot_paged` for the ``G`` prompts of one
+    chunk boundary in ONE launch (the frame's contract,
+    ``models/serving.py``): the mixers' projections, the expert layer
+    (in the blocks :func:`ray_tpu.models.moe.group_cfg` widens) and the
+    head run over all the prompts' rows at once
+    (:class:`ray_tpu.models.serving.PromptRows`); each prompt's causal
+    attention, its convolution and its chunked recurrence from a zero
+    state are the single prefill's on its own rows (:func:`_gqa_causal`,
+    :func:`_kda_mix`), and each lands in its own pages and its own
+    slot."""
+    rows = serving.PromptRows(tokens, length, jnp.zeros_like(length))
+    del hist_len, cow_src
+    G = rows.G
+    n_pages = cache["k"].shape[1]
+    x = embed(params, rows.tokens)                          # [R, d]
+    live = rows.split(rows.live)
+    page_w, off = rows.pages(pt_row, page_size)
+    kpool, vpool = flat(cache["k"]), flat(cache["v"])
+    state, conv = cache["state"], cache["conv"]
+    back = cfg.conv_size - 1
+    ffn_cfg = group_cfg(cfg, G)
+    ig = ik = 0
+    for l, p in enumerate(params["layers"]):
+        h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+        if l in cfg.gqa_layers:
+            q, k, v, z = _gqa_qkvz(h, p, cfg)
+            with jax.named_scope("gqa.prefill"):
+                att = jnp.concatenate([
+                    _gqa_causal(qg, kg, vg, cfg) for qg, kg, vg in zip(
+                        rows.split(q), rows.split(k), rows.split(v))])
+            at = (at_layer(page_w, ig, n_pages), off)
+            kpool = kpool.at[at].set(k, mode="drop")
+            vpool = vpool.at[at].set(v, mode="drop")
+            y = _gqa_out(att, z, p, cfg)
+            ig += 1
+        else:
+            with jax.named_scope("kda.proj"):
+                pre, g, beta, gate = _kda_proj(h, p, cfg)
+            outs = []
+            for i, (pre_i, g_i, beta_i) in enumerate(zip(
+                    rows.split(pre), rows.split(g), rows.split(beta))):
+                o, S_end, padded = _kda_mix(pre_i, g_i, beta_i, p, cfg,
+                                            live[i])
+                outs.append(o)
+                state = _put(state, S_end, ik, slot[i])
+                conv = _put(conv, lax.dynamic_slice(
+                    padded, (length[i], 0), (back, padded.shape[1])), ik,
+                    slot[i])
+            with jax.named_scope("kda.proj"):
+                y = _kda_out(jnp.concatenate(outs), gate, p, cfg)
+            ik += 1
+        x = block_ffn(x + y.astype(x.dtype), p, ffn_cfg, rows.live)[0]
+    token, rng = serving.sample_slots(head(x[rows.last], params, cfg),
+                                      temperature, rng)
+    return token, {"k": kpool.reshape(cache["k"].shape),
+                   "v": vpool.reshape(cache["v"].shape),
+                   "state": state, "conv": conv,
+                   "pos": cache["pos"].at[slot].set(
+                       length.astype(jnp.int32))}, rng
 
 
 def _slot_decode_step_paged(params: Params, cache: Cache,

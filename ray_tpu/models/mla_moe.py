@@ -402,6 +402,24 @@ shard_params = serving.bind(serving.shard_params, _THIS)
 
 
 # -------------------------------------------------------------- programs
+def _prefill_attend(qn, qr, ent, pool, p, a: int, hist_len, T: int,
+                    hist_pages, causal, cfg):
+    """One prompt suffix's attention ``a`` in a paged prefill: queries
+    ``[1, S, H, .]`` over the suffix's own latents ``ent`` ``[1, S,
+    row]``, causally, and over the ``hist_len`` cached tokens before
+    them, read from the flat ``pool`` a block of ``T`` tokens at
+    ``hist_pages(j, a)`` at once, keys and values materialised per head
+    (:func:`ray_tpu.models.serving.attend_history`). Returns float32
+    ``[1, S, H, v]``."""
+    def block(j):
+        return _materialised(
+            qn, qr, pool[hist_pages(j, a)].reshape(1, T, -1), p, cfg)
+
+    lg, v = _materialised(qn, qr, ent, p, cfg)
+    return serving.attend_history(
+        jnp.where(causal, lg, -1e30), v, hist_len, T, block)
+
+
 def prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
                        cow_src, cfg, page_size: int):
     """The paged prefill's frame around ANY model whose attentions
@@ -450,16 +468,10 @@ def prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
 
     def attend(x, p, a: int, pool):
         qn, qr, ent = _latent_qkv(x, p, positions[None], cfg)
-
-        def block(j):
-            return _materialised(
-                qn, qr, pool[hist_pages(j, a)].reshape(1, T, -1), p, cfg)
-
         with jax.named_scope("mla.prefill"):
-            lg, v = _materialised(qn, qr, ent, p, cfg)
-            att = serving.attend_history(
-                jnp.where(causal, lg, -1e30), v, hist_len, T, block
-            ).astype(cfg.dtype).reshape(1, S, -1)
+            att = _prefill_attend(
+                qn, qr, ent, pool, p, a, hist_len, T, hist_pages, causal,
+                cfg).astype(cfg.dtype).reshape(1, S, -1)
         x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
         return x, pool.at[serving.at_layer(page_w, a, n_pages),
                           positions % ps].set(ent[0], mode="drop")
@@ -478,6 +490,65 @@ def prefill_result(x, pool, params: Params, cache: Cache, length,
         cache["pos"], jnp.reshape(hist_len + length, (1,)), (slot,))
     return token[0], {"latent": pool.reshape(cache["latent"].shape),
                       "pos": pos}, rng
+
+
+def prefill_group_attention(cache: Cache, rows, hist_len, pt_row, cow_src,
+                            cfg, page_size: int):
+    """:func:`prefill_attention` for the ``G`` prompts of one launch,
+    whose rows lie end to end (``rows``, a
+    :class:`ray_tpu.models.serving.PromptRows`; ``hist_len`` ``cow_src``
+    ``[G]``, ``pt_row`` ``[G, max_pages]``): every prompt's fork at
+    once, and ``attend(x [1, R, d], p, a, pool)`` whose projections run
+    over all the rows while each prompt attends over its own rows and
+    its own cached prefix (:func:`_prefill_attend`, the single
+    prefill's) and lands its latents in its own pages. Returns ``(pool,
+    attend)``."""
+    ps = page_size
+    A, n_pages = cache["latent"].shape[:2]
+    G, max_pages = pt_row.shape
+
+    pool = serving.flat(cache["latent"])
+    layers = jnp.arange(A, dtype=jnp.int32) * n_pages
+    dst = jnp.take_along_axis(
+        pt_row, jnp.clip(hist_len // ps, 0, max_pages - 1)[:, None], axis=1)
+    dst_w = jnp.where((cow_src[:, None] < n_pages) & (dst < n_pages),
+                      dst + layers, jnp.int32(PT_SENTINEL))     # [G, A]
+    src = jnp.clip(cow_src, 0, n_pages - 1)[:, None] + layers
+    pool = pool.at[dst_w.reshape(-1)].set(pool[src.reshape(-1)],
+                                          mode="drop")
+
+    blocks = [serving.hist_blocks(pt_row[g], n_pages, ps) for g in range(G)]
+    causal = [jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
+              for S in rows.sizes]
+    page_w, off = rows.pages(pt_row, ps)
+
+    def attend(x, p, a: int, pool):
+        qn, qr, ent = _latent_qkv(x, p, rows.positions[None], cfg)
+        with jax.named_scope("mla.prefill"):
+            att = jnp.concatenate([
+                _prefill_attend(n, r, e, pool, p, a, hist_len[g],
+                                *blocks[g], causal[g], cfg)
+                for g, (n, r, e) in enumerate(zip(
+                    rows.split(qn, 1), rows.split(qr, 1),
+                    rows.split(ent, 1)))],
+                axis=1).astype(cfg.dtype).reshape(1, rows.R, -1)
+        x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
+        return x, pool.at[serving.at_layer(page_w, a, n_pages), off].set(
+            ent[0], mode="drop")
+
+    return pool, attend
+
+
+def prefill_group_result(x, pool, params: Params, cache: Cache, rows,
+                         length, hist_len, slot, rng, cfg,
+                         temperature: float):
+    """:func:`prefill_result` for a group: every prompt's first token
+    from its own last live row of ``x`` [1, R, d] with its own key, and
+    the cache with ``pool`` and the slots' ``pos``."""
+    token, rng = serving.sample_slots(
+        moe.head(x[0, rows.last], params, cfg), temperature, rng)
+    return token, {"latent": pool.reshape(cache["latent"].shape),
+                   "pos": cache["pos"].at[slot].set(hist_len + length)}, rng
 
 
 def prefill_into_slot_paged(params: Params, cache: Cache,
@@ -501,6 +572,31 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
         x = moe.block_ffn(x[0], p, cfg, live)[0][None]
     return prefill_result(x, pool, params, cache, length, hist_len, slot,
                           rng, cfg, temperature)
+
+
+def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
+                                   length: jax.Array, hist_len: jax.Array,
+                                   pt_row: jax.Array, cow_src: jax.Array,
+                                   slot: jax.Array, rng: jax.Array, *,
+                                   cfg: MLAMoEConfig, page_size: int,
+                                   temperature: float = 0.0,
+                                   kv_dtype: str = "fp"
+                                   ) -> Tuple[jax.Array, Cache, jax.Array]:
+    """:func:`prefill_into_slot_paged` for the ``G`` prompts of one
+    chunk boundary in ONE launch (the frame's contract,
+    ``models/serving.py``; :func:`prefill_group_attention`): the FFNs
+    and the expert layer see all the prompts' rows as one batch, in
+    blocks :func:`ray_tpu.models.moe.group_cfg` widens, so an expert's matrices are read
+    once a launch."""
+    rows = serving.PromptRows(tokens, length, hist_len)
+    pool, attend = prefill_group_attention(
+        cache, rows, hist_len, pt_row, cow_src, cfg, page_size)
+    x = moe.embed(params, rows.tokens)[None]
+    for l, p in enumerate(params["layers"]):
+        x, pool = attend(x, p, l, pool)
+        x = moe.block_ffn(x[0], p, moe.group_cfg(cfg, rows.G), rows.live)[0][None]
+    return prefill_group_result(x, pool, params, cache, rows, length,
+                                hist_len, slot, rng, cfg, temperature)
 
 
 def decode_attention_fused(cfg: MLAMoEConfig, page_size: int,

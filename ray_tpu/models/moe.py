@@ -23,6 +23,7 @@ around it (``rmsnorm``, ``embed``, ``head``, ``block_ffn``).
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Optional, Tuple
 
@@ -363,6 +364,14 @@ def head(x, params, cfg):
         x.astype(cfg.dtype), params["head"]["kernel"].astype(cfg.dtype),
         (((x.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
+
+
+def group_cfg(cfg, G: int):
+    """``cfg`` for a prefill launch of ``G`` prompts: an expert's rows
+    grow ``G``-fold, and its blocks with them (``moe_block_rows``), so
+    that a group's expert reads its matrices as often as ONE prompt's
+    does; a row's arithmetic does not depend on its block's size."""
+    return dataclasses.replace(cfg, moe_block_rows=G * cfg.moe_block_rows)
 
 
 def block_ffn(x, p, cfg, live=None):

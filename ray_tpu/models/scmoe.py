@@ -65,7 +65,8 @@ from . import mla_moe, moe, serving
 # ``decode_attention_fused`` is the description's entry as it stands:
 # this model's one kernel is the imported attention's
 from .mla_moe import (decode_attention, decode_attention_fused,
-                      prefill_attention, prefill_result)
+                      prefill_attention, prefill_group_attention,
+                      prefill_group_result, prefill_result)
 from .serving import PT_SENTINEL, CacheEntry, CacheSpec
 
 _THIS = sys.modules[__name__]
@@ -294,6 +295,32 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
         x, pool, _ = _layer(x, p, 2 * l, pool, attend, cfg, live)
     return prefill_result(x, pool, params, cache, length, hist_len, slot,
                           rng, cfg, temperature)
+
+
+def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
+                                   length: jax.Array, hist_len: jax.Array,
+                                   pt_row: jax.Array, cow_src: jax.Array,
+                                   slot: jax.Array, rng: jax.Array, *,
+                                   cfg: ScMoEConfig, page_size: int,
+                                   temperature: float = 0.0,
+                                   kv_dtype: str = "fp"
+                                   ) -> Tuple[jax.Array, Cache, jax.Array]:
+    """:func:`prefill_into_slot_paged` for the ``G`` prompts of one
+    chunk boundary in ONE launch (the frame's contract,
+    ``models/serving.py``;
+    :func:`ray_tpu.models.mla_moe.prefill_group_attention`): a layer's
+    dense FFNs and its expert branch see all the prompts' rows at once,
+    the experts in the blocks
+    :func:`ray_tpu.models.moe.group_cfg` widens."""
+    rows = serving.PromptRows(tokens, length, hist_len)
+    pool, attend = prefill_group_attention(
+        cache, rows, hist_len, pt_row, cow_src, cfg, page_size)
+    x = moe.embed(params, rows.tokens)[None]
+    for l, p in enumerate(params["layers"]):
+        x, pool, _ = _layer(x, p, 2 * l, pool, attend,
+                            moe.group_cfg(cfg, rows.G), rows.live)
+    return prefill_group_result(x, pool, params, cache, rows, length,
+                                hist_len, slot, rng, cfg, temperature)
 
 
 def _slot_decode_step_paged(params: Params, cache: Cache,

@@ -34,6 +34,25 @@ public entry).
 pt_row, cow_src, slot, rng, *, cfg, page_size, temperature, kv_dtype)``
     one prompt suffix into its pages (and its slot), with the first
     token's sample: ``(token, cache', rng')``.
+``prefill_group_into_slots_paged(params, cache, tokens, length,
+hist_len, pt_row, cow_src, slot, rng, *, cfg, page_size, temperature,
+kv_dtype)``
+    the same for the ``G`` prompts admitted at ONE chunk boundary, in
+    one launch: ``tokens`` a tuple of ``G`` arrays ``[1, bucket_g]``
+    (each suffix in its OWN bucket: no prompt is padded to another's)
+    and every other operand with a leading ``G`` (``pt_row`` ``[G,
+    max_pages]``, ``rng`` ``[G, 2]``); ``(tokens [G], cache', rngs [G,
+    2])``. The prompts' rows lie end to end (:class:`PromptRows`): what
+    is per ROW (embedding, norms, projections, the FFNs and the expert
+    layer, the head over the last rows) runs over all of them as one
+    set of matrix products, so a weight is read once a launch and an
+    expert sees the rows of every prompt; what is per PROMPT (the
+    suffix's attention and its read of the cached prefix, a recurrence
+    from its zero state, the slot written, the sample with the
+    request's own key) takes the prompt's own rows, one prompt after
+    another, each row's arithmetic the single prefill's. The prompts'
+    pages and slots are disjoint and no prompt reads a page another
+    writes (the engine closes a group before such a request).
 ``_slot_decode_step_paged(params, cache, token, active, pt, cfg,
 page_size, kv_dtype, attn_kernel)``
     ONE masked decode step over the slot pool: ``(logits, cache')``,
@@ -427,6 +446,48 @@ def attend_history(lg_s, v_s, hist_len, block_tokens: int, block):
         preferred_element_type=jnp.float32))
 
 
+class PromptRows:
+    """The rows of the ``G`` prompts ONE launch prefills, laid end to
+    end: ``tokens`` is a sequence of ``[1, S_g]`` suffixes, each in its
+    own bucket, ``length`` and ``hist_len`` ``[G]`` their live rows and
+    cached prefixes. ``R = sum(S_g)`` rows in all; the buckets and the
+    offsets are static, so a prompt's rows are a static slice
+    (:meth:`split`). ``tokens`` ``[R]``, ``positions`` ``[R]``
+    (``hist_len[g] + i``), ``live`` ``[R]`` (``i < length[g]``) and
+    ``last`` ``[G]`` (each prompt's last live row) are what the per-row
+    parts of a group prefill need; :meth:`pages` is where each row
+    lands."""
+
+    def __init__(self, tokens, length, hist_len):
+        self.sizes = tuple(t.shape[-1] for t in tokens)
+        self.offs = tuple(sum(self.sizes[:g]) for g in range(len(tokens)))
+        self.G, self.R = len(tokens), sum(self.sizes)
+        self.tokens = jnp.concatenate([t.reshape(-1) for t in tokens])
+        self.positions = jnp.concatenate(
+            [hist_len[g] + jnp.arange(S) for g, S in enumerate(self.sizes)])
+        self.live = jnp.concatenate(
+            [jnp.arange(S) < length[g] for g, S in enumerate(self.sizes)])
+        self.last = jnp.asarray(self.offs, jnp.int32) + length - 1
+
+    def split(self, a, axis: int = 0):
+        """``a``'s rows (along ``axis``) a prompt at a time."""
+        return [lax.slice_in_dim(a, o, o + S, axis=axis)
+                for o, S in zip(self.offs, self.sizes)]
+
+    def pages(self, pt_row, page_size: int):
+        """``(page [R], offset [R])``: the page of its prompt's table
+        row (``pt_row`` ``[G, max_pages]``) and the place in it where
+        each row's cache entry lands; the sentinel for a row that pads
+        its bucket or lies past the table: its write is dropped."""
+        max_pages = pt_row.shape[1]
+        vp = self.positions // page_size
+        own = jnp.concatenate([
+            pt_row[g][jnp.clip(v, 0, max_pages - 1)]
+            for g, v in enumerate(self.split(vp))])
+        return jnp.where(self.live & (vp < max_pages), own,
+                         jnp.int32(PT_SENTINEL)), self.positions % page_size
+
+
 # ------------------------------------------------------ the chunk program
 def decode_chunk_slots_paged(params, cache, token: jax.Array,
                              rngs: jax.Array, active: jax.Array,
@@ -569,17 +630,22 @@ def _checked_mesh(model, cfg, kv_dtype, tp, attn_kernel=None):
     return model.check_tp(cfg, tp)
 
 
-# rtlint: program-budget: len(prompt_buckets)
+# rtlint: program-budget: len(prompt_buckets) + len(prompt_buckets) * len(prompt_buckets)
 @knob_cache
 def jit_prefill_into_slot_paged(cfg, page_size: int,
                                 temperature: float = 0.0,
                                 kv_dtype: str = "fp", tp: int = 1, *,
                                 model):
-    """Jitted ``model.prefill_into_slot_paged``; one compiled program
-    per SUFFIX bucket per (model, cfg, page_size, temperature, kv_dtype,
-    tp) key — prefix-hit depth (``hist_len``), page-table contents, and
-    COW source are all traced, so shared-prefix admission never
-    retraces. ``kv_dtype`` is an engine-level static baked into the
+    """Jitted ``model.prefill_into_slot_paged`` and, called with a
+    TUPLE of prompts (``tokens`` ``([1, bucket_g], ...)``, a ``length``
+    each), the group's ``model.prefill_group_into_slots_paged`` under
+    the same name: one compiled program per SUFFIX bucket, and one per
+    group's buckets (the engine hands a pair over widest first: a pair
+    of buckets is one program whichever came first), per (model, cfg,
+    page_size, temperature, kv_dtype, tp) key; one prompt, with scalar
+    operands, is the program it always was — prefix-hit depth (``hist_len``),
+    page-table contents, and COW source are all traced, so
+    shared-prefix admission never retraces. ``kv_dtype`` is an engine-level static baked into the
     same program set (it changes the pool layout, not the program
     COUNT). Cached on the static knobs so every engine for the same
     knobs shares one wrapper (and its trace cache). The pool cache is
@@ -589,10 +655,16 @@ def jit_prefill_into_slot_paged(cfg, page_size: int,
     (``tp > 1`` of a description that has one) the same inner function
     runs under its ``shard_program``."""
     mesh = _checked_mesh(model, cfg, kv_dtype, tp)
+
+    def prefill(params, cache, tokens, *rest, **knobs):
+        fn = model.prefill_group_into_slots_paged \
+            if isinstance(tokens, (tuple, list)) \
+            else model.prefill_into_slot_paged
+        return fn(params, cache, tokens, *rest, **knobs)
+
     return jit_program(
-        model, model.prefill_into_slot_paged, "prefill_into_slot_paged",
-        mesh, 3, cfg=cfg, page_size=page_size, temperature=temperature,
-        kv_dtype=kv_dtype)
+        model, prefill, "prefill_into_slot_paged", mesh, 3, cfg=cfg,
+        page_size=page_size, temperature=temperature, kv_dtype=kv_dtype)
 
 
 # rtlint: program-budget: 1

@@ -639,15 +639,15 @@ def _ssd_chunked(x, B, C, dt, g, S0, chunk: int):
     return y.reshape((T,) + y.shape[2:]), S
 
 
-def _ssm_sequence(h, p, cfg: SSMHybridConfig, live):
-    """The SSM mixer over one whole sequence from a zero state: ``h``
-    [S, d] (normed), ``live`` [S] bool (rows past the prompt advance
-    nothing). Returns ``(out [S, d] float32, S_end [H, P, N], padded
-    [conv_size - 1 + S, conv_dim]: ``xBC`` before the convolution
+def _ssm_mix(xBC, dt, g, p, cfg: SSMHybridConfig, live):
+    """What of the SSM mixer is ONE sequence's, from a zero state: the
+    convolution over its ``xBC`` [S, conv_dim] and the chunked SSD form
+    (``dt`` ``g`` [S, H]; ``live`` [S] bool: rows past the prompt
+    advance nothing). Returns ``(y [S, H, P] float32 with the skip,
+    S_end [H, P, N], padded [conv_size - 1 + S, conv_dim]: ``xBC``
     behind the zero rows that stand before the sequence's start)``."""
-    S = h.shape[0]
+    S = xBC.shape[0]
     with jax.named_scope("ssm.proj"):
-        z, xBC, dt, g = _ssm_proj(h, p, cfg)
         back = cfg.conv_size - 1
         padded = jnp.concatenate(
             [jnp.zeros((back, xBC.shape[-1]), xBC.dtype), xBC])
@@ -666,6 +666,18 @@ def _ssm_sequence(h, p, cfg: SSMHybridConfig, live):
             (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32),
             Cn)
         y = y[:S] + p["D_skip"].astype(jnp.float32)[:, None] * x
+    return y, S_end, padded
+
+
+def _ssm_sequence(h, p, cfg: SSMHybridConfig, live):
+    """The SSM mixer over one whole sequence from a zero state: ``h``
+    [S, d] (normed), ``live`` [S] bool (rows past the prompt advance
+    nothing). Returns ``(out [S, d] float32, S_end [H, P, N], padded
+    [conv_size - 1 + S, conv_dim]: ``xBC`` before the convolution
+    behind the zero rows that stand before the sequence's start)``."""
+    with jax.named_scope("ssm.proj"):
+        z, xBC, dt, g = _ssm_proj(h, p, cfg)
+    y, S_end, padded = _ssm_mix(xBC, dt, g, p, cfg, live)
     with jax.named_scope("ssm.proj"):
         out = _ssm_out(y, z, p, cfg)
     return out, S_end, padded
@@ -836,6 +848,68 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
     return token[0], {"k": kpool.reshape(cache["k"].shape),
                       "v": vpool.reshape(cache["v"].shape),
                       **slot_entries, "pos": pos}, rng
+
+
+def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
+                                   length: jax.Array, hist_len: jax.Array,
+                                   pt_row: jax.Array, cow_src: jax.Array,
+                                   slot: jax.Array, rng: jax.Array, *,
+                                   cfg: SSMHybridConfig, page_size: int,
+                                   temperature: float = 0.0,
+                                   kv_dtype: str = "fp"
+                                   ) -> Tuple[jax.Array, Cache, jax.Array]:
+    """:func:`prefill_into_slot_paged` for the ``G`` prompts of one
+    chunk boundary in ONE launch (the frame's contract,
+    ``models/serving.py``): both branches' projections, the MLP and the
+    head (its 261k rows read once) run over all the prompts' rows at
+    once (:class:`ray_tpu.models.serving.PromptRows`); each prompt's
+    causal attention, its convolution and its chunked recurrence from a
+    zero state are the single prefill's on its own rows
+    (:func:`_attn_causal`, :func:`_ssm_mix`), and each lands in its own
+    pages and its own slot."""
+    rows = serving.PromptRows(tokens, length, jnp.zeros_like(length))
+    del hist_len, cow_src
+    n_pages = cache["k"].shape[1]
+    x = _embed(params, rows.tokens, cfg)                    # [R, d]
+    live = rows.split(rows.live)
+    page_w, off = rows.pages(pt_row, page_size)
+    kpool, vpool = flat(cache["k"]), flat(cache["v"])
+    slot_entries = {}
+    back = cfg.conv_size - 1
+    for l, p in enumerate(params["layers"]):
+        h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
+        q, k, v = _attn_qkv(h, p, rows.positions, cfg)
+        with jax.named_scope("hgqa.prefill"):
+            att = jnp.concatenate([
+                _attn_causal(qg, kg, vg, cfg) for qg, kg, vg in zip(
+                    rows.split(q), rows.split(k), rows.split(v))])
+        at = (at_layer(page_w, l, n_pages), off)
+        kpool = kpool.at[at].set(k, mode="drop")
+        vpool = vpool.at[at].set(v, mode="drop")
+        with jax.named_scope("ssm.proj"):
+            z, xBC, dt, g = _ssm_proj(h, p, cfg)
+        state, conv = slot_entry("state", l), slot_entry("conv", l)
+        slot_entries[state], slot_entries[conv] = cache[state], cache[conv]
+        ys = []
+        for i, (xBC_i, dt_i, g_i) in enumerate(zip(
+                rows.split(xBC), rows.split(dt), rows.split(g))):
+            y, S_end, padded = _ssm_mix(xBC_i, dt_i, g_i, p, cfg, live[i])
+            ys.append(y)
+            slot_entries[state] = _put(slot_entries[state], S_end, 0,
+                                       slot[i])
+            slot_entries[conv] = _put(slot_entries[conv], lax.dynamic_slice(
+                padded, (length[i], 0), (back, padded.shape[1])), 0,
+                slot[i])
+        with jax.named_scope("ssm.proj"):
+            y = _ssm_out(jnp.concatenate(ys), z, p, cfg)
+        x = _mlp(x + y + _attn_out(att, p, cfg), p, cfg)
+    token, rng = serving.sample_slots(_head(x[rows.last], params, cfg),
+                                      temperature, rng)
+    return token, {"k": kpool.reshape(cache["k"].shape),
+                   "v": vpool.reshape(cache["v"].shape),
+                   **slot_entries,
+                   "pos": cache["pos"].at[slot].set(
+                       length.astype(jnp.int32))}, rng
 
 
 def _slot_decode_step_paged(params: Params, cache: Cache,

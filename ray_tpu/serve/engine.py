@@ -24,7 +24,23 @@ TPU-friendly static shapes:
   prompt bucket; the TRUE length is traced, so any length within a
   bucket shares the program) and the first sampled token streams out
   immediately — TTFT is one prefill dispatch away from admission, not
-  one full gang generation.
+  one full gang generation. The head requests ONE boundary can admit
+  (a free slot each, pages granted one after another in FIFO order,
+  up to ``PREFILL_GROUP``) share one launch
+  (``prefill_group_into_slots_paged`` of the model's description,
+  under the same program name; each prompt in its own bucket, its
+  rows end to end with the others'): every weight is read once for the
+  group, an expert layer sees the rows of all its prompts, and the
+  running lanes wait for one dispatch and one blocking read instead of
+  one a prompt. What is per prompt stays per prompt (its attention and
+  cached prefix, its recurrent state, its pages, its sample and key),
+  so a request's tokens do not depend on who shared its launch. The
+  first request that cannot get pages closes the group and defers; a
+  handoff import, an export, a prompt the drafter prefills too, a
+  prompt whose bucket is not among the ``PREFILL_GROUP_BUCKETS`` widest
+  that keep a launch within ``PREFILL_GROUP_ROWS`` rows, and a prompt whose prefix lookup would hit pages a groupmate
+  is about to write go alone. A lone prompt runs the single program
+  unchanged.
 - :func:`~ray_tpu.models.gpt_decode.decode_chunk_slots_paged` then
   decodes ALL active slots in one fused k-step dispatch; a slot frees
   the moment its lane samples EOS, exhausts ``max_new``, passes its
@@ -32,9 +48,14 @@ TPU-friendly static shapes:
   batch.
 
 Static-shape discipline: the compiled-program set is exactly
-``len(prompt_buckets)`` prefill programs + 1 chunk program, bounded for
-ANY admission pattern (see the recompile guard in
-``tests/test_serve_engine.py``).
+``len(prompt_buckets)`` prefill programs for one prompt, at most one
+more for every pair of buckets a group can hold (each prompt in its
+own bucket; the ``PREFILL_GROUP_BUCKETS`` widest that keep a launch
+within ``PREFILL_GROUP_ROWS`` rows: three programs) + 1 chunk program,
+bounded for ANY
+admission pattern (see the recompile guard in
+``tests/test_serve_engine.py``); ``warm_up()`` runs every one of them
+before traffic.
 
 Results stream back through the same :class:`~.batching._StreamLane`
 queues the batched streaming path uses, so replicas, handles, and the
@@ -132,7 +153,8 @@ the driver interleaves **draft → verify** per chunk boundary instead:
   pattern).
 
 The compiled-program set grows by exactly ONE verify program per
-``draft_k`` (``len(prompt_buckets) + 1 + 1`` with the n-gram drafter);
+``draft_k`` (``len(prompt_buckets) + 1 + 1`` with the n-gram drafter,
+whose admissions never group);
 accepted-token throughput multiplies by the mean committed tokens per
 verify forward (``1 + mean_accept_len``) while the per-forward cost
 stays one weight sweep. Wired through the config plane as
@@ -167,7 +189,9 @@ pool. The handoff plane adds exactly TWO compiled programs per engine
 from __future__ import annotations
 
 import collections
+import functools
 import hashlib
+import itertools
 import os
 import queue
 import threading
@@ -285,6 +309,77 @@ class _Slot:
     pages: List[int] = field(default_factory=list)
     parked: bool = False          # out of pages: excluded from dispatch
     skip: int = 0                 # replay tokens left to suppress
+
+
+#: Prompts one prefill launch holds at most: the head requests a chunk
+#: boundary can admit share ONE launch (``_admit_head``), so their
+#: weights are read once and the lanes wait for one dispatch and one
+#: blocking read. Two: the closed-loop cells free 1.9-2.3 slots a
+#: launch (PERF.md section 5), and every size is one more program a
+#: bucket to compile and keep. A lone prompt runs the single program.
+PREFILL_GROUP = 2
+#: Rows one group launch holds at most (prompts x their bucket): a
+#: prompt whose bucket is wider goes alone. Past a thousand rows a
+#: prefill is bound by its arithmetic whoever shares it, so a second
+#: prompt saves no read of the weights worth having, while the launch's
+#: temporaries (the scores are quadratic in the bucket) would outgrow
+#: what the pool was sized beside: the widest SINGLE prompt's.
+PREFILL_GROUP_ROWS = 1024
+#: Buckets whose prompts may share a launch: the widest two of those
+#: :data:`PREFILL_GROUP_ROWS` admits. A pair of buckets is a program of
+#: its own (each prompt in its own bucket: nothing is padded to
+#: another's), so one bucket more adds as many programs as there are
+#: buckets below it, each traced, loaded and run once before a replica
+#: reports ready (3 s apiece at seven layers of 7,168: PERF.md section
+#: 6, PR 54), for the prompts whose launches are the shortest.
+PREFILL_GROUP_BUCKETS = 2
+
+
+@dataclass
+class _Grant:
+    """What the host half of a paged admission took for one request
+    (``_grant_pages``), held until its launch is read: the slot, the
+    prefix hit and its COW source (pinned), the pages (shared first),
+    the suffix's bucket and the entries evicted for it."""
+
+    req: _EngineRequest
+    slot: int
+    hist: int
+    cow_src: int
+    pages: List[int]
+    bucket: int
+    evicted: int
+
+
+@functools.lru_cache(maxsize=1024)
+def _request_key(seed: int) -> np.ndarray:
+    """``jax.random.PRNGKey(seed)`` as the host array a prefill is
+    handed. Making a key is a device dispatch of its own (1.2 ms of
+    the driver's thread at every admission with the device idle:
+    PERF.md section 6, PR 54); requests that share a seed (the default,
+    0) share the one made first."""
+    import jax
+
+    key = np.asarray(jax.random.PRNGKey(seed))
+    key.setflags(write=False)
+    return key
+
+
+def _shares_pages(prompt: np.ndarray, mate: np.ndarray, hist: int,
+                  page_size: int) -> bool:
+    """Whether ``prompt``'s prefix lookup would hit pages that ``mate``,
+    granted but not yet prefilled, is about to write: the two share a
+    prefix that reaches a page boundary (or all of both), and what a
+    lookup would map of it (one token short of the prompt at most)
+    reaches past what ``mate`` itself maps from the cache (``hist``):
+    after ``mate``'s launch registers its pages the lookup finds what
+    it cannot find now."""
+    n = min(len(prompt), len(mate))
+    diff = np.nonzero(prompt[:n] != mate[:n])[0]
+    common = int(diff[0]) if len(diff) else n
+    hit = common if common == len(prompt) == len(mate) \
+        else common // page_size * page_size
+    return min(hit, len(prompt) - 1) > hist
 
 
 class EngineShutdownError(RuntimeError):
@@ -617,7 +712,8 @@ class DecodeEngine:
         self._kept_lock = threading.Lock()
         self._stats_lock = threading.Lock()
         self._stats = {"admitted": 0, "completed": 0, "expired": 0,
-                       "abandoned": 0, "prefills": 0, "dispatches": 0,
+                       "abandoned": 0, "prefills": 0,
+                       "prefill_launches": 0, "dispatches": 0,
                        "tokens": 0, "occupancy_sum": 0.0,
                        "peak_active": 0, "prefix_hits": 0,
                        "prefix_tokens_reused": 0, "cow_copies": 0,
@@ -637,8 +733,12 @@ class DecodeEngine:
                        # request lifecycle, summed where it happens
                        # (monotonic ns): queued -> slot granted over
                        # `admitted`; slot granted -> first token on the
-                       # host and the suffix tokens prefilled over
-                       # `prefills`; and, before each decode/verify
+                       # host (a launch's span ONCE, whatever prompts
+                       # it holds: `prefills` counts prompts,
+                       # `prefill_launches` launches, so the sum over
+                       # `prefills` is the lanes' wait a prompt) and the
+                       # suffix tokens prefilled over `prefills`; and,
+                       # before each decode/verify
                        # dispatch, the time since the previous one's
                        # tokens were read while a lane stayed occupied,
                        # and the part of it spent in prefill phases
@@ -692,13 +792,17 @@ class DecodeEngine:
 
     # THE engine program budget (rtflow RT109, ISSUE 15): one prefill
     # program per prompt bucket (the true prompt length is traced, so
-    # every length within a bucket shares its program) + 1 fused chunk
-    # program + the 2 KV-handoff programs (export + import). The verify
-    # program is budgeted separately in _bind_verify. rtflow audits
-    # this bound against every factory call and dispatch shape
-    # reachable from here; the budget-vs-actual test pins it to the
-    # jit cache sizes on nano CPU.
-    # rtlint: program-budget: len(prompt_buckets) + 3
+    # every length within a bucket shares its program), at most one
+    # more per PAIR of buckets (the two prompts of one chunk boundary in
+    # one launch, each in its own bucket, widest first: n (n + 1) / 2
+    # of the n = PREFILL_GROUP_BUCKETS buckets that group, bounded here
+    # by the square of all there are) + 1 fused chunk program +
+    # the 2 KV-handoff programs (export + import). The verify program
+    # is budgeted separately in _bind_verify. rtflow audits this bound
+    # against every factory call and dispatch shape reachable from
+    # here; the budget-vs-actual test pins it to the jit cache sizes on
+    # nano CPU.
+    # rtlint: program-budget: len(prompt_buckets) * len(prompt_buckets) + len(prompt_buckets) + 3
     def _build_pool(self, page_size: int, n_pages: int,
                     prefix_cache: bool):  # rtlint: holds=_admit_lock
         """Allocate THE persistent page pool and bind its jitted
@@ -1329,7 +1433,7 @@ class DecodeEngine:
             return out
 
         try:
-            key = jax.random.PRNGKey(0)
+            key = _request_key(0)       # as an admission hands it over
             active = np.zeros((self.slots,), bool)
             # The programs' paging operands: no history, an
             # all-sentinel page table (every write drops), no COW.
@@ -1340,6 +1444,25 @@ class DecodeEngine:
                     f"prefill_{b}", self._prefill, self._params_dev,
                     self._cache, np.zeros((1, b), np.int32), np.int32(1),
                     *mid, np.int32(0), key)
+            # ... and the program of every pair of buckets a chunk
+            # boundary can group (_admit_head; widest first, as
+            # _prefill_paged hands a pair over). Its prompts land in no
+            # page, but a model with per-slot state writes the slots it
+            # is given: the first ones, distinct, which hold no lane
+            # now and are rebuilt by the prefill that admits one.
+            G = min(PREFILL_GROUP, self.slots)
+            if G > 1 and self._drafter is None and self.role != "prefill":
+                ones = np.ones((G,), np.int32)
+                wide = sorted(filter(self._groups, self.prompt_buckets),
+                              reverse=True)
+                for bs in itertools.combinations_with_replacement(wide, G):
+                    _, self._cache, _ = timed(
+                        "prefill_" + "+".join(map(str, bs)), self._prefill,
+                        self._params_dev, self._cache,
+                        tuple(np.zeros((1, b), np.int32) for b in bs),
+                        ones, 0 * ones, np.stack([none] * G),
+                        ones * gd.PT_SENTINEL, np.arange(G, dtype=np.int32),
+                        np.stack([key] * G))
             step_args = (self._params_dev, self._cache, self._token,
                          self._rngs, active, self._pt)
             mode = None
@@ -1575,7 +1698,8 @@ class DecodeEngine:
         d = max(out["dispatches"], 1)
         out["avg_occupancy"] = out.pop("occupancy_sum") / d
         out["dispatches_per_token"] = (
-            (out["dispatches"] + out["prefills"]) / max(out["tokens"], 1))
+            (out["dispatches"] + out["prefill_launches"])
+            / max(out["tokens"], 1))
         out.update({f"driver_cpu_ns_{ph[4:]}" if ph.startswith("cpu.")
                     else f"driver_ns_{ph.replace('.', '_')}": ns
                     for ph, ns in self._driver_ns.items()})
@@ -1946,57 +2070,99 @@ class DecodeEngine:
             # the pool would thrash prefills instead of progressing.
             return
         while self._pending and any(s is None for s in self._state):
-            admitted = self._admit_one(self._pending[0], epoch)
+            admitted, deferred = self._admit_head(epoch)
             if epoch >= 0 and epoch != self._epoch:
                 # The supervisor restarted past this driver WHILE its
                 # prefill was blocked on the device: the deque now holds
                 # the new driver's requests — popping would silently
                 # discard one (its lane would hang to its deadline).
                 return
-            if not admitted:
+            for _ in range(admitted):
+                self._pending.popleft()
+            if deferred:
                 self._count(admissions_deferred=1)
                 return               # out of pages: keep FIFO, back off
-            self._pending.popleft()
 
     # rtlint: owner=driver
-    def _admit_one(self, req: _EngineRequest, epoch: int = -1) -> bool:
-        """Prefill ``req`` into a free slot; returns False to defer
-        (no pages). Lane-closed/expired checks happen in
-        :meth:`_admit_pending` before any resources are taken. A stale
-        driver (the supervisor restarted past it while its prefill was
-        stuck on the device) drops the result at the epoch guard instead
-        of writing into the rebuilt pool."""
+    def _admit_head(self, epoch: int = -1) -> Tuple[int, bool]:
+        """Admit the head of the FIFO into free slots with ONE launch:
+        ``(requests admitted, whether the next one deferred)``. The
+        head requests that have a free slot and get their pages, one
+        after another exactly as a lone admission takes them (prefix
+        lookup, pins, allocation with LRU eviction), up to
+        :data:`PREFILL_GROUP`, share one prefill launch
+        (:meth:`_prefill_paged`): one read of the weights, one dispatch
+        and one blocking read for the group. The first request that
+        cannot get pages closes the group and defers as ever (what
+        stood before it still launches); so does one that must go
+        alone: a handoff import (no prefill at all), an export, a
+        prompt the drafter prefills too, a prompt whose bucket shares
+        no launch (:meth:`_groups`), and a prompt whose prefix lookup
+        would hit pages a groupmate is about to write (it goes next,
+        alone or first of its own group, and hits). Lane-closed /
+        expired checks happen in :meth:`_admit_pending` before any
+        resources are taken. A stale driver (the supervisor restarted
+        past it while its prefill was stuck on the device) drops the
+        result at the epoch guard instead of writing into the rebuilt
+        pool."""
         from .._private.metrics import serve_metrics
 
-        slot = next(i for i, s in enumerate(self._state) if s is None)
-        import jax
-
-        P = req.prompt.shape[0]
         sm = serve_metrics()
-        if req.handoff is not None:
-            return self._admit_import(req, slot, sm, epoch)
-        admitted = self._prefill_paged(req, slot, P, sm, jax, epoch)
-        if admitted is None:
-            return False
-        first, pages, hist, bucket = admitted
-        fresh = req.skip == 0 and not req.export
-        self._note_admission(req, slot, sm, fresh)
-        if req.trace_ctx is not None:
-            tracing.record_span("engine.prefill",
-                                mono_ns=(req.granted_ns, req.first_ns),
-                                parent_ctx=req.trace_ctx, slot=slot,
-                                bucket=bucket, hist_len=hist,
-                                pages=len(pages),
-                                deployment=self.deployment)
-        self._count(prefills=1, admitted=1 if fresh else 0,
-                    prefill_ns_sum=req.first_ns - req.granted_ns,
-                    prefill_tokens_sum=P - hist)
-        self._token[slot] = first
-        if req.export:
-            with self._phases.phase("prefill", export=True):
-                return self._finish_export(req, slot, P, pages, first,
-                                           sm)
-        return self._enter_steady_state(req, slot, first, P, pages, sm)
+        free = [i for i, s in enumerate(self._state) if s is None]
+        head = self._pending[0]
+        if head.handoff is not None:
+            done = self._admit_import(head, free[0], sm, epoch)
+            return int(done), not done
+        # ONE pool/prefix snapshot for the whole launch: a supervisor
+        # restart swaps self._pool wholesale, and page accounting split
+        # across two pool objects would corrupt both free lists.
+        pool, prefix = self._pool, self._prefix
+        group: List[_Grant] = []
+        deferred = False
+        for req, slot in zip(self._pending, free[:PREFILL_GROUP]):
+            hist, shared = prefix.lookup(req.prompt) \
+                if prefix is not None else (0, [])
+            bucket = next(b for b in self.prompt_buckets
+                          if b >= req.prompt.shape[0] - hist)
+            alone = req.export or req.handoff is not None \
+                or self._drafter is not None or not self._groups(bucket)
+            if group and (alone or prefix is not None and any(
+                    _shares_pages(req.prompt, g.req.prompt, g.hist,
+                                  self.page_size) for g in group)):
+                break
+            grant = self._grant_pages(req, slot, hist, shared, bucket,
+                                      pool, prefix)
+            if grant is None:
+                deferred = True
+                break
+            group.append(grant)
+            if alone:
+                break
+        if not group:
+            return 0, True
+        firsts = self._prefill_paged(group, pool, prefix, sm, epoch)
+        if firsts is None:
+            return 0, False              # stale: the caller's guard
+        for g, first in zip(group, firsts):
+            req, slot, P = g.req, g.slot, g.req.prompt.shape[0]
+            fresh = req.skip == 0 and not req.export
+            self._note_admission(req, slot, sm, fresh)
+            if req.trace_ctx is not None:
+                tracing.record_span("engine.prefill",
+                                    mono_ns=(req.granted_ns, req.first_ns),
+                                    parent_ctx=req.trace_ctx, slot=slot,
+                                    bucket=g.bucket, hist_len=g.hist,
+                                    pages=len(g.pages), group=len(group),
+                                    deployment=self.deployment)
+            self._count(prefills=1, admitted=1 if fresh else 0,
+                        prefill_tokens_sum=P - g.hist)
+            self._token[slot] = first
+            if req.export:
+                with self._phases.phase("prefill", export=True):
+                    self._finish_export(req, slot, P, g.pages, first, sm)
+            else:
+                self._enter_steady_state(req, slot, first, P, g.pages, sm)
+        return len(group), deferred
 
     # rtlint: owner=driver
     def _note_admission(self, req: _EngineRequest, slot: int, sm,
@@ -2059,27 +2225,28 @@ class DecodeEngine:
         self._observe_pages(sm)
         return True
 
+    def _groups(self, bucket: int) -> bool:
+        """Whether a prompt whose suffix takes ``bucket`` may share its
+        launch: the bucket is one of the widest
+        :data:`PREFILL_GROUP_BUCKETS` of which :data:`PREFILL_GROUP`
+        prompts stay within :data:`PREFILL_GROUP_ROWS`."""
+        wide = [b for b in self.prompt_buckets
+                if PREFILL_GROUP * b <= PREFILL_GROUP_ROWS]
+        return bucket in wide[-PREFILL_GROUP_BUCKETS:]
+
     # rtlint: owner=driver
-    def _prefill_paged(self, req: _EngineRequest, slot: int, P: int,
-                       sm, jax, epoch: int = -1
-                       ) -> Optional[Tuple[int, List[int], int, int]]:
-        """Paged admission: map the cached prefix (refcounted, COW fork
-        if it ends mid-page), allocate fresh pages for the suffix,
-        prefill ONLY the suffix, then register the prompt's pages in the
-        prefix cache. Returns None (nothing taken) when pages are
-        unavailable even after LRU eviction — or when a supervisor
-        restart retired this driver's epoch while its prefill ran (the
-        stale result must not touch the rebuilt pool)."""
+    def _grant_pages(self, req: _EngineRequest, slot: int, hist: int,
+                     shared_pages: List[int], bucket: int,
+                     pool: _PagePool, prefix: Optional[_PrefixCache]
+                     ) -> Optional["_Grant"]:
+        """The host half of a paged admission, behind the prefix lookup
+        (``hist`` tokens of cached prefix on ``shared_pages``): pin the
+        cached pages it maps (refcounted, the COW source too if the
+        prefix ends mid-page) and allocate fresh pages for the suffix,
+        evicting LRU prefix entries while short. Returns None (nothing
+        taken) when pages are unavailable even after eviction."""
         gd = self._model
         ps = self.page_size
-        # ONE pool/prefix snapshot for the whole admission: a supervisor
-        # restart swaps self._pool wholesale, and page accounting split
-        # across two pool objects would corrupt both free lists.
-        pool = self._pool
-        prefix = self._prefix
-        hist, shared_pages = (0, [])
-        if prefix is not None:
-            hist, shared_pages = prefix.lookup(req.prompt)
         shared_full = hist // ps
         partial = hist % ps
         cow_src = shared_pages[shared_full] if partial else \
@@ -2090,7 +2257,7 @@ class DecodeEngine:
         pool.ref(shared)
         if partial:
             pool.ref([cow_src])
-        n_fresh = -(-P // ps) - shared_full
+        n_fresh = -(-req.prompt.shape[0] // ps) - shared_full
         evicted0 = prefix.evictions if prefix is not None else 0
         fresh = self._alloc_pages(n_fresh, pool, prefix)
         if fresh is None:
@@ -2098,63 +2265,107 @@ class DecodeEngine:
             if partial:
                 pool.unref([cow_src])
             return None
-        pages = shared + fresh
-        suffix = req.prompt[hist:]
-        sl = P - hist
-        bucket = next(b for b in self.prompt_buckets if b >= sl)
+        return _Grant(
+            req=req, slot=slot, hist=hist, cow_src=cow_src,
+            pages=shared + fresh, bucket=bucket,
+            evicted=(prefix.evictions - evicted0)
+            if prefix is not None else 0)
+
+    # rtlint: owner=driver
+    def _prefill_paged(self, group: List["_Grant"], pool: _PagePool,
+                       prefix: Optional[_PrefixCache], sm,
+                       epoch: int = -1) -> Optional[List[int]]:
+        """The device half of a paged admission, for the prompts one
+        chunk boundary granted (:meth:`_admit_head`): prefill ONLY each
+        prompt's suffix, all of them in ONE launch (a lone prompt with
+        the scalar operands of the program it always was; a group as a
+        tuple of suffixes, each in its own bucket, widest first), ONE
+        blocking read of their first tokens and keys, then register
+        every prompt's pages in the prefix cache. Returns the first
+        tokens — or None when a supervisor restart retired this
+        driver's epoch while its prefill ran (the stale result must not
+        touch the rebuilt pool; every page the group took is handed
+        back to ``pool``, the snapshot it was granted from)."""
+        gd = self._model
+        G = len(group)
+        # the program's order: widest bucket first, so that a pair of
+        # buckets is ONE program whichever prompt came first
+        order = sorted(group, key=lambda g: -g.bucket)
         clock = self._phases
         with clock.phase(
-                "prefill", bucket=bucket, hist_len=hist,
-                pages_evicted=(prefix.evictions - evicted0)
-                if prefix is not None else 0) as ph:
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :sl] = suffix
-            pt_row = np.full((self.max_pages,), gd.PT_SENTINEL, np.int32)
-            pt_row[:len(pages)] = pages
-            self._pt[slot] = pt_row
+                "prefill", bucket=order[0].bucket, hist_len=group[0].hist,
+                pages_evicted=sum(g.evicted for g in group),
+                group=G) as ph:
+            tokens, pt_rows = [], []
+            for g in order:
+                suffix = g.req.prompt[g.hist:]
+                padded = np.zeros((1, g.bucket), np.int32)
+                padded[0, :len(suffix)] = suffix
+                pt_row = np.full((self.max_pages,), gd.PT_SENTINEL,
+                                 np.int32)
+                pt_row[:len(g.pages)] = g.pages
+                self._pt[g.slot] = pt_row
+                tokens.append(padded)
+                pt_rows.append(pt_row)
             with clock.step("key"):
-                rng = jax.random.PRNGKey(req.seed)
+                rngs = [_request_key(g.req.seed) for g in order]
+            operands = [np.asarray(v, np.int32) for v in zip(*(
+                (g.req.prompt.shape[0] - g.hist, g.hist, g.cow_src, g.slot)
+                for g in order))]
+            if G == 1:
+                length, hist, cow_src, slot = (v[0] for v in operands)
+                tokens, pt_rows, rngs = tokens[0], pt_rows[0], rngs[0]
+            else:
+                length, hist, cow_src, slot = operands
+                tokens, pt_rows = tuple(tokens), np.stack(pt_rows)
+                rngs = np.stack(rngs)
             with clock.step("dispatch"):
                 tok, cache, key = self._prefill(
-                    self._params_dev, self._cache, padded, np.int32(sl),
-                    np.int32(hist), pt_row, np.int32(cow_src),
-                    np.int32(slot), rng)
-            # One transfer per admission — THE TTFT point. The read
+                    self._params_dev, self._cache, tokens, length, hist,
+                    pt_rows, cow_src, slot, rngs)
+            # One transfer per launch — THE TTFT point. The read
             # stays in this frame (and the step annotation is no
             # function of this file): profilers that label the driver
             # by function see the wait here.
             with clock.step("read"):
                 # rtlint: sync-ok=ttft first token streams from the host
-                first = int(np.asarray(tok))
-        req.granted_ns, req.first_ns = ph.t0, ph.t1
+                firsts = np.asarray(tok).reshape(G).tolist()
+            first = {g.slot: t for g, t in zip(order, firsts)}
+        for g in group:
+            g.req.granted_ns, g.req.first_ns = ph.t0, ph.t1
         if epoch >= 0 and epoch != self._epoch:
             # Stale driver: drop the result AND hand back every page
-            # this admission took — against the SAME pool snapshot, so
-            # the accounting stays balanced whether the restart replaced
-            # the pool before or during the admission (a leak here would
-            # shrink the free list forever).
-            pool.unref(pages)
-            if partial:
-                pool.unref([cow_src])
+            # this launch's prompts took — against the SAME pool
+            # snapshot, so the accounting stays balanced whether the
+            # restart replaced the pool before or during the admission
+            # (a leak here would shrink the free list forever).
+            for g in group:
+                pool.unref(g.pages)
+                if g.hist % self.page_size:
+                    pool.unref([g.cow_src])
             return None
         self._cache = cache
-        # Host mirror of the slot's PRNG lane (tiny [2] uint32).
+        self._count(prefill_launches=1, prefill_ns_sum=ph.t1 - ph.t0)
+        # Host mirror of the slots' PRNG lanes (tiny [2] uint32 each).
         # rtlint: sync-ok=prng-mirror re-uploaded per dispatch
-        self._rngs[slot] = np.asarray(key)
-        if partial:
-            # The fork read src synchronously inside the dispatch above;
-            # its pin is no longer needed.
-            pool.unref([cow_src])
-            self._count(cow_copies=1)
-            sm["engine_cow_copies"].inc(
-                labels={"deployment": self.deployment})
-        if hist:
-            self._count(prefix_hits=1, prefix_tokens_reused=hist)
-            sm["engine_prefix_hits"].inc(
-                labels={"deployment": self.deployment})
+        keys = np.asarray(key).reshape(G, 2)
+        for g, k in zip(order, keys):
+            self._rngs[g.slot] = k
+            if g.hist % self.page_size:
+                # The fork read src synchronously inside the dispatch
+                # above; its pin is no longer needed.
+                pool.unref([g.cow_src])
+                self._count(cow_copies=1)
+                sm["engine_cow_copies"].inc(
+                    labels={"deployment": self.deployment})
+            if g.hist:
+                self._count(prefix_hits=1, prefix_tokens_reused=g.hist)
+                sm["engine_prefix_hits"].inc(
+                    labels={"deployment": self.deployment})
         if prefix is not None:
-            prefix.insert(req.prompt, pages)
-        return first, pages, hist, bucket
+            for g in group:         # the cache's LRU order is FIFO's
+                prefix.insert(g.req.prompt, g.pages)
+        return [first[g.slot] for g in group]
 
     # rtlint: owner=driver
     def _finish_export(self, req: _EngineRequest, slot: int, P: int,

@@ -287,13 +287,20 @@ def test_idle_engine_is_idle(make):
 
 @pytest.mark.parametrize("kind", ["int8", "paged"])
 def test_compiles_stand_still_after_first_use(make, nano, kind):
-    """After a bucket's first request nothing compiles, however many
-    requests follow; the next bucket's first use builds one program."""
+    """After a bucket's first requests, one alone and two that share a
+    chunk boundary's launch, nothing compiles, however many requests
+    follow; the next bucket's first use builds one program."""
     # a pool shape no other test of this process has compiled
-    eng = make(kind, slots=3, max_len=40)
-    list(eng.stream(_prompt(nano, 5), 6))
+    eng = make(kind, slots=3, max_len=40, auto_start=False)
+    lanes = [eng.submit(_prompt(nano, 5, i), 6) for i in range(3)]
+    eng.start()                   # a group of two, then one alone
+    from ray_tpu.serve.batching import _EngineStream
+
+    for ln in lanes:
+        list(_EngineStream(ln))
     a = eng.stats()
     assert a["compiles"] > 0 and a["compile_ns"] > 0
+    assert (a["prefills"], a["prefill_launches"]) == (3, 2)
     _run_all(eng, nano, 50, max_new=7)            # prompts of 3-7: bucket 8
     b = eng.stats()
     assert b["compiles"] == a["compiles"]
@@ -398,7 +405,11 @@ def test_traced_request_and_driver_spans(make, nano, spans_on, kind):
     for s in decodes:   # phases nest under the loop iteration's span
         assert by_id[s["parent_id"]]["name"] == "engine.other"
         assert s["attrs"]["deployment"] == eng.deployment
-    assert sum(s["name"] == "engine.prefill" for s in drv) == 3
+    # one engine.prefill phase a LAUNCH, which holds one prompt or the
+    # two that one chunk boundary admitted
+    pre = [s for s in drv if s["name"] == "engine.prefill"]
+    assert len(pre) == d["prefill_launches"] <= d["prefills"] == 3
+    assert sum(s["attrs"]["group"] for s in pre) == 3
     # an iteration that found nothing to do leaves no spans behind
     tracing.drain()
     time.sleep(0.3)

@@ -644,8 +644,10 @@ def _ref_fp_stream(nano, nano_params, prompt):
 # ------------------------------------------------------- program budget
 def test_recompile_guard_both_knobs(nano, nano_params):
     """With attn_kernel=pallas AND kv_dtype=int8 the compiled-program
-    set is STILL ``len(prompt_buckets)`` prefill programs + 1 fused
-    chunk program — quantization scatter, scale updates, and the
+    set is STILL a prefill program a prompt bucket and one a pair of
+    buckets (the group of one chunk boundary; ``warm_up()`` runs them
+    all) + 1 fused chunk program — quantization scatter, scale
+    updates, and the
     kernel dispatch are all inside the same jitted programs, keyed by
     static knobs only. page_size=24 is unique to this test, so the
     (process-wide, lru-shared) wrappers count only this pool's
@@ -675,10 +677,12 @@ def test_recompile_guard_both_knobs(nano, nano_params):
                               [int(rng.integers(1, 10))
                                for _ in prompts])
 
+        eng.warm_up()
         storm([5, 16, 8])                     # warm every bucket
         pre_prefill = eng._prefill._cache_size()
         pre_step = eng._step._cache_size()
-        assert pre_prefill == len(eng.prompt_buckets)
+        n = len(eng.prompt_buckets)
+        assert pre_prefill == n + n * (n + 1) // 2
         assert pre_step == 1
         storm([1, 3, 7, 9, 12, 15, 16, 2])    # mixed-shape storm
         assert eng._prefill._cache_size() == pre_prefill

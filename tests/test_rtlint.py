@@ -624,7 +624,13 @@ def test_engine_declared_budget_matches_actual_nano():
                     "_export": eng._export, "_import": eng._import}
         pre = {k: w._cache_size() for k, w in wrappers.items()}
         rng = np.random.default_rng(3)
-        # Every bucket decodes...
+        # warm_up() runs the whole set once: each bucket's program for
+        # one prompt, each PAIR of buckets' for the two prompts one
+        # chunk boundary admits (widest first: n (n + 1) / 2 of them),
+        # and the chunk program; then every bucket decodes...
+        assert set(eng.warm_up()["programs"]) == {
+            "prefill_8", "prefill_16", "prefill_8+8", "prefill_16+8",
+            "prefill_16+16", "chunk"}
         for n in (5, 8, 11, 16):
             prompt = rng.integers(0, cfg.vocab_size, (n,)).astype(
                 np.int32)
@@ -646,7 +652,11 @@ def test_engine_declared_budget_matches_actual_nano():
         decls = declared_budgets(mod)
         declared = parse_budget(decls["DecodeEngine._build_pool"][1])
         env = {"len(prompt_buckets)": len(buckets)}
-        assert actual == declared.evaluate(env) == len(buckets) + 3
+        # the declaration bounds the pairs by n * n (the grammar has no
+        # division); what is built is n (n + 1) / 2 of them
+        n = len(buckets)
+        assert declared.evaluate(env) == n * n + n + 3
+        assert actual == n * (n + 1) // 2 + n + 3 <= declared.evaluate(env)
         # The verify budget is declared separately (spec engines).
         assert parse_budget(
             decls["DecodeEngine._bind_verify"][1]).evaluate(env) == 1
@@ -658,7 +668,7 @@ def test_engine_declared_budget_matches_actual_nano():
         gdecls = declared_budgets(
             Module("serving.py", "models/serving.py", gsrc))
         assert parse_budget(gdecls["jit_prefill_into_slot_paged"][1]
-                            ).evaluate(env) == len(buckets)
+                            ).evaluate(env) == n * n + n
         assert parse_budget(gdecls["jit_decode_chunk_slots_paged"][1]
                             ).evaluate(env) == 1
     finally:

@@ -324,7 +324,9 @@ def test_paged_dead_parked_lane_is_culled(nano, nano_params):
 
 def test_paged_recompile_guard_with_prefix_hits(nano, nano_params):
     """The paged compiled-program set is exactly
-    ``len(prompt_buckets) + 1`` — prefix-hit admissions (traced
+    ``n + n (n + 1) / 2 + 1`` of ``n`` prompt buckets (a bucket's
+    program for one prompt and a pair of buckets' for the group of one
+    chunk boundary; ``warm_up()`` builds them all) — prefix-hit admissions (traced
     hist_len, COW, arbitrary page tables) and page-pressure replays add
     ZERO programs across a mixed-shape storm. page_size=4 is unique to
     this test (16 is every default engine's), so the (process-wide, lru-shared) jit wrappers count
@@ -368,10 +370,12 @@ def test_paged_recompile_guard_with_prefix_hits(nano, nano_params):
 
         # Warm: cold 20-token shared prompt (bucket 32), plain 5/16
         # (buckets 8/16), then shared repeats (suffix bucket 8).
+        eng.warm_up()
         storm(7, [5, 16])
         pre_prefill = eng._prefill._cache_size()
         pre_step = eng._step._cache_size()
-        assert pre_prefill == len(eng.prompt_buckets)
+        # three buckets alone, and the three pairs of the widest two
+        assert pre_prefill == len(eng.prompt_buckets) + 3
         assert pre_step == 1
         storm(14, [1, 3, 7, 8, 9, 12, 15, 16])
         assert eng._prefill._cache_size() == pre_prefill

@@ -130,8 +130,10 @@ def test_tp2_spec_decode_identity(nano, nano_params):
 
 # --------------------------------------------------- program budget
 def test_tp_recompile_guard(nano, nano_params):
-    """The per-mesh compiled-program budget: a tp=2 engine compiles one
-    prefill per prompt bucket + 1 chunk + 2 handoff programs on ITS OWN
+    """The per-mesh compiled-program budget: a tp=2 engine compiles a
+    prefill per prompt bucket and one per pair of buckets (the group of
+    one chunk boundary: ``warm_up()`` runs them all) + 1 chunk + 2 handoff
+    programs on ITS OWN
     wrappers (distinct lru keys from tp=1), and an admission storm adds
     zero programs."""
     from ray_tpu.models.gpt_decode import (jit_decode_chunk_slots_paged,
@@ -156,10 +158,14 @@ def test_tp_recompile_guard(nano, nano_params):
             for t in threads:
                 t.join()
 
+        # twice: a program takes the pool as the init placed it the
+        # first time and as another program's output the second
+        eng.warm_up()
+        eng.warm_up()
         storm(4, [5, 16])             # warm pass: touch both buckets
         pre_prefill = eng._prefill._cache_size()
         pre_step = eng._step._cache_size()
-        assert pre_prefill >= 2       # one program per prompt bucket
+        assert pre_prefill >= 5       # a bucket's, and a pair's
         storm(12, [1, 3, 7, 8, 9, 12, 15, 16])
         assert eng._prefill._cache_size() == pre_prefill
         assert eng._step._cache_size() == pre_step
