@@ -42,7 +42,10 @@ sequence, and :func:`cache_spec` describes both in one
   this model's config: :func:`gqa_decode_attention` (with
   :func:`gqa_kernel` and :func:`gqa_decode_reads`) is the PUBLIC entry
   through which :mod:`ray_tpu.models.ssm_hybrid` runs the same path at
-  20 queries over 4 KV heads, its rotary applied by the caller. The GPT-2 block's kernel (one
+  20 queries over 4 KV heads, its rotary applied by the caller, and
+  :mod:`ray_tpu.models.ssm_moe` at 32 over 8 with its own score scale
+  folded into ``q``; :func:`gqa_causal_attention` is prefill's, public
+  the same way. The GPT-2 block's kernel (one
   query a head on the VPU) and the latent decoder's (64 absorbed
   queries over one row a token) are other inner loops around the same
   ring of copies, which is copied here, not shared (ROADMAP D13).
@@ -652,21 +655,35 @@ def _gqa_out(att, z, p, cfg: KDAMoEConfig):
     return _dot(att, p["wo"]["kernel"], cfg.dtype)
 
 
-def _gqa_causal(q, k, v, cfg: KDAMoEConfig):
-    """Causal softmax attention of one sequence, no positions: ``q`` [S,
-    Hq, hd] over ``k``, ``v`` [S, Hkv, hd]. Returns float32 [S, Hq,
-    hd]."""
+def gqa_causal_attention(q, k, v, *, n_head: int, n_kv_head: int,
+                         head_dim: int, dtype):
+    """A prefill's grouped-query attention over ONE whole sequence,
+    for any model of such heads (PUBLIC, as
+    :func:`gqa_decode_attention` is decode's): causal softmax of ``q``
+    [S, n_head, head_dim] over ``k``, ``v`` [S, n_kv_head, head_dim]
+    (positions, where the model has any, already applied), scores
+    scaled by ``head_dim ** -0.5`` as the decode kernel scales them,
+    probabilities rounded to ``dtype``. Returns float32 [S, n_head,
+    head_dim]."""
     S = q.shape[0]
-    G = cfg.n_head // cfg.n_kv_head
-    qg = q.reshape(S, cfg.n_kv_head, G, cfg.head_dim)
+    G = n_head // n_kv_head
+    qg = q.reshape(S, n_kv_head, G, head_dim)
     lg = jnp.einsum("qkgd,tkd->kgqt", qg, k,
                     preferred_element_type=jnp.float32) \
-        * cfg.head_dim ** -0.5
+        * head_dim ** -0.5
     lg = jnp.where(jnp.tril(jnp.ones((S, S), jnp.bool_)), lg, -1e30)
-    probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
+    probs = jax.nn.softmax(lg, axis=-1).astype(dtype)
     return jnp.einsum("kgqt,tkd->qkgd", probs, v,
                       preferred_element_type=jnp.float32
-                      ).reshape(S, cfg.n_head, cfg.head_dim)
+                      ).reshape(S, n_head, head_dim)
+
+
+def _gqa_causal(q, k, v, cfg: KDAMoEConfig):
+    """:func:`gqa_causal_attention` at this model's heads (no
+    positions)."""
+    return gqa_causal_attention(q, k, v, n_head=cfg.n_head,
+                                n_kv_head=cfg.n_kv_head,
+                                head_dim=cfg.head_dim, dtype=cfg.dtype)
 
 
 def _gqa_attention_gather(q, kpool, vpool, pages, pos, n_kv_head: int,

@@ -18,8 +18,9 @@ formulation; a sort-based scatter variant can replace it later without
 changing the interface.
 
 Below it: the serving-side DROPLESS expert layer (routing and dispatch
-apart), and the block math the three served expert decoders share
-around it (``rmsnorm``, ``embed``, ``head``, ``block_ffn``).
+apart: three routers, one dispatch), and the block math the served
+expert decoders share around it (``rmsnorm``, ``embed``, ``head``,
+``block_ffn``).
 """
 from __future__ import annotations
 
@@ -127,8 +128,9 @@ def moe_ffn(x: jax.Array, router_kernel: jax.Array, w_up: jax.Array,
 # and is not stood in for. ROUTING and DISPATCH are apart: a router
 # (``route_sigmoid``: sigmoid scores, group-limited, normalised;
 # ``route_softmax_bias``: softmax scores, a selection bias, ids that may
-# lie past the routed experts) gives ids and weights, and ONE dispatch,
-# block loop and combine (``dropless_experts``) serves them all;
+# lie past the routed experts; ``route_topk_softmax``: the top k LOGITS,
+# a softmax over the chosen ones) gives ids and weights, and ONE
+# dispatch, block loop and combine (``dropless_experts``) serves them all;
 # ``zero_experts`` is the part of the ids that cost nothing.
 
 def group_limited_top_k(scores: jax.Array, n_group: int, topk_group: int,
@@ -150,11 +152,13 @@ def group_limited_top_k(scores: jax.Array, n_group: int, topk_group: int,
     return ids.astype(jnp.int32), chosen
 
 
-def _router_logits(x: jax.Array, router_kernel: jax.Array, dtype
-                   ) -> jax.Array:
-    """x [T, d] times the router [d, width] in ``dtype``, float32 sums."""
+def _router_logits(x: jax.Array, router_kernel: jax.Array, dtype,
+                   precision=None) -> jax.Array:
+    """x [T, d] times the router [d, width] in ``dtype``, float32 sums
+    (``precision``: the product's, where ``dtype`` is float32 and the
+    chip would else multiply in bfloat16 passes)."""
     return lax.dot_general(x.astype(dtype), router_kernel.astype(dtype),
-                           (((1,), (0,)), ((), ())),
+                           (((1,), (0,)), ((), ())), precision=precision,
                            preferred_element_type=jnp.float32)
 
 
@@ -203,6 +207,26 @@ def route_softmax_bias(x: jax.Array, router_kernel: jax.Array,
     return ids, jnp.take_along_axis(p, ids, axis=1) * route_scale
 
 
+def route_topk_softmax(x: jax.Array, router_kernel: jax.Array, *,
+                       top_k: int, dtype) -> Tuple[jax.Array, jax.Array]:
+    """x [T, d] -> (ids [T, k], weights [T, k] float32): the ``top_k``
+    largest LOGITS over the router's whole width (best first; a tie
+    goes to the lower id, as :func:`jax.lax.top_k` breaks it), and the
+    weights a softmax over THOSE k logits in float32: they sum to one,
+    and an expert that was not chosen has no say in them. The
+    softmax over the whole width renormalised over the chosen ones is
+    the same number in another order of operations (the two differ by
+    float32 rounding alone); sigmoid scores normalised are not.
+    ``dtype`` float32 means a float32 PRODUCT (precision ``highest``:
+    a width-``k`` choice among logits two wide flips on a bfloat16
+    product's rounding; the router is a thousandth of a layer's
+    operations)."""
+    precision = lax.Precision.HIGHEST if dtype == jnp.float32 else None
+    top, ids = lax.top_k(
+        _router_logits(x, router_kernel, dtype, precision), top_k)
+    return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
 def zero_experts(x: jax.Array, ids: jax.Array, w: jax.Array, *,
                  n_routed: int, live: Optional[jax.Array] = None
                  ) -> Tuple[jax.Array, jax.Array]:
@@ -248,7 +272,8 @@ def dropless_experts(x: jax.Array, ids: jax.Array, w: jax.Array, experts,
     """The held experts' part of a routed layer, dropless, for a
     ROUTING its caller made: ``ids`` [T, k] int32 and ``w`` [T, k]
     float32 from whichever router the model has
-    (:func:`route_sigmoid`, :func:`route_softmax_bias`); an id outside
+    (:func:`route_sigmoid`, :func:`route_softmax_bias`,
+    :func:`route_topk_softmax`); an id outside
     the held range, another chip's expert or no routed expert at all,
     lands nowhere here.
 
@@ -329,10 +354,11 @@ def dropless_experts(x: jax.Array, ids: jax.Array, w: jax.Array, experts,
 # ------------------------------------------- the expert decoders' block
 # What the served expert decoders share around the layer above
 # (``mla_moe``, ``kda_moe``, ``scmoe``: pre-norm, RMSNorm, no biases,
-# a float32 residual stream, an untied head): here, beside the expert
-# layer, because ``block_ffn`` IS that layer behind its norm and the
-# three models already import this module; no model imports another
-# for them.
+# a float32 residual stream, an untied head; ``ssm_moe`` takes the norm
+# and the dispatch and has a tied head and multipliers of its own):
+# here, beside the expert layer, because ``block_ffn`` IS that layer
+# behind its norm and the models already import this module; no model
+# imports another for them.
 
 def rmsnorm(x, scale, eps, dtype=None, gain: float = 1.0):
     """RMSNorm in float32; the result in ``dtype`` (``x``'s own if
@@ -380,7 +406,12 @@ def block_ffn(x, p, cfg, live=None):
     sigmoid-routed layer (:func:`dropless_moe`, by ``cfg``'s
     ``experts_held`` .. ``moe_block_rows``) plus the shared expert
     every token takes (scope ``moe.shared``). ``counts``: whether this
-    was an expert layer, then :func:`dropless_experts`' three."""
+    was an expert layer, then :func:`dropless_experts`' three. The
+    first router's block (``mla_moe``, ``kda_moe``); the second's
+    (:func:`route_softmax_bias`, its shortcut branch) is ``scmoe``'s
+    own and the third's (:func:`route_topk_softmax`, with one
+    multiplier on the residual branch) ``ssm_moe``'s, each around
+    :func:`dropless_experts` and :func:`gated_ffn` as this one is."""
     h = rmsnorm(x, p["ln2_scale"], cfg.eps, cfg.dtype)
     if "ffn" in p:
         return x + gated_ffn(h, p["ffn"], cfg.dtype).astype(x.dtype), \

@@ -3,7 +3,7 @@
 :class:`~ray_tpu.serve.engine.DecodeEngine` serves any decoder whose
 config object answers ``cfg.decode_programs()`` with a module (or
 namespace) of the paged slot-pool programs: the engine binds no model
-module by name. Five decoders answer today: the GPT-2 block
+module by name. Six decoders answer today: the GPT-2 block
 (:mod:`ray_tpu.models.gpt_decode`: a page holds keys and values per
 head), the latent-attention expert decoder
 (:mod:`ray_tpu.models.mla_moe`: a page holds one 576-wide latent a
@@ -14,11 +14,18 @@ fixed recurrent state and a short convolution's tail PER SLOT), the
 shortcut-connected expert decoder (:mod:`ray_tpu.models.scmoe`: TWO
 latent attentions a layer, so the latent entry counts ``2 * n_layer``
 layers of the one pool; the attention is ``mla_moe``'s, imported under
-its public names) and the parallel hybrid decoder
+its public names), the parallel hybrid decoder
 (:mod:`ray_tpu.models.ssm_hybrid`: a state-space state and a
 convolution's tail per slot BESIDE rotary grouped keys and values in
 pages, in EVERY layer; decode's attention is ``kda_moe``'s, through its
-public entry).
+public entry) and the state-space expert decoder
+(:mod:`ray_tpu.models.ssm_moe`: Mamba-2 layers and NoPE grouped-query
+attention layers IN TURN, by index, so a layer keeps a state per slot
+OR pages; the mixer is ``ssm_hybrid``'s and the attention ``kda_moe``'s,
+both imported under public names; its expert layer is routed by the
+THIRD router, :func:`ray_tpu.models.moe.route_topk_softmax`, the top k
+logits and a softmax over the chosen ones, and its head is its
+table).
 
 **A description provides** what only the model knows:
 
@@ -88,7 +95,8 @@ page_size, kv_dtype, attn_kernel)``
     over pages, each taken by its own shapes: either one makes the
     answer true, ``ssm_hybrid`` for two as well, that attention's
     kernel and its own recurrence's on the per-slot state-space state,
-    each by its own shapes. The engine asks it for
+    each by its own shapes, and ``ssm_moe`` for the same two, both
+    imported, one a layer by the layer's kind. The engine asks it for
     ``warm_up()["attn_kernel_mode"]`` (``"compiled"`` / ``"interpret"``,
     read off the lowered program, or ``None`` without a kernel) and for
     ``stats()["attn_kernel_dispatches"]``; every description answers.
@@ -144,7 +152,7 @@ of another (``tests/test_models_frame.py`` holds it; the training
 step's ``_mm``, ``_rmsnorm``, ``_project_vocab`` of ``models/gpt.py``
 are the listed exemption). Block math the expert decoders share
 (``rmsnorm``, ``embed``, ``head``, ``block_ffn``) lives beside the
-expert layer in :mod:`ray_tpu.models.moe`.
+expert layer and its three routers in :mod:`ray_tpu.models.moe`.
 """
 from __future__ import annotations
 
@@ -266,10 +274,10 @@ def knob_cache(fn):
     callers omit trailing defaults — a raw ``lru_cache`` would key
     those spellings separately, silently doubling the compiled-program
     set and breaking the recompile guards' wrapper ``is``-identity.
-    256 entries: the frame's two factories hold every description's
-    wrappers (51 each of five)."""
+    512 entries: the frame's two factories hold every description's
+    wrappers (51 each of six)."""
     sig = inspect.signature(fn)
-    cached = functools.lru_cache(maxsize=256)(fn)
+    cached = functools.lru_cache(maxsize=512)(fn)
 
     @functools.wraps(fn)
     def wrapper(*args, **kwargs):

@@ -53,9 +53,10 @@ the other, by layer):
   ``exp`` of the decays' running sums, between chunks the state passed
   on), every decode step reads a live lane's state ONCE and writes it
   once, in place (scope ``ssm.state``: one Pallas kernel a layer,
-  :func:`_ssm_step_pallas`, in float32, wherever Mosaic can address a
-  head's state, :func:`_state_kernel`; plain XLA, :func:`_ssm_step`,
-  elsewhere), and an idle or parked lane's comes out as it went in, not
+  :func:`ssm_step_pallas`, in float32, wherever Mosaic can address a
+  head's state and a row of it is two lane tiles or more,
+  :func:`state_kernel`; plain XLA, :func:`ssm_step`, elsewhere), and
+  an idle or parked lane's comes out as it went in, not
   a byte of it moved. So no page hash shares them and nothing rolls
   back: :data:`UNSUPPORTED`.
 
@@ -67,6 +68,22 @@ are scaled by ``embed_mult`` and the logits by ``head_mult`` (scope
 Layers are a list of per-layer trees, unrolled; the chunk program
 returns the live lanes and the positions its attention fetched, summed
 over its steps (:data:`STEP_COUNTERS`).
+
+**The SSM mixer is SHARED**, under public names, by sizes
+(:class:`Mamba2Sizes`, which a model's config mixes in) and not by this
+model's config: :func:`ssm_proj`, :func:`ssm_conv`, :func:`ssm_out`
+(projection, convolution, gated norm and output projection),
+:func:`ssm_step` / :func:`ssm_step_pallas` / :func:`state_kernel` (the
+recurrence of one token a lane), :func:`ssm_decode` (a decode step's
+whole mixer on a layer's per-slot entries), :func:`ssd_chunked`,
+:func:`ssm_mix`, :func:`ssm_sequence` (a whole sequence from a zero
+state, with its convolution tail), and :func:`slot_entries` /
+:func:`put_slot` (a layer's per-slot entries and a prefill's write into
+them). :mod:`ray_tpu.models.ssm_moe` imports them (a model whose Mamba-2
+layers STAND IN for attention, with every multiplier 1 and one group);
+this module's own programs call the same functions, and its lowered
+programs are what they were before the names went public
+(``tests/test_models_frame.py``).
 """
 from __future__ import annotations
 
@@ -114,20 +131,63 @@ UNSUPPORTED = {
 #: attention fetched from the pools, all layers
 #: (:func:`ray_tpu.models.kda_moe.gqa_decode_reads`).
 STEP_COUNTERS = ("state_lanes_sum", "gqa_tokens_read_sum")
-#: Heads of a lane's state the recurrence's kernel holds in VMEM at
-#: once (:func:`_block_heads` fits it to the groups): 16 heads of [128,
-#: 256] float32 are 2 MiB, 8 MiB with the block before and the block
-#: after in flight, in and out (of the 16 MiB a kernel may take on a
-#: v5e; 32 heads need the limit raised). Measured there, alone: 8 / 16
-#: / 32 heads 1.73 / 1.68 / 1.71 ms a layer of 127 live lanes, the
-#: copies alone 1.70 / 1.67 / 1.69, the XLA body 2.36 (PERF.md section
-#: 6, PR 53).
-_SSM_BLOCK_HEADS = 16
+#: Bytes of a lane's state the recurrence's kernel holds in VMEM at
+#: once (:func:`block_heads` turns them into heads and fits those to the
+#: groups): 2 MiB, 8 MiB with the block before and the block after in
+#: flight, in and out (of the 16 MiB a kernel may take on a v5e; 4 MiB
+#: need the limit raised). That is 16 heads of [128, 256] float32
+#: (Falcon-H1: measured there, alone, 8 / 16 / 32 heads 1.73 / 1.68 /
+#: 1.71 ms a layer of 127 live lanes, the copies alone 1.70 / 1.67 /
+#: 1.69, the XLA body 2.36: PERF.md section 6, PR 53) and 64 heads of
+#: [64, 128] (granite-4.0-h-small, 128 heads in one group: 16 / 32 / 64
+#: heads 2.76 / 2.62 / 2.58 ms a layer of 127 live lanes, the copies
+#: alone 1.68 / 1.66 / 1.65: a cap in HEADS, 16, cost it 7%; PERF.md
+#: section 6, PR 55, which also says why :func:`state_kernel` leaves
+#: that shape to the XLA body all the same).
+_SSM_BLOCK_BYTES = 2 << 20
 _HI = lax.Precision.HIGHEST
 
 
+class Mamba2Sizes:
+    """What the SHARED mixer's frames read of a config: a model's
+    (frozen dataclass) config mixes this in and provides the fields
+    ``ssm_heads``, ``ssm_head_dim`` (channels a head, ``P``),
+    ``ssm_state`` (the state's width, ``N``), ``ssm_groups`` (groups of
+    heads sharing ``B`` and ``C``), ``conv_size``, ``ssm_chunk``
+    (prefill's chunk), ``eps``, ``dtype`` (compute) and
+    ``state_dtype``. The widths below follow from them; the two
+    multipliers are 1 unless the model states its own."""
+    #: on the output projection's result (1.0: not applied)
+    ssm_out_mult = 1.0
+
+    @property
+    def ssm_col_mults(self) -> Optional[Tuple[float, ...]]:
+        """The input projection's multiplier a segment ``z | x | B | C
+        | dt``, or None: none applied."""
+        return None
+
+    @property
+    def ssm_width(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def bc_width(self) -> int:
+        """``B`` (and ``C``) over all groups."""
+        return self.ssm_groups * self.ssm_state
+
+    @property
+    def conv_dim(self) -> int:
+        """``x | B | C``: what the convolution runs over."""
+        return self.ssm_width + 2 * self.bc_width
+
+    @property
+    def in_width(self) -> int:
+        """``z | x | B | C | dt``: the input projection's columns."""
+        return self.ssm_width + self.conv_dim + self.ssm_heads
+
+
 @dataclasses.dataclass(frozen=True)
-class SSMHybridConfig:
+class SSMHybridConfig(Mamba2Sizes):
     vocab_size: int = 512
     n_layer: int = 2
     d_model: int = 64
@@ -159,23 +219,9 @@ class SSMHybridConfig:
     state_dtype: Any = jnp.float32
 
     @property
-    def ssm_width(self) -> int:
-        return self.ssm_heads * self.ssm_head_dim
-
-    @property
-    def bc_width(self) -> int:
-        """``B`` (and ``C``) over all groups."""
-        return self.ssm_groups * self.ssm_state
-
-    @property
-    def conv_dim(self) -> int:
-        """``x | B | C``: what the convolution runs over."""
-        return self.ssm_width + 2 * self.bc_width
-
-    @property
-    def in_width(self) -> int:
-        """``z | x | B | C | dt``: the input projection's columns."""
-        return self.ssm_width + self.conv_dim + self.ssm_heads
+    def ssm_col_mults(self) -> Tuple[float, ...]:
+        """``ssm_in_mult`` times its segment's of ``ssm_mup``."""
+        return tuple(self.ssm_in_mult * m for m in self.ssm_mup)
 
     def decode_programs(self):
         """This model's description for the serving engine
@@ -363,86 +409,88 @@ def _attn_out(att, p, cfg: SSMHybridConfig):
 
 
 def _attn_causal(q, k, v, cfg: SSMHybridConfig):
-    """Causal softmax attention of one sequence: ``q`` [S, Hq, hd] over
-    ``k``, ``v`` [S, Hkv, hd], rotated already. Returns float32 [S, Hq,
-    hd]."""
-    S = q.shape[0]
-    G = cfg.n_head // cfg.n_kv_head
-    qg = q.reshape(S, cfg.n_kv_head, G, cfg.head_dim)
-    lg = jnp.einsum("qkgd,tkd->kgqt", qg, k,
-                    preferred_element_type=jnp.float32) \
-        * cfg.head_dim ** -0.5
-    lg = jnp.where(jnp.tril(jnp.ones((S, S), jnp.bool_)), lg, -1e30)
-    probs = jax.nn.softmax(lg, axis=-1).astype(cfg.dtype)
-    return jnp.einsum("kgqt,tkd->qkgd", probs, v,
-                      preferred_element_type=jnp.float32
-                      ).reshape(S, cfg.n_head, cfg.head_dim)
+    """:func:`ray_tpu.models.kda_moe.gqa_causal_attention` at this
+    model's heads: ``q`` [S, Hq, hd] over ``k``, ``v`` [S, Hkv, hd],
+    rotated already. Returns float32 [S, Hq, hd]."""
+    return kda_moe.gqa_causal_attention(
+        q, k, v, n_head=cfg.n_head, n_kv_head=cfg.n_kv_head,
+        head_dim=cfg.head_dim, dtype=cfg.dtype)
 
 
-def _mup(cfg: SSMHybridConfig):
-    """The input projection's multiplier a column: ``ssm_in_mult`` times
-    its segment's of ``ssm_mup`` (``z | x | B | C | dt``)."""
-    widths = (cfg.ssm_width, cfg.ssm_width, cfg.bc_width, cfg.bc_width,
-              cfg.ssm_heads)
+# ---------------------------------------------- the shared Mamba-2 mixer
+# Public, by ``Mamba2Sizes`` (``m``): what :mod:`ray_tpu.models.ssm_moe`
+# imports and this module's own programs call. A layer's tree ``p``
+# holds ``in_proj``, ``conv_w``, ``conv_b``, ``dt_bias``, ``A_log``,
+# ``D_skip``, ``ssm_norm`` and ``out_proj`` (:func:`init_params`).
+
+def _mup(m: Mamba2Sizes):
+    """The input projection's multiplier a column, from
+    ``ssm_col_mults`` (``z | x | B | C | dt``)."""
+    widths = (m.ssm_width, m.ssm_width, m.bc_width, m.bc_width,
+              m.ssm_heads)
     return jnp.concatenate([
-        jnp.full((n,), cfg.ssm_in_mult * m, jnp.float32)
-        for n, m in zip(widths, cfg.ssm_mup)])
+        jnp.full((n,), mult, jnp.float32)
+        for n, mult in zip(widths, m.ssm_col_mults)])
 
 
-def _ssm_proj(h, p, cfg: SSMHybridConfig):
+def ssm_proj(h, p, m: Mamba2Sizes):
     """``h`` [..., d] (normed) -> (z [..., W] float32: the gate; xBC
     [..., conv_dim]: ``x | B | C`` BEFORE the convolution, in the
     compute dtype (what the convolution's tail keeps); dt [..., H]
     float32: the step size, ``softplus`` taken; g [..., H]: the log of
     the decay, ``-exp(A_log) dt`` <= 0)."""
-    W = cfg.ssm_width
-    zxbcdt = _dot(h, p["in_proj"]["kernel"], cfg.dtype) * _mup(cfg)
-    z, xBC, dt = (zxbcdt[..., :W], zxbcdt[..., W:W + cfg.conv_dim],
-                  zxbcdt[..., W + cfg.conv_dim:])
+    W = m.ssm_width
+    zxbcdt = _dot(h, p["in_proj"]["kernel"], m.dtype)
+    if m.ssm_col_mults is not None:
+        zxbcdt = zxbcdt * _mup(m)
+    z, xBC, dt = (zxbcdt[..., :W], zxbcdt[..., W:W + m.conv_dim],
+                  zxbcdt[..., W + m.conv_dim:])
     dt = jax.nn.softplus(dt + p["dt_bias"].astype(jnp.float32))
     g = -jnp.exp(p["A_log"].astype(jnp.float32)) * dt
-    return z, xBC.astype(cfg.dtype), dt, g
+    return z, xBC.astype(m.dtype), dt, g
 
 
-def _ssm_conv(window, p, cfg: SSMHybridConfig):
+def ssm_conv(window, p, m: Mamba2Sizes):
     """The short convolution's output for positions whose ``conv_size``
     input rows are ``window[i]`` (a list of ``[..., conv_dim]`` arrays,
     oldest first): SiLU of the depthwise sum plus the bias, in float32,
     split into ``x`` [..., H, P], ``B`` and ``C`` [..., G, N]."""
     taps = p["conv_w"].astype(jnp.float32)               # [conv, conv_dim]
     y = sum(taps[i] * window[i].astype(jnp.float32)
-            for i in range(cfg.conv_size))
+            for i in range(m.conv_size))
     y = jax.nn.silu(y + p["conv_b"].astype(jnp.float32))
-    W, bc = cfg.ssm_width, cfg.bc_width
+    W, bc = m.ssm_width, m.bc_width
     lead = y.shape[:-1]
-    return (y[..., :W].reshape(lead + (cfg.ssm_heads, cfg.ssm_head_dim)),
-            y[..., W:W + bc].reshape(lead + (cfg.ssm_groups, cfg.ssm_state)),
-            y[..., W + bc:].reshape(lead + (cfg.ssm_groups, cfg.ssm_state)))
+    return (y[..., :W].reshape(lead + (m.ssm_heads, m.ssm_head_dim)),
+            y[..., W:W + bc].reshape(lead + (m.ssm_groups, m.ssm_state)),
+            y[..., W + bc:].reshape(lead + (m.ssm_groups, m.ssm_state)))
 
 
-def _per_head(a, cfg: SSMHybridConfig):
+def per_head(a, m: Mamba2Sizes):
     """``B`` or ``C`` [..., G, N] -> [..., H, N]: head ``h`` reads
     group ``h // (H / G)``."""
-    return jnp.repeat(a, cfg.ssm_heads // cfg.ssm_groups, axis=-2)
+    return jnp.repeat(a, m.ssm_heads // m.ssm_groups, axis=-2)
 
 
-def _ssm_out(y, z, p, cfg: SSMHybridConfig):
+def ssm_out(y, z, p, m: Mamba2Sizes):
     """``y`` [..., H, P] float32 (the skip added) and the gate ``z``
     [..., W] -> the branch's part of the residual [..., d]: the gate,
     then an RMSNorm a GROUP of ``W / ssm_groups`` channels with one
-    learned weight a channel, the output projection, its multiplier."""
+    learned weight a channel, the output projection, its multiplier
+    (``ssm_out_mult``, where the model has one)."""
     lead = y.shape[:-2]
     y = y.reshape(lead + (-1,)) * jax.nn.silu(z)
-    y = y.reshape(lead + (cfg.ssm_groups, -1))
-    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.eps)
+    y = y.reshape(lead + (m.ssm_groups, -1))
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + m.eps)
     y = y.reshape(lead + (-1,)) * p["ssm_norm"].astype(jnp.float32)
-    return _dot(y, p["out_proj"]["kernel"], cfg.dtype) * cfg.ssm_out_mult
+    out = _dot(y, p["out_proj"]["kernel"], m.dtype)
+    return out if m.ssm_out_mult == 1.0 else out * m.ssm_out_mult
 
 
-def _ssm_step(S, x, B, C, dt, g, D):
+def ssm_step(S, x, B, C, dt, g, D):
     """The recurrence, one token a lane, in plain XLA float32: the
-    fallback where :func:`_state_kernel` is false, and the oracle the
-    kernel (:func:`_ssm_step_pallas`) is tested against. ``S`` [B, H,
+    fallback where :func:`state_kernel` is false, and the oracle the
+    kernel (:func:`ssm_step_pallas`) is tested against. ``S`` [B, H,
     P, N], ``x`` [B, H, P], ``B`` ``C`` [B, H, N] (per head), ``dt``
     ``g`` [B, H], ``D`` [H]. Returns ``(S', y [B, H, P])``. ``y = S' C
     + D x`` is taken from the state BEFORE the step, ``a (S C) + dt x
@@ -457,33 +505,47 @@ def _ssm_step(S, x, B, C, dt, g, D):
     return S * a[..., None, None] + dx[..., None] * B[..., None, :], y
 
 
-def _state_kernel(cfg: SSMHybridConfig) -> bool:
-    """Whether the step's recurrence is :func:`_ssm_step_pallas`:
-    wherever Mosaic can address a head's state as whole tiles of the
-    state dtype with ``P`` on sublanes and ``N`` on lanes, as the entry
-    lies (``ssm_state`` a multiple of 128 and ``ssm_head_dim`` of 8 in
-    float32, 16 in bfloat16) compiled for a TPU, any width interpreted
-    off it; elsewhere :func:`_ssm_step`."""
+def state_kernel(m: Mamba2Sizes) -> bool:
+    """Whether the step's recurrence is :func:`ssm_step_pallas`:
+    compiled for a TPU, wherever Mosaic can address a head's state as
+    whole tiles of the state dtype with ``P`` on sublanes and ``N`` on
+    lanes, as the entry lies (``ssm_head_dim`` a multiple of 8 in
+    float32, 16 in bfloat16) AND a row of it is at least two lane tiles
+    (``ssm_state`` a multiple of 256); any width interpreted off it;
+    elsewhere :func:`ssm_step`. Two tiles a row, because the kernel
+    pays one cross-lane sum and two lane broadcasts a ROW TILE: at
+    ``[128, 256]`` heads a pair of vregs shares them and they hide
+    under the block's copies (78% of the state's bandwidth bound where
+    the XLA body reads 56: PERF.md section 6, PR 53); at ``[64, 128]``
+    heads every vreg of state pays them and the kernel is bound by its
+    own arithmetic: ALONE, nine layers of 127 live lanes, 2.58 ms a
+    layer where the same blocks copied with no arithmetic take 1.65 and
+    the XLA body 2.41; in the step (traced, 112 lanes) the XLA body
+    read 1.85-2.08 ms a layer and the kernel 2.26 (PERF.md section 6,
+    PR 55): there the XLA body is the FASTER one. By what the program
+    can see of its own shapes; no knob."""
     from .._private.chip import pallas_interpret
 
-    rows = 32 // jnp.dtype(cfg.state_dtype).itemsize
-    return pallas_interpret() or (cfg.ssm_state % 128 == 0
-                                  and cfg.ssm_head_dim % rows == 0)
+    rows = 32 // jnp.dtype(m.state_dtype).itemsize
+    return pallas_interpret() or (m.ssm_state % 256 == 0
+                                  and m.ssm_head_dim % rows == 0)
 
 
-def _block_heads(heads: int, groups: int) -> int:
+def block_heads(heads: int, groups: int, head_bytes: int) -> int:
     """Heads a block of the kernel: the most, up to
-    :data:`_SSM_BLOCK_HEADS`, that divide the heads into blocks of
-    whole groups or blocks inside one group (a block then names its
-    ``B`` and ``C`` by a block of groups)."""
+    :data:`_SSM_BLOCK_BYTES` of state at ``head_bytes`` a head, that
+    divide the heads into blocks of whole groups or blocks inside one
+    group (a block then names its ``B`` and ``C`` by a block of
+    groups)."""
     per_group = heads // groups
-    return max(d for d in range(1, min(heads, _SSM_BLOCK_HEADS) + 1)
+    cap = max(1, _SSM_BLOCK_BYTES // head_bytes)
+    return max(d for d in range(1, min(heads, cap) + 1)
                if heads % d == 0
                and (d % per_group == 0 or per_group % d == 0))
 
 
-def _ssm_step_pallas(state, x, B, C, dt, g, D, active):
-    """:func:`_ssm_step` on a layer's whole per-slot entry ``state``
+def ssm_step_pallas(state, x, B, C, dt, g, D, active):
+    """:func:`ssm_step` on a layer's whole per-slot entry ``state``
     [1, slots, H, P, N], IN PLACE, as one kernel that reads a live
     lane's state once and writes it once. ``x`` [B, H, P], ``B`` ``C``
     [B, G, N] (per GROUP: the kernel reads a group's once for all its
@@ -492,7 +554,7 @@ def _ssm_step_pallas(state, x, B, C, dt, g, D, active):
     inactive lane, whose state no byte of is moved.
 
     Grid ``(B, H / hb)``: step ``(i, j)`` is block ``j``
-    (:func:`_block_heads` heads, ``[hb, P, N]``) of the ``i``-th LIVE
+    (:func:`block_heads` heads, ``[hb, P, N]``) of the ``i``-th LIVE
     lane, named by the scalar-prefetched
     :func:`ray_tpu.models.serving.live_lanes`; the steps past the last
     live lane name the block before them again, which the pipeline
@@ -501,7 +563,7 @@ def _ssm_step_pallas(state, x, B, C, dt, g, D, active):
     entry): a block comes into VMEM, and the sum ``S C``, the decay
     ``a S``, the rank-one term ``dt x (x) B`` and ``y`` are taken from
     that one copy in float32 on the VPU, operation for operation
-    :func:`_ssm_step`'s, and the block goes back where it came from.
+    :func:`ssm_step`'s, and the block goes back where it came from.
     The state lies as the entry holds it, ``N`` on lanes: the sum over
     it is a cross-lane reduction of a head's 16 row tiles, which hides
     under the block's copies (PERF.md section 6, PR 53: within 1.5% of
@@ -520,7 +582,7 @@ def _ssm_step_pallas(state, x, B, C, dt, g, D, active):
 
     Bn, H, P = x.shape
     G, N = B.shape[1:]
-    hb = _block_heads(H, G)
+    hb = block_heads(H, G, P * N * state.dtype.itemsize)
     nb, per_group = H // hb, H // G
     gb = max(1, hb // per_group)             # groups a block reads
     a = jnp.exp(g)
@@ -594,7 +656,7 @@ def _ssm_step_pallas(state, x, B, C, dt, g, D, active):
     return state, jnp.where(active[:, None, None], y, 0.0)
 
 
-def _ssd_chunked(x, B, C, dt, g, S0, chunk: int):
+def ssd_chunked(x, B, C, dt, g, S0, chunk: int):
     """The same recurrence over a whole sequence in chunks (the SSD
     form): ``x`` [T, H, P], ``B`` ``C`` [T, H, N] (per head), ``dt``
     ``g`` [T, H], ``S0`` [H, P, N], float32; ``T`` a multiple of
@@ -639,7 +701,7 @@ def _ssd_chunked(x, B, C, dt, g, S0, chunk: int):
     return y.reshape((T,) + y.shape[2:]), S
 
 
-def _ssm_mix(xBC, dt, g, p, cfg: SSMHybridConfig, live):
+def ssm_mix(xBC, dt, g, p, m: Mamba2Sizes, live):
     """What of the SSM mixer is ONE sequence's, from a zero state: the
     convolution over its ``xBC`` [S, conv_dim] and the chunked SSD form
     (``dt`` ``g`` [S, H]; ``live`` [S] bool: rows past the prompt
@@ -648,39 +710,77 @@ def _ssm_mix(xBC, dt, g, p, cfg: SSMHybridConfig, live):
     behind the zero rows that stand before the sequence's start)``."""
     S = xBC.shape[0]
     with jax.named_scope("ssm.proj"):
-        back = cfg.conv_size - 1
+        back = m.conv_size - 1
         padded = jnp.concatenate(
             [jnp.zeros((back, xBC.shape[-1]), xBC.dtype), xBC])
-        x, B, C = _ssm_conv([padded[i:i + S] for i in range(cfg.conv_size)],
-                            p, cfg)
+        x, B, C = ssm_conv([padded[i:i + S] for i in range(m.conv_size)],
+                           p, m)
         dt = jnp.where(live[:, None], dt, 0.0)
         g = jnp.where(live[:, None], g, 0.0)
     with jax.named_scope("ssm.prefill"):
-        Cn = min(cfg.ssm_chunk, S)
+        Cn = min(m.ssm_chunk, S)
         pad = -S % Cn
-        ops = (x, _per_head(B, cfg), _per_head(C, cfg), dt, g)
+        ops = (x, per_head(B, m), per_head(C, m), dt, g)
         if pad:
             ops = tuple(jnp.concatenate(
                 [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)]) for a in ops)
-        y, S_end = _ssd_chunked(*ops, jnp.zeros(
-            (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32),
+        y, S_end = ssd_chunked(*ops, jnp.zeros(
+            (m.ssm_heads, m.ssm_head_dim, m.ssm_state), jnp.float32),
             Cn)
         y = y[:S] + p["D_skip"].astype(jnp.float32)[:, None] * x
     return y, S_end, padded
 
 
-def _ssm_sequence(h, p, cfg: SSMHybridConfig, live):
+def ssm_sequence(h, p, m: Mamba2Sizes, live):
     """The SSM mixer over one whole sequence from a zero state: ``h``
     [S, d] (normed), ``live`` [S] bool (rows past the prompt advance
     nothing). Returns ``(out [S, d] float32, S_end [H, P, N], padded
     [conv_size - 1 + S, conv_dim]: ``xBC`` before the convolution
     behind the zero rows that stand before the sequence's start)``."""
     with jax.named_scope("ssm.proj"):
-        z, xBC, dt, g = _ssm_proj(h, p, cfg)
-    y, S_end, padded = _ssm_mix(xBC, dt, g, p, cfg, live)
+        z, xBC, dt, g = ssm_proj(h, p, m)
+    y, S_end, padded = ssm_mix(xBC, dt, g, p, m, live)
     with jax.named_scope("ssm.proj"):
-        out = _ssm_out(y, z, p, cfg)
+        out = ssm_out(y, z, p, m)
     return out, S_end, padded
+
+
+def ssm_decode(h, p, m: Mamba2Sizes, state, conv, active, kernel: bool):
+    """One decode step of the SSM mixer on a layer's per-slot entries:
+    ``h`` [B, d] (normed), ``state`` [1, slots, H, P, N] and ``conv``
+    [1, slots, conv_size - 1, conv_dim] (:func:`slot_entries`),
+    ``active`` [B] bool. Every active lane reads and writes its state
+    and its convolution tail whole, the state through
+    :func:`ssm_step_pallas` where ``kernel`` (the caller's
+    :func:`state_kernel`) and :func:`ssm_step` elsewhere; an inactive
+    lane's come out as they went in. Returns ``(out [B, d] float32,
+    state', conv')``; scopes ``ssm.proj`` and ``ssm.state``."""
+    with jax.named_scope("ssm.proj"):
+        z, xBC, dt, g = ssm_proj(h, p, m)
+        tail = conv[0]                             # [B, back, conv_dim]
+        window = [tail[:, i] for i in range(tail.shape[1])] + [xBC]
+        xs, Bs, Cs = ssm_conv(window, p, m)
+        conv = jnp.where(
+            active[:, None, None], jnp.stack(window[1:], axis=1),
+            tail)[None]
+    with jax.named_scope("ssm.state"):
+        D = p["D_skip"].astype(jnp.float32)
+        if kernel:
+            state, y = ssm_step_pallas(state, xs, Bs, Cs, dt, g, D, active)
+        else:
+            S = state[0].astype(jnp.float32)
+            S_new, y = ssm_step(S, xs, per_head(Bs, m), per_head(Cs, m),
+                                dt, g, D)
+            state = jnp.where(active[:, None, None, None], S_new, S
+                              ).astype(state.dtype)[None]
+    with jax.named_scope("ssm.proj"):
+        return ssm_out(y, z, p, m), state, conv
+
+
+# the names this module's tests, and ``tests/perf``'s, go by
+_ssm_proj, _ssm_step, _ssm_step_pallas = ssm_proj, ssm_step, ssm_step_pallas
+_ssd_chunked, _state_kernel, _block_heads = ssd_chunked, state_kernel, \
+    block_heads
 
 
 def forward(params: Params, tokens: jax.Array, cfg: SSMHybridConfig
@@ -697,7 +797,7 @@ def forward(params: Params, tokens: jax.Array, cfg: SSMHybridConfig
         for p in params["layers"]:
             h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
             q, k, v = _attn_qkv(h, p, positions, cfg)
-            x = _mlp(x + _ssm_sequence(h, p, cfg, live)[0]
+            x = _mlp(x + ssm_sequence(h, p, cfg, live)[0]
                      + _attn_out(_attn_causal(q, k, v, cfg), p, cfg), p, cfg)
         return _head(x, params, cfg)
 
@@ -708,6 +808,40 @@ def forward(params: Params, tokens: jax.Array, cfg: SSMHybridConfig
 def slot_entry(name: str, layer: int) -> str:
     """The pool's key of a layer's per-slot entry (``state3``)."""
     return f"{name}{layer}"
+
+
+def _put(pool, rows, *start):
+    """``rows`` written at ``pool[start]`` in place (the leading
+    indices; a traced slot among them)."""
+    lead = len(start)
+    return lax.dynamic_update_slice(
+        pool, rows.astype(pool.dtype)[(None,) * lead],
+        tuple(start) + (0,) * (pool.ndim - lead))
+
+
+def slot_entries(m: Mamba2Sizes, layer: int
+                 ) -> Tuple[CacheEntry, CacheEntry]:
+    """The two per-slot entries of ONE layer's mixer (shared): the
+    state ``[H, P, N]`` in the state dtype (``state<layer>``) and the
+    convolution's last ``conv_size - 1`` input rows ``[conv - 1,
+    conv_dim]`` in the compute dtype (``conv<layer>``), each an array
+    of its own (:func:`cache_spec` says why)."""
+    return (CacheEntry(slot_entry("state", layer), "slot",
+                       (m.ssm_heads, m.ssm_head_dim, m.ssm_state),
+                       m.state_dtype, 1),
+            CacheEntry(slot_entry("conv", layer), "slot",
+                       (m.conv_size - 1, m.conv_dim), m.dtype, 1))
+
+
+def put_slot(state, conv, S_end, padded, length, slot):
+    """A prefill's write into a layer's per-slot entries (shared):
+    ``S_end`` over slot ``slot`` of ``state`` and, over the slot's
+    ``conv``, the ``conv_size - 1`` rows of ``padded``
+    (:func:`ssm_mix`) that end at the prompt's LAST token (row
+    ``length`` on of the padded rows). Returns ``(state', conv')``."""
+    back = conv.shape[2]
+    return _put(state, S_end, 0, slot), _put(conv, lax.dynamic_slice(
+        padded, (length, 0), (back, padded.shape[1])), 0, slot)
 
 
 def cache_spec(cfg: SSMHybridConfig, kv_dtype: str = "fp") -> CacheSpec:
@@ -734,17 +868,10 @@ def cache_spec(cfg: SSMHybridConfig, kv_dtype: str = "fp") -> CacheSpec:
     52)."""
     serving.check_kv_dtype(_THIS, kv_dtype)
     row = (cfg.n_kv_head, cfg.head_dim)
-    per_layer = [
-        (CacheEntry(slot_entry("state", l), "slot",
-                    (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
-                    cfg.state_dtype, 1),
-         CacheEntry(slot_entry("conv", l), "slot",
-                    (cfg.conv_size - 1, cfg.conv_dim), cfg.dtype, 1))
-        for l in range(cfg.n_layer)]
     return CacheSpec(cfg.n_layer, (
         CacheEntry("k", "token", row, cfg.dtype),
         CacheEntry("v", "token", row, cfg.dtype),
-        *(e for pair in per_layer for e in pair)))
+        *(e for l in range(cfg.n_layer) for e in slot_entries(cfg, l))))
 
 
 def max_positions(cfg: SSMHybridConfig) -> int:
@@ -769,7 +896,7 @@ def decode_attention_fused(cfg: SSMHybridConfig, page_size: int,
     own shapes: its ATTENTION over pages
     (:func:`ray_tpu.models.kda_moe.gqa_kernel`: from the page and the
     head) and the RECURRENCE on the per-slot state
-    (:func:`_ssm_step_pallas`, :func:`_state_kernel`: from the state's
+    (:func:`ssm_step_pallas`, :func:`state_kernel`: from the state's
     head); the answer is for the program, so either one makes it true.
     ``attn_kernel`` (one value) has no say."""
     return _state_kernel(cfg) or _gqa_kernel(cfg, page_size)
@@ -783,15 +910,6 @@ shard_params = serving.bind(serving.shard_params, _THIS)
 
 
 # -------------------------------------------------------------- programs
-def _put(pool, rows, *start):
-    """``rows`` written at ``pool[start]`` in place (the leading
-    indices; a traced slot among them)."""
-    lead = len(start)
-    return lax.dynamic_update_slice(
-        pool, rows.astype(pool.dtype)[(None,) * lead],
-        tuple(start) + (0,) * (pool.ndim - lead))
-
-
 def prefill_into_slot_paged(params: Params, cache: Cache,
                             tokens: jax.Array, length: jax.Array,
                             hist_len: jax.Array, pt_row: jax.Array,
@@ -825,8 +943,7 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
                        pt_row[jnp.clip(vp, 0, max_pages - 1)],
                        jnp.int32(PT_SENTINEL))
     kpool, vpool = flat(cache["k"]), flat(cache["v"])
-    slot_entries = {}
-    back = cfg.conv_size - 1
+    slots = {}
     for l, p in enumerate(params["layers"]):
         h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
         q, k, v = _attn_qkv(h, p, wpos, cfg)
@@ -835,11 +952,10 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
         at = (at_layer(page_w, l, n_pages), wpos % ps)
         kpool = kpool.at[at].set(k, mode="drop")
         vpool = vpool.at[at].set(v, mode="drop")
-        y, S_end, padded = _ssm_sequence(h, p, cfg, live)
+        y, S_end, padded = ssm_sequence(h, p, cfg, live)
         state, conv = slot_entry("state", l), slot_entry("conv", l)
-        slot_entries[state] = _put(cache[state], S_end, 0, slot)
-        slot_entries[conv] = _put(cache[conv], lax.dynamic_slice(
-            padded, (length, 0), (back, padded.shape[1])), 0, slot)
+        slots[state], slots[conv] = put_slot(
+            cache[state], cache[conv], S_end, padded, length, slot)
         x = _mlp(x + y + _attn_out(att, p, cfg), p, cfg)
     x_last = lax.dynamic_slice(x, (length - 1, 0), (1, cfg.d_model))
     token, rng = serving.sample(_head(x_last, params, cfg), temperature, rng)
@@ -847,7 +963,7 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
         cache["pos"], jnp.reshape(length, (1,)).astype(jnp.int32), (slot,))
     return token[0], {"k": kpool.reshape(cache["k"].shape),
                       "v": vpool.reshape(cache["v"].shape),
-                      **slot_entries, "pos": pos}, rng
+                      **slots, "pos": pos}, rng
 
 
 def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
@@ -865,7 +981,7 @@ def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
     once (:class:`ray_tpu.models.serving.PromptRows`); each prompt's
     causal attention, its convolution and its chunked recurrence from a
     zero state are the single prefill's on its own rows
-    (:func:`_attn_causal`, :func:`_ssm_mix`), and each lands in its own
+    (:func:`_attn_causal`, :func:`ssm_mix`), and each lands in its own
     pages and its own slot."""
     rows = serving.PromptRows(tokens, length, jnp.zeros_like(length))
     del hist_len, cow_src
@@ -874,7 +990,7 @@ def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
     live = rows.split(rows.live)
     page_w, off = rows.pages(pt_row, page_size)
     kpool, vpool = flat(cache["k"]), flat(cache["v"])
-    slot_entries = {}
+    slots = {}
     back = cfg.conv_size - 1
     for l, p in enumerate(params["layers"]):
         h = rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
@@ -887,27 +1003,29 @@ def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
         kpool = kpool.at[at].set(k, mode="drop")
         vpool = vpool.at[at].set(v, mode="drop")
         with jax.named_scope("ssm.proj"):
-            z, xBC, dt, g = _ssm_proj(h, p, cfg)
+            z, xBC, dt, g = ssm_proj(h, p, cfg)
         state, conv = slot_entry("state", l), slot_entry("conv", l)
-        slot_entries[state], slot_entries[conv] = cache[state], cache[conv]
+        slots[state], slots[conv] = cache[state], cache[conv]
         ys = []
         for i, (xBC_i, dt_i, g_i) in enumerate(zip(
                 rows.split(xBC), rows.split(dt), rows.split(g))):
-            y, S_end, padded = _ssm_mix(xBC_i, dt_i, g_i, p, cfg, live[i])
+            y, S_end, padded = ssm_mix(xBC_i, dt_i, g_i, p, cfg, live[i])
             ys.append(y)
-            slot_entries[state] = _put(slot_entries[state], S_end, 0,
-                                       slot[i])
-            slot_entries[conv] = _put(slot_entries[conv], lax.dynamic_slice(
+            # put_slot's two writes, spelled out: the order in which
+            # ``slot[i]`` and ``length[i]`` are sliced is the lowered
+            # text's, held to the parent's (tests/test_models_frame.py)
+            slots[state] = _put(slots[state], S_end, 0, slot[i])
+            slots[conv] = _put(slots[conv], lax.dynamic_slice(
                 padded, (length[i], 0), (back, padded.shape[1])), 0,
                 slot[i])
         with jax.named_scope("ssm.proj"):
-            y = _ssm_out(jnp.concatenate(ys), z, p, cfg)
+            y = ssm_out(jnp.concatenate(ys), z, p, cfg)
         x = _mlp(x + y + _attn_out(att, p, cfg), p, cfg)
     token, rng = serving.sample_slots(_head(x[rows.last], params, cfg),
                                       temperature, rng)
     return token, {"k": kpool.reshape(cache["k"].shape),
                    "v": vpool.reshape(cache["v"].shape),
-                   **slot_entries,
+                   **slots,
                    "pos": cache["pos"].at[slot].set(
                        length.astype(jnp.int32))}, rng
 
@@ -923,10 +1041,10 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
     (:func:`ray_tpu.models.kda_moe.gqa_decode_attention`: the kernel
     over its live pages or the gather over its whole table row, by
     shape), AND reads and writes its state and convolution tail whole
-    (the state through :func:`_ssm_step_pallas` or :func:`_ssm_step`,
-    by shape: :func:`_state_kernel`). An inactive lane (idle, or parked
-    for pages) neither writes nor advances: its state and tail come out
-    as they went in. Returns
+    (:func:`ssm_decode`: the state through :func:`ssm_step_pallas` or
+    :func:`ssm_step`, by shape: :func:`state_kernel`). An inactive lane
+    (idle, or parked for pages) neither writes nor advances: its state
+    and tail come out as they went in. Returns
     ``(logits [B, rows], cache', counts)``: int32 [2]
     (:data:`STEP_COUNTERS`)."""
     ps = page_size
@@ -941,7 +1059,7 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                             axis=1)[:, 0], jnp.int32(PT_SENTINEL))
     ptc = jnp.clip(pt, 0, n_pages - 1)
     kpool, vpool = flat(cache["k"]), flat(cache["v"])
-    slot_entries = {}
+    slots = {}
     length, fetched = kda_moe.gqa_decode_reads(
         pt, pos, active, n_pages, ps, _gqa_kernel(cfg, ps))
     # the step's own scope: a reader tells the decode program's state,
@@ -959,33 +1077,13 @@ def _slot_decode_step_paged(params: Params, cache: Cache,
                     n_head=cfg.n_head, n_kv_head=cfg.n_kv_head,
                     head_dim=cfg.head_dim, dtype=cfg.dtype, page_size=ps)
             state, conv = slot_entry("state", l), slot_entry("conv", l)
-            with jax.named_scope("ssm.proj"):
-                z, xBC, dt, g = _ssm_proj(h, p, cfg)
-                tail = cache[conv][0]              # [B, back, conv_dim]
-                window = [tail[:, i] for i in range(tail.shape[1])] + [xBC]
-                xs, Bs, Cs = _ssm_conv(window, p, cfg)
-                slot_entries[conv] = jnp.where(
-                    active[:, None, None], jnp.stack(window[1:], axis=1),
-                    tail)[None]
-            with jax.named_scope("ssm.state"):
-                D = p["D_skip"].astype(jnp.float32)
-                if _state_kernel(cfg):
-                    slot_entries[state], y = _ssm_step_pallas(
-                        cache[state], xs, Bs, Cs, dt, g, D, active)
-                else:
-                    S = cache[state][0].astype(jnp.float32)
-                    S_new, y = _ssm_step(
-                        S, xs, _per_head(Bs, cfg), _per_head(Cs, cfg), dt,
-                        g, D)
-                    slot_entries[state] = jnp.where(
-                        active[:, None, None, None], S_new, S
-                    ).astype(cache[state].dtype)[None]
-            with jax.named_scope("ssm.proj"):
-                y = _ssm_out(y, z, p, cfg)
+            y, slots[state], slots[conv] = ssm_decode(
+                h, p, cfg, cache[state], cache[conv], active,
+                _state_kernel(cfg))
             x = _mlp(x + y + _attn_out(att, p, cfg), p, cfg)
         logits = _head(x, params, cfg)
     cache_out = {"k": kpool.reshape(cache["k"].shape),
-                 "v": vpool.reshape(cache["v"].shape), **slot_entries,
+                 "v": vpool.reshape(cache["v"].shape), **slots,
                  "pos": pos + active.astype(jnp.int32)}
     counts = jnp.stack([jnp.sum(active, dtype=jnp.int32),
                         cfg.n_layer * fetched])

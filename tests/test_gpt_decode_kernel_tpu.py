@@ -2,7 +2,8 @@
 keys and values per head, ``mla_moe``'s over latent pages, ``kda_moe``'s
 recurrence on the per-slot state and its grouped-query attention over
 pages of ``(token, KV head)`` rows, ``ssm_hybrid``'s recurrence on its
-state-space state) compiled by the
+state-space state, and the last two again as ``ssm_moe`` imports them)
+compiled by the
 TPU's own compiler, for a chip that is described and not attached
 (v5e), at the shapes the chip runs: what interpret mode cannot see —
 a slice off the tiling, a DMA Mosaic cannot address, more VMEM than a
@@ -502,7 +503,7 @@ def test_ssm_state_kernel_compiles_for_v5e(one_chip, compiled_mode, shape):
 
     B = SSM_SHAPES[shape]
     L, H, G, P, N = 5, 32, 2, 128, 256
-    assert ssm_hybrid._block_heads(H, G) == 16
+    assert ssm_hybrid._block_heads(H, G, P * N * 4) == 16
 
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -512,6 +513,113 @@ def test_ssm_state_kernel_compiles_for_v5e(one_chip, compiled_mode, shape):
         for state in states:
             state, y = ssm_hybrid._ssm_step_pallas(state, x, Bs, Cs, dt, g,
                                                    D, active)
+            out.append(state)
+            x = x + y
+        return out, x
+
+    lowered = jax.jit(step, donate_argnums=(0,)).lower(
+        [arg((1, B, H, P, N))] * L, arg((B, H, P)), arg((B, G, N)),
+        arg((B, G, N)), arg((B, H)), arg((B, H)), arg((H,)),
+        arg((B,), jnp.bool_))
+    assert chip.compiled_by_mosaic(lowered.as_text())
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == L
+    assert "ssm_state/pallas_call" in text
+    layer_state = B * H * P * N * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == L * layer_state
+    assert memory.temp_size_in_bytes < layer_state // 16
+
+
+# ----------------- the state-space expert decoder's step (ISSUE 55)
+def test_the_sixth_blocks_step_chooses_by_shape_for_v5e(
+        one_chip, compiled_mode):
+    """``ssm_moe``'s whole decode step as a TPU process builds it, at
+    ``nano``'s depth (``mamba mamba attention mamba``) around Granite's
+    heads: 32 query heads over 8 KV heads of 128 (pages of 16 x 8 rows)
+    and 8 state heads of ``[64, 128]`` float32 in ONE group. Both
+    choices are IMPORTED and made by shape: ``kda_moe``'s attention
+    kernel once in the ATTENTION layer under
+    ``decode_step/smoe.attention``, the path the benchmark's reader
+    looks for; the recurrence at ONE lane tile a row is the XLA body
+    (``ssm_hybrid.state_kernel``: the faster one there), under
+    ``decode_step/ssm.state`` all the same, and every per-slot entry
+    of the donated pool comes out in place; the expert layer's three
+    scopes and the tied head's are there; and nothing raises."""
+    import dataclasses
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import ssm_hybrid, ssm_moe
+
+    cfg = dataclasses.replace(
+        ssm_moe.CONFIGS["nano"], d_model=256, n_head=32, n_kv_head=8,
+        head_dim=128, attn_mult=1 / 128, ssm_heads=8, ssm_head_dim=64,
+        ssm_state=128, d_expert=128, d_shared=256)
+    B, ps, n_pages = 8, 16, 32
+
+    def arg(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda k: ssm_moe.init_params(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(
+        lambda: ssm_moe.init_paged_cache(cfg, B, n_pages, ps))
+    args = (params, cache, jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((B, n_pages // B), jnp.int32))
+    lowered = jax.jit(functools.partial(
+        ssm_moe._slot_decode_step_paged, cfg=cfg, page_size=ps),
+        donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
+    assert not ssm_hybrid.state_kernel(cfg)
+    assert ssm_hybrid.state_kernel(dataclasses.replace(cfg, ssm_state=256))
+    assert ssm_moe.decode_attention_fused(cfg, ps)
+    assert chip.compiled_by_mosaic(lowered.as_text())
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert "decode_step/smoe.attention/gqa_attention/pallas_call" in text
+    assert "ssm_state/pallas_call" not in text
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    for scope in ("ssm.state", "ssm.proj", "smoe.attention", "moe.route",
+                  "moe.experts", "moe.shared", "lm.head"):
+        assert f"decode_step/{scope}/" in text, scope
+    layer_state = B * 8 * 64 * 128 * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 3 * layer_state
+
+
+def test_ssm_state_kernel_compiles_at_the_sixth_blocks_shape_for_v5e(
+        one_chip, compiled_mode):
+    """The recurrence's kernel at granite-4.0-h-small's widths: 128
+    heads of a ``[64, 128]`` float32 state in ONE group (a block lies
+    inside the group and reads the one ``B`` and ``C``), nine layers of
+    128 slots, one entry each: one ``tpu_custom_call`` a layer, every
+    layer's whole entry its kernel's operand AND result. It COMPILES
+    there; the step does not take it at this shape (``state_kernel``:
+    the XLA body is faster at one lane tile a row), and this test keeps
+    the kernel honest for whoever makes it win."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import ssm_hybrid
+
+    B, L, H, G, P, N = 128, 9, 128, 1, 64, 128
+    # 2 MiB a block: 64 of its heads, where Falcon-H1's are 16
+    assert ssm_hybrid.block_heads(H, G, P * N * 4) == 64
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(states, x, Bs, Cs, dt, g, D, active):
+        out = []
+        for state in states:
+            state, y = ssm_hybrid.ssm_step_pallas(state, x, Bs, Cs, dt, g,
+                                                  D, active)
             out.append(state)
             x = x + y
         return out, x

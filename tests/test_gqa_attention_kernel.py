@@ -45,6 +45,10 @@ CFGS = {
                                 n_kv_head=3),
     "g5x4": dataclasses.replace(km.CONFIGS["nano"], n_head=20,
                                 n_kv_head=4),
+    # the state-space expert decoder's (``models/ssm_moe.py``): 32
+    # queries over 8 KV heads, its own score scale folded into ``q``
+    "g4x8": dataclasses.replace(km.CONFIGS["nano"], n_head=32,
+                                n_kv_head=8),
 }
 T = 32                        # tokens a block in this file
 #: Positions a lane's table reaches: two whole blocks and half a third.

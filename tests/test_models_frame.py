@@ -1,4 +1,4 @@
-"""The serving frame (``ray_tpu/models/serving.py``) and the five model
+"""The serving frame (``ray_tpu/models/serving.py``) and the six model
 descriptions around it.
 
 - no module under ``ray_tpu/models`` imports, or reads off another
@@ -8,11 +8,16 @@ descriptions around it.
   to its own step: its outputs, its counters and the EOS mask-and-carry
   are judged through every description alike;
 - every description answers ``decode_attention_fused``, and the engine
-  asks nothing else.
+  asks nothing else;
+- what a second model imports of another has a PUBLIC name there
+  (``ssm_hybrid``'s Mamba-2 mixer and ``kda_moe``'s attention, for
+  ``ssm_moe``), and making it public changed no program: the lowered
+  text of every older description's three programs is the parent's.
 """
 import ast
 import functools
 import glob
+import hashlib
 import os
 
 import numpy as np
@@ -22,7 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import (gpt, gpt_decode, kda_moe, mla_moe, scmoe,
-                            serving, ssm_hybrid)
+                            serving, ssm_hybrid, ssm_moe)
 from ray_tpu.serve.engine import DecodeEngine
 
 MODELS = os.path.dirname(os.path.abspath(serving.__file__))
@@ -126,6 +131,7 @@ DESCRIPTIONS = {
     kda_moe: (4, 32, 4, 8),
     scmoe: (4, 32, 4, 8),
     ssm_hybrid: (4, 32, 4, 8),
+    ssm_moe: (4, 32, 4, 8),
 }
 
 
@@ -247,7 +253,7 @@ def test_every_description_says_whether_its_chunk_program_holds_a_kernel():
     nano = gpt.CONFIGS["nano"]
     assert [gpt_decode.decode_attention_fused(nano, 8, kernel)
             for kernel in gpt_decode.ATTN_KERNELS] == [False, True]
-    for desc in (mla_moe, kda_moe, scmoe, ssm_hybrid):
+    for desc in (mla_moe, kda_moe, scmoe, ssm_hybrid, ssm_moe):
         assert desc.decode_attention_fused(_model(desc)[0], 4, "gather")
     assert scmoe.decode_attention_fused is mla_moe.decode_attention_fused
 
@@ -273,3 +279,74 @@ def test_the_gpt_engine_reads_its_kernel_as_before(kernel, mode):
             st["dispatches"] if kernel == "pallas" else 0)
     finally:
         eng.shutdown()
+
+
+# ------------------------- what went public changed no program (ISSUE 55)
+#: sha256 (16 hex) of the lowered text of each older description's THREE
+#: programs at ``nano`` on the CPU (page 4, 4 slots of 24 pages): one
+#: prompt (``[1, 8]``), a group of two (``[1, 16]`` and ``[1, 8]``) and
+#: the chunk program (k 3, EOS 7), as commit 1791fd5 (the parent of
+#: ISSUE 55) lowers them: before ``ssm_hybrid``'s Mamba-2 mixer went
+#: public by sizes (``Mamba2Sizes``: ``ssm_proj`` ... ``ssm_decode``,
+#: ``slot_entries``, ``put_slot``) and ``kda_moe.gqa_causal_attention``
+#: by head counts. Whoever changes a program's arithmetic on purpose
+#: reads the new values off this test's failure.
+PARENT_TEXT = {
+    "gpt_decode": ("631a6f1c7deb4552", "63fcf7fc7ca55395",
+                   "c7045c705baca87e"),
+    "mla_moe": ("c643aca1dc411603", "001ebc98072190ad",
+                "722df5bf130357b5"),
+    "scmoe": ("aad294ac66cca770", "7eb5f1cae1ef6639", "9d0e356957ce6c41"),
+    "kda_moe": ("92ae57f1de5ced9c", "855abf4908ee3f2f",
+                "cf3802afa63a7310"),
+    "ssm_hybrid": ("31a6d34e04b5d0fb", "5381337d2af13972",
+                   "ed13fa45b4726bd6"),
+}
+
+
+@pytest.mark.parametrize("name", list(PARENT_TEXT))
+def test_the_older_descriptions_lower_to_the_parents_text(name):
+    """Falcon-H1's three programs before and after its mixer went
+    public, and the other four's beside them: equal to the character
+    (their hashes)."""
+    desc = {d.__name__.rsplit(".", 1)[1]: d for d in DESCRIPTIONS}[name]
+    cfg, params = _model(desc)
+    sentinel = serving.PT_SENTINEL
+    cache = desc.init_paged_cache(cfg, 4, 96, 4)
+    prefill = desc.jit_prefill_into_slot_paged(cfg, 4, 0.0)
+    lone = prefill.lower(
+        params, cache, np.zeros((1, 8), np.int32), np.int32(1), np.int32(0),
+        np.full((24,), sentinel, np.int32), np.int32(sentinel),
+        np.int32(0), jax.random.PRNGKey(0))
+    group = prefill.lower(
+        params, cache,
+        (np.zeros((1, 16), np.int32), np.zeros((1, 8), np.int32)),
+        np.ones((2,), np.int32), np.zeros((2,), np.int32),
+        np.full((2, 24), sentinel, np.int32),
+        np.full((2,), sentinel, np.int32), np.arange(2, dtype=np.int32),
+        np.zeros((2, 2), np.uint32))
+    chunk = desc.jit_decode_chunk_slots_paged(cfg, 3, 4, 0.0, 7).lower(
+        params, cache, np.zeros((4,), np.int32),
+        np.zeros((4, 2), np.uint32), np.ones((4,), bool),
+        np.full((4, 24), sentinel, np.int32))
+    assert tuple(hashlib.sha256(p.as_text().encode()).hexdigest()[:16]
+                 for p in (lone, group, chunk)) == PARENT_TEXT[name]
+
+
+def test_the_shared_mixer_is_imported_under_public_names():
+    """``ssm_moe`` reads of ``ssm_hybrid`` and ``kda_moe`` public names
+    alone (the hygiene walk above holds it for every module); the names
+    it takes exist, and both configs are ``Mamba2Sizes``."""
+    for name in ("Mamba2Sizes", "ssm_proj", "ssm_conv", "ssm_out",
+                 "ssm_step", "ssm_step_pallas", "state_kernel",
+                 "ssd_chunked", "ssm_mix", "ssm_sequence", "ssm_decode",
+                 "slot_entry", "slot_entries", "put_slot"):
+        assert callable(getattr(ssm_hybrid, name)), name
+    for name in ("gqa_kernel", "gqa_decode_reads", "gqa_decode_attention",
+                 "gqa_causal_attention"):
+        assert callable(getattr(kda_moe, name)), name
+    for desc in (ssm_hybrid, ssm_moe):
+        assert isinstance(_model(desc)[0], ssm_hybrid.Mamba2Sizes)
+    falcon, granite = _model(ssm_hybrid)[0], _model(ssm_moe)[0]
+    assert len(falcon.ssm_col_mults) == 5 and granite.ssm_col_mults is None
+    assert ssm_moe.STEP_COUNTERS is kda_moe.STEP_COUNTERS

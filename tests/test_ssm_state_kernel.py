@@ -39,6 +39,10 @@ from ray_tpu.serve.engine import DecodeEngine
 #: bfloat16 product anywhere over ``S`` would read 4e-3.
 REL = 1e-5
 H, G, P, N = 8, 2, 16, 32
+#: bytes of one head's float32 state here: the kernel's block is capped
+#: in BYTES (``_SSM_BLOCK_BYTES``), so a test that wants ``n`` heads a
+#: block sets the cap to ``n`` heads' worth
+HEAD = P * N * 4
 
 
 def _inputs(B, seed, groups=G):
@@ -84,8 +88,8 @@ def test_the_kernel_is_the_recurrence_on_the_live_lanes(
         monkeypatch, B, heads_a_block, mask):
     """Blocks of 1 and 2 heads lie inside a group of 4, one of 4 is a
     group, one of 8 both groups."""
-    monkeypatch.setattr(sh, "_SSM_BLOCK_HEADS", heads_a_block)
-    assert sh._block_heads(H, G) == heads_a_block
+    monkeypatch.setattr(sh, "_SSM_BLOCK_BYTES", heads_a_block * HEAD)
+    assert sh._block_heads(H, G, HEAD) == heads_a_block
     state, *ops = _inputs(B, seed=B)
     active = MASKS[mask](B)
     got_state, got_y = jax.jit(
@@ -104,14 +108,47 @@ def test_the_kernel_is_the_recurrence_on_the_live_lanes(
         assert not np.array_equal(got_state[0][active], state[0][active])
 
 
+@pytest.mark.parametrize("heads_a_block", [8, 16, 32])
+def test_heads_of_64_by_128_in_one_group_as_the_sixth_block_holds_them(
+        monkeypatch, heads_a_block):
+    """The state-space expert decoder's shape (``models/ssm_moe.py``
+    calls the kernel through ``ssm_decode``): heads of ``[64, 128]``,
+    ALL in one group, so that every block lies inside the group and
+    reads the one ``B`` and ``C``; 32 heads here (128 there) in blocks
+    of 8, 16 and all 32, a parked lane between two live ones."""
+    heads, P_, N_, B = 32, 64, 128, 3
+    monkeypatch.setattr(sh, "_SSM_BLOCK_BYTES",
+                        heads_a_block * P_ * N_ * 4)
+    assert sh.block_heads(heads, 1, P_ * N_ * 4) == heads_a_block
+    rng = np.random.default_rng(heads_a_block)
+    state, x, Bs, Cs = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                        for shape in ((1, B, heads, P_, N_),
+                                      (B, heads, P_), (B, 1, N_),
+                                      (B, 1, N_)))
+    dt = jnp.asarray(rng.uniform(0.001, 0.2, (B, heads)), jnp.float32)
+    g = -jnp.asarray(rng.uniform(0.001, 3.0, (B, heads)), jnp.float32)
+    D = jnp.asarray(rng.normal(size=(heads,)) + 1.0, jnp.float32)
+    active = np.array([True, False, True])
+    got_state, got_y = jax.jit(sh.ssm_step_pallas)(
+        state, x, Bs, Cs, dt, g, D, jnp.asarray(active))
+    want_S, want_y = sh.ssm_step(
+        state[0], x, jnp.repeat(Bs, heads, axis=1),
+        jnp.repeat(Cs, heads, axis=1), dt, g, D)
+    got_state = np.asarray(got_state)
+    assert np.array_equal(got_state[0, 1], np.asarray(state)[0, 1])
+    assert not np.asarray(got_y)[1].any()
+    assert _rel(got_state[0][active], np.asarray(want_S)[active]) < REL
+    assert _rel(np.asarray(got_y)[active], np.asarray(want_y)[active]) < REL
+
+
 @pytest.mark.parametrize("block,heads,groups,want", [
     (16, 32, 2, 16), (8, 32, 2, 8), (32, 32, 2, 32),   # the cell's
     (16, 4, 2, 4), (16, 6, 2, 6), (4, 12, 4, 3),       # odd groups
     (2, 12, 4, 1), (16, 24, 1, 12), (16, 24, 8, 12)])
 def test_a_block_is_whole_groups_or_lies_inside_one(
         monkeypatch, block, heads, groups, want):
-    monkeypatch.setattr(sh, "_SSM_BLOCK_HEADS", block)
-    hb = sh._block_heads(heads, groups)
+    monkeypatch.setattr(sh, "_SSM_BLOCK_BYTES", block * HEAD)
+    hb = sh._block_heads(heads, groups, HEAD)
     per = heads // groups
     assert hb == want and heads % hb == 0
     assert hb % per == 0 or per % hb == 0
@@ -123,7 +160,7 @@ def test_a_group_of_heads_reads_its_own_B_and_C(monkeypatch,
                                                 heads_a_block):
     """Moving one group's ``B`` and ``C`` moves that group's heads'
     state and ``y`` and leaves the other group's bits alone."""
-    monkeypatch.setattr(sh, "_SSM_BLOCK_HEADS", heads_a_block)
+    monkeypatch.setattr(sh, "_SSM_BLOCK_BYTES", heads_a_block * HEAD)
     state, x, Bs, Cs, dt, g, D = _inputs(3, seed=7)
     active = jnp.ones((3,), bool)
     s0, y0 = sh._ssm_step_pallas(state, x, Bs, Cs, dt, g, D, active)
@@ -237,15 +274,16 @@ def test_the_step_with_the_kernel_stays_by_the_step_with_the_fallback(
 
 def test_the_choice_is_made_from_what_the_program_can_see(monkeypatch):
     """Interpreted (here) any width is addressable; compiled for a TPU
-    a head's state must be whole tiles of the state dtype (``N`` of 128
-    lanes, ``P`` of 8 sublanes in float32 and 16 in bfloat16), and a
-    shape off the tile takes ``_ssm_step``: the description says which,
-    the program holds a ``pallas_call`` or none, and no knob has a
-    say."""
+    a head's state must be whole tiles of the state dtype (``P`` of 8
+    sublanes in float32 and 16 in bfloat16) with TWO lane tiles a row
+    or more (``N`` of 256: at one tile a row the XLA body is the faster
+    one, PERF.md section 6, PR 55), and any other shape takes
+    ``_ssm_step``: the description says which, the program holds a
+    ``pallas_call`` or none, and no knob has a say."""
     from ray_tpu._private import chip
 
     nano = sh.CONFIGS["nano"]
-    wide = dataclasses.replace(nano, ssm_head_dim=8, ssm_state=128)
+    wide = dataclasses.replace(nano, ssm_head_dim=8, ssm_state=256)
     assert sh.decode_attention_fused(nano, 4)
     assert sh._state_kernel(nano)
     monkeypatch.setattr(chip, "pallas_interpret", lambda: False)
@@ -253,6 +291,7 @@ def test_the_choice_is_made_from_what_the_program_can_see(monkeypatch):
     assert not sh.decode_attention_fused(nano, 4)
     assert not sh._state_kernel(nano)
     assert not sh._state_kernel(dataclasses.replace(wide, ssm_state=192))
+    assert not sh._state_kernel(dataclasses.replace(wide, ssm_state=128))
     assert not sh._state_kernel(dataclasses.replace(wide, ssm_head_dim=12))
     assert not sh._state_kernel(dataclasses.replace(
         wide, state_dtype=jnp.bfloat16))
