@@ -44,17 +44,20 @@ the other, by layer):
       out   = (RMSNorm_groups(y_t * silu(z_t)) * w_norm) W_out
 
   A sequence keeps, whatever its length, ``S`` (``[heads, head_dim,
-  state]`` in :attr:`SSMHybridConfig.state_dtype`) and the last
-  ``conv_size - 1`` rows of ``xBC`` before the convolution: entries
-  ``state<l>`` and ``conv<l>``, ``per "slot"``, ONE ARRAY A LAYER
-  (:func:`cache_spec` says why). They belong to
+  state]`` in :attr:`SSMHybridConfig.state_dtype`; where a row of it
+  is ONE lane tile, ``ssm_state`` 128, held ``N``-major with the heads
+  side by side on lanes: :func:`lane_heads`, one layout a shape) and
+  the last ``conv_size - 1`` rows of ``xBC`` before the convolution:
+  entries ``state<l>`` and ``conv<l>``, ``per "slot"``, ONE ARRAY A
+  LAYER (:func:`cache_spec` says why). They belong to
   the SLOT: every prefill rebuilds them from zero (the chunked form,
   scope ``ssm.prefill``: inside a chunk the masked quadratic form with
   ``exp`` of the decays' running sums, between chunks the state passed
   on), every decode step reads a live lane's state ONCE and writes it
-  once, in place (scope ``ssm.state``: one Pallas kernel a layer,
-  :func:`ssm_step_pallas`, in float32, wherever Mosaic can address a
-  head's state and a row of it is two lane tiles or more,
+  once, in place (scope ``ssm.state``: one Pallas kernel a layer, in
+  float32, wherever Mosaic can address a head's state: at two lane
+  tiles a row or more :func:`ssm_step_pallas`, at one
+  :func:`ssm_step_pallas_nmajor` on the ``N``-major entry,
   :func:`state_kernel`; plain XLA, :func:`ssm_step`, elsewhere), and
   an idle or parked lane's comes out as it went in, not
   a byte of it moved. So no page hash shares them and nothing rolls
@@ -73,8 +76,11 @@ over its steps (:data:`STEP_COUNTERS`).
 (:class:`Mamba2Sizes`, which a model's config mixes in) and not by this
 model's config: :func:`ssm_proj`, :func:`ssm_conv`, :func:`ssm_out`
 (projection, convolution, gated norm and output projection),
-:func:`ssm_step` / :func:`ssm_step_pallas` / :func:`state_kernel` (the
-recurrence of one token a lane), :func:`ssm_decode` (a decode step's
+:func:`ssm_step` / :func:`ssm_step_pallas` /
+:func:`ssm_step_pallas_nmajor` / :func:`state_kernel` (the recurrence
+of one token a lane), :func:`lane_heads` / :func:`state_shape` /
+:func:`state_entry` / :func:`state_heads` (how a layer's state lies in
+its entry), :func:`ssm_decode` (a decode step's
 whole mixer on a layer's per-slot entries), :func:`ssd_chunked`,
 :func:`ssm_mix`, :func:`ssm_sequence` (a whole sequence from a zero
 state, with its convolution tail), and :func:`slot_entries` /
@@ -139,11 +145,11 @@ STEP_COUNTERS = ("state_lanes_sum", "gqa_tokens_read_sum")
 #: (Falcon-H1: measured there, alone, 8 / 16 / 32 heads 1.73 / 1.68 /
 #: 1.71 ms a layer of 127 live lanes, the copies alone 1.70 / 1.67 /
 #: 1.69, the XLA body 2.36: PERF.md section 6, PR 53) and 64 heads of
-#: [64, 128] (granite-4.0-h-small, 128 heads in one group: 16 / 32 / 64
-#: heads 2.76 / 2.62 / 2.58 ms a layer of 127 live lanes, the copies
-#: alone 1.68 / 1.66 / 1.65: a cap in HEADS, 16, cost it 7%; PERF.md
-#: section 6, PR 55, which also says why :func:`state_kernel` leaves
-#: that shape to the XLA body all the same).
+#: [64, 128] (granite-4.0-h-small, 128 heads in one group: held
+#: ``N``-major, 32 rows of two heads, :func:`ssm_step_pallas_nmajor`;
+#: the copies alone 16 / 32 / 64 heads 1.68 / 1.66 / 1.65 ms a layer of
+#: 127 live lanes: a cap in HEADS, 16, would cost 2%; PERF.md section
+#: 6, PRs 55 and 56).
 _SSM_BLOCK_BYTES = 2 << 20
 _HI = lax.Precision.HIGHEST
 
@@ -505,30 +511,85 @@ def ssm_step(S, x, B, C, dt, g, D):
     return S * a[..., None, None] + dx[..., None] * B[..., None, :], y
 
 
+def lane_heads(m: Mamba2Sizes) -> int:
+    """How a layer's state LIES, by the sizes alone (the same on every
+    platform): 0 where the entry is ``[H, P, N]``, ``N`` on lanes (a
+    row of two lane tiles or more, and every shape no lane tile fits);
+    ``k`` >= 1 where a row of state is ONE lane tile (``ssm_state``
+    128) and the entry is held ``N``-MAJOR with ``k`` heads side by
+    side on lanes, ``[H / k, N, k P]``: ``k = 128 / P`` heads fill the
+    128 lanes (granite-4.0-h-small: two heads of 64), one head where
+    ``P`` is whole lane tiles itself. The ``k`` heads of a row share a
+    group. :func:`state_entry` / :func:`state_heads` turn one layout
+    into the other; :func:`state_kernel` names each layout's kernel."""
+    P = m.ssm_head_dim
+    k = max(1, 128 // P)
+    if m.ssm_state != 128 or (k * P) % 128 \
+            or (m.ssm_heads // m.ssm_groups) % k:
+        return 0
+    return k
+
+
+def state_shape(m: Mamba2Sizes) -> Tuple[int, int, int]:
+    """A slot's state as the entry holds it (:func:`lane_heads`)."""
+    H, P, N = m.ssm_heads, m.ssm_head_dim, m.ssm_state
+    k = lane_heads(m)
+    return (H // k, N, k * P) if k else (H, P, N)
+
+
+def state_entry(S, m: Mamba2Sizes):
+    """``S`` [..., H, P, N] -> as the entry holds it: itself, or
+    ``[..., H / k, N, k P]`` (:func:`lane_heads`)."""
+    k = lane_heads(m)
+    if not k:
+        return S
+    lead, (H, P, N) = S.shape[:-3], S.shape[-3:]
+    return jnp.moveaxis(S.reshape(lead + (H // k, k, P, N)), -1, -3) \
+        .reshape(lead + (H // k, N, k * P))
+
+
+def state_heads(S, m: Mamba2Sizes):
+    """The entry's layout -> ``[..., H, P, N]``, what :func:`ssm_step`
+    and the chunked form speak: :func:`state_entry`'s inverse."""
+    k = lane_heads(m)
+    if not k:
+        return S
+    lead, (R, N, W) = S.shape[:-3], S.shape[-3:]
+    return jnp.moveaxis(S.reshape(lead + (R, N, k, W // k)), -3, -1) \
+        .reshape(lead + (R * k, W // k, N))
+
+
 def state_kernel(m: Mamba2Sizes) -> bool:
-    """Whether the step's recurrence is :func:`ssm_step_pallas`:
-    compiled for a TPU, wherever Mosaic can address a head's state as
-    whole tiles of the state dtype with ``P`` on sublanes and ``N`` on
-    lanes, as the entry lies (``ssm_head_dim`` a multiple of 8 in
-    float32, 16 in bfloat16) AND a row of it is at least two lane tiles
-    (``ssm_state`` a multiple of 256); any width interpreted off it;
-    elsewhere :func:`ssm_step`. Two tiles a row, because the kernel
-    pays one cross-lane sum and two lane broadcasts a ROW TILE: at
-    ``[128, 256]`` heads a pair of vregs shares them and they hide
-    under the block's copies (78% of the state's bandwidth bound where
-    the XLA body reads 56: PERF.md section 6, PR 53); at ``[64, 128]``
-    heads every vreg of state pays them and the kernel is bound by its
-    own arithmetic: ALONE, nine layers of 127 live lanes, 2.58 ms a
-    layer where the same blocks copied with no arithmetic take 1.65 and
-    the XLA body 2.41; in the step (traced, 112 lanes) the XLA body
-    read 1.85-2.08 ms a layer and the kernel 2.26 (PERF.md section 6,
-    PR 55): there the XLA body is the FASTER one. By what the program
-    can see of its own shapes; no knob."""
+    """Whether the step's recurrence is a Pallas kernel: compiled for a
+    TPU wherever Mosaic can address a block of the entry as whole tiles
+    of the state dtype, any shape interpreted off it; elsewhere
+    :func:`ssm_step`. WHICH kernel follows the entry's layout
+    (:func:`lane_heads`), by the row's width:
+
+    - two lane tiles a row or more (``ssm_state`` a multiple of 256),
+      the entry ``[H, P, N]``: :func:`ssm_step_pallas`, with ``P`` on
+      sublanes (``ssm_head_dim`` a multiple of 8 in float32, 16 in
+      bfloat16). It pays one cross-lane sum and two lane broadcasts a
+      ROW TILE: at ``[128, 256]`` heads a pair of vregs shares them and
+      they hide under the block's copies (78% of the state's bandwidth
+      bound where the XLA body reads 56: PERF.md section 6, PR 53).
+    - ONE lane tile a row (``ssm_state`` 128), the entry ``N``-major
+      with the heads side by side on lanes:
+      :func:`ssm_step_pallas_nmajor`, whose blocks are whole tiles in
+      either state dtype. In the other layout every vreg of such a
+      state is a row tile of its own and THAT kernel is bound by its
+      own arithmetic (ALONE at ``[64, 128]`` heads, nine layers of 127
+      live lanes, 2.58 ms a layer where the same blocks copied take
+      1.65 and the XLA body 2.41: PERF.md section 6, PR 55); held
+      ``N``-major the sum over ``N`` adds whole vregs and the per-head
+      factors are rows (PERF.md section 6, PR 56).
+
+    By what the program can see of its own shapes; no knob."""
     from .._private.chip import pallas_interpret
 
     rows = 32 // jnp.dtype(m.state_dtype).itemsize
-    return pallas_interpret() or (m.ssm_state % 256 == 0
-                                  and m.ssm_head_dim % rows == 0)
+    return pallas_interpret() or bool(lane_heads(m)) or (
+        m.ssm_state % 256 == 0 and m.ssm_head_dim % rows == 0)
 
 
 def block_heads(heads: int, groups: int, head_bytes: int) -> int:
@@ -656,6 +717,111 @@ def ssm_step_pallas(state, x, B, C, dt, g, D, active):
     return state, jnp.where(active[:, None, None], y, 0.0)
 
 
+def ssm_step_pallas_nmajor(state, x, B, C, dt, g, D, active):
+    """:func:`ssm_step_pallas` for an entry held ``N``-MAJOR
+    (:func:`lane_heads`): ``state`` [1, slots, H / k, N, k P], ``k``
+    heads side by side on lanes, a row of state ONE lane tile. The
+    other operands, the result, the grid over the live lanes' blocks,
+    the aliased entry and what happens where no lane is live are that
+    kernel's; the two share :func:`block_heads` (here in ROWS of ``k``
+    heads, ``[N, k P]`` each) and :func:`ray_tpu.models.serving.
+    live_lanes`, and no line of their bodies, because every operand
+    lies the other way round:
+
+    - the sum ``S C`` runs over SUBLANES and row tiles: ``S`` times
+      ``C`` laid along ``N`` in every lane, then whole vregs added over
+      the row tiles and ONE sublane reduction a row of heads (``[P,
+      N]``: a cross-lane reduction a vreg);
+    - a head's ``a``, ``dt x`` and ``dt x (B . C) + D x`` are ROWS
+      over its ``P`` lanes (``[3, hp, k P]`` a block), broadcast over
+      sublanes once a row of heads (``[P, N]``: a lane broadcast a
+      vreg), and ``y`` leaves as a lane-dense row;
+    - ``B`` and ``C`` come laid along ``N`` in every lane, ``[G, 2, N,
+      k P]`` by the caller's XLA under the same scope (128 KiB a lane a
+      group beside 8 MiB of state in and out), fetched once a lane.
+
+    Some five VPU operations a vreg of state and nothing on the XLU but
+    that one reduction in sixteen: the kernel runs at its copies' pace
+    (PERF.md section 6, PR 56)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from .._private.chip import pallas_interpret
+
+    Bn, H, P = x.shape
+    G, N = B.shape[1:]
+    R, W = state.shape[2], state.shape[4]    # rows of k heads, [N, W] each
+    hp = block_heads(R, G, N * W * state.dtype.itemsize)
+    nb, per_group = R // hp, R // G
+    gb = max(1, hp // per_group)             # groups a block reads
+    a = jnp.exp(g)
+    dx = dt[..., None] * x
+    rest = dx * jnp.repeat(jnp.sum(B * C, axis=-1), H // G,
+                           axis=-1)[..., None] + D[:, None] * x
+    rows = jnp.stack([r.reshape(Bn, nb, hp, W) for r in (
+        jnp.broadcast_to(a[..., None], x.shape), dx, rest)],
+        axis=2)                                      # [B, nb, 3, hp, W]
+    along = jnp.stack([B, C], axis=2)                        # [B, G, 2, N]
+    along = jnp.broadcast_to(along[..., None], along.shape + (W,))
+    lanes, n = serving.live_lanes(active)
+
+    def kernel(lanes_ref, n_ref, s_ref, rows_ref, along_ref, s_out, y_ref):
+        i, j = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(i < n_ref[0])
+        def _():
+            for h in range(hp):
+                S = s_ref[h].astype(jnp.float32)             # [N, W]
+                Bh, Ch = (along_ref[h // per_group, m]
+                          for m in range(2))                 # [N, W]
+                ah, dxh, rh = (rows_ref[m, h:h + 1]
+                               for m in range(3))            # [1, W]
+                y_ref[h:h + 1] = ah * jnp.sum(
+                    S * Ch, axis=0, keepdims=True) + rh
+                s_out[h] = (S * ah + Bh * dxh).astype(s_out.dtype)
+
+        @pl.when((n_ref[0] == 0) & (i == 0) & (j == 0))
+        def _():
+            s_out[...] = s_ref[...]
+
+    def at(*lead, tail, groups=False):
+        """The index map of an operand whose leading indices are
+        ``lead`` (statics), then the lane and the block of rows (or the
+        block of groups that holds those rows), then ``tail`` whole
+        dimensions."""
+        def index(i, j, lanes_ref, n_ref):
+            j = jnp.where(i < n_ref[0], j, nb - 1)
+            if groups and hp < per_group:
+                j = j * hp // per_group
+            return lead + (lanes_ref[i], j) + (0,) * tail
+        return index
+
+    state, y = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(Bn, nb),
+            in_specs=[
+                pl.BlockSpec((None, None, hp, N, W), at(0, tail=2)),
+                pl.BlockSpec((None, None, 3, hp, W), at(tail=3)),
+                pl.BlockSpec((None, gb, 2, N, W), at(tail=3, groups=True)),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, None, hp, N, W), at(0, tail=2)),
+                pl.BlockSpec((None, None, hp, W), at(tail=2)),
+            ]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((Bn, nb, hp, W), jnp.float32)],
+        # operand 2 (after the two scalar operands) is the state
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=pallas_interpret(),
+        name="ssm_state",
+    )(lanes, n, state, rows, along)
+    return state, jnp.where(active[:, None, None], y.reshape(Bn, H, P), 0.0)
+
+
 def ssd_chunked(x, B, C, dt, g, S0, chunk: int):
     """The same recurrence over a whole sequence in chunks (the SSD
     form): ``x`` [T, H, P], ``B`` ``C`` [T, H, N] (per head), ``dt``
@@ -747,13 +913,16 @@ def ssm_sequence(h, p, m: Mamba2Sizes, live):
 
 def ssm_decode(h, p, m: Mamba2Sizes, state, conv, active, kernel: bool):
     """One decode step of the SSM mixer on a layer's per-slot entries:
-    ``h`` [B, d] (normed), ``state`` [1, slots, H, P, N] and ``conv``
-    [1, slots, conv_size - 1, conv_dim] (:func:`slot_entries`),
-    ``active`` [B] bool. Every active lane reads and writes its state
-    and its convolution tail whole, the state through
-    :func:`ssm_step_pallas` where ``kernel`` (the caller's
-    :func:`state_kernel`) and :func:`ssm_step` elsewhere; an inactive
-    lane's come out as they went in. Returns ``(out [B, d] float32,
+    ``h`` [B, d] (normed), ``state`` [1, slots, *:func:`state_shape`]
+    and ``conv`` [1, slots, conv_size - 1, conv_dim]
+    (:func:`slot_entries`), ``active`` [B] bool. Every active lane
+    reads and writes its state and its convolution tail whole, the
+    state through its layout's kernel (:func:`ssm_step_pallas`, or
+    :func:`ssm_step_pallas_nmajor` where :func:`lane_heads`) where
+    ``kernel`` (the caller's :func:`state_kernel`) and through
+    :func:`ssm_step` elsewhere, which reads either layout as ``[H, P,
+    N]`` (:func:`state_heads`); an inactive lane's come out as they
+    went in. Returns ``(out [B, d] float32,
     state', conv')``; scopes ``ssm.proj`` and ``ssm.state``."""
     with jax.named_scope("ssm.proj"):
         z, xBC, dt, g = ssm_proj(h, p, m)
@@ -766,13 +935,16 @@ def ssm_decode(h, p, m: Mamba2Sizes, state, conv, active, kernel: bool):
     with jax.named_scope("ssm.state"):
         D = p["D_skip"].astype(jnp.float32)
         if kernel:
-            state, y = ssm_step_pallas(state, xs, Bs, Cs, dt, g, D, active)
+            step = ssm_step_pallas_nmajor if lane_heads(m) \
+                else ssm_step_pallas
+            state, y = step(state, xs, Bs, Cs, dt, g, D, active)
         else:
-            S = state[0].astype(jnp.float32)
+            S = state_heads(state[0], m).astype(jnp.float32)
             S_new, y = ssm_step(S, xs, per_head(Bs, m), per_head(Cs, m),
                                 dt, g, D)
-            state = jnp.where(active[:, None, None, None], S_new, S
-                              ).astype(state.dtype)[None]
+            state = state_entry(
+                jnp.where(active[:, None, None, None], S_new, S), m
+            ).astype(state.dtype)[None]
     with jax.named_scope("ssm.proj"):
         return ssm_out(y, z, p, m), state, conv
 
@@ -822,26 +994,30 @@ def _put(pool, rows, *start):
 def slot_entries(m: Mamba2Sizes, layer: int
                  ) -> Tuple[CacheEntry, CacheEntry]:
     """The two per-slot entries of ONE layer's mixer (shared): the
-    state ``[H, P, N]`` in the state dtype (``state<layer>``) and the
+    state in the state dtype (``state<layer>``), ``[H, P, N]`` or,
+    where a row of it is one lane tile, ``N``-major with ``k`` heads
+    side by side on lanes, ``[H / k, N, k P]`` (:func:`state_shape`:
+    ONE layout a shape, the one its kernel reads), and the
     convolution's last ``conv_size - 1`` input rows ``[conv - 1,
     conv_dim]`` in the compute dtype (``conv<layer>``), each an array
     of its own (:func:`cache_spec` says why)."""
     return (CacheEntry(slot_entry("state", layer), "slot",
-                       (m.ssm_heads, m.ssm_head_dim, m.ssm_state),
-                       m.state_dtype, 1),
+                       state_shape(m), m.state_dtype, 1),
             CacheEntry(slot_entry("conv", layer), "slot",
                        (m.conv_size - 1, m.conv_dim), m.dtype, 1))
 
 
-def put_slot(state, conv, S_end, padded, length, slot):
+def put_slot(m: Mamba2Sizes, state, conv, S_end, padded, length, slot):
     """A prefill's write into a layer's per-slot entries (shared):
-    ``S_end`` over slot ``slot`` of ``state`` and, over the slot's
-    ``conv``, the ``conv_size - 1`` rows of ``padded``
+    ``S_end`` [H, P, N], laid as the entry holds it
+    (:func:`state_entry`), over slot ``slot`` of ``state`` and, over
+    the slot's ``conv``, the ``conv_size - 1`` rows of ``padded``
     (:func:`ssm_mix`) that end at the prompt's LAST token (row
     ``length`` on of the padded rows). Returns ``(state', conv')``."""
     back = conv.shape[2]
-    return _put(state, S_end, 0, slot), _put(conv, lax.dynamic_slice(
-        padded, (length, 0), (back, padded.shape[1])), 0, slot)
+    return _put(state, state_entry(S_end, m), 0, slot), _put(
+        conv, lax.dynamic_slice(
+            padded, (length, 0), (back, padded.shape[1])), 0, slot)
 
 
 def cache_spec(cfg: SSMHybridConfig, kv_dtype: str = "fp") -> CacheSpec:
@@ -896,8 +1072,9 @@ def decode_attention_fused(cfg: SSMHybridConfig, page_size: int,
     own shapes: its ATTENTION over pages
     (:func:`ray_tpu.models.kda_moe.gqa_kernel`: from the page and the
     head) and the RECURRENCE on the per-slot state
-    (:func:`ssm_step_pallas`, :func:`state_kernel`: from the state's
-    head); the answer is for the program, so either one makes it true.
+    (:func:`ssm_step_pallas` or :func:`ssm_step_pallas_nmajor`,
+    :func:`state_kernel`: from the state's head); the answer is for
+    the program, so either one makes it true.
     ``attn_kernel`` (one value) has no say."""
     return _state_kernel(cfg) or _gqa_kernel(cfg, page_size)
 
@@ -955,7 +1132,7 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
         y, S_end, padded = ssm_sequence(h, p, cfg, live)
         state, conv = slot_entry("state", l), slot_entry("conv", l)
         slots[state], slots[conv] = put_slot(
-            cache[state], cache[conv], S_end, padded, length, slot)
+            cfg, cache[state], cache[conv], S_end, padded, length, slot)
         x = _mlp(x + y + _attn_out(att, p, cfg), p, cfg)
     x_last = lax.dynamic_slice(x, (length - 1, 0), (1, cfg.d_model))
     token, rng = serving.sample(_head(x_last, params, cfg), temperature, rng)
@@ -1014,7 +1191,8 @@ def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
             # put_slot's two writes, spelled out: the order in which
             # ``slot[i]`` and ``length[i]`` are sliced is the lowered
             # text's, held to the parent's (tests/test_models_frame.py)
-            slots[state] = _put(slots[state], S_end, 0, slot[i])
+            slots[state] = _put(slots[state], state_entry(S_end, cfg), 0,
+                                slot[i])
             slots[conv] = _put(slots[conv], lax.dynamic_slice(
                 padded, (length[i], 0), (back, padded.shape[1])), 0,
                 slot[i])

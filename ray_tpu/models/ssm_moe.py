@@ -22,17 +22,21 @@ in one :class:`~ray_tpu.models.serving.CacheSpec`:
 - ``"mamba"``: the Mamba-2 mixer of :mod:`ray_tpu.models.ssm_hybrid`,
   imported under its public names (:class:`~ray_tpu.models.ssm_hybrid.
   Mamba2Sizes`, which this config mixes in: every multiplier 1). A
-  sequence keeps, whatever its length, the state ``[heads, head_dim,
-  state]`` in :attr:`SSMMoEConfig.state_dtype` and the convolution's
-  last ``conv_size - 1`` input rows: entries ``state<l>`` and
+  sequence keeps, whatever its length, the state (``[heads, head_dim,
+  state]``, or ``N``-major with the heads side by side on lanes where a
+  row of it is one lane tile: :func:`ray_tpu.models.ssm_hybrid.
+  state_shape`) in :attr:`SSMMoEConfig.state_dtype` and the
+  convolution's last ``conv_size - 1`` input rows: entries ``state<l>`` and
   ``conv<l>``, ``per "slot"``, ONE ARRAY A LAYER (``ssm_hybrid.
   cache_spec`` says why), for the Mamba layers ``l`` alone. Prefill
   rebuilds them from zero in the chunked form (scopes ``ssm.proj``,
   ``ssm.prefill``), a decode step reads and writes a live lane's state
-  in place (scope ``ssm.state``: the Pallas kernel ``ssm_state`` or
-  the XLA body, by shape: :func:`ray_tpu.models.ssm_hybrid.
-  state_kernel`; at granite-4.0-h-small's ``[64, 128]`` heads the XLA
-  body, which is the faster one there).
+  in place (scope ``ssm.state``: the Pallas kernel ``ssm_state`` of
+  the entry's layout or the XLA body, by shape: :func:`ray_tpu.models.
+  ssm_hybrid.state_kernel`; at granite-4.0-h-small's ``[64, 128]``
+  heads the entry is ``[64, 128, 128]``, two heads side by side on
+  lanes, and the kernel :func:`ray_tpu.models.ssm_hybrid.
+  ssm_step_pallas_nmajor`).
 - ``"attention"``: ``n_head`` query heads over ``n_kv_head`` key/value
   heads, NO positions, scores ``q . k * attn_mult`` (a published
   constant, NOT ``head_dim ** -0.5``). A token leaves ``n_kv_head x
@@ -412,9 +416,12 @@ def decode_attention_fused(cfg: SSMMoEConfig, page_size: int,
     This model has TWO, both imported and each taken by what the
     program can see of its own shapes: the RECURRENCE on the Mamba
     layers' per-slot state (:func:`ray_tpu.models.ssm_hybrid.
-    state_kernel`) and the attention layers' ATTENTION over pages
+    state_kernel`: the kernel of the entry's layout, at
+    granite-4.0-h-small's one lane tile a row the ``N``-major one) and
+    the attention layers' ATTENTION over pages
     (:func:`ray_tpu.models.kda_moe.gqa_kernel`); either one makes the
-    answer true. ``attn_kernel`` (one value) has no say."""
+    answer true, and at granite-4.0-h-small's shapes on a TPU both
+    are. ``attn_kernel`` (one value) has no say."""
     return (bool(cfg.ssm_layers) and ssm_hybrid.state_kernel(cfg)) \
         or (bool(cfg.attn_layers) and _gqa_kernel(cfg, page_size))
 
@@ -474,7 +481,7 @@ def prefill_into_slot_paged(params: Params, cache: Cache,
             state, conv = (ssm_hybrid.slot_entry(n, l)
                            for n in ("state", "conv"))
             slots[state], slots[conv] = ssm_hybrid.put_slot(
-                cache[state], cache[conv], S_end, padded, length, slot)
+                cfg, cache[state], cache[conv], S_end, padded, length, slot)
         x = _ffn(x + cfg.resid_mult * y, p, cfg, live)[0]
     x_last = lax.dynamic_slice(x, (length - 1, 0), (1, cfg.d_model))
     token, rng = serving.sample(_head(x_last, params, cfg), temperature, rng)
@@ -544,8 +551,8 @@ def prefill_group_into_slots_paged(params: Params, cache: Cache, tokens,
                     xBC_i, dt_i, g_i, p, cfg, live[i])
                 ys.append(yi)
                 slots[state], slots[conv] = ssm_hybrid.put_slot(
-                    slots[state], slots[conv], S_end, padded, length[i],
-                    slot[i])
+                    cfg, slots[state], slots[conv], S_end, padded,
+                    length[i], slot[i])
             with jax.named_scope("ssm.proj"):
                 y = ssm_hybrid.ssm_out(jnp.concatenate(ys), z, p, cfg)
         x = _ffn(x + cfg.resid_mult * y, p, ffn_cfg, rows.live)[0]

@@ -542,11 +542,12 @@ def test_the_sixth_blocks_step_chooses_by_shape_for_v5e(
     choices are IMPORTED and made by shape: ``kda_moe``'s attention
     kernel once in the ATTENTION layer under
     ``decode_step/smoe.attention``, the path the benchmark's reader
-    looks for; the recurrence at ONE lane tile a row is the XLA body
-    (``ssm_hybrid.state_kernel``: the faster one there), under
-    ``decode_step/ssm.state`` all the same, and every per-slot entry
-    of the donated pool comes out in place; the expert layer's three
-    scopes and the tied head's are there; and nothing raises."""
+    looks for; the recurrence at ONE lane tile a row is the kernel of
+    the ``N``-major entry (``ssm_hybrid.state_kernel``, ISSUE 56: the
+    pool holds the state ``[H / 2, N, 2 P]``), once a MAMBA layer under
+    ``decode_step/ssm.state``, every per-slot entry of the donated pool
+    out in place; the expert layer's three scopes and the tied head's
+    are there; and nothing raises."""
     import dataclasses
     import functools
 
@@ -575,15 +576,19 @@ def test_the_sixth_blocks_step_chooses_by_shape_for_v5e(
     lowered = jax.jit(functools.partial(
         ssm_moe._slot_decode_step_paged, cfg=cfg, page_size=ps),
         donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
-    assert not ssm_hybrid.state_kernel(cfg)
+    assert ssm_hybrid.lane_heads(cfg) == 2 and ssm_hybrid.state_kernel(cfg)
+    assert cache["state0"].shape == (1, B, 4, 128, 128)
     assert ssm_hybrid.state_kernel(dataclasses.replace(cfg, ssm_state=256))
+    assert not ssm_hybrid.state_kernel(
+        dataclasses.replace(cfg, ssm_state=384))
     assert ssm_moe.decode_attention_fused(cfg, ps)
     assert chip.compiled_by_mosaic(lowered.as_text())
     compiled = lowered.compile()
     text = compiled.as_text()
     assert "decode_step/smoe.attention/gqa_attention/pallas_call" in text
-    assert "ssm_state/pallas_call" not in text
-    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "decode_step/ssm.state/ssm_state/pallas_call" in text
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 1 + len(cfg.ssm_layers)
     for scope in ("ssm.state", "ssm.proj", "smoe.attention", "moe.route",
                   "moe.experts", "moe.shared", "lm.head"):
         assert f"decode_step/{scope}/" in text, scope
@@ -592,16 +597,23 @@ def test_the_sixth_blocks_step_chooses_by_shape_for_v5e(
     assert memory.alias_size_in_bytes >= 3 * layer_state
 
 
+@pytest.mark.parametrize("kernel,state_dtype", [
+    ("ssm_step_pallas_nmajor", "float32"),
+    ("ssm_step_pallas_nmajor", "bfloat16"), ("ssm_step_pallas", "float32")])
 def test_ssm_state_kernel_compiles_at_the_sixth_blocks_shape_for_v5e(
-        one_chip, compiled_mode):
-    """The recurrence's kernel at granite-4.0-h-small's widths: 128
+        one_chip, compiled_mode, kernel, state_dtype):
+    """The recurrence's kernels at granite-4.0-h-small's widths: 128
     heads of a ``[64, 128]`` float32 state in ONE group (a block lies
     inside the group and reads the one ``B`` and ``C``), nine layers of
     128 slots, one entry each: one ``tpu_custom_call`` a layer, every
-    layer's whole entry its kernel's operand AND result. It COMPILES
-    there; the step does not take it at this shape (``state_kernel``:
-    the XLA body is faster at one lane tile a row), and this test keeps
-    the kernel honest for whoever makes it win."""
+    layer's whole entry its kernel's operand AND result. The step takes
+    ``ssm_step_pallas_nmajor`` there, on the entry held ``[64, 128,
+    128]`` (ISSUE 56: blocks of 32 rows of two heads, 2 MiB, in
+    float32 as the cell states it, and of all 64 rows in bfloat16,
+    whose rows of 128 are whole tiles too); the
+    kernel of the ``[H, P, N]`` entry COMPILES at this shape too (blocks
+    of 64 heads), though no step takes it below two lane tiles a row
+    (``state_kernel``: bound by its own arithmetic there)."""
     import jax
     import jax.numpy as jnp
 
@@ -609,8 +621,15 @@ def test_ssm_state_kernel_compiles_at_the_sixth_blocks_shape_for_v5e(
     from ray_tpu.models import ssm_hybrid
 
     B, L, H, G, P, N = 128, 9, 128, 1, 64, 128
-    # 2 MiB a block: 64 of its heads, where Falcon-H1's are 16
-    assert ssm_hybrid.block_heads(H, G, P * N * 4) == 64
+    size = jnp.dtype(state_dtype).itemsize
+    if kernel == "ssm_step_pallas_nmajor":
+        entry = (1, B, H // 2, N, 2 * P)
+        assert ssm_hybrid.block_heads(H // 2, G, N * 2 * P * size) \
+            == 128 // size
+    else:
+        entry = (1, B, H, P, N)
+        # 2 MiB a block: 64 of its heads, where Falcon-H1's are 16
+        assert ssm_hybrid.block_heads(H, G, P * N * 4) == 64
 
     def arg(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -618,14 +637,14 @@ def test_ssm_state_kernel_compiles_at_the_sixth_blocks_shape_for_v5e(
     def step(states, x, Bs, Cs, dt, g, D, active):
         out = []
         for state in states:
-            state, y = ssm_hybrid.ssm_step_pallas(state, x, Bs, Cs, dt, g,
-                                                  D, active)
+            state, y = getattr(ssm_hybrid, kernel)(state, x, Bs, Cs, dt, g,
+                                                   D, active)
             out.append(state)
             x = x + y
         return out, x
 
     lowered = jax.jit(step, donate_argnums=(0,)).lower(
-        [arg((1, B, H, P, N))] * L, arg((B, H, P)), arg((B, G, N)),
+        [arg(entry, state_dtype)] * L, arg((B, H, P)), arg((B, G, N)),
         arg((B, G, N)), arg((B, H)), arg((B, H)), arg((H,)),
         arg((B,), jnp.bool_))
     assert chip.compiled_by_mosaic(lowered.as_text())
@@ -633,7 +652,7 @@ def test_ssm_state_kernel_compiles_at_the_sixth_blocks_shape_for_v5e(
     text = compiled.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == L
     assert "ssm_state/pallas_call" in text
-    layer_state = B * H * P * N * 4
+    layer_state = B * H * P * N * size
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == L * layer_state
     assert memory.temp_size_in_bytes < layer_state // 16

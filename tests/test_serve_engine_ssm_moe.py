@@ -449,6 +449,72 @@ def test_the_step_takes_both_kernels_by_shape_and_both_bodies_agree(
     assert text.count("pallas_call") == (cfg.n_layer if kernel else 0)
 
 
+# ---- one lane tile a row: the state held N-major in the pool
+
+@pytest.fixture(scope="module")
+def model_tile():
+    """granite-4.0-h-small's heads, ``[64, 128]`` in one group (four of
+    them): a row of state is ONE lane tile, so the pool holds the state
+    ``N``-major, two heads side by side on lanes."""
+    cfg = dataclasses.replace(sm.CONFIGS["nano"], dtype=jnp.float32,
+                              param_dtype=jnp.float32, ssm_heads=4,
+                              ssm_head_dim=64, ssm_state=128)
+    return cfg, sm.init_params(jax.random.PRNGKey(1), cfg)
+
+
+@pytest.mark.parametrize("grouped", [False, True],
+                         ids=["one-prompt", "group-of-two"])
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "xla"])
+def test_a_prefill_into_the_n_major_entry_then_decoding_is_forward(
+        model_tile, monkeypatch, kernel, grouped):
+    """``put_slot`` lays a prefill's ``S_end`` as the entry holds it
+    (``[H / 2, N, 2 P]``), from a single prefill and from a group of
+    two, and ``ssm_decode`` steps it there, with the kernel of that
+    layout and (``state_kernel`` steered off) with the XLA body through
+    the accessor: the logits are ``forward``'s and the reference's at
+    every step, the parked lane's state the bits that went in."""
+    cfg, params = model_tile
+    assert ssm_hybrid.lane_heads(cfg) == 2
+    if not kernel:
+        monkeypatch.setattr(ssm_hybrid, "state_kernel", lambda m: False)
+    a, b, c = _prompts(cfg, (19, 15, 9), seed=11)
+    want = {0: _reference(model_tile, a), 2: _reference(model_tile, b)}
+    own = {0: np.asarray(sm.forward(params, jnp.asarray(a)[None], cfg))[0],
+           2: np.asarray(sm.forward(params, jnp.asarray(b)[None], cfg))[0]}
+    if grouped:
+        _, cache, pt = _prefilled(model_tile, {1: c}, 16, slots=3)
+        tokens = []
+        for prompt in (a[:13], b[:9]):
+            padded = np.zeros((1, 16), np.int32)
+            padded[0, :len(prompt)] = prompt
+            tokens.append(padded)
+        _, cache, _ = sm.jit_prefill_into_slot_paged(cfg, 4)(
+            params, cache, tuple(tokens), np.array([13, 9], np.int32),
+            np.zeros((2,), np.int32), pt[[0, 2]],
+            np.full((2,), serving.PT_SENTINEL, np.int32),
+            np.array([0, 2], np.int32), np.zeros((2, 2), np.uint32))
+    else:
+        _, cache, pt = _prefilled(model_tile, {0: a[:13], 2: b[:9], 1: c},
+                                  16, slots=3)
+    for l in cfg.ssm_layers:
+        assert cache[f"state{l}"].shape == (1, 3, 2, 128, 128)
+    before = jax.tree_util.tree_map(np.asarray, cache)
+    step = jax.jit(functools.partial(sm._slot_decode_step_paged, cfg=cfg,
+                                     page_size=4))
+    active = np.array([True, False, True])
+    for i in range(5):
+        logits, cache, _ = step(
+            params, cache, jnp.asarray([int(a[13 + i]), 3, int(b[9 + i])]),
+            active, jnp.asarray(pt))
+        for lane, off in ((0, 13), (2, 9)):
+            for ref in (want[lane][off + i], own[lane][off + i]):
+                assert np.abs(np.asarray(logits)[lane] - ref).max() \
+                    < REL * np.abs(ref).max(), (lane, i)
+    for key, arr in _slot_arrays(cache, "state").items():
+        assert np.array_equal(arr[:, 1], before[key][:, 1])
+        assert not np.array_equal(arr[:, 0], before[key][:, 0])
+
+
 # ---- the third router
 
 def test_route_topk_softmax_is_the_references_router():

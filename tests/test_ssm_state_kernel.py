@@ -16,6 +16,11 @@ The contract under test:
   reads ``y`` = 0; the entry keeps its dtype;
 - a group's ``B`` and ``C`` reach that group's heads and no other,
   whether a block holds part of a group, one, or several;
+- where a row of state is ONE lane tile (``ssm_state`` 128) the entry
+  lies ``N``-major with the heads side by side on lanes
+  (``lane_heads``, ``state_entry`` / ``state_heads``) and the kernel is
+  ``ssm_step_pallas_nmajor`` (ISSUE 56), held to the same oracle and
+  the same contract: one layout and one kernel a shape;
 - the step takes the kernel by what it can see (no knob, no new
   ``attn_kernel`` name) and says which through the description's
   ``decode_attention_fused``; the engine reports and counts it.
@@ -111,11 +116,13 @@ def test_the_kernel_is_the_recurrence_on_the_live_lanes(
 @pytest.mark.parametrize("heads_a_block", [8, 16, 32])
 def test_heads_of_64_by_128_in_one_group_as_the_sixth_block_holds_them(
         monkeypatch, heads_a_block):
-    """The state-space expert decoder's shape (``models/ssm_moe.py``
-    calls the kernel through ``ssm_decode``): heads of ``[64, 128]``,
-    ALL in one group, so that every block lies inside the group and
-    reads the one ``B`` and ``C``; 32 heads here (128 there) in blocks
-    of 8, 16 and all 32, a parked lane between two live ones."""
+    """The kernel of the ``[H, P, N]`` entry at the state-space expert
+    decoder's heads (no step takes it there since ISSUE 56: that
+    model's entry lies ``N``-major, the tests below; the body is right
+    at any shape all the same): heads of ``[64, 128]``, ALL in one
+    group, so that every block lies inside the group and reads the one
+    ``B`` and ``C``; 32 heads here (128 there) in blocks of 8, 16 and
+    all 32, a parked lane between two live ones."""
     heads, P_, N_, B = 32, 64, 128, 3
     monkeypatch.setattr(sh, "_SSM_BLOCK_BYTES",
                         heads_a_block * P_ * N_ * 4)
@@ -139,6 +146,158 @@ def test_heads_of_64_by_128_in_one_group_as_the_sixth_block_holds_them(
     assert not np.asarray(got_y)[1].any()
     assert _rel(got_state[0][active], np.asarray(want_S)[active]) < REL
     assert _rel(np.asarray(got_y)[active], np.asarray(want_y)[active]) < REL
+
+
+# ---- one lane tile a row: the entry N-major, heads side by side on lanes
+
+class _Sizes(sh.Mamba2Sizes):
+    """Bare sizes, as the shared mixer's frames read them."""
+
+    def __init__(self, heads, head_dim, state, groups,
+                 state_dtype=jnp.float32):
+        self.ssm_heads, self.ssm_head_dim = heads, head_dim
+        self.ssm_state, self.ssm_groups = state, groups
+        self.state_dtype = state_dtype
+
+
+def _tile_inputs(m, B, seed):
+    """The operands of a step at ``m``'s heads, the state ``[B, H, P,
+    N]`` in float32 (``state_entry`` lays it as the entry holds it)."""
+    rng = np.random.default_rng(seed)
+    H_, P_, N_, G_ = m.ssm_heads, m.ssm_head_dim, m.ssm_state, m.ssm_groups
+    S, x, Bs, Cs = (jnp.asarray(rng.normal(size=shape), jnp.float32)
+                    for shape in ((B, H_, P_, N_), (B, H_, P_),
+                                  (B, G_, N_), (B, G_, N_)))
+    dt = jnp.asarray(rng.uniform(0.001, 0.2, (B, H_)), jnp.float32)
+    g = -jnp.asarray(rng.uniform(0.001, 3.0, (B, H_)), jnp.float32)
+    D = jnp.asarray(rng.normal(size=(H_,)) + 1.0, jnp.float32)
+    return S, (x, Bs, Cs, dt, g, D)
+
+
+def _nmajor_against_the_oracle(m, S, ops, active):
+    """The kernel on the entry as ``state_entry`` lays it, against
+    ``ssm_step`` on ``[H, P, N]``; returns nothing, asserts."""
+    dtype = jnp.dtype(m.state_dtype)
+    entry = sh.state_entry(S, m).astype(dtype)[None]
+    assert entry.shape[2:] == sh.state_shape(m)
+    got_state, got_y = jax.jit(sh.ssm_step_pallas_nmajor)(
+        entry, *ops, jnp.asarray(active))
+    assert got_state.shape == entry.shape and got_state.dtype == dtype
+    assert got_y.dtype == jnp.float32
+    held = sh.state_heads(entry[0], m).astype(jnp.float32)
+    want_S, want_y = _oracle(held[None], *ops)
+    got_S = np.asarray(sh.state_heads(got_state[0], m).astype(jnp.float32))
+    # an inactive lane: not a bit of its state, and y = 0
+    assert np.array_equal(np.asarray(got_state[0].astype(jnp.float32))[
+        ~active], np.asarray(entry[0].astype(jnp.float32))[~active])
+    assert not np.asarray(got_y)[~active].any()
+    if active.any():
+        assert _rel(np.asarray(got_y)[active],
+                    np.asarray(want_y)[active]) < REL
+        ulp = 2.0 ** -8 if dtype == jnp.bfloat16 else REL
+        assert _rel(got_S[active], np.asarray(want_S)[active]) <= ulp
+        assert not np.array_equal(got_S[active], np.asarray(held)[active])
+
+
+TILE_MASKS = {
+    "parked-between": np.array([True, False, True]),
+    "all-live": np.array([True, True, True]),
+    "no-lane-live": np.array([False, False, False]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("mask", sorted(TILE_MASKS))
+@pytest.mark.parametrize("heads_a_block", [2, 4, 8, 16])
+def test_heads_of_64_by_128_in_one_group_held_n_major(
+        monkeypatch, heads_a_block, mask, dtype):
+    """The state-space expert decoder's heads (``[64, 128]``, ALL in
+    one group; 16 here, 128 there) as ``ssm_decode`` now steps them:
+    the entry ``[H / 2, N, 2 P]``, two heads side by side on lanes, in
+    blocks of 1, 2, 4 and all 8 rows of heads; a parked lane between
+    two live ones, every lane live, NO lane live (the aliased entry
+    comes out as it went in); the state float32 or bfloat16."""
+    m = _Sizes(16, 64, 128, 1, dtype)
+    assert sh.lane_heads(m) == 2 and sh.state_shape(m) == (8, 128, 128)
+    row = 128 * 128 * jnp.dtype(dtype).itemsize      # a row of two heads
+    monkeypatch.setattr(sh, "_SSM_BLOCK_BYTES", heads_a_block // 2 * row)
+    assert sh.block_heads(8, 1, row) == heads_a_block // 2
+    S, ops = _tile_inputs(m, 3, seed=heads_a_block)
+    _nmajor_against_the_oracle(m, S, ops, TILE_MASKS[mask])
+
+
+@pytest.mark.parametrize("head_dim,heads_a_block", [
+    (64, 2), (64, 4), (64, 8),          # inside a group, a group, both
+    (32, 4), (32, 8), (32, 16),         # four heads a row
+    (128, 1), (128, 2), (128, 4)])      # a head a row
+def test_two_groups_at_one_lane_tile_a_row_read_their_own_B_and_C(
+        monkeypatch, head_dim, heads_a_block):
+    """A row of ``k = 128 / P`` heads lies inside ONE group, and a
+    block names its groups as the other kernel's does: a block inside a
+    group, one group, both; moving the second group's ``B`` and ``C``
+    moves that group's heads alone."""
+    k = max(1, 128 // head_dim)
+    m = _Sizes(4 * k, head_dim, 128, 2)
+    assert sh.lane_heads(m) == k
+    assert sh.state_shape(m) == (4, 128, k * head_dim)
+    row = 128 * k * head_dim * 4
+    monkeypatch.setattr(sh, "_SSM_BLOCK_BYTES", heads_a_block // k * row)
+    assert sh.block_heads(4, 2, row) == heads_a_block // k
+    S, ops = _tile_inputs(m, 3, seed=head_dim + heads_a_block)
+    active = np.array([True, False, True])
+    _nmajor_against_the_oracle(m, S, ops, active)
+    x, Bs, Cs, dt, g, D = ops
+    entry = sh.state_entry(S, m)[None]
+    s0, y0 = sh.ssm_step_pallas_nmajor(entry, *ops, jnp.asarray(active))
+    s1, y1 = sh.ssm_step_pallas_nmajor(
+        entry, x, Bs.at[:, 1].mul(2.0), Cs.at[:, 1].mul(-1.0), dt, g, D,
+        jnp.asarray(active))
+    first, second = slice(0, 2 * k), slice(2 * k, 4 * k)
+    s0, s1 = (np.asarray(sh.state_heads(s[0], m)) for s in (s0, s1))
+    assert np.array_equal(s0[:, first], s1[:, first])
+    assert np.array_equal(np.asarray(y0)[:, first], np.asarray(y1)[:, first])
+    assert not np.array_equal(s0[active][:, second], s1[active][:, second])
+    assert not np.array_equal(np.asarray(y0)[active][:, second],
+                              np.asarray(y1)[active][:, second])
+
+
+@pytest.mark.parametrize("sizes,want", [
+    ((128, 64, 128, 1), (2, (64, 128, 128))),    # granite-4.0-h-small
+    ((8, 32, 128, 2), (4, (2, 128, 128))),
+    ((8, 128, 128, 2), (1, (8, 128, 128))),
+    ((8, 256, 128, 2), (1, (8, 128, 256))),
+    ((32, 128, 256, 2), (0, (32, 128, 256))),    # Falcon-H1: two tiles
+    ((4, 16, 32, 2), (0, (4, 16, 32))),          # nano: no tile fits
+    ((8, 48, 128, 2), (0, (8, 48, 128))),        # heads do not fill lanes
+    ((6, 64, 128, 2), (0, (6, 64, 128))),        # a row would span groups
+    ((8, 64, 384, 2), (0, (8, 64, 384)))])
+def test_one_layout_a_shape_and_the_two_accessors_are_inverses(sizes, want):
+    """``lane_heads`` and ``state_shape`` are functions of the sizes
+    alone; ``state_entry`` puts head ``r k + i`` of ``[H, P, N]`` at
+    lanes ``i P ...`` of row ``r`` with ``N`` on sublanes, and
+    ``state_heads`` undoes it to the bit."""
+    m = _Sizes(*sizes)
+    k, shape = want
+    assert sh.lane_heads(m) == k and sh.state_shape(m) == shape
+    H_, P_, N_, _ = sizes
+    S = jnp.asarray(np.random.default_rng(0).normal(size=(2, H_, P_, N_)),
+                    jnp.float32)
+    entry = sh.state_entry(S, m)
+    assert entry.shape == (2,) + shape
+    assert np.array_equal(np.asarray(sh.state_heads(entry, m)),
+                          np.asarray(S))
+    if k:
+        S, entry = np.asarray(S), np.asarray(entry)
+        for head, p, n in ((0, 0, 0), (k - 1, P_ - 1, 5), (H_ - 1, 3, 127)):
+            assert entry[1, head // k, n, head % k * P_ + p] \
+                == S[1, head, p, n]
+    else:
+        assert entry is S
+    spec_state, _ = sh.slot_entries(dataclasses.replace(
+        sh.CONFIGS["nano"], ssm_heads=H_, ssm_head_dim=P_, ssm_state=N_,
+        ssm_groups=sizes[3]), 3)
+    assert spec_state.name == "state3" and tuple(spec_state.shape) == shape
 
 
 @pytest.mark.parametrize("block,heads,groups,want", [
@@ -274,12 +433,14 @@ def test_the_step_with_the_kernel_stays_by_the_step_with_the_fallback(
 
 def test_the_choice_is_made_from_what_the_program_can_see(monkeypatch):
     """Interpreted (here) any width is addressable; compiled for a TPU
-    a head's state must be whole tiles of the state dtype (``P`` of 8
-    sublanes in float32 and 16 in bfloat16) with TWO lane tiles a row
-    or more (``N`` of 256: at one tile a row the XLA body is the faster
-    one, PERF.md section 6, PR 55), and any other shape takes
-    ``_ssm_step``: the description says which, the program holds a
-    ``pallas_call`` or none, and no knob has a say."""
+    a head's state must be whole tiles of the state dtype: with TWO
+    lane tiles a row or more (``N`` of 256) as the entry ``[H, P, N]``
+    lies (``P`` of 8 sublanes in float32 and 16 in bfloat16), with ONE
+    lane tile a row (``N`` of 128) as the ``N``-major entry lies, heads
+    side by side filling the lanes (``ssm_step_pallas_nmajor``: PERF.md
+    section 6, PR 56); any other shape takes ``_ssm_step``: the
+    description says which, the program holds a ``pallas_call`` or
+    none, and no knob has a say."""
     from ray_tpu._private import chip
 
     nano = sh.CONFIGS["nano"]
@@ -291,7 +452,20 @@ def test_the_choice_is_made_from_what_the_program_can_see(monkeypatch):
     assert not sh.decode_attention_fused(nano, 4)
     assert not sh._state_kernel(nano)
     assert not sh._state_kernel(dataclasses.replace(wide, ssm_state=192))
-    assert not sh._state_kernel(dataclasses.replace(wide, ssm_state=128))
+    # one lane tile a row: the kernel of the N-major entry wherever the
+    # heads of a group fill the lanes side by side, in either dtype
+    # (sixteen heads of 8 would, and a group of ``wide`` has two)
+    tile = dataclasses.replace(wide, ssm_state=128, ssm_head_dim=64)
+    assert sh.lane_heads(tile) == 2 and sh._state_kernel(tile)
+    assert sh._state_kernel(dataclasses.replace(
+        tile, state_dtype=jnp.bfloat16))
+    assert sh._state_kernel(dataclasses.replace(
+        tile, ssm_heads=128, ssm_groups=1))          # granite-4.0-h-small
+    assert sh._state_kernel(dataclasses.replace(tile, ssm_head_dim=128))
+    for off in (dict(ssm_head_dim=8), dict(ssm_head_dim=48),
+                dict(ssm_heads=6), dict(ssm_state=384)):
+        cfg = dataclasses.replace(tile, **off)
+        assert not sh.lane_heads(cfg) and not sh._state_kernel(cfg), off
     assert not sh._state_kernel(dataclasses.replace(wide, ssm_head_dim=12))
     assert not sh._state_kernel(dataclasses.replace(
         wide, state_dtype=jnp.bfloat16))
@@ -320,6 +494,7 @@ def test_the_choice_is_made_from_what_the_program_can_see(monkeypatch):
 
     assert held(nano) == 0                   # off the tile: _ssm_step
     assert held(wide) == wide.n_layer        # the recurrence's, a layer
+    assert held(tile) == tile.n_layer        # the N-major entry's
     monkeypatch.undo()
     assert held(nano) == 2 * nano.n_layer    # interpreted: both kernels
 
