@@ -197,7 +197,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -231,6 +231,119 @@ _STEPS = ("prefill.key", "prefill.dispatch", "prefill.read",
 #: CPU time (``stats()["driver_cpu_ns_<phase>"]``) is the thread waiting
 #: for the interpreter lock or a processor, not for the device.
 _HOST_PHASES = ("admit", "cover", "deliver", "other")
+#: How many launch records the engine keeps (``launch_log()``): a 40 s
+#: window of 54 ms launches and its drain; many times the window a
+#: stall is judged by.
+LAUNCH_RING = 2048
+#: A launch is a STALL where its period (``gap`` + ``phase``: from the
+#: previous launch's read to its own) outside what its prompts usually
+#: take exceeds this many times the median of that among the records
+#: LIKE it (``_stall``) of the ``LAUNCH_STALL_WINDOW`` before it. A
+#: launch period moves by 1.6x with the host's work and a collection in
+#: its gap, a standstill by 5-300x; until half the window is like it
+#: (a driver run's first launches, a ramp) a launch classifies nothing.
+LAUNCH_STALL_FACTOR = 4
+LAUNCH_STALL_WINDOW = 32
+#: One launch record (``launch_log()``, the ``engine.dispatch`` and
+#: ``engine.stall`` events): ints, every time ``time.monotonic_ns()``.
+#: ``t0``: the ``decode`` phase's start, ``phase`` its length. ``gap``:
+#: since the previous launch's tokens were read, if a lane stayed
+#: occupied (else 0), and what the ``prefill``, ``idle`` and ``deliver``
+#: phases took of it (the previous launch's state pass and whatever was
+#: handed over with no launch to ride behind); the rest of it is the
+#: host's (admission, coverage, the loop). ``prompts`` /
+#: ``prefill_launches``: admitted since the previous record closed. The
+#: four counted steps of THIS launch. ``deliver``: the state pass after
+#: it, where the record closes. ``hold``: how long the messages its
+#: ``flush`` handed over had been kept (0: nothing was kept). ``gc`` /
+#: ``cpu_proc``: collections of the process and CPU time of all its
+#: threads over ``wall``, which runs from the previous record's close
+#: (with no ``gap``: from ``t0``) to this one's, so that the records of
+#: a busy engine tile its time. ``lanes`` live; ``kind`` chunk (0) or
+#: verify (1).
+LAUNCH_FIELDS = ("t0", "phase", "gap", "gap_prefill", "gap_idle",
+                 "gap_deliver", "prompts", "prefill_launches", "enqueue",
+                 "flush", "wait", "read", "deliver", "hold", "gc",
+                 "cpu_proc", "wall", "lanes", "kind")
+_LAUNCH_KINDS = ("chunk", "verify")
+_F = {name: i for i, name in enumerate(LAUNCH_FIELDS)}
+_PHASE, _GAP, _DELIVER = _F["phase"], _F["gap"], _F["deliver"]
+_GAP_PREFILL, _PROMPTS, _LANES = _F["gap_prefill"], _F["prompts"], _F["lanes"]
+#: Where a stalled launch's excess fell
+#: (``stats()["launch_stall_ns_<part>"]``): the parts of its period.
+_STALL_PARTS = ("gap_prefill", "gap_host", "gap_idle", "enqueue", "flush",
+                "wait", "read", "deliver")
+
+
+class _Tables(NamedTuple):
+    """The driver's tables at one moment (``at``, monotonic ns): what a
+    launch record's differences are taken from. The phases ``prefill``,
+    ``idle`` and ``deliver``; the four steps of ``decode``; the prompts
+    and prefill launches admitted; the process's collections and its
+    CPU time."""
+    at: int
+    prefill: int
+    idle: int
+    deliver: int
+    enqueue: int
+    flush: int
+    wait: int
+    read: int
+    prompts: int
+    prefill_launches: int
+    gc: int
+    cpu: int
+
+
+def _launch_dict(rec: tuple) -> dict:
+    out = dict(zip(LAUNCH_FIELDS, rec))
+    out["kind"] = _LAUNCH_KINDS[out["kind"]]
+    out["period"] = out["gap"] + out["phase"]
+    return out
+
+
+def _stall_parts(rec: tuple) -> tuple:
+    """A record's period by ``_STALL_PARTS``: the gap's prefills, its
+    host time outside the state pass, its idle time, the launch's four
+    steps, and the state pass that lay in the gap."""
+    g = rec[_GAP]
+    pre, idle, dl = (rec[_GAP_PREFILL], rec[_F["gap_idle"]],
+                     rec[_F["gap_deliver"]])
+    return (pre, g - pre - idle - dl, idle, rec[_F["enqueue"]],
+            rec[_F["flush"]], rec[_F["wait"]], rec[_F["read"]], dl)
+
+
+def _stall(rec: tuple, recent) -> Optional[Tuple[int, int, tuple]]:
+    """Whether the launch ``rec`` stalled, judged by ``recent``, the
+    records of its driver run before it (``LAUNCH_STALL_WINDOW`` at
+    most): None, or its excess, the usual period it is measured from,
+    and the excess by ``_STALL_PARTS``. Two things that are work and no
+    standstill are kept out. A launch is judged only by records LIKE
+    it, of half to twice its lanes (a full engine's launch is not
+    stalled for being longer than a lone lane's), and only where they
+    are half the window or more. And the prefills in its gap count only
+    for what they took beyond their prompts' due, the median time a
+    prompt among those records (no prompt among them: not judged), so a
+    burst of admissions in one gap counts as none and a prefill that
+    stood still does. The usual period is the median, among the like,
+    of the period outside prefills."""
+    lanes = rec[_LANES]
+    like = [r for r in recent
+            if r[_LANES] <= 2 * lanes and lanes <= 2 * r[_LANES]]
+    if 2 * len(like) < LAUNCH_STALL_WINDOW:
+        return None
+    mid, pre = len(like) // 2, rec[_GAP_PREFILL]
+    each = sorted(r[_GAP_PREFILL] // r[_PROMPTS] for r in like
+                  if r[_PROMPTS]) if pre else ()
+    over = max(pre - rec[_PROMPTS] * each[len(each) // 2], 0) if each else 0
+    usual = sorted(r[_GAP] - r[_GAP_PREFILL] + r[_PHASE] for r in like)[mid]
+    judged = rec[_GAP] - pre + rec[_PHASE] + over
+    if judged <= LAUNCH_STALL_FACTOR * usual:
+        return None
+    usual_parts = (sorted(col)[mid] for col in zip(*map(_stall_parts, like)))
+    parts = [max(v - u, 0) for v, u in zip(_stall_parts(rec), usual_parts)]
+    parts[0] = over
+    return judged - usual, usual, tuple(parts)
 
 
 def default_prompt_buckets(max_len: int) -> List[int]:
@@ -730,6 +843,9 @@ class DecodeEngine:
                        # delivery, and those of them handed over with
                        # a device program in flight
                        "deliver_puts": 0, "deliver_puts_overlapped": 0,
+                       # and how long each had been kept by then,
+                       # summed (ns, over `deliver_puts`)
+                       "deliver_hold_ns_sum": 0,
                        # request lifecycle, summed where it happens
                        # (monotonic ns): queued -> slot granted over
                        # `admitted`; slot granted -> first token on the
@@ -744,7 +860,18 @@ class DecodeEngine:
                        # and the part of it spent in prefill phases
                        "admission_wait_ns_sum": 0, "prefill_ns_sum": 0,
                        "prefill_tokens_sum": 0, "decode_gap_ns_sum": 0,
-                       "decode_gap_prefill_ns_sum": 0}
+                       "decode_gap_prefill_ns_sum": 0,
+                       # the stalls among the launch records,
+                       # classified as each closed: their excess over
+                       # the usual period, the wall their `gc` and
+                       # `cpu_proc` cover, and those two
+                       "launch_stalls": 0, "launch_stall_ns_sum": 0,
+                       "launch_stall_wall_ns_sum": 0,
+                       "launch_stall_gc_ns_sum": 0,
+                       "launch_stall_cpu_ns_sum": 0}
+        # the excess by where it fell: each part less its usual
+        self._stats.update(dict.fromkeys(
+            (f"launch_stall_ns_{p}" for p in _STALL_PARTS), 0))
         # Counters the model's chunk program returns with its tokens
         # (an expert layer's load), summed per dispatch.
         self._stats.update(dict.fromkeys(model.STEP_COUNTERS, 0))
@@ -759,10 +886,23 @@ class DecodeEngine:
         self._phases: Optional[tracing.PhaseClock] = None
         # monotonic ns at which the last decode/verify dispatch's tokens
         # were read, while a lane has stayed occupied since; else None.
-        # Beside it what the prefill phases had taken by then.
         self._decode_read_ns: Optional[int] = None
-        self._decode_read_prefill_ns = 0
         self._compiles = tracing.compile_counts()
+        self._gc = tracing.gc_counts()
+        # One record a decode/verify launch (LAUNCH_FIELDS), appended by
+        # the driver as the launch's state pass ends. Beside it what the
+        # driver carries from one launch to the next: the tables as the
+        # last record closed, what _note_decode_gap left at this
+        # launch's start, this driver run's last records (a stall is
+        # judged by them: _stall), and the read stamp of the launch
+        # whose messages are kept.
+        self._launches: "collections.deque[tuple]" = collections.deque(
+            maxlen=LAUNCH_RING)
+        self._launch_closed = self._tables(0)
+        self._launch_open: tuple = ()
+        self._recent: "collections.deque[tuple]" = collections.deque(
+            maxlen=LAUNCH_STALL_WINDOW)
+        self._kept_read_ns = 0
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # ---- driver supervision (ISSUE 7): the driver stamps _beat at
@@ -1705,6 +1845,10 @@ class DecodeEngine:
                     for ph, ns in self._driver_ns.items()})
         out["compiles"] = self._compiles["n"]
         out["compile_ns"] = self._compiles["ns"]
+        out["gc_pauses"] = self._gc["n"]
+        out["gc_pause_ns_sum"] = self._gc["ns"]
+        out["gc2_pauses"] = self._gc["n2"]
+        out["gc2_pause_ns_sum"] = self._gc["ns2"]
         out["paged"] = True     # one pool; _controller.py folds the key
         out["deployment"] = self.deployment
         out["tp"] = self.tp
@@ -1784,6 +1928,22 @@ class DecodeEngine:
             for k, v in deltas.items():
                 self._stats[k] += v
 
+    def launch_log(self, since_ns: int = 0) -> List[dict]:
+        """The last ``LAUNCH_RING`` decode and verify launches, oldest
+        first, each a dict of ``LAUNCH_FIELDS`` (``kind`` by name) with
+        its ``period``; ``since_ns``: those whose ``t0`` is later. The
+        stamps are ``time.monotonic_ns()``: the clock of the driver's
+        spans (``mono_ns``), of the flight recorder's ``mono`` and,
+        through ``Tracer.handoff``'s ``sync_host_ns``, of a
+        ``jax.profiler`` trace. Not part of ``stats()``."""
+        while True:
+            try:
+                recs = list(self._launches)
+                break
+            except RuntimeError:    # the driver appended meanwhile
+                continue
+        return [_launch_dict(r) for r in recs if r[0] > since_ns]
+
     # ---------------------------------------------------------- driver loop
     # THE driver loop: everything it calls below dispatches against
     # pool structures only this thread (or a supervisor that already
@@ -1800,6 +1960,7 @@ class DecodeEngine:
             "engine", self._driver_ns, epoch=epoch,
             deployment=self.deployment)
         self._decode_read_ns = None
+        self._recent.clear()
         try:
             while not stop.is_set():
                 # The whole iteration is phase "other"; what it opens
@@ -1918,7 +2079,8 @@ class DecodeEngine:
                 return
             req.lane.q.put(("err", exc))
 
-    def _flush_kept(self, in_flight: bool, epoch: int = -1):
+    def _flush_kept(self, in_flight: bool, epoch: int = -1,
+                    now_ns: int = 0) -> int:
         """Hand the last launch's kept messages to their lanes, in the
         order the state pass kept them. ``in_flight``: the next decode
         (or verify) program has just been enqueued and the driver is
@@ -1929,10 +2091,14 @@ class DecodeEngine:
         work at 256 lanes, and what spills contends with the driver
         before the next launch (``PERF.md`` section 6, PR 43: both
         rules measured). A stale driver (``epoch`` moved on) hands
-        nothing over: the supervisor already did, before its error."""
+        nothing over: the supervisor already did, before its error.
+        Returns how long the messages had been kept: ``now_ns`` (the
+        caller's stamp as it starts; absent, one is taken) less the read
+        stamp of the launch that made them; 0 where nothing was."""
         kept = self._kept
         if not kept or (epoch >= 0 and epoch != self._epoch):
-            return
+            return 0
+        hold = max((now_ns or time.monotonic_ns()) - self._kept_read_ns, 0)
         n = 0
         with self._kept_lock:
             while kept:
@@ -1940,15 +2106,18 @@ class DecodeEngine:
                 lane.q.put(msg)
                 n += 1
         self._count(deliver_puts=n,
-                    deliver_puts_overlapped=n if in_flight else 0)
+                    deliver_puts_overlapped=n if in_flight else 0,
+                    deliver_hold_ns_sum=hold * n)
+        return hold if n else 0
 
     def _flush_now(self, epoch: int):  # rtlint: owner=driver
         """Nothing will be enqueued for the kept messages to ride
         behind (no lane left or none runnable, the loop going idle):
         hand them over at once, as delivery the device does not hide."""
         if self._kept:
-            with self._phases.phase("deliver"):
-                self._flush_kept(in_flight=False, epoch=epoch)
+            with self._phases.phase("deliver") as dl:
+                self._flush_kept(in_flight=False, epoch=epoch,
+                                 now_ns=dl.t0)
 
     # Ownership transfers to the failing thread only once the driver is
     # confirmed dead — see _fail_all's free_state contract.
@@ -2654,8 +2823,9 @@ class DecodeEngine:
             # The previous launch's tokens reach their lanes HERE: the
             # consumers they wake take the interpreter lock while this
             # thread is blocked in the wait below.
-            with clock.step("flush"):
-                self._flush_kept(in_flight=True, epoch=epoch)
+            with clock.step("flush") as fl:
+                hold = self._flush_kept(in_flight=True, epoch=epoch,
+                                        now_ns=fl.t0)
             # The wait and the reads stay in this frame, as the
             # prefill's do: profilers that label the driver by function
             # see the device's part here, told from the host's by step.
@@ -2674,7 +2844,7 @@ class DecodeEngine:
                     if more else []
         if epoch >= 0 and epoch != self._epoch:
             return                    # stale driver: drop on the floor
-        with self._phases.phase("deliver", slots_active=n_active):
+        with self._phases.phase("deliver", slots_active=n_active) as dl:
             self._cache = cache
             sm = serve_metrics()
             sm["engine_slot_occupancy"].observe(
@@ -2684,11 +2854,6 @@ class DecodeEngine:
                 labels={"deployment": self.deployment})
             self._count(dispatches=1, occupancy_sum=n_active / self.slots,
                         **dict(zip(self._model.STEP_COUNTERS, counted)))
-            # Rate-capped: under a dispatch-per-token storm the cap drops
-            # the excess (counted) instead of flooding the ring.
-            _driver_emit("engine.dispatch", epoch=self._epoch,
-                         active=n_active, chunk=self.chunk,
-                         dispatch_s=round((ph.t1 - ph.t0) / 1e9, 6))
             if self.tp > 1:
                 # Post-mortem breadcrumb for sharded dispatch: which mesh
                 # shape ran which compiled program. Same rate cap as
@@ -2706,6 +2871,8 @@ class DecodeEngine:
                 self._stats["peak_active"] = max(self._stats["peak_active"],
                                                  n_active)
             self._advance_lanes(toks_np, rngs_np, None, ph, n_active, sm)
+        self._note_decode_read(ph, dl, hold, n_active, 0,
+                               chunk=self.chunk)
 
     # rtlint: owner=driver
     def _advance_lanes(self, rows, rngs, acc, ph, n_active: int, sm):
@@ -2722,6 +2889,7 @@ class DecodeEngine:
         advances by ``chunk``."""
         labels = {"deployment": self.deployment}
         keep = self._kept.append
+        self._kept_read_ns = ph.t1      # what is kept below is held from
         emitted = 0
         for i, st in enumerate(self._state):
             if st is None or st.parked:
@@ -2787,30 +2955,88 @@ class DecodeEngine:
             sm["engine_tokens"].inc(emitted, labels=labels)
             self._count(tokens=emitted)
         self._observe_pages(sm)
-        self._note_decode_read(ph.t1)
+
+    def _tables(self, at_ns: int) -> "_Tables":
+        ns, st = self._driver_ns, self._stats
+        return _Tables(
+            at_ns, ns["prefill"], ns["idle"], ns["deliver"],
+            ns["decode.enqueue"], ns["decode.flush"], ns["decode.wait"],
+            ns["decode.read"], st["prefills"], st["prefill_launches"],
+            self._gc["ns"], time.process_time_ns())
 
     # rtlint: owner=driver
-    def _note_decode_read(self, read_ns: int):
-        """After a decode/verify dispatch's delivery: where its tokens
-        were read, if a lane is still occupied, and what the prefill
-        phases have taken so far — the two the next gap is counted
-        from."""
-        self._decode_read_ns = read_ns if any(
-            s is not None for s in self._state) else None
-        self._decode_read_prefill_ns = self._driver_ns["prefill"]
-
-    # rtlint: owner=driver
-    def _note_decode_gap(self, now_ns: int):
-        """At the start of a decode/verify dispatch: the time since the
-        previous one's tokens were read, if a lane stayed occupied all
-        the while — what running lanes lost to whatever came between
-        (delivery, admission, another request's prefill) — and the part
-        of it in which the driver was in a prefill phase."""
+    def _note_decode_gap(self, t0: int):
+        """At the start of a decode/verify dispatch (inside its
+        ``enqueue``, before the table has any of this launch): the time
+        since the previous one's tokens were read, if a lane stayed
+        occupied all the while — what running lanes lost to whatever
+        came between (delivery, admission, another request's prefill) —
+        and the parts of it in which the driver was in a prefill, an
+        idle or a deliver phase (the previous launch's state pass, and
+        what was handed over since with no launch to ride behind). What
+        the record of THIS launch is differenced from is left in
+        ``_launch_open``."""
+        now, last = self._tables(t0), self._launch_closed
         if self._decode_read_ns is not None:
+            gap = (t0 - self._decode_read_ns, now.prefill - last.prefill,
+                   now.idle - last.idle, now.deliver - last.deliver
+                   + self._launches[-1][_DELIVER])
+            self._count(decode_gap_ns_sum=gap[0],
+                        decode_gap_prefill_ns_sum=gap[1])
+            since = last
+        else:
+            # nobody waited: what the record says of the process starts
+            # here, not at the close of a launch before the lull
+            gap, since = (0, 0, 0, 0), now
+        self._launch_open = (now, since, gap + (
+            now.prompts - last.prompts,
+            now.prefill_launches - last.prefill_launches))
+
+    # rtlint: owner=driver
+    def _note_decode_read(self, ph, dl, hold: int, lanes: int, kind: int,
+                          **attrs):
+        """A decode/verify launch closes, its state pass (``dl``) just
+        ended: ONE record to the ring (``LAUNCH_FIELDS``), every field a
+        stamp the phases took or a difference of the tables; a stall
+        classified and counted where it fell; the launch's
+        flight-recorder event with the record's fields (``attrs``
+        beside them); and where the launch's tokens were read, if a
+        lane is still occupied: what the next gap is counted from."""
+        opened, since, head = self._launch_open
+        now = self._launch_closed = self._tables(dl.t1)
+        rec = (ph.t0, ph.t1 - ph.t0) + head + (
+            now.enqueue - opened.enqueue, now.flush - opened.flush,
+            now.wait - opened.wait, now.read - opened.read,
+            now.deliver - opened.deliver, hold, now.gc - since.gc,
+            now.cpu - since.cpu, now.at - since.at, lanes, kind)
+        self._decode_read_ns = ph.t1 if any(
+            s is not None for s in self._state) else None
+        stall = _stall(rec, self._recent)
+        self._launches.append(rec)
+        self._recent.append(rec)
+        if stall is not None:
+            excess, usual, parts = stall
+            stall = {"excess": excess, "median": usual}
             self._count(
-                decode_gap_ns_sum=now_ns - self._decode_read_ns,
-                decode_gap_prefill_ns_sum=self._driver_ns["prefill"]
-                - self._decode_read_prefill_ns)
+                launch_stalls=1, launch_stall_ns_sum=excess,
+                launch_stall_wall_ns_sum=rec[_F["wall"]],
+                launch_stall_gc_ns_sum=rec[_F["gc"]],
+                launch_stall_cpu_ns_sum=rec[_F["cpu_proc"]],
+                **{f"launch_stall_ns_{p}": v
+                   for p, v in zip(_STALL_PARTS, parts)})
+        if stall is not None or _events.enabled():
+            fields = _launch_dict(rec)
+            fields["launch"] = fields.pop("kind")   # an event's own word
+            # Rate-capped: under a dispatch-per-token storm the cap drops
+            # the excess (counted) instead of flooding the ring.
+            _driver_emit("engine.dispatch", epoch=self._epoch, **attrs,
+                         **fields)
+            if stall is not None:
+                # what an operator searches a timeline for
+                _driver_emit("engine.stall", epoch=self._epoch, **stall,
+                             **fields)
+                self._phases.span("stall", ph.t0 - rec[_GAP], ph.t1,
+                                  {**stall, **fields})
 
     def _dispatch_spec(self, epoch: int = -1):  # rtlint: owner=driver
         """Draft-k-verify-once twin of :meth:`_dispatch_chunk`
@@ -2870,8 +3096,9 @@ class DecodeEngine:
                     self._rngs, active, self._pt)
                 for arr in (committed, n_acc, rngs):
                     arr.copy_to_host_async()
-            with clock.step("flush"):     # as _dispatch_chunk's
-                self._flush_kept(in_flight=True, epoch=epoch)
+            with clock.step("flush") as fl:   # as _dispatch_chunk's
+                hold = self._flush_kept(in_flight=True, epoch=epoch,
+                                        now_ns=fl.t0)
             with clock.step("wait"):
                 # rtlint: sync-ok=verify-boundary the device's part, alone
                 jax.block_until_ready(committed)
@@ -2886,7 +3113,7 @@ class DecodeEngine:
                 rngs_np = np.asarray(rngs)
         if epoch >= 0 and epoch != self._epoch:
             return                    # stale driver: drop on the floor
-        with self._phases.phase("deliver", slots_active=n_active):
+        with self._phases.phase("deliver", slots_active=n_active) as dl:
             self._cache = cache
             sm = serve_metrics()
             labels = {"deployment": self.deployment}
@@ -2901,9 +3128,6 @@ class DecodeEngine:
             self._count(dispatches=1, occupancy_sum=n_active / self.slots,
                         spec_rounds=1, spec_proposed=self.draft_k * n_active,
                         spec_accepted=accepted_total, spec_lanes=n_active)
-            _driver_emit("engine.dispatch", epoch=self._epoch,
-                         active=n_active, spec=True,
-                         accepted=accepted_total)
             if self.tp > 1:
                 _driver_emit("shard.dispatch", epoch=self._epoch,
                              mesh=[("tp", self.tp)],
@@ -2912,3 +3136,5 @@ class DecodeEngine:
                 self._stats["peak_active"] = max(self._stats["peak_active"],
                                                  n_active)
             self._advance_lanes(com_np, rngs_np, acc_np, ph, n_active, sm)
+        self._note_decode_read(ph, dl, hold, n_active, 1,
+                               accepted=accepted_total)

@@ -312,6 +312,44 @@ def compile_counts() -> Dict[str, int]:
         return _compiles
 
 
+_gc: Optional[Dict[str, int]] = None
+_gc_lock = threading.Lock()
+
+
+def gc_counts() -> Dict[str, int]:
+    """``{"n", "ns", "n2", "ns2", "open_ns"}``: garbage collections of
+    this process and the time they took (every generation; generation 2
+    alone), and the ``time.monotonic_ns()`` stamp at which the one in
+    flight started (0: none is), counted by one ``gc.callbacks``
+    listener (registered on the first call). A collection holds the
+    interpreter lock whichever thread it runs on, so its time is a
+    pause of every thread that needs the lock. The table is live. The
+    listener runs on every generation-0 collection of every thread:
+    two stamps and two additions, nothing else."""
+    global _gc
+    with _gc_lock:
+        if _gc is None:
+            import gc
+
+            table = {"n": 0, "ns": 0, "n2": 0, "ns2": 0, "open_ns": 0}
+
+            def on_gc(phase: str, info: dict):
+                if phase == "start":
+                    table["open_ns"] = time.monotonic_ns()
+                elif table["open_ns"]:
+                    ns = time.monotonic_ns() - table["open_ns"]
+                    table["open_ns"] = 0
+                    table["n"] += 1
+                    table["ns"] += ns
+                    if info["generation"] == 2:
+                        table["n2"] += 1
+                        table["ns2"] += ns
+
+            gc.callbacks.append(on_gc)
+            _gc = table
+        return _gc
+
+
 class PhaseClock:
     """What one driver thread (the serving engine's) is doing, phase by
     phase. The thread is at every moment in exactly one phase; phases
@@ -344,6 +382,21 @@ class PhaseClock:
 
     One clock per driver run: the table outlives it (the engine's), the
     stack of open phases does not.
+
+    ONE time base. Every stamp is ``time.monotonic_ns()``:
+    CLOCK_MONOTONIC, one for all processes of a machine. The spans'
+    ``mono_ns``, the engine's launch records
+    (``DecodeEngine.launch_log()``: ``t0``, and ``t0 - gap`` .. ``t0 +
+    phase``), the flight recorder's ``mono`` (the same clock in
+    seconds) and the stamp a tracing process takes inside its sync
+    annotation (``benchmarks/perf``: ``Tracer.handoff()``'s
+    ``sync_host_ns``) are on it. A ``jax.profiler`` trace has a clock
+    of its own; ``offset = <the sync annotation's start in the trace> -
+    sync_host_ns`` maps one onto the other, so a record's launch lies at
+    ``t0 + offset`` in the trace, beside this clock's
+    ``<prefix>.<phase>.<step>`` annotations in the host plane and the
+    device's operations: a reducer joins launches to device gaps by
+    interval and needs no sampler.
     """
 
     def __init__(self, prefix: str, table: Dict[str, int], **attrs):
@@ -373,6 +426,15 @@ class PhaseClock:
         ``table["<phase>.<name>"]``. Steps do not nest, and no phase
         opens inside one."""
         return _Step(self, name, args)
+
+    def span(self, name: str, t0: int, t1: int, args: dict) -> None:
+        """One span of this clock's trace that is no phase (a stall the
+        driver found in its own launches), from two monotonic stamps it
+        already holds; nothing when not :func:`enabled`."""
+        if _enabled:
+            _record(f"{self.prefix}.{name}", "driver", self.trace_id,
+                    _new_id(8), None, wall_of(t0), wall_of(t1),
+                    {**self.attrs, **args}, mono_ns=(t0, t1))
 
 
 class _Step:

@@ -4,7 +4,9 @@ it happens, compiles counted, and spans on the monotonic clock. And a
 decode launch accounts for itself (ISSUE 42): counted steps, the
 thread's CPU time beside the wall's, the prefills inside a gap. Since
 ISSUE 43 a launch has a fourth step, ``flush``: the previous launch's
-tokens handed to their lanes behind the enqueue.
+tokens handed to their lanes behind the enqueue. Since ISSUE 57 a launch
+keeps its own record: a ring of per-launch records, stalls classified
+as each closes, collections stamped.
 
 CPU, ``nano``: these are counts, names and orderings, never a speed.
 """
@@ -165,7 +167,13 @@ _STATS_READ = (
     "driver_ns_admit", "driver_ns_cover", "driver_ns_decode_enqueue",
     "driver_ns_decode_read", "driver_ns_deliver", "driver_ns_other",
     "driver_ns_prefill_dispatch", "driver_ns_prefill_key",
-    "driver_ns_prefill_read")
+    "driver_ns_prefill_read",
+    # what PRs 43 and 54 counted (ISSUE 57 lists their readers):
+    # layer_metrics/deliver_overlap_pct, prompts_per_prefill_launch
+    "deliver_puts", "deliver_puts_overlapped", "prefill_launches",
+    # the launch's own record (ISSUE 57): layer_metrics/launch_stall_s,
+    # gc_pause_ms_per_s, deliver_hold_mean_ms
+    "launch_stall_ns_sum", "gc_pause_ns_sum", "deliver_hold_ns_sum")
 
 
 @pytest.mark.parametrize("consumer", ["program_names", "program_readers",
@@ -747,3 +755,424 @@ def test_a_driver_death_behind_the_state_pass_loses_no_token(make, nano,
     for c in eng.stream(prompt, 30, resume_from=len(got)):
         got += [int(t) for t in c]
     assert got == ref
+
+
+# --------------------------------- a launch keeps its own record (57)
+def _quiet(eng):
+    """The engine at rest (every phase closed, the last record in the
+    ring), its counters and the stamp they were taken at."""
+    time.sleep(0.12)
+    return eng.stats(), time.monotonic_ns()
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_launch_keeps_its_own_record(make, nano, kind):
+    """One record a decode or verify launch, every field an int; its
+    four steps lie inside its phase and the gap's parts inside the gap;
+    over any run the records sum to the table's counters over the same
+    launches."""
+    from ray_tpu.serve.engine import LAUNCH_FIELDS
+
+    eng = make(kind)
+    list(eng.stream(_prompt(nano, 5), 6))       # compiled
+    a, since = _quiet(eng)
+    _run_staggered(eng, nano, 12)
+    b, until = _quiet(eng)
+    d = _delta(a, b)
+    log = eng.launch_log(since)
+    assert len(log) == d["dispatches"] >= 6
+    assert len(eng.launch_log()) > len(log)     # the compiling launch
+    for r in log:
+        assert set(r) == set(LAUNCH_FIELDS) | {"period"}
+        assert all(isinstance(r[f], int) for f in LAUNCH_FIELDS
+                   if f != "kind")
+        assert r["kind"] == ("verify" if kind == "spec" else "chunk")
+        assert 0 < r["enqueue"] + r["flush"] + r["wait"] + r["read"] \
+            <= r["phase"]
+        assert 0 <= r["gap_prefill"] + r["gap_idle"] + r["gap_deliver"] \
+            <= r["gap"]
+        assert r["period"] == r["gap"] + r["phase"]
+        assert 1 <= r["lanes"] <= eng.slots
+        assert 0 < r["deliver"] < r["wall"] and 0 < r["cpu_proc"]
+        assert r["gc"] >= 0 and r["hold"] >= 0
+        if not r["gap"]:        # nobody waited: the launch alone
+            assert r["wall"] <= r["phase"] + r["deliver"] + 1_000_000
+    assert [r["t0"] for r in log] == sorted(r["t0"] for r in log)
+    total = {f: sum(r[f] for r in log) for f in LAUNCH_FIELDS
+             if f != "kind"}
+    assert total["gap"] == d["decode_gap_ns_sum"] > 0
+    assert total["gap_prefill"] == d["decode_gap_prefill_ns_sum"] > 0
+    assert total["gap_idle"] == 0       # a lane waited: never idle
+    assert total["phase"] == d["driver_ns_decode"]
+    for step in DECODE_STEPS:
+        assert total[step] == d[f"driver_ns_decode_{step}"], step
+    # the state passes, less what was handed over with no launch to
+    # ride behind (a deliver phase of its own, inside a gap or not)
+    assert 0 < total["deliver"] <= d["driver_ns_deliver"]
+    assert total["prompts"] == d["prefills"] == 12
+    assert total["prefill_launches"] == d["prefill_launches"]
+    assert sum(r["period"] for r in log) == \
+        d["decode_gap_ns_sum"] + d["driver_ns_decode"]
+    # the walls (what ``gc`` and ``cpu_proc`` cover) hold the launch and
+    # its state pass, and no two overlap
+    assert all(r["phase"] + r["deliver"] <= r["wall"] for r in log)
+    assert sum(r["wall"] for r in log) <= until - since
+
+
+def test_the_ring_is_bounded_and_the_log_filters(make, nano, monkeypatch):
+    from ray_tpu.serve import engine as E
+
+    monkeypatch.setattr(E, "LAUNCH_RING", 8)
+    eng = make("paged")
+    list(eng.stream(_prompt(nano, 5), 56))      # 14 launches
+    time.sleep(0.12)
+    assert eng.stats()["dispatches"] >= 14
+    log = eng.launch_log()
+    assert len(log) == 8
+    assert eng.launch_log(log[2]["t0"]) == log[3:]
+    assert eng.launch_log(log[-1]["t0"]) == []
+    assert "launches" not in eng.stats() and not [
+        v for v in eng.stats().values() if isinstance(v, list)]
+
+
+def test_the_log_is_read_while_the_driver_appends(make, nano, monkeypatch):
+    """Readers on other threads (a health pass, a builder's script)
+    copy the ring while the driver appends to it: every copy is whole
+    records in order, none raises."""
+    import sys
+
+    from ray_tpu.serve import engine as E
+
+    monkeypatch.setattr(E, "LAUNCH_RING", 64)   # a ring that turns over
+    eng = make("paged")
+    list(eng.stream(_prompt(nano, 5), 6))
+    stop, seen, errors = threading.Event(), [0], []
+
+    def reader():
+        while not stop.is_set():
+            try:
+                log = eng.launch_log()
+                assert len(log) <= 64
+                assert [r["t0"] for r in log] == sorted(
+                    r["t0"] for r in log)
+                assert all(r["period"] == r["gap"] + r["phase"]
+                           for r in log)
+                seen[0] += 1
+            except Exception as e:  # noqa: BLE001 - reported below
+                errors.append(e)
+                return
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        readers = [threading.Thread(target=reader) for _ in range(4)]
+        for t in readers:
+            t.start()
+        _run_all(eng, nano, 16, max_new=40)
+        stop.set()
+        for t in readers:
+            t.join(10.0)
+    finally:
+        sys.setswitchinterval(interval)
+        stop.set()
+    assert not errors and seen[0] > 0
+    assert not [t for t in readers if t.is_alive()]
+    st = eng.stats()
+    assert st["driver_restarts"] == 0 and st["completed"] == 17
+    assert st["dispatches"] > 64 == len(eng.launch_log())
+
+
+def _long_lane(eng, nano, seed, on_launch):
+    """Three lone lanes of 100 tokens, one after the other (75 and more
+    launches; a lane's first has no gap); the launch's program wrapped
+    to call ``on_launch(n)`` first, ``n`` counted from the wrap.
+    Returns the counters' delta and the records."""
+    name = "_verify" if eng._drafter is not None else "_step"
+    inner, n = getattr(eng, name), [0]
+
+    def program(*args):
+        n[0] += 1
+        on_launch(n[0])
+        return inner(*args)
+
+    a, since = _quiet(eng)
+    setattr(eng, name, program)
+    try:
+        for i in range(3):
+            list(eng.stream(_prompt(nano, 5, 3 * seed + i), 100))
+    finally:
+        setattr(eng, name, inner)
+    b, _ = _quiet(eng)
+    return _delta(a, b), eng.launch_log(since)
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_slowed_launch_is_one_stall_in_the_part_that_was_slowed(
+        make, nano, kind, spans_on, tmp_path):
+    """After 59 quiet launches one launch's program sleeps 20 median
+    periods inside its enqueue: ONE stall, its excess in ``_enqueue``
+    and nowhere else within 10%; the stall's event and span carry the
+    record. (Another worker of the suite can stall a quiet launch of
+    its own: such a window is run again, twice at most.)"""
+    from ray_tpu._private import events as ev
+    from ray_tpu.serve.engine import (LAUNCH_FIELDS, LAUNCH_STALL_FACTOR,
+                                      _STALL_PARTS)
+
+    ev._reset_for_tests()
+    try:
+        ev.init(str(tmp_path), proc="stall-test")
+        eng = make(kind, max_len=128)
+        list(eng.stream(_prompt(nano, 5), 6))   # compiled
+        # 10 ms a loop: a steady period, far above the machine's jitter
+        eng.inject_fault("driver_slow", wedge_s=0.01)
+        slept = []
+
+        def on_launch(n):
+            if n == 60:
+                periods = sorted(r["period"]
+                                 for r in eng.launch_log()[-32:])
+                slept.append(20 * periods[16])
+                time.sleep(slept[-1] / 1e9)
+
+        for attempt in range(3):
+            del slept[:]
+            tracing.drain()
+            d, log = _long_lane(eng, nano, attempt, on_launch)
+            if d["launch_stalls"] == 1:
+                break
+        assert d["launch_stalls"] == 1 and len(log) >= 75
+        excess = d["launch_stall_ns_sum"]
+        assert excess == pytest.approx(slept[0], rel=0.1)
+        assert d["launch_stall_ns_enqueue"] == pytest.approx(excess,
+                                                             rel=0.1)
+        for part in _STALL_PARTS:
+            if part != "enqueue":
+                assert d[f"launch_stall_ns_{part}"] <= 0.1 * excess, part
+        slow = max(log, key=lambda r: r["period"])
+        assert slow == log[59]
+        assert slow["period"] > LAUNCH_STALL_FACTOR * (slept[0] // 20)
+        assert d["launch_stall_wall_ns_sum"] == slow["wall"]
+        assert d["launch_stall_cpu_ns_sum"] == slow["cpu_proc"]
+        assert d["launch_stall_gc_ns_sum"] == slow["gc"]
+        # asleep: the replica was off the processor through the stall
+        assert slow["cpu_proc"] < 0.5 * slow["wall"]
+
+        # the flight recorder: the launch's event carries its record,
+        # and the stall has one of its own
+        rec = ev.recorder()
+        rec.flush()
+        events = ev.read_ring(rec.path)["events"]
+        sent = [e["attrs"] for e in events
+                if e["kind"] == "engine.dispatch"]
+        keys = (set(LAUNCH_FIELDS) - {"kind"}) | {"launch", "period",
+                                                  "epoch"}
+        assert sent and all(keys <= set(e) for e in sent)
+        assert "dispatch_s" not in sent[-1]
+        assert sent[-1]["launch"] == slow["kind"]
+        stalls = [e["attrs"] for e in events if e["kind"] == "engine.stall"
+                  and e["attrs"]["t0"] == slow["t0"]]
+        assert len(stalls) == 1 and stalls[0]["excess"] == excess
+        assert {k: stalls[0][k] for k in LAUNCH_FIELDS if k != "kind"} \
+            == {k: slow[k] for k in LAUNCH_FIELDS if k != "kind"}
+        # and the span, on the driver's own trace and clock
+        spans = tracing.local_spans()
+        mine = [s for s in spans if s["name"] == "engine.stall"
+                and s["attrs"]["t0"] == slow["t0"]]
+        assert len(mine) == 1 and mine[0]["kind"] == "driver"
+        assert mine[0]["mono_ns"] == [slow["t0"] - slow["gap"],
+                                      slow["t0"] + slow["phase"]]
+        assert mine[0]["trace_id"] in {
+            s["trace_id"] for s in spans if s["name"] == "engine.decode"}
+    finally:
+        ev._reset_for_tests()
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_forced_collection_shows_in_its_launch(make, nano, kind):
+    """``gc.collect()`` inside one launch (automatic collection off, so
+    that it is the window's only one): that record's ``gc``, one
+    generation-2 pause in ``stats()``, and no other record's."""
+    import gc
+
+    eng = make(kind, max_len=128)
+    list(eng.stream(_prompt(nano, 5), 6))
+    gc.collect()
+    gc.disable()
+    try:
+        d, log = _long_lane(eng, nano, 3,
+                            lambda n: n == 12 and gc.collect())
+    finally:
+        gc.enable()
+    assert d["gc2_pauses"] == d["gc_pauses"] == 1
+    assert log[11]["gc"] == d["gc2_pause_ns_sum"] \
+        == d["gc_pause_ns_sum"] > 0
+    assert log[11]["gc"] < log[11]["enqueue"]
+    assert not [r for i, r in enumerate(log) if i != 11 and r["gc"]]
+    assert d["launch_stall_gc_ns_sum"] <= log[11]["gc"]
+
+
+MS = 1_000_000
+
+
+def _rec(lanes, prompts=0, each=30, host=4, wait=140, **more):
+    """A hand-made record (ms): ``prompts`` prefills of ``each`` and
+    ``host`` of the loop's own work in the gap, a state pass of 1, and a
+    launch of 2 + 1 + ``wait`` + 1; ``more``: fields given outright."""
+    from ray_tpu.serve.engine import LAUNCH_FIELDS
+
+    pre = prompts * each
+    r = dict.fromkeys(LAUNCH_FIELDS, 0)
+    r.update(gap=pre + host + 1, gap_prefill=pre, gap_deliver=1,
+             prompts=prompts, prefill_launches=-(-prompts // 2),
+             enqueue=2, flush=1, wait=wait, read=1, phase=wait + 4,
+             deliver=1, lanes=lanes)
+    r.update(more)
+    return tuple(r[f] * (1 if f in ("prompts", "prefill_launches", "lanes",
+                                    "kind") else MS)
+                 for f in LAUNCH_FIELDS)
+
+
+#: a full engine's last 32 launches: a prompt or two in every other gap
+_FULL = [_rec(120 + i % 8, prompts=i % 4 % 3) for i in range(32)]
+_STALL_CASES = {
+    # work, and no standstill: None
+    "a burst of admissions in one gap":
+        (_FULL, _rec(128, prompts=60, host=64), None),
+    "the ramp: one lane, then 127 prompts in one gap":
+        ([_rec(1, wait=20)] * 32,
+         _rec(128, prompts=127, host=130), None),
+    "two launches on, a full collection in the gap":
+        ([_rec(1, wait=20)] * 30 + [_rec(128, prompts=127), _rec(128)],
+         _rec(128, host=190), None),
+    "a long prompt where nobody has seen one":
+        ([_rec(2, wait=50)] * 32, _rec(2, prompts=1, each=600, wait=50),
+         None),
+    "a collection and three long prompts":
+        (_FULL, _rec(128, prompts=3, each=45, host=180), None),
+    # a standstill: the excess, and the part it fell in
+    "2 s in a launch's wait":
+        (_FULL, _rec(128, prompts=1, wait=2140), (2000, "wait")),
+    "1.9 s in a prefill's read":
+        (_FULL, _rec(128, prompts=2, gap_prefill=1960, gap=1965),
+         (1900, "gap_prefill")),
+    "1 s between the phases":
+        (_FULL, _rec(128, host=1004), (1000, "gap_host")),
+    # half the window is like it, and no more is needed
+    "sixteen like it":
+        ([_rec(1, wait=20)] * 16 + _FULL[:16], _rec(128, wait=2140),
+         (2000, "wait")),
+    "fifteen like it":
+        ([_rec(1, wait=20)] * 17 + _FULL[:15], _rec(128, wait=2140), None),
+}
+
+
+@pytest.mark.parametrize("case", list(_STALL_CASES))
+def test_a_stall_is_a_standstill_and_not_work(case):
+    """The rule alone, on hand-made records: a burst of admissions, a
+    ramp and a collection are no stall; a standstill is one wherever it
+    falls, a prefill's read too, with its excess in that part."""
+    from ray_tpu.serve.engine import _STALL_PARTS, _stall
+
+    recent, rec, want = _STALL_CASES[case]
+    found = _stall(rec, recent)
+    if want is None:
+        assert found is None
+        return
+    excess, usual, parts = found
+    by_part = dict(zip(_STALL_PARTS, parts))
+    assert usual == 149 * MS        # 4 + 1 of the gap, 144 of the launch
+    assert excess == pytest.approx(want[0] * MS, rel=0.02)
+    assert by_part.pop(want[1]) == pytest.approx(excess, rel=0.02)
+    assert sum(by_part.values()) <= 0.02 * excess
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_a_burst_of_admissions_counts_no_stall(make, nano, kind):
+    """A lone lane for 34 launches, then seven callers at once: their
+    prefills (20 ms a prompt here) lie in ONE gap, many periods long,
+    and the launches behind it hold eight lanes for the window's one:
+    work, and no stall."""
+    eng = make(kind, slots=8, chunk=2, max_len=128)
+    eng.warm_up()
+    eng.inject_fault("driver_slow", wedge_s=0.01)   # a steady period
+    inner = eng._prefill
+
+    def prefill(params, cache, tokens, *rest):
+        time.sleep(0.02 * (len(tokens) if isinstance(tokens, tuple) else 1))
+        return inner(params, cache, tokens, *rest)
+
+    eng._prefill = prefill
+    lane = eng.stream(_prompt(nano, 5), 120)
+    start = eng.stats()["dispatches"]
+    while eng.stats()["dispatches"] < start + 34:
+        next(lane)
+    a, since = eng.stats(), time.monotonic_ns()
+    _run_all(eng, nano, 7, max_new=8)
+    list(lane)
+    b, _ = _quiet(eng)
+    d, log = _delta(a, b), eng.launch_log(since)
+    assert len(eng._recent) == 32
+    burst = max(log, key=lambda r: r["prompts"])
+    assert burst["prompts"] >= 4 and burst["lanes"] >= 5
+    usual = sorted(r["period"] for r in eng.launch_log()
+                   if r["t0"] < burst["t0"])[-16]
+    assert burst["period"] > 4 * usual
+    assert d["launch_stalls"] == d["launch_stall_ns_sum"] == 0
+
+
+def test_gc_counts_registers_one_listener():
+    import gc
+
+    table = tracing.gc_counts()
+    n = len(gc.callbacks)
+    for _ in range(3):
+        assert tracing.gc_counts() is table
+    assert len(gc.callbacks) == n
+    assert set(table) == {"n", "ns", "n2", "ns2", "open_ns"}
+    before = dict(table)
+    gc.collect()
+    assert table["n2"] == before["n2"] + 1 and table["n"] > before["n"]
+    assert table["ns2"] > before["ns2"] and table["open_ns"] == 0
+    assert table["ns"] - before["ns"] >= table["ns2"] - before["ns2"]
+
+
+@pytest.mark.parametrize("kind", list(KINDS))
+def test_hold_is_what_lay_between_a_read_and_the_next_flush(make, nano,
+                                                            kind):
+    """A lone lane's first launch hands nothing over (``hold`` 0) and
+    every later one what the launch before it kept, held for the gap
+    and the enqueue; a prefill that lay between two launches is inside
+    the hold. Twelve quiet launches classify nothing (a driver run's
+    first 16 never do, whatever the machine did to them): no stall."""
+    eng = make(kind)
+    list(eng.stream(_prompt(nano, 5), 6))
+    a, since = _quiet(eng)
+    eng._recent.clear()         # at rest: as a driver run begins
+    # a verify launch commits one to three tokens
+    list(eng.stream(_prompt(nano, 5, 1), 20 if kind == "spec" else 48))
+    b, _ = _quiet(eng)
+    d, log = _delta(a, b), eng.launch_log(since)
+    assert 9 <= len(log) == len(eng._recent) <= 16
+    assert d["launch_stalls"] == d["launch_stall_ns_sum"] == 0
+    assert log[0]["hold"] == 0 and log[0]["gap"] == 0
+    for r in log[1:]:
+        assert r["hold"] >= r["gap"] + r["enqueue"] > 0
+    # one message a launch rode behind the next; the last launch's
+    # (slice and end) went with no launch to ride behind
+    assert d["deliver_puts_overlapped"] == len(log) - 1
+    assert d["deliver_hold_ns_sum"] > sum(r["hold"] for r in log)
+
+    eng.inject_fault("driver_slow", wedge_s=0.01)   # keep lane A running
+    lane = eng.stream(_prompt(nano, 5, 2), 40)
+    next(lane)
+    a, since = eng.stats(), time.monotonic_ns()
+    other = threading.Thread(
+        target=lambda: list(eng.stream(_prompt(nano, 6, 3), 4)))
+    other.start()
+    list(lane)
+    other.join()
+    time.sleep(0.12)
+    after = [r for r in eng.launch_log(since) if r["prompts"]]
+    assert len(after) == 1 and after[0]["prefill_launches"] == 1
+    assert after[0]["hold"] >= after[0]["gap"] \
+        > after[0]["gap_prefill"] > 0
