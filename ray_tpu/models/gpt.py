@@ -253,6 +253,40 @@ def _attention(q, k, v, cfg: GPTConfig, mesh=None):
     return _attention_xla(q, k, v, cfg)
 
 
+# How a block COMPUTES with its matrices: column matrices split their
+# output over tp, row matrices their input (``_attention``: heads over
+# tp), and nothing over fsdp, which shards the batch. Where a matrix is
+# STORED is ``parallel/sharding.py LM_RULES``' to say.
+_COLUMN, _ROW = ("wq", "wk", "wv", "w1"), ("wo", "w2")
+
+
+def _gather_layer(layer_params, cfg: GPTConfig, mesh):
+    """ZeRO-3's all-gather, asked for by name: one layer's matrices cast
+    to ``cfg.dtype`` and then constrained to their compute layout, which
+    is replicated over ``fsdp``. Left to itself the partitioner keeps a
+    weight that is sharded over the batch's own axis where it lies and
+    all-gathers / all-reduces the whole batch's ACTIVATIONS in every
+    layer instead. The cast comes first, so the gather moves
+    ``cfg.dtype`` bytes and not the float32 masters'. Called INSIDE the
+    function ``jax.checkpoint`` wraps: the gathered copy is no residual
+    of the scan, backward gathers the layer again, and the constraint's
+    transpose sums the layer's gradient over the chips there (on a TPU
+    a reduce-scatter). A no-op on a mesh without an ``fsdp`` axis."""
+    if mesh is None or "fsdp" not in mesh.axis_names:
+        return layer_params
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    tp = "tp" if "tp" in mesh.axis_names else None
+    out = dict(layer_params)
+    for names, spec in ((_COLUMN, P(None, tp)), (_ROW, P(tp, None))):
+        for name in names:
+            if name in out:
+                w = out[name]["kernel"].astype(cfg.dtype)
+                out[name] = {"kernel": lax.with_sharding_constraint(
+                    w, NamedSharding(mesh, spec))}
+    return out
+
+
 def _block(x, layer_params, cfg: GPTConfig, mesh=None):
     """One transformer block → (x, aux_loss).
 
@@ -261,7 +295,7 @@ def _block(x, layer_params, cfg: GPTConfig, mesh=None):
     """
     B, S, d = x.shape
     H, hd = cfg.n_head, cfg.head_dim
-    p = layer_params
+    p = _gather_layer(layer_params, cfg, mesh)
     h = _rmsnorm(x, p["ln1_scale"])
     q = _mm(h, p["wq"]["kernel"], cfg.dtype).reshape(B, S, H, hd)
     k = _mm(h, p["wk"]["kernel"], cfg.dtype).reshape(B, S, H, hd)
@@ -522,11 +556,22 @@ def make_train_step(cfg: GPTConfig, mesh, optimizer=None, *,
                     rules=None, donate: bool = True):
     """Build (init_fn, step_fn) jitted over ``mesh``.
 
-    The sharding plan (GSPMD) comes from ``rules``
-    (default :data:`ray_tpu.parallel.sharding.LM_RULES`): fsdp/tp sharded
-    params, dp×fsdp sharded batch. XLA inserts all collectives — this is
-    the TPU-native replacement for torch DDP/FSDP wrapping
-    (reference ``train_loop_utils.py:158,175``).
+    Where the state is STORED comes from ``rules`` (default
+    :data:`ray_tpu.parallel.sharding.LM_RULES`): the batch over
+    dp×fsdp; a block's matrices, stacked ``[L, in, out]`` for the layer
+    scan, over fsdp and tp on their OWN two dimensions and never on
+    ``L``, so every chip holds its part of every layer; Adam's moments
+    like their parameters. The partitioner places the collectives, with
+    one asked for by name: on an ``fsdp`` axis each layer's matrices
+    are cast to ``cfg.dtype`` and ALL-GATHERED inside the scan's body,
+    where the layer runs (``_gather_layer``), once in the forward and
+    once in the backward pass; the constraint's transpose sums the
+    layer's float32 gradient over the chips in the backward body (a
+    reduce-scatter on a TPU). That is ZeRO-3's schedule: the TPU-native
+    replacement for torch DDP/FSDP wrapping (reference
+    ``train_loop_utils.py:158,175``).
+    ``parallel.sharding.compiled_collectives(step.lower(...).compile())``
+    reads what the compiler made of it.
     """
     import optax
 

@@ -18,6 +18,7 @@ from .sharding import (  # noqa: F401
     DP_RULES,
     LM_RULES,
     batch_sharding,
+    compiled_collectives,
     replicated,
     shard_tree,
     spec_for,

@@ -10,6 +10,11 @@ a slice off the tiling, a DMA Mosaic cannot address, more VMEM than a
 kernel may take — fails here, at no chip time. Nothing runs: a compile
 that passes is not a chip run.
 
+Since PR 58 also the fsdp TRAINING step, whole, for the four
+described chips: the plan the TPU's compiler makes of it (which sinks
+a loop-invariant gather into the scan where the CPU's hoists it), read
+with ``parallel.sharding.compiled_collectives``.
+
 The topology is described inside a fixture (only the worker that is
 given this file loads the TPU's library), and every such test lives in
 this ONE file."""
@@ -19,16 +24,21 @@ import pytest
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:          # no TPU compiler in this install
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -656,3 +666,68 @@ def test_ssm_state_kernel_compiles_at_the_sixth_blocks_shape_for_v5e(
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == L * layer_state
     assert memory.temp_size_in_bytes < layer_state // 16
+
+
+def test_train_step_gathers_a_layer_in_the_scan_on_v5e(topo, monkeypatch):
+    """``cgpt1b3-train-fsdp4``'s step at its widths (24 layers, d 2048,
+    f 8192, 16 sequences, flash attention, ``remat="dots"``,
+    ``loss_chunk`` 256; 1,024 tokens a sequence so that no table is
+    ``[d, d]``) as the v5e compiler plans it for the four chips: every
+    weight all-gather is ONE layer's matrix in bfloat16 inside a scan's
+    body, six a pass; the layer's gradient is summed over the chips in
+    the backward body; no collective carries the layer dimension or a
+    whole batch's activations through the blocks; and the program fits
+    a chip. The parent's plan held 3.38 GB of collective results a
+    layer in the loops (the whole stack gathered again in EVERY layer,
+    all-to-alls of ``[B, S, d]``); this one 0.48 GB."""
+    import dataclasses
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt
+    from ray_tpu.ops import flash_attention
+    from ray_tpu.parallel import compiled_collectives, create_mesh
+
+    # the kernel as a TPU process builds it: flash_attention holds the
+    # decision under its own name, bound when it was first imported
+    monkeypatch.setattr(flash_attention, "pallas_interpret", lambda: False)
+    L, B, S = 24, 16, 1024
+    cfg = dataclasses.replace(gpt.CONFIGS["1b"], n_layer=L, max_seq=S,
+                              remat="dots", loss_chunk=256)
+    d, f = cfg.d_model, cfg.d_ff
+    mesh = create_mesh({"fsdp": 4}, devices=list(topo.devices))
+    init, step, state_sh, batch_sh = gpt.make_train_step(cfg, mesh)
+    shapes = jax.eval_shape(init, jax.random.PRNGKey(0))
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, state_sh)
+    tokens = jax.ShapeDtypeStruct((B, S + 1), jnp.int32, sharding=batch_sh)
+    compiled = step.lower(state, {"tokens": tokens}).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3     # flash, compiled
+    plan = compiled_collectives(compiled)
+
+    def dims(e):
+        return [tuple(x for x in dd if x != 1) for _dt, dd in e["shapes"]]
+
+    matrices = {(d, d), (d, f), (f, d)}
+    assert not [e for e in plan for dd in dims(e) if L in dd]
+    gathers = [e for e in plan if e["op"] == "all-gather"
+               and set(dims(e)) & matrices]
+    assert len(gathers) == 12, gathers
+    assert all(e["in_loop"] for e in gathers), gathers
+    assert {dt for e in gathers for dt, _ in e["shapes"]} == {"bf16"}
+    assert sum(e["bytes"] for e in gathers) == 2 * 2 * (4 * d * d + 2 * d * f)
+    sums = [e for e in plan if e["in_loop"] and e["bytes"] >= 4 * d * d
+            and e["op"] in ("all-reduce", "reduce-scatter")]
+    assert len(sums) >= 6, sums
+    whole = [e for e in plan if e["in_loop"] for _dt, dd in e["shapes"]
+             if math.prod(dd) in (B * S * d, B * S * f)]
+    assert not whole, whole
+    assert not [e for e in plan if e["in_loop"] and e["op"] == "all-to-all"]
+    assert sum(e["bytes"] for e in plan if e["in_loop"]) < 0.6e9
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 0.9 * 16 * 2 ** 30

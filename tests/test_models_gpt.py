@@ -61,8 +61,12 @@ def test_sharded_train_step_loss_decreases(nano, axes):
     assert int(state["step"]) == 5
 
 
-def test_sharding_plans_agree(nano):
-    """dp-only and fsdp+tp shardings compute the same loss trajectory."""
+@pytest.mark.parametrize("axes", [{"fsdp": 4, "tp": 2}, {"fsdp": 4}],
+                         ids=["fsdp4-tp2", "fsdp4"])
+def test_sharding_plans_agree(nano, axes):
+    """Replicated weights (dp only) and fsdp(+tp) shardings compute the
+    same loss trajectory: the gather a layer asks for by name moves
+    weights, not results."""
     import jax
 
     from ray_tpu.models import gpt
@@ -72,7 +76,8 @@ def test_sharding_plans_agree(nano):
     tokens = rng.integers(0, nano.vocab_size, (8, 33)).astype(np.int32)
 
     def run(axes):
-        mesh = create_mesh(axes)
+        n = int(np.prod(list(axes.values())))
+        mesh = create_mesh(axes, devices=jax.devices()[:n])
         init, step, _, batch_sh = gpt.make_train_step(nano, mesh)
         state = init(jax.random.PRNGKey(0))
         batch = {"tokens": jax.device_put(tokens, batch_sh)}
@@ -83,7 +88,7 @@ def test_sharding_plans_agree(nano):
         return out
 
     a = run({"dp": 8})
-    b = run({"fsdp": 4, "tp": 2})
+    b = run(axes)
     assert np.allclose(a, b, rtol=2e-2), (a, b)
 
 

@@ -59,6 +59,81 @@ def test_sharding_rules_fsdp_tp():
     assert spec_for("ln1_scale", (64,), LM_RULES, m) == P()
 
 
+# The shapes the model really has (models/gpt.py init_params at L=6,
+# d=64, f=256, E=4): a block's matrices are STACKED, and a stacked
+# matrix is never sharded on its layer dimension by fsdp or tp.
+_L, _D, _F, _E = 6, 64, 256, 4
+STACKED_SPECS = {
+    # leaf: (shape, spec on {"fsdp": 4, "tp": 2}, spec on {"fsdp": 4})
+    "block/wq/kernel": ((_L, _D, _D), (None, "fsdp", "tp"), (None, "fsdp")),
+    "block/wk/kernel": ((_L, _D, _D), (None, "fsdp", "tp"), (None, "fsdp")),
+    "block/wv/kernel": ((_L, _D, _D), (None, "fsdp", "tp"), (None, "fsdp")),
+    "block/wo/kernel": ((_L, _D, _D), (None, "tp", "fsdp"),
+                        (None, None, "fsdp")),
+    "block/w1/kernel": ((_L, _D, _F), (None, "fsdp", "tp"), (None, "fsdp")),
+    "block/w2/kernel": ((_L, _F, _D), (None, "tp", "fsdp"),
+                        (None, None, "fsdp")),
+    # Adam's moments carry the parameter's path as a suffix
+    "opt/0/mu/block/w1/kernel": ((_L, _D, _F), (None, "fsdp", "tp"),
+                                 (None, "fsdp")),
+    "block/ln1_scale": ((_L, _D), (), ()),
+    "embed/kernel": ((512, _D), ("fsdp", "tp"), ("fsdp",)),
+    "pos_embed": ((128, _D), (None, "fsdp"), (None, "fsdp")),
+    # the expert rules name L and shard it on purpose (ROADMAP T1)
+    "block/router/kernel": ((8, _D, _E), ("fsdp",), ("fsdp",)),
+    "block/w_up/kernel": ((8, _E, _D, _F), ("fsdp", None, None, "tp"),
+                          ("fsdp",)),
+}
+
+
+@pytest.mark.parametrize("leaf", sorted(STACKED_SPECS))
+def test_sharding_rules_stacked_shapes(leaf):
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    from ray_tpu.parallel import create_mesh, spec_for, LM_RULES
+    from ray_tpu.parallel.sharding import PP_LM_RULES
+
+    shape, both, fsdp = STACKED_SPECS[leaf]
+    m = create_mesh({"fsdp": 4, "tp": 2})
+    assert spec_for(leaf, shape, LM_RULES, m) == P(*both)
+    m4 = create_mesh({"fsdp": 4}, devices=jax.devices()[:4])
+    assert spec_for(leaf, shape, LM_RULES, m4) == P(*fsdp)
+    # an unstacked matrix under the same rule: its own two dimensions
+    if len(shape) == 3 and "router" not in leaf:
+        assert spec_for(leaf, shape[1:], LM_RULES, m) == P(*both[1:])
+    # the pipeline's rules are untouched: the layer dimension over pp
+    mp = create_mesh({"pp": 2, "dp": 4})
+    want = P("pp") if "block/" in leaf else P()
+    assert spec_for(leaf, shape, PP_LM_RULES, mp) == want
+
+
+def test_stacked_plan_of_the_real_tree():
+    """Over ``init_params``' own tree: no leaf under ``block/`` of the
+    dense model is sharded on dimension 0, and the optimizer's moments
+    get their parameter's spec."""
+    import jax
+    import optax
+
+    from ray_tpu.models import gpt
+    from ray_tpu.parallel import create_mesh, tree_shardings, LM_RULES
+
+    cfg = gpt.CONFIGS["nano"]
+    params = jax.eval_shape(lambda k: gpt.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    opt = jax.eval_shape(optax.adamw(1e-3).init, params)
+    m = create_mesh({"fsdp": 2, "tp": 2}, devices=jax.devices()[:4])
+    sh = tree_shardings(params, m, LM_RULES)
+    for name, leaf in sh["block"].items():
+        spec = leaf["kernel"].spec if isinstance(leaf, dict) else leaf.spec
+        assert not spec or spec[0] is None, (name, spec)
+    assert sh["block"]["wq"]["kernel"].spec == \
+        jax.sharding.PartitionSpec(None, "fsdp", "tp")
+    mu = tree_shardings(opt, m, LM_RULES)[0].mu
+    assert jax.tree.map(lambda a: a.spec, mu) == \
+        jax.tree.map(lambda a: a.spec, sh)
+
+
 def test_xla_collective_group(mesh8):
     from ray_tpu.collective import collective as C
 
