@@ -175,19 +175,33 @@ tp>1: :func:`ray_tpu.models.moe.moe_ffn` is not tp-aware.
 Paged-attention kernel + int8 KV (ISSUE 16): two orthogonal,
 engine-static knobs on the paged hot path. ``attn_kernel="pallas"``
 swaps the decode step's gather-then-mask attention for
-:func:`paged_attention`'s fused Pallas kernel: one grid step a slot,
-whose work is that slot's OWN live pages — the page table and the live
-lengths ride as scalar-prefetch operands, the pool stays in HBM, and
-the kernel copies each live page ONCE into a ring of VMEM pages, ahead
-of the arithmetic, and folds it into one running-max softmax pass.
-Pages that are :data:`PT_SENTINEL`-unmapped or past ``pos`` cost
-nothing: no grid step, no fetch. Off-TPU the same kernel runs in
-interpret mode, so CPU tier-1 exercises the shipping kernel body. The
-kernel rounds its probabilities to the compute dtype before they meet
-V, as the gather path does, but before the division by the sum instead
-of after it: the two paths agree to :data:`ATTN_KERNEL_ULPS` bf16 ulp
-of the largest output, not to the bit, and streams are held to the
-reference by margin, not by token identity (ROADMAP D10).
+:func:`paged_attention`'s fused Pallas kernel, which since ISSUE 61 is
+the grouped-query kernel of :mod:`ray_tpu.models.kda_moe`
+(:func:`~ray_tpu.models.kda_moe.gqa_decode_attention`) at a group of
+ONE: multi-head attention is grouped-query attention whose every query
+head has keys and values of its own. One grid step a slot, whose work
+is that slot's OWN live pages — the page table, the live lengths and
+the count of blocks before each lane ride as scalar-prefetch operands,
+the pool stays in HBM viewed ``[pages, page_size * H, hd]`` (a page's
+rows as they lie, ``(token, head)``: no copy), and the kernel copies
+each live page ONCE into a ring of VMEM blocks of pages (of a page
+larger than a block, a lane's whole ``max_len`` say, in equal parts, a
+part a block), the lanes' blocks one stream, so a lane's last blocks
+fetch the next lane's first. A block is ONE operand of two MXU products over all heads at
+once (``q . K^T`` with a foreign head's score masked to -1e30, then ``p
+. V``) around one running-max softmax pass in float32. Pages that are
+:data:`PT_SENTINEL`-unmapped or past ``pos`` cost nothing: no fetch,
+and a lane without a live token returns zeros. Off-TPU the same kernel
+runs in interpret mode, so CPU tier-1 exercises the shipping kernel
+body; on a TPU a page's rows (or such a part's) must be whole sublane
+tiles (``page_size * H`` a multiple of 16 in bfloat16, 32 in int8) and
+``hd`` whole lanes,
+and a pool Mosaic cannot address is refused by name. The kernel rounds
+its probabilities to the compute dtype before they meet V, as the
+gather path does, but before the division by the sum instead of after
+it: the two paths agree to :data:`ATTN_KERNEL_ULPS` bf16 ulp of the
+largest output, not to the bit, and streams are held to the reference
+by margin, not by token identity (ROADMAP D10).
 ``kv_dtype="int8"`` stores pages as symmetric int8 codes with one f32
 scale per (layer, page, head) per side (~2x the pages in the same
 HBM at bf16): scatters become page-granular requantize-and-merge
@@ -856,21 +870,28 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
     into virtual order and run masked full-length attention (sentinel
     entries clip to an arbitrary real page whose garbage the mask
     hides). ``kernel="pallas"`` fuses the gather, the length masking,
-    and the softmax into one kernel whose grid is ``(B,)``: a slot's
-    step loops over THAT slot's live pages (``pos[b] // page_size + 1``
+    and the softmax into one kernel whose grid is ``(B,)``
+    (:func:`_paged_attention_pallas`: the grouped-query kernel of
+    :mod:`ray_tpu.models.kda_moe` at a group of one): a slot's step
+    loops over THAT slot's live pages (``pos[b] // page_size + 1``
     inside the mapped prefix of its row, none for a row of
-    :data:`PT_SENTINEL`), copies each from the pool in HBM into a ring
-    of VMEM pages ahead of the arithmetic, steered by the
-    scalar-prefetched page table, and reads it once. ``max_pages``
+    :data:`PT_SENTINEL`) in blocks of pages, copied from the pool in
+    HBM into a ring of VMEM blocks ahead of the arithmetic, steered by
+    the scalar-prefetched page table, each read once; the lanes'
+    blocks are one stream, so the ring is filled once a call and a
+    lane's last blocks fetch the next lane's first. ``max_pages``
     multiplies nothing: the kernel does O(pages actually held) work.
 
-    One softmax pass (flash decoding): a running max, a running sum
-    and a float32 accumulator, rescaled as the max moves, divided once
-    at the slot's end. q·k is accumulated in float32, max / exp / sum
-    are float32, the probabilities are rounded to the compute dtype
+    A block's rows, ``(token, head)`` as the pool holds them, are ONE
+    operand: ``q . K^T`` over all heads at once on the MXU
+    (compute-dtype operands, float32 sums, scaled in float32) with a
+    foreign head's score set to -1e30, one softmax pass (flash
+    decoding: a running max, a running sum and a float32 accumulator,
+    rescaled as the max moves, divided once at the slot's end), then
+    ``p . V`` with the probabilities rounded to the compute dtype
     before they meet V — as the gather path's
     ``softmax(...).astype(dtype)`` rounds them, but before the division
-    by the sum, not after — and p·v is summed in float32 and rounded
+    by the sum, not after — summed in float32 and rounded
     once. So the two paths are NOT bit-identical: they agree to
     :data:`ATTN_KERNEL_ULPS` ulps of the largest output. Every live
     position enters the softmax; a position past ``pos[b]`` or in an
@@ -879,7 +900,8 @@ def paged_attention(q: jax.Array, kc: jax.Array, vc: jax.Array,
 
     With int8 pools pass ``ks``/``vs`` (per-(page, head) scales); both
     paths dequantize through :func:`_deq_page` semantics at the point
-    of use, so the same bound holds quantized."""
+    of use (the kernel a block's rows where it reads them: the same
+    body), so the same bound holds quantized."""
     if kernel == "pallas":
         return _paged_attention_pallas(q, kc, vc, pt, pos, page_size,
                                        ks, vs)
@@ -914,229 +936,43 @@ def _paged_attention_gather(q, kc, vc, pt, pos, page_size, ks, vs):
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-#: VMEM the kernel's page ring may take for K (and again for V): with
-#: 64 KB pages (the serving cells') that is 32 pages = 512 tokens.
-_ATTN_RING_BYTES = 2 << 20
-#: Most tokens the kernel multiplies at once: a ``[tokens, H, hd]``
-#: float32 value of this many tokens is 64 vregs at the cells' ``H``,
-#: ``hd``, the whole register file.
-_ATTN_CHUNK_TOKENS = 32
-
-
-def _attn_schedule(page_size: int, max_pages: int, H: int, hd: int,
-                   itemsize: int) -> Tuple[int, int, int]:
-    """How :func:`_paged_attention_pallas` cuts its work, from what it
-    can see: ``(ring, chunk, ring_bytes)``. ``ring`` pages of K (and of
-    V) are in flight or in use at once — as many as fit
-    :data:`_ATTN_RING_BYTES`, at least two (the fetch of one hides
-    behind the arithmetic of the other), never more than a lane can
-    hold (``max_pages``; ONE where a page is a lane's whole
-    ``max_len``). ``chunk`` tokens are multiplied at once: a whole page
-    where it is small, else the largest divisor of ``page_size`` up to
-    :data:`_ATTN_CHUNK_TOKENS`. ``ring_bytes`` is what one ring takes
-    of VMEM, whose tiles hold ``32 / itemsize`` rows of 128 lanes."""
-    rows = 32 // itemsize
-    page_bytes = page_size * -(-H // rows) * rows * -(-hd // 128) * 128 \
-        * itemsize
-    ring = min(max(2, _ATTN_RING_BYTES // page_bytes), max_pages)
-    chunk = max(c for c in range(1, min(page_size, _ATTN_CHUNK_TOKENS) + 1)
-                if page_size % c == 0)
-    return ring, chunk, ring * page_bytes
-
-
 def _paged_attention_pallas(q, kc, vc, pt, pos, page_size, ks, vs):
-    """Fused paged-attention kernel (see :func:`paged_attention`).
+    """:func:`paged_attention` through the grouped-query kernel
+    (:func:`ray_tpu.models.kda_moe.gqa_decode_attention`) at a group of
+    ONE: multi-head attention is grouped-query attention whose every
+    query head has keys and values of its own, and a page's rows,
+    ``(token, head)`` as they lie, are that kernel's operand whatever
+    the heads are called. What this adds is the block's own: each
+    lane's live length (:func:`ray_tpu.models.serving.live_length`:
+    ``pos + 1`` for a lane the engine steps, 0 for a row of
+    :data:`PT_SENTINEL`); the table clipped, because the kernel
+    names a page by what the table holds and an int8 pool's scales are
+    gathered through every column; the refusal by name of a pool
+    Mosaic cannot address; and the scope a trace's readers know the
+    kernel by."""
+    from . import kda_moe
 
-    Grid ``(B,)``: one step a lane, and inside it a loop over THAT
-    lane's live tokens, ``length[b]`` of them, where ``length[b]`` is
-    ``pos[b] + 1`` cut to the mapped prefix of the lane's table row (0
-    for a row of :data:`PT_SENTINEL`). ``pt`` and ``length`` ride as
-    scalar-prefetch operands; the pool stays in HBM and the kernel
-    copies the pages the table names into a ring of ``ring`` VMEM pages
-    (:func:`_attn_schedule`), one semaphore a page and side: the first
-    ``ring`` at the lane's start, each later one into the slot of the
-    page just read. A page is fetched ONCE, and its fetch hides behind
-    the arithmetic of the ``ring`` pages before it.
-
-    One softmax pass, in base 2: the running max ``m [H, 1]``, the
-    running sum ``l [H, 1]`` and the accumulator ``acc [H, hd]``
-    (float32) are carried through the loop and rescaled as the max
-    moves; ``acc / l`` is written once, at the lane's end (zeros where
-    ``l`` is 0). The probabilities are rounded to the compute dtype
-    before they meet V, as the gather path rounds them, but BEFORE the
-    division by ``l``: that is the whole numeric difference
-    (:data:`ATTN_KERNEL_ULPS`).
-
-    The loop's unit is a ``chunk`` of tokens (a page, at the cells'
-    shape): its scores (q·k: a VPU multiply and a lane reduction), then
-    its share of the sums (exp, p·v: a lane broadcast and an accumulate
-    over the leading axis). Whole chunks need no mask; the lane's last,
-    partial chunk masks K's side AND V's (0 * inf is NaN). One query
-    row a head has no use for the MXU, and the contraction
-    ``einsum("hd,thd->ht")`` has its batch axis in the MIDDLE of the
-    page operand, which Mosaic refuses. Products of two compute-dtype
-    values are exact in float32; the query carries ``log2(e) /
-    sqrt(hd)``, one float32 rounding away from the gather path's
-    scaling of the sums. int8 scales arrive gathered per (lane, column)
-    as ``[B, H, max_pages]``: a page's ``[H, 1]`` column is picked by a
-    lane mask and a lane reduction, which needs no dynamic lane
-    slice."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    from .._private.chip import pallas_interpret
-
-    B = q.shape[0]
     H, hd = q.shape[2], q.shape[3]
     n_pages = kc.shape[0]
-    ps = page_size
-    max_pages = pt.shape[1]
-    quant = ks is not None
-    dtype = q.dtype
-    ring, chunk, ring_bytes = _attn_schedule(
-        ps, max_pages, H, hd, jnp.dtype(kc.dtype).itemsize)
-    cpp = ps // chunk                          # chunks a page
-    interpret = pallas_interpret()
-    if not interpret and (hd % 128 or (H % 8 and H != 4)):
-        # Mosaic addresses a page of the pool in whole (8, 128) tiles.
+    if not kda_moe.gqa_kernel(H, hd, kc.dtype, page_size):
+        # Mosaic addresses a page of the pool in whole tiles of rows.
         raise ValueError(
-            f"attn_kernel='pallas' fetches pages of [page_size, H, hd] "
-            f"by DMA, which on a TPU needs hd a multiple of 128 and H a "
-            f"multiple of 8 (or 4); got H={H}, hd={hd}: use "
+            f"attn_kernel='pallas' fetches pages of [page_size * H, hd] "
+            f"rows by DMA (a page larger than a block in equal parts), "
+            f"which on a TPU needs hd a multiple of 128 and the rows of "
+            f"a page or part whole sublane tiles of the pool's dtype (16 "
+            f"rows of bfloat16, 32 of int8); got H={H}, hd={hd}, "
+            f"page_size={page_size}, {jnp.dtype(kc.dtype).name}: use "
             f"attn_kernel='gather' for this model")
-    # Python float (f32-exact) so the kernel closure stays constant-free.
-    scale = float(np.float32(np.log2(np.e)) / np.sqrt(np.float32(hd)))
-    # The live length of a lane: positions <= pos inside the mapped
-    # prefix of its row. The engine maps a lane's pages from column 0
-    # without holes, so this is pos + 1 for a lane it steps and 0 for a
-    # row of sentinels.
-    mapped = jnp.min(jnp.where(pt == PT_SENTINEL,
-                               jnp.arange(max_pages, dtype=jnp.int32),
-                               jnp.int32(max_pages)), axis=1)
-    length = jnp.minimum(pos.astype(jnp.int32) + 1, mapped * ps)
-
-    def kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, *rest):
-        if quant:
-            ks_ref, vs_ref, o_ref, k_buf, v_buf, sems = rest
-        else:
-            ks_ref = vs_ref = None
-            o_ref, k_buf, v_buf, sems = rest
-        b = pl.program_id(0)
-        n_live = len_ref[b]
-        n = (n_live + ps - 1) // ps            # this lane's live pages
-
-        def copy(side, g):
-            hbm, buf = ((k_hbm, k_buf), (v_hbm, v_buf))[side]
-            page = jnp.clip(pt_ref[b, g], 0, n_pages - 1)
-            return pltpu.make_async_copy(hbm.at[page], buf.at[g % ring],
-                                         sems.at[side, g % ring])
-
-        def start(g):
-            copy(0, g).start()
-            copy(1, g).start()
-
-        lax.fori_loop(0, jnp.minimum(n, ring), lambda g, _: start(g), None)
-        qf = q_ref[0].astype(jnp.float32) * scale      # [H, hd]
-
-        def rows(side, g, c):
-            """Chunk ``c`` of page ``g`` as f32 ``[chunk, H, hd]``,
-            through the compute dtype (int8 rows dequantize exactly as
-            :func:`_deq_page`)."""
-            buf, s_ref = ((k_buf, ks_ref), (v_buf, vs_ref))[side]
-            r = buf[g % ring] if cpp == 1 else \
-                buf[g % ring, pl.ds(c * chunk, chunk)]
-            if quant:
-                # Column g of this lane's [H, max_pages] scales, [H, 1].
-                col = lax.broadcasted_iota(jnp.int32, (H, max_pages), 1)
-                sc = jnp.sum(jnp.where(col == g, s_ref[0], 0.0), axis=1,
-                             keepdims=True)
-                r = (r.astype(jnp.float32) * sc).astype(dtype)
-            return r.astype(jnp.float32)
-
-        def fold(u, carry, whole=True):
-            """Chunk ``u`` of the lane into ``(m, l, acc)``. A copy's
-            wait and its start fence the vector slots, so the waits
-            come first (on a page's first chunk) and the refill last
-            (behind its last chunk the slot is free: the page ``ring``
-            further on starts), with all the arithmetic between them
-            in one block."""
-            def on_chunk(which, fn):
-                fn() if cpp == 1 else pl.when(c == which)(fn)
-
-            g, c = (u, 0) if cpp == 1 else (u // cpp, u % cpp)
-            m, l, acc = carry
-
-            @functools.partial(on_chunk, 0)
-            def _():
-                copy(0, g).wait()
-                copy(1, g).wait()
-
-            s = jnp.sum(qf * rows(0, g, c), axis=2,
-                        keepdims=True)                   # [chunk, H, 1]
-            v = rows(1, g, c)
-            if not whole:
-                valid = u * chunk + lax.broadcasted_iota(
-                    jnp.int32, (chunk, H, 1), 0) < n_live
-                s = jnp.where(valid, s, -1e30)
-                v = jnp.where(valid, v, 0.0)
-            m_new = jnp.maximum(m, jnp.max(s, axis=0))
-            alpha = jnp.exp2(m - m_new)
-            p = jnp.exp2(s - m_new)                      # 0 where masked
-            l = alpha * l + jnp.sum(p, axis=0)
-            acc = alpha * acc + jnp.sum(
-                p.astype(dtype).astype(jnp.float32) * v, axis=0)
-            if whole:
-                on_chunk(cpp - 1, lambda: pl.when(g + ring < n)(
-                    lambda: start(g + ring)))
-            return m_new, l, acc
-
-        n_whole = n_live // chunk              # chunks that need no mask
-        carry = lax.fori_loop(
-            0, n_whole, fold,
-            (jnp.full((H, 1), -1e30, jnp.float32),
-             jnp.zeros((H, 1), jnp.float32),
-             jnp.zeros((H, hd), jnp.float32)))
-        m, l, acc = lax.cond(
-            n_live > n_whole * chunk,
-            lambda carry: fold(n_whole, carry, whole=False),
-            lambda carry: carry, carry)
-        o_ref[0] = (acc / jnp.where(l > 0.0, l, 1.0)).astype(dtype)
-
-    def lane_map(b, pt_s, len_s):
-        return (b, 0, 0)
-
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [pl.BlockSpec((1, H, hd), lane_map), hbm, hbm]
-    inputs = [q[:, 0], kc, vc]
-    if quant:
-        ptc = jnp.clip(pt, 0, n_pages - 1)
-        in_specs += [pl.BlockSpec((1, H, max_pages), lane_map)] * 2
-        inputs += [ks[ptc].transpose(0, 2, 1), vs[ptc].transpose(0, 2, 1)]
+    length = serving.live_length(pt, pos, True, n_pages, page_size)
     # The scope names the kernel's path for a trace's readers
-    # (".../paged_attention/pallas_call"); `name` names the device
-    # operation itself ("paged_attention.N").
+    # (".../paged_attention/gqa_attention/pallas_call").
     with jax.named_scope("paged_attention"):
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(B,),
-                in_specs=in_specs,
-                out_specs=pl.BlockSpec((1, H, hd), lane_map),
-                scratch_shapes=[pltpu.VMEM((ring, ps, H, hd), kc.dtype),
-                                pltpu.VMEM((ring, ps, H, hd), vc.dtype),
-                                pltpu.SemaphoreType.DMA((2, ring))]),
-            out_shape=jax.ShapeDtypeStruct((B, H, hd), dtype),
-            # Every index a copy takes is clipped (page) or a remainder
-            # (slot): the bounds checks Mosaic adds to a dynamic slice
-            # are a fifth of a page's instructions and cannot fire.
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=2 * ring_bytes + (12 << 20),
-                disable_bounds_checks=True),
-            interpret=interpret,
-            name="paged_attention",
-        )(pt, length, *inputs)
-    return out[:, None]
+        out = kda_moe.gqa_decode_attention(
+            q[:, 0], kc, vc, jnp.clip(pt, 0, n_pages - 1), pos, length,
+            n_head=H, n_kv_head=H, head_dim=hd, dtype=q.dtype,
+            page_size=page_size, kscale=ks, vscale=vs)
+    return out.astype(q.dtype)[:, None]
 
 
 def _prefill_attend(q, k, v, hist: Cache, hist_len, T: int, hist_pages,

@@ -45,10 +45,14 @@ sequence, and :func:`cache_spec` describes both in one
   20 queries over 4 KV heads, its rotary applied by the caller, and
   :mod:`ray_tpu.models.ssm_moe` at 32 over 8 with its own score scale
   folded into ``q``; :func:`gqa_causal_attention` is prefill's, public
-  the same way. The GPT-2 block's kernel (one
-  query a head on the VPU) and the latent decoder's (64 absorbed
-  queries over one row a token) are other inner loops around the same
-  ring of copies, which is copied here, not shared (ROADMAP D13).
+  the same way. Since ISSUE 61 the GPT-2 block's decode attention is
+  the kernel too, at a group of ONE (16 heads, each with keys and
+  values of its own: a page's rows are ``(token, head)`` whatever the
+  heads are called), and its int8 pools with it, dequantized a block
+  where it is read (``kscale`` / ``vscale``). The latent decoder's (64
+  absorbed queries over one row a token) is another inner loop around
+  the same ring of copies, which is copied here, not shared (ROADMAP
+  D13).
 - **KDA** (``kda_heads`` heads of ``kda_head_dim`` keys and values): a
   width-``conv_size`` causal depthwise convolution and SiLU on the
   ``q``/``k``/``v`` projections, L2-normalised ``q`` (scaled) and ``k``
@@ -151,9 +155,9 @@ _GQA_LANE_BLOCK = 32
 #: in float32 (as :data:`ray_tpu.models.mla_moe.ATTN_KERNEL_ULPS`, the
 #: same difference).
 ATTN_KERNEL_ULPS = 4
-#: Tokens the GQA kernel multiplies at once (``// page_size`` pages; one
-#: page where a page is larger), and the blocks of keys (and as many of
-#: values) in flight or in use at once. Measured on a v5e at the cell's
+#: Tokens the GQA kernel multiplies at once (``// page_size`` pages; a
+#: part of a page where a page is larger: :func:`_gqa_block`), and the
+#: blocks of keys (and as many of values) in flight or in use at once. Measured on a v5e at the cell's
 #: shapes (254 lanes of 128-1,792 live tokens, 253 k in all, 1.05 GB of
 #: pages; the gather 9.54 ms): blocks of 128 / 192 / 256 / 384 / 512 /
 #: 1,024 tokens read 1.62 / 1.46 / 1.44 / 1.48 / 1.47 / 1.67 ms at the
@@ -163,6 +167,22 @@ ATTN_KERNEL_ULPS = 4
 #: flight, and a ring that is a power of two indexes with a mask.
 _GQA_BLOCK_TOKENS = 256
 _GQA_RING_BLOCKS = 4
+#: Most ``(token, KV head)`` rows of a block, whatever the heads are
+#: called: 2,048 rows of 128 bfloat16 are 512 KiB a block and side, 4
+#: MiB of VMEM at the ring of 4, and the scores and probabilities of a
+#: block are ``[Hq, rows]`` float32 each. Eight KV heads fill it with
+#: the 256 tokens above; sixteen (multi-head attention, a group of
+#: one: the GPT-2 block's) with 128. Measured on a v5e at THAT shape
+#: (32 lanes of 250-450 live tokens, 24 layers a step; the VPU kernel
+#: this replaced 4.31 ms): 1,024 / 2,048 / 4,096 rows read 3.27 / 3.27
+#: / 3.30 ms at the best ring of each, and 2,048 rows with a ring of 2
+#: / 4 / 8 read 3.51 / 3.27 / 3.26 (PERF.md section 6, PR 61): the
+#: copies bound it here too (87% of 819 GB/s), so the rows follow the
+#: 512 KiB of the measurement above and not the heads. (The tokens
+#: above are kept beside the rows only for four KV heads, whose block
+#: they hold to the 1,024 rows it had: the rule is the rows alone once
+#: ``tests/test_models_frame.py: PARENT_TEXT`` may move.)
+_GQA_BLOCK_ROWS = 2048
 #: Heads of a lane's state the recurrence's kernel holds in VMEM at
 #: once (the largest common divisor with ``kda_heads``): 32 heads of
 #: [128, 128] float32 are 2 MiB, 8 MiB with the block before and the
@@ -392,20 +412,38 @@ def _state_kernel(cfg: KDAMoEConfig) -> bool:
     return pallas_interpret() or cfg.kda_head_dim % 128 == 0
 
 
+def _gqa_block(n_kv_head: int, page_size: int) -> Tuple[int, int]:
+    """A block of the GQA kernel as ``(pages a block, blocks a page)``:
+    :data:`_GQA_BLOCK_TOKENS` tokens or :data:`_GQA_BLOCK_ROWS` rows of
+    ``n_kv_head`` a token, whichever is fewer, in whole pages; a page
+    that holds more is read in the fewest equal parts that hold no
+    more, a part a block, so what a block takes of VMEM is bounded
+    whatever the page is (a page of a lane's whole ``max_len``
+    included)."""
+    cap = max(1, min(_GQA_BLOCK_TOKENS, _GQA_BLOCK_ROWS // n_kv_head))
+    if page_size <= cap:
+        return cap // page_size, 1
+    return 1, next(s for s in range(2, page_size + 1)
+                   if page_size % s == 0 and page_size // s <= cap)
+
+
 def gqa_kernel(n_kv_head: int, head_dim: int, dtype, page_size: int
                ) -> bool:
     """Whether decode's GQA attention (:func:`gqa_decode_attention`) is
-    the Pallas kernel: wherever Mosaic can address a page of the pool
-    as the kernel views it, ``[page_size * n_kv_head, head_dim]`` rows:
-    compiled for a TPU ``head_dim`` must be whole 128-lane tiles and a
-    page's rows whole sublane tiles of the pool's dtype (16 of
-    bfloat16, 8 of float32); interpreted, off the TPU, any page is
-    addressable. Elsewhere the gather."""
+    the Pallas kernel: wherever Mosaic can address what the kernel
+    copies of the pool, a page viewed ``[page_size * n_kv_head,
+    head_dim]`` rows or the part of it that is a block
+    (:func:`_gqa_block`): compiled for a TPU ``head_dim`` must be whole
+    128-lane tiles and a copy's rows whole sublane tiles of the pool's
+    dtype (16 of bfloat16, 8 of float32, 32 of an int8 pool's codes);
+    interpreted, off the TPU, any page is addressable. Elsewhere the
+    gather."""
     from .._private.chip import pallas_interpret
 
-    rows = 32 // jnp.dtype(dtype).itemsize
+    rows = page_size // _gqa_block(n_kv_head, page_size)[1] * n_kv_head
     return pallas_interpret() or (
-        head_dim % 128 == 0 and (page_size * n_kv_head) % rows == 0)
+        head_dim % 128 == 0
+        and rows % (32 // jnp.dtype(dtype).itemsize) == 0)
 
 
 def _gqa_kernel(cfg: KDAMoEConfig, page_size: int) -> bool:
@@ -716,7 +754,7 @@ def _gqa_attention_gather(q, kpool, vpool, pages, pos, n_kv_head: int,
 
 
 def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
-                          page_size: int):
+                          page_size: int, kscale=None, vscale=None):
     """Decode's attention as ONE kernel that reads what is live, once:
     ``q`` [B, Hq, hd] against the first ``length[b]`` tokens of lane
     ``b``, whose pages ``pages`` [B, max_pages] names in the flat pools
@@ -736,6 +774,10 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
     each later block started behind the arithmetic of the block whose
     buffer it takes, so a lane's last blocks fetch the NEXT lane's
     first. A page is fetched ONCE; a page past the live length never.
+    A page that holds more than a block (:func:`_gqa_block`) is a row
+    of equal parts, a part a block and a copy, so the ring is as large
+    whatever the page, and of the lane's last page only the parts that
+    hold a live token are fetched.
 
     A page's rows are ``(token, KV head)`` with the head in the MIDDLE
     of ``[page_size, Hkv, hd]``, which Mosaic takes neither as a
@@ -755,7 +797,18 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
     (:data:`ATTN_KERNEL_ULPS`). Whole blocks take no other mask; the
     lane's last, partial block masks the scores AND the values past
     the live length (a block's unfetched rows hold whatever was there,
-    and 0 * inf is NaN)."""
+    and 0 * inf is NaN).
+
+    Pools of int8 codes come with ``kscale`` and ``vscale`` [pages,
+    Hkv] float32, a scale a page and KV head (``pages`` then in
+    bounds in EVERY column): the same body, whose block is dequantized
+    where it is read, ``(code * scale)`` rounded to the compute dtype
+    before the product as :func:`ray_tpu.models.gpt_decode._deq_page`
+    rounds it. A row's scale is its page's and its head's, so a lane's
+    scales ride beside its query as ``[R, max_pages]``, ``R`` the
+    float32 sublane tiles that hold whole tokens' heads, and a block's
+    pages pick their columns by a mask and a lane reduction: a column
+    ``[R, 1]`` a page, repeated down the page's rows."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -765,37 +818,48 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
     Hkv = n_kv_head
     G = Hq // Hkv
     ps = page_size
-    rows = ps * Hkv                                # rows a page
-    bp = max(1, _GQA_BLOCK_TOKENS // ps)           # pages a block
-    T = bp * ps
+    bp, split = _gqa_block(Hkv, ps)       # pages a block, blocks a page
+    cp = ps // split                      # tokens a copy: a page, or a part
+    rows = cp * Hkv                                # rows a copy
+    T = bp * cp
     ring = _GQA_RING_BLOCKS
     dtype = q.dtype
+    quant = kscale is not None
     scale = hd ** -0.5             # a Python float: no captured constant
-    kpool = kpool.reshape(-1, rows, hd)
-    vpool = vpool.reshape(-1, rows, hd)
+    kpool = kpool.reshape(-1, ps * Hkv, hd)
+    vpool = vpool.reshape(-1, ps * Hkv, hd)
     first = jnp.concatenate([
         jnp.zeros((1,), jnp.int32),
         jnp.cumsum((length + T - 1) // T, dtype=jnp.int32)])
     own = jnp.where(
         (jnp.arange(T * Hkv) % Hkv)[None] == (jnp.arange(Hq) // G)[:, None],
         0.0, -1e30).astype(jnp.float32)            # [Hq, T * Hkv]
+    # rows of a float32 tile pattern that holds whole tokens' heads
+    R = math.lcm(Hkv, 8)
+    R = R if rows % R == 0 else rows
 
     def kernel(pt_ref, len_ref, first_ref, q_ref, own_ref, k_hbm, v_hbm,
-               o_ref, kbuf, vbuf, sems):
+               *rest):
+        ks_ref, vs_ref = rest[:-4] if quant else (None, None)
+        o_ref, kbuf, vbuf, sems = rest[-4:]
         b = pl.program_id(0)
         n_live = len_ref[b]
         base, total = first_ref[b], first_ref[B]
 
         def each_page(lane, j, i, what):
             """``what`` (start or wait) on the two copies of every live
-            page of ``lane``'s block ``j``, block ``i`` of the stream."""
-            n = (len_ref[lane] + ps - 1) // ps         # its live pages
+            page of ``lane``'s block ``j``, block ``i`` of the stream
+            (of a page larger than a block, its part ``g % split``)."""
+            n = (len_ref[lane] + cp - 1) // cp         # its live copies
 
             def page(g, _):
                 for side, (hbm, buf) in enumerate(((k_hbm, kbuf),
                                                    (v_hbm, vbuf))):
                     what(pltpu.make_async_copy(
-                        hbm.at[pt_ref[lane, g]],
+                        hbm.at[pt_ref[lane, g]] if split == 1 else
+                        hbm.at[pt_ref[lane, g // split],
+                               pl.ds(pl.multiple_of(g % split * rows, rows),
+                                     rows)],
                         buf.at[i % ring, g - j * bp],
                         sems.at[side, i % ring]))
 
@@ -816,6 +880,27 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
 
         qv = q_ref[0]                                        # [Hq, hd]
 
+        def block(buf, s_ref, i, j):
+            """The lane's block ``j``, block ``i`` of the stream, as
+            ONE operand [T * Hkv, hd]. int8: its codes under their
+            scales, rounded to the compute dtype; a table column past
+            the lane's last page scales by what the caller gathered
+            there or by 0, and the partial block's masks cover both."""
+            codes = buf[i % ring]
+            if not quant:
+                return codes.reshape(T * Hkv, hd)
+            width = s_ref.shape[2]
+            col = lax.broadcasted_iota(jnp.int32, (bp, R, width), 2) \
+                - lax.broadcasted_iota(jnp.int32, (bp, R, width), 0)
+            sc = jnp.sum(jnp.where(col == (j * bp if split == 1 else
+                                           j // split),
+                                   s_ref[0][None], 0.0),
+                         axis=2, keepdims=True)              # [bp, R, 1]
+            rows_f = codes.astype(jnp.float32).reshape(
+                bp, rows // R, R, hd) * sc[:, None]
+            return rows_f.reshape(bp, rows, hd).astype(dtype).reshape(
+                T * Hkv, hd)
+
         def fold(j, carry, whole=True):
             """Block ``j`` of the lane into ``(m, l, acc)``: the waits
             first, the refill last (behind the second product the
@@ -823,8 +908,8 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
             m, l, acc = carry
             i = base + j
             each_page(b, j, i, lambda copy: copy.wait())
-            kb = kbuf[i % ring].reshape(T * Hkv, hd)
-            vb = vbuf[i % ring].reshape(T * Hkv, hd)
+            kb = block(kbuf, ks_ref, i, j)
+            vb = block(vbuf, vs_ref, i, j)
             s = lax.dot_general(qv, kb, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) \
                 * scale + own_ref[...]
@@ -860,6 +945,8 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
         return (b, 0, 0)
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    scales = [jnp.tile(s[pages].transpose(0, 2, 1), (1, R // Hkv, 1))
+              for s in ((kscale, vscale) if quant else ())]
     # `name` names the device operation ("gqa_attention.N") and the last
     # component of its path before "pallas_call"; the rest of the path
     # is the caller's scope.
@@ -870,7 +957,8 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
             grid=(B,),
             in_specs=[pl.BlockSpec((1, Hq, hd), lane_map),
                       pl.BlockSpec((Hq, T * Hkv), lambda b, *_: (0, 0)),
-                      hbm, hbm],
+                      hbm, hbm]
+            + [pl.BlockSpec((1, R, pages.shape[1]), lane_map)] * len(scales),
             out_specs=pl.BlockSpec((1, Hq, hd), lane_map),
             scratch_shapes=[pltpu.VMEM((ring, bp, rows, hd), kpool.dtype),
                             pltpu.VMEM((ring, bp, rows, hd), vpool.dtype),
@@ -881,7 +969,7 @@ def _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head: int,
         compiler_params=pltpu.CompilerParams(disable_bounds_checks=True),
         interpret=pallas_interpret(),
         name="gqa_attention",
-    )(pages, length, first, q, own, kpool, vpool)
+    )(pages, length, first, q, own, kpool, vpool, *scales)
 
 
 def gqa_decode_reads(pt, pos, active, n_pages: int, page_size: int,
@@ -891,7 +979,8 @@ def gqa_decode_reads(pt, pos, active, n_pages: int, page_size: int,
     (:func:`gqa_kernel`) ``length`` int32 [B] is each lane's live
     tokens (:func:`~ray_tpu.models.serving.live_length`; the step
     writes a token's keys and values before it attends) and
-    ``fetched`` those rounded up to whole pages, summed; with the
+    ``fetched`` those rounded up to whole pages, summed (a bound from
+    above where a page is read in parts, :func:`_gqa_block`); with the
     gather ``length`` is None and ``fetched`` the whole table,
     ``slots x max_pages x page_size``, whatever is live."""
     if not kernel:
@@ -903,7 +992,7 @@ def gqa_decode_reads(pt, pos, active, n_pages: int, page_size: int,
 
 def gqa_decode_attention(q, kpool, vpool, pages, pos, length, *,
                          n_head: int, n_kv_head: int, head_dim: int,
-                         dtype, page_size: int):
+                         dtype, page_size: int, kscale=None, vscale=None):
     """Decode's grouped-query attention over pages, for any model whose
     pages hold ``[n_kv_head, head_dim]`` keys and values a token: ``q``
     [B, n_head, head_dim] in ``dtype`` (positions, where the model has
@@ -916,13 +1005,17 @@ def gqa_decode_attention(q, kpool, vpool, pages, pos, length, *,
     kernel over the live pages (:func:`_gqa_attention_pallas`); None
     takes plain XLA over the whole table row masked past ``pos``
     (:func:`_gqa_attention_gather`). Returns float32 [B, n_head,
-    head_dim]; the two agree to :data:`ATTN_KERNEL_ULPS`."""
+    head_dim]; the two agree to :data:`ATTN_KERNEL_ULPS`. The kernel
+    alone also reads pools of int8 codes, given their scales a page
+    and KV head, ``kscale`` and ``vscale`` [pages, n_kv_head] float32
+    (:mod:`ray_tpu.models.gpt_decode`'s, whose gather is its own)."""
     assert q.shape[1:] == (n_head, head_dim), (q.shape, n_head, head_dim)
     if length is None:
+        assert kscale is None, "the gather reads no int8 pool"
         return _gqa_attention_gather(q, kpool, vpool, pages, pos,
                                      n_kv_head, dtype, page_size)
     return _gqa_attention_pallas(q, kpool, vpool, pages, length, n_kv_head,
-                                 page_size)
+                                 page_size, kscale, vscale)
 
 
 def forward(params: Params, tokens: jax.Array, cfg: KDAMoEConfig

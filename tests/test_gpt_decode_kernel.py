@@ -192,7 +192,7 @@ def test_paged_attention_matches_gather_direct(nano, nano_params):
     (8, 4), (16, 4), (8, 128), (16, 128), (8, 1), (64, 1)],
     ids=lambda v: str(v))
 def test_kernel_adapts_to_pool_shape(nano, ps, max_pages, kv_dtype):
-    """What the kernel's ring and chunk adapt to — ``page_size`` 8 / 16
+    """What the kernel's blocks adapt to — ``page_size`` 8 / 16
     / a lane's whole ``max_len`` (64, one page a lane), tables 1 / 4 /
     128 columns wide — over lanes at ``pos`` 0, mid-page, page-exact
     and full, an out-of-order table, and a lane whose row is all
@@ -231,6 +231,72 @@ def test_kernel_adapts_to_pool_shape(nano, ps, max_pages, kv_dtype):
     ref = run("gather", kc, vc, ks, vs)
     assert np.isfinite(out).all() and (out[-1] == 0).all()
     assert _ulps(out[:-1], ref[:-1]) <= gd.ATTN_KERNEL_ULPS
+    for bad in (np.inf, np.nan):
+        assert np.array_equal(
+            run("pallas", *_poison(kc, vc, ks, vs, pt, pos, ps, bad)),
+            out), bad
+
+
+#: Tokens of a block in the cases below (the kernel's own rule gives a
+#: block of 256 at two heads: a table of 128 positions would be one).
+BLOCK = 32
+#: ``pos`` of each lane of a call, None for a row of sentinels: what the
+#: lanes' blocks as ONE stream have to get right. A lane that ends on a
+#: block's last token has no partial block; one of three blocks refills
+#: its own ring; and empty lanes first, between and last are skipped by
+#: the fetch that runs ahead (the ring is filled once a call, from the
+#: first lane that has a block).
+STREAMS = {
+    "ends-on-a-block": [BLOCK - 1, 2 * BLOCK - 1, 3 * BLOCK - 1],
+    "three-blocks": [2 * BLOCK + 5, 3 * BLOCK - 2, 2 * BLOCK],
+    "first-lanes-empty": [None, None, BLOCK + 3, None, 5, 2 * BLOCK, None],
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["fp", "int8"])
+@pytest.mark.parametrize("ps", [16, 64, 96])
+@pytest.mark.parametrize("stream", sorted(STREAMS))
+def test_kernel_reads_the_lanes_blocks_as_one_stream(nano, monkeypatch,
+                                                     stream, ps, kv_dtype):
+    """The cases of :data:`STREAMS` at blocks of :data:`BLOCK` tokens,
+    two pages of 16, or a half of a page of 64 and a third of one of
+    96 (a page larger than a block is read in parts, each a block and
+    a copy): within the written bound of the gather on every
+    live lane, zeros on every empty one, and not a bit moved by ``inf``
+    or ``NaN`` in the pages no table maps and past ``pos``."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gpt_decode as gd, kda_moe
+
+    monkeypatch.setattr(kda_moe, "_GQA_BLOCK_TOKENS", BLOCK)
+    assert kda_moe._gqa_block(nano.n_head, ps) == {
+        16: (2, 1), 64: (1, 2), 96: (1, 3)}[ps]
+    H, hd, max_pages = nano.n_head, nano.head_dim, 8
+    quant = kv_dtype == "int8"
+    lanes = STREAMS[stream]
+    rng = np.random.default_rng(61 + len(lanes))
+    n_pages = sum(p // ps + 1 for p in lanes if p is not None) + 4
+    perm, off = rng.permutation(n_pages), 0
+    pt = np.full((len(lanes), max_pages), gd.PT_SENTINEL, np.int32)
+    for b, p in enumerate(lanes):
+        if p is not None:
+            pt[b, :p // ps + 1] = perm[off:off + p // ps + 1]
+            off += p // ps + 1
+    live = np.array([p is not None for p in lanes])
+    pos = jnp.asarray([p or 0 for p in lanes], jnp.int32)
+    kc, vc, ks, vs = _pool(rng, n_pages, ps, H, hd, nano.dtype, quant)
+    q = jnp.asarray(rng.standard_normal((len(lanes), 1, H, hd)),
+                    nano.dtype)
+
+    def run(kernel, kc, vc, ks, vs):
+        return np.asarray(gd.paged_attention(
+            q, kc, vc, jnp.asarray(pt), pos, page_size=ps, kernel=kernel,
+            ks=ks, vs=vs), np.float32)
+
+    out = run("pallas", kc, vc, ks, vs)
+    ref = run("gather", kc, vc, ks, vs)
+    assert np.isfinite(out).all() and (out[~live] == 0).all()
+    assert _ulps(out[live], ref[live]) <= gd.ATTN_KERNEL_ULPS
     for bad in (np.inf, np.nan):
         assert np.array_equal(
             run("pallas", *_poison(kc, vc, ks, vs, pt, pos, ps, bad)),
@@ -304,12 +370,15 @@ def test_kernel_work_does_not_scale_with_table_width(nano, kv_dtype):
         (call,) = _eqns(jaxpr.jaxpr, "pallas_call")
         gm = call.params["grid_mapping"]
         grids.append(tuple(gm.grid))
+        # the whole pool, viewed as the kernel takes a page: its rows
+        # as they lie, ``(token, head)`` (a reshape that moves no byte)
+        view = (n_pages, ps * H, hd)
         pools = [bm.transformed_block_aval for bm in gm.block_mappings
-                 if bm.array_aval.shape == kc.shape]
+                 if bm.array_aval.shape == view]
         assert len(pools) == 2                          # K and V
         for block in pools:
             assert str(block.memory_space) == "any", block
-            assert block.shape == kc.shape, block
+            assert block.shape == view, block
         assert "paged_attention" in str(call.source_info.name_stack)
     assert grids == [(B,), (B,)]
 

@@ -61,8 +61,12 @@ SHAPES = {
     "tp4-int8": (32, 4, 128, 16, 24 * 2048, 128, "int8"),
     # chip_smoke's tp probe: one page a lane, pt[s] = [s]
     "tp-probe": (2, 4, 128, 32, 2, 1, "fp"),
-    # a page that is a lane's whole max_len: the chunked loop, one slot
+    # a page that is a block by itself (128 tokens x 16 heads), one slot
+    "block-page": (4, 16, 128, 128, 64, 16, "fp"),
+    # a page that is a lane's whole max_len: sixteen blocks a page, each
+    # a part of it and a copy, the same ring
     "max-len-page": (4, 16, 128, 2048, 8, 1, "fp"),
+    "max-len-page-int8": (4, 16, 128, 2048, 8, 1, "int8"),
 }
 
 
@@ -94,23 +98,44 @@ def test_paged_attention_compiles_for_v5e(one_chip, compiled_mode, shape):
     assert "tpu_custom_call" in text
     # the pool is an operand of the kernel as it lies: no copy of it
     assert f"[{n_pages},{ps},{H},{hd}]" in text
-    assert "paged_attention/pallas_call" in text     # the scope's path
+    # the scope's path, the kernel's own name inside it
+    assert "paged_attention/gqa_attention/pallas_call" in text
 
 
-def test_heads_off_the_tiling_are_refused_by_name(compiled_mode):
-    """Mosaic addresses a page in whole tiles: a model whose heads are
-    not (GPT-2 small: 12 heads of 64) is refused when the program is
-    built, with the shapes and the way out, not deep in a compile."""
+#: (H, hd, page_size, pool dtype) -> what the message must name
+REFUSED = {
+    # GPT-2 small: 12 heads of 64, half a lane tile a head
+    "heads-of-64": (12, 64, 16, "bfloat16", "H=12, hd=64"),
+    # 8 x 3 = 24 rows a page: a tile and a half of bfloat16
+    "rows-off-the-tile": (3, 128, 8, "bfloat16", "H=3, hd=128"),
+    # int8 codes lie 32 rows a tile: a page of 16 x 1 is half of one
+    "int8-rows-off-the-tile": (1, 128, 16, "int8", "page_size=16, int8"),
+    # a page of 400 rows, 25 tiles, is read in two parts of 200: twelve
+    # tiles and a half
+    "part-off-the-tile": (1, 128, 400, "bfloat16", "page_size=400"),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(REFUSED))
+def test_heads_off_the_tiling_are_refused_by_name(compiled_mode, shape):
+    """Mosaic addresses a page's rows, ``(token, head)`` as they lie, in
+    whole tiles: a pool whose pages (or, of a page read in parts, whose
+    parts) are not whole tiles (GPT-2 small: 12 heads of 64) is refused
+    when the program is built, with the shapes and the way out, not
+    deep in a compile."""
     import jax.numpy as jnp
 
     from ray_tpu.models import gpt_decode as gd
 
-    q = jnp.zeros((2, 1, 12, 64), jnp.bfloat16)
-    pool = jnp.zeros((8, 16, 12, 64), jnp.bfloat16)
-    with pytest.raises(ValueError, match="H=12, hd=64.*gather"):
+    H, hd, ps, dtype, named = REFUSED[shape]
+    quant = dtype == "int8"
+    q = jnp.zeros((2, 1, H, hd), jnp.bfloat16)
+    pool = jnp.zeros((8, ps, H, hd), dtype)
+    scale = jnp.ones((8, H), jnp.float32) if quant else None
+    with pytest.raises(ValueError, match=f"{named}.*gather"):
         gd.paged_attention(q, pool, pool, jnp.zeros((2, 4), jnp.int32),
-                           jnp.zeros((2,), jnp.int32), page_size=16,
-                           kernel="pallas")
+                           jnp.zeros((2,), jnp.int32), page_size=ps,
+                           kernel="pallas", ks=scale, vs=scale)
 
 
 # ---------------------------------------------- latent attention (S5e)
