@@ -281,7 +281,7 @@ def yarn_inv_freq(cfg: MLAMoEConfig) -> jax.Array:
     return extra / cfg.rope_factor * ramp + extra * (1.0 - ramp)
 
 
-def _rope(x, positions, cfg: MLAMoEConfig):
+def rope(x, positions, cfg: MLAMoEConfig):
     """Rotate ``x`` [..., S, dim] (or [..., S, H, dim] with
     ``positions`` broadcast over H) by its positions [..., S]; halves
     pairing; float32 inside, ``x``'s dtype out."""
@@ -294,15 +294,18 @@ def _rope(x, positions, cfg: MLAMoEConfig):
                            axis=-1).astype(x.dtype)
 
 
-def _latent_qkv(x, p, positions, cfg: MLAMoEConfig):
-    """x [B, S, d] at ``positions`` [B, S] -> (q_n [B, S, H, nope],
-    q_r [B, S, H, rope] rotated, entry [B, S, latent_row]: the token's
-    cache row, ``c`` after its norm, ``k_r`` rotated, zeros to the
-    row's width). ``p`` is ONE attention's tree (``ln1_scale`` ..
-    ``wo``). ``cfg.q_gain`` scales ``c_q`` after its norm (so both
-    parts of the query: ``W_qb`` is linear) and ``cfg.kv_gain`` the
-    latent ``c`` after its norm, NOT ``k_r``; 1.0 is no factor at
-    all."""
+def latent_projections(x, p, positions, cfg: MLAMoEConfig):
+    """x [B, S, d] at ``positions`` [B, S] -> (h [B, S, d]: ``x`` after
+    the attention's norm, c_q [B, S, q_rank]: the query's low-rank
+    state after its norm, q_n [B, S, H, nope], q_r [B, S, H, rope]
+    rotated, entry [B, S, latent_row]: the token's cache row, ``c``
+    after its norm, ``k_r`` rotated, zeros to the row's width). ``p``
+    is ONE attention's tree (``ln1_scale`` .. ``wo``). ``cfg.q_gain``
+    scales ``c_q`` after its norm (so both parts of the query: ``W_qb``
+    is linear) and ``cfg.kv_gain`` the latent ``c`` after its norm, NOT
+    ``k_r``; 1.0 is no factor at all. ``h`` and ``c_q`` are what a
+    model reads that projects MORE from them
+    (:mod:`ray_tpu.models.dsa_moe`'s indexer)."""
     B, S, _ = x.shape
     H = cfg.n_head
     h = moe.rmsnorm(x, p["ln1_scale"], cfg.eps, cfg.dtype)
@@ -314,18 +317,42 @@ def _latent_qkv(x, p, positions, cfg: MLAMoEConfig):
     ckv = _mm(h, p["wkva"]["kernel"], cfg.dtype)
     c = moe.rmsnorm(ckv[..., :cfg.kv_rank], p["kv_norm_scale"], cfg.eps,
                  gain=cfg.kv_gain)
-    kr = _rope(ckv[..., cfg.kv_rank:], positions, cfg)
+    kr = rope(ckv[..., cfg.kv_rank:], positions, cfg)
     pad = jnp.zeros(c.shape[:-1] + (cfg.latent_row - cfg.latent_dim,),
                     c.dtype)
-    return qn, _rope(qr, positions, cfg), \
+    return h, cq, qn, rope(qr, positions, cfg), \
         jnp.concatenate([c, kr, pad], axis=-1)
 
 
-def _wkvb(p, cfg: MLAMoEConfig):
+def wkvb(p, cfg: MLAMoEConfig):
     """``W_kvb`` as (W_uk [kv_rank, H, nope], W_uv [kv_rank, H, v])."""
     w = p["wkvb"]["kernel"].astype(cfg.dtype).reshape(
         cfg.kv_rank, cfg.n_head, cfg.nope_dim + cfg.v_dim)
     return w[..., :cfg.nope_dim], w[..., cfg.nope_dim:]
+
+
+def absorbed_query(qn, qr, w_uk, cfg: MLAMoEConfig):
+    """Decode's query in the latent space: ``q_n`` [B, 1, H, nope]
+    through ``W_uk``, ``q_r`` [B, 1, H, rope] beside it and zeros in
+    the row's pad lanes: ``[B, H, latent_row]``, which meets a cached
+    row as one product."""
+    return jnp.concatenate([
+        jnp.einsum("bhn,rhn->bhr", qn[:, 0], w_uk,
+                   preferred_element_type=jnp.float32
+                   ).astype(cfg.dtype), qr[:, 0],
+        jnp.zeros((qn.shape[0], cfg.n_head,
+                   cfg.latent_row - cfg.latent_dim),
+                  cfg.dtype)], axis=-1)
+
+
+def attention_output(o, x, w_uv, p, cfg: MLAMoEConfig):
+    """``x + (o W_uv) W_o``: decode's weighted latents ``o`` [B, H,
+    kv_rank] through the values' up-projection and the output
+    projection, added to ``x`` [B, 1, d]."""
+    att = jnp.einsum("bhr,rhv->bhv", o, w_uv,
+                     preferred_element_type=jnp.float32
+                     ).astype(cfg.dtype).reshape(o.shape[0], 1, -1)
+    return x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
 
 
 def forward(params: Params, tokens: jax.Array, cfg: MLAMoEConfig
@@ -338,7 +365,7 @@ def forward(params: Params, tokens: jax.Array, cfg: MLAMoEConfig
     mask = jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
     x = moe.embed(params, tokens)
     for p in params["layers"]:
-        qn, qr, ent = _latent_qkv(x, p, positions, cfg)
+        qn, qr, ent = latent_projections(x, p, positions, cfg)[2:]
         att = _attend_materialised(qn, qr, ent, mask, p, cfg)
         x = x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype)
         x = moe.block_ffn(x.reshape(B * S, -1), p, cfg)[0].reshape(
@@ -350,7 +377,7 @@ def _materialised(qn, qr, latents, p, cfg: MLAMoEConfig):
     """Queries [B, S, H, .] against ``latents`` [B, K, kv_rank + rope]
     whose keys and values are materialised per head: ``(scores [B, H,
     S, K] float32, scaled; values [B, K, H, v])``."""
-    w_uk, w_uv = _wkvb(p, cfg)
+    w_uk, w_uv = wkvb(p, cfg)
     c = latents[..., :cfg.kv_rank]
     kr = latents[..., cfg.kv_rank:cfg.latent_dim]
     kn = jnp.einsum("bkr,rhn->bkhn", c, w_uk,
@@ -467,7 +494,7 @@ def prefill_attention(cache: Cache, S: int, length, hist_len, pt_row,
                        jnp.int32(PT_SENTINEL))
 
     def attend(x, p, a: int, pool):
-        qn, qr, ent = _latent_qkv(x, p, positions[None], cfg)
+        qn, qr, ent = latent_projections(x, p, positions[None], cfg)[2:]
         with jax.named_scope("mla.prefill"):
             att = _prefill_attend(
                 qn, qr, ent, pool, p, a, hist_len, T, hist_pages, causal,
@@ -492,6 +519,26 @@ def prefill_result(x, pool, params: Params, cache: Cache, length,
                       "pos": pos}, rng
 
 
+def fork_pages(entry, hist_len, pt_row, cow_src, page_size: int):
+    """The copy-on-write forks of ``G`` prompts in one per-token entry
+    of the pool (``entry`` ``[A, n_pages, ps, row]``; ``hist_len``
+    ``cow_src`` ``[G]``, ``pt_row`` ``[G, max_pages]``): every layer's
+    page at once, in the entry's FLAT view, which is returned; no fork
+    copies to an out-of-bounds page and is dropped."""
+    A, n_pages = entry.shape[:2]
+    max_pages = pt_row.shape[1]
+    pool = serving.flat(entry)
+    layers = jnp.arange(A, dtype=jnp.int32) * n_pages
+    dst = jnp.take_along_axis(
+        pt_row, jnp.clip(hist_len // page_size, 0, max_pages - 1)[:, None],
+        axis=1)
+    dst_w = jnp.where((cow_src[:, None] < n_pages) & (dst < n_pages),
+                      dst + layers, jnp.int32(PT_SENTINEL))     # [G, A]
+    src = jnp.clip(cow_src, 0, n_pages - 1)[:, None] + layers
+    return pool.at[dst_w.reshape(-1)].set(pool[src.reshape(-1)],
+                                          mode="drop")
+
+
 def prefill_group_attention(cache: Cache, rows, hist_len, pt_row, cow_src,
                             cfg, page_size: int):
     """:func:`prefill_attention` for the ``G`` prompts of one launch,
@@ -507,15 +554,7 @@ def prefill_group_attention(cache: Cache, rows, hist_len, pt_row, cow_src,
     A, n_pages = cache["latent"].shape[:2]
     G, max_pages = pt_row.shape
 
-    pool = serving.flat(cache["latent"])
-    layers = jnp.arange(A, dtype=jnp.int32) * n_pages
-    dst = jnp.take_along_axis(
-        pt_row, jnp.clip(hist_len // ps, 0, max_pages - 1)[:, None], axis=1)
-    dst_w = jnp.where((cow_src[:, None] < n_pages) & (dst < n_pages),
-                      dst + layers, jnp.int32(PT_SENTINEL))     # [G, A]
-    src = jnp.clip(cow_src, 0, n_pages - 1)[:, None] + layers
-    pool = pool.at[dst_w.reshape(-1)].set(pool[src.reshape(-1)],
-                                          mode="drop")
+    pool = fork_pages(cache["latent"], hist_len, pt_row, cow_src, ps)
 
     blocks = [serving.hist_blocks(pt_row[g], n_pages, ps) for g in range(G)]
     causal = [jnp.tril(jnp.ones((S, S), jnp.bool_))[None, None]
@@ -523,7 +562,7 @@ def prefill_group_attention(cache: Cache, rows, hist_len, pt_row, cow_src,
     page_w, off = rows.pages(pt_row, ps)
 
     def attend(x, p, a: int, pool):
-        qn, qr, ent = _latent_qkv(x, p, rows.positions[None], cfg)
+        qn, qr, ent = latent_projections(x, p, rows.positions[None], cfg)[2:]
         with jax.named_scope("mla.prefill"):
             att = jnp.concatenate([
                 _prefill_attend(n, r, e, pool, p, a, hist_len[g],
@@ -616,15 +655,18 @@ def decode_attention_fused(cfg: MLAMoEConfig, page_size: int,
 
 
 def _latent_attention_gather(q, pool, pages, pos, cfg: MLAMoEConfig,
-                             page_size: int):
+                             page_size: int, picked=None):
     """Decode's latent attention in plain XLA, the fallback and the
     tests' oracle: ``q`` [B, H, latent_row] (absorbed queries, zeros in
     the pad lanes) over each lane's WHOLE virtual sequence, gathered
     from the flat ``pool`` through ``pages`` [B, max_pages] (in bounds)
-    and masked past ``pos``. Returns ``o`` [B, H, kv_rank]."""
+    and masked past ``pos`` (and outside ``picked`` [B, V], where
+    given). Returns ``o`` [B, H, kv_rank]."""
     B = q.shape[0]
     V = pages.shape[1] * page_size
     seen = (jnp.arange(V)[None] <= pos[:, None])[:, None]    # [B, 1, V]
+    if picked is not None:
+        seen = seen & picked[:, None]
     lat = pool[pages].reshape(B, V, -1)
     lg = jnp.einsum("bhc,bvc->bhv", q, lat,
                     preferred_element_type=jnp.float32)
@@ -638,7 +680,7 @@ def _latent_attention_gather(q, pool, pages, pos, cfg: MLAMoEConfig,
 
 
 def _latent_attention_pallas(q, pool, pages, length, cfg: MLAMoEConfig,
-                             page_size: int):
+                             page_size: int, picked=None):
     """Decode's latent attention as ONE kernel that reads what is live,
     once: ``q`` [B, H, latent_row] against the first ``length[b]``
     tokens of lane ``b``, whose pages ``pages`` [B, max_pages] names in
@@ -672,7 +714,17 @@ def _latent_attention_pallas(q, pool, pages, length, cfg: MLAMoEConfig,
     last, partial block masks the scores AND the latents (a block's
     unfetched rows hold whatever was there, and 0 * inf is NaN). The
     first ``kv_rank`` lanes of ``acc / l`` are written once, at the
-    lane's end."""
+    lane's end.
+
+    ``picked`` [B, V] bool (``V = max_pages * page_size``; absent: every
+    live token) says which of a lane's live tokens the softmax runs
+    over, for a model that attends over a selection
+    (:mod:`ray_tpu.models.dsa_moe`): it rides as one float32 row a lane
+    in VMEM, 0 or -1e30 added to the block's scores. The pages are read
+    as ever, so a masked token costs what a picked one does; a block
+    without a picked token folds to weights that the first picked
+    token's rescale wipes (``alpha`` 0), and at least one live token
+    must be picked."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -688,9 +740,14 @@ def _latent_attention_pallas(q, pool, pages, length, cfg: MLAMoEConfig,
     first = jnp.concatenate([
         jnp.zeros((1,), jnp.int32),
         jnp.cumsum((length + T - 1) // T, dtype=jnp.int32)])
+    bias = ()
+    if picked is not None:
+        bias = (jnp.pad(jnp.where(picked, 0.0, -1e30).astype(jnp.float32),
+                        ((0, 0), (0, -picked.shape[1] % T)),
+                        constant_values=-1e30)[:, None],)
 
-    def kernel(pt_ref, len_ref, first_ref, q_ref, pool_hbm, o_ref, buf,
-               sems):
+    def kernel(pt_ref, len_ref, first_ref, q_ref, pool_hbm, *rest):
+        *bias_ref, o_ref, buf, sems = rest
         b = pl.program_id(0)
         n_live = len_ref[b]
         base, total = first_ref[b], first_ref[B]
@@ -732,6 +789,8 @@ def _latent_attention_pallas(q, pool, pages, length, cfg: MLAMoEConfig,
             blk = buf[i % ring].reshape(T, R)
             s = lax.dot_general(qv, blk, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
+            if bias_ref:
+                s = s + bias_ref[0][0, :, pl.ds(pl.multiple_of(j * T, T), T)]
             if not whole:
                 s = jnp.where(j * T + lax.broadcasted_iota(
                     jnp.int32, (1, T), 1) < n_live, s, -1e30)
@@ -772,7 +831,8 @@ def _latent_attention_pallas(q, pool, pages, length, cfg: MLAMoEConfig,
             num_scalar_prefetch=3,
             grid=(B,),
             in_specs=[pl.BlockSpec((1, H, R), lane_map),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+                      pl.BlockSpec(memory_space=pl.ANY)]
+            + [pl.BlockSpec((1, 1, b.shape[2]), lane_map) for b in bias],
             out_specs=pl.BlockSpec((1, H, cfg.kv_rank), lane_map),
             scratch_shapes=[pltpu.VMEM((ring, bp, ps, R), pool.dtype),
                             pltpu.SemaphoreType.DMA((ring,))]),
@@ -782,7 +842,45 @@ def _latent_attention_pallas(q, pool, pages, length, cfg: MLAMoEConfig,
         compiler_params=pltpu.CompilerParams(disable_bounds_checks=True),
         interpret=pallas_interpret(),
         name="latent_attention",
-    )(pages, length, first, q, pool)
+    )(pages, length, first, q, pool, *bias)
+
+
+def latent_attention(q, pool, pages, pos, length, cfg: MLAMoEConfig,
+                     page_size: int, picked=None):
+    """Decode's latent attention of ``q`` [B, H, latent_row] over each
+    lane's pages ``pages`` [B, max_pages] of the flat ``pool``, under a
+    public name: the kernel where ``length`` [B] is given (the caller
+    asked :func:`decode_attention_fused`), else plain XLA over the
+    gathered pages up to ``pos``; over the ``picked`` [B, V] tokens
+    alone where given. Returns ``o`` [B, H, kv_rank]."""
+    if length is not None:
+        return _latent_attention_pallas(q, pool, pages, length, cfg,
+                                        page_size, picked)
+    return _latent_attention_gather(q, pool, pages, pos, cfg, page_size,
+                                    picked)
+
+
+def decode_lanes(cache: Cache, active, pt, cfg, page_size: int,
+                 attn_kernel: str = "gather"):
+    """Where a decode step's lanes write and read: ``(page_w [B]: the
+    page each active lane's own position lands in, the sentinel for an
+    inactive lane or a position past its table; ptc [B, max_pages]: the
+    table clipped into the pool; length [B]: the tokens the kernel
+    reads a lane, or None where the step holds no kernel`` (:func:`
+    decode_attention_fused`)."""
+    ps = page_size
+    max_pages = pt.shape[1]
+    pos = cache["pos"]
+    n_pages = cache["latent"].shape[1]
+    vp = pos // ps
+    page_w = jnp.where(
+        active & (vp < max_pages),
+        jnp.take_along_axis(pt, jnp.clip(vp, 0, max_pages - 1)[:, None],
+                            axis=1)[:, 0], jnp.int32(PT_SENTINEL))
+    ptc = jnp.clip(pt, 0, n_pages - 1)
+    length = serving.live_length(pt, pos, active, n_pages, ps) \
+        if decode_attention_fused(cfg, ps, attn_kernel) else None
+    return page_w, ptc, length
 
 
 def decode_attention(cache: Cache, active, pt, cfg, page_size: int,
@@ -797,40 +895,21 @@ def decode_attention(cache: Cache, active, pt, cfg, page_size: int,
     it (the kernel wherever :func:`decode_attention_fused`, else the
     XLA body). Inactive lanes do not write."""
     ps = page_size
-    B, max_pages = pt.shape
     pos = cache["pos"]
     n_pages = cache["latent"].shape[1]
-    vp = pos // ps
-    page_w = jnp.where(
-        active & (vp < max_pages),
-        jnp.take_along_axis(pt, jnp.clip(vp, 0, max_pages - 1)[:, None],
-                            axis=1)[:, 0], jnp.int32(PT_SENTINEL))
-    ptc = jnp.clip(pt, 0, n_pages - 1)
-    fused = decode_attention_fused(cfg, ps, attn_kernel)
-    length = serving.live_length(pt, pos, active, n_pages, ps) if fused \
-        else None
+    page_w, ptc, length = decode_lanes(cache, active, pt, cfg, ps,
+                                       attn_kernel)
 
     def attend(x, p, a: int, pool):
-        qn, qr, ent = _latent_qkv(x, p, pos[:, None], cfg)
+        qn, qr, ent = latent_projections(x, p, pos[:, None], cfg)[2:]
         pool = pool.at[serving.at_layer(page_w, a, n_pages),
                        pos % ps].set(ent[:, 0], mode="drop")
-        w_uk, w_uv = _wkvb(p, cfg)
-        q = jnp.concatenate([
-            jnp.einsum("bhn,rhn->bhr", qn[:, 0], w_uk,
-                       preferred_element_type=jnp.float32
-                       ).astype(cfg.dtype), qr[:, 0],
-            jnp.zeros((B, cfg.n_head, cfg.latent_row - cfg.latent_dim),
-                      cfg.dtype)], axis=-1)
+        w_uk, w_uv = wkvb(p, cfg)
+        q = absorbed_query(qn, qr, w_uk, cfg)
         with jax.named_scope("mla.attention"):
             pages = ptc + a * n_pages
-            o = _latent_attention_pallas(q, pool, pages, length, cfg,
-                                         ps) if fused else \
-                _latent_attention_gather(q, pool, pages, pos, cfg, ps)
-        att = jnp.einsum("bhr,rhv->bhv", o, w_uv,
-                         preferred_element_type=jnp.float32
-                         ).astype(cfg.dtype).reshape(B, 1, -1)
-        return x + _mm(att, p["wo"]["kernel"], cfg.dtype).astype(x.dtype), \
-            pool
+            o = latent_attention(q, pool, pages, pos, length, cfg, ps)
+        return attention_output(o, x, w_uv, p, cfg), pool
 
     return serving.flat(cache["latent"]), attend
 

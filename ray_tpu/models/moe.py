@@ -134,20 +134,23 @@ def moe_ffn(x: jax.Array, router_kernel: jax.Array, w_up: jax.Array,
 # ``zero_experts`` is the part of the ids that cost nothing.
 
 def group_limited_top_k(scores: jax.Array, n_group: int, topk_group: int,
-                        top_k: int) -> Tuple[jax.Array, jax.Array]:
+                        top_k: int, floor: float = -1.0
+                        ) -> Tuple[jax.Array, jax.Array]:
     """scores [T, E] (each in (0, 1]) -> (ids [T, k] int32, their
     scores [T, k]), best first. The E experts lie in ``n_group``
     contiguous groups; a group's score is the sum of its two highest
     scores, the ``topk_group`` best groups stay, and the ``top_k``
     highest scores among their experts are chosen. ``topk_group ==
-    n_group`` is a plain top k."""
+    n_group`` is a plain top k. ``floor``: what a closed group's
+    experts score, below every open one's (scores that carry a
+    selection bias may lie below -1)."""
     T, E = scores.shape
     if topk_group < n_group:
         per = E // n_group
         best2 = lax.top_k(scores.reshape(T, n_group, per), 2)[0]
         _, keep = lax.top_k(best2.sum(-1), topk_group)      # [T, kg]
         kept = jnp.any(keep[:, :, None] == jnp.arange(n_group), axis=1)
-        scores = jnp.where(jnp.repeat(kept, per, axis=1), scores, -1.0)
+        scores = jnp.where(jnp.repeat(kept, per, axis=1), scores, floor)
     chosen, ids = lax.top_k(scores, top_k)
     return ids.astype(jnp.int32), chosen
 
@@ -164,15 +167,24 @@ def _router_logits(x: jax.Array, router_kernel: jax.Array, dtype,
 
 def route_sigmoid(x: jax.Array, router_kernel: jax.Array, *, n_group: int,
                   topk_group: int, top_k: int, norm_topk: bool,
-                  route_scale: float, dtype
+                  route_scale: float, dtype,
+                  bias: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, jax.Array]:
     """x [T, d] -> (ids [T, k], weights [T, k] float32): sigmoid scores
     over ALL routed experts (the router keeps its published width),
     group-limited selection, the chosen scores normalised to sum to one
-    where ``norm_topk``, times ``route_scale``."""
-    ids, s = group_limited_top_k(
-        jax.nn.sigmoid(_router_logits(x, router_kernel, dtype)), n_group,
-        topk_group, top_k)
+    where ``norm_topk``, times ``route_scale``. ``bias`` [width]
+    float32 (absent: none, and the program is what it was) steers the
+    SELECTION only, the groups' and the experts' alike: both are chosen
+    on ``score + bias``, and the weights are the chosen experts'
+    UNBIASED scores."""
+    s = jax.nn.sigmoid(_router_logits(x, router_kernel, dtype))
+    if bias is None:
+        ids, s = group_limited_top_k(s, n_group, topk_group, top_k)
+    else:
+        ids, _ = group_limited_top_k(s + bias.astype(jnp.float32), n_group,
+                                     topk_group, top_k, floor=-jnp.inf)
+        s = jnp.take_along_axis(s, ids, axis=1)
     if norm_topk:
         s = s / (jnp.sum(s, axis=-1, keepdims=True) + 1e-20)
     return ids, s * route_scale

@@ -3,7 +3,7 @@
 :class:`~ray_tpu.serve.engine.DecodeEngine` serves any decoder whose
 config object answers ``cfg.decode_programs()`` with a module (or
 namespace) of the paged slot-pool programs: the engine binds no model
-module by name. Six decoders answer today: the GPT-2 block
+module by name. Seven decoders answer today: the GPT-2 block
 (:mod:`ray_tpu.models.gpt_decode`: a page holds keys and values per
 head), the latent-attention expert decoder
 (:mod:`ray_tpu.models.mla_moe`: a page holds one 576-wide latent a
@@ -25,7 +25,12 @@ OR pages; the mixer is ``ssm_hybrid``'s and the attention ``kda_moe``'s,
 both imported under public names; its expert layer is routed by the
 THIRD router, :func:`ray_tpu.models.moe.route_topk_softmax`, the top k
 logits and a softmax over the chosen ones, and its head is its
-table).
+table) and the sparse latent-attention expert decoder
+(:mod:`ray_tpu.models.dsa_moe`: ``mla_moe``'s block whose page holds a
+SECOND per-token entry, an indexer's key, under the same page ids, and
+whose decode step attends over the ``index_topk`` cached tokens the
+indexer scores highest; the attention and its kernel are ``mla_moe``'s,
+imported under public names, the kernel with the selection as a mask).
 
 **A description provides** what only the model knows:
 
@@ -275,7 +280,7 @@ def knob_cache(fn):
     those spellings separately, silently doubling the compiled-program
     set and breaking the recompile guards' wrapper ``is``-identity.
     512 entries: the frame's two factories hold every description's
-    wrappers (51 each of six)."""
+    wrappers (51 each of seven)."""
     sig = inspect.signature(fn)
     cached = functools.lru_cache(maxsize=512)(fn)
 

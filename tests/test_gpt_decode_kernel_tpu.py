@@ -1,5 +1,6 @@
 """The decode step's kernels (``gpt_decode``'s attention over pages of
-keys and values per head, ``mla_moe``'s over latent pages, ``kda_moe``'s
+keys and values per head, ``mla_moe``'s over latent pages (and again
+under ``dsa_moe``'s selection, masked), ``kda_moe``'s
 recurrence on the per-slot state and its grouped-query attention over
 pages of ``(token, KV head)`` rows, ``ssm_hybrid``'s recurrence on its
 state-space state, and the last two again as ``ssm_moe`` imports them)
@@ -277,6 +278,61 @@ def test_the_shortcut_models_step_holds_the_imported_kernel_for_v5e(
     assert "decode_step/mla.attention/latent_attention/pallas_call" in text
     assert text.count("custom_call_target=\"tpu_custom_call\"") == 4
     for scope in ("scmoe.dense", "moe.route", "moe.experts", "moe.zero"):
+        assert f"decode_step/{scope}/" in text, scope
+
+
+def test_the_sparse_models_step_masks_the_imported_kernel_for_v5e(
+        one_chip, compiled_mode):
+    """``dsa_moe``'s decode step as a TPU process builds it, at the
+    cell's attention and indexer widths and its ``max_len`` (128 heads,
+    64 index heads of 128, pages of 16 to 5,120 tokens, ``index_topk``
+    2,048): the latent attention is ``mla_moe``'s kernel with the
+    selection's mask as one more operand (a float32 row a lane in VMEM,
+    sliced a block at a time: the slice is on the tiling), once a
+    layer, under the selection's own scope; the bisection for the
+    2,048th score is a kernel of its own under ``dsa.select``
+    (``pick_top``: 64 lanes' scores a grid step in VMEM), once a
+    layer too; the index scores are plain XLA under theirs."""
+    import dataclasses
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private import chip
+    from ray_tpu.models import dsa_moe
+
+    cfg = dataclasses.replace(
+        dsa_moe.CONFIGS["nano"], n_layer=2, d_model=256, n_head=128,
+        q_rank=128, kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+        experts_held=8, index_heads=64, index_dim=128, index_topk=2048)
+    B, ps, max_pages = 8, 16, 320
+    n_pages = B * max_pages
+
+    def arg(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    params = jax.eval_shape(
+        lambda k: dsa_moe.init_params(k, cfg), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(
+        lambda: dsa_moe.init_paged_cache(cfg, B, n_pages, ps))
+    assert cache["latent"].shape == (2, n_pages, ps, 640)
+    assert cache["ikey"].shape == (2, n_pages, ps, 128)
+    args = (params, cache, jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B,), jnp.bool_),
+            jax.ShapeDtypeStruct((B, max_pages), jnp.int32))
+    assert dsa_moe.decode_attention_fused(cfg, ps)
+    lowered = jax.jit(functools.partial(
+        dsa_moe._slot_decode_step_paged, cfg=cfg, page_size=ps),
+        donate_argnums=(1,)).lower(*jax.tree.map(arg, args))
+    assert chip.compiled_by_mosaic(lowered.as_text())
+    text = lowered.compile().as_text()
+    assert "decode_step/dsa.attention/latent_attention/pallas_call" in text
+    assert "decode_step/dsa.select/pick_top/pallas_call" in text
+    assert "mla.attention" not in text
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 4
+    for scope in ("dsa.index", "moe.route", "moe.experts",
+                  "moe.shared"):
         assert f"decode_step/{scope}/" in text, scope
 
 

@@ -1,5 +1,5 @@
-"""The serving frame (``ray_tpu/models/serving.py``) and the six model
-descriptions around it.
+"""The serving frame (``ray_tpu/models/serving.py``) and the seven
+model descriptions around it.
 
 - no module under ``ray_tpu/models`` imports, or reads off another
   module of the package, an underscore name: what two models share has a
@@ -26,8 +26,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import (gpt, gpt_decode, kda_moe, mla_moe, scmoe,
-                            serving, ssm_hybrid, ssm_moe)
+from ray_tpu.models import (dsa_moe, gpt, gpt_decode, kda_moe, mla_moe,
+                            scmoe, serving, ssm_hybrid, ssm_moe)
 from ray_tpu.serve.engine import DecodeEngine
 
 MODELS = os.path.dirname(os.path.abspath(serving.__file__))
@@ -132,6 +132,7 @@ DESCRIPTIONS = {
     scmoe: (4, 32, 4, 8),
     ssm_hybrid: (4, 32, 4, 8),
     ssm_moe: (4, 32, 4, 8),
+    dsa_moe: (4, 32, 4, 8),
 }
 
 
@@ -301,6 +302,10 @@ PARENT_TEXT = {
                 "cf3802afa63a7310"),
     "ssm_hybrid": ("31a6d34e04b5d0fb", "5381337d2af13972",
                    "ed13fa45b4726bd6"),
+    # as PR 63 measured it on the chip (its own first values; the chunk
+    # program's with the selection as one kernel, after the review)
+    "dsa_moe": ("ffed2d3086b3faf2", "1e772c0440ee7efa",
+                "723f89fa4d853c84"),
 }
 
 

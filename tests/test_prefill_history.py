@@ -163,10 +163,11 @@ def _oracle_latent_attention(cache, S, length, hist_len, pt_row, cow_src,
                        jnp.int32(PT_SENTINEL))
 
     def attend(x, p, a, pool):
-        qn, qr, ent = mla_moe._latent_qkv(x, p, positions[None], cfg)
+        qn, qr, ent = mla_moe.latent_projections(
+            x, p, positions[None], cfg)[2:]
         latents = jnp.concatenate(
             [pool[ptc + a * n_pages].reshape(1, V, -1), ent], axis=1)
-        w_uk, w_uv = mla_moe._wkvb(p, cfg)
+        w_uk, w_uv = mla_moe.wkvb(p, cfg)
         c = latents[..., :cfg.kv_rank]
         kr = latents[..., cfg.kv_rank:cfg.latent_dim]
         kn = jnp.einsum("bkr,rhn->bkhn", c, w_uk,

@@ -401,7 +401,7 @@ def test_descriptions_are_found_by_rule_and_the_frame_is_in_scope(
         tmp_path):
     """A module of ``ray_tpu/models`` that defines ``cache_spec`` is a
     description: rtflow's alias resolution and budget scope and rtsan's
-    dispatch wrap find all six by that one rule (``scmoe``,
+    dispatch wrap find all seven by that one rule (``scmoe``,
     ``ssm_hybrid`` and ``ssm_moe``, which no list named, among them);
     the engine's ``self._model.jit_x(...)``
     resolves to the FRAME's def of a factory a description only binds;
@@ -413,18 +413,18 @@ def test_descriptions_are_found_by_rule_and_the_frame_is_in_scope(
 
     models = os.path.join(REPO, "ray_tpu", "models")
     assert description_names(models) == [
-        "gpt_decode", "kda_moe", "mla_moe", "scmoe", "ssm_hybrid",
-        "ssm_moe"]
+        "dsa_moe", "gpt_decode", "kda_moe", "mla_moe", "scmoe",
+        "ssm_hybrid", "ssm_moe"]
     mods = [Module(ap, os.path.relpath(ap, REPO), open(ap).read())
             for ap, _ in collect_files([os.path.join(REPO, "ray_tpu")])]
     by_name = {os.path.basename(m.relpath): m for m in mods
                if "/models/" in m.relpath}
     assert [n for n, m in sorted(by_name.items()) if is_description(m)] \
-        == ["gpt_decode.py", "kda_moe.py", "mla_moe.py", "scmoe.py",
-            "ssm_hybrid.py", "ssm_moe.py"]
+        == ["dsa_moe.py", "gpt_decode.py", "kda_moe.py", "mla_moe.py",
+            "scmoe.py", "ssm_hybrid.py", "ssm_moe.py"]
     assert all(in_budget_scope(by_name[n]) for n in (
         "ssm_moe.py", "ssm_hybrid.py", "scmoe.py", "kda_moe.py",
-        "mla_moe.py",
+        "mla_moe.py", "dsa_moe.py",
         "gpt_decode.py", "serving.py"))
     assert not in_budget_scope(by_name["gpt.py"])
     g = CallGraph.build(mods)
